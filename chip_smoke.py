@@ -1,0 +1,547 @@
+"""On-chip smoke: the main path, once, through the entry points users call.
+
+    python chip_smoke.py              # one TPU chip
+    python chip_smoke.py --multichip  # four chips: data-parallel training
+
+One process, every phase in it (a chip belongs to one process). The
+phases call the same ``main(argv)`` functions that ``python -m
+deepspeech_tpu.train|infer|serve`` call, at the published widths of
+presets the repo ships — only steps, utterances and seconds of audio
+are cut:
+
+  device     jax.devices(); anything but a TPU ends the run
+  train      ds2_full (2 conv + 7 BiGRU-1760 + BN, bf16), b=16, 4 steps
+  infer      restores that checkpoint, greedy-decodes 32 utterances
+  reference  the Pallas GRU and CTC kernels against the repo's XLA/jnp
+             oracles at those widths on a small input
+  serve      ds2_streaming (uni-GRU 5x800 + lookahead 20): a checkpoint
+             from two train steps, two generated wavs streamed chunk by
+             chunk, finals compared with the offline decode of the same
+             wavs
+  sync       one jitted call timed to block_until_ready and to a host
+             read
+
+Every phase prints one JSON line of set-up facts (wall seconds, compiles
+and compile seconds, persistent-cache hits, losses, peak device memory);
+they are not benchmark numbers. A phase that raises ends the run with
+its traceback. The last line of stdout is the result the driver reads:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+``--multichip`` runs, instead of the phases above, the same three
+ds2_full steps on all four chips and on one, and compares the losses.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import gc
+import io
+import json
+import math
+import os
+import shutil
+import sys
+import tempfile
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+
+BATCH = 16
+SEED = 0  # wavs; the weights come from the presets' train.seed
+# Side of the square matmuls the sync probe chains: large enough that
+# the device needs tens of milliseconds, so a block_until_ready that
+# returned at dispatch would show.
+SYNC_N = 4096
+# Losses of two runs that differ only in how the batch is split over
+# chips: bf16 matmuls keep 8 mantissa bits (eps 2^-8 ~ 0.004), and the
+# gradient all-reduce sums in another order, so allow a few eps.
+LOSS_RTOL = 0.02
+# Kernel against oracle at bf16 dots (r2 on this chip measured 1.3e-3).
+GRU_RTOL = 1e-2
+CTC_RTOL = 1e-3
+# Streamed finals against the offline decode of the same audio: the
+# two graphs reduce in different orders in bf16, so an argmax near a
+# tie may flip; more than this is a wrong stream, not rounding.
+STREAM_CER_MAX = 0.1
+
+
+def emit(rec: dict) -> None:
+    print(json.dumps(rec), flush=True)
+
+
+def fail(msg: str):
+    raise SystemExit(f"chip_smoke: FAILED: {msg}")
+
+
+# -- observation ----------------------------------------------------------
+
+class CompileCounter:
+    """Counts what jax reports about compilation while the phases run:
+    every backend compile request (and its seconds), and how many of
+    them the persistent cache answered."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.cache_hits = 0
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, duration_secs, **_):
+        if event == self.COMPILE:
+            self.compiles += 1
+            self.compile_s += duration_secs
+
+    def _on_event(self, event, **_):
+        if event == self.HIT:
+            self.cache_hits += 1
+
+    def snapshot(self):
+        return self.compiles, self.compile_s, self.cache_hits
+
+    def since(self, snap) -> dict:
+        return {"compiles": self.compiles - snap[0],
+                "compile_s": round(self.compile_s - snap[1], 2),
+                "cache_hits": self.cache_hits - snap[2]}
+
+
+class _Tee(io.TextIOBase):
+    """stdout that passes text through and keeps each line with the
+    time it arrived."""
+
+    def __init__(self, out):
+        self._out = out
+        self._buf = ""
+        self.lines = []  # (perf_counter, line)
+
+    def write(self, s):
+        self._out.write(s)
+        self._buf += s
+        while "\n" in self._buf:
+            line, self._buf = self._buf.split("\n", 1)
+            self.lines.append((time.perf_counter(), line))
+        return len(s)
+
+    def flush(self):
+        self._out.flush()
+
+    def records(self):
+        """(arrival time, parsed object) of every JSON line."""
+        out = []
+        for t, line in self.lines:
+            if line.startswith("{"):
+                out.append((t, json.loads(line)))
+        return out
+
+
+def call_main(main, argv):
+    """Run one entry point's ``main(argv)``; returns its stdout tee."""
+    tee = _Tee(sys.stdout)
+    with contextlib.redirect_stdout(tee):
+        main(argv)
+    return tee
+
+
+def run_phase(name: str, counter: CompileCounter, fn, *args) -> None:
+    import jax
+
+    gc.collect()  # drop the previous phase's device buffers
+    snap = counter.snapshot()
+    t0 = time.perf_counter()
+    facts = fn(*args)
+    rec = {"phase": name,
+           "wall_s": round(time.perf_counter() - t0, 2),
+           **counter.since(snap), **facts}
+    stats = jax.devices()[0].memory_stats() or {}
+    if "peak_bytes_in_use" in stats:
+        rec["peak_bytes_in_use"] = stats["peak_bytes_in_use"]
+    emit(rec)
+
+
+# -- checks ---------------------------------------------------------------
+
+def check_device(want: int) -> dict:
+    """The device as jax reports it; the run ends here without a TPU."""
+    import jax
+
+    devs = jax.devices()
+    dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+           "count": len(devs)}
+    if dev["platform"] != "tpu":
+        fail(f"no TPU: jax.devices() reports {dev}")
+    if dev["count"] != want:
+        fail(f"need {want} chip(s), jax.devices() reports {dev}")
+    return dev
+
+
+def kernel_route(preset: str) -> dict:
+    """What 'auto' resolved to for this preset, and which recurrent
+    kernel its width selects."""
+    import jax.numpy as jnp
+
+    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.ops.rnn_pallas import bigru_fits_vmem, fits_vmem
+    from deepspeech_tpu.utils.impl import interpret_default, resolve_impl
+    from deepspeech_tpu.utils.quantize import kernel_regime
+
+    cfg = get_config(preset)
+    h = cfg.model.rnn_hidden
+    dot_bytes = jnp.dtype(cfg.model.dtype).itemsize
+    if cfg.model.bidirectional and bigru_fits_vmem(h, dot_bytes):
+        route = "bigru-resident"
+    elif fits_vmem(h, dot_bytes):
+        route = "resident"
+    else:
+        route = "blocked"
+    return {"preset": preset,
+            "rnn_impl": resolve_impl(cfg.model.rnn_impl, oracle="xla"),
+            "loss_impl": resolve_impl(cfg.train.loss_impl, oracle="jnp"),
+            "interpret": interpret_default(),
+            "kernel_regime": kernel_regime(cfg.model, quantized=False),
+            "rnn_route": route, "rnn_hidden": h}
+
+
+def check_kernels(route: dict, step_text: str) -> dict:
+    """'auto' must have chosen the compiled Pallas kernels, and the
+    lowered train step must hold them: a run on interpreted kernels or
+    on the oracles looks the same from outside."""
+    if route["rnn_impl"] != "pallas" or route["loss_impl"] != "pallas":
+        fail(f"'auto' did not resolve to pallas: {route}")
+    if route["interpret"]:
+        fail("Pallas kernels would run interpreted")
+    n = step_text.count("tpu_custom_call")
+    if n == 0:
+        fail("the lowered train step holds no tpu_custom_call")
+    return {"tpu_custom_calls": n}
+
+
+@contextlib.contextmanager
+def watch_train_step():
+    """Keep a handle on the jitted step ``train.main`` builds, the
+    shapes of its first call, and what the devices held while it ran —
+    for the HLO and placement checks; the step itself is untouched."""
+    import jax
+
+    from deepspeech_tpu import train
+
+    seen = {}
+    make = train.make_train_step
+
+    def make_and_watch(*a, **kw):
+        step = seen["step"] = make(*a, **kw)
+
+        def watched(*args):
+            if "args" not in seen:
+                seen["args"] = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+                seen["batch_devices"] = len(
+                    args[1]["features"].sharding.device_set)
+            else:  # the first step has run: the state is resident
+                seen["bytes_in_use"] = [
+                    (d.memory_stats() or {}).get("bytes_in_use")
+                    for d in jax.devices()]
+            return step(*args)
+
+        return watched
+
+    train.make_train_step = make_and_watch
+    try:
+        yield seen
+    finally:
+        train.make_train_step = make
+
+
+# -- phases ---------------------------------------------------------------
+
+def train_steps(preset: str, n_steps: int, ckpt_dir: str, extra=()):
+    """``train.main`` for ``n_steps`` synthetic batches; returns the
+    per-step facts and the watched step."""
+    from deepspeech_tpu import train
+
+    t0 = time.perf_counter()
+    with watch_train_step() as seen:
+        tee = call_main(train.main, [
+            f"--config={preset}", f"--synthetic={n_steps * BATCH}",
+            f"--data.batch_size={BATCH}", "--train.epochs=1",
+            "--train.log_every=1", f"--train.checkpoint_dir={ckpt_dir}",
+            *extra])
+    steps = [(t, r) for t, r in tee.records()
+             if r.get("event") == "train_step"]
+    losses = [r["loss"] for _, r in steps]
+    if len(steps) < n_steps:
+        fail(f"{preset}: {len(steps)} optimizer steps logged, "
+             f"wanted {n_steps}")
+    if not all(math.isfinite(x) for x in losses):
+        fail(f"{preset}: non-finite loss in {losses}")
+    if not os.path.isdir(os.path.join(ckpt_dir, str(n_steps))):
+        fail(f"{preset}: no checkpoint for step {n_steps} in {ckpt_dir}")
+    stamps = [t0] + [t for t, _ in steps]
+    step_s = [round(b - a, 3) for a, b in zip(stamps, stamps[1:])]
+    facts = {"preset": preset, "steps": len(steps), "losses": losses,
+             # Set-up and compile land in the first step's seconds.
+             "first_step_s": step_s[0], "later_step_s": step_s[1:],
+             "checkpoint": ckpt_dir}
+    return facts, seen
+
+
+def phase_train(work: str) -> dict:
+    route = kernel_route("ds2_full")
+    emit({"resolved": route})
+    facts, seen = train_steps("ds2_full", 4, os.path.join(work, "full"))
+    lowered = seen["step"].lower(*seen["args"])
+    facts.update(check_kernels(route, lowered.as_text()))
+    return facts
+
+
+def phase_infer(work: str) -> dict:
+    from deepspeech_tpu import infer
+
+    n = 2 * BATCH
+    tee = call_main(infer.main, [
+        "--config=ds2_full",
+        f"--checkpoint-dir={os.path.join(work, 'full')}",
+        f"--synthetic={n}", f"--data.batch_size={BATCH}"])
+    done = [r for _, r in tee.records() if r.get("event") == "done"]
+    if len(done) != 1 or done[0]["n_utts"] != n:
+        fail(f"infer: wanted one 'done' line for {n} utterances, "
+             f"got {done}")
+    if not all(math.isfinite(done[0][k]) for k in ("wer", "cer")):
+        fail(f"infer: non-finite error rate in {done[0]}")
+    return {"n_utts": n, "wer": done[0]["wer"], "cer": done[0]["cer"]}
+
+
+def phase_reference() -> dict:
+    """Kernel against oracle at the presets' widths, small B and T."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeech_tpu.models.rnn import gru_scan
+    from deepspeech_tpu.ops.ctc import ctc_loss
+    from deepspeech_tpu.ops.ctc_pallas import ctc_loss_pallas
+    from deepspeech_tpu.ops.rnn_pallas import gru_scan_pallas
+    from deepspeech_tpu.utils.impl import interpret_default
+
+    interpret = interpret_default()
+    rng = np.random.default_rng(0)
+    b, t = 8, 8
+    out = {}
+    for h in (1760, 800):  # blocked and resident weights
+        xp = jnp.asarray(rng.normal(size=(b, t, 3 * h)), jnp.float32)
+        wh = jnp.asarray(rng.normal(size=(h, 3 * h)) / np.sqrt(h),
+                         jnp.float32)
+        bh = jnp.asarray(rng.normal(size=(3 * h,)) * 0.1, jnp.float32)
+        lens = rng.integers(t // 2, t + 1, size=b)
+        mask = jnp.asarray(np.arange(t)[None] < lens[:, None], jnp.float32)
+        got = np.asarray(jax.jit(lambda x, w: gru_scan_pallas(
+            x, mask, w, bh, False, interpret, "bfloat16"))(xp, wh))
+        want = np.asarray(jax.jit(lambda x, w: gru_scan(
+            x, mask, w, bh, dot_dtype=jnp.bfloat16))(xp, wh))
+        err = float(np.max(np.abs(got - want))
+                    / max(1.0, float(np.abs(want).max())))
+        if not err <= GRU_RTOL:
+            fail(f"GRU H={h} kernel differs from the XLA scan: {err}")
+        out[f"gru_h{h}_rel_err"] = err
+    t, v, lmax = 100, 29, 20
+    logits = jnp.asarray(rng.normal(size=(b, t, v)), jnp.float32)
+    label_lens = jnp.asarray(rng.integers(lmax // 2, lmax + 1, size=b),
+                             jnp.int32)
+    labels = jnp.asarray(rng.integers(1, v, size=(b, lmax)), jnp.int32)
+    labels = labels * (jnp.arange(lmax)[None] < label_lens[:, None])
+    in_lens = jnp.full((b,), t, jnp.int32)
+    got = np.asarray(jax.jit(lambda lg: ctc_loss_pallas(
+        lg, labels, in_lens, label_lens, interpret))(logits))
+    want = np.asarray(jax.jit(lambda lg: ctc_loss(
+        lg, labels, in_lens, label_lens))(logits))
+    err = float(np.max(np.abs(got - want) / np.maximum(np.abs(want), 1.0)))
+    if not err <= CTC_RTOL:
+        fail(f"CTC kernel differs from the jnp loss: {err}")
+    out["ctc_rel_err"] = err
+    return out
+
+
+def make_wavs(wav_dir: str, seed: int):
+    """Two tone-coded utterances (tools/rehearsal.py's synthesiser) and
+    a manifest of them; returns (wav paths, texts, manifest path)."""
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    from rehearsal import RATE, WORDS, synth, write_wav
+
+    rng = np.random.default_rng(seed)
+    os.makedirs(wav_dir, exist_ok=True)
+    wavs, texts = [], []
+    manifest = os.path.join(wav_dir, "wavs.jsonl")
+    with open(manifest, "w") as f:
+        for i, n_words in enumerate((4, 6)):  # ~2 s and ~3.5 s
+            text = " ".join(rng.choice(WORDS, size=n_words))
+            audio = synth(text, rng)
+            path = os.path.join(wav_dir, f"utt{i}.wav")
+            write_wav(path, audio)
+            wavs.append(path)
+            texts.append(text)
+            f.write(json.dumps({"audio": path, "text": text,
+                                "duration": len(audio) / RATE}) + "\n")
+    return wavs, texts, manifest
+
+
+def phase_serve(work: str) -> dict:
+    from deepspeech_tpu import infer, serve
+    from deepspeech_tpu.metrics import cer
+
+    route = kernel_route("ds2_streaming")
+    emit({"resolved": route})
+    ckpt = os.path.join(work, "streaming")
+    trained, _ = train_steps("ds2_streaming", 2, ckpt)
+    wavs, texts, manifest = make_wavs(os.path.join(work, "wavs"), SEED)
+
+    t0 = time.perf_counter()
+    tee = call_main(serve.main, [
+        "--config=ds2_streaming", f"--checkpoint-dir={ckpt}",
+        "--decode=greedy", *wavs])
+    recs = tee.records()
+    chunks = [(t, r) for t, r in recs if "chunk" in r]
+    finals = [r["final"] for _, r in recs if "final" in r]
+    if not chunks or any(len(r["partials"]) != len(wavs)
+                         for _, r in chunks):
+        fail(f"serve: wanted chunk partials for {len(wavs)} streams, "
+             f"got {[r for _, r in chunks][:3]}")
+    if len(finals) != 1 or len(finals[0]) != len(wavs):
+        fail(f"serve: wanted one final per wav, got {finals}")
+
+    # The offline graph over the same audio is the reference for what
+    # the chunked engine streamed.
+    tee = call_main(infer.main, [
+        "--config=ds2_streaming", f"--checkpoint-dir={ckpt}",
+        f"--manifest={manifest}", f"--data.batch_size={len(wavs)}"])
+    offline = {r["ref"]: r["hyp"] for _, r in tee.records()
+               if r.get("event") == "utt"}
+    if sorted(offline) != sorted(texts):
+        fail(f"serve: offline decode covered {sorted(offline)}")
+    stream_cer = cer([offline[t] for t in texts], finals[0])
+    if not stream_cer <= STREAM_CER_MAX:
+        fail(f"serve: streamed finals differ from the offline decode "
+             f"(CER {stream_cer}): {finals[0]} vs "
+             f"{[offline[t] for t in texts]}")
+    return {"train_losses": trained["losses"],
+            "streams": len(wavs), "chunks": len(chunks),
+            # Set-up and compile land in the first chunk's seconds.
+            "first_chunk_s": round(chunks[0][0] - t0, 3),
+            "later_chunk_ms": [r["ms"] for _, r in chunks[1:]],
+            "final_chars": [len(x) for x in finals[0]],
+            "stream_vs_offline_cer": stream_cer}
+
+
+def phase_sync() -> dict:
+    """Is ``block_until_ready`` the sync? Time one jitted call three
+    ways: to dispatch, to block_until_ready, to a host read."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def work(x):
+        for _ in range(64):
+            x = jnp.tanh(x @ x) * 0.5
+        return x
+
+    x = jnp.full((SYNC_N, SYNC_N), 0.01, jnp.bfloat16)
+    float(work(x)[0, 0])  # compile + warm
+    t0 = time.perf_counter()
+    work(x)
+    dispatch = time.perf_counter() - t0
+    float(work(x)[0, 0])  # drain
+    t0 = time.perf_counter()
+    jax.block_until_ready(work(x))
+    blocked = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    float(work(x)[0, 0])
+    host_read = time.perf_counter() - t0
+    return {"dispatch_ms": round(dispatch * 1e3, 3),
+            "block_until_ready_ms": round(blocked * 1e3, 3),
+            "host_read_ms": round(host_read * 1e3, 3)}
+
+
+def phase_multichip(work: str) -> dict:
+    """The same three ds2_full steps on every chip and on one."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    from _aot_common import count_collectives
+
+    route = kernel_route("ds2_full")
+    emit({"resolved": route})
+    many, seen = train_steps("ds2_full", 3, os.path.join(work, "dp"))
+    hlo = seen["step"].lower(*seen["args"]).compile().as_text()
+    collectives = count_collectives(hlo, keep_zero=False)
+    check_kernels(route, hlo)
+    one, seen_one = train_steps("ds2_full", 3, os.path.join(work, "one"),
+                                extra=["--train.mesh_shape=1,1"])
+    n = seen["batch_devices"]
+    facts = {"losses": many["losses"], "losses_one_chip": one["losses"],
+             "loss_rtol": LOSS_RTOL, "batch_devices": n,
+             "batch_devices_one_chip": seen_one["batch_devices"],
+             "collectives": collectives,
+             "bytes_in_use": seen["bytes_in_use"],
+             "first_step_s": many["first_step_s"],
+             "later_step_s": many["later_step_s"]}
+    emit({"multichip": facts})  # before the checks: a failed run shows them
+    if n != 4 or seen_one["batch_devices"] != 1:
+        fail(f"batch spans {n} devices (wanted 4) and "
+             f"{seen_one['batch_devices']} (wanted 1)")
+    if not collectives.get("all-reduce"):
+        fail(f"the compiled step holds no all-reduce: {collectives}")
+    # Parameters and momentum alone are hundreds of MB on every chip.
+    if not all(b and b > 100e6 for b in seen["bytes_in_use"]):
+        fail(f"a device holds next to nothing: {seen['bytes_in_use']}")
+    for a, b in zip(many["losses"], one["losses"]):
+        if abs(a - b) > LOSS_RTOL * max(1.0, abs(b)):
+            fail(f"4-chip and 1-chip losses differ: {many['losses']} "
+                 f"vs {one['losses']}")
+    return {"losses_agree": True}
+
+
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--multichip", action="store_true",
+                    help="four chips: data-parallel ds2_full training "
+                         "against the same steps on one chip, and "
+                         "nothing else")
+    args = ap.parse_args(argv)
+
+    t0 = time.perf_counter()
+    device = check_device(4 if args.multichip else 1)
+    from deepspeech_tpu import native
+    from deepspeech_tpu.utils.cache import resolve_cache_dir
+
+    emit({"phase": "device", **device,
+          "cache_dir": resolve_cache_dir(),
+          "native": ("built" if native.available()
+                     else f"unavailable: {native.build_error()}")})
+    counter = CompileCounter()
+    start = counter.snapshot()
+    work = tempfile.mkdtemp(prefix="chip_smoke_")
+    try:
+        if args.multichip:
+            run_phase("multichip", counter, phase_multichip, work)
+        else:
+            run_phase("train", counter, phase_train, work)
+            run_phase("infer", counter, phase_infer, work)
+            run_phase("reference", counter, phase_reference)
+            run_phase("serve", counter, phase_serve, work)
+            run_phase("sync", counter, phase_sync)
+    finally:
+        shutil.rmtree(work, ignore_errors=True)
+    emit({"phase": "total",
+          "wall_s": round(time.perf_counter() - t0, 2),
+          **counter.since(start)})
+    emit({"ok": True, "device": device})
+
+
+if __name__ == "__main__":
+    main()

@@ -47,11 +47,11 @@ from typing import Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import ModelConfig
 from .layers import BN_EPS, BN_MOMENTUM, length_mask, masked_bn_stats
-from ..utils.compat import shard_map
 from .rnn import gru_scan, lstm_scan
 
 

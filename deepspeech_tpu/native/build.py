@@ -2,8 +2,9 @@
 
 Sources live in ``native/src`` at the repo root; the shared library is
 compiled once into ``native/build/`` with g++ (baked into the image) and
-rebuilt automatically whenever a source file is newer than the binary.
-Concurrent builders (pytest-xdist, multi-process loaders) are serialized
+rebuilt whenever the content of the sources differs from what the
+binary was built from (a hash recorded beside it — a copied tree has
+arbitrary mtimes). Concurrent builders (pytest-xdist, multi-process loaders) are serialized
 with an fcntl lock and an atomic rename, so a half-written .so is never
 loaded.
 """
@@ -12,6 +13,7 @@ from __future__ import annotations
 
 import ctypes
 import fcntl
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -23,6 +25,7 @@ _SRC_DIR = os.path.join(
     "native", "src")
 _BUILD_DIR = os.path.join(os.path.dirname(_SRC_DIR), "build")
 _LIB_PATH = os.path.join(_BUILD_DIR, "libds2native.so")
+_HASH_PATH = _LIB_PATH + ".srchash"
 _ABI_VERSION = 1
 
 _CXXFLAGS = ["-O3", "-std=c++17", "-fPIC", "-shared", "-pthread", "-Wall"]
@@ -40,16 +43,26 @@ def _sources():
         if f.endswith(".cc"))
 
 
+def _source_hash() -> str:
+    """Hash of everything the binary is a function of: the bytes of
+    every ``.cc``/``.h`` under ``native/src`` and the compiler flags."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    for name in sorted(os.listdir(_SRC_DIR)):
+        if name.endswith((".cc", ".h")):
+            with open(os.path.join(_SRC_DIR, name), "rb") as f:
+                h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
+
+
 def _needs_build() -> bool:
     if not os.path.exists(_LIB_PATH):
         return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    deps = _sources() + [
-        os.path.join(_SRC_DIR, f)
-        for f in os.listdir(_SRC_DIR)
-        if f.endswith(".h")
-    ]
-    return any(os.path.getmtime(p) > lib_mtime for p in deps)
+    try:
+        with open(_HASH_PATH) as f:
+            built_from = f.read().strip()
+    except OSError:
+        return True
+    return built_from != _source_hash()
 
 
 def _build() -> None:
@@ -60,6 +73,7 @@ def _build() -> None:
         try:
             if not _needs_build():  # another process built it meanwhile
                 return
+            src_hash = _source_hash()
             fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)
             os.close(fd)
             cmd = ["g++", *_CXXFLAGS, "-I", _SRC_DIR, *_sources(), "-o", tmp]
@@ -70,6 +84,11 @@ def _build() -> None:
                 raise RuntimeError(
                     f"g++ failed ({proc.returncode}):\n{proc.stderr[-4000:]}")
             os.replace(tmp, _LIB_PATH)  # atomic: loaders never see partials
+            # Hash lands after the binary: a crash in between leaves a
+            # stale hash, which only costs a rebuild.
+            with open(_HASH_PATH + ".tmp", "w") as f:
+                f.write(src_hash + "\n")
+            os.replace(_HASH_PATH + ".tmp", _HASH_PATH)
         finally:
             fcntl.flock(lock_f, fcntl.LOCK_UN)
 

@@ -29,9 +29,8 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..utils.compat import shard_map
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"

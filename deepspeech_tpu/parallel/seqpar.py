@@ -43,13 +43,13 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..config import ModelConfig
 from ..models.layers import BN_EPS
 from ..models.rnn import gru_scan, lstm_scan
 from .mesh import DATA_AXIS
-from ..utils.compat import shard_map
 
 # The relay needs every shard's local scan to see the same static
 # shapes; callers pad T to sp_frame_multiple(cfg, n_shards).

@@ -13,7 +13,7 @@ gap against a :class:`~deepspeech_tpu.utils.aotstore.AotStore`:
   (``Inferencer.preloaded_forwards``) BEFORE admission. Every rung is
   counted ``compile_cache_{hit,miss,reject}{rung=...,tier=...,
   replica=...}`` — a *reject* is an entry that exists only under a
-  foreign fingerprint (the ``_platform_salt`` SIGABRT class, downgraded
+  foreign fingerprint (a foreign-host artifact would abort; downgraded
   to a counter) or whose argument signature no longer matches. Misses
   and rejects fall back to jit; preload is never fatal. A ``warm_pct``
   gauge and one ``kind="warm_start"`` postmortem (numeric ``warm_pct``
@@ -42,6 +42,8 @@ import logging
 import os
 import threading
 from typing import Dict, List, Optional, Tuple
+
+import jax
 
 from ..data.infer_bucket import ladder_shapes
 from ..obs import timeline as _timeline
@@ -79,7 +81,7 @@ class WarmStore:
         # Entries the offline tools emitted for THIS platform live
         # under the portable (machine-free) fingerprint — accept them
         # as hits rather than rejecting over the missing machine axis.
-        portable = aotstore.fingerprint_for(aotstore._platform_salt())
+        portable = aotstore.fingerprint_for(jax.default_backend())
         self.store = AotStore(root, fingerprint=fingerprint,
                               fallback_fingerprints=(portable,))
         # Preset key override; '' = each inferencer's own cfg.preset.

@@ -15,14 +15,12 @@ directory::
     <root>/<fp-hash>/<preset>--<tier>--<version>--b{B}xt{T}.wse
     <root>/<fp-hash>/FINGERPRINT          # the full fingerprint string
 
-The fingerprint carries jax/jaxlib/libtpu versions plus the
-``_platform_salt()`` discipline (and, for host-locked formats, the
-machine type): the SIGABRT class documented on
-:func:`~deepspeech_tpu.utils.cache._platform_salt` — CPU AOT artifacts
-loaded on a host with different machine features — turns into a
-counted, non-fatal *reject* here instead of a crash, because a
-mismatched entry lives in a different directory and is never
-deserialized.
+The fingerprint carries jax/jaxlib/libtpu versions plus the platform
+jax runs on (and, for host-locked formats, the machine type): CPU AOT
+artifacts loaded on a host with different machine features abort the
+process, so that class turns into a counted, non-fatal *reject* here
+instead of a crash, because a mismatched entry lives in a different
+directory and is never deserialized.
 
 Entry file format: one JSON meta line, ``\\n``, then the payload::
 
@@ -57,8 +55,6 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
-
-from .cache import _platform_salt
 
 logger = logging.getLogger(__name__)
 
@@ -108,13 +104,15 @@ def _versions() -> Dict[str, str]:
 
 def host_fingerprint() -> str:
     """Fingerprint for host-locked (``"xc"``) entries: jax/jaxlib/
-    libtpu versions, the selected-platform salt, and the machine type
-    (the CPU-feature axis behind the documented SIGABRT class)."""
+    libtpu versions, the platform jax runs on, and the machine type
+    (the CPU-feature axis behind the abort class in the module
+    docstring)."""
     import platform
 
-    v = _versions()
-    return ("jax={jax}|jaxlib={jaxlib}|libtpu={libtpu}".format(**v)
-            + f"|plat={_platform_salt()}|machine={platform.machine()}")
+    import jax
+
+    return (fingerprint_for(jax.default_backend())
+            + f"|machine={platform.machine()}")
 
 
 def fingerprint_for(platform_name: str) -> str:
@@ -270,7 +268,7 @@ class AotStore:
 
         A *reject* means the entry exists under a DIFFERENT fingerprint
         only — the machine/toolchain the executable was built for is
-        not this one (the `_platform_salt` SIGABRT class): the caller
+        not this one (a foreign-host artifact would abort): the caller
         falls back to jit and counts it, and the foreign payload is
         never deserialized."""
         got = self.get(key)

@@ -1,0 +1,110 @@
+"""chip_smoke.py off the chip: it must refuse to pass, and its control
+flow is rehearsed at toy widths with the device check bypassed HERE,
+in the test — the script has no option that does it."""
+
+import dataclasses
+import importlib
+import json
+import os
+import sys
+
+import pytest
+
+from deepspeech_tpu import config
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FAKE = {"platform": "tpu", "kind": "not a chip", "count": 1}
+
+
+@pytest.fixture()
+def smoke(monkeypatch):
+    monkeypatch.syspath_prepend(REPO)
+    return importlib.import_module("chip_smoke")
+
+
+def _json_lines(text):
+    return [json.loads(l) for l in text.splitlines() if l.startswith("{")]
+
+
+def _toy(preset):
+    """The preset with its widths, depth and buckets cut for the CPU."""
+    def build():
+        c = preset()
+        return dataclasses.replace(
+            c,
+            model=dataclasses.replace(c.model, rnn_hidden=32, rnn_layers=2,
+                                      conv_channels=(4, 4)),
+            data=dataclasses.replace(c.data, bucket_frames=(64, 400)))
+    return build
+
+
+def test_cpu_run_fails_at_the_device_phase(smoke, capsys):
+    with pytest.raises(SystemExit) as exit_:
+        smoke.main([])
+    assert exit_.value.code not in (0, None)
+    assert "no TPU" in str(exit_.value.code)
+    out = capsys.readouterr().out
+    assert '"ok": true' not in out and '"phase"' not in out
+
+
+def test_kernel_check_rejects_oracles_and_interpreted_kernels(smoke):
+    """What a CPU resolves to is exactly what the smoke must not
+    accept on a chip."""
+    route = smoke.kernel_route("ds2_full")
+    assert route["rnn_impl"] == "xla" and route["interpret"] is True
+    assert route["rnn_route"] == "blocked"  # H=1760 misses the VMEM budget
+    with pytest.raises(SystemExit):
+        smoke.check_kernels(route, "stablehlo.custom_call @tpu_custom_call")
+    on_chip = dict(route, rnn_impl="pallas", loss_impl="pallas",
+                   interpret=False)
+    with pytest.raises(SystemExit):
+        smoke.check_kernels(on_chip, "no kernel in this step")
+    assert smoke.check_kernels(
+        on_chip, "@tpu_custom_call @tpu_custom_call") == {
+            "tpu_custom_calls": 2}
+
+
+def test_toy_run_takes_every_phase_in_order(smoke, monkeypatch, capsys):
+    for name in ("ds2_full", "ds2_streaming"):
+        monkeypatch.setitem(config.PRESETS, name,
+                            _toy(config.PRESETS[name]))
+    monkeypatch.setattr(smoke, "check_device", lambda want: dict(FAKE))
+    monkeypatch.setattr(smoke, "check_kernels",
+                        lambda route, text: {"tpu_custom_calls": 0})
+    monkeypatch.setattr(smoke, "SYNC_N", 64)
+    pid = os.getpid()
+    smoke.main([])
+    assert os.getpid() == pid
+    out = capsys.readouterr().out
+    recs = _json_lines(out)
+    assert [r["phase"] for r in recs if "phase" in r] == [
+        "device", "train", "infer", "reference", "serve", "sync", "total"]
+    by_phase = {r["phase"]: r for r in recs if "phase" in r}
+    train = by_phase["train"]
+    assert train["steps"] == 4 and len(train["losses"]) == 4
+    assert train["compiles"] > 0 and "first_step_s" in train
+    assert by_phase["infer"]["n_utts"] == 32
+    serve = by_phase["serve"]
+    assert serve["streams"] == 2 and serve["chunks"] >= 2
+    assert serve["stream_vs_offline_cer"] <= smoke.STREAM_CER_MAX
+    # serve.main printed partials per chunk and one final per wav.
+    assert any("chunk" in r and len(r["partials"]) == 2 for r in recs)
+    assert [len(r["final"]) for r in recs if "final" in r] == [2]
+    # The last line is the contract's object and nothing more.
+    last = json.loads(out.strip().splitlines()[-1])
+    assert last == {"ok": True, "device": FAKE}
+    assert sorted(last["device"]) == ["count", "kind", "platform"]
+
+
+def test_a_phase_that_raises_ends_the_run(smoke, monkeypatch, capsys):
+    from deepspeech_tpu import train
+
+    monkeypatch.setattr(smoke, "check_device", lambda want: dict(FAKE))
+
+    def boom(argv):
+        raise SystemExit("train.main gave up")
+
+    monkeypatch.setattr(train, "main", boom)
+    with pytest.raises(SystemExit, match="gave up"):
+        smoke.main([])
+    assert '"ok": true' not in capsys.readouterr().out

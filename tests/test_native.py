@@ -349,3 +349,48 @@ def test_featurize_batch_in_memory(fcfg):
         t = min(ref.shape[0], 60)
         assert frames[i] == t
         assert np.abs(feats[i, :t] - ref[:t]).max() < 2e-3
+
+
+def test_stale_source_hash_rebuilds_despite_newer_mtime(tmp_path,
+                                                        monkeypatch):
+    """Staleness is decided by the CONTENT of native/src (a hash
+    recorded beside the binary), not by mtimes: a copied tree has
+    arbitrary mtimes, and the library loaded must be the one the
+    committed sources build."""
+    from deepspeech_tpu.native import build
+
+    src, out = tmp_path / "src", tmp_path / "build"
+    src.mkdir()
+    out.mkdir()
+    (src / "a.cc").write_text("int one() { return 1; }\n")
+    (src / "a.h").write_text("int one();\n")
+    lib = out / "libds2native.so"
+    monkeypatch.setattr(build, "_SRC_DIR", str(src))
+    monkeypatch.setattr(build, "_BUILD_DIR", str(out))
+    monkeypatch.setattr(build, "_LIB_PATH", str(lib))
+    monkeypatch.setattr(build, "_HASH_PATH", str(lib) + ".srchash")
+    compiles = []
+
+    def fake_gxx(cmd, **kw):
+        compiles.append(cmd)
+        with open(cmd[cmd.index("-o") + 1], "w") as f:
+            f.write(f"binary {len(compiles)}")
+        return build.subprocess.CompletedProcess(cmd, 0, "", "")
+
+    monkeypatch.setattr(build.subprocess, "run", fake_gxx)
+    assert build._needs_build()  # nothing there yet
+    build._build()
+    assert lib.read_text() == "binary 1" and not build._needs_build()
+
+    # Sources touched but unchanged: newer than the binary, not stale.
+    later = os.path.getmtime(lib) + 100
+    os.utime(src / "a.cc", (later, later))
+    assert not build._needs_build()
+
+    # Sources changed, binary made to look newer: stale all the same.
+    (src / "a.h").write_text("int one(); int two();\n")
+    os.utime(lib, (later + 100, later + 100))
+    assert build._needs_build()
+    build._build()
+    assert lib.read_text() == "binary 2" and not build._needs_build()
+    assert len(compiles) == 2

@@ -1,0 +1,91 @@
+"""The main path's Pallas kernels, compiled by Mosaic for a described
+v5e at the presets' real widths — no chip needed, nothing runs.
+
+Interpret-mode tests cannot see what the TPU compiler refuses (a block
+not aligned to the tiling, more scoped VMEM than a kernel may use); a
+compile against ``topologies.get_topology_desc("v5e:2x2")`` can. The
+shapes are the case builders of tools/aot_kernels.py, not copies.
+"""
+
+import os
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "tools"))
+
+from _aot_common import AOT_ENV  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """Sharding onto one chip of a described v5e:2x2, with the
+    persistent compile cache off (an entry written by a compile for a
+    described device cannot be read back here, and warns)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        for key, value in AOT_ENV.items():
+            mp.setenv(key, value)
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+        except Exception as e:  # no libtpu, or one that cannot describe it
+            pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was_on)
+            compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("case", [
+    "gru_h1760",        # ds2_full's cell: blocked weights, forward + VJP
+    "gru_stream_h800",  # ds2_streaming's serve cell: resident, carried h0
+    "bigru_h800",       # fused bidirectional cell (ds2_small's width)
+    "ctc_en",           # CTC loss at the English vocabulary, forward + VJP
+    "gru_q_h1760",      # int8 weights at ds2_full's width
+])
+def test_kernel_compiles_for_v5e(v5e_chip, case):
+    from aot_kernels import compile_case, kernel_cases
+
+    compiled = compile_case(kernel_cases()[case], v5e_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_on_tpu_assume_override(monkeypatch):
+    """DS2N_ASSUME_TPU=1 (tools/aot_tpu.py): 'auto' impls must resolve
+    exactly as on the chip while the runtime backend is cpu, so the
+    AOT lowering emits the Pallas/Mosaic kernels."""
+    from deepspeech_tpu.utils import impl
+
+    monkeypatch.delenv("DS2N_ASSUME_TPU", raising=False)
+    assert impl.on_tpu() is False  # conftest pins the cpu backend
+    assert impl.resolve_impl("auto", oracle="xla") == "xla"
+    assert impl.interpret_default() is True
+    monkeypatch.setenv("DS2N_ASSUME_TPU", "1")
+    assert impl.on_tpu() is True
+    assert impl.resolve_impl("auto", oracle="xla") == "pallas"
+    assert impl.interpret_default() is False
+
+
+def test_aot_topology_constructs(monkeypatch):
+    """The AOT compiler oracle's foundation: a v5e TopologyDescription
+    builds locally from the installed libtpu (no chip attached).
+    tools/aot_tpu.py compiles the real train step against it; here we
+    pin the cheap part — topology + device kind — so a libtpu/jax
+    upgrade that breaks AOT is caught early."""
+    for key, value in AOT_ENV.items():
+        monkeypatch.setenv(key, value)
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    assert len(topo.devices) == 4
+    assert "v5" in str(topo.devices[0].device_kind).lower()

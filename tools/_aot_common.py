@@ -8,15 +8,28 @@ import os
 import re
 import sys
 
+# The tools run as plain scripts (`python tools/aot_*.py`): make the
+# checkout importable without PYTHONPATH.
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
+
 COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
                   "collective-permute", "all-to-all")
 
 
+# What libtpu needs in the environment to describe a topology with no
+# chip attached (tests/test_tpu_compile.py sets the same through
+# monkeypatch).
+AOT_ENV = {"TPU_ACCELERATOR_TYPE": "v5litepod-1",
+           "TPU_WORKER_HOSTNAMES": "localhost",
+           "TPU_SKIP_MDS_QUERY": "1"}
+
+
 def setup_aot_env() -> None:
     """libtpu topology construction needs these before jax import."""
-    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-1")
-    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
-    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    for key, value in AOT_ENV.items():
+        os.environ.setdefault(key, value)
 
 
 def log(tag: str, msg: str) -> None:

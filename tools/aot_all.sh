@@ -1,15 +1,14 @@
 #!/bin/bash
-# One command for the whole offline-TPU-evidence suite (run it at round
-# start while the chip claim is wedged; ~40-60 min on the 1-core host):
+# One command for the whole offline-TPU-evidence suite (no chip needed):
 #   whole-step HBM/collectives (aot_tpu.py, flagship b16/b32 + presets)
-#   routed-kernel battery        (aot_kernels.py, 13 cases)
+#   routed-kernel battery        (aot_kernels.py)
 #   multichip PP/TP/ZeRO + SP    (aot_multichip.py, 8 chips)
 #   composed serving bf16 + int8 (aot_infer.py, s8-verified)
 # Results land in tools/aot_r{N}_*.jsonl-style files named by $1.
 set -u
 REPO="$(cd "$(dirname "$0")/.." && pwd)"
 TAG="${1:-local}"
-ENV=(env -u PYTHONPATH PYTHONPATH="$REPO" JAX_PLATFORMS=cpu)
+ENV=(env JAX_PLATFORMS=cpu TPU_LOG_DIR=disabled)
 cd "$REPO"
 "${ENV[@]}" python tools/aot_tpu.py --preset ds2_full --batch 16 --frames 800 \
   --ndev 1 --rnn-impl pallas --loss-impl pallas > "tools/aot_step_$TAG.jsonl"

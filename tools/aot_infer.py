@@ -5,12 +5,11 @@ inference program — jitted forward (bf16 Pallas kernels, or int8 PTQ
 with the recurrent matrices threaded int8 into the resident q-kernel
 via utils/quantize.keep_recurrent_q) composed with on-device greedy
 decode — lowered and compiled by the real XLA-TPU + Mosaic pipeline.
-This is the `infer --quantize-weights=int8` / `serve` headline path
-whose speed claim is chip-queued (VERDICT r4 weak #2); here its
-COMPILE validity and HBM footprint are proven offline.
+This is the `infer --quantize-weights=int8` / `serve` headline path;
+its speed is not measured here, only its COMPILE validity and HBM
+footprint.
 
-  env -u PYTHONPATH PYTHONPATH=/root/repo JAX_PLATFORMS=cpu \
-    python tools/aot_infer.py            # bf16 + int8 legs
+  JAX_PLATFORMS=cpu python tools/aot_infer.py      # bf16 + int8 legs
 
 One JSON line per leg: {leg, ok, compile_s, hbm_peak_bytes, error?}.
 

@@ -2,16 +2,15 @@
 
 Companion to tools/aot_tpu.py (whole-step oracle): this one answers
 per-kernel questions at exactly the shapes the framework's `auto`
-routing sends to them on hardware — the shapes the judge called
-"unmeasured bets" (VERDICT r4 weak #2/#3). Mosaic compiling a kernel
-at its routed shape is the compiler half of the evidence (the timing
-half still needs the chip); a compile FAILURE here means the routing
-would break on real hardware, which interpret-mode CPU tests can
-never reveal (the b=64 blocked-bwd scoped-VMEM overflow was found
-exactly this way).
+routing sends to them on hardware. Mosaic compiling a kernel at its
+routed shape is the compiler half of the evidence (the timing half
+still needs the chip); a compile FAILURE here means the routing would
+break on real hardware, which interpret-mode CPU tests can never
+reveal (the b=64 blocked-bwd scoped-VMEM overflow was found exactly
+this way). tests/test_tpu_compile.py keeps the main path's cases as
+tests (it reuses :func:`kernel_cases` and :func:`compile_case`).
 
-  env -u PYTHONPATH PYTHONPATH=/root/repo JAX_PLATFORMS=cpu \
-    python tools/aot_kernels.py gru_q_h1760 bigru_h800 ...
+  JAX_PLATFORMS=cpu python tools/aot_kernels.py gru_q_h1760 bigru_h800 ...
 
 Each named case prints one JSON line {case, ok, compile_s, error?}.
 With no args, runs the full routed-shape battery.
@@ -28,14 +27,21 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _aot_common import log, setup_aot_env  # noqa: E402
 
-setup_aot_env()
-# Kernels are only TRACED here; resolve interpret=False (Mosaic).
-os.environ["DS2N_ASSUME_TPU"] = "1"
-
 _log = functools.partial(log, "aot_kernels")
 
 
-def _cases():
+def compile_case(builder, sharding):
+    """Lower + compile one case for the device ``sharding`` names (a
+    described, not attached, TPU). The kernels' ``interpret`` default
+    is False, so the lowering goes through Mosaic."""
+    import jax
+
+    fn, args = builder()
+    return jax.jit(fn, in_shardings=(sharding,) * len(args)) \
+        .lower(*args).compile()
+
+
+def kernel_cases():
     """case name -> (fn_builder, arg ShapeDtypeStructs). Shapes mirror
     the presets' routed configurations (BASELINE.md chip-suite rows):
     streaming H=800, flagship H=1760, lstm H=1536, AISHELL CTC."""
@@ -89,6 +95,22 @@ def _cases():
                 ys, vjp = jax.vjp(step, xp_, m_, w_, bh_)
                 return vjp(jnp.ones_like(ys))
             return train, (xp, m, w, bh)
+        return f
+
+    def gru_stream_case(h, b_=2, k=32):
+        # The serve path's cell (streaming.py _chunk_fn): resident
+        # forward with a carried h0, one 64-frame chunk (32 post-conv
+        # frames) of a two-stream session.
+        hN = 3 * h
+        args = (S((b_, k, hN), jnp.bfloat16), S((b_, k), jnp.float32),
+                S((h, hN), jnp.float32), S((hN,), jnp.float32),
+                S((b_, h), jnp.float32))
+
+        def f():
+            def fwd(xp_, m_, w_, bh_, h0_):
+                return rp.gru_scan_pallas_stream(xp_, m_, w_, bh_, h0_,
+                                                 False, "bfloat16")
+            return fwd, args
         return f
 
     def bigru_case(h):
@@ -173,6 +195,7 @@ def _cases():
 
     cases["gru_h800"] = gru_case(800)
     cases["gru_h1760"] = gru_case(1760)
+    cases["gru_stream_h800"] = gru_stream_case(800)
     cases["lstm_h800"] = lstm_case(800)
     cases["lstm_h1536"] = lstm_case(1536)
     cases["bigru_h800"] = bigru_case(800)
@@ -284,8 +307,7 @@ def _stream_step_bytes(gates, h, weight_bytes):
 
 
 def main() -> None:
-    import numpy as np
-    import jax
+    setup_aot_env()
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -293,12 +315,7 @@ def main() -> None:
     dev = topo.devices[0]
     sh = SingleDeviceSharding(dev)
 
-    def compile_case(builder):
-        fn, args = builder()
-        return jax.jit(fn, in_shardings=(sh,) * len(args)) \
-            .lower(*args).compile()
-
-    cases = _cases()
+    cases = kernel_cases()
     stream_cases = _stream_cases()
     names = sys.argv[1:] or (list(cases) + list(stream_cases))
     for name in names:
@@ -306,8 +323,8 @@ def main() -> None:
             q_builder, fp_builder, gates, h = stream_cases[name]
             t0 = time.time()
             try:
-                q_bytes = _bytes_accessed(compile_case(q_builder))
-                fp_bytes = _bytes_accessed(compile_case(fp_builder))
+                q_bytes = _bytes_accessed(compile_case(q_builder, sh))
+                fp_bytes = _bytes_accessed(compile_case(fp_builder, sh))
                 step_q = _stream_step_bytes(gates, h, 1)
                 step_fp = _stream_step_bytes(gates, h, 4)
                 rec = {"case": name, "ok": True,
@@ -332,7 +349,7 @@ def main() -> None:
             continue
         t0 = time.time()
         try:
-            comp = compile_case(cases[name])
+            comp = compile_case(cases[name], sh)
             ma = comp.memory_analysis()
             rec = {"case": name, "ok": True,
                    "compile_s": round(time.time() - t0, 1),

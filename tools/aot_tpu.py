@@ -1,8 +1,7 @@
 """AOT-compile the training step for a REAL v5e target — no chip needed.
 
-The wedged-claim rounds (BASELINE.md r2-r5) left every TPU question
-unanswerable at runtime; this tool answers the compiler-level half
-offline. jax.experimental.topologies + the installed libtpu build a
+A chip answers timing questions; this tool answers the compiler-level
+half without one. jax.experimental.topologies + the installed libtpu build a
 v5e TopologyDescription locally, and ``jit(...).lower(...).compile()``
 against a mesh of those abstract devices runs the REAL TPU compiler
 (Mosaic included for Pallas kernels when they compile ahead-of-time):
@@ -23,9 +22,8 @@ against a mesh of those abstract devices runs the REAL TPU compiler
 
 Usage (CPU env, real libtpu):
 
-  env -u PYTHONPATH PYTHONPATH=/root/repo JAX_PLATFORMS=cpu \
-    python tools/aot_tpu.py --preset ds2_full --batch 16 --frames 800 \
-      --topology v5e:2x2 --ndev 1 --rnn-impl xla --loss-impl jnp
+  JAX_PLATFORMS=cpu python tools/aot_tpu.py --preset ds2_full \
+      --batch 16 --frames 800 --topology v5e:2x2 --ndev 1
 
 Prints ONE JSON line per invocation (diagnostics on stderr). Notes:
 the smallest constructible v5e topology here is 2x2 (4 chips,

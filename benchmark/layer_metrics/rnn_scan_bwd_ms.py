@@ -1,0 +1,12 @@
+"""Device milliseconds a step and chip in the backward recurrent scan
+kernels, found by name: Mosaic events whose ``kernel_metadata`` names a
+``*_scan_bwd`` kernel (``deepspeech_tpu/ops/kernel_id.py``), events
+wholly inside the window, over chips and completed steps."""
+
+from benchmark.layer_metrics import _kernel_id
+
+DRIVERS = ("train",)
+
+
+def read(record):
+    return _kernel_id.ms_per_step(record, _kernel_id.is_scan_bwd)

@@ -8,6 +8,17 @@ I/O — with a per-record schema shared by every pipeline::
      "dur_ms": <float>, "id": 7, "parent": 3, ...attrs}
     {"event": "compile", "name": "compile", "ts": ..., "dur_ms": 0.0,
      "rung": "4x64", "site": "infer.py:267"}
+    {"event": "span", "name": "jax.lower", "ts": <wall s at its start>,
+     "dur_ms": ..., "id": 9, "parent": 7, "fun": "train_step"}
+
+jax's own compile phases arrive as spans too, from one
+``jax.monitoring`` listener: ``jax.trace`` (the function traced to a
+jaxpr), ``jax.lower`` (jaxpr to StableHLO module) and ``jax.compile``
+(the backend compile request, a persistent-cache read included), each
+with ``fun`` = the function's name and ``parent`` = the span open on the
+calling thread, so a recompile inside ``train.step`` is that span's
+child. jax reports a phase when it ends: ``ts`` is the wall clock then
+minus the duration.
 
 Durations come from a monotonic clock (injectable for tests — wall
 time only stamps ``ts``); nesting is tracked per thread, so gateway
@@ -27,6 +38,7 @@ import os
 import sys
 import threading
 import time
+import weakref
 from typing import Callable, IO, Optional
 
 from .metrics import MetricsRegistry, registry as _default_registry
@@ -48,6 +60,12 @@ class _NoopSpan:
 
 
 _NOOP = _NoopSpan()
+
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
 
 
 class _Span:
@@ -120,6 +138,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._tl = threading.local()
         self._id = 0
+        self._hears_jax = False
 
     # -- configuration --------------------------------------------------
     def configure(self, enabled: bool = True,
@@ -153,7 +172,26 @@ class Tracer:
                 atexit.register(self._close_sink)
             if not enabled:
                 self._close_sink()
+            elif not self._hears_jax:
+                self._listen_to_jax()
             self.enabled = enabled
+
+    def _listen_to_jax(self) -> None:
+        """Once per tracer, on its first enabling: hear jax's duration
+        events for as long as the tracer lives (jax keeps listeners for
+        the life of the process, so the listener holds it weakly)."""
+        import jax.monitoring
+
+        ref = weakref.ref(self)
+
+        def on_duration(event, duration_secs, **kwargs):
+            tr = ref()
+            if tr is not None and tr.enabled and event in _JAX_PHASES:
+                tr._jax_phase(_JAX_PHASES[event], duration_secs,
+                              kwargs.get("fun_name"))
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        self._hears_jax = True
 
     def _close_sink(self) -> None:
         if self._sink is not None and self._owns_sink:
@@ -214,13 +252,21 @@ class Tracer:
         return stack
 
     def _record(self, span: _Span, dur_ms: float) -> None:
-        self._registry.observe("span_ms", dur_ms,
-                               labels={"name": span.name})
-        self._write({"event": "span", "name": span.name,
-                     "ts": round(span.ts, 6),
-                     "dur_ms": round(dur_ms, 6),
-                     "id": span.id, "parent": span.parent,
-                     **span.attrs})
+        self._write_span(span.name, span.ts, dur_ms, span.id, span.parent,
+                         span.attrs)
+
+    def _jax_phase(self, name: str, duration_secs: float, fun) -> None:
+        stack = self._stack()
+        self._write_span(name, self._wall() - duration_secs,
+                         duration_secs * 1e3, self._new_id(),
+                         stack[-1].id if stack else None, {"fun": fun})
+
+    def _write_span(self, name: str, ts: float, dur_ms: float, id_: int,
+                    parent: Optional[int], attrs: dict) -> None:
+        self._registry.observe("span_ms", dur_ms, labels={"name": name})
+        self._write({"event": "span", "name": name, "ts": round(ts, 6),
+                     "dur_ms": round(dur_ms, 6), "id": id_,
+                     "parent": parent, **attrs})
 
     def _write(self, rec: dict) -> None:
         # Interleaving audit (threaded per-replica fan-out): the line

@@ -34,6 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .ctc import NEG, _transition_masks, scatter_ext_to_vocab
+from .kernel_id import kernel_call
 
 _LANE = 128
 _SUBLANE = 8
@@ -180,8 +181,9 @@ def _pallas_ctc_fwd_bwd(lp_ext, skip, valid, input_lens, s_last,
     full = pl.BlockSpec((b, s), lambda t: (0, 0), memory_space=pltpu.VMEM)
     col = pl.BlockSpec((b, 1), lambda t: (0, 0), memory_space=pltpu.VMEM)
 
-    alphas, ll = pl.pallas_call(
-        _fwd_kernel,
+    facts = {"t": t_max, "b": b, "s": s}
+    alphas, ll = kernel_call(
+        _fwd_kernel, kernel="ctc_alpha", facts=facts,
         grid=(t_max,),
         in_specs=[row, full, full, col, col],
         out_specs=[row, col],
@@ -200,8 +202,8 @@ def _pallas_ctc_fwd_bwd(lp_ext, skip, valid, input_lens, s_last,
         (1, b, s), lambda ti: (jnp.minimum(t_max - ti, t_max - 1), 0, 0),
         memory_space=pltpu.VMEM)
 
-    gamma = pl.pallas_call(
-        _bwd_kernel,
+    gamma = kernel_call(
+        _bwd_kernel, kernel="ctc_gamma", facts=facts,
         grid=(t_max,),
         in_specs=[rev_next, full, full, col, col, rev, col],
         out_specs=rev,
@@ -252,8 +254,9 @@ def _pallas_ctc_loss_only(lp_ext, skip, valid, input_lens, s_last,
                        memory_space=pltpu.VMEM)
     full = pl.BlockSpec((b, s), lambda t: (0, 0), memory_space=pltpu.VMEM)
     col = pl.BlockSpec((b, 1), lambda t: (0, 0), memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        _fwd_kernel_loss_only,
+    return kernel_call(
+        _fwd_kernel_loss_only, kernel="ctc_alpha_loss",
+        facts={"t": t_max, "b": b, "s": s},
         grid=(t_max,),
         in_specs=[row, full, full, col, col],
         out_specs=col,

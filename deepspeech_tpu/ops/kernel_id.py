@@ -1,0 +1,65 @@
+"""Kernel identity: every Pallas kernel of ``ops/`` is built here, under
+a name the device trace carries.
+
+``pl.pallas_call(name=..., metadata=...)`` lowers to a Mosaic custom
+call whose instruction is named after the kernel (``%gru_scan_bwd.7``)
+and whose ``frontend_attributes={kernel_metadata={...}}`` holds the
+facts below. A profiler trace names each device event by its
+instruction's text, so a reader finds a kernel, and which call of it,
+by name and not by result shape
+(``benchmark/layer_metrics/_kernel_id.py``). Everything here is
+resolved while jax traces the caller; the kernel's body is unchanged.
+
+One name per kernel ROLE; what tells two builds of a role apart is a
+fact, not a name:
+
+  variant  ``resident`` (whole weight matrix in VMEM), ``blocked``
+           (weight columns streamed over a second grid axis),
+           ``resident_q`` / ``blocked_q`` (the same with int8 weights)
+  reverse  1 if the scan runs from the last frame to the first, else
+           0; ``both`` for the fused bidirectional kernels
+  t, b, h  steps, batch rows and hidden width of the call
+  gates    3 (GRU) or 4 (LSTM)
+  t, b, s  CTC: frames, padded batch rows, padded extended labels
+"""
+
+from __future__ import annotations
+
+from jax.experimental import pallas as pl
+
+KERNELS = frozenset({
+    "gru_scan_fwd",       # training/eval forward, one direction
+    "gru_scan_bwd",       # its BPTT
+    "gru_scan_stream",    # forward with a carried h0 (serving chunks)
+    "gru_scan_q_fwd",     # int8-weight forward (inference)
+    "gru_scan_q_stream",  # int8-weight forward with a carried h0
+    "bigru_scan_fwd",     # both directions in one kernel
+    "bigru_scan_bwd",
+    "lstm_scan_fwd",
+    "lstm_scan_bwd",
+    "lstm_scan_q_fwd",
+    "ctc_alpha",          # alpha recursion, alphas taped for the VJP
+    "ctc_alpha_loss",     # alpha recursion, log-likelihood only
+    "ctc_gamma",          # beta recursion folded into the occupancies
+})
+
+
+def kernel_call(body, *, kernel: str, facts: dict, **pallas_kwargs):
+    """``pl.pallas_call`` under the identity ``kernel`` (one of
+    :data:`KERNELS`) with the static ``facts`` of this build."""
+    if kernel not in KERNELS:
+        raise ValueError(f"{kernel!r} is not in ops.kernel_id.KERNELS")
+    return pl.pallas_call(
+        body, name=kernel,
+        metadata={"kernel": kernel,
+                  **{k: str(v) for k, v in facts.items()}},
+        **pallas_kwargs)
+
+
+def scan_facts(variant: str, reverse, t: int, b: int, h: int,
+               gates: int) -> dict:
+    """The facts every recurrent scan kernel carries."""
+    return {"variant": variant,
+            "reverse": reverse if isinstance(reverse, str)
+            else int(bool(reverse)),
+            "t": t, "b": b, "h": h, "gates": gates}

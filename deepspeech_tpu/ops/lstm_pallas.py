@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .kernel_id import kernel_call, scan_facts
 from .rnn_pallas import (_block_layout, _blocked_q_in_specs,
                          _dot_jnp_dtype, _pad_cols,
                          _resident_in_specs, _resident_q_in_specs,
@@ -235,8 +236,9 @@ def _lstm_pallas_raw(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype,
 
     if not _use_blocked(h, dot, n_gates=4):
         idx, midx = _time_index_maps(t_max, reverse, blocked=False)
-        out = pl.pallas_call(
-            _lstm_kernel,
+        out = kernel_call(
+            _lstm_kernel, kernel="lstm_scan_fwd",
+            facts=scan_facts("resident", reverse, t_max, b, h, 4),
             grid=(t_max,),
             in_specs=_resident_in_specs(b, h, h4, idx, midx),
             out_specs=[
@@ -249,9 +251,11 @@ def _lstm_pallas_raw(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype,
     else:
         n_blocks, c = _block_layout(h4)
         idx, midx = _time_index_maps(t_max, reverse, blocked=True)
-        out = pl.pallas_call(
+        out = kernel_call(
             functools.partial(_lstm_kernel_blocked, h=h, n_blocks=n_blocks,
                               c=c),
+            kernel="lstm_scan_fwd",
+            facts=scan_facts("blocked", reverse, t_max, b, h, 4),
             grid=(t_max, n_blocks),
             in_specs=[
                 pl.BlockSpec((1, b, h4), idx, memory_space=pltpu.VMEM),
@@ -375,9 +379,11 @@ def lstm_scan_pallas_q(xproj: jnp.ndarray, mask: jnp.ndarray,
     if use_blocked:
         n_blocks, c = _block_layout(h4)
         idx, midx = _time_index_maps(t_max, reverse, blocked=True)
-        ys = pl.pallas_call(
+        ys = kernel_call(
             functools.partial(_lstm_kernel_blocked_q, h=h,
                               n_blocks=n_blocks, c=c, dot=dot),
+            kernel="lstm_scan_q_fwd",
+            facts=scan_facts("blocked_q", reverse, t_max, b, h, 4),
             grid=(t_max, n_blocks),
             in_specs=_blocked_q_in_specs(b, h, h4, c, idx, midx),
             out_specs=pl.BlockSpec((1, b, h), idx,
@@ -393,8 +399,10 @@ def lstm_scan_pallas_q(xproj: jnp.ndarray, mask: jnp.ndarray,
           _pad_cols(sc2, n_blocks * c), _pad_cols(bh2, n_blocks * c))
         return jnp.moveaxis(ys, 0, 1)
     idx, midx = _time_index_maps(t_max, reverse, blocked=False)
-    ys = pl.pallas_call(
+    ys = kernel_call(
         functools.partial(_lstm_kernel_q, dot=dot),
+        kernel="lstm_scan_q_fwd",
+        facts=scan_facts("resident_q", reverse, t_max, b, h, 4),
         grid=(t_max,),
         # Shared with gru_scan_pallas_q: specs in OPERAND order
         # (xp, mask, w_q, scale, bias) from one constructor (ADVICE r4).
@@ -440,8 +448,9 @@ def _lstm_bwd(reverse, interpret, dot_dtype, residuals, dy):
     out_shape = [jax.ShapeDtypeStruct((t_max, b, h4), jnp.float32)] * 2
 
     if not blocked:
-        dxp_t, dgates_t = pl.pallas_call(
-            _lstm_bwd_kernel,
+        dxp_t, dgates_t = kernel_call(
+            _lstm_bwd_kernel, kernel="lstm_scan_bwd",
+            facts=scan_facts("resident", reverse, t_max, b, h, 4),
             grid=(t_max,),
             in_specs=[
                 pl.BlockSpec((1, b, h4), bidx, memory_space=pltpu.VMEM),
@@ -461,9 +470,11 @@ def _lstm_bwd(reverse, interpret, dot_dtype, residuals, dy):
         )(xp_t, mask_t, ys, cs, dy_t, w, bh2)
     else:
         n_blocks, c = _block_layout(h4)
-        dxp_t, dgates_t = pl.pallas_call(
+        dxp_t, dgates_t = kernel_call(
             functools.partial(_lstm_bwd_kernel_blocked, h=h,
                               n_blocks=n_blocks, c=c),
+            kernel="lstm_scan_bwd",
+            facts=scan_facts("blocked", reverse, t_max, b, h, 4),
             grid=(t_max, n_blocks),
             in_specs=[
                 pl.BlockSpec((1, b, h4), bidx, memory_space=pltpu.VMEM),

@@ -57,6 +57,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .kernel_id import kernel_call, scan_facts
+
 # Leave headroom for xproj/mask/out rows + double buffering.
 _VMEM_WEIGHT_BUDGET = 10 * 1024 * 1024
 # Streamed weight-block width (lane-aligned); G = ceil(3H / this).
@@ -475,8 +477,9 @@ def _gru_pallas_raw(xproj, mask, w_h, b_h, reverse: bool, interpret: bool,
 
     if not _use_blocked(h, dot):
         idx, midx = _time_index_maps(t_max, reverse, blocked=False)
-        ys = pl.pallas_call(
-            _gru_kernel,
+        ys = kernel_call(
+            _gru_kernel, kernel="gru_scan_fwd",
+            facts=scan_facts("resident", reverse, t_max, b, h, 3),
             grid=(t_max,),
             in_specs=_resident_in_specs(b, h, h3, idx, midx),
             out_specs=pl.BlockSpec((1, b, h), idx, memory_space=pltpu.VMEM),
@@ -488,8 +491,10 @@ def _gru_pallas_raw(xproj, mask, w_h, b_h, reverse: bool, interpret: bool,
 
     n_blocks, c = _block_layout(h3)
     idx, midx = _time_index_maps(t_max, reverse, blocked=True)
-    ys = pl.pallas_call(
+    ys = kernel_call(
         functools.partial(_gru_kernel_blocked, h=h, n_blocks=n_blocks, c=c),
+        kernel="gru_scan_fwd",
+        facts=scan_facts("blocked", reverse, t_max, b, h, 3),
         grid=(t_max, n_blocks),
         in_specs=[
             pl.BlockSpec((1, b, h3), idx, memory_space=pltpu.VMEM),
@@ -544,8 +549,9 @@ def gru_scan_pallas_stream(xproj: jnp.ndarray, mask: jnp.ndarray,
     xp_t, mask_t = _time_major(xproj, mask)
     bh2 = b_h.astype(jnp.float32).reshape(1, h3)
     idx, midx = _time_index_maps(t_max, reverse=False, blocked=False)
-    ys, hfin = pl.pallas_call(
-        _gru_kernel,
+    ys, hfin = kernel_call(
+        _gru_kernel, kernel="gru_scan_stream",
+        facts=scan_facts("resident", False, t_max, b, h, 3),
         grid=(t_max,),
         in_specs=_resident_in_specs(b, h, h3, idx, midx) + [
             pl.BlockSpec((b, h), lambda t: (0, 0),
@@ -655,9 +661,11 @@ def gru_scan_pallas_q(xproj: jnp.ndarray, mask: jnp.ndarray,
     if use_blocked:
         n_blocks, c = _block_layout(h3)
         idx, midx = _time_index_maps(t_max, reverse, blocked=True)
-        ys = pl.pallas_call(
+        ys = kernel_call(
             functools.partial(_gru_kernel_blocked_q, h=h,
                               n_blocks=n_blocks, c=c, dot=dot),
+            kernel="gru_scan_q_fwd",
+            facts=scan_facts("blocked_q", reverse, t_max, b, h, 3),
             grid=(t_max, n_blocks),
             in_specs=_blocked_q_in_specs(b, h, h3, c, idx, midx),
             out_specs=pl.BlockSpec((1, b, h), idx,
@@ -677,8 +685,9 @@ def gru_scan_pallas_q(xproj: jnp.ndarray, mask: jnp.ndarray,
     in_specs = _resident_q_in_specs(b, h, h3, idx, midx)
     kern = functools.partial(_gru_kernel_q, dot=dot)
     if h0 is None:
-        ys = pl.pallas_call(
-            kern,
+        ys = kernel_call(
+            kern, kernel="gru_scan_q_fwd",
+            facts=scan_facts("resident_q", reverse, t_max, b, h, 3),
             grid=(t_max,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, b, h), idx,
@@ -688,8 +697,9 @@ def gru_scan_pallas_q(xproj: jnp.ndarray, mask: jnp.ndarray,
             interpret=interpret,
         )(xp_t, mask_t, w_q, sc2, bh2)
         return jnp.moveaxis(ys, 0, 1)
-    ys, hfin = pl.pallas_call(
-        kern,
+    ys, hfin = kernel_call(
+        kern, kernel="gru_scan_q_stream",
+        facts=scan_facts("resident_q", reverse, t_max, b, h, 3),
         grid=(t_max,),
         in_specs=in_specs + [const((b, h))],
         out_specs=[
@@ -735,8 +745,9 @@ def _bigru_raw(xproj, mask, w_f, b_f, w_b, b_b, interpret, dot_dtype):
     xp_t, mask_t = _time_major(xproj, mask)
     idx, midx = _time_index_maps(t_max, reverse=False, blocked=False)
     ridx, rmidx = _time_index_maps(t_max, reverse=True, blocked=False)
-    ysf, ysb = pl.pallas_call(
-        _bigru_kernel,
+    ysf, ysb = kernel_call(
+        _bigru_kernel, kernel="bigru_scan_fwd",
+        facts=scan_facts("resident", "both", t_max, b, h, 3),
         grid=(t_max,),
         # The shared resident layout, once per direction (the backward
         # direction's maps mirror the time axis).
@@ -785,8 +796,9 @@ def _bigru_bwd(interpret, dot_dtype, residuals, dy):
     const = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0),
                                        memory_space=pltpu.VMEM)
 
-    dxpf, dgf, dxpb, dgb = pl.pallas_call(
-        _bigru_bwd_kernel,
+    dxpf, dgf, dxpb, dgb = kernel_call(
+        _bigru_bwd_kernel, kernel="bigru_scan_bwd",
+        facts=scan_facts("resident", "both", t_max, b, h, 3),
         grid=(t_max,),
         in_specs=[
             pl.BlockSpec((1, b, h3), fi, memory_space=pltpu.VMEM),
@@ -875,8 +887,9 @@ def _gru_bwd(reverse, interpret, dot_dtype, residuals, dy):
     ]
 
     if not blocked:
-        dxp_t, dgates_t = pl.pallas_call(
-            _gru_bwd_kernel,
+        dxp_t, dgates_t = kernel_call(
+            _gru_bwd_kernel, kernel="gru_scan_bwd",
+            facts=scan_facts("resident", reverse, t_max, b, h, 3),
             grid=(t_max,),
             in_specs=[
                 pl.BlockSpec((1, b, h3), bidx, memory_space=pltpu.VMEM),
@@ -895,9 +908,11 @@ def _gru_bwd(reverse, interpret, dot_dtype, residuals, dy):
         )(xp_t, mask_t, ys, dy_t, w, bh2)
     else:
         n_blocks, c = _block_layout(h3)
-        dxp_t, dgates_t = pl.pallas_call(
+        dxp_t, dgates_t = kernel_call(
             functools.partial(_gru_bwd_kernel_blocked, h=h,
                               n_blocks=n_blocks, c=c),
+            kernel="gru_scan_bwd",
+            facts=scan_facts("blocked", reverse, t_max, b, h, 3),
             grid=(t_max, n_blocks),
             in_specs=[
                 pl.BlockSpec((1, b, h3), bidx, memory_space=pltpu.VMEM),

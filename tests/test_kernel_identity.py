@@ -1,0 +1,147 @@
+"""Every Pallas kernel of ``ops/`` carries an identity into the program
+text: ``kernel_metadata`` with a ``kernel`` from the closed vocabulary
+of ``ops/kernel_id.py`` and the facts of the build. Lowering only (no
+Mosaic compile): the attribute is attached when the caller is lowered
+for the TPU platform, which needs no chip and takes well under a
+second a case. The compiled form is checked in test_tpu_compile.py."""
+
+import json
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeech_tpu.ops import ctc_pallas, kernel_id, rnn_pallas
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "tools"))
+
+_ATTR = re.compile(r'kernel_metadata = "((?:[^"\\]|\\.)*)"')
+
+
+def lowered_facts(fn, args) -> list:
+    """The ``kernel_metadata`` of every Mosaic call in ``fn`` lowered
+    for the TPU, in program order. MLIR prints the attribute as a
+    string with ``\\0A`` for a newline and ``\\22`` for a quote."""
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == len(_ATTR.findall(text))
+    return [json.loads(m.replace("\\0A", "\n").replace("\\22", '"'))
+            for m in _ATTR.findall(text)]
+
+
+def scan(kernel, variant, reverse, t, b, h, gates=3):
+    return {"kernel": kernel, "variant": variant, "reverse": str(reverse),
+            "t": str(t), "b": str(b), "h": str(h), "gates": str(gates)}
+
+
+# tools/aot_kernels.kernel_cases() at b=8, t=400: the five that
+# test_tpu_compile.py compiles, then the other routed shapes.
+CASES = {
+    "gru_h1760": [scan("gru_scan_fwd", "blocked", 0, 400, 8, 1760),
+                  scan("gru_scan_bwd", "blocked", 0, 400, 8, 1760)],
+    "gru_stream_h800": [scan("gru_scan_stream", "resident", 0, 32, 2, 800)],
+    "bigru_h800": [scan("bigru_scan_fwd", "resident", "both", 400, 8, 800)],
+    "ctc_en": [{"kernel": "ctc_alpha", "t": "400", "b": "8", "s": "384"},
+               {"kernel": "ctc_gamma", "t": "400", "b": "8", "s": "384"}],
+    "gru_q_h1760": [scan("gru_scan_q_fwd", "resident_q", 0, 400, 8, 1760)],
+    "gru_h800": [scan("gru_scan_fwd", "resident", 0, 400, 8, 800),
+                 scan("gru_scan_bwd", "resident", 0, 400, 8, 800)],
+    "lstm_h800": [scan("lstm_scan_fwd", "resident", 0, 400, 8, 800, 4),
+                  scan("lstm_scan_bwd", "resident", 0, 400, 8, 800, 4)],
+    "lstm_h1536": [scan("lstm_scan_fwd", "blocked", 0, 400, 8, 1536, 4),
+                   scan("lstm_scan_bwd", "blocked", 0, 400, 8, 1536, 4)],
+    "lstm_q_h800": [scan("lstm_scan_q_fwd", "resident_q", 0, 400, 8, 800,
+                         4)],
+    "gru_q_blocked_h1760": [scan("gru_scan_q_fwd", "blocked_q", 0, 400, 8,
+                                 1760)],
+    "lstm_q_blocked_h1760": [scan("lstm_scan_q_fwd", "blocked_q", 0, 400,
+                                  8, 1760, 4)],
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_lowered_kernel_carries_its_identity(case):
+    from aot_kernels import kernel_cases
+
+    fn, args = kernel_cases()[case]()
+    got = lowered_facts(fn, args)
+    assert got == CASES[case]
+    assert all(f["kernel"] in kernel_id.KERNELS for f in got)
+
+
+S = jax.ShapeDtypeStruct
+_T, _B, _H = 16, 8, 128
+
+
+def _gru_args(n_w=1):
+    return ((S((_B, _T, 3 * _H), jnp.float32), S((_B, _T), jnp.float32))
+            + (S((_H, 3 * _H), jnp.float32), S((3 * _H,), jnp.float32))
+            * n_w)
+
+
+def test_reverse_scan_says_so_forward_and_backward():
+    def train(xp, m, w, bh):
+        ys, vjp = jax.vjp(lambda *a: rnn_pallas.gru_scan_pallas(
+            *a, True, False, None), xp, m, w, bh)
+        return vjp(ys)
+
+    assert lowered_facts(train, _gru_args()) == [
+        scan("gru_scan_fwd", "resident", 1, _T, _B, _H),
+        scan("gru_scan_bwd", "resident", 1, _T, _B, _H)]
+
+
+def test_the_roles_no_routed_case_reaches():
+    def bigru_train(xp, m, wf, bf, wb, bb):
+        ys, vjp = jax.vjp(lambda *a: rnn_pallas.bigru_scan_pallas(
+            *a, False, None), xp, m, wf, bf, wb, bb)
+        return vjp(ys)
+
+    assert [f["kernel"] for f in lowered_facts(
+        bigru_train, _gru_args(2))] == ["bigru_scan_fwd", "bigru_scan_bwd"]
+
+    def q_stream(xp, m, wq, sc, bh, h0):
+        return rnn_pallas.gru_scan_pallas_q(xp, m, wq, sc, bh, h0=h0)
+
+    xp, m = _gru_args()[:2]
+    col = S((3 * _H,), jnp.float32)
+    assert lowered_facts(q_stream, (
+        xp, m, S((_H, 3 * _H), jnp.int8), col, col,
+        S((_B, _H), jnp.float32))) == [
+        scan("gru_scan_q_stream", "resident_q", 0, _T, _B, _H)]
+
+    def ctc_eval(lg, lab, il, ll):
+        return ctc_pallas.ctc_loss_pallas(lg, lab, il, ll)
+
+    lens = S((4,), jnp.int32)
+    assert lowered_facts(ctc_eval, (
+        S((4, 40, 29), jnp.float32), S((4, 10), jnp.int32), lens,
+        lens)) == [{"kernel": "ctc_alpha_loss", "t": "40", "b": "8",
+                    "s": "128"}]
+
+
+def test_every_name_of_the_vocabulary_is_built_somewhere():
+    """The vocabulary is closed both ways: a name nobody builds is a
+    reader's dead branch."""
+    used = set()
+    for name in ("rnn_pallas.py", "lstm_pallas.py", "ctc_pallas.py"):
+        with open(os.path.join(REPO, "deepspeech_tpu", "ops", name)) as f:
+            used.update(re.findall(r'kernel="(\w+)"', f.read()))
+    assert used == kernel_id.KERNELS
+
+
+def test_a_name_outside_the_vocabulary_is_refused():
+    with pytest.raises(ValueError, match="KERNELS"):
+        kernel_id.kernel_call(lambda *refs: None, kernel="gru_scan",
+                              facts={}, out_shape=S((8, 128), jnp.float32))
+
+
+def test_no_pallas_call_outside_the_helper():
+    ops = os.path.join(REPO, "deepspeech_tpu", "ops")
+    for name in sorted(os.listdir(ops)):
+        if name.endswith(".py") and name != "kernel_id.py":
+            with open(os.path.join(ops, name)) as f:
+                assert "pallas_call(" not in f.read(), name

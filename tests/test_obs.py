@@ -271,6 +271,93 @@ def test_shape_cache_compile_events_attributed():
     assert all("test_obs.py" in r["site"] for r in recs)
 
 
+# -- jax's compile phases as spans ---------------------------------------
+
+def test_jax_compile_phases_are_spans_under_the_open_span():
+    import jax
+    import jax.numpy as jnp
+
+    reg = MetricsRegistry()
+    tr = Tracer(registry=reg)
+    sink = io.StringIO()
+    tr.configure(enabled=True, sink=sink)
+
+    def never_jitted_before(x):
+        return x * 3 + 1
+
+    with tr.span("train.step"):
+        jax.jit(never_jitted_before)(jnp.ones(4)).block_until_ready()
+    tr.configure(enabled=False)
+    jax.jit(lambda x: x - 2)(jnp.ones(5))  # off: nothing more is written
+    recs = [json.loads(l) for l in sink.getvalue().splitlines()]
+    step = recs[-1]
+    assert step["name"] == "train.step"
+    mine = {r["name"]: r for r in recs
+            if "never_jitted_before" in str(r.get("fun"))}
+    assert set(mine) == {"jax.trace", "jax.lower", "jax.compile"}
+    for r in mine.values():
+        assert r["event"] == "span" and r["parent"] == step["id"]
+        assert r["dur_ms"] > 0
+        # Reported at its end, stamped at its start, inside the parent.
+        assert step["ts"] <= r["ts"]
+        assert r["ts"] + r["dur_ms"] / 1e3 \
+            <= step["ts"] + step["dur_ms"] / 1e3 + 1e-3
+    assert mine["jax.trace"]["ts"] <= mine["jax.lower"]["ts"] \
+        <= mine["jax.compile"]["ts"]
+    assert all(r["name"].startswith("jax.") for r in recs[:-1])
+    assert reg.snapshot()["histograms"][
+        'span_ms{name="jax.compile"}']["count"] >= 1
+
+
+def test_jax_phase_listener_is_silent_and_free_when_off(monkeypatch):
+    import tracemalloc
+
+    import jax.monitoring
+
+    from deepspeech_tpu.obs import trace as trace_mod
+
+    listeners = []
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        listeners.append)
+    tr = Tracer(registry=MetricsRegistry())
+    assert not listeners  # lazily: a tracer never enabled hears nothing
+    sink = io.StringIO()
+    tr.configure(enabled=True, sink=sink)
+    tr.configure(enabled=False)
+    tr.configure(enabled=True, sink=sink)   # registers once, not twice
+    on_duration, = listeners
+    event = "/jax/core/compile/backend_compile_duration"
+    on_duration(event, 0.25, fun_name="f")
+    on_duration("/jax/some/other_duration", 0.25)
+    tr.configure(enabled=False, sink=sink)
+    kwargs = {"fun_name": "f"}
+    on_duration(event, 0.25, **kwargs)  # warm: specialise the bytecode
+    tracemalloc.start()
+    try:
+        a = tracemalloc.take_snapshot()
+        for _ in range(200):
+            on_duration(event, 0.25, **kwargs)
+        b = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    only = [tracemalloc.Filter(True, trace_mod.__file__)]
+    grown = sum(s.size_diff for s in b.filter_traces(only).compare_to(
+        a.filter_traces(only), "lineno"))
+    assert grown == 0
+    recs = [json.loads(l) for l in sink.getvalue().splitlines()]
+    assert [(r["name"], r["fun"], r["dur_ms"], r["parent"])
+            for r in recs] == [("jax.compile", "f", 250.0, None)]
+    # The listener does not keep a dropped tracer alive.
+    import gc
+    import weakref
+    ref = weakref.ref(tr)
+    del tr
+    gc.collect()
+    assert ref() is None
+    on_duration(event, 0.25, fun_name="f")
+
+
 # -- trace report ---------------------------------------------------------
 
 def test_trace_report_on_synthetic_trace(tmp_path):

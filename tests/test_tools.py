@@ -1,41 +1,11 @@
-"""Tool smoke tests: trace summarizer on a synthetic Chrome trace."""
+"""Tool smoke tests."""
 
-import gzip
 import json
 import os
 import subprocess
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_profile_summary_on_synthetic_trace(tmp_path):
-    trace = {"traceEvents": [
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "TPU"}},
-        {"ph": "M", "name": "thread_name", "pid": 1, "tid": 2,
-         "args": {"name": "XLA Ops"}},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "fusion.1",
-         "ts": 0, "dur": 3000},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "fusion.1",
-         "ts": 4000, "dur": 1000},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "dot.7",
-         "ts": 6000, "dur": 6000},
-        {"ph": "B", "pid": 1, "tid": 2, "name": "ignored-open-span",
-         "ts": 0},
-    ]}
-    d = tmp_path / "plugins" / "profile"
-    d.mkdir(parents=True)
-    with gzip.open(d / "host.trace.json.gz", "wt") as f:
-        json.dump(trace, f)
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "profile_summary.py"),
-         str(tmp_path)], capture_output=True, text=True, timeout=120)
-    assert out.returncode == 0, out.stderr
-    # dot.7 dominates (6ms of 10ms = 60%), fusion.1 counted twice.
-    assert "dot.7" in out.stdout and "60.0%" in out.stdout
-    assert "x2" in out.stdout
-    assert "TPU / XLA Ops" in out.stdout
 
 
 def test_estimate_arpa_order3_parses_and_scores():

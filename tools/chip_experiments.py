@@ -436,8 +436,7 @@ def suite_beam() -> None:
                  "decode_ms_per_batch": t_run * 1e3,
                  "utt_per_sec": b / t_run})
             # Where do the milliseconds go (VERDICT r2 #7): one trace
-            # per impl at the headline prune level, for
-            # tools/profile_summary.py.
+            # per impl at the headline prune level.
             prof = os.environ.get("CHIP_PROFILE_DIR")
             if prof and k == 20:
                 try:

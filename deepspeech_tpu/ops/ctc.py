@@ -176,19 +176,19 @@ def backward_betas(log_probs: jnp.ndarray, labels: jnp.ndarray,
 
 def scatter_ext_to_vocab(vals: jnp.ndarray, ext: jnp.ndarray,
                          vocab: int) -> jnp.ndarray:
-    """Scatter-add extended-label values into vocab bins.
+    """Sum extended-label values into vocab bins.
 
-    vals [B, T, S], ext [B, S] -> [B, T, V]. Shared by the alpha/beta
+    vals [B, T, S], ext [B, S] -> [B, T, V] (f32):
+    ``out[b, t, v] = sum of vals[b, t, s] over the s with ext[b, s] == v``.
+    One batched contraction with the one-hot of ``ext`` (a TPU runs a
+    scatter-add one update after another). ``HIGHEST`` keeps it exact
+    in f32: the one-hot is exact in bf16, so only the order of the f32
+    additions differs from a scatter-add. Shared by the alpha/beta
     gradient here and the Pallas kernel wrapper (ops/ctc_pallas.py).
     """
-    b, t_max, _ = vals.shape
-
-    def one(v_b, ext_b):  # [T, S], [S] -> [T, V]
-        t_idx = jnp.broadcast_to(jnp.arange(t_max)[:, None], v_b.shape)
-        v_idx = jnp.broadcast_to(ext_b[None, :], v_b.shape)
-        return jnp.zeros((t_max, vocab), jnp.float32).at[t_idx, v_idx].add(v_b)
-
-    return jax.vmap(one)(vals, ext)
+    one_hot = (ext[:, :, None] == jnp.arange(vocab)).astype(jnp.float32)
+    return jnp.einsum("bts,bsv->btv", vals, one_hot,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 # Back-compat re-export: the interpreter-mode default historically
@@ -229,7 +229,7 @@ def ctc_grad(logits: jnp.ndarray, labels: jnp.ndarray,
     # occupancy[t,b,s] = P(path passes s at t | labels), in log space.
     log_occ = alphas + betas - loglik[None, :, None]
 
-    # gamma[b,t,v] = scatter-add occupancy into vocab bins by ext[s].
+    # gamma[b,t,v] = occupancy summed into vocab bins by ext[s].
     occ = jnp.exp(jnp.minimum(log_occ, 0.0))  # clip tiny numeric overshoot
     occ = jnp.moveaxis(occ, 1, 0)  # [B, T, S]
     gamma = scatter_ext_to_vocab(occ, ext, v)  # [B, T, V]

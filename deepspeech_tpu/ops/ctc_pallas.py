@@ -16,7 +16,7 @@ Layout (time-major, batched bands):
 - backward kernel: reversed sequential grid over T; carries
   ``beta[B, S]``, reads the stored alphas, and emits the occupancy
   ``gamma_ext[T, B, S] = exp(alpha + beta - loglik)``.
-- jnp wrapper: scatter-adds gamma_ext into vocab bins and forms
+- jnp wrapper: sums gamma_ext into vocab bins (one-hot contraction) and forms
   ``dlogits = softmax - gamma`` (the closed-form CTC gradient).
 
 Banded transitions (stay / step / skip) are lane-shifts: ``pltpu.roll``
@@ -240,7 +240,7 @@ def _prepare(logits, labels, input_lens, label_lens):
 
 
 def _scatter_gamma(gamma_ext, ext, b, t_max, v):
-    """gamma_ext [T, B, S] + ext [B, S] -> gamma [B, T, V] scatter-add."""
+    """gamma_ext [T, B, S] + ext [B, S] -> gamma [B, T, V] per vocab bin."""
     return scatter_ext_to_vocab(jnp.moveaxis(gamma_ext, 1, 0), ext, v)
 
 

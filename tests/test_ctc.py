@@ -1,14 +1,18 @@
 """CTC loss tests (SURVEY.md §4.1): hand-computed cases, the optax
 oracle, finite differences, and alpha/beta-vs-autodiff agreement."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
 
-from deepspeech_tpu.ops.ctc import (ctc_grad, ctc_loss, ctc_loss_ref,
-                                    forward_alphas)
+from deepspeech_tpu.ops import ctc as ctc_ops
+from deepspeech_tpu.ops.ctc import (_transition_masks, ctc_grad, ctc_loss,
+                                    ctc_loss_ref, forward_alphas,
+                                    scatter_ext_to_vocab)
 
 
 def _rand_case(rng, b, t, v, lmax):
@@ -145,3 +149,113 @@ def test_ctc_jit_and_vmap_compatible():
     l1 = jitted(logits, labels, input_lens, label_lens)
     l2 = ctc_loss(logits, labels, input_lens, label_lens)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), rtol=1e-6)
+
+
+def _scatter_add_form(vals, ext, vocab):
+    """The retired form of ``scatter_ext_to_vocab`` (one ``.at[].add``
+    per utterance), kept here as the reference only."""
+    t_max = vals.shape[1]
+
+    def one(v_b, ext_b):  # [T, S], [S] -> [T, V]
+        t_idx = jnp.broadcast_to(jnp.arange(t_max)[:, None], v_b.shape)
+        v_idx = jnp.broadcast_to(ext_b[None, :], v_b.shape)
+        return jnp.zeros((t_max, vocab), jnp.float32).at[t_idx, v_idx].add(v_b)
+
+    return jax.vmap(one)(vals, ext)
+
+
+def _labels_case(case, vocab, lmax=8):
+    """(labels [B, lmax], label_lens [B]) for one named case."""
+    rng = np.random.default_rng(40)
+    if case == "repeated":      # "aaa": one bin hit from several s
+        labels = np.full((3, lmax), [[1], [vocab - 1], [vocab // 2]])
+        lens = np.array([lmax, 3, 5])
+    elif case == "all_blank":   # no label: every position is the blank
+        labels = np.zeros((2, lmax), np.int64)
+        lens = np.array([0, 0])
+    elif case == "padded_slots":  # ext past 2*len+1 still holds values
+        labels = rng.integers(1, vocab, size=(4, lmax))
+        lens = np.array([0, 1, lmax // 2, lmax])
+    else:                        # "random": distinct and repeated mixed
+        labels = rng.integers(1, vocab, size=(4, lmax))
+        lens = np.full(4, lmax)
+    labels = labels * (np.arange(lmax)[None, :] < lens[:, None])
+    return jnp.asarray(labels, jnp.int32), jnp.asarray(lens, jnp.int32)
+
+
+@pytest.mark.parametrize("vocab", [29, 4336])
+@pytest.mark.parametrize(
+    "case", ["repeated", "all_blank", "padded_slots", "random"])
+def test_scatter_ext_to_vocab_matches_add_at(case, vocab):
+    """out[b, t, v] = sum of vals[b, t, s] over ext[b, s] == v, against
+    numpy's unbuffered add on the same indices."""
+    labels, label_lens = _labels_case(case, vocab)
+    ext, _, _ = _transition_masks(labels, label_lens)
+    b, s = ext.shape
+    t = 6
+    vals = np.random.default_rng(41).uniform(
+        0.0, 1.0, size=(b, t, s)).astype(np.float32)
+
+    got = scatter_ext_to_vocab(jnp.asarray(vals), ext, vocab)
+    assert got.shape == (b, t, vocab) and got.dtype == jnp.float32
+
+    want = np.zeros((b, t, vocab), np.float64)
+    bi, ti, _ = np.indices(vals.shape)
+    np.add.at(want, (bi, ti, np.asarray(ext)[:, None, :]), vals)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=0)
+    np.testing.assert_allclose(
+        np.asarray(_scatter_add_form(jnp.asarray(vals), ext, vocab)),
+        want, rtol=1e-6, atol=0)
+
+
+def _three_steps(loss_impl):
+    """(losses, gradient norms) of the first three steps of a small
+    preset from a fixed seed."""
+    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.data import CharTokenizer
+    from deepspeech_tpu.parallel import shard_batch
+    from deepspeech_tpu.train import Trainer, _SyntheticPipeline
+    from deepspeech_tpu.utils.logging import JsonlLogger
+
+    cfg = get_config("dev_slice")
+    cfg = dataclasses.replace(
+        cfg,
+        model=dataclasses.replace(cfg.model, rnn_hidden=16, rnn_layers=1,
+                                  conv_channels=(4, 4), dtype="float32"),
+        data=dataclasses.replace(cfg.data, batch_size=8,
+                                 bucket_frames=(64,), max_label_len=16),
+        train=dataclasses.replace(cfg.train, checkpoint_dir="",
+                                  loss_impl=loss_impl, learning_rate=3e-3,
+                                  warmup_steps=10, log_every=100))
+    pipe = _SyntheticPipeline(cfg, n_utts=8, frames=64, label_len=4)
+    trainer = Trainer(cfg, pipe, CharTokenizer.english(),
+                      logger=JsonlLogger(echo=False))
+    batch = shard_batch(trainer.mesh, next(iter(pipe.epoch(0))))
+    state, out = trainer.state, []
+    for _ in range(3):
+        state, m = trainer.train_step(state, batch)
+        out.append((float(m["loss"]), float(m["grad_norm"])))
+    return np.asarray(out)
+
+
+@pytest.mark.parametrize("loss_impl", ["jnp", "pallas"])
+def test_training_equals_scatter_add_form(monkeypatch, loss_impl):
+    """The contraction changes only the order of f32 additions: three
+    training steps give the losses and gradient norms that the retired
+    scatter-add gives (``pallas`` runs interpreted here)."""
+    from deepspeech_tpu.ops import ctc_pallas
+
+    got = _three_steps(loss_impl)
+
+    calls = []
+
+    def retired(vals, ext, vocab):
+        calls.append(vals.shape)
+        return _scatter_add_form(vals, ext, vocab)
+
+    monkeypatch.setattr(ctc_ops, "scatter_ext_to_vocab", retired)
+    monkeypatch.setattr(ctc_pallas, "scatter_ext_to_vocab", retired)
+    want = _three_steps(loss_impl)
+    assert calls, "the reference run did not go through the retired form"
+    assert np.isfinite(want).all()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=0)

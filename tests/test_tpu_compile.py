@@ -8,6 +8,7 @@ shapes are the case builders of tools/aot_kernels.py, not copies.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -69,6 +70,27 @@ def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     calls = text.split('custom_call_target="tpu_custom_call"')[1:]
     assert sorted(kernel_facts(c)["kernel"] for c in calls) \
         == sorted(kernels)
+
+
+def test_ctc_vjp_folds_gamma_without_a_scatter(v5e_chip):
+    """CTC backward sums gamma from the extended labels into the
+    vocabulary with one f32-exact contraction. A TPU runs a scatter-add
+    one update after another (98.8 ms of ds2_full's step, PERF.md
+    PR 25), so none may come back: the only scatter left is the
+    interleaving of the integer labels with blanks."""
+    from aot_kernels import compile_case, kernel_cases
+    from benchmark.layer_metrics._kernel_id import kernel_facts
+
+    text = compile_case(kernel_cases()["ctc_en"], v5e_chip).as_text()
+    scattered = re.findall(r"= \(?(\w+)\[[^\]]*\]\S* scatter\(", text)
+    assert set(scattered) <= {"s32"}, scattered
+    folds = [line for line in text.splitlines()
+             if re.search(r"= f32\[4,400,29\]\S* (convolution|dot)\(", line)]
+    assert len(folds) == 1, folds
+    assert "operand_precision={highest,highest}" in folds[0]
+    calls = text.split('custom_call_target="tpu_custom_call"')[1:]
+    assert sorted(kernel_facts(c)["kernel"] for c in calls) \
+        == ["ctc_alpha", "ctc_gamma"]
 
 
 def test_on_tpu_assume_override(monkeypatch):

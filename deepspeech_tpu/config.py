@@ -42,7 +42,15 @@ class ModelConfig:
     # RNN stack.
     rnn_layers: int = 3
     rnn_hidden: int = 800
-    rnn_type: str = "gru"  # "gru" | "lstm"
+    # "gru" | "lstm" | "lstmp" (LSTM with a recurrent projection,
+    # Sak et al. arXiv:1402.1128: the carried/output state is the
+    # ``rnn_proj``-wide projection of the cell output; unidirectional).
+    rnn_type: str = "gru"
+    # lstmp only: width of the recurrent projection, and layer
+    # normalisation of the four gate pre-activations (learned gain and
+    # bias per gate, none on the cell state).
+    rnn_proj: int = 0
+    rnn_layer_norm: bool = False
     bidirectional: bool = True
     # Streaming variant: unidirectional + lookahead conv over future frames.
     lookahead_context: int = 0  # 0 disables lookahead conv
@@ -81,14 +89,28 @@ class ModelConfig:
     # stages-1), so more microbatches = better stage utilization.
     # batch_size must divide by it (strided split, train.py accum-style).
     pipeline_microbatches: int = 0
-    # RNN-T family (train.objective="rnnt"): prediction-net GRU width
-    # and joint projection dim (models/transducer.py).
+    # RNN-T family (train.objective="rnnt"): prediction-net width and
+    # joint projection dim (models/transducer.py). The prediction net
+    # is a GRU for rnn_type gru/lstm and ``rnnt_pred_layers`` LSTM
+    # layers with the ``rnn_proj`` projection for rnn_type lstmp.
     rnnt_pred_hidden: int = 128
     rnnt_joint_dim: int = 256
+    rnnt_pred_layers: int = 1
+    rnnt_pred_embed: int = 64
+    # The lstmp encoder (He et al. arXiv:1811.06621) has no conv
+    # frontend: ``frame_stack`` adjacent feature frames are concatenated
+    # into one input frame, and after encoder layer
+    # ``time_reduction_layer`` (0 = never) every ``time_reduction``
+    # adjacent outputs are concatenated into one frame.
+    frame_stack: int = 1
+    time_reduction_layer: int = 0
+    time_reduction: int = 2
 
     @property
     def time_stride(self) -> int:
-        s = 1
+        s = self.frame_stack
+        if self.time_reduction_layer > 0:
+            s *= self.time_reduction
         for (_, _, ts, _) in self.conv_layers:
             s *= ts
         return s
@@ -178,9 +200,10 @@ class TrainConfig:
     # oracle ~1.7x fwd / ~1.9x grad at EN and AISHELL shapes.
     loss_impl: str = "auto"
     # Training objective / model family: "ctc" (the DS2 stack) or
-    # "rnnt" (EXPERIMENTAL transducer: models/transducer.RNNTModel +
-    # ops/transducer.transducer_loss; greedy transducer eval, single
-    # process, no sequence_parallel/pipeline).
+    # "rnnt" (transducer: models/transducer.RNNTModel trained through
+    # ops/transducer.rnnt_joint_loss, which never holds the
+    # [B,T',U+1,V] lattice; greedy transducer eval, single process, no
+    # sequence_parallel/pipeline).
     objective: str = "ctc"
     # Sequence-parallel training (parallel/seqpar.sp_loss): the TIME
     # axis of each batch shards over the mesh's data axis — conv halos
@@ -370,6 +393,33 @@ def dev_slice() -> Config:
     )
 
 
+def rnnt_he2019() -> Config:
+    """The streaming RNN-T of He et al. 2019 (arXiv:1811.06621, model
+    section) at its published widths: 8 unidirectional LSTM-2048 layers
+    with a 640 projection and layer normalisation, time reduction 2
+    after layer 2, a 2 x LSTM-2048/640 prediction net over a 128-wide
+    embedding, joint 640, 4096 word-pieces (blank among them); about
+    122 M parameters. The front end is this repo's 161-bin
+    log-spectrogram at 10 ms, three frames stacked to 483 inputs at
+    30 ms (the paper's is log-mel); ``benchmark/configs/
+    rnnt_he2019.json`` lists every such reading."""
+    c = Config(name="rnnt_he2019")
+    return _replace(
+        c,
+        model=_replace(
+            c.model, conv_layers=(), conv_channels=(),
+            rnn_type="lstmp", rnn_layers=8, rnn_hidden=2048,
+            rnn_proj=640, rnn_layer_norm=True, bidirectional=False,
+            rnn_batch_norm=False, frame_stack=3,
+            time_reduction_layer=2, time_reduction=2,
+            rnnt_pred_layers=2, rnnt_pred_hidden=2048,
+            rnnt_pred_embed=128, rnnt_joint_dim=640, vocab_size=4096),
+        data=_replace(c.data, batch_size=64, max_label_len=64),
+        train=_replace(c.train, objective="rnnt"),
+        decode=_replace(c.decode, mode="rnnt_greedy"),
+    )
+
+
 PRESETS = {
     "ds2_small": ds2_small,
     "ds2_full": ds2_full,
@@ -377,6 +427,7 @@ PRESETS = {
     "ds2_beam_lm": ds2_beam_lm,
     "aishell": aishell,
     "dev_slice": dev_slice,
+    "rnnt_he2019": rnnt_he2019,
 }
 
 

@@ -181,6 +181,185 @@ def lstm_scan(xproj: jnp.ndarray, mask: jnp.ndarray, w_h: jnp.ndarray,
     return ys
 
 
+LN_EPS = 1e-5
+
+
+def gate_layer_norm(a: jnp.ndarray, scale: jnp.ndarray,
+                    bias: jnp.ndarray) -> jnp.ndarray:
+    """Layer normalisation of each of the four gate pre-activations
+    on its own: a [B, 4H] -> [B, 4H], statistics over the H units of
+    one gate, learned gain and bias [4H]."""
+    b, h4 = a.shape
+    g = a.reshape(b, 4, h4 // 4)
+    mu = jnp.mean(g, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(g - mu), axis=-1, keepdims=True)
+    g = (g - mu) * jax.lax.rsqrt(var + LN_EPS)
+    return g.reshape(b, h4) * scale + bias
+
+
+def lstmp_scan(xproj: jnp.ndarray, mask: jnp.ndarray, w_r: jnp.ndarray,
+               w_p: jnp.ndarray, ln_scale: jnp.ndarray | None = None,
+               ln_bias: jnp.ndarray | None = None,
+               dot_dtype: jnp.dtype | None = None,
+               remat_chunk: int = 0,
+               cr0: Tuple[jnp.ndarray, jnp.ndarray] | None = None,
+               return_final: bool = False):
+    """LSTM with a recurrent projection (Sak et al., arXiv:1402.1128,
+    no peepholes) and, optionally, layer normalisation of the gates.
+
+      a = xproj_t + r_{t-1} W_r          [B, 4H] (i, f, g, o; xproj
+                                         includes the input bias)
+      i, f, g, o = LN_k(a_k)             per gate, if ln_scale is given
+      c = sig(f + 1) c + sig(i) tanh(g)  (+1: the forget-gate bias of
+                                         ``lstm_scan``)
+      m = sig(o) tanh(c);  r = m W_p     [B, P]
+
+    xproj [B, T, 4H], mask [B, T] (masked frames carry (c, r)
+    through), w_r [P, 4H], w_p [H, P]. Returns r [B, T, P] float32, or
+    ``(r, (c_final, r_final))`` with ``return_final``; ``cr0`` is the
+    carried (c [B, H], r [B, P]) of a one-step decode.
+    """
+    b, t, h4 = xproj.shape
+    h, p = h4 // 4, w_p.shape[1]
+    if dot_dtype is not None:
+        w_r, w_p = w_r.astype(dot_dtype), w_p.astype(dot_dtype)
+    xs = (jnp.moveaxis(xproj, 1, 0), jnp.moveaxis(mask, 1, 0))
+    init = ((jnp.zeros((b, h), jnp.float32),
+             jnp.zeros((b, p), jnp.float32)) if cr0 is None
+            else (cr0[0].astype(jnp.float32), cr0[1].astype(jnp.float32)))
+
+    def step(carry, xt):
+        cprev, rprev = carry
+        xp, m = xt
+        a = xp.astype(jnp.float32) + jnp.dot(
+            rprev.astype(w_r.dtype), w_r,
+            preferred_element_type=jnp.float32)
+        if ln_scale is not None:
+            a = gate_layer_norm(a, ln_scale, ln_bias)
+        gi, gf, gg, go = jnp.split(a, 4, axis=-1)
+        cnew = (jax.nn.sigmoid(gf + 1.0) * cprev
+                + jax.nn.sigmoid(gi) * jnp.tanh(gg))
+        mout = jax.nn.sigmoid(go) * jnp.tanh(cnew)
+        rnew = jnp.dot(mout.astype(w_p.dtype), w_p,
+                       preferred_element_type=jnp.float32)
+        mm = m[:, None]
+        cnew = mm * cnew + (1.0 - mm) * cprev
+        rnew = mm * rnew + (1.0 - mm) * rprev
+        return (cnew, rnew), rnew
+
+    final, ys = _scan_steps(step, init, xs, t, remat_chunk)
+    ys = jnp.moveaxis(ys, 0, 1)
+    if return_final:
+        return ys, final
+    return ys
+
+
+# Backward of the XLA lstmp scan: a plain scan tapes every step's four
+# gates and cell state ([B, 4H] + [B, H] float32 a step, 13 GB over the
+# 2838 encoder steps of rnnt_he2019 at b=64), so the scan is cut into
+# chunks whose internals are recomputed from their boundary carries.
+LSTMP_REMAT_CHUNK = 32
+
+
+def _run_lstmp(cfg: ModelConfig, xproj, mask, w_r, w_p, ln_scale,
+               ln_bias, mesh=None):
+    """A whole-sequence LSTM-with-projection recurrence from a zero
+    carry: the fused Pallas kernels where ``rnn_impl`` resolves to them
+    (TPU) and the call fits them — sublane-aligned local batch rows,
+    weights within the kernels' VMEM limit — else the XLA scan."""
+    from ..utils.impl import interpret_default, resolve_impl
+
+    dtype = jnp.dtype(cfg.dtype)
+    if resolve_impl(cfg.rnn_impl, oracle="xla") == "pallas":
+        from ..ops.lstm_pallas import lstmp_fits_vmem, lstmp_scan_pallas
+        from ..parallel.mesh import DATA_AXIS, shard_batchwise
+
+        h, p = w_p.shape
+        dd = _pallas_dot_dtype(dtype)
+        rows = xproj.shape[0] // (mesh.shape[DATA_AXIS] if mesh else 1)
+        if rows % 8 == 0 and lstmp_fits_vmem(
+                rows, h, p, 4 if dd is None else dtype.itemsize):
+            interp = interpret_default()
+            cell = lambda xp, m, *w: lstmp_scan_pallas(*(xp, m) + w,
+                                                       interp, dd)
+            return shard_batchwise(cell, mesh, n_sharded=2)(
+                xproj, mask, w_r, w_p, ln_scale, ln_bias)
+    return lstmp_scan(
+        xproj, mask, w_r, w_p, ln_scale, ln_bias,
+        dot_dtype=None if dtype == jnp.float32 else dtype,
+        remat_chunk=cfg.rnn_remat_chunk or LSTMP_REMAT_CHUNK)
+
+
+class LSTMPLayer(nn.Module):
+    """One unidirectional LSTM-with-projection layer: the hoisted input
+    projection (one matmul over all frames) and the recurrence.
+    ``hidden`` cells, ``proj``-wide output. With ``cr0`` (the decoders'
+    carried one-step path) it runs the XLA scan and returns the final
+    carry too."""
+
+    cfg: ModelConfig
+    hidden: int
+    proj: int
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, mask: jnp.ndarray, cr0=None,
+                 return_final: bool = False):
+        cfg, h, p = self.cfg, self.hidden, self.proj
+        dtype = jnp.dtype(cfg.dtype)
+        xproj = nn.Dense(4 * h, dtype=dtype, name="wx")(x.astype(dtype))
+        w_r = self.param("wr", nn.initializers.orthogonal(),
+                         (p, 4 * h), jnp.float32)
+        w_p = self.param("wp", nn.initializers.lecun_normal(),
+                         (h, p), jnp.float32)
+        ln_scale = ln_bias = None
+        if cfg.rnn_layer_norm:
+            ln_scale = self.param("ln_scale", nn.initializers.ones,
+                                  (4 * h,), jnp.float32)
+            ln_bias = self.param("ln_bias", nn.initializers.zeros,
+                                 (4 * h,), jnp.float32)
+        if cr0 is None and not return_final:
+            return _run_lstmp(cfg, xproj, mask, w_r, w_p, ln_scale,
+                              ln_bias, mesh=self.mesh)
+        return lstmp_scan(
+            xproj, mask, w_r, w_p, ln_scale, ln_bias,
+            dot_dtype=None if dtype == jnp.float32 else dtype,
+            cr0=cr0, return_final=return_final)
+
+
+def stack_frames(x: jnp.ndarray, lens: jnp.ndarray, k: int):
+    """Concatenate every ``k`` adjacent frames into one: x [B, T, D]
+    (zero past ``lens``) -> [B, ceil(T/k), k*D], lens -> ceil(lens/k)."""
+    if k == 1:
+        return x, lens
+    b, t, d = x.shape
+    n = -(-t // k)
+    x = jnp.pad(x, [(0, 0), (0, n * k - t), (0, 0)])
+    return x.reshape(b, n, k * d), -(-lens // k)
+
+
+class LSTMPEncoder(nn.Module):
+    """The encoder of He et al. 2019 (arXiv:1811.06621): stacked
+    feature frames, ``rnn_layers`` LSTM-with-projection layers, and one
+    time reduction after layer ``time_reduction_layer``."""
+
+    cfg: ModelConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, features: jnp.ndarray, feat_lens: jnp.ndarray):
+        cfg = self.cfg
+        x, lens = stack_frames(features, feat_lens, cfg.frame_stack)
+        for i in range(cfg.rnn_layers):
+            mask = length_mask(lens, x.shape[1])
+            x = LSTMPLayer(cfg, cfg.rnn_hidden, cfg.rnn_proj, self.mesh,
+                           name=f"lstmp{i}")(x, mask)
+            x = (x * mask[:, :, None]).astype(jnp.dtype(cfg.dtype))
+            if i + 1 == cfg.time_reduction_layer:
+                x, lens = stack_frames(x, lens, cfg.time_reduction)
+        return x, lens
+
+
 def _pallas_dot_dtype(dtype) -> "str | None":
     """Single derivation of the Pallas cells' MXU operand precision
     from the model compute dtype (mirrors the oracle's mixed precision:

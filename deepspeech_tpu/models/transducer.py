@@ -1,17 +1,25 @@
-"""RNN-T (transducer) model family — beyond-the-reference extra.
+"""RNN-T (transducer) model family (Graves, arXiv:1211.3711).
 
-The reference is CTC-only; this adds the streaming-ASR successor
-architecture (Graves 2012) reusing this repo's TPU-first pieces: the
-conv frontend + (uni- or bidirectional) RNN stack as the encoder, a
-GRU prediction network over label prefixes, and an additive tanh
-joint. The loss lives in ops/transducer.py (log-semiring
-associative-scan lattice). EXPERIMENTAL: not wired into the CTC
-Trainer/CLI; train with the module's own apply (see
-tests/test_transducer.py for the overfit recipe).
+Two encoders behind one model, told apart by ``ModelConfig.rnn_type``:
 
-Memory note: training materializes the [B, T', U+1, V] joint lattice —
-that tensor, not the recursion, bounds batch/sequence sizes; shard it
-over the data axis like any batch tensor.
+- ``gru`` / ``lstm``: this repo's conv frontend + (uni- or
+  bidirectional) RNN stack, a GRU prediction network over label
+  prefixes, an additive tanh joint (the small family of
+  ``tests/test_transducer.py``).
+- ``lstmp``: the streaming RNN-T of He et al. 2019 (arXiv:1811.06621;
+  preset ``rnnt_he2019``): stacked frames, unidirectional
+  LSTM-with-projection layers with layer normalisation and one time
+  reduction (``models/rnn.LSTMPEncoder``), an LSTM-with-projection
+  prediction network whose one-step decode path carries ``(c, r)`` of
+  every layer, the same joint.
+
+Training (``train.py``, ``objective="rnnt"``) calls
+:meth:`RNNTModel.loss`: the joint's two projections go into
+``ops/transducer.rnnt_joint_loss``, which computes the logits tile by
+tile and never holds the [B, T', U+1, V] lattice. ``__call__``
+materialises that lattice and is the small-size oracle; decoding
+(greedy and beam, below; ``infer --decode.mode=rnnt_greedy|rnnt_beam``,
+``Trainer._evaluate_rnnt``) needs single nodes of it only.
 """
 
 from __future__ import annotations
@@ -26,10 +34,17 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..config import ModelConfig
-from ..ops.transducer import transducer_loss
+from ..ops.transducer import rnnt_joint_loss
 from .conv import ConvFrontend
 from .layers import length_mask
-from .rnn import RNNStack, gru_scan
+from .rnn import LSTMPEncoder, LSTMPLayer, RNNStack, gru_scan
+
+
+def _start_and_labels(labels: jnp.ndarray) -> jnp.ndarray:
+    """[B, U] -> [B, U+1]: position 0 consumes the start token (the
+    blank id 0), position u the label u."""
+    return jnp.concatenate(
+        [jnp.zeros((labels.shape[0], 1), labels.dtype), labels], axis=1)
 
 
 class PredictionNet(nn.Module):
@@ -51,15 +66,12 @@ class PredictionNet(nn.Module):
                               (3 * self.hidden,), jnp.float32)
 
     def __call__(self, labels: jnp.ndarray) -> jnp.ndarray:
-        b, u = labels.shape
-        # Shift right; position 0 consumes the start (blank id 0) token.
-        inputs = jnp.concatenate(
-            [jnp.zeros((b, 1), labels.dtype), labels], axis=1)  # [B, U+1]
+        inputs = _start_and_labels(labels)  # [B, U+1]
         xp = self.wx(self.embed(inputs))
         # All U+1 prefix states matter (row u feeds lattice row u), so
         # the scan mask is all-ones; label_lens bounds are applied by
         # the loss/decode consumers.
-        mask = jnp.ones((b, u + 1), jnp.float32)
+        mask = jnp.ones(inputs.shape, jnp.float32)
         return gru_scan(xp, mask, self.w_h, self.b_h)  # [B, U+1, H]
 
     def step(self, last_ids: jnp.ndarray, h: jnp.ndarray):
@@ -71,25 +83,102 @@ class PredictionNet(nn.Module):
         return ys[:, 0], hf
 
 
+class LSTMPPredictionNet(nn.Module):
+    """Label-prefix LSTM-with-projection stack (He et al. 2019):
+    ``rnnt_pred_layers`` layers of ``hidden`` cells projected to
+    ``rnn_proj`` over a ``rnnt_pred_embed``-wide embedding. ``step``
+    carries every layer's (c, r), packed into ONE array
+    [B, layers * (hidden + rnn_proj)] so the decoders handle it like
+    the GRU's h."""
+
+    cfg: ModelConfig
+    hidden: int
+    mesh: Optional[Mesh] = None
+
+    def setup(self):
+        cfg = self.cfg
+        self.embed = nn.Embed(cfg.vocab_size, cfg.rnnt_pred_embed)
+        self.layers = [
+            LSTMPLayer(cfg, self.hidden, cfg.rnn_proj, self.mesh,
+                       name=f"lstmp{i}")
+            for i in range(cfg.rnnt_pred_layers)]
+
+    def __call__(self, labels: jnp.ndarray) -> jnp.ndarray:
+        inputs = _start_and_labels(labels)
+        x = self.embed(inputs)
+        mask = jnp.ones(inputs.shape, jnp.float32)
+        for layer in self.layers:
+            x = layer(x, mask)
+        return x  # [B, U+1, P]
+
+    def step(self, last_ids: jnp.ndarray, state: jnp.ndarray):
+        """Consume one label id per stream: (out [B, P], state')."""
+        h, p = self.hidden, self.cfg.rnn_proj
+        x = self.embed(last_ids)[:, None, :]
+        mask = jnp.ones((last_ids.shape[0], 1), jnp.float32)
+        new = []
+        for i, layer in enumerate(self.layers):
+            at = i * (h + p)
+            cr0 = (state[:, at:at + h], state[:, at + h:at + h + p])
+            x, (c, r) = layer(x, mask, cr0=cr0, return_final=True)
+            new += [c, r]
+        return x[:, 0], jnp.concatenate(new, axis=-1)
+
+
+class _OutLayer(nn.Module):
+    """The joint's output layer as bare parameters (the tree of an
+    ``nn.Dense``): the tiled loss multiplies by them tile by tile."""
+
+    vocab_size: int
+    joint_dim: int
+
+    @nn.compact
+    def __call__(self):
+        return (self.param("kernel", nn.initializers.lecun_normal(),
+                           (self.joint_dim, self.vocab_size), jnp.float32),
+                self.param("bias", nn.initializers.zeros,
+                           (self.vocab_size,), jnp.float32))
+
+
 class RNNTJoint(nn.Module):
-    """Additive joint: tanh(W_e enc + W_p pred) -> vocab logits."""
+    """Additive joint (Graves et al. 2013):
+    tanh(W_e enc + W_p pred + b) W_o + b_o -> vocab logits."""
 
     vocab_size: int
     joint_dim: int = 256
+    dtype: str = "float32"
 
-    @nn.compact
+    def setup(self):
+        dtype = jnp.dtype(self.dtype)
+        self.enc_proj = nn.Dense(self.joint_dim, dtype=dtype)
+        self.pred_proj = nn.Dense(self.joint_dim, use_bias=False,
+                                  dtype=dtype)
+        self.out = _OutLayer(self.vocab_size, self.joint_dim)
+
     def __call__(self, enc: jnp.ndarray, pred: jnp.ndarray) -> jnp.ndarray:
         # enc [B, T, De] + pred [B, U+1, Dp] -> [B, T, U+1, V]
-        e = nn.Dense(self.joint_dim, name="enc_proj")(enc)[:, :, None, :]
-        p = nn.Dense(self.joint_dim, name="pred_proj")(pred)[:, None, :, :]
-        return nn.Dense(self.vocab_size, name="out")(jnp.tanh(e + p))
+        e = self.enc_proj(enc)[:, :, None, :].astype(jnp.float32)
+        p = self.pred_proj(pred)[:, None, :, :].astype(jnp.float32)
+        w_o, b_o = self.out()
+        dtype = jnp.dtype(self.dtype)
+        return jnp.dot(jnp.tanh(e + p).astype(dtype), w_o.astype(dtype),
+                       preferred_element_type=jnp.float32) + b_o
+
+    def loss(self, enc, pred, labels, input_lens, label_lens):
+        """Per-utterance NLL [B] through the tiled joint + loss."""
+        w_o, b_o = self.out()
+        return rnnt_joint_loss(
+            self.enc_proj(enc), self.pred_proj(pred),
+            w_o.astype(jnp.dtype(self.dtype)), b_o, labels, input_lens,
+            label_lens)
 
 
 class RNNTModel(nn.Module):
-    """Encoder (ConvFrontend + RNNStack from the shared ModelConfig) +
-    prediction net + joint. ``__call__`` returns the full-lattice
-    log-probs for training; ``encode``/``predict``/``joint_logits``
-    serve decoding."""
+    """Encoder + prediction net + joint from the shared ModelConfig.
+    ``loss`` is the training path; ``__call__`` returns the
+    full-lattice log-probs (small sizes only);
+    ``encode``/``predict``/``predict_step``/``joint_logits`` serve
+    decoding."""
 
     cfg: ModelConfig
     pred_hidden: int = 128
@@ -97,16 +186,33 @@ class RNNTModel(nn.Module):
     mesh: Optional[Mesh] = None
 
     def setup(self):
-        self._conv = ConvFrontend(self.cfg, name="conv")
-        self._rnn = RNNStack(self.cfg, mesh=self.mesh, name="rnn")
-        self._pred = PredictionNet(self.cfg.vocab_size, self.pred_hidden,
-                                   name="pred")
-        self._joint = RNNTJoint(self.cfg.vocab_size, self.joint_dim,
-                                name="joint")
+        cfg = self.cfg
+        if cfg.rnn_type == "lstmp":
+            self._enc = LSTMPEncoder(cfg, self.mesh, name="enc")
+            self._pred = LSTMPPredictionNet(cfg, self.pred_hidden,
+                                            self.mesh, name="pred")
+        else:
+            self._conv = ConvFrontend(cfg, name="conv")
+            self._rnn = RNNStack(cfg, mesh=self.mesh, name="rnn")
+            self._pred = PredictionNet(cfg.vocab_size, self.pred_hidden,
+                                       cfg.rnnt_pred_embed, name="pred")
+        self._joint = RNNTJoint(cfg.vocab_size, self.joint_dim,
+                                cfg.dtype, name="joint")
+
+    @property
+    def pred_state_size(self) -> int:
+        """Width of the prediction net's carried decode state."""
+        cfg = self.cfg
+        if cfg.rnn_type == "lstmp":
+            return cfg.rnnt_pred_layers * (self.pred_hidden + cfg.rnn_proj)
+        return self.pred_hidden
 
     def encode(self, features, feat_lens, train: bool = False):
-        x, lens = self._conv(features, feat_lens, train)
-        x = self._rnn(x, lens, train)
+        if self.cfg.rnn_type == "lstmp":
+            x, lens = self._enc(features, feat_lens)
+        else:
+            x, lens = self._conv(features, feat_lens, train)
+            x = self._rnn(x, lens, train)
         mask = length_mask(lens, x.shape[1])
         return (x * mask[:, :, None]).astype(jnp.float32), lens
 
@@ -116,8 +222,8 @@ class RNNTModel(nn.Module):
         # the loss/decode consumers, not here.
         return self._pred(labels)
 
-    def predict_step(self, last_ids, h):
-        return self._pred.step(last_ids, h)
+    def predict_step(self, last_ids, state):
+        return self._pred.step(last_ids, state)
 
     def joint_logits(self, enc, pred):
         return self._joint(enc, pred).astype(jnp.float32)
@@ -129,6 +235,19 @@ class RNNTModel(nn.Module):
         pred = self.predict(labels)
         logits = self.joint_logits(enc, pred)
         return jax.nn.log_softmax(logits, axis=-1), lens
+
+    def joint_loss(self, enc, pred, labels, enc_lens, label_lens):
+        """Per-utterance NLL [B] of encoder rows against prediction
+        rows through the tiled joint + loss."""
+        return self._joint.loss(enc, pred, labels, enc_lens, label_lens)
+
+    def loss(self, features, feat_lens, labels, label_lens,
+             train: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """(per-utterance NLL [B], encoder lengths [B]); equal to
+        ``transducer_loss(self(...))`` without its lattice."""
+        enc, lens = self.encode(features, feat_lens, train)
+        pred = self.predict(labels)
+        return self.joint_loss(enc, pred, labels, lens, label_lens), lens
 
 
 def create_rnnt_model(cfg: ModelConfig, mesh: Optional[Mesh] = None
@@ -161,16 +280,15 @@ def _beam_fns(model: RNNTModel, w: int):
     def rescore(variables, enc_i, enc_len, labels, label_lens):
         """Exact lattice log-likelihood of W label sequences against ONE
         utterance's encoder output: enc_i [T, De], labels [W, U],
-        label_lens [W] -> [W] f32. One training-style forward — the
-        [W, T, U+1, V] joint lattice — so the scores the search returns
-        are honest full-sum likelihoods, not pruned-alignment bounds."""
+        label_lens [W] -> [W] f32. One training-style forward through
+        the tiled joint + loss, so the scores the search returns are
+        honest full-sum likelihoods, not pruned-alignment bounds, at
+        any vocabulary size."""
         enc_b = jnp.broadcast_to(enc_i[None], (w,) + enc_i.shape)
         pred = model.apply(variables, labels, method=RNNTModel.predict)
-        logits = model.apply(variables, enc_b, pred,
-                             method=RNNTModel.joint_logits)
-        lp = jax.nn.log_softmax(logits, axis=-1)
         lens = jnp.full((w,), enc_len, jnp.int32)
-        return -transducer_loss(lp, labels, lens, label_lens)
+        return -model.apply(variables, enc_b, pred, labels, lens,
+                            label_lens, method=RNNTModel.joint_loss)
 
     return pstep, frame_logps, rescore
 
@@ -214,8 +332,8 @@ def rnnt_beam_decode(model: RNNTModel, variables, features, feat_lens,
     even when the longer prefix has the higher full-sum likelihood.
     The search therefore finishes with an EXACT full-lattice rescoring
     of the surviving <=W hypotheses (one batched training-style
-    forward per utterance, static [W, max_label_len] shapes so it
-    compiles once) and ranks by that. Returns list[list[int]] — or,
+    forward per utterance through the tiled loss, static
+    [W, max_label_len] shapes so it compiles once) and ranks by that. Returns list[list[int]] — or,
     with ``return_nbest``, per-utterance ``[(prefix_list,
     exact_log_likelihood)]`` best-first. (Even ``beam_width=1`` can
     beat greedy: the frame loop compares "blank now" against "emit
@@ -225,7 +343,7 @@ def rnnt_beam_decode(model: RNNTModel, variables, features, feat_lens,
                             method=RNNTModel.encode)
     enc = np.asarray(enc)
     lens = np.asarray(lens)
-    hidden = model.pred_hidden
+    hidden = model.pred_state_size
     w = beam_width
     pstep_v, frame_logps_v, rescore_v = _beam_fns(model, w)
     pstep = functools.partial(pstep_v, variables)
@@ -333,7 +451,7 @@ def rnnt_greedy_decode(model: RNNTModel, variables, features, feat_lens,
     enc = np.asarray(enc)
     lens = np.asarray(lens)
     b = enc.shape[0]
-    hidden = model.pred_hidden
+    hidden = model.pred_state_size
     pstep_v, step_logits_v = _greedy_fns(model)
     pstep = functools.partial(pstep_v, variables)
     step_logits = functools.partial(step_logits_v, variables)

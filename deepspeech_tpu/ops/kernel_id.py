@@ -20,6 +20,7 @@ fact, not a name:
            0; ``both`` for the fused bidirectional kernels
   t, b, h  steps, batch rows and hidden width of the call
   gates    3 (GRU) or 4 (LSTM)
+  p        lstmp_scan_*: width of the recurrent projection
   t, b, s  CTC: frames, padded batch rows, padded extended labels
 """
 
@@ -38,6 +39,8 @@ KERNELS = frozenset({
     "lstm_scan_fwd",
     "lstm_scan_bwd",
     "lstm_scan_q_fwd",
+    "lstmp_scan_fwd",     # LSTM with projection (+ layer-normed gates)
+    "lstmp_scan_bwd",
     "ctc_alpha",          # alpha recursion, alphas taped for the VJP
     "ctc_alpha_loss",     # alpha recursion, log-likelihood only
     "ctc_gamma",          # beta recursion folded into the occupancies

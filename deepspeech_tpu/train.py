@@ -127,11 +127,19 @@ def create_train_state(cfg: Config, rng: jax.Array, sample_batch: Dict,
         from .models.transducer import create_rnnt_model
 
         model = create_rnnt_model(cfg.model, mesh=mesh)
-        variables = model.init(
-            rng, jnp.asarray(sample_batch["features"]),
-            jnp.asarray(sample_batch["feat_lens"]),
-            jnp.asarray(sample_batch["labels"]),
-            jnp.asarray(sample_batch["label_lens"]), train=False)
+        # No parameter's shape depends on the batch: initialise through
+        # the training path on a few rows and frames of the sample, as
+        # ONE compiled program (the eager path compiles every primitive
+        # of ten recurrent layers on its own).
+        rows = slice(0, 8)
+        frames = slice(0, 8 * cfg.model.time_stride)
+        variables = jax.jit(partial(
+            model.init, train=False, method=type(model).loss))(
+            rng, jnp.asarray(sample_batch["features"][rows, frames]),
+            jnp.minimum(jnp.asarray(sample_batch["feat_lens"][rows]),
+                        frames.stop),
+            jnp.asarray(sample_batch["labels"][rows]),
+            jnp.asarray(sample_batch["label_lens"][rows]))
     else:
         model = create_model(cfg.model, mesh=mesh)
         variables = model.init(
@@ -217,16 +225,15 @@ def make_train_step(cfg: Config, model, optimizer, mesh, state_sh,
 
             return jax.value_and_grad(loss_of, has_aux=True)(params)
     elif cfg.train.objective == "rnnt":
-        from .ops.transducer import transducer_loss
-
         def grads_of(params, stats, mb):
             def loss_of(p):
-                (lp, lens), mutated = model.apply(
+                # The tiled joint + loss (ops/transducer.py): no
+                # [B, T', U+1, V] lattice at any size.
+                (per_utt, lens), mutated = model.apply(
                     {"params": p, "batch_stats": stats},
                     mb["features"], mb["feat_lens"], mb["labels"],
-                    mb["label_lens"], True, mutable=["batch_stats"])
-                per_utt = transducer_loss(
-                    lp, mb["labels"], lens, mb["label_lens"])
+                    mb["label_lens"], True, mutable=["batch_stats"],
+                    method=type(model).loss)
                 # Zero-frame rows carry the loss's -LOG_ZERO sentinel
                 # (no lattice, no likelihood) — average over real rows
                 # only so one empty/corrupt utterance can't blow up the
@@ -234,7 +241,8 @@ def make_train_step(cfg: Config, model, optimizer, mesh, state_sh,
                 valid = (lens > 0).astype(per_utt.dtype)
                 loss = jnp.sum(per_utt * valid) \
                     / jnp.maximum(jnp.sum(valid), 1.0)
-                return loss, mutated["batch_stats"]
+                # The lstmp encoder has layer norm, no batch norm.
+                return loss, mutated.get("batch_stats", stats)
 
             return jax.value_and_grad(loss_of, has_aux=True)(params)
     else:

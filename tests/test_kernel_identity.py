@@ -60,6 +60,13 @@ CASES = {
                                  1760)],
     "lstm_q_blocked_h1760": [scan("lstm_scan_q_fwd", "blocked_q", 0, 400,
                                   8, 1760, 4)],
+    # rnnt_he2019's prediction net: 65 prefixes, b=64, 2048 cells
+    # projected to 640.
+    "lstmp_t65_b64": [
+        {**scan("lstmp_scan_fwd", "resident", 0, 65, 64, 2048, 4),
+         "p": "640"},
+        {**scan("lstmp_scan_bwd", "resident", 0, 65, 64, 2048, 4),
+         "p": "640"}],
 }
 
 

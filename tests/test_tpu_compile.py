@@ -58,6 +58,9 @@ def v5e_chip():
     ("ctc_en", ["ctc_alpha", "ctc_gamma"]),
     # int8 weights at ds2_full's width
     ("gru_q_h1760", ["gru_scan_q_fwd"]),
+    # rnnt_he2019's prediction net: 13.1 MB of single-buffered bf16
+    # weights under a raised scoped-VMEM limit, forward + VJP
+    ("lstmp_t65_b64", ["lstmp_scan_fwd", "lstmp_scan_bwd"]),
 ])
 def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     """Mosaic accepts the kernel, and the compiled instruction still

@@ -193,6 +193,23 @@ def kernel_cases():
             return fwd, (xp, m, wq, sc, bh)
         return f
 
+    def lstmp_case(t_, b_=64, h=2048, p=640):
+        """rnnt_he2019's recurrences at the cell's shapes: forward
+        with its cell-state tape and the backward kernel."""
+        args = (S((b_, t_, 4 * h), jnp.bfloat16), S((b_, t_), jnp.float32),
+                S((p, 4 * h), jnp.float32), S((h, p), jnp.float32),
+                S((4 * h,), jnp.float32), S((4 * h,), jnp.float32))
+
+        def f():
+            def step(*a):
+                return lp.lstmp_scan_pallas(*a, dot_dtype="bfloat16")
+
+            def train(*a):
+                ys, vjp = jax.vjp(step, *a)
+                return vjp(jnp.ones_like(ys))
+            return train, args
+        return f
+
     cases["gru_h800"] = gru_case(800)
     cases["gru_h1760"] = gru_case(1760)
     cases["gru_stream_h800"] = gru_stream_case(800)
@@ -207,6 +224,11 @@ def kernel_cases():
     # its (natural) int8 residency, LSTM naturally blocked at H=1760.
     cases["gru_q_blocked_h1760"] = gru_q_blocked_case(1760)
     cases["lstm_q_blocked_h1760"] = lstm_q_blocked_case(1760)
+    # rnnt_he2019.train_16s_b64: encoder layers 0-1 (567 stacked
+    # frames), layers 2-7 (284), prediction net (65 prefixes).
+    cases["lstmp_t567_b64"] = lstmp_case(567)
+    cases["lstmp_t284_b64"] = lstmp_case(284)
+    cases["lstmp_t65_b64"] = lstmp_case(65)
     cases["ctc_aishell"] = ctc_case(4336, 400, 60)
     cases["ctc_en"] = ctc_case(29, 400, 160)
     # The weak-#1 shape: AISHELL-width device beam search, both merge

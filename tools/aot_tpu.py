@@ -117,13 +117,18 @@ def main() -> None:
     if args.objective:
         train_cfg = dataclasses.replace(train_cfg,
                                         objective=args.objective)
+    # The transducer's lattice is padded to max_label_len, so a preset
+    # that trains one keeps its own (rnnt_he2019: 64 word-pieces).
+    rnnt = train_cfg.objective == "rnnt"
+    max_label_len = cfg.data.max_label_len if rnnt else 160
     cfg = dataclasses.replace(
         cfg, model=model_cfg, train=train_cfg,
         data=dataclasses.replace(cfg.data, batch_size=args.batch,
                                  bucket_frames=(args.frames,),
-                                 max_label_len=160))
+                                 max_label_len=max_label_len))
 
-    batch, _ = synthetic_batch(cfg, args.batch, args.frames, 120)
+    batch, _ = synthetic_batch(cfg, args.batch, args.frames,
+                               min(120, max_label_len))
     rng = jax.random.PRNGKey(0)
     optimizer = make_optimizer(cfg, 100)
     # Param init runs EAGERLY on the cpu runtime — keep the on-chip
@@ -217,13 +222,16 @@ def main() -> None:
 
     from deepspeech_tpu.utils.flops import ds2_step_flops
 
+    # utils/flops.py knows the DS2 stack only: a transducer step gets
+    # no analytic number here (benchmark/costs/rnnt.py has one).
     analytic = None
-    try:
-        analytic = float(ds2_step_flops(
-            cfg.model, args.batch, args.frames,
-            num_features=cfg.features.num_features))
-    except Exception as e:  # keep the compiler numbers either way
-        _log(f"analytic flops unavailable: {type(e).__name__}: {e}")
+    if not rnnt:
+        try:
+            analytic = float(ds2_step_flops(
+                cfg.model, args.batch, args.frames,
+                num_features=cfg.features.num_features))
+        except Exception as e:  # keep the compiler numbers either way
+            _log(f"analytic flops unavailable: {type(e).__name__}: {e}")
 
     print(json.dumps({
         "tool": "aot_tpu",

@@ -13,9 +13,13 @@ resolved while jax traces the caller; the kernel's body is unchanged.
 One name per kernel ROLE; what tells two builds of a role apart is a
 fact, not a name:
 
-  variant  ``resident`` (whole weight matrix in VMEM), ``blocked``
-           (weight columns streamed over a second grid axis),
-           ``resident_q`` / ``blocked_q`` (the same with int8 weights)
+  variant  ``resident`` (whole weight matrix a VMEM block), ``blocked``
+           (weight columns moved by the pipeline over a second grid
+           axis, from wherever XLA left the matrix), ``blocked_pinned``
+           (the same grid, but the kernel copies the matrix into a
+           VMEM scratch once and slices its column blocks from there),
+           ``resident_q`` / ``blocked_q`` (as the first two, with int8
+           weights)
   reverse  1 if the scan runs from the last frame to the first, else
            0; ``both`` for the fused bidirectional kernels
   t, b, h  steps, batch rows and hidden width of the call

@@ -10,23 +10,41 @@ cuDNN's "persistent RNN" equivalent. Budget: 3*H^2*bytes must fit the
 ~10 MB residency budget (H=800 f32 -> 7.7 MB ok; bf16 doubles reach
 to H~1280).
 
-**Blocked streaming** (big H, e.g. the ds2_full flagship H=1760 where
-weights are 37 MB f32 / 18.6 MB bf16 — larger than VMEM itself): the
-weight columns are streamed through a ``(T, G)`` grid in ``[H, C]``
-blocks. Pallas auto-double-buffers the moving block, so the fetch of
-block g+1 overlaps the matmul of block g; per-step gate partials land
-in a VMEM scratch and the GRU elementwise update fires on the last
-block. HBM traffic equals the XLA scan's (the weights must move every
-step either way — that is physics), but the gate math is fused and
-there is no per-step loop/dynamic-slice overhead. The backward kernel
-streams the same blocks once per step by pipelining the ``dgates @
-W^T`` contraction one step behind the gate recompute (SURVEY.md §7
-hard-parts #2: H-blocked weight residency).
+**Blocked** (big H, e.g. the ds2_full flagship H=1760, whose weights
+are 37 MB f32 / 18.6 MB bf16: past the residency budget that Mosaic's
+default 16 MiB scoped limit leaves, not past VMEM, of which a v5e core
+has 128 MiB): the weight columns are consumed in ``[H, C]`` blocks
+over a ``(T, G)`` grid. The grid is there for the scoped-VMEM working
+set and the MXU feed: a pipelined operand is double-buffered, so the
+whole matrix as one block would cost twice its size where two 1.8 MB
+column blocks do, and each step's matmul runs as G block matmuls whose
+partials land in a VMEM scratch, the GRU elementwise update firing on
+the last block. It is NOT there because the weights must cross HBM
+every step (PERF.md section 6, PR 22 finding 1: they need not, and a
+step that does fetch them takes 29.5 us against 17.3). Who places the
+matrix:
+
+- forward (``_gru_kernel_blocked``): XLA's memory-space assignment. It
+  has put the operand in VMEM (``S(1)``) in every trace so far, and the
+  BlockSpec pipeline then copies VMEM to VMEM.
+- backward (``_gru_bwd_kernel_blocked``): the kernel itself, when
+  ``_pinned_bwd_vmem_limit`` says the call fits ``_PINNED_VMEM_CAP``
+  (variant ``blocked_pinned``): the operand is taken in ``pl.ANY``, one
+  DMA at the first grid step copies it whole into a VMEM scratch, and
+  every column block is a slice of that scratch; the call raises its
+  own scoped limit from its shapes. Past the cap (f32 dots at H=1760,
+  wider layers) the BlockSpec pipeline streams the blocks from wherever
+  XLA left the operand (variant ``blocked``): from HBM that is the
+  whole matrix every step, the honest cost of a matrix that cannot
+  live in VMEM. One kernel body serves both; only where a column block
+  comes from differs. The backward kernel needs the blocks once per
+  step: it pipelines the ``dgates @ W^T`` contraction one step behind
+  the gate recompute (SURVEY.md §7 hard-parts #2).
 
 **int8 resident / int8 blocked streaming** (weight-only PTQ serving):
 ``gru_scan_pallas_q`` keeps the QUANTIZED matrix resident — int8
 quadruples the residency reach over f32, so the flagship H=1760
-(9.3 MB) stops streaming weights per step altogether; scales apply to
+(9.3 MB) needs no blocked grid at all; scales apply to
 the gates via column-scale associativity (see the section comment
 below). Past even the 1-byte budget (GRU H>1869; LSTM's 4-gate
 layout already at H=1620) the q path switches to
@@ -44,7 +62,7 @@ T-1-t), so no operand flipping is materialized. ``dot_dtype``
 ("bfloat16" for bf16 models) sets the MXU operand precision of the
 recurrent matmuls — accumulation stays f32, matching the oracle's
 ``dot_dtype`` semantics — and halves both the residency budget and
-the streamed bytes.
+the weights' bytes.
 """
 
 from __future__ import annotations
@@ -61,8 +79,12 @@ from .kernel_id import kernel_call, scan_facts
 
 # Leave headroom for xproj/mask/out rows + double buffering.
 _VMEM_WEIGHT_BUDGET = 10 * 1024 * 1024
-# Streamed weight-block width (lane-aligned); G = ceil(3H / this).
+# Weight-block width (lane-aligned); G = ceil(3H / this).
 _BLOCK_COLS = 512
+# The most scoped VMEM a copy-once call asks Mosaic for. A v5e core has
+# 128 MiB; 16 MiB is only the default scoped limit (BASELINE.md:111).
+# The rest stays with XLA, which places the neighbouring calls' operands.
+_PINNED_VMEM_CAP = 48 * 1024 * 1024
 
 
 def fits_vmem(hidden: int, dtype_bytes: int = 4, n_gates: int = 3) -> bool:
@@ -256,7 +278,7 @@ def _bigru_bwd_kernel(xpf_ref, xpb_ref, mf_ref, mb_ref,
 
 
 # ---------------------------------------------------------------------------
-# Blocked-streaming kernels (weights larger than VMEM: flagship H=1760).
+# Blocked kernels (weights past the residency budget: flagship H=1760).
 # ---------------------------------------------------------------------------
 
 def _gru_kernel_blocked(xp_ref, mask_ref, wh_ref, bh_ref, out_ref,
@@ -313,18 +335,37 @@ def _gru_kernel_blocked_q(xp_ref, mask_ref, wq_ref, sc_ref, bh_ref,
 
 def _gru_bwd_kernel_blocked(xp_ref, mask_ref, ys_prev_ref, dy_ref, wh_ref,
                             bh_ref, dxp_ref, dgates_ref,
-                            dh_c, dh_acc, gates_buf, dg_prev,
-                            *, h: int, n_blocks: int, c: int):
+                            dh_c, dh_acc, gates_buf, dg_prev, *pinned,
+                            h: int, n_blocks: int, c: int):
     """Blocked BPTT step: ONE pass over the weight blocks per time step.
 
     The ``dgates @ W^T`` contribution to dh uses the *previous* step's
-    dgates (held in ``dg_prev``), so it rides the same weight-block
-    stream as the current step's gate recompute — no second pass.
+    dgates (held in ``dg_prev``), so it rides the same pass over the
+    weight blocks as the current step's gate recompute — no second pass.
     ``dh_c`` therefore carries only the elementwise part of dh_prev;
     the full dh assembles at the last block as dh_c + dh_acc + dy.
+
+    ``pinned`` = (w_scr, sem) in the copy-once build: ``wh_ref`` is then
+    the whole padded matrix wherever XLA left it (``pl.ANY``), copied
+    into ``w_scr`` once, and a column block is a slice of ``w_scr``.
+    Without it ``wh_ref`` is the ``[H, C]`` block the pipeline moved.
     """
     ti = pl.program_id(0)
     g = pl.program_id(1)
+
+    if pinned:
+        w_scr, sem = pinned
+
+        @pl.when((ti == 0) & (g == 0))
+        def _():
+            copy = pltpu.make_async_copy(wh_ref, w_scr, sem)
+            copy.start()
+            copy.wait()
+
+        cols = pl.ds(pl.multiple_of(g * c, c), c)
+        w_blk = lambda: w_scr[:, cols]
+    else:
+        w_blk = lambda: wh_ref[:]
 
     @pl.when((ti == 0) & (g == 0))
     def _():
@@ -337,13 +378,13 @@ def _gru_bwd_kernel_blocked(xp_ref, mask_ref, ys_prev_ref, dy_ref, wh_ref,
 
     hprev = jnp.where(ti == pl.num_programs(0) - 1,
                       jnp.zeros_like(ys_prev_ref[0]), ys_prev_ref[0])
-    blk = jnp.dot(hprev.astype(wh_ref.dtype), wh_ref[:],
+    blk = jnp.dot(hprev.astype(wh_ref.dtype), w_blk(),
                   preferred_element_type=jnp.float32) + bh_ref[:]
     gates_buf[:, pl.ds(g * c, c)] = blk
 
     dgp = dg_prev[:, pl.ds(g * c, c)]
     dh_acc[:] += jax.lax.dot_general(
-        dgp.astype(wh_ref.dtype), wh_ref[:], (((1,), (1,)), ((), ())),
+        dgp.astype(wh_ref.dtype), w_blk(), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(g == n_blocks - 1)
@@ -354,8 +395,8 @@ def _gru_bwd_kernel_blocked(xp_ref, mask_ref, ys_prev_ref, dy_ref, wh_ref,
         dxp_ref[0] = dxp
         dgates_ref[0] = dgates
         dg_prev[:, :3 * h] = dgates
-        # Elementwise part of dh_prev; the dgates @ W^T part streams
-        # with the next step's weight blocks into dh_acc.
+        # Elementwise part of dh_prev; the dgates @ W^T part rides the
+        # next step's pass over the weight blocks into dh_acc.
         dh_c[:] = dh_elt
 
 
@@ -464,6 +505,30 @@ def _use_blocked(h: int, dot, n_gates: int = 3,
     dot dtype, so stored width == operand width there)."""
     wb = jnp.dtype(dot).itemsize if weight_bytes is None else weight_bytes
     return not fits_vmem(h, wb, n_gates)
+
+
+def _pinned_bwd_vmem_limit(b: int, h: int, xp_bytes: int,
+                           dot_bytes: int) -> Optional[int]:
+    """The scoped-VMEM limit the copy-once blocked backward call asks
+    for, or None when it would pass :data:`_PINNED_VMEM_CAP` (the call
+    then streams its blocks). What the call holds: ONE copy of the
+    padded ``[H, cols]`` matrix, the double-buffered per-step blocks
+    (xproj row, mask, h_prev and dy rows, bias block in; dxp and dgates
+    rows out) and the four float32 scratches; a quarter on top for the
+    gate math's temporaries, rounded up to 4 MiB and never under
+    Mosaic's default of 16 MiB. ds2_full (H=1760, bf16): 32 MiB at
+    b=32, 40 MiB at b=64."""
+    h3 = 3 * h
+    n_blocks, c = _block_layout(h3)
+    cols = n_blocks * c
+    weights = h * cols * dot_bytes
+    rows = 2 * (b * h3 * xp_bytes + b * 128 * 4 + 2 * b * h * 4
+                + 8 * c * 4 + 2 * b * h3 * 4)
+    scratch = 2 * b * h * 4 + 2 * b * cols * 4
+    step = 4 * 1024 * 1024
+    limit = max(16 * 1024 * 1024,
+                pl.cdiv((weights + rows + scratch) * 5 // 4, step) * step)
+    return limit if limit <= _PINNED_VMEM_CAP else None
 
 
 def _gru_pallas_raw(xproj, mask, w_h, b_h, reverse: bool, interpret: bool,
@@ -575,8 +640,8 @@ def gru_scan_pallas_stream(xproj: jnp.ndarray, mask: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # Weight-only int8 inference kernel (VERDICT r3 #7): the quantized
 # [H, 3H] matrix lives int8 in VMEM, so the flagship H=1760 (9.3 MB)
-# becomes RESIDENT — the bf16 path must stream 18.6 MB of weight
-# columns per time step at that size. Dequantization never
+# becomes RESIDENT — the bf16 forward takes the blocked grid at that
+# size, its 18.6 MB placed by XLA. Dequantization never
 # materializes a full-precision matrix: column-scale associativity,
 # (h @ Q) * scale == h @ (Q * scale), moves the per-output-channel
 # scale onto the [B, 3H] gates — O(B*3H) VPU work per step instead of
@@ -908,19 +973,36 @@ def _gru_bwd(reverse, interpret, dot_dtype, residuals, dy):
         )(xp_t, mask_t, ys, dy_t, w, bh2)
     else:
         n_blocks, c = _block_layout(h3)
+        cols = n_blocks * c
+        # Who puts the matrix into VMEM is decided from the shapes: the
+        # kernel (one copy, its own scoped limit) when that fits the
+        # cap, else the pipeline, block by block.
+        limit = _pinned_bwd_vmem_limit(
+            b, h, xp_t.dtype.itemsize, jnp.dtype(dot).itemsize)
+        pinned = limit is not None
+        if pinned:
+            w_spec = pl.BlockSpec(memory_space=pl.ANY)
+            pin = {"compiler_params":
+                   pltpu.CompilerParams(vmem_limit_bytes=limit)}
+            pin_scratch = [pltpu.VMEM((h, cols), dot),
+                           pltpu.SemaphoreType.DMA(())]
+        else:
+            w_spec = pl.BlockSpec((h, c), lambda i, g: (0, g),
+                                  memory_space=pltpu.VMEM)
+            pin, pin_scratch = {}, []
         dxp_t, dgates_t = kernel_call(
             functools.partial(_gru_bwd_kernel_blocked, h=h,
                               n_blocks=n_blocks, c=c),
             kernel="gru_scan_bwd",
-            facts=scan_facts("blocked", reverse, t_max, b, h, 3),
+            facts=scan_facts("blocked_pinned" if pinned else "blocked",
+                             reverse, t_max, b, h, 3),
             grid=(t_max, n_blocks),
             in_specs=[
                 pl.BlockSpec((1, b, h3), bidx, memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, b, 1), bmidx, memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, b, h), pidx, memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, b, h), bidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((h, c), lambda i, g: (0, g),
-                             memory_space=pltpu.VMEM),
+                w_spec,
                 pl.BlockSpec((1, c), lambda i, g: (0, g),
                              memory_space=pltpu.VMEM),
             ],
@@ -929,12 +1011,12 @@ def _gru_bwd(reverse, interpret, dot_dtype, residuals, dy):
             scratch_shapes=[
                 pltpu.VMEM((b, h), jnp.float32),
                 pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, n_blocks * c), jnp.float32),
-                pltpu.VMEM((b, n_blocks * c), jnp.float32),
-            ],
+                pltpu.VMEM((b, cols), jnp.float32),
+                pltpu.VMEM((b, cols), jnp.float32),
+            ] + pin_scratch,
             interpret=interpret,
-        )(xp_t, mask_t, ys, dy_t, _pad_cols(w, n_blocks * c),
-          _pad_cols(bh2, n_blocks * c))
+            **pin,
+        )(xp_t, mask_t, ys, dy_t, _pad_cols(w, cols), _pad_cols(bh2, cols))
 
     # h_prev sequence in scan order: ys shifted by one scan step.
     if reverse:

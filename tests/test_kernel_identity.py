@@ -42,7 +42,14 @@ def scan(kernel, variant, reverse, t, b, h, gates=3):
 # test_tpu_compile.py compiles, then the other routed shapes.
 CASES = {
     "gru_h1760": [scan("gru_scan_fwd", "blocked", 0, 400, 8, 1760),
-                  scan("gru_scan_bwd", "blocked", 0, 400, 8, 1760)],
+                  scan("gru_scan_bwd", "blocked_pinned", 0, 400, 8, 1760)],
+    # ds2_full.train_1chip's own call, and twice its rows
+    "gru_h1760_b32": [
+        scan("gru_scan_fwd", "blocked", 0, 850, 32, 1760),
+        scan("gru_scan_bwd", "blocked_pinned", 0, 850, 32, 1760)],
+    "gru_h1760_b64": [
+        scan("gru_scan_fwd", "blocked", 0, 850, 64, 1760),
+        scan("gru_scan_bwd", "blocked_pinned", 0, 850, 64, 1760)],
     "gru_stream_h800": [scan("gru_scan_stream", "resident", 0, 32, 2, 800)],
     "bigru_h800": [scan("bigru_scan_fwd", "resident", "both", 400, 8, 800)],
     "ctc_en": [{"kernel": "ctc_alpha", "t": "400", "b": "8", "s": "384"},
@@ -99,6 +106,79 @@ def test_reverse_scan_says_so_forward_and_backward():
     assert lowered_facts(train, _gru_args()) == [
         scan("gru_scan_fwd", "resident", 1, _T, _B, _H),
         scan("gru_scan_bwd", "resident", 1, _T, _B, _H)]
+
+
+def _pallas_calls(jaxpr):
+    """The parameters of every ``pallas_call`` in a jaxpr, nested
+    ones included, in program order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params)
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                found.extend(_pallas_calls(inner))
+    return found
+
+
+@pytest.mark.parametrize("case, variant, limit_mib", [
+    ("gru_h1760_b32", "blocked_pinned", 32),
+    ("gru_h1760_b64", "blocked_pinned", 40),
+    # f32 dots: 39.6 MB of weights pass the cap, the pipeline streams
+    ("gru_h1760_f32", "blocked", None),
+])
+def test_who_places_the_backward_scan_weights(case, variant, limit_mib):
+    """The copy-once build says so in its facts, takes its weights
+    where XLA left them (``pl.ANY``: no BlockSpec pipeline on the
+    operand) and asks Mosaic for the scoped VMEM its shapes need; past
+    the module's cap the call is today's streamed one."""
+    from aot_kernels import kernel_cases
+
+    fn, args = kernel_cases()[case]()
+    bwd, = [p for p in _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+            if p["name"] == "gru_scan_bwd"]
+    assert bwd["metadata"]["variant"] == variant
+    w = bwd["grid_mapping"].block_mappings[4]
+    limit = bwd["compiler_params"].get("mosaic_tpu")
+    if limit_mib is None:
+        assert "vmem" in str(w.block_aval) and limit is None
+        assert [d.block_size for d in w.block_shape] == [1760, 512]
+    else:
+        assert "any" in str(w.block_aval)
+        assert [d.block_size for d in w.block_shape] == [1760, 5632]
+        assert limit.vmem_limit_bytes == limit_mib * 2 ** 20
+
+
+def test_every_backward_scan_of_the_ds2_full_step_is_pinned(monkeypatch):
+    """ds2_full.train_1chip's model (7 BiGRU-1760, bf16, b=32 in the
+    1700-frame bucket), forward and gradient, lowered for the TPU as
+    the chip resolves it: 14 forward scans whose weights XLA places,
+    and 14 backward scans that all place their own. None is left to
+    the lottery that made six of them stream 19.8 MB a time step."""
+    from collections import Counter
+
+    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.models import create_model
+
+    monkeypatch.setenv("DS2N_ASSUME_TPU", "1")
+    model = create_model(get_config("ds2_full").model)
+    x, lens = S((32, 1700, 161), jnp.float32), S((32,), jnp.int32)
+    variables = jax.eval_shape(
+        lambda x_, l_: model.init(jax.random.PRNGKey(0), x_, l_,
+                                  train=False), x, lens)
+
+    def grads(v, x_, l_):
+        return jax.grad(lambda p: jnp.sum(model.apply(
+            {**v, "params": p}, x_, l_, train=False)[0]
+            .astype(jnp.float32)))(v["params"])
+
+    got = Counter((f["kernel"], f["variant"], f["reverse"], f["t"], f["b"])
+                  for f in lowered_facts(grads, (variables, x, lens)))
+    assert got == {
+        ("gru_scan_fwd", "blocked", r, "850", "32"): 7 for r in "01"} | {
+        ("gru_scan_bwd", "blocked_pinned", r, "850", "32"): 7 for r in "01"}
 
 
 def test_the_roles_no_routed_case_reaches():

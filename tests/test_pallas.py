@@ -380,6 +380,84 @@ def test_gru_pallas_blocked_grads_match_scan(force_blocked, reverse, h):
                                    rtol=1e-4, atol=1e-4, err_msg=name)
 
 
+def _scan_variants(fn, *args):
+    """``variant`` of every Pallas scan call ``fn`` traces to, in
+    program order (the fact ops/kernel_id.py lowers with the call)."""
+    return [str(e.params["metadata"]["variant"])
+            for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("dot_dtype", [None, "bfloat16"])
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("h", [12, 176])  # 36 -> 128 cols / 528 -> 1024
+def test_gru_blocked_bwd_copy_once_bit_identical_to_streamed(
+        force_blocked, monkeypatch, reverse, h, dot_dtype):
+    """Who puts the recurrent matrix into VMEM changes nothing of the
+    mathematics: the copy-once build of the blocked backward kernel
+    (one DMA into a scratch, column blocks sliced from it) and the
+    streamed build (a BlockSpec pipeline on the operand, taken when
+    the call's need passes the cap) give the same bits, and both stay
+    within the blocked kernels' tolerance of the XLA scan."""
+    from deepspeech_tpu.ops import rnn_pallas
+
+    rng = np.random.default_rng(24)
+    xproj, mask, w_h, b_h = _rand_gru(rng, 2, 7, h)
+
+    def grads(scan):
+        return jax.grad(
+            lambda xp, wh, bh: jnp.sum(scan(xp, wh, bh) ** 2),
+            argnums=(0, 1, 2))(xproj, w_h, b_h)
+
+    def pallas(xp, wh, bh):
+        return gru_scan_pallas(xp, mask, wh, bh, reverse, True, dot_dtype)
+
+    assert _scan_variants(lambda: grads(pallas)) == [
+        "blocked", "blocked_pinned"]
+    pinned = grads(pallas)
+    monkeypatch.setattr(rnn_pallas, "_PINNED_VMEM_CAP", 0)
+    assert _scan_variants(lambda: grads(pallas)) == ["blocked", "blocked"]
+    streamed = grads(pallas)
+    for a, b_, name in zip(pinned, streamed, ["dxproj", "dw_h", "db_h"]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_),
+                                      err_msg=name)
+
+    dot = None if dot_dtype is None else jnp.bfloat16
+    oracle = grads(lambda xp, wh, bh: gru_scan(
+        xp, mask, wh, bh, reverse=reverse, dot_dtype=dot))
+    tol = 1e-4 if dot_dtype is None else 0.08
+    for a, b_, name in zip(pinned, oracle, ["dxproj", "dw_h", "db_h"]):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b_), rtol=tol,
+            atol=tol * max(1.0, float(jnp.abs(b_).max())), err_msg=name)
+
+
+def test_gru_blocked_bwd_streams_when_the_matrix_passes_the_cap():
+    """The choice is made from the call's shapes: ds2_full's layer
+    (H=1760, b=32) in bf16 is pinned under 32 MiB of scoped VMEM; with
+    float32 dots its 39.6 MB of weights pass the cap and the call that
+    is lowered (interpret off) is the streamed build."""
+    from deepspeech_tpu.ops import rnn_pallas
+
+    S = jax.ShapeDtypeStruct
+    b, t, h = 32, 850, 1760
+    args = (S((b, t, 3 * h), jnp.bfloat16), S((b, t), jnp.float32),
+            S((h, 3 * h), jnp.float32), S((3 * h,), jnp.float32))
+
+    def variants(dot_dtype):
+        def train(xp, m, w, bh):
+            ys, vjp = jax.vjp(lambda *a: gru_scan_pallas(
+                *a, False, False, dot_dtype), xp, m, w, bh)
+            return vjp(ys)
+
+        return _scan_variants(train, *args)
+
+    assert variants("bfloat16") == ["blocked", "blocked_pinned"]
+    assert variants(None) == ["blocked", "blocked"]
+    assert rnn_pallas._pinned_bwd_vmem_limit(b, h, 2, 2) == 32 * 2 ** 20
+    assert rnn_pallas._pinned_bwd_vmem_limit(b, h, 2, 4) is None
+
+
 def test_gru_pallas_blocked_respects_mask(force_blocked):
     rng = np.random.default_rng(22)
     xproj, mask, w_h, b_h = _rand_gru(rng, 2, 10, 8)

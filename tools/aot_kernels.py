@@ -69,13 +69,14 @@ def kernel_cases():
 
     cases = {}
 
-    def gru_case(h):
-        xp, m, w, bh = rnnshapes(h, 3)
+    def gru_case(h, b_=b, t_=t, xdt=jnp.float32, dot="bfloat16"):
+        _, _, w, bh = rnnshapes(h, 3)
+        xp, m = S((b_, t_, 3 * h), xdt), S((b_, t_), jnp.float32)
 
         def f():
             def step(xp_, m_, w_, bh_):
                 return rp.gru_scan_pallas(xp_, m_, w_, bh_,
-                                          dot_dtype="bfloat16")
+                                          dot_dtype=dot)
 
             def train(xp_, m_, w_, bh_):
                 ys, vjp = jax.vjp(step, xp_, m_, w_, bh_)
@@ -212,6 +213,14 @@ def kernel_cases():
 
     cases["gru_h800"] = gru_case(800)
     cases["gru_h1760"] = gru_case(1760)
+    # ds2_full.train_1chip's own call (850 post-conv frames, bf16
+    # xproj) and twice its rows: the backward call copies its weights
+    # into VMEM once and asks for 32 / 40 MiB of scoped VMEM.
+    cases["gru_h1760_b32"] = gru_case(1760, 32, 850, jnp.bfloat16)
+    cases["gru_h1760_b64"] = gru_case(1760, 64, 850, jnp.bfloat16)
+    # float32 dots: 39.6 MB of weights, past what the backward call may
+    # pin, so it streams its column blocks as before.
+    cases["gru_h1760_f32"] = gru_case(1760, dot="float32")
     cases["gru_stream_h800"] = gru_stream_case(800)
     cases["lstm_h800"] = lstm_case(800)
     cases["lstm_h1536"] = lstm_case(1536)

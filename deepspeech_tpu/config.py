@@ -63,9 +63,11 @@ class ModelConfig:
     #   "auto"   - fused Pallas cell on TPU, XLA scan elsewhere
     #   "xla"    - lax.scan over a jnp cell (reference / oracle path)
     #   "pallas" - fused Pallas cell (interpreter mode off-TPU)
-    # The on-TPU winner was chosen by measurement (chip_results.jsonl,
-    # r2): fused cell matches XLA forward and is 1.2-1.4x faster on the
-    # backward at both H=800 (resident) and H=1760 (blocked streaming).
+    # Every cell of BENCHMARK.json runs "auto" = the fused cell
+    # (`correct` checks rnn_impl_pallas). Against the XLA scan on this
+    # chip: measured for the LSTM-with-projection stack only (PERF.md
+    # section 6, PR 26: 712.6 -> 307.9 ms a step); the GRU stack: not
+    # measured.
     rnn_impl: str = "auto"
     # XLA-scan path only: >0 bounds the backward pass's per-step
     # residual memory to this many timesteps via chunked
@@ -195,9 +197,10 @@ class TrainConfig:
     # reference (SURVEY §2 parallelism table: DP-only, no ZeRO).
     zero_opt_sharding: bool = False
     # "auto" (Pallas kernel on TPU, jnp oracle elsewhere) | "jnp" |
-    # "pallas". The on-TPU winner was chosen by measurement
-    # (chip_results.jsonl, r2): the Pallas CTC kernel beats the jnp
-    # oracle ~1.7x fwd / ~1.9x grad at EN and AISHELL shapes.
+    # "pallas". On the TPU "auto" takes the Pallas kernel: 1.1 ms of an
+    # 893 ms step in ds2_full.train_1chip (ledger PR 27,
+    # `ctc_kernel_ms`); against the jnp oracle on this chip: not
+    # measured.
     loss_impl: str = "auto"
     # Training objective / model family: "ctc" (the DS2 stack) or
     # "rnnt" (transducer: models/transducer.RNNTModel trained through
@@ -366,12 +369,11 @@ def aishell() -> Config:
     Big vocab (~4.3k chars + blank) stresses the CTC kernel's V dimension
     and motivates model-axis sharding of the output head.
 
-    On-device beam search at this scale measured on TPU v5e (r2,
-    tools/chip_results.jsonl; B=8, T=400, V=4336, W=128): prune_top_k
-    20 -> 813 ms/batch (9.8 utt/s), 40 -> 1533 ms, 80 -> 2911 ms, and
-    a second bucket shape compiles once (~8 s) with no recompile storm.
-    The default prune_top_k=40 keeps decode exactness headroom; drop to
-    20 for 2x faster decode when the top-20 symbols per frame suffice.
+    On-device beam search at this scale (B=8, W=128): the merge's cost
+    grows with prune_top_k; its time on this chip is not measured
+    (PERF.md section 7 keeps `aishell.decode_beam_w128` as an open
+    cell). The default prune_top_k=40 keeps decode exactness headroom;
+    20 suffices when the top-20 symbols per frame do.
     """
     c = Config(name="aishell")
     return _replace(

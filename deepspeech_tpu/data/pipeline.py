@@ -255,8 +255,8 @@ class DataPipeline:
         """``cache``: override the size heuristic for the feature cache
         (None = cache iff the corpus fits MAX_CACHED_UTTS). cache=False
         forces the big-corpus path — fresh featurization per batch via
-        the native loader when available — which bench.py's pipeline
-        mode uses to measure the real host-input cost at any size."""
+        the native loader when available — the real host-input cost
+        at any size."""
         self.cfg = cfg
         self.tokenizer = tokenizer
         if utterances is None:

@@ -1,7 +1,7 @@
 """Synthetic speech-like data for tests and benchmarks.
 
 No LibriSpeech audio ships in this environment, so the end-to-end tests
-(SURVEY.md §4.6 overfit gate) and ``bench.py`` run on a deterministic
+(SURVEY.md §4.6 overfit gate) and ``train --synthetic`` run on a deterministic
 synthetic task: each "utterance" is a feature sequence whose frames
 encode its label sequence through a fixed random linear map plus noise —
 learnable by the real model, shaped like real batches.

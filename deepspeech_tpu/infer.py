@@ -139,7 +139,7 @@ class Inferencer:
         self._stream_quantize = ""
         # How many times THIS engine ran PTQ (0 or 1): quantization is
         # an init-time cost, never a per-request one — the
-        # quant_serving bench reads this per replica. Streaming mode
+        # two-tier scenario (tests/test_quantize.py) reads this per replica. Streaming mode
         # defers to the StreamingTranscriber's own PTQ; that call is
         # counted here too (see _decode_streaming).
         self.quantize_calls = 0
@@ -221,7 +221,7 @@ class Inferencer:
 
             keep_q = keep_recurrent_q(cfg.model)
         # Which regime this replica's recurrence runs in ("resident-q"
-        # / "blocked-q" / "fp") — the quant_serving bench records it
+        # / "blocked-q" / "fp") — the two-tier scenario records it
         # per replica to attribute throughput to the kernel path.
         from .utils.quantize import kernel_regime
 

@@ -20,12 +20,12 @@ The accounting is transition-based: :meth:`TraceContext.to` attributes
 closes the last phase with the same clock value the scheduler uses for
 the result's latency. The intervals therefore telescope — the phase
 parts sum to the measured latency to float rounding, which
-``bench.py --bench=serve_traffic`` asserts for 100% of finished
-requests (``trace_complete_pct``).
+``tests/test_serving.py``
+``test_scenario_traffic_replay_accounts_for_every_request`` asserts for
+every finished request.
 
 Context bookkeeping is always on (it is a handful of dict ops per
-request; ``--bench=obs_overhead`` pins the cost under 1% of the CPU
-serve path). The JSONL ``{"event": "trace", ...}`` record only leaves
+request; its cost is not measured). The JSONL ``{"event": "trace", ...}`` record only leaves
 the process when the tracer is enabled — bit-identical transcripts
 either way, since nothing downstream reads the context.
 

@@ -18,7 +18,8 @@ breaker closed" as ONE story. This module is that story's ledger:
   Installation mirrors ``resilience.faults``: :func:`install` /
   :func:`clear` / :func:`active`, and the module-level :func:`publish`
   is ONE global read when no log is installed — the production-default
-  cost, measured by ``--bench=obs_overhead``.
+  path (returns None, records nothing: ``tests/test_obs.py``
+  ``test_scenario_disabled_hooks_hand_out_noops_and_record_nothing``).
 - :class:`IncidentCorrelator` — folds causally-linked events into
   **incidents**: a root event (fault fire, breaker open, SLO alert,
   guardian skip), the ordered action chain that reacted to it, the
@@ -27,7 +28,9 @@ breaker closed" as ONE story. This module is that story's ledger:
   a ``kind="incident"`` postmortem (via the ``postmortem_link`` seam)
   plus ``incidents_opened`` / ``incidents_resolved`` counters. A
   reaction-kind event with NO causal edge at all is an **orphan** —
-  the lint signal ``--bench=incident_timeline`` drives to zero.
+  the lint signal ``tests/test_timeline.py``
+  ``test_scenario_fault_day_through_real_controllers_is_one_incident``
+  holds at zero.
 - :class:`MetricSeries` — a small flight-recorder ring sampling
   configured counter/gauge *families* (queue fill, pressure,
   availability, ``warm_pct``) on an injectable cadence, so each
@@ -65,8 +68,8 @@ ROOT_KINDS = frozenset({
 })
 
 # Kinds that only ever happen as a REACTION to something: one of these
-# with no causal edge at all is an orphan — the correlation gap
-# --bench=incident_timeline asserts to zero.
+# with no causal edge at all is an orphan — the correlation gap the
+# fault-day scenario of tests/test_timeline.py asserts to zero.
 REACTION_KINDS = frozenset({
     "migration", "migration_fallback", "drain_cancel",
     "rollout_rollback", "guardian_rollback",

@@ -25,9 +25,10 @@ time only stamps ``ts``); nesting is tracked per thread, so gateway
 dispatch spans on a worker thread never adopt a train-loop parent.
 
 DISABLED BY DEFAULT. ``span()`` on a disabled tracer returns a shared
-no-op context manager — one attribute read, no allocation — which is
-what keeps ``bench.py --bench=obs_overhead`` under 1% of a CPU train
-step. Enable with ``configure(jsonl_path=...)`` or by exporting
+no-op context manager — one attribute read, no allocation, nothing
+recorded (``tests/test_obs.py``
+``test_scenario_disabled_hooks_hand_out_noops_and_record_nothing``).
+Enable with ``configure(jsonl_path=...)`` or by exporting
 ``DS2_TRACE=/path``; read the output with ``tools/trace_report.py``.
 """
 

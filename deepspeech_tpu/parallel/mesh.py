@@ -170,7 +170,7 @@ def shard_batchwise(fn, mesh: Optional[Mesh], n_sharded: int):
     The first ``n_sharded`` positional args are split on their leading
     (batch) dim; the rest (weights/scalars) are replicated. All outputs
     are batch-leading. No-op for single-device data axes — the
-    single-chip hot path measured in tools/chip_results.jsonl stays
+    single-chip hot path (``ds2_full.train_1chip``) stays
     byte-identical.
     """
     if mesh is None or mesh.shape[DATA_AXIS] == 1:

@@ -30,11 +30,13 @@ for. Four modules, one per concern:
   rollback, stall), shared by the data pipeline, the guardian, and the
   serving scheduler.
 
-End-to-end validation: ``bench.py --bench=chaos_traffic`` replays the
-serve_traffic workload under an injected fault schedule and reports
-availability, p95-under-fault, and breaker recovery time;
-``--bench=train_chaos`` replays a seeded divergence/corruption plan
-through the guarded trainer and asserts rollback bit-identity.
+End-to-end validation (``tests/test_resilience.py``):
+``test_scenario_traffic_under_fault_plan_loses_nothing`` replays
+modeled traffic under an injected fault schedule (nothing lost, breaker
+opens and recovers, transcripts unchanged);
+``test_scenario_training_survives_poison_and_leaves_no_trace`` runs a
+seeded divergence/corruption plan through the guarded trainer and
+asserts rollback bit-identity.
 """
 
 from . import faults, postmortem

@@ -1,9 +1,8 @@
 """Deterministic fault injection: a process-wide ``FaultPlan``.
 
-Chaos testing needs the failure, not the outage: the recorded bench
-runs (``BENCH_r05.json``) show the real failure modes — a backend that
-never comes up, a decode that throws mid-batch, a checkpoint cut off
-mid-write — but none of them can be *scheduled*, so none of the
+Chaos testing needs the failure, not the outage: the real failure
+modes — a decode that throws mid-batch, a checkpoint cut off
+mid-write, a peer that stops answering — cannot be *scheduled*, so none of the
 recovery paths can be regression-tested. This module is the scheduler
 for failures.
 
@@ -16,7 +15,6 @@ bound to a named **injection point** (a call site that opted in via
 - ``pipeline.materialize``  — data/pipeline.py, per materialized batch
   (``corrupt_batch`` poisons a sample for the quarantine scrubber)
 - ``checkpoint.save`` / ``checkpoint.restore`` — checkpoint.py
-- ``backend.init``          — bench.py's backend probe
 - ``train.step``            — train.py, before each guarded step
   (``nan_grad`` poisons the batch so the loss/grads go non-finite)
 - ``rollout.swap`` / ``rollout.canary`` — serving/rollout.py, around
@@ -54,7 +52,7 @@ Six fault kinds:
 Determinism: firing decisions come from one seeded ``random.Random``
 and a plan-relative clock (``clock() - started_at``; the clock is
 injectable), so a plan replays identically under a virtual clock. For
-*step-exact* schedules (the train-chaos bench), ``skip`` counts down
+*step-exact* schedules (the training scenario), ``skip`` counts down
 would-fire checks before the first real fire — e.g. ``skip=10,
 count=2`` fires on exactly the 11th and 12th eligible checks at that
 point, independent of wall time.
@@ -65,7 +63,7 @@ Every fire is counted in the plan's metrics registry as
 of a wall-clock window, a spec may be *armed* by a named controller
 event — the serving controllers call :func:`notify` as they act
 (``autoscale.scale_up``, ``autoscale.drain_begin``,
-``rollout.swap_begin``, the bench replay's ``traffic.burst``, the
+``rollout.swap_begin``, a replay's ``traffic.burst``, the
 ``RecoveryController``'s ``recovery.begin``/``recovery.done`` bracket
 around each boot-time journal replay, the remote migration
 controller's ``migration.remote_begin`` as a cross-process transfer
@@ -96,8 +94,9 @@ Configuration is env/JSON: export ``DS2_FAULT_PLAN=/path/plan.json``
     faults.clear()
 
 When no plan is installed (the production default) :func:`inject` is
-one module-global read — measured by ``bench --bench=obs_overhead``
-against the <1 %% overhead bar.
+one module-global read that returns None and counts nothing
+(``tests/test_obs.py``
+``test_scenario_disabled_hooks_hand_out_noops_and_record_nothing``).
 """
 
 from __future__ import annotations
@@ -122,7 +121,7 @@ KINDS = ("error", "unavailable", "latency", "partial_write",
 # never fires.
 KNOWN_POINTS = ("gateway.dispatch", "pipeline.device_prefetch",
                 "pipeline.materialize", "checkpoint.save",
-                "checkpoint.restore", "backend.init", "train.step",
+                "checkpoint.restore", "train.step",
                 "rollout.swap", "rollout.canary",
                 "journal.append", "journal.recover",
                 "transport.send", "transport.recv", "transport.ack")

@@ -11,7 +11,7 @@ audited recovery instead of a dead run:
    update, and *gates the state transition on device*: a non-finite
    step keeps the previous params/opt-state/BN stats bit-exactly
    (``jnp.where`` on every leaf), so a skipped batch is a true no-op —
-   the property the rollback bit-identity bench rests on.
+   the property the rollback bit-identity scenario rests on.
 2. **Classification.** Each step is ``ok`` / ``soft-anomaly`` (finite
    but the grad-norm spikes ``soft_grad_factor``× above the rolling
    median kept in the obs ``MetricsRegistry``) / ``hard-anomaly``
@@ -37,11 +37,11 @@ registry (``guardian_skipped_batches``, ``guardian_soft_anomalies``,
 ``stall_watchdog_fires``). Knobs ride ``DS2_GUARDIAN`` (``1`` =
 defaults, a JSON object or a path to one = overrides — see
 :class:`GuardianConfig`); chaos coverage comes from the ``nan_grad`` /
-``corrupt_batch`` fault kinds and ``bench.py --bench=train_chaos``.
+``corrupt_batch`` fault kinds and ``tests/test_resilience.py``
+``test_scenario_training_survives_poison_and_leaves_no_trace``.
 
 Disabled (the default), the training loop's only cost is one
-``is not None`` test per step — measured by ``--bench=obs_overhead``
-against the <1% bar.
+``is not None`` test per step.
 """
 
 from __future__ import annotations
@@ -152,7 +152,7 @@ class TrainingGuardian:
         # of the rollback it may escalate into.
         self._last_skip_seq: Optional[int] = None
         # Batch ordinals whose updates currently stand (rollback
-        # truncates) — the surviving-batch list the bit-identity bench
+        # truncates) — the surviving-batch list the bit-identity scenario
         # replays.
         self.applied: List[int] = []
 

@@ -5,7 +5,7 @@ human can audit afterwards: which utterance was quarantined and why,
 which step tripped the guardian, what the thread stacks looked like
 when the watchdog fired. A :class:`PostmortemWriter` appends one JSONL
 line per intervention and keeps a bounded in-memory tail for callers
-(the chaos bench, tests) that never configure a file.
+(tests) that never configure a file.
 
 Record schema (linted by ``tools/check_obs_schema.py``, which knows
 ``event == "postmortem"`` as its own record type)::
@@ -138,7 +138,7 @@ def writer() -> PostmortemWriter:
 
 def configure(path: Optional[str] = None, sink: Optional[IO[str]] = None,
               registry=None) -> PostmortemWriter:
-    """Replace the process-wide writer (tests, bench phases)."""
+    """Replace the process-wide writer (tests)."""
     global _DEFAULT
     with _DEFAULT_LOCK:
         if _DEFAULT is not None:

@@ -1,8 +1,8 @@
 """Unified retry/backoff and circuit-breaker primitives.
 
-Before this module every caller rolled its own recovery: bench.py
-slept a hardcoded 45 s once, the gateway requeued failed batches with
-zero backoff, checkpointing had none at all. These two classes are the
+Before this module every caller rolled its own recovery: the gateway
+requeued failed batches with zero backoff, checkpointing had none at
+all. These two classes are the
 shared vocabulary:
 
 - :class:`Retry` — bounded attempts with exponential backoff and
@@ -23,7 +23,7 @@ shared vocabulary:
   (``circuit_state{name=...}``: 0 closed / 1 half-open / 2 open) and
   transitions are kept on the instance for recovery-time reporting.
 
-Both take injectable clock/sleep/rng so tests and the chaos bench are
+Both take injectable clock/sleep/rng so tests and chaos scenarios are
 deterministic and fast.
 """
 
@@ -98,7 +98,7 @@ class Retry:
         Non-retryable errors propagate immediately; retryable ones are
         counted, backed off, and re-raised once attempts or the sleep
         budget run out. ``on_retry(attempt, exc, delay)`` fires before
-        each sleep (bench logging hook).
+        each sleep (logging hook).
         """
         labels = {"name": self.name}
         slept = 0.0
@@ -158,7 +158,7 @@ class CircuitBreaker:
         self.opened_at: Optional[float] = None
         self._probes_in_flight = 0
         self.opens = 0
-        # (t, state) transition log — the chaos bench reads recovery
+        # (t, state) transition log — the chaos scenario reads recovery
         # time (last open -> following close) straight off this.
         self.transitions: List[Tuple[float, str]] = []
 

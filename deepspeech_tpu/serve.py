@@ -88,7 +88,7 @@ folds the causally-linked events into incidents live (scraped at
 ``tools/incident_report.py`` reconstructs the same incidents offline
 from the JSONL. Either ``--timeline`` or ``--status-port`` alone turns
 the ledger on; with neither flag the publish hooks are a single module
-global read (measured by ``bench.py --bench=obs_overhead``).
+global read.
 
 Crash-durable sessions: ``--session-journal=DIR`` attaches a
 write-ahead :class:`~.serving.sessionstore.SessionJournal` — every
@@ -415,8 +415,8 @@ def serve_files_pooled(cfg, tokenizer, params, batch_stats,
     AutoscaleController` ticks once per chunk, free to resize the pool
     between ``autoscale_min`` and ``autoscale_max`` replicas on the
     ``obs`` pressure signals (here: the worst ``slo_burn_rate`` gauge
-    — file replay has no admission queue; the gateway signals live on
-    ``bench.py --bench=autoscale``). Every controller event is one
+    — file replay has no admission queue; the gateway signals are
+    driven in ``tests/test_autoscale.py``). Every controller event is one
     ``{"autoscale": {...}}`` JSONL line (``tools/autoscale_report.py``
     renders the timeline); sessions re-pin at most once per resize via
     the consistent-hash ring, and the controller holds off while the

@@ -30,7 +30,9 @@ work into those ladder-shaped batches:
   brownout level, SLO burn) and resizes the pool through a hysteresis
   state machine with drain-before-remove; :class:`TrafficModel`
   generates the deterministic diurnal/bursty/heavy-tailed arrival
-  schedules the ``--bench=autoscale`` replay proves it against;
+  schedules ``tests/test_autoscale.py``
+  ``test_scenario_modeled_day_scales_up_and_down_losing_nothing``
+  replays it against;
 - :mod:`.registry` / :mod:`.tenancy` — the multi-model multi-tenant
   gateway: :class:`ModelRegistry` maps ``model_id`` to a
   :class:`ModelGroup` (its own pool, rung ladder, controller scope;
@@ -70,7 +72,7 @@ work into those ladder-shaped batches:
   degradation) which emits :class:`RevisionEvent` streams — the
   ``{"revision": ...}`` JSONL lines beside the original transcripts;
 - :mod:`.telemetry` — counters/gauges/histograms for all of it,
-  emitted as JSONL and consumed by ``bench.py --bench=serve_traffic``;
+  emitted as JSONL (linted by ``tools/check_obs_schema.py``);
 - :mod:`.ladder` — tier-aware rung-ladder sizing: converts measured
   parameter footprints (bf16 vs int8 PTQ) plus a per-row cost into
   per-tier max-B heights under an HBM budget.

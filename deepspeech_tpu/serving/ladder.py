@@ -13,9 +13,11 @@ HBM headroom → throughput conversion this module prices.
 B rung whose footprint fits this budget", and
 :func:`tier_max_batches` applies it per tier from a PTQ report's
 measured byte counts, producing the ``tier_max_batch`` map the
-:class:`~.scheduler.MicroBatchScheduler` flushes by. The
-``--bench=quant_serving`` ladder-height leg asserts the int8 tier's
-rung strictly exceeds the bf16 tier's under the same synthetic budget.
+:class:`~.scheduler.MicroBatchScheduler` flushes by.
+``tests/test_quantize.py``
+``test_scenario_two_tier_pool_quantizes_once_and_keeps_tiers_apart``
+asserts the int8 tier's rung strictly exceeds the bf16 tier's under
+the same synthetic budget.
 
 Beyond the resident footprint, blocked-regime replicas also RESERVE
 bandwidth-backed working bytes: when the recurrent matrices miss the
@@ -27,8 +29,8 @@ prices that term per regime (0 once resident; the stored-width matrix
 otherwise), and ``tier_max_batches(..., stream_bytes=...)`` charges it
 before sizing the rung. With the s8-streaming kernels the bulk tier's
 term drops 4× (or to zero where int8 newly fits residency), which is
-how in-kernel dequant converts to a taller bulk ladder — the
-``--bench=quant_serving`` streamed-bytes leg proves the rise.
+how in-kernel dequant converts to a taller bulk ladder
+(``tests/test_ops_quant_blocked.py`` ``test_stream_ladder_bulk_rises``).
 """
 
 from __future__ import annotations

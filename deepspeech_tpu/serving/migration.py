@@ -106,7 +106,8 @@ class MigrationController:
         self.migrations = 0
         self.fallbacks = 0
         # Per-session handoff counts: the ≤1-per-topology-change
-        # accounting --bench=migration asserts.
+        # accounting tests/test_migration.py
+        # test_scenario_mass_repin_twice asserts.
         self.per_session: Dict[str, int] = {}
         self.events: List[dict] = []
 

@@ -436,9 +436,8 @@ def synthetic_replicas(n: int, service_s_per_row: float = 0.0, *,
                        clock: Callable[[], float] = time.monotonic
                        ) -> List[Replica]:
     """N replicas over a synthetic timed backend (``sleep``-based cost
-    model, texts deterministic in the request lengths) — the scaling
-    pipeline for ``bench.py --bench=serve_traffic`` BENCH_REPLICAS and
-    for tests that need wall-clock overlap without a model."""
+    model, texts deterministic in the request lengths) — for tests
+    that need wall-clock overlap without a model."""
     tel = telemetry if telemetry is not None else ServingTelemetry()
 
     def make_fn():

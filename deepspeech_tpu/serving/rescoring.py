@@ -42,9 +42,10 @@ plane rather than as a batch job):
 The pool is **pump-driven and synchronous**, like every controller in
 this plane (scheduler ``pump()``, rollout/autoscale ``tick()``): the
 host decides when slow-path compute runs (between chunks, after a
-flush, on an idle beat) and the injectable clock makes every bench
-leg deterministic — two same-seed replays produce bit-identical
-revision streams, which ``bench.py --bench=rescoring`` asserts.
+flush, on an idle beat) and the injectable clock makes every replay
+deterministic — two same-script replays produce bit-identical
+revision streams, which ``tests/test_rescoring.py``
+``test_scenario_slow_path_costs_the_fast_path_nothing`` asserts.
 "Workers" are logical LM owners (``lm_factory`` is called once per
 worker; jobs are assigned round-robin at submit time so the
 job→worker mapping is replay-stable), not threads: LM scoring is

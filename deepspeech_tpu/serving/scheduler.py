@@ -54,7 +54,7 @@ Failure handling (deepspeech_tpu/resilience):
   pipeline corrupt-sample quarantine feed;
 - the ``gateway.dispatch`` fault-injection point
   (``resilience.faults``) sits inside the decode try block, so the
-  chaos bench exercises exactly these paths.
+  chaos scenario (tests/test_resilience.py) exercises these paths.
 
 The scheduler's *state* is synchronous and single-threaded by design —
 the gateway loop is one host thread pumping between jitted calls, and
@@ -370,7 +370,7 @@ class MicroBatchScheduler:
         # inactive (the default; the controller installs/clears it).
         self.tier_shift: Dict[str, str] = {}
         # Finished-request trace summaries land here (and, tracing on,
-        # in the JSONL stream). Benches pass a private ring per leg;
+        # in the JSONL stream). Tests pass a private ring each;
         # the default is the process-wide one the status server reads.
         self.flight_recorder = flight_recorder \
             if flight_recorder is not None else obs.flight_recorder()

@@ -51,7 +51,9 @@ one process; this module makes it survive the process:
   ``recovery_latency`` observation, published as ``kind="recovery"``
   timeline events (begin → one per session → ``recovery_done``, all
   causally threaded) and summarized in one ``kind="crash_recovery"``
-  postmortem. ``--bench=crash_recovery`` proves the whole plane;
+  postmortem. ``tests/test_sessionstore.py``
+  ``test_scenario_crash_midstream_cold_restart_is_bit_identical``
+  drives the whole plane with real sessions;
   ``tools/journal_report.py`` inspects a journal offline.
 
 This module is deliberately stdlib + numpy at import time (package

@@ -4,9 +4,9 @@ Historically this module owned the only metrics sink in the repo; the
 implementation now lives in ``deepspeech_tpu/obs/metrics.py`` as the
 shared, thread-safe :class:`~deepspeech_tpu.obs.MetricsRegistry`, and
 this module is a thin compatibility shim: the scheduler/session
-manager keep their ``telemetry.count(...)`` call sites and
-``bench.py --bench=serve_traffic`` keeps its exact output shape
-(``snapshot()`` dict and the ``"serving_telemetry"`` JSONL event),
+manager keep their ``telemetry.count(...)`` call sites and the
+output shape stays (``snapshot()`` dict and the
+``"serving_telemetry"`` JSONL event),
 while gaining the registry's labels, ``render_text()`` exposition and
 the drift-free reservoir ``Histogram``.
 
@@ -19,7 +19,7 @@ Conventions (unchanged):
   live-traffic complement of ``ShapeBucketCache.rung_usage()``.
 
 ``snapshot()`` returns one JSON-ready dict; ``emit_jsonl()`` appends it
-as one line, the format ``bench.py --bench=serve_traffic`` consumes.
+as one line, the format ``tools/check_obs_schema.py`` lints.
 """
 
 from __future__ import annotations

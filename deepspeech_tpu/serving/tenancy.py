@@ -11,7 +11,7 @@ module is the gateway's admission layer:
   streaming router). Past the quota, :meth:`AdmissionController.charge`
   raises :class:`TenantQuotaExceeded` — a subclass of
   :class:`~.scheduler.OverloadRejected`, so every existing shed path
-  (bench accounting, serve loops) handles it unchanged;
+  (test accounting, serve loops) handles it unchanged;
 - **priority classes** ``realtime | standard | batch`` — each class
   carries a default relative deadline (realtime tightest), which is
   exactly what the scheduler's oldest-deadline flush rule consumes: a
@@ -207,7 +207,7 @@ class AdmissionController:
         return self._inflight.get(tenant, 0)
 
     def peak(self, tenant: str) -> int:
-        """High-water admitted units — the bench's "admission never
+        """High-water admitted units — the tenancy scenario's "admission never
         exceeded quota" evidence."""
         return self._peak.get(tenant, 0)
 

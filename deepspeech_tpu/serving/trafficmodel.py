@@ -1,13 +1,13 @@
-"""Deterministic traffic model: make replay benches tell the truth.
+"""Deterministic traffic model: make traffic replays tell the truth.
 
-``bench.py --bench=serve_traffic`` replays a flat Poisson process —
+A flat Poisson process (``tests/scenario.py`` ``poisson_requests``) is
 useful for exercising the gateway, useless for sizing a fleet. Real
 speech traffic from millions of users is none of that: request rate
 follows the day (diurnal curve), rides sharp social/broadcast bursts
 on top of it, utterance lengths are heavy-tailed (a few long
 dictations dominate device time), traffic splits across quality
 tiers, and streaming sessions churn continuously. This module models
-all five as one *seeded, deterministic* generator so a bench replay —
+all five as one *seeded, deterministic* generator so a replay —
 and the :class:`~.autoscale.AutoscaleController` reacting to it — is
 reproducible sample for sample:
 

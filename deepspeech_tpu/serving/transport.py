@@ -52,7 +52,8 @@ fleet timeline (``remote_begin`` / ``remote_ack`` / ``remote_fail``
 events with ``cause_seq``). Fault points ``transport.send`` /
 ``transport.recv`` / ``transport.ack`` (kinds ``latency`` /
 ``unavailable`` / ``partial_write`` tearing a frame mid-send) drive
-``--bench=xhost_migration``.
+``tests/test_transport.py``
+``test_scenario_transport_flaps_retry_or_fall_down_the_ladder``.
 """
 
 from __future__ import annotations

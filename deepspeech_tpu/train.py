@@ -324,7 +324,7 @@ def make_train_step(cfg: Config, model, optimizer, mesh, state_sh,
         # A bad step must be a bit-exact no-op: every leaf (params, BN
         # stats, optimizer state, step counter) falls back to its
         # previous value on device — the donated input cannot be kept
-        # host-side, and the rollback bit-identity bench depends on
+        # host-side, and the rollback bit-identity scenario depends on
         # skipped batches leaving literally no trace in the state.
         new_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
                                  new_state, state)
@@ -729,8 +729,8 @@ class Trainer:
                         # Chaos: poison the device batch so this step's
                         # loss/gradients come out non-finite — the
                         # guarded step's gate (or, unguarded, the run's
-                        # death) is exactly what --bench=train_chaos
-                        # measures.
+                        # death) is what the training scenario of
+                        # tests/test_resilience.py drives.
                         feats = sharded["features"]
                         sharded = dict(sharded, features=feats * jnp.asarray(
                             jnp.nan, feats.dtype))
@@ -935,7 +935,7 @@ def main(argv=None) -> None:
 
 
 class _SyntheticPipeline:
-    """Duck-typed DataPipeline over synthetic batches (tests/bench)."""
+    """Duck-typed DataPipeline over synthetic batches (tests, --synthetic)."""
 
     # Deterministic per-seed generation: every process holds the FULL
     # global batch, so replicated-batch mesh layouts are safe (see the

@@ -319,20 +319,38 @@ class AotStore:
 
 def serialize_compiled(compiled) -> bytes:
     """``"xc"``: pickle a loaded executable's serialized form — the
-    true zero-compile round trip (deserialize loads, never compiles)."""
+    true zero-compile round trip (deserialize loads, never compiles).
+    The ids of the devices it was compiled for travel with it: jax's
+    own payload does not say, and a load that guesses "every device of
+    the backend" builds a callable that wants one shard per device.
+    They are read off the unloaded executable that ``se.serialize``
+    pickles, so an executable compiled for a described topology
+    (``tools/aot_*.py --emit-store``) says them too. No fallback where
+    jax moves that attribute: the export raises, and every caller
+    treats an export as best-effort."""
     from jax.experimental import serialize_executable as se
 
     payload, in_tree, out_tree = se.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree))
+    device_ids = [int(d.id) for d in
+                  compiled._executable._unloaded_executable.device_list]
+    return pickle.dumps((payload, in_tree, out_tree, device_ids))
 
 
 def deserialize_compiled(blob: bytes):
     """Inverse of :func:`serialize_compiled`: a callable with the
-    original function's signature, backed by the stored executable."""
+    original function's signature, backed by the stored executable and
+    bound to the devices it was compiled for. Raises on a blob without
+    device ids (written before they were stored) or with an id this
+    backend does not have: ``WarmStore.preload_replica`` rejects the
+    entry and the rung compiles through jit."""
+    import jax
     from jax.experimental import serialize_executable as se
 
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 def serialize_exported(exported) -> bytes:

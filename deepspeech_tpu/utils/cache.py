@@ -40,8 +40,8 @@ def enable_compilation_cache() -> bool:
     """Turn on jax's persistent compile cache at :func:`resolve_cache_dir`.
 
     Returns True only when the cache is configured — callers asserting
-    "a later process will reuse this compile" (bench.py's warm markers)
-    must not claim warmth otherwise.
+    "a later process will reuse this compile" must not claim warmth
+    otherwise.
     """
     if os.environ.get("DS2_COMPILE_CACHE", "1") == "0":
         return False
@@ -208,7 +208,7 @@ class ShapeBucketCache:
         return {k: round(self._decayed(k), 6) for k in self._use}
 
     def stats(self) -> dict:
-        """JSONL-ready counter snapshot (bench.py's infer_bucketed row)."""
+        """JSONL-ready counter snapshot."""
         return {
             "compiles": self.compiles,
             "hits": self.hits,

@@ -1,10 +1,12 @@
 """Analytic FLOP accounting for the DS2 model family (VERDICT r2 #2).
 
-Converts the bench's ``utt/s/chip`` into an absolute scale: model
+Converts a rate (``utt/s/chip``) into an absolute scale: model
 flops/step -> achieved TFLOP/s -> MFU against the chip's bf16 peak.
-Without this there is no way to judge "is this fast" — the per-kernel
-speedups (chip_results.jsonl) are relative to this repo's own oracles,
-not to hardware capability (BASELINE.json:5 north-star scale clause).
+Without this there is no way to judge "is this fast" — a kernel's
+speedup over this repo's own oracle says nothing about hardware
+capability (BASELINE.json:5 north-star scale clause). The benchmark
+keeps its own copy of these counts (``benchmark/costs/``, PERF.md
+section 3: `mfu_pct`).
 
 Conventions (the standard MFU bookkeeping, e.g. the PaLM appendix):
 - A matmul [m,k]x[k,n] counts 2*m*k*n flops.

@@ -2,8 +2,9 @@
 
 Shared by the RNN stack (models/rnn.py) and the CTC loss
 (train.select_loss_fn): both expose an 'auto' | <oracle> | 'pallas'
-knob whose 'auto' value resolves to the measurement-backed winner
-(tools/chip_results.jsonl) — the Pallas kernel on real TPU, the
+knob whose 'auto' value resolves to the Pallas kernel on real TPU
+(what every cell of BENCHMARK.json runs and ``correct`` checks;
+PERF.md section 6 has what was measured against the oracles), the
 XLA/jnp oracle elsewhere so CPU CI and virtual-device meshes never
 crawl through the Pallas interpreter.
 """

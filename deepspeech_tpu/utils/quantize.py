@@ -60,7 +60,7 @@ _INT8_MAX = 127.0
 
 # Module-wide PTQ invocation count. Quantization is meant to run
 # exactly once per replica/engine at init — never per request — and
-# the quant_serving bench asserts that by reading this before/after
+# tests/test_quantize.py's two-tier scenario asserts that by reading this before/after
 # building the pool and after serving traffic.
 QUANTIZE_CALLS = 0
 
@@ -173,7 +173,7 @@ def kernel_regime(model_cfg, quantized: bool,
     ``"resident-q"`` (int8 weights VMEM-resident), ``"blocked-q"``
     (s8 column streaming with in-VMEM dequant), or ``"fp"`` (full-
     precision kernels / dequant-at-entry). Recorded per replica by the
-    quant_serving bench so throughput deltas can be attributed to the
+    two-tier serving scenario so throughput deltas can be attributed to the
     kernel path."""
     from ..ops.rnn_pallas import fits_vmem
 

@@ -33,8 +33,10 @@ import pytest
 from deepspeech_tpu.resilience import CircuitBreaker
 from deepspeech_tpu.serving import (AutoscaleController,
                                     MicroBatchScheduler,
+                                    OverloadRejected,
                                     PooledSessionRouter, Replica,
-                                    ReplicaPool, ServingTelemetry)
+                                    ReplicaPool, ServingTelemetry,
+                                    TrafficModel)
 from deepspeech_tpu.serving.autoscale import (AUTOSCALE_DRAINING,
                                               AUTOSCALE_HOLDOFF,
                                               AUTOSCALE_STEADY)
@@ -831,3 +833,112 @@ def test_run_until_steady_finishes_a_started_drain():
 
     assert ctrl.run_until_steady(pump=pump) == AUTOSCALE_STEADY
     assert len(pool) == 1 and ctrl.status()["victim"] is None
+
+
+# -- the seeded load layer ----------------------------------------------
+
+def test_traffic_model_is_seed_deterministic():
+    """The seeded load layer must replay bit-identically:
+    same seed -> the same arrivals, lengths, and session plans; a
+    different seed -> a different schedule."""
+    kw = dict(duration_s=10.0, base_rps=20.0, day_s=10.0,
+              diurnal_amplitude=0.8, burst_rate_mult=2.0,
+              session_rate=0.5)
+    a = TrafficModel(seed=7, **kw).schedule()
+    b = TrafficModel(seed=7, **kw).schedule()
+    assert a.arrivals == b.arrivals
+    assert a.sessions == b.sessions
+    assert a.summary() == b.summary()
+    assert a.arrivals and a.sessions
+    # Arrivals are time-ordered with lengths inside the clip band.
+    ts = [arr.t for arr in a.arrivals]
+    assert ts == sorted(ts) and ts[-1] <= 10.0
+    assert all(16 <= arr.feat_len <= 1600 for arr in a.arrivals)
+    c = TrafficModel(seed=8, **kw).schedule()
+    assert c.arrivals != a.arrivals
+
+
+# -- scenario: one modeled day, closed loop -------------------------------
+
+def test_scenario_modeled_day_scales_up_and_down_losing_nothing(obs_lint, postmortems):
+    """One compressed day of seeded ``TrafficModel`` traffic through a
+    real scheduler and pool on the virtual clock, the controller
+    ticking every 50 ms between arrivals and dispatch, with pinned
+    sessions fed a chunk a tick: the fleet grows under the burst and
+    drains back in the trough, one replica an episode; no request and
+    no session chunk is lost across the resizes; no session is re-pinned
+    more often than the fleet resized; and the telemetry with its
+    ``autoscale`` postmortems lints clean."""
+    import math
+
+
+    day = 6.0
+    arrivals = TrafficModel(
+        seed=0, duration_s=day, base_rps=26.0, day_s=day,
+        diurnal_amplitude=0.9, burst_rate_mult=2.5, burst_enter_p=0.25,
+        burst_exit_p=0.2, burst_step_s=0.25,
+        len_log_mean=math.log(64.0), len_log_sigma=0.5, len_min=16,
+        len_max=max(EDGES), max_arrivals=260).schedule().arrivals
+    clock = Clock()
+    tel = ServingTelemetry()
+    log = []
+    pm = postmortems
+
+    def factory(rid):
+        return _replica(rid, clock, tel,
+                        session_factory=lambda: FakeMgr(log))
+
+    pool = ReplicaPool([factory("r0")], clock=clock, telemetry=tel,
+                       drain_window_s=0.15)
+    sched = MicroBatchScheduler(EDGES, 4, clock=clock, telemetry=tel,
+                                max_queue=8, default_deadline=2.5,
+                                pool=pool)
+    ctrl = _ctrl(pool, clock, tel, factory=factory, scheduler=sched,
+                 up_pressure=0.5, down_pressure=0.12, hold_s=0.1,
+                 cooldown_s=0.6, drain_window_s=0.15,
+                 postmortem_fn=pm.write)
+    router = PooledSessionRouter(pool)
+    sids = [f"s{k}" for k in range(6)]
+    homes = {sid: router.join(sid) for sid in sids}
+    moves = dict.fromkeys(sids, 0)
+
+    i = ticks = 0
+    peak = 1
+    while i < len(arrivals) or sched.pending or len(pool) > 1 \
+            or ctrl.status()["victim"] is not None:
+        clock.t += 0.05
+        while i < len(arrivals) and arrivals[i].t <= clock.t:
+            try:
+                sched.submit(_feat(arrivals[i].feat_len), rid=f"q{i}")
+            except OverloadRejected:
+                pass
+            i += 1
+        ctrl.tick()
+        peak = max(peak, len(pool))
+        sched.pump()
+        router.step({sid: f"c{ticks}" for sid in sids})
+        ticks += 1
+        for sid in sids:
+            if router.home_of(sid) != homes[sid]:
+                moves[sid] += 1
+                homes[sid] = router.home_of(sid)
+        assert ticks < 400, "the day never settled"
+    for sid in sids:
+        router.leave(sid)
+    router.flush()
+
+    assert ctrl.scale_ups >= 1 and ctrl.scale_downs >= 1
+    assert peak > len(pool) == ctrl.min_replicas
+    for ep in ctrl.episodes:
+        assert ep["direction"] in ("up", "down")
+        assert abs(ep["from_replicas"] - ep["to_replicas"]) == 1
+    c = tel.snapshot()["counters"]
+    assert int(c["admitted"]) + int(c.get("rejected", 0)) \
+        == len(arrivals)
+    assert int(c["admitted"]) == int(c["requests_ok"])
+    want = " ".join(f"c{k}" for k in range(ticks))
+    assert [router.final(sid) for sid in sids] == [want] * len(sids)
+    assert max(moves.values()) <= ctrl.scale_ups + ctrl.scale_downs
+    assert any(k.startswith("autoscale_events{") for k in c)
+    assert len(pm.recent("autoscale")) == len(ctrl.episodes)
+    assert obs_lint(tel, pm) == []

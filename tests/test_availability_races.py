@@ -31,6 +31,8 @@ controller share one injectable clock — no sleeping, deterministic.
 import numpy as np
 import pytest
 
+from scenario import ChunkLogManager, burst_edges, counter_family
+
 from deepspeech_tpu.resilience import (CircuitBreaker, FaultPlan,
                                        FaultSpec, InjectedFault, Retry,
                                        faults)
@@ -51,37 +53,6 @@ class Clock:
 
     def __call__(self):
         return self.t
-
-
-class FakeMgr:
-    """Duck-typed session manager (the test_replica idiom): a left
-    session finalizes immediately, so no-lost-chunks is exact."""
-
-    def __init__(self, log):
-        self.log = log
-        self.active = {}
-        self.done = {}
-
-    def join(self, sid, raw_len=None):
-        self.active[sid] = []
-
-    def leave(self, sid, tail=None):
-        self.done[sid] = " ".join(self.active.pop(sid))
-
-    def step(self, chunks):
-        for sid, c in chunks.items():
-            self.active[sid].append(str(c))
-            self.log.append((sid, str(c)))
-        return {sid: " ".join(v) for sid, v in self.active.items()}
-
-    def flush(self):
-        pass
-
-    def final(self, sid):
-        return self.done[sid]
-
-    def stats(self):
-        return {"active": len(self.active), "draining": 0}
 
 
 def _echo(tag):
@@ -236,7 +207,7 @@ def test_fault_during_drain_cancels_and_unparks():
     chunk_log = []
     pool = ReplicaPool(
         [_replica(f"r{k}", clock, tel,
-                  session_factory=lambda: FakeMgr(chunk_log))
+                  session_factory=lambda: ChunkLogManager(chunk_log))
          for k in range(2)],
         clock=clock, telemetry=tel, drain_window_s=0.25)
     router = PooledSessionRouter(pool)
@@ -306,3 +277,160 @@ def test_fault_during_drain_cancels_and_unparks():
             sorted(["c0"] * 10 + ["c1"] * 10)
     finally:
         faults.clear()
+
+
+# -- scenario: the three races on one modeled day --------------------------
+
+def test_scenario_modeled_day_with_episode_faults_loses_nothing(obs_lint, postmortems):
+    """One compressed day of seeded, tier-mixed traffic through an
+    autoscaled, handoff-enabled fleet on the virtual clock, under a
+    plan of three episode-relative faults: dispatch errors chasing the
+    replica a scale-up just added, unavailability armed by a drain, and
+    a swap fault armed by a traffic burst under a rolling swap. Every
+    spec fires; the fleet scaled up and took a vertical step inside the
+    horizontal cooldown; the drain episode resolved (removed or
+    cancelled at most once) with no victim left parked; pinned
+    sessions moved by live handoff, never by fallback; the rollout
+    rolled back; and no admitted request and no session chunk was
+    lost. Telemetry and postmortems lint clean."""
+    import math
+
+    from deepspeech_tpu.serving import (MigrationController,
+                                        OverloadRejected,
+                                        RolloutController, TrafficModel)
+
+    day = 7.0
+    schedule = TrafficModel(
+        seed=7, duration_s=day, base_rps=26.0, day_s=day,
+        diurnal_amplitude=0.9, burst_rate_mult=2.5, burst_enter_p=0.3,
+        burst_exit_p=0.2, burst_step_s=0.25,
+        len_log_mean=math.log(64.0), len_log_sigma=0.5, len_min=16,
+        len_max=max(EDGES), tier_mix={"premium": 0.35, "bulk": 0.65},
+        max_arrivals=280).schedule()
+    arrivals = schedule.arrivals
+    bursts = burst_edges(schedule)
+    t_roll = next(t for t, ev in bursts
+                  if ev == "traffic.burst" and t >= 0.45 * day)
+
+    clock = Clock()
+    tel = ServingTelemetry()
+    chunk_log = []
+    pm = postmortems
+    spec_fresh = FaultSpec("gateway.dispatch", "error", prob=1.0,
+                           count=2, on_event="autoscale.scale_up",
+                           target="@event", arm_for_s=1.5, min_load=0.05)
+    spec_drain = FaultSpec("gateway.dispatch", "unavailable", prob=1.0,
+                           count=4, on_event="autoscale.drain_begin",
+                           arm_for_s=1.5)
+    spec_swap = FaultSpec("rollout.swap", "error", prob=1.0, count=1,
+                          on_event="traffic.burst", arm_for_s=2.5)
+    plan = FaultPlan([spec_fresh, spec_drain, spec_swap], seed=7,
+                     clock=clock, registry=tel)
+
+    def decode(batch, plan_):
+        clock.t += 0.005               # service time, on the same clock
+        return ["ok"] * plan_.n_valid
+
+    def factory(rid):
+        rep = _replica(rid, clock, tel,
+                       session_factory=lambda: ChunkLogManager(chunk_log))
+        rep.decode_fn, rep.version = decode, "v1"
+        return rep
+
+    pool = ReplicaPool([factory("r0")], clock=clock, telemetry=tel,
+                       drain_window_s=0.2, handoff=True)
+    sched = MicroBatchScheduler(EDGES, 4, clock=clock, telemetry=tel,
+                                max_queue=16, default_deadline=2.5,
+                                max_attempts=12, pool=pool)
+    probes = {"due": 0, "sent": 0}
+
+    def on_event(ev):
+        if ev.get("action") == "drain_begin":
+            probes["due"] += 8          # traffic to meet the drain fault
+
+    ctrl = AutoscaleController(
+        pool, factory, scheduler=sched, min_replicas=1, max_replicas=3,
+        up_pressure=0.3, down_pressure=0.12, hold_s=0.08, cooldown_s=1.2,
+        rows_per_replica=8, drain_window_s=0.2, vertical_max_batch=8,
+        tier_shift={"premium": "bulk"}, vertical_hold_s=0.03,
+        vertical_cooldown_s=0.25, handoff=True, telemetry=tel,
+        clock=clock, on_event=on_event, postmortem_fn=pm.write)
+    ro = RolloutController(
+        pool, lambda rep: {"decode_fn": decode,
+                           "session_factory":
+                           lambda: ChunkLogManager(chunk_log)},
+        to_version="v2", min_routable=1, drain_window_s=0.15,
+        handoff=True, telemetry=tel, postmortem_fn=pm.write)
+    mig = MigrationController(telemetry=tel, postmortem_fn=pm.write)
+    router = PooledSessionRouter(pool, migrator=mig)
+    sids = [f"s{k}" for k in range(4)]
+    for sid in sids:
+        router.join(sid)
+
+    faults.install(plan)
+    i = b = ticks = 0
+    try:
+        while True:
+            clock.t += 0.05
+            while b < len(bursts) and bursts[b][0] <= clock.t:
+                faults.notify(bursts[b][1])
+                b += 1
+            while i < len(arrivals) and arrivals[i].t <= clock.t:
+                try:
+                    sched.submit(_feat(arrivals[i].feat_len),
+                                 rid=f"q{i}", tier=arrivals[i].tier)
+                except OverloadRejected:
+                    pass
+                i += 1
+            while probes["due"]:
+                probes["due"] -= 1
+                probes["sent"] += 1
+                try:
+                    sched.submit(_feat(16), rid=f"p{probes['sent']}",
+                                 tier="bulk")
+                except OverloadRejected:
+                    pass
+            ctrl.tick()
+            faults.note_load(float(
+                tel.gauges.get("autoscale_pressure", 0.0)))
+            if ro.state == "idle" and clock.t >= t_roll:
+                if len(pool) < 2:
+                    pool.add_replica(factory("rroll"))
+                ro.start()
+            if ro.state in ("running", "paused"):
+                ro.tick()
+            sched.pump()
+            router.step({sid: f"c{ticks}" for sid in sids})
+            ticks += 1
+            if (i >= len(arrivals) and not sched.pending
+                    and ctrl.status()["victim"] is None
+                    and ro.state not in ("idle", "running", "paused")
+                    and (ctrl.drain_cancels or ctrl.scale_downs
+                         or len(pool) <= ctrl.min_replicas)):
+                break
+            assert ticks < 600, "the day never settled"
+        clock.t += 5.0
+        sched.drain()
+    finally:
+        faults.clear()
+    for sid in sids:
+        router.leave(sid)
+    router.flush()
+
+    assert min(spec_fresh.fired, spec_drain.fired, spec_swap.fired) >= 1
+    assert ctrl.scale_ups >= 1
+    assert ctrl.scale_downs + ctrl.drain_cancels >= 1
+    assert ctrl.drain_cancels <= 1
+    assert ctrl.status()["victim"] is None
+    assert not [r.rid for r in pool if r.park_reason == "autoscale"]
+    assert any(ev.get("action") == "vertical_up"
+               and ev.get("in_horizontal_cooldown") for ev in ctrl.events)
+    assert mig.migrations >= 1 and mig.fallbacks == 0
+    assert ro.rollbacks >= 1
+    admitted = counter_family(tel, "admitted")
+    assert admitted == counter_family(tel, "requests_ok") > 0
+    assert admitted + counter_family(tel, "rejected") \
+        == len(arrivals) + probes["sent"]
+    want = " ".join(f"c{k}" for k in range(ticks))
+    assert [router.final(sid) for sid in sids] == [want] * len(sids)
+    assert obs_lint(tel, pm) == []

@@ -5,7 +5,8 @@ double-buffered device prefetch (data/pipeline.device_prefetch).
 Pure host-side tests: the planner is a deterministic function of
 (feat_lens, bucket_frames, max_batch) and everything here is checked
 against hand-computed expectations. The end-to-end bit-identity of the
-bucketed decode path lives in tests/test_infer.py.
+bucketed decode path lives in tests/test_infer.py; the one model-backed
+scenario here is what the ladder buys on a mixed-length request.
 """
 
 import numpy as np
@@ -173,3 +174,32 @@ def test_device_prefetch_order_and_overlap():
     assert list(device_prefetch(iter([7]), put_fn=put, depth=1)) == [70]
     with pytest.raises(ValueError):
         list(device_prefetch(iter([1]), put_fn=put, depth=0))
+
+
+def test_scenario_mixed_request_wastes_less_than_one_max_shape(tiny_offline):
+    """A mixed-length request through a real (tiny) engine: the ladder
+    pads strictly less than decoding every batch at the single max
+    shape, compiles at most one executable a rung, and a repeated
+    request compiles nothing."""
+    cfg = tiny_offline.cfg
+    edges, bs = cfg.data.bucket_frames, cfg.data.batch_size
+    t_max, nf = max(edges), cfg.features.num_features
+    rng = np.random.default_rng(0)
+    n = 2 * bs + bs // 2
+    lens = rng.integers(t_max // 8, t_max, size=n, endpoint=True)
+    feats = rng.standard_normal((n, t_max, nf)).astype(np.float32)
+    for i, k in enumerate(lens):
+        feats[i, k:] = 0.0
+    batch = {"features": feats, "feat_lens": lens.astype(np.int32)}
+    inf = tiny_offline.inferencer()
+    texts = inf.decode_batch_bucketed(batch)
+    assert len(texts) == n
+    waste = padding_waste(lens, plan_infer_buckets(lens, edges, bs))
+    baseline = 1.0 - lens.sum() / (-(-n // bs) * bs * t_max)
+    assert 0.0 < waste < baseline
+    assert inf.shape_cache.padding_waste == pytest.approx(waste)
+    compiles = inf.shape_cache.compiles
+    assert 0 < compiles <= len(ladder_shapes(edges, bs))
+    assert inf.decode_batch_bucketed(batch) == texts
+    assert inf.shape_cache.compiles == compiles
+    assert inf.shape_cache.hits >= compiles

@@ -39,32 +39,6 @@ class Clock:
         return self.t
 
 
-@pytest.fixture(scope="module")
-def tiny_streaming():
-    import jax
-    import jax.numpy as jnp
-
-    from deepspeech_tpu.config import get_config
-    from deepspeech_tpu.data import CharTokenizer
-    from deepspeech_tpu.models import create_model
-
-    cfg = get_config("ds2_streaming")
-    cfg = dataclasses.replace(
-        cfg,
-        model=dataclasses.replace(cfg.model, rnn_hidden=32, rnn_layers=2,
-                                  conv_channels=(4, 4),
-                                  lookahead_context=4, dtype="float32"),
-        data=dataclasses.replace(cfg.data, max_label_len=32),
-        features=dataclasses.replace(cfg.features, num_features=NF))
-    tok = CharTokenizer.english()
-    model = create_model(cfg.model)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 64, NF), jnp.float32),
-                           jnp.full((1,), 64, jnp.int32), train=False)
-    return (cfg, tok, variables["params"],
-            variables.get("batch_stats", {}))
-
-
 def _mgr(tiny_streaming, **kw):
     cfg, tok, params, stats = tiny_streaming
     return StreamingSessionManager(cfg, params, stats, tok,
@@ -581,3 +555,80 @@ def test_router_adopt_restores_into_pool(tiny_streaming, tmp_path):
     router.leave("x")
     router.flush()
     assert router.final("x") == ref
+
+
+# -- scenario: a cohort re-pinned twice, handoff against drain ------------
+
+@pytest.mark.parametrize("handoff", [True, False])
+def test_scenario_mass_repin_twice(tiny_streaming, obs_lint, postmortems, handoff):
+    """Two live streams pinned to one replica, whose breaker trips;
+    then their new home trips too. With the handoff plane every stream
+    moves by snapshot both times: one migration a stream a topology
+    change, no fallback, ONE segment, and the final transcripts equal
+    the never-migrated reference bit for bit (so no chunk was lost).
+    The legacy drain splits each stream into trips + 1 segments and
+    migrates nothing. Telemetry and ``migration`` postmortems lint
+    clean."""
+
+    trips, per_trip = 2, 2
+    clock = Clock()
+    tel = ServingTelemetry()
+    pm = postmortems
+    pool = _streaming_pool(tiny_streaming, clock, tel, handoff=handoff)
+    mig = MigrationController(telemetry=tel, clock=clock,
+                              postmortem_fn=pm.write)
+    router = PooledSessionRouter(pool, migrator=mig if handoff else None)
+    sids, k = [], 0
+    while len(sids) < 2:                 # both homed on r0
+        if pool.ring_owner(f"m{k}") == "r0":
+            sids.append(f"m{k}")
+        k += 1
+    feats = {sid: _feat(64 * (1 + trips * per_trip), seed=30 + j)
+             for j, sid in enumerate(sids)}
+    step = 0
+
+    def feed():
+        nonlocal step
+        router.step({sid: feats[sid][64 * step:64 * (step + 1)]
+                     for sid in sids})
+        step += 1
+
+    for sid in sids:
+        assert router.join(sid) == "r0"
+    feed()
+    for _ in range(trips):
+        clock.t += 2.0                   # past the last trip's cooldown
+        pool.maintain()
+        victim = pool.replica(router.home_of(sids[0]))
+        assert {router.home_of(sid) for sid in sids} == {victim.rid}
+        victim.breaker.allow()           # half-open, so a failure re-opens
+        _trip(victim.breaker)
+        for _ in range(per_trip):
+            feed()
+        assert victim.rid not in {router.home_of(sid) for sid in sids}
+    for sid in sids:
+        router.leave(sid)
+    router.flush()
+
+    finals = {sid: router.final(sid) for sid in sids}
+    # final() closes each session's trace; its flight-recorder summary
+    # says how many segments the transcript was joined from.
+    segments = {rec["segments"]
+                for rec in router.flight_recorder.recent(len(sids))}
+    if handoff:
+        for sid in sids:
+            assert finals[sid] == _solo(tiny_streaming, feats[sid])
+        assert segments == {1}
+        assert mig.stats() == {"migrations": len(sids) * trips,
+                               "fallbacks": 0, "max_per_session": trips}
+        pms = pm.recent("migration")
+        assert len(pms) == len(sids) * trips
+        assert {p["outcome"] for p in pms} == {"handoff"}
+        assert any(k.startswith("session_migrations{")
+                   for k in tel.counters)
+        assert obs_lint(tel, pm) == []
+    else:
+        assert segments == {trips + 1}
+        assert mig.migrations == 0 and not pm.recent("migration")
+        assert all(isinstance(t, str) for t in finals.values())
+        assert obs_lint(tel) == []

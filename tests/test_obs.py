@@ -542,3 +542,47 @@ def test_tracer_concurrent_writers_never_tear_lines():
     import check_obs_schema
     importlib.reload(check_obs_schema)
     assert check_obs_schema.scan(lines) == []
+
+
+# -- what the always-on hooks do when nothing is listening ---------------
+
+def test_scenario_disabled_hooks_hand_out_noops_and_record_nothing(
+        obs_lint):
+    """The hooks the hot paths call unconditionally (``obs.span``,
+    ``faults.inject``/``notify``, ``timeline.publish``/``last_for``)
+    are, with nothing installed, the shared no-op: one object for every
+    call, nothing written, nothing counted. Switched on, the same calls
+    give one lint-clean record each."""
+    from deepspeech_tpu.obs import timeline
+    from deepspeech_tpu.obs.trace import _NOOP
+    from deepspeech_tpu.resilience import faults
+
+    faults.clear()
+    timeline.clear()
+    reg = MetricsRegistry()
+    sink = io.StringIO()
+    obs.configure(enabled=False, sink=sink, registry=reg)
+    try:
+        before = reg.snapshot()
+        for k in range(100):
+            with obs.span("train.step", step=k) as sp:
+                assert sp is _NOOP
+            assert faults.inject("train.step") is None
+            assert faults.inject("gateway.dispatch", replica="r0") is None
+            assert faults.notify("autoscale.scale_up") == 0
+            assert timeline.publish("breaker_open", "pool",
+                                    replica="r0", cause_seq=None) is None
+            assert timeline.last_for("r0") is None
+        assert sink.getvalue() == ""
+        assert reg.snapshot() == before
+        assert faults.active() is None and timeline.active() is None
+
+        obs.configure(enabled=True, sink=sink, registry=reg)
+        for k in range(5):
+            with obs.span("train.step", step=k):
+                pass
+        recs = [json.loads(l) for l in sink.getvalue().splitlines()]
+        assert [r["step"] for r in recs] == list(range(5))
+        assert obs_lint(sink.getvalue()) == []
+    finally:
+        obs.configure(enabled=False, registry=obs.registry())

@@ -1,10 +1,14 @@
 """Blocked-q (s8 weight-streaming) Pallas RNN kernels, interpret mode
 on the CPU harness.
 
-The contract under test: the int8 column-streaming kernels are
-BIT-IDENTICAL to the resident-q kernels wherever both apply (matmul
-columns are independent, so each block's ``(h @ Q_blk) * sc_blk +
-bh_blk`` is exactly a column slice of the resident full product),
+The contract under test: the int8 column-streaming kernels compute
+the resident-q kernels' gates wherever both apply (matmul columns are
+independent, so each block's ``(h @ Q_blk) * sc_blk + bh_blk`` is a
+column slice of the resident full product) and agree with them to a
+few ulp of the output's range — not to the bit: the two are different
+programs, and the compiler contracts the elementwise gate update into
+fused multiply-adds differently in each (on this harness XLA:CPU gives
+one-ulp differences from IDENTICAL gates) —
 match the dequant-outside oracle within the established int8
 tolerances, and the regime plumbing — fits_vmem boundaries per stored
 width, the serving ladder's streamed-bytes reservation, the analytic
@@ -54,14 +58,18 @@ def _quantize_wh(w_h):
 
 
 # ---------------------------------------------------------------------------
-# Bit-identity: blocked-q == resident-q, exactly. h=16 exercises a
+# blocked-q == resident-q to _ULPS (outputs lie in (-1, 1), where one
+# f32 ulp is 1.19e-7). h=16 exercises a
 # single zero-padded block (3H=48 -> one 128-col block), h=176 a
 # multi-block layout with a padded tail (3H=528 -> 512 + 16).
 # ---------------------------------------------------------------------------
 
+_ULPS = dict(rtol=0, atol=5e-7)
+
+
 @pytest.mark.parametrize("reverse", [False, True])
 @pytest.mark.parametrize("h", [16, 176])
-def test_gru_blocked_q_bit_identical_to_resident(reverse, h):
+def test_gru_blocked_q_matches_resident(reverse, h):
     rng = np.random.default_rng(60)
     xproj, mask, w_h, b_h = _rand_gru(rng, 2, 9, h)
     q, scale = _quantize_wh(w_h)
@@ -69,12 +77,13 @@ def test_gru_blocked_q_bit_identical_to_resident(reverse, h):
                                True, None, blocked=False)
     ys_blk = gru_scan_pallas_q(xproj, mask, q, scale, b_h, reverse,
                                True, None, blocked=True)
-    np.testing.assert_array_equal(np.asarray(ys_res), np.asarray(ys_blk))
+    np.testing.assert_allclose(np.asarray(ys_res), np.asarray(ys_blk),
+                               **_ULPS)
 
 
 @pytest.mark.parametrize("reverse", [False, True])
 @pytest.mark.parametrize("h", [16, 144])  # 4H=64 / 4H=576 -> 2 blocks
-def test_lstm_blocked_q_bit_identical_to_resident(reverse, h):
+def test_lstm_blocked_q_matches_resident(reverse, h):
     rng = np.random.default_rng(61)
     xproj, mask, w_h, b_h = _rand_lstm(rng, 2, 8, h)
     q, scale = _quantize_wh(w_h)
@@ -82,7 +91,8 @@ def test_lstm_blocked_q_bit_identical_to_resident(reverse, h):
                                 True, None, blocked=False)
     ys_blk = lstm_scan_pallas_q(xproj, mask, q, scale, b_h, reverse,
                                 True, None, blocked=True)
-    np.testing.assert_array_equal(np.asarray(ys_res), np.asarray(ys_blk))
+    np.testing.assert_allclose(np.asarray(ys_res), np.asarray(ys_blk),
+                               **_ULPS)
 
 
 # ---------------------------------------------------------------------------
@@ -135,7 +145,7 @@ def test_gru_blocked_q_respects_mask():
 def test_blocked_q_auto_dispatch(monkeypatch):
     """With the residency budget forced to 0 the q entry points pick
     the blocked kernel on their own (no ``blocked=`` hint) and still
-    produce the resident answer bit for bit."""
+    produce the resident answer (to ``_ULPS``)."""
     rng = np.random.default_rng(65)
     xproj, mask, w_h, b_h = _rand_gru(rng, 2, 7, 16)
     q, scale = _quantize_wh(w_h)
@@ -143,8 +153,8 @@ def test_blocked_q_auto_dispatch(monkeypatch):
     monkeypatch.setattr(rnn_pallas, "_VMEM_WEIGHT_BUDGET", 0)
     assert _use_blocked(16, jnp.float32, weight_bytes=1)
     ys_auto = gru_scan_pallas_q(xproj, mask, q, scale, b_h, False, True)
-    np.testing.assert_array_equal(np.asarray(ys_res),
-                                  np.asarray(ys_auto))
+    np.testing.assert_allclose(np.asarray(ys_res), np.asarray(ys_auto),
+                               **_ULPS)
 
 
 def test_models_rnn_routes_qdict_every_h(monkeypatch):

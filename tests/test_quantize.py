@@ -223,3 +223,87 @@ def test_inferencer_int8_kernel_path_matches_dequant(model_and_vars):
                                 and set(x) == {"q", "scale"}))
         outs[impl] = inf.decode_batch(batch)
     assert outs["pallas"] == outs["xla"]
+
+
+# -- scenario: a premium and a bulk replica behind one gateway ------------
+
+def test_scenario_two_tier_pool_quantizes_once_and_keeps_tiers_apart(
+        tiny_offline, obs_lint):
+    """Full-precision ``premium`` and int8 ``bulk`` replicas (Pallas
+    recurrent kernels) behind one tier-aware scheduler under a seeded
+    mixed-tier replay: PTQ ran exactly once, at engine build, and
+    quantized leaves; the int8 logits stay within the deterministic
+    bound of ``test_quantized_forward_close``; under one byte budget
+    the bulk ladder is strictly taller; each tier's gateway transcripts
+    equal that tier's own solo decode (bulk is never upgraded); and
+    the tier-labeled telemetry lints clean."""
+    from scenario import (EDGES, NF, ManualClock, poisson_requests,
+                          replay, solo_decode)
+    from deepspeech_tpu.serving import (MicroBatchScheduler, Replica,
+                                        ReplicaPool, ServingTelemetry,
+                                        tier_max_batches)
+    from deepspeech_tpu.utils import quantize as quant
+
+    cfg = dataclasses.replace(tiny_offline.cfg, model=dataclasses.replace(
+        tiny_offline.cfg.model, rnn_impl="pallas"))
+    calls0 = quant.QUANTIZE_CALLS
+    premium = tiny_offline.inferencer(cfg)
+    bulk = tiny_offline.inferencer(cfg, quantize="int8")
+    assert quant.QUANTIZE_CALLS - calls0 == 1
+    assert (premium.quantize_calls, bulk.quantize_calls) == (0, 1)
+    assert (premium.kernel_regime, bulk.kernel_regime) \
+        == ("fp", "resident-q")
+    report = bulk.quantize_report
+    assert report["quantized"] > 0
+    assert report["bytes_after"] < report["bytes_before"]
+
+    model = create_model(cfg.model)
+    rng = np.random.default_rng(0)
+    feats = jnp.asarray(rng.normal(size=(2, 64, NF)), jnp.float32)
+    lens = jnp.asarray([64, 48], jnp.int32)
+
+    def logits(params):
+        return model.apply({"params": params,
+                            "batch_stats": tiny_offline.stats},
+                           feats, lens, train=False)[0]
+
+    ref = logits(tiny_offline.params)
+    got = logits(dequantize_params(bulk.params))
+    assert float(jnp.abs(ref - got).max()) \
+        / float(jnp.abs(ref).max()) < 0.05
+
+    per_row = max((report["bytes_before"] - report["bytes_after"]) // 8,
+                  1)
+    ladder = tier_max_batches(report, per_row,
+                              report["bytes_before"] + 8 * per_row)
+    assert ladder["bulk"] > ladder["premium"] > 0
+
+    clock = ManualClock()
+    tel = ServingTelemetry()
+    pool = ReplicaPool(
+        [Replica.from_inferencer("r0", premium, tier="premium",
+                                 telemetry=tel, clock=clock),
+         Replica.from_inferencer("r1", bulk, tier="bulk",
+                                 telemetry=tel, clock=clock)],
+        telemetry=tel, clock=clock)
+    n = 12
+    arrivals, reqs = poisson_requests(n)
+    tiers = ["premium" if j % 2 == 0 else "bulk" for j in range(n)]
+    sched = MicroBatchScheduler(
+        EDGES, 4, clock=clock, pool=pool, telemetry=tel, max_queue=16,
+        default_deadline=0.02,
+        tier_max_batch={t: max(1, min(4, ladder[t])) for t in ladder})
+    results = replay(sched, clock, arrivals, reqs, tiers=tiers)
+    assert quant.QUANTIZE_CALLS - calls0 == 1      # none while serving
+    assert len(results) == n
+    engine = {"premium": premium, "bulk": bulk}
+    for rid, r in results.items():
+        j = int(rid[1:])
+        assert r.status == "ok"
+        assert r.text == solo_decode(engine[tiers[j]], reqs[j])
+    c = tel.snapshot()["counters"]
+    for t in ("premium", "bulk"):
+        assert int(c[f'requests_ok{{tier="{t}"}}']) == n // 2
+    assert {r.rid: r.stats()["rows"] > 0 for r in pool} \
+        == {"r0": True, "r1": True}
+    assert obs_lint(tel) == []

@@ -741,3 +741,83 @@ def test_remove_replica_repins_live_sessions_no_lost_chunks():
     router.flush()
     for sid in sids:
         assert router.final(sid) == "c0 c1 c2"
+
+
+# -- scenario: two real engines behind the pool ---------------------------
+
+def test_scenario_two_replicas_trip_midreplay_loses_nothing(
+        tiny_offline, tiny_streaming, obs_lint):
+    """Seeded traffic over two real (tiny) engines with one replica's
+    breaker forced open halfway, then pinned streaming sessions whose
+    home trips: no admitted request is lost, a transcript does not
+    depend on the replica that served it, both replicas carried rows,
+    every session finalizes after its re-pin, and the replica-labeled
+    telemetry lints clean."""
+    from scenario import ManualClock, poisson_requests, replay, solo_decode
+    from deepspeech_tpu.serving import StreamingSessionManager
+
+    clock = ManualClock()
+    tel = ServingTelemetry()
+    scfg, stok, sparams, sstats = tiny_streaming
+
+    def sessions():
+        return StreamingSessionManager(scfg, sparams, sstats, stok,
+                                       chunk_frames=64, capacity=1,
+                                       telemetry=tel)
+
+    infs = [tiny_offline.inferencer() for _ in range(2)]
+    pool = ReplicaPool(
+        [Replica.from_inferencer(f"r{k}", infs[k], telemetry=tel,
+                                 clock=clock, session_factory=sessions,
+                                 breaker=_breaker(clock, tel, f"b{k}",
+                                                  cooldown=0.25))
+         for k in range(2)], clock=clock, telemetry=tel)
+    n = 16
+    arrivals, reqs = poisson_requests(n)
+    s = _sched(clock, pool, default_deadline=0.02)
+    results = replay(
+        s, clock, arrivals, reqs, on_arrival=lambda i: i == n // 2
+        and _trip(pool.replica("r1").breaker))
+    c = tel.snapshot()["counters"]
+    assert int(c["admitted"]) == n == len(results)
+    assert int(c["admitted"]) - int(c.get("requests_ok", 0)) \
+        - int(c.get("requests_timeout", 0)) \
+        - int(c.get("requests_error", 0)) == 0
+    assert sum(r.breaker.opens for r in pool) >= 1
+    for rid, r in results.items():
+        assert r.status == "ok"
+        feat = reqs[int(rid[1:])]
+        assert r.text == solo_decode(infs[0], feat) \
+            == solo_decode(infs[1], feat)
+    rows = {r.rid: r.stats()["rows"] for r in pool}
+    assert set(rows) == {"r0", "r1"} and min(rows.values()) > 0
+    assert sum(rows.values()) >= n
+
+    # Streaming: two sessions, the first one's home trips mid-stream.
+    for r in pool:
+        r.breaker.cooldown_s = 60.0
+    clock.t += 1.0
+    pool.maintain()
+    router = PooledSessionRouter(pool)
+    rng = np.random.default_rng(1)
+    sids = ["s0", "s1"]
+    homes = {sid: router.join(sid) for sid in sids}
+
+    def feed():
+        router.step({sid: rng.standard_normal((64, NF)).astype(
+            np.float32) for sid in sids})
+
+    feed(), feed()
+    _trip(pool.replica(homes["s0"]).breaker)
+    feed(), feed()
+    assert router.home_of("s0") != homes["s0"]
+    for sid in sids:
+        router.leave(sid)
+    router.flush()
+    assert all(isinstance(router.final(sid), str) for sid in sids)
+    assert pool.repins >= 1
+    grows = [ev for r in pool if r.peek_session_manager() is not None
+             for ev in r.peek_session_manager().grow_events]
+    assert int(tel.snapshot()["counters"]["capacity_grows"]) \
+        == len(grows) >= 1
+    assert obs_lint(tel) == []

@@ -2,8 +2,10 @@
 order (empty n-best, brownout rung, tenancy quota, bounded queue),
 pump-driven determinism, the score_delta argmax contract, per-job
 trace ledgers, and the brownout controller's dedicated rescore rung.
-The end-to-end legs (first-pass p95 unchanged, shed-to-zero under
-flood) live in bench.py --bench=rescoring."""
+The end-to-end legs (first-pass latencies unchanged on the scripted
+clock, shed-to-zero under flood) are the scenario at the end."""
+
+import json
 
 import pytest
 
@@ -262,3 +264,111 @@ def test_rescore_trace_ledger_is_its_own_context():
     assert rec["revised"] is True
     assert rec["phases"]["rescore_queue"] == pytest.approx(200.0)
     assert rec["latency_ms"] == pytest.approx(200.0)
+
+
+# -- scenario: the slow path behind a live gateway ------------------------
+
+def _gateway_replay(rescoring_on):
+    """30 scripted requests through a two-replica gateway whose
+    decoders return ``(texts, nbest)``: 16 paced, a 12-request flood
+    (queue fill 0.5: above the rescore rung, below degradation), 2
+    after the drain. The pool pumps between scheduler pumps."""
+    import numpy as np
+
+    from deepspeech_tpu.serving import (MicroBatchScheduler, Replica,
+                                        ReplicaPool)
+
+    clock = Clock()
+    tel = ServingTelemetry()
+    bro = BrownoutController(enter_pressure=0.75, exit_pressure=0.0,
+                             shed_pressure=0.9, hold_s=0.0,
+                             rescore_pressure=0.3, clock=clock,
+                             registry=tel)
+    revisions = []
+    resc = _pool(clock, workers=2, max_queue=16, telemetry=tel,
+                 brownout=bro, on_revision=revisions.append) \
+        if rescoring_on else None
+
+    def decode(batch, plan):
+        uids = [int(batch["features"][i].sum())
+                for i in range(plan.n_valid)]
+        texts = [f"bad {u}" if u % 2 else f"plain {u}" for u in uids]
+        return texts, [[(t, 1.0),
+                        (f"good {u}" if u % 2 else f"also {u}", 0.9)]
+                       for t, u in zip(texts, uids)]
+
+    pool = ReplicaPool([Replica(f"r{k}", decode, telemetry=tel,
+                                clock=clock) for k in range(2)],
+                       clock=clock, telemetry=tel)
+    sched = MicroBatchScheduler((16, 32), 4, max_queue=24,
+                                default_deadline=0.05, clock=clock,
+                                telemetry=tel, pool=pool, brownout=bro,
+                                rescorer=resc)
+    rids = []
+
+    def submit(uid, frames=8):
+        feat = np.zeros((frames, 8), np.float32)
+        feat[0, 0] = uid
+        clock.advance(0.0005)
+        rids.append(sched.submit(feat))
+
+    for uid in range(1, 17):
+        submit(uid, 8 if uid % 3 else 20)
+        clock.advance(0.0015)
+        sched.pump()
+        clock.advance(0.0005)
+        if resc is not None:
+            resc.pump(now=clock())
+    sched.drain()
+    if resc is not None:
+        clock.advance(0.001)
+        resc.drain(now=clock())
+    shed0 = dict(resc.shed).get("brownout", 0) if resc else 0
+    for uid in range(17, 29):
+        submit(uid)
+    level_at_flood = bro.level
+    sched.drain()
+    flood_shed = (dict(resc.shed).get("brownout", 0) - shed0
+                  if resc else 0)
+    offered0 = resc.submitted if resc else 0
+    for uid in range(29, 31):
+        submit(uid)
+        clock.advance(0.0015)
+        sched.pump()
+    sched.drain()
+    if resc is not None:
+        clock.advance(0.001)
+        resc.drain(now=clock())
+    assert [sched.results[r].status for r in rids] == ["ok"] * 30
+    return {"latencies": [sched.results[r].latency for r in rids],
+            "revisions": [(ev.rid, ev.old_text, ev.new_text,
+                           ev.score_delta) for ev in revisions],
+            "events": revisions, "tel": tel,
+            "level_at_flood": level_at_flood, "flood_shed": flood_shed,
+            "offered_after": resc.submitted - offered0 if resc else 0}
+
+
+def test_scenario_slow_path_costs_the_fast_path_nothing(obs_lint):
+    """The same scripted replay with the rescoring pool on, on again,
+    and off: every first-pass request completes with the SAME
+    scripted-clock latency either way; the LM revises exactly the
+    flippable (odd) finals of the paced phase, each to its 'good'
+    hypothesis with a nonnegative delta; two runs emit identical
+    revision streams; the flood, which never degrades the first pass,
+    sheds all 12 rescoring offers and the pool re-enables afterwards;
+    telemetry and the streamed ``revision`` lines lint clean."""
+    on, again, off = (_gateway_replay(True), _gateway_replay(True),
+                      _gateway_replay(False))
+    assert on["latencies"] == off["latencies"]
+    revs = on["revisions"]
+    assert len(revs) == 9                  # odd uids of 1..16, 29
+    assert all(old.startswith("bad") and new.startswith("good")
+               and delta >= 0.0 for _, old, new, delta in revs)
+    assert revs == again["revisions"] and off["revisions"] == []
+    assert on["level_at_flood"] == 0 and on["flood_shed"] == 12
+    assert on["offered_after"] == 2
+    c = on["tel"].snapshot()["counters"]
+    assert c["rescore_disabled"] >= 1 and c["rescore_reenabled"] >= 1
+    lines = [json.dumps({"revision": ev.to_json()})
+             for ev in on["events"]]
+    assert obs_lint(on["tel"], lines) == []

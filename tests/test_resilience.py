@@ -154,9 +154,9 @@ def test_fault_plan_json_roundtrip(tmp_path):
 
     p = tmp_path / "plan.json"
     p.write_text(json.dumps({"seed": 5, "faults": [
-        {"point": "backend.init", "kind": "unavailable", "count": 2}]}))
+        {"point": "checkpoint.restore", "kind": "unavailable", "count": 2}]}))
     plan = FaultPlan.from_json(str(p))
-    assert plan.seed == 5 and plan.specs[0].point == "backend.init"
+    assert plan.seed == 5 and plan.specs[0].point == "checkpoint.restore"
 
 
 def test_fault_spec_skip_gives_step_exact_schedule():
@@ -887,3 +887,170 @@ def test_brownout_effective_tier_degrades_premium_only():
         clock.t += 1.0
         b.update(0.0, now=clock.t)
     assert b.effective_tier("premium") == "premium"
+
+
+# -- scenario: modeled traffic under a pinned fault plan ------------------
+
+def test_scenario_traffic_under_fault_plan_loses_nothing(tiny_offline):
+    """Arrivals from the seeded ``TrafficModel`` through the scheduler
+    with the whole resilience stack (requeue, breaker, brownout) into a
+    real (tiny) engine, under a pinned plan: two dispatch errors, then
+    an unavailable window. Both kinds fire and are counted, the breaker
+    opens in the window and closes through a probe after it, failed
+    dispatches are retried, and every admitted request still completes
+    with the transcript it gets alone. (The plan's third classic leg,
+    the torn checkpoint write, is
+    ``test_checkpoint_restore_falls_back_to_intact_step``.)"""
+    from scenario import (EDGES, NF, ManualClock, replay, solo_decode)
+    from deepspeech_tpu.serving import (MicroBatchScheduler,
+                                        ServingTelemetry, TrafficModel)
+
+    n, rps = 16, 120.0
+    traffic = TrafficModel(
+        seed=0, duration_s=n / rps, base_rps=rps, day_s=n / rps,
+        diurnal_amplitude=0.5, burst_rate_mult=2.0, burst_enter_p=0.15,
+        burst_exit_p=0.3, burst_step_s=0.05,
+        len_log_mean=float(np.log(64)), len_log_sigma=0.6,
+        len_min=16, len_max=max(EDGES), max_arrivals=n).schedule()
+    arrivals = [a.t for a in traffic.arrivals]
+    rng = np.random.default_rng(0)
+    reqs = [rng.standard_normal((a.feat_len, NF)).astype(np.float32)
+            for a in traffic.arrivals]
+    assert 0 < len(reqs) <= n
+
+    clock = ManualClock()
+    tel = ServingTelemetry()
+    breaker = CircuitBreaker(failure_threshold=2, cooldown_s=0.05,
+                             name="gateway", clock=clock, registry=tel)
+    brownout = BrownoutController(enter_pressure=0.7, exit_pressure=0.2,
+                                  shed_pressure=0.95, hold_s=0.03,
+                                  clock=clock, registry=tel)
+    sched = MicroBatchScheduler(EDGES, 4, clock=clock, max_queue=32,
+                                default_deadline=0.03, max_attempts=12,
+                                telemetry=tel, breaker=breaker,
+                                brownout=brownout)
+    inf = tiny_offline.inferencer()
+    t_mid = arrivals[len(arrivals) // 2]
+    plan = FaultPlan([
+        FaultSpec("gateway.dispatch", "error", prob=1.0, count=2,
+                  message="injected decode error"),
+        FaultSpec("gateway.dispatch", "unavailable",
+                  after_s=t_mid, until_s=arrivals[-1] + 0.01),
+    ], seed=0, clock=clock, registry=tel)
+    faults.install(plan)
+    try:
+        results = replay(
+            sched, clock, arrivals, reqs,
+            lambda batch, p: inf.decode_batch_bucketed(batch, plans=[p]))
+    finally:
+        faults.clear()
+
+    c = tel.snapshot()["counters"]
+    kinds = {k.split('kind="')[1].split('"')[0]
+             for k in c if k.startswith("faults_injected{")}
+    assert kinds == {"error", "unavailable"}
+    assert int(c["retries"]) > 0
+    assert breaker.opens >= 1 and breaker.state == "closed"
+    assert breaker.recovery_s() > 0
+    assert int(c["admitted"]) == int(c["requests_ok"]) == len(results)
+    assert int(c["admitted"]) + int(c.get("rejected", 0)) == len(reqs)
+    for rid, r in results.items():
+        assert r.status == "ok"
+        assert r.text == solo_decode(inf, reqs[int(rid[1:])])
+
+
+# -- scenario: a training run that heals itself ---------------------------
+
+def test_scenario_training_survives_poison_and_leaves_no_trace(tmp_path):
+    """``Trainer.fit`` under a pinned plan (one NaN-poisoned sample at
+    batch 5, NaN gradients at steps 11 and 12): the run ends without an
+    exception and with a finite loss, having quarantined the sample,
+    skipped a batch, rolled back to the ring once and written a
+    postmortem for each; and a clean trainer fed only the surviving
+    batches ends on bit-identical params."""
+    import jax
+
+    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.data import CharTokenizer
+    from deepspeech_tpu.data.pipeline import scrub_padded_batch
+    from deepspeech_tpu.parallel import shard_batch
+    from deepspeech_tpu.train import Trainer, _SyntheticPipeline
+    from deepspeech_tpu.utils.logging import JsonlLogger
+
+    cfg = get_config("dev_slice")
+    cfg = dataclasses.replace(
+        cfg,
+        model=dataclasses.replace(cfg.model, rnn_hidden=96, rnn_layers=1,
+                                  dtype="float32", conv_channels=(8, 8)),
+        data=dataclasses.replace(cfg.data, batch_size=8,
+                                 bucket_frames=(64,), max_label_len=16),
+        train=dataclasses.replace(
+            cfg.train, checkpoint_dir=str(tmp_path / "ck"), epochs=1,
+            warmup_steps=20, log_every=1, checkpoint_every_steps=0,
+            guardian=True))
+    knobs = GuardianConfig(snapshot_every=4, max_consecutive_skips=1,
+                           stats_warmup_steps=10 ** 6, watchdog=False)
+
+    class Recording:
+        """Scrubs every batch through the quarantine path (where the
+        ``pipeline.materialize`` fault fires) and keeps the post-scrub
+        copies for the clean replay."""
+
+        provides_global_batches = True
+
+        def __init__(self, inner):
+            self.inner, self.seen = inner, []
+            self.peek = inner.peek
+            self.batches_per_epoch = inner.batches_per_epoch
+            self.eval_epoch = inner.eval_epoch
+
+        def epoch(self, e):
+            for b in self.inner.epoch(e):
+                b = {k: np.array(v, copy=True) for k, v in b.items()}
+                b, _ = scrub_padded_batch(b, step=len(self.seen))
+                self.seen.append({k: v.copy() for k, v in b.items()})
+                yield b
+
+    def trainer_for(cfg, pipe):
+        t = Trainer(cfg, pipe, tok, logger=JsonlLogger(echo=False))
+        t.guardian = TrainingGuardian(knobs, ckpt=t.ckpt)
+        return t
+
+    tok = CharTokenizer.english()
+    pipe = Recording(_SyntheticPipeline(cfg, 16 * 8, label_len=12))
+    names = ("guardian_skipped_batches", "guardian_rollbacks",
+             "samples_quarantined", "postmortems_written")
+    reg = obs.registry()
+    base = {k: int(reg.counter(k)) for k in names}
+    plan = FaultPlan.from_dict({"seed": 7, "faults": [
+        {"point": "train.step", "kind": "nan_grad", "skip": 10,
+         "count": 2},
+        {"point": "pipeline.materialize", "kind": "corrupt_batch",
+         "skip": 4, "count": 1}]})
+    chaos = trainer_for(cfg, pipe)
+    faults.install(plan)
+    try:
+        last = chaos.fit()
+    finally:
+        faults.clear()
+    chaos.ckpt.close()
+    got = {k: int(reg.counter(k)) - base[k] for k in names}
+    assert plan.fired() == 3
+    assert got["samples_quarantined"] >= 1
+    assert got["guardian_skipped_batches"] >= 1
+    assert got["guardian_rollbacks"] == 1
+    assert got["postmortems_written"] >= got["guardian_skipped_batches"]
+    assert np.isfinite(last["loss"])
+    survivors = list(chaos.guardian.applied)
+    assert 0 < len(survivors) < len(pipe.seen)
+
+    clean = trainer_for(dataclasses.replace(cfg, train=dataclasses.replace(
+        cfg.train, checkpoint_dir="")), pipe)
+    state = clean.state
+    for i in survivors:
+        state, _ = clean.train_step(
+            state, shard_batch(clean.mesh, pipe.seen[i]),
+            {"lr_scale": np.float32(1.0)})
+    for a, b in zip(jax.tree.leaves(chaos.state.params),
+                    jax.tree.leaves(state.params), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

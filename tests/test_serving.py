@@ -12,8 +12,6 @@ deterministic; model-backed tests reuse the tiny ds2_streaming config
 from tests/test_serve.py's setup idiom.
 """
 
-import dataclasses
-
 import numpy as np
 import pytest
 
@@ -263,31 +261,8 @@ def test_shape_cache_usage_decays_on_logical_clock():
 # -- gateway end-to-end: batched == per-request ---------------------------
 
 @pytest.fixture(scope="module")
-def tiny_infer():
-    import jax
-    import jax.numpy as jnp
-
-    from deepspeech_tpu.config import get_config
-    from deepspeech_tpu.data import CharTokenizer
-    from deepspeech_tpu.infer import Inferencer
-    from deepspeech_tpu.models import create_model
-
-    cfg = get_config("dev_slice")
-    cfg = dataclasses.replace(
-        cfg,
-        model=dataclasses.replace(cfg.model, rnn_hidden=32, rnn_layers=1,
-                                  conv_channels=(4, 4), dtype="float32"),
-        data=dataclasses.replace(cfg.data, bucket_frames=EDGES,
-                                 batch_size=4),
-        features=dataclasses.replace(cfg.features, num_features=NF),
-        decode=dataclasses.replace(cfg.decode, mode="greedy"))
-    tok = CharTokenizer.english()
-    model = create_model(cfg.model)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 64, NF), jnp.float32),
-                           jnp.full((1,), 64, jnp.int32), train=False)
-    return cfg, Inferencer(cfg, tok, variables["params"],
-                           variables.get("batch_stats", {}))
+def tiny_infer(tiny_offline):
+    return tiny_offline.cfg, tiny_offline.inferencer()
 
 
 def test_gateway_batched_decode_bit_identical(tiny_infer):
@@ -312,32 +287,6 @@ def test_gateway_batched_decode_bit_identical(tiny_infer):
 
 
 # -- session manager ------------------------------------------------------
-
-@pytest.fixture(scope="module")
-def tiny_streaming():
-    import jax
-    import jax.numpy as jnp
-
-    from deepspeech_tpu.config import get_config
-    from deepspeech_tpu.data import CharTokenizer
-    from deepspeech_tpu.models import create_model
-
-    cfg = get_config("ds2_streaming")
-    cfg = dataclasses.replace(
-        cfg,
-        model=dataclasses.replace(cfg.model, rnn_hidden=32, rnn_layers=2,
-                                  conv_channels=(4, 4),
-                                  lookahead_context=4, dtype="float32"),
-        data=dataclasses.replace(cfg.data, max_label_len=32),
-        features=dataclasses.replace(cfg.features, num_features=NF))
-    tok = CharTokenizer.english()
-    model = create_model(cfg.model)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 64, NF), jnp.float32),
-                           jnp.full((1,), 64, jnp.int32), train=False)
-    return (cfg, tok, variables["params"],
-            variables.get("batch_stats", {}))
-
 
 def _mgr(tiny_streaming, **kw):
     cfg, tok, params, stats = tiny_streaming
@@ -808,3 +757,54 @@ def test_nbest_threads_through_dispatch_bit_identical():
     (mb,) = s.poll()
     (res,) = s.dispatch(mb, _echo_decode)
     assert res.status == "ok" and res.nbest is None
+
+
+# -- scenario: a seeded replay through the gateway ------------------------
+
+def test_scenario_traffic_replay_accounts_for_every_request(
+        tiny_infer, obs_lint):
+    """Seeded Poisson traffic through the scheduler into a real (tiny)
+    engine, on a manual clock with a queue small enough to shed: every
+    request ends in exactly one outcome, every finished one left a
+    flight-recorder trace whose phases sum to its latency, batching
+    never changed a transcript, and the telemetry snapshot (per-rung
+    usage, occupancy, padding waste, a named latency exemplar) lints
+    clean."""
+    from scenario import (ManualClock, poisson_requests, replay,
+                          solo_decode)
+    from deepspeech_tpu.obs import FlightRecorder
+
+    _, inf = tiny_infer
+    n = 24
+    arrivals, reqs = poisson_requests(n)
+    clock = ManualClock()
+    tel = ServingTelemetry()
+    frec = FlightRecorder(capacity=4 * n)
+    s = MicroBatchScheduler(EDGES, 4, clock=clock, max_queue=3,
+                            default_deadline=0.02, telemetry=tel,
+                            flight_recorder=frec)
+    results = replay(
+        s, clock, arrivals, reqs,
+        lambda batch, plan: inf.decode_batch_bucketed(batch,
+                                                      plans=[plan]))
+    c = tel.snapshot()["counters"]
+    done = int(c.get("requests_ok", 0))
+    assert done + int(c.get("rejected", 0)) \
+        + int(c.get("requests_timeout", 0)) \
+        + int(c.get("requests_error", 0)) == n
+    assert done == len(results) > 0 and int(c["rejected"]) > 0
+    assert int(c["admitted"]) == done
+    traces = {t["rid"]: t for t in frec.recent()}
+    for rid, r in results.items():
+        assert r.status == "ok"
+        assert r.text == solo_decode(inf, reqs[int(rid[1:])])
+        t = traces[rid]
+        assert sum(t["phases"].values()) \
+            == pytest.approx(t["latency_ms"], abs=1e-3)
+        assert t["latency_ms"] == pytest.approx(r.latency * 1e3)
+    snap = tel.snapshot()
+    assert snap["per_rung"]
+    assert 0 < snap["histograms"]["batch_occupancy"]["mean"] <= 1
+    assert 0 <= snap["histograms"]["padding_waste"]["mean"] < 1
+    assert snap["histograms"]["latency_ok"]["max_exemplar"] in results
+    assert obs_lint(tel) == []

@@ -8,11 +8,11 @@ monotonicity across reopen, rotation, torn-tail truncation, the
 ``partial_write`` fault point), and :class:`RecoveryController`
 outcome accounting with its timeline/postmortem publications.
 
-Everything here rides synthetic :class:`StreamSnapshot` payloads and
-duck-typed recovery targets — no model build. The model-backed
-crash/restart bit-identity proof lives in tests/test_migration.py
-(same tiny-model fixture as the handoff tests) and in
-``bench.py --bench=crash_recovery``.
+The contract tests ride synthetic :class:`StreamSnapshot` payloads and
+duck-typed recovery targets — no model build. The scenarios at the end
+kill real (tiny) streaming sessions mid-stream and cold-restart them
+from the journal (tests/test_migration.py has the single-session
+greedy case beside the handoff tests).
 """
 
 import struct
@@ -341,3 +341,153 @@ def test_scan_segment_bytes_degenerate():
     assert scan_segment_bytes(b"", "s") == ([], None)
     entries, torn = scan_segment_bytes(b"XXXXXXXXXX", "s")
     assert entries == [] and torn == 0
+
+
+# -- scenarios: real sessions, killed mid-stream --------------------------
+
+_CHUNK, _STEPS, _CRASH_AT = 64, 4, 2
+
+
+def _cohort(tiny_streaming, tel, sids, seed):
+    cfg, tok, params, stats = tiny_streaming
+    rng = np.random.default_rng(seed)
+    feats = {sid: rng.standard_normal((_STEPS * _CHUNK, 13)).astype(
+        np.float32) for sid in sids}
+
+    def mgr(journal=None, decode="greedy", chunk_frames=_CHUNK):
+        from deepspeech_tpu.serving import StreamingSessionManager
+        return StreamingSessionManager(
+            cfg, params, stats, tok, chunk_frames=chunk_frames,
+            capacity=len(sids), decode=decode, telemetry=tel,
+            journal=journal, journal_every=1)
+
+    def feed(m, k0, k1, join=False, finish=False):
+        if join:
+            for sid in sids:
+                m.join(sid)
+        for k in range(k0, k1):
+            m.step({sid: feats[sid][k * _CHUNK:(k + 1) * _CHUNK]
+                    for sid in sids})
+        if finish:
+            for sid in sids:
+                m.leave(sid)
+            m.flush()
+            return {sid: m.final(sid) for sid in sids}
+
+    return feats, mgr, feed
+
+
+@pytest.mark.parametrize("decode", ["greedy", "beam"])
+def test_scenario_crash_midstream_cold_restart_is_bit_identical(
+        tiny_streaming, tmp_path, obs_lint, postmortems, decode):
+    """Two live streams checkpoint every chunk into the journal and die
+    at the halfway chunk. A fresh manager recovered from the journal
+    resumes each at exactly the crash position and finishes with the
+    uninterrupted reference's transcripts, bit for bit; finalizing
+    tombstones every sid; the ``crash_recovery`` postmortem, the
+    timeline's ``recovery`` events and the journal counters lint
+    clean."""
+    import json
+
+    from deepspeech_tpu.obs import timeline
+    from deepspeech_tpu.obs.timeline import EventLog
+
+    tel = ServingTelemetry()
+    sids = ["c0", "c1"]
+    _, mgr, feed = _cohort(tiny_streaming, tel, sids, seed=31)
+    want = feed(mgr(decode=decode), 0, _STEPS, join=True, finish=True)
+
+    j1 = SessionJournal(str(tmp_path / "wal"), telemetry=tel)
+    feed(mgr(j1, decode), 0, _CRASH_AT, join=True)
+    assert j1.appends == len(sids) * _CRASH_AT
+    j1.close()                          # the process dies here
+
+    pm = postmortems
+    log = timeline.install(EventLog(registry=tel))
+    lines = []
+    log.add_listener(lambda ev: lines.append(
+        json.dumps(EventLog.to_record(ev))))
+    try:
+        j2 = SessionJournal(str(tmp_path / "wal"), telemetry=tel)
+        fresh = mgr(j2, decode)
+        report = RecoveryController(j2, telemetry=tel,
+                                    postmortem_fn=pm.write).recover(fresh)
+    finally:
+        timeline.clear()
+    assert (report["recovered"], report["torn"],
+            report["incompatible"]) == (len(sids), 0, 0)
+    assert {fresh._sessions[sid].fed for sid in sids} \
+        == {_CRASH_AT * _CHUNK}
+    assert feed(fresh, _CRASH_AT, _STEPS, finish=True) == want
+    scan = j2.scan()
+    assert not scan.live and sorted(scan.tombstoned) == sids
+    j2.close()
+    assert tel.counter("sessions_recovered",
+                       labels={"outcome": "ok"}) == len(sids)
+    assert int(tel.counters["journal_appends"]) > 0
+    pms = pm.recent("crash_recovery")
+    assert len(pms) == 1 and pms[0]["trigger"] == "boot"
+    assert obs_lint(tel, lines, pm) == []
+
+
+def test_scenario_torn_tail_and_skew_recover_what_they_can(
+        tiny_streaming, tmp_path):
+    """The same crash, with the journal damaged. Torn in the middle of
+    its last record: the torn session resumes one checkpoint behind,
+    the other at the crash position, and after a per-session refeed
+    both reach the reference. A version-patched record, and a target
+    with another chunk geometry, each recover nothing and are counted
+    ``incompatible``."""
+    import os
+
+    tel = ServingTelemetry()
+    sids = ["c0", "c1"]
+    feats, mgr, feed = _cohort(tiny_streaming, tel, sids, seed=31)
+    want = feed(mgr(), 0, _STEPS, join=True, finish=True)
+    j1 = SessionJournal(str(tmp_path / "wal"), telemetry=tel)
+    m1 = mgr(j1)
+    feed(m1, 0, _CRASH_AT, join=True)
+    snap = m1.snapshot_session(sids[0])
+    j1.close()
+
+    (seg,) = j1.segments()
+    data = open(seg, "rb").read()
+    starts, pos = [], 6
+    while pos + 8 <= len(data):
+        starts.append(pos)
+        pos += 8 + struct.unpack_from("<I", data, pos)[0]
+    os.makedirs(tmp_path / "torn")
+    with open(tmp_path / "torn" / os.path.basename(seg), "wb") as fh:
+        fh.write(data[:starts[-1] + (len(data) - starts[-1]) // 2])
+    jt = SessionJournal(str(tmp_path / "torn"), telemetry=tel)
+    m2 = mgr()
+    report = RecoveryController(jt, telemetry=tel).recover(m2)
+    jt.close()
+    assert (report["recovered"], report["torn"]) == (len(sids), 1)
+    at = {sid: m2._sessions[sid].fed // _CHUNK for sid in sids}
+    assert sorted(at.values()) == [_CRASH_AT - 1, _CRASH_AT]
+    while at:
+        for sid in [s for s in at if at[s] >= _STEPS]:
+            m2.leave(sid)
+            del at[sid]
+        if at:
+            m2.step({sid: feats[sid][k * _CHUNK:(k + 1) * _CHUNK]
+                     for sid, k in at.items()})
+            at = {sid: k + 1 for sid, k in at.items()}
+    m2.flush()
+    assert {sid: m2.final(sid) for sid in sids} == want
+
+    raw = bytearray(snapshot_to_bytes(snap))
+    struct.pack_into("<H", raw, 4, 99)      # version field, before CRC
+    for name, blob, target in (
+            ("version", bytes(raw), mgr()),
+            ("geometry", snapshot_to_bytes(snap), mgr(chunk_frames=32))):
+        js = SessionJournal(str(tmp_path / name), telemetry=tel)
+        js.append("skew", blob)
+        js.close()
+        got = RecoveryController(
+            SessionJournal(str(tmp_path / name), telemetry=tel),
+            telemetry=tel).recover(target)
+        assert (got["recovered"], got["incompatible"]) == (0, 1), name
+    assert tel.counter("sessions_recovered",
+                       labels={"outcome": "incompatible"}) == 2

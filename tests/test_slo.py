@@ -393,3 +393,86 @@ def test_status_server_500_on_every_endpoint_and_silent_handler(capsys):
             assert r.status == 200
     out = capsys.readouterr()
     assert out.out == "" and out.err == ""
+
+
+# -- scenario: breach -> page -> brownout -> recovery ---------------------
+
+def test_scenario_breach_pages_browns_out_and_recovers():
+    """One scripted day through the real scheduler: healthy rounds burn
+    nothing; with every decode at 4x the deadline the fast window pages
+    ONCE, its postmortem names the slowest requests and their causes,
+    the burn gauges walk brownout up until admissions shed; once the
+    breach ages out the alert re-arms and the ladder is back at 0. The
+    status endpoints answer in every phase."""
+    import urllib.request
+
+    from deepspeech_tpu.resilience.brownout import BrownoutController
+    from deepspeech_tpu.resilience.postmortem import PostmortemWriter
+    from deepspeech_tpu.serving import (MicroBatchScheduler,
+                                        OverloadRejected,
+                                        ServingTelemetry)
+
+    clk = Clock()
+    tel = ServingTelemetry()
+    frec = FlightRecorder(capacity=512)
+    pm = PostmortemWriter(registry=tel)
+    bro = BrownoutController(registry=tel, clock=clk, hold_s=0.0,
+                             slo_burn_budget=10.0)
+    eng = SloBurnEngine(target=0.99, registry=tel, clock=clk,
+                        recorder=frec, postmortem_fn=pm.write)
+    deadline, bs = 0.05, 4
+    sched = MicroBatchScheduler([64, 128], bs, max_queue=8 * bs,
+                                default_deadline=deadline, clock=clk,
+                                telemetry=tel, brownout=bro,
+                                flight_recorder=frec)
+    cost = {"s": 0.01}
+    seen = {"shed": 0, "level": 0}
+
+    def decode_fn(batch, plan):
+        clk.advance(cost["s"])
+        return ["ok"] * int(batch["features"].shape[0])
+
+    def rounds(tag, n):
+        for k in range(n):
+            for j in range(bs):
+                try:
+                    sched.submit(np.zeros((48, 8), np.float32),
+                                 rid=f"{tag}{k}-{j}")
+                except OverloadRejected:
+                    seen["shed"] += 1
+            sched.pump(decode_fn)
+            eng.update()
+            seen["level"] = max(seen["level"], bro.level)
+            clk.advance(30.0)
+
+    def poll(srv):
+        for p in ("/metrics", "/healthz", "/slo", "/traces?n=8"):
+            with urllib.request.urlopen(srv.url(p), timeout=5) as r:
+                assert r.status == 200 and r.read()
+
+    with StatusServer(port=0, registry=tel, slo_fn=eng.status,
+                      health_fn=lambda: {"status": "ok",
+                                         "brownout_level": bro.level},
+                      traces_fn=lambda: frec.recent(64)) as srv:
+        rounds("h", 6)
+        assert eng.worst_burn("fast") < 14.4 and not eng.alerts
+        poll(srv)
+        cost["s"] = 4 * deadline
+        rounds("b", 6)
+        assert eng.worst_burn("fast") >= 14.4
+        assert eng.alert_active("fast")
+        poll(srv)
+        cost["s"] = 0.01
+        clk.advance(max(eng.windows.values()) + 60.0)
+        rounds("r", 8)
+        poll(srv)
+
+    fast = [a for a in eng.alerts if a["window"] == "fast"]
+    assert len(fast) == 1 and not eng.alert_active("fast")
+    slowest = fast[0]["postmortem"]["slowest_requests"]
+    assert slowest[0]["rid"].startswith("b")
+    assert all(r["rid"] and r["cause"] for r in slowest)
+    assert len(pm.recent("slo_burn")) == len(eng.alerts)
+    assert seen["level"] >= 2 and seen["shed"] >= 1
+    assert bro.level == 0
+    assert int(tel.counter("slo_miss")) > 0

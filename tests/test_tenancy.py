@@ -605,3 +605,120 @@ def test_router_charges_session_quota_per_join():
     assert ten.inflight("acme") == 0      # released at leave
     router.join("s3", model="a", tenant="acme")   # re-admitted
     router.leave("s3")
+
+
+# -- scenario: three tenants, two models, one plane -----------------------
+
+def test_scenario_shared_plane_isolates_models_and_tenants(obs_lint):
+    """gold (realtime), silver (standard) and bulk (batch) share two
+    model groups whose decoders stamp their model id on every
+    transcript, through a steady mix, a quota flood and a brownout
+    flood on the scripted clock. No micro-batch mixed models and every
+    transcript is its own model's; the flooding tenant peaks at exactly
+    its quota with the overflow rejected, and every tenant's inflight
+    returns to 0; under brownout batch sheds first, standard only at
+    level 2, realtime never and always inside its deadline; the plane
+    recovers; the model+tenant labeled telemetry lints clean."""
+    from deepspeech_tpu.resilience.brownout import BrownoutController
+    from deepspeech_tpu.serving import OverloadRejected
+
+    quotas = {"gold": 6, "silver": 8, "bulk": 12}
+    clock = Clock()
+    tel = ServingTelemetry()
+    batches, model_of, expected = [], {}, {}
+
+    def decoder(mid):
+        def fn(batch, plan):
+            uids = [int(batch["features"][i].sum())
+                    for i in range(plan.n_valid)]
+            batches.append((mid, uids))
+            return [f"{mid}:{u}" for u in uids]
+        return fn
+
+    reg = ModelRegistry()
+    for mid in ("a", "b"):
+        reg.add_group(mid, ReplicaPool(
+            [Replica(f"{mid}-r{k}", decoder(mid), telemetry=tel,
+                     clock=clock) for k in range(2)],
+            clock=clock, telemetry=tel))
+    ten = _tenancy(**quotas)
+    bro = BrownoutController(enter_pressure=0.75, exit_pressure=0.0,
+                             shed_pressure=0.9, hold_s=0.0, clock=clock,
+                             registry=tel)
+    sched = MicroBatchScheduler(EDGES, 4, max_queue=24,
+                                default_deadline=0.05, clock=clock,
+                                telemetry=tel, registry=reg, tenancy=ten,
+                                brownout=bro)
+    rng = np.random.default_rng(7)
+
+    def submit(tenant, model, shed):
+        uid = len(model_of) + 1
+        model_of[uid] = model
+        feat = _feat(int(rng.integers(4, max(EDGES), endpoint=True)))
+        feat[0, 0] = uid
+        clock.t += 0.0005
+        try:
+            rid = sched.submit(feat, model=model, tenant=tenant)
+        except TenantQuotaExceeded:
+            return shed.append((tenant, "quota"))
+        except OverloadRejected:
+            return shed.append((tenant, "brownout"))
+        expected[rid] = f"{model}:{uid}"
+        return rid
+
+    steady = []
+    cycle = [("gold", "a"), ("silver", "b"), ("bulk", "a"),
+             ("gold", "a"), ("silver", "b"), ("bulk", "b")]
+    for k in range(24):
+        submit(*cycle[k % 6], steady)
+        clock.t += 0.0015
+        sched.pump()
+    sched.drain()
+    assert steady == [] and sched.pending == 0
+
+    over = []
+    admitted = sum(submit("bulk", "ab"[k % 2], over) is not None
+                   for k in range(20))
+    assert admitted == ten.peak("bulk") == quotas["bulk"]
+    assert over == [("bulk", "quota")] * (20 - quotas["bulk"])
+    sched.drain()
+
+    flood = []
+    for k in range(quotas["bulk"]):
+        submit("bulk", "ab"[k % 2], flood)
+    for k in range(quotas["silver"]):      # fill passes enter_pressure
+        submit("silver", "b", flood)
+    assert bro.level >= 1
+    for k in range(4):                     # batch sheds at level 1
+        submit("bulk", "a", flood)
+    gold = [submit("gold", "a", flood) for _ in range(2)]
+    submit("silver", "b", flood)           # fill passes shed_pressure
+    assert bro.level >= 2
+    gold.append(submit("gold", "a", flood))
+    first = {}
+    for k, (tenant, _) in enumerate(flood):
+        first.setdefault(tenant, k)
+    assert first["bulk"] < first["silver"] and "gold" not in first
+    assert all(rid is not None for rid in gold)
+    sched.drain()
+
+    for _ in range(4):
+        bro.update(0.0, now=clock.t)
+        clock.t += 0.001
+    assert bro.level == 0
+    assert submit("bulk", "a", []) is not None
+    sched.drain()
+
+    assert set(sched.results) == set(expected)
+    for rid, text in expected.items():
+        assert sched.results[rid].status == "ok"
+        assert sched.results[rid].text == text
+    assert all(model_of[u] == mid for mid, uids in batches for u in uids)
+    assert all(ten.peak(x) <= quotas[x] and ten.inflight(x) == 0
+               for x in quotas)
+    c = tel.snapshot()["counters"]
+    assert not [k for k in c if k.startswith("slo_miss")
+                and 'tenant="gold"' in k]
+    assert sum(v for k, v in c.items() if k.startswith("slo_ok")
+               and 'tenant="gold"' in k) == 8 + len(gold)
+    assert obs_lint(tel) == []

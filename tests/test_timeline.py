@@ -1,7 +1,8 @@
 """Fleet event timeline: ledger, correlation engine, flight recorder.
 
-Covers the ISSUE 18 core surface in isolation (the scripted fault-day
-integration lives in ``--bench=incident_timeline``): EventLog ring
+Covers the ISSUE 18 core surface in isolation, then (the scenario at
+the end) a scripted fault day through the real controllers that must
+fold into ONE incident: EventLog ring
 semantics on an injected clock, the process-wide install/clear seam's
 production-default cost path (publish is a no-op returning None when
 no log is installed), causal folding in IncidentCorrelator — join via
@@ -395,3 +396,169 @@ def test_incident_report_tool_renders_replayed_stream(tmp_path):
     assert "root=breaker_open" in out.stdout
     assert "resolved (breaker_close)" in out.stdout
     assert "orphan reactions: 0" in out.stdout
+
+
+# -- scenario: a scripted fault day is ONE incident -----------------------
+
+def test_scenario_fault_day_through_real_controllers_is_one_incident(
+        obs_lint, postmortems):
+    """The real pool, breakers, gateway, autoscaler (with the vertical
+    actuator), handoff router and an episode-relative fault plan on one
+    virtual clock: a trough starts a drain, which arms a fault that
+    fires twice on the only routable peer; its breaker opens; the
+    controller cancels the drain and the peer's pinned sessions
+    live-migrate; pressure inside the horizontal cooldown takes a
+    vertical step; a probe closes the breaker. The correlator must
+    fold that into exactly one incident rooted at the first fault fire
+    and resolved by the breaker close, with no orphan reaction and the
+    exact per-kind counts the script implies; ``incident_report``
+    replayed over the JSONL reconstructs it; nothing is lost; all three
+    streams lint clean."""
+    from collections import Counter
+
+    import numpy as np
+
+    from scenario import ChunkLogManager
+    from deepspeech_tpu.resilience import (CircuitBreaker, FaultPlan,
+                                           FaultSpec, Retry, faults)
+    from deepspeech_tpu.serving import (AutoscaleController,
+                                        MicroBatchScheduler,
+                                        MigrationController,
+                                        PooledSessionRouter, Replica,
+                                        ReplicaPool, ServingTelemetry)
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import incident_report
+    finally:
+        sys.path.pop(0)
+
+    clock = Clock()
+    tel = ServingTelemetry()
+    log = tl.install(_log(clock, registry=tel))
+    lines = []
+    log.add_listener(lambda ev: lines.append(
+        json.dumps(EventLog.to_record(ev), ensure_ascii=False)))
+    series = MetricSeries(registry=tel, clock=clock, interval_s=0.02,
+                          names=("autoscale_pressure",
+                                 "autoscale_replicas"))
+    pm = postmortems
+    corr = IncidentCorrelator(quiet_s=2.0, clock=clock, series=series,
+                              registry=tel,
+                              postmortem_fn=pm.write).attach(log)
+    chunk_log = []
+
+    def replica(rid):
+        return Replica(
+            rid, lambda batch, plan: ["ok"] * plan.n_valid,
+            telemetry=tel, clock=clock,
+            session_factory=lambda: ChunkLogManager(chunk_log),
+            breaker=CircuitBreaker(name=f"b{rid}", failure_threshold=2,
+                                   cooldown_s=0.5, clock=clock,
+                                   registry=tel))
+
+    pool = ReplicaPool([replica("r0"), replica("r1")], clock=clock,
+                       telemetry=tel, drain_window_s=0.25, handoff=True)
+    sched = MicroBatchScheduler(
+        (64, 128), 2, max_queue=24, default_deadline=0.05,
+        default_timeout=60.0, max_attempts=8, clock=clock,
+        telemetry=tel, pool=pool,
+        retry_backoff=Retry(base_s=0.01, max_s=0.01, jitter=0.0,
+                            name="gateway_dispatch"))
+    mig = MigrationController(telemetry=tel, clock=clock,
+                              postmortem_fn=pm.write)
+    router = PooledSessionRouter(pool, migrator=mig)
+    sids = []
+    while len(sids) < 8 or not (pool.pins_on("r0")
+                                and pool.pins_on("r1")):
+        sids.append(f"s{len(sids)}")
+        router.join(sids[-1])
+    router.step({sid: "c0" for sid in sids})
+    ctrl = AutoscaleController(
+        pool, replica, scheduler=sched, min_replicas=1, max_replicas=2,
+        up_pressure=0.45, down_pressure=0.2, hold_s=0.05,
+        cooldown_s=10.0, rows_per_replica=4, drain_window_s=0.25,
+        vertical_max_batch=4, vertical_hold_s=0.02,
+        vertical_cooldown_s=5.0, handoff=True, telemetry=tel,
+        clock=clock, postmortem_fn=pm.write)
+    faults.install(FaultPlan([FaultSpec(
+        "gateway.dispatch", "unavailable", prob=1.0, count=2,
+        on_event="autoscale.drain_begin", arm_for_s=5.0)],
+        clock=clock, registry=tel))
+
+    def submit(n):
+        return [sched.submit(np.zeros((32, 13), np.float32),
+                             deadline=5.0, timeout=60.0)
+                for _ in range(n)]
+
+    def pump_until_done(rids):
+        for _ in range(60):
+            if all(r in sched.results for r in rids):
+                return
+            clock.t += 0.05
+            sched.pump()
+
+    try:
+        ctrl.tick()                    # trough hold starts
+        clock.t = 0.06
+        ctrl.tick()                    # drain_begin arms the spec
+        victim = ctrl.status()["victim"]
+        peer = "r1" if victim == "r0" else "r0"
+        rids = submit(4)
+        clock.t = 0.08
+        sched.pump()                   # two fires open the peer
+        clock.t = 0.10
+        ctrl.tick()                    # breaker_open -> drain_cancel
+        moved = pool.pins_on(peer)
+        router.step({sid: "c1" for sid in sids})
+        rids += submit(8)
+        clock.t = 0.12
+        ctrl.tick()                    # holdoff, vertical hold starts
+        clock.t = 0.15
+        ctrl.tick()                    # vertical_up inside the cooldown
+        pump_until_done(rids)
+        clock.t = max(clock.t, 0.75)   # past the breaker cooldown
+        rids += submit(8)
+        pump_until_done(rids)
+        pool.maintain(clock.t)
+        router.step({sid: "c2" for sid in sids})
+        for sid in sids:
+            router.leave(sid)
+        router.flush()
+        clock.t += 2.5
+        corr.poll()                    # quiet-close
+    finally:
+        faults.clear()
+
+    assert len(corr.closed) == 1 and not corr.open
+    inc = corr.closed[0]
+    assert inc["root_kind"] == "fault_fire"
+    assert (inc["resolution"], inc["resolution_kind"]) \
+        == ("resolved", "breaker_close")
+    assert corr.orphans == 0 and moved >= 1
+    assert dict(Counter(ev["kind"] for ev in log.recent())) == {
+        "init": 1, "drain_begin": 1, "fault_armed": 1, "fault_fire": 2,
+        "breaker_open": 1, "drain_cancel": 1, "holdoff": 1,
+        "migration": moved, "vertical_up": 1, "breaker_half_open": 1,
+        "breaker_close": 1}
+    assert inc["n_events"] == 9 + moved
+    assert {e["kind"] for e in inc["chain"]} >= {
+        "drain_begin", "fault_armed", "fault_fire", "breaker_open",
+        "drain_cancel", "migration", "vertical_up",
+        "breaker_half_open", "breaker_close"}
+    assert set(inc["replicas"]) == {"r0", "r1"}
+    assert inc["metrics"]["before"] is not None \
+        and inc["metrics"]["after"] is not None \
+        and inc["metrics"]["during"]
+    assert (mig.migrations, mig.fallbacks) == (moved, 0)
+    assert (ctrl.vertical_ups, ctrl.drain_cancels) == (1, 1)
+
+    report = incident_report.aggregate([json.loads(ln) for ln in lines])
+    assert len(report["incidents"]) == 1 and report["orphans"] == 0
+    assert report["incidents"][0]["n_events"] == inc["n_events"]
+    assert report["incidents"][0]["root_kind"] == "fault_fire"
+    assert "incident #" in incident_report.render(report)
+
+    assert [sched.results[r].status for r in rids] == ["ok"] * 20
+    assert [router.final(sid) for sid in sids] == ["c0 c1 c2"] * len(sids)
+    assert len(pm.recent("incident")) == 1
+    assert obs_lint(lines, pm, tel) == []

@@ -329,14 +329,14 @@ def test_check_obs_schema_rejects_bad_incident_postmortems(tmp_path):
 
 
 def test_check_tier1_budget_covers_timeline_suite(tmp_path):
-    """The timeline tests (tests/test_timeline.py) and the
-    incident_timeline bench smoke (tests/test_bench.py) sit under the
-    same per-test budget as every other quick-suite file."""
+    """The timeline tests (tests/test_timeline.py), the fault-day
+    scenario included, sit under the same per-test budget as every
+    other quick-suite file."""
     out = _run_budget(tmp_path, "\n".join([
         "0.40s call     tests/test_timeline.py::"
         "test_correlator_folds_cause_chain_into_one_incident",
-        "2.10s call     tests/test_bench.py::"
-        "test_bench_incident_timeline_smoke",
+        "2.10s call     tests/test_timeline.py::"
+        "test_scenario_fault_day_through_real_controllers_is_one_incident",
     ]))
     assert out.returncode == 0, out.stderr
     out = _run_budget(tmp_path,
@@ -1044,39 +1044,6 @@ def test_check_obs_schema_autoscale_rules(tmp_path):
     assert "'direction'" in out.stderr
     assert "'to_replicas'" in out.stderr
     assert "'from_replicas'" in out.stderr
-
-
-def test_check_obs_schema_availability_rule(tmp_path):
-    """``kind="availability"`` postmortems (the availability bench's
-    end-of-day verdict) must quantify the claim: a numeric
-    ``availability_pct`` and the admitted population it was measured
-    over."""
-    import io
-
-    from deepspeech_tpu.resilience import postmortem
-
-    sink = io.StringIO()
-    postmortem.configure(sink=sink)
-    try:
-        postmortem.record("availability", trigger="bench_availability",
-                          availability_pct=99.5, admitted=240, lost=0,
-                          slo_attainment=98.0)
-    finally:
-        postmortem.configure()
-    out = _run_obs_schema(tmp_path, sink.getvalue())
-    assert out.returncode == 0, out.stderr
-
-    for missing in ("availability_pct", "admitted"):
-        rec = json.loads(sink.getvalue())
-        del rec[missing]
-        out = _run_obs_schema(tmp_path, json.dumps(rec) + "\n")
-        assert out.returncode == 1
-        assert missing in out.stderr
-    # A boolean availability_pct is not a percentage.
-    rec = json.loads(sink.getvalue())
-    rec["availability_pct"] = True
-    out = _run_obs_schema(tmp_path, json.dumps(rec) + "\n")
-    assert out.returncode == 1
 
 
 def test_check_obs_schema_revision_and_rescore_rules(tmp_path):

@@ -113,6 +113,23 @@ def test_ctc_vjp_folds_gamma_without_a_scatter(v5e_chip):
         == ["ctc_alpha", "ctc_gamma"]
 
 
+def test_store_blob_of_a_described_chip_names_its_device(v5e_chip):
+    """What ``tools/aot_*.py --emit-store`` relies on: an executable
+    compiled for a described chip (never loaded) serializes, and its
+    blob carries that chip's device id for the host that loads it."""
+    import pickle
+
+    import jax.numpy as jnp
+
+    from deepspeech_tpu.utils import aotstore
+
+    comp = jax.jit(lambda v: v * 2, in_shardings=v5e_chip,
+                   out_shardings=v5e_chip).lower(
+        jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
+    ids = pickle.loads(aotstore.serialize_compiled(comp))[3]
+    assert ids == [d.id for d in v5e_chip.device_set]
+
+
 def test_on_tpu_assume_override(monkeypatch):
     """DS2N_ASSUME_TPU=1 (tools/aot_tpu.py): 'auto' impls must resolve
     exactly as on the chip while the runtime backend is cpu, so the

@@ -14,11 +14,13 @@ journal-recovery re-pin, or stay — with the session preserved on all
 of them. Router adoption conflicts (sid already live, adopt racing a
 pin) keep ONE owner and zero lost chunks.
 
-Everything here is model-free: duck-typed managers that speak the
-real snapshot codec (real ``StreamSnapshot`` payloads through
+The contract tests are model-free: duck-typed managers that speak
+the real snapshot codec (real ``StreamSnapshot`` payloads through
 ``snapshot_to_bytes``), real routers/pools/breakers, injected clocks.
-Bit-identity of model-backed transfers is --bench=xhost_migration's
-job (and tests/test_migration.py's for the in-process plane).
+The scenarios at the end put it together: real (tiny) streaming
+sessions cross a real socket and finish bit-identically on the other
+host, and scripted ``transport.*`` flaps resolve by retry or down the
+ladder (tests/test_migration.py has the in-process plane).
 """
 
 import numpy as np
@@ -436,3 +438,140 @@ def test_adopt_lands_on_prior_pin_one_owner_zero_lost_chunks():
     router.leave("a")
     router.flush()
     assert router.final("a") == "c0 c1 c2"
+
+
+# -- scenarios ------------------------------------------------------------
+
+@pytest.mark.parametrize("decode", ["greedy", "beam"])
+def test_scenario_live_sessions_cross_a_socket_bit_identical(
+        tiny_streaming, obs_lint, postmortems, decode):
+    """Two live streams on host A are shipped mid-utterance through a
+    real TCP listener to host B (disjoint pools and managers) after the
+    listener has been thrown garbage: every transfer ends ``remote``,
+    A no longer owns the sids, B imported each once, and the streams
+    finish on B with the transcripts of the never-migrated reference,
+    bit for bit. Telemetry and ``migration`` postmortems lint clean."""
+    import socket
+
+    from deepspeech_tpu.serving import StreamingSessionManager
+
+    cfg, tok, params, stats = tiny_streaming
+    tel = ServingTelemetry()
+    pm = postmortems
+
+    def mgr():
+        return StreamingSessionManager(cfg, params, stats, tok,
+                                       chunk_frames=64, capacity=2,
+                                       decode=decode, telemetry=tel)
+
+    def host(prefix):
+        pool = ReplicaPool([Replica(f"{prefix}0", telemetry=tel,
+                                    session_factory=mgr)], telemetry=tel)
+        return PooledSessionRouter(pool)
+
+    sids = ["x0", "x1"]
+    rng = np.random.default_rng(41)
+    feats = {sid: rng.standard_normal((4 * 64, 13)).astype(np.float32)
+             for sid in sids}
+
+    def feed(router, k0, k1):
+        for k in range(k0, k1):
+            router.step({sid: feats[sid][64 * k:64 * (k + 1)]
+                         for sid in sids})
+
+    def finish(router):
+        for sid in sids:
+            router.leave(sid)
+        router.flush()
+        return {sid: router.final(sid) for sid in sids}
+
+    ref_router = host("ref")
+    for sid in sids:
+        ref_router.join(sid)
+    feed(ref_router, 0, 4)
+    want = finish(ref_router)
+
+    router_a, router_b = host("a"), host("b")
+    rx = HandoffReceiver(router_b, name="host-b", telemetry=tel)
+    lsn = HandoffListener(rx, port=0)
+    try:
+        with socket.create_connection((lsn.host, lsn.port),
+                                      timeout=5.0) as sk:
+            sk.sendall(b"\xffgarbage-not-a-frame" * 7)
+            sk.shutdown(socket.SHUT_WR)
+            while sk.recv(65536):
+                pass
+        ctrl = RemoteMigrationController(
+            telemetry=tel, postmortem_fn=pm.write,
+            retry=Retry(attempts=3, base_s=0.01, jitter=0.0,
+                        budget_s=1.0, name="handoff",
+                        sleep=lambda s: None))
+        for sid in sids:
+            router_a.join(sid)
+        feed(router_a, 0, 2)
+        tx = SocketTransport(lsn.host, lsn.port, timeout_s=10.0)
+        assert [ctrl.migrate_remote(router_a, sid, tx)
+                for sid in sids] == ["remote", "remote"]
+        for sid in sids:
+            with pytest.raises(KeyError):
+                router_a.home_of(sid)
+        feed(router_b, 2, 4)
+        assert finish(router_b) == want
+    finally:
+        lsn.close()
+    assert rx.imports == len(sids) and sorted(rx.imported_sids) == sids
+    assert ctrl.remote_handoffs == len(sids)
+    assert ctrl.remote_fallbacks == 0
+    assert any(k.startswith("session_migrations{")
+               and 'replica="peer:' in k for k in tel.counters)
+    assert {p["outcome"] for p in pm.recent("migration")} \
+        == {"remote_handoff"}
+    assert obs_lint(tel, pm) == []
+
+
+@pytest.mark.parametrize("point,count,want", [
+    ("transport.send", 2, "remote"),     # flaps twice, third try lands
+    ("transport.ack", 1, "remote"),      # ACK lost: retry is a duplicate
+    ("transport.send", 99, "local"),     # hard down: retry exhausted
+])
+def test_scenario_transport_flaps_retry_or_fall_down_the_ladder(
+        point, count, want):
+    """A scripted ``transport.*`` fault under one handoff: a send flap
+    is retried to ``remote``; a lost ACK retries onto the idempotent
+    duplicate path, so the peer imports exactly once; a peer that stays
+    down exhausts the retry (published on the timeline) and the session
+    re-pins locally. No chunk is lost on any rung."""
+    from deepspeech_tpu.obs import timeline
+    from deepspeech_tpu.obs.timeline import EventLog
+    from deepspeech_tpu.resilience import FaultPlan, FaultSpec, faults
+
+    clock, tel, _, router_a = _host()
+    _, tel_b, _, router_b = _host()
+    rx = HandoffReceiver(router_b, name="host-b", telemetry=tel_b)
+    ctrl = _ctrl(tel, clock)
+    log = timeline.install(EventLog(registry=tel))
+    kinds = []
+    log.add_listener(lambda ev: kinds.append(
+        (EventLog.to_record(ev)["kind"],
+         EventLog.to_record(ev).get("detail", {}).get("status"))))
+    router_a.join("a")
+    router_a.step({"a": "c0"})
+    faults.install(FaultPlan([FaultSpec(point, "unavailable",
+                                        count=count)], seed=7,
+                             clock=clock, registry=tel))
+    try:
+        out = ctrl.migrate_remote(router_a, "a",
+                                  LoopbackTransport(rx, name="host-b"))
+    finally:
+        faults.clear()
+        timeline.clear()
+    assert out == want
+    owner = router_b if want == "remote" else router_a
+    assert rx.imports == (1 if want == "remote" else 0)
+    assert (("remote_ack", "duplicate") in kinds) \
+        == (point == "transport.ack")
+    assert (("retry_exhausted", None) in kinds) == (want == "local")
+    owner.step({"a": "c1"})
+    owner.leave("a")
+    owner.flush()
+    assert owner.final("a") == "c0 c1"

@@ -23,17 +23,21 @@ Covers the ISSUE-16 contracts at both layers:
 """
 
 import dataclasses
+import io
 import json
+import os
 
 import numpy as np
 import pytest
+
+from scenario import counter_family
 
 from deepspeech_tpu.serving import Replica, ServingTelemetry, WarmStore
 from deepspeech_tpu.serving.warmstore import default_store, store_tier
 from deepspeech_tpu.utils import aotstore
 from deepspeech_tpu.utils.aotstore import (AotStore, StoreKey,
                                            parse_filename)
-from deepspeech_tpu.utils.cache import (ShapeBucketCache,
+from deepspeech_tpu.utils.cache import (USAGE_SIDECAR, ShapeBucketCache,
                                         load_rung_usage,
                                         save_rung_usage, seed_usage)
 
@@ -212,37 +216,11 @@ def test_seed_usage_bounded_by_max_shapes():
 # -- warmstore: end to end on a tiny inferencer ---------------------------
 
 @pytest.fixture(scope="module")
-def tiny_infer_factory():
-    import jax
-    import jax.numpy as jnp
-
-    from deepspeech_tpu.config import get_config
-    from deepspeech_tpu.data import CharTokenizer
-    from deepspeech_tpu.infer import Inferencer
-    from deepspeech_tpu.models import create_model
-
-    cfg = get_config("dev_slice")
-    cfg = dataclasses.replace(
-        cfg,
-        model=dataclasses.replace(cfg.model, rnn_hidden=32,
-                                  rnn_layers=1, conv_channels=(4, 4),
-                                  dtype="float32"),
-        data=dataclasses.replace(cfg.data, bucket_frames=EDGES,
-                                 batch_size=BS),
-        features=dataclasses.replace(cfg.features, num_features=NF),
-        decode=dataclasses.replace(cfg.decode, mode="greedy"))
-    tok = CharTokenizer.english()
-    model = create_model(cfg.model)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 64, NF), jnp.float32),
-                           jnp.full((1,), 64, jnp.int32), train=False)
-    params = variables["params"]
-    bstats = variables.get("batch_stats", {})
-
-    def mk():
-        return Inferencer(cfg, tok, params, bstats)
-
-    return mk
+def tiny_infer_factory(tiny_offline):
+    cfg = tiny_offline.cfg
+    cfg = dataclasses.replace(cfg, data=dataclasses.replace(
+        cfg.data, bucket_frames=EDGES, batch_size=BS))
+    return lambda: tiny_offline.inferencer(cfg)
 
 
 LADDER = [(1, 64), (2, 64)]
@@ -262,11 +240,6 @@ def _decode_ladder(inf):
     return texts
 
 
-def _counter_sum(tel, family):
-    return int(sum(v for k, v in tel.counters.items()
-                   if k.split("{", 1)[0] == family))
-
-
 @pytest.fixture(scope="module")
 def populated_store(tiny_infer_factory, tmp_path_factory):
     """One cold run: compile the 2-rung ladder, export every rung at
@@ -280,8 +253,8 @@ def populated_store(tiny_infer_factory, tmp_path_factory):
     ws.flush()
     assert inf.shape_cache.compiles == len(LADDER)
     assert len(ws.store.keys()) == len(LADDER)
-    assert _counter_sum(tel, "compile_cache_export") == len(LADDER)
-    assert _counter_sum(tel, "compile_cache_miss") == len(LADDER)
+    assert counter_family(tel, "compile_cache_export") == len(LADDER)
+    assert counter_family(tel, "compile_cache_miss") == len(LADDER)
     return root, texts
 
 
@@ -299,7 +272,7 @@ def test_restart_preloads_ladder_bit_identical(tiny_infer_factory,
     # The whole point: bit-identical decode, zero runtime compiles.
     assert texts == cold_texts
     assert inf.shape_cache.compiles == 0
-    assert _counter_sum(tel, "compile_cache_hit") == len(LADDER)
+    assert counter_family(tel, "compile_cache_hit") == len(LADDER)
     # Counters always carry rung + tier (the schema-lint contract).
     hit_keys = [k for k in tel.counters
                 if k.startswith("compile_cache_hit")]
@@ -308,6 +281,79 @@ def test_restart_preloads_ladder_bit_identical(tiny_infer_factory,
     assert tel.gauges[
         'warm_pct{replica="r0",tier="fp"}'] == 100.0
     assert rep.can_route(0.0)
+
+
+def _compiled_on(ids):
+    """``v * 2`` compiled for the devices ``ids`` of this platform in
+    that order (one device, or a mesh over several), and an argument
+    placed for it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    devs = np.array(jax.devices())[list(ids)]
+    sharding = NamedSharding(Mesh(devs, ("d",)), PartitionSpec("d"))
+    x = jax.device_put(jnp.arange(8.0), sharding)
+    return jax.jit(lambda v: v * 2.0).lower(x).compile(), x
+
+
+@pytest.mark.parametrize("ids", [[3], [5, 2, 7, 1]],
+                         ids=["one_device", "mesh_in_its_own_order"])
+def test_compiled_blob_carries_its_devices_and_reloads_on_them(ids):
+    """On a platform with eight devices an executable says in its blob
+    which devices it was compiled for, in its own order, and the
+    reloaded callable runs there (jax's payload alone loads it onto
+    every device of the backend)."""
+    import pickle
+
+    comp, x = _compiled_on(ids)
+    blob = aotstore.serialize_compiled(comp)
+    assert pickle.loads(blob)[3] == ids
+    out = aotstore.deserialize_compiled(blob)(x)
+    assert out.sharding == x.sharding
+    assert [s.device.id for s in out.addressable_shards] == ids
+    np.testing.assert_array_equal(np.asarray(out), np.arange(8.0) * 2.0)
+
+
+@pytest.mark.parametrize("ids,error", [
+    pytest.param(None, ValueError, id="written_before_ids_were_stored"),
+    pytest.param([99], KeyError, id="device_not_on_this_backend")])
+def test_blob_without_usable_device_ids_does_not_load(ids, error):
+    import jax
+    import pickle
+
+    comp, _ = _compiled_on([0])
+    parts = pickle.loads(aotstore.serialize_compiled(comp))[:3]
+    blob = pickle.dumps(parts if ids is None else parts + (ids,))
+    with pytest.raises(error):
+        aotstore.deserialize_compiled(blob)
+
+
+def test_stored_blob_without_device_ids_rejects_to_jit(
+        tiny_infer_factory, populated_store, tmp_path):
+    """A store written before the ids travelled with the blob: the rung
+    is rejected at preload and compiles through jit, instead of being
+    counted a hit and failing on the first request."""
+    import pickle
+    import shutil
+
+    root, cold_texts = populated_store
+    old_root = str(tmp_path / "old")
+    shutil.copytree(root, old_root)
+    ws = WarmStore(old_root, preset="dev_slice", background=False)
+    key = StoreKey("dev_slice", "fp", "base", *LADDER[0])
+    meta, payload = ws.store.get(key)
+    ws.store.put(key, pickle.dumps(pickle.loads(payload)[:3]),
+                 meta["format"], sig=meta["sig"])
+    tel = ServingTelemetry()
+    inf = tiny_infer_factory()
+    Replica.from_inferencer("r0", inf, telemetry=tel, warmstore=ws)
+    assert counter_family(tel, "compile_cache_reject") == 1
+    assert counter_family(tel, "compile_cache_hit") == len(LADDER) - 1
+    assert LADDER[0] not in inf.preloaded_forwards
+    ws.flush()
+    assert _decode_ladder(inf) == cold_texts
+    assert inf.shape_cache.compiles == 1
 
 
 def test_fingerprint_mismatch_rejects_to_jit(tiny_infer_factory,
@@ -327,7 +373,7 @@ def test_fingerprint_mismatch_rejects_to_jit(tiny_infer_factory,
     assert summary["rejects"] == len(LADDER)
     assert summary["hits"] == 0 and summary["warm_pct"] == 0.0
     assert inf.preloaded_forwards == {}
-    assert _counter_sum(tel, "compile_cache_reject") == len(LADDER)
+    assert counter_family(tel, "compile_cache_reject") == len(LADDER)
     texts = _decode_ladder(inf)
     assert texts == cold_texts          # jit fallback, same bytes
     assert inf.shape_cache.compiles == len(LADDER)
@@ -348,8 +394,8 @@ def test_signature_mismatch_rejects_single_rung(tiny_infer_factory,
         tel = ServingTelemetry()
         inf = tiny_infer_factory()
         Replica.from_inferencer("r0", inf, telemetry=tel, warmstore=ws)
-        assert _counter_sum(tel, "compile_cache_reject") == 1
-        assert _counter_sum(tel, "compile_cache_hit") == len(LADDER) - 1
+        assert counter_family(tel, "compile_cache_reject") == 1
+        assert counter_family(tel, "compile_cache_hit") == len(LADDER) - 1
         assert LADDER[0] not in inf.preloaded_forwards
         assert LADDER[1] in inf.preloaded_forwards
     finally:
@@ -387,3 +433,100 @@ def test_default_store_reads_env(tmp_path, monkeypatch):
     ws = default_store()
     assert isinstance(ws, WarmStore)
     assert ws.store.root == str(tmp_path / "ws")
+
+
+def test_scenario_restart_and_fleet_consumers_start_warm(
+        tiny_infer_factory, populated_store, obs_lint, postmortems):
+    """A restart under tracing plus the two fleet paths that bring a
+    replica up (autoscale scale-up, rolling swap to v2): the restarted
+    engine's trace has no compile event, its rung-usage sidecar marks
+    the ladder warm before traffic, each consumer leaves a
+    ``warm_start`` postmortem that avoided compiles, and everything
+    emitted passes the schema lint."""
+    from deepspeech_tpu import obs
+    from deepspeech_tpu.obs.metrics import MetricsRegistry
+    from deepspeech_tpu.serving import (AutoscaleController, ReplicaPool,
+                                        RolloutController)
+
+    root, cold_texts = populated_store
+    pm = postmortems
+
+    def store():
+        return WarmStore(root, preset="dev_slice", background=False,
+                         postmortem_fn=pm.write)
+
+    # Restart: the cold run's usage sidecar seeds the fresh cache, then
+    # the ladder preloads and decodes with tracing on.
+    cold = tiny_infer_factory()
+    _decode_ladder(cold)
+    sidecar = os.path.join(root, USAGE_SIDECAR)
+    save_rung_usage(cold.shape_cache, sidecar, preset="dev_slice")
+    tel = ServingTelemetry()
+    inf = tiny_infer_factory()
+    assert seed_usage(inf.shape_cache,
+                      load_rung_usage(sidecar)) == len(LADDER)
+    assert set(LADDER) <= set(inf.shape_cache.rung_usage())
+    trace = io.StringIO()
+    obs.configure(enabled=True, sink=trace, registry=MetricsRegistry())
+    try:
+        Replica.from_inferencer("r0", inf, telemetry=tel,
+                                warmstore=store())
+        assert _decode_ladder(inf) == cold_texts
+    finally:
+        obs.configure(enabled=False, registry=obs.registry())
+    assert not [ln for ln in trace.getvalue().splitlines()
+                if json.loads(ln).get("event") == "compile"]
+    assert inf.shape_cache.compiles == 0
+
+    # Autoscale scale-up: the newcomer preloads before it is routable.
+    tel_up = ServingTelemetry()
+
+    def factory(rid):
+        return Replica.from_inferencer(rid, tiny_infer_factory(),
+                                       telemetry=tel_up)
+
+    pool = ReplicaPool([factory("r0")], telemetry=tel_up)
+    ctrl = AutoscaleController(pool, factory, max_replicas=2,
+                               slo_burn_budget=1.0, hold_s=0.05,
+                               telemetry=tel_up, warmstore=store(),
+                               postmortem_fn=pm.write)
+    tel_up.gauge("slo_burn_rate", 2.0,       # pressure 1.0, held
+                 labels={"window": "1h", "tier": "fp"})
+    ctrl.tick(0.0)
+    ctrl.tick(1.0)
+    assert len(pool) == 2 and ctrl.scale_ups == 1
+
+    # Rolling swap: the v2 ladder is in the store (same shapes, so the
+    # base entries re-keyed); every swapped replica re-admits warm.
+    tel_ro = ServingTelemetry()
+    ws = store()
+    for key in ws.store.keys():
+        meta, payload = ws.store.get(key)
+        ws.store.put(dataclasses.replace(key, version="v2"), payload,
+                     meta["format"], sig=meta["sig"])
+    pool_ro = ReplicaPool(
+        [Replica.from_inferencer(f"r{k}", tiny_infer_factory(),
+                                 telemetry=tel_ro, warmstore=ws)
+         for k in range(2)], telemetry=tel_ro)
+
+    def v2_backend(rep):
+        inf2 = tiny_infer_factory()
+        return {"decode_fn": lambda batch, plan:
+                inf2.decode_batch_bucketed(batch, plans=[plan]),
+                "session_factory": None, "inferencer": inf2}
+
+    ro = RolloutController(pool_ro, v2_backend, to_version="v2",
+                           telemetry=tel_ro, warmstore=ws,
+                           drain_window_s=0.0, postmortem_fn=pm.write)
+    ro.run(sleep_s=0.0)
+    assert ro.state == "done"
+
+    warm = [p for p in pm.recent("warm_start")]
+    by_trigger = {t: [p for p in warm if p["trigger"] == t]
+                  for t in ("scale_up", "rollout_readmit")}
+    assert len(by_trigger["scale_up"]) == 1
+    assert len(by_trigger["rollout_readmit"]) == 2
+    for p in by_trigger["scale_up"] + by_trigger["rollout_readmit"]:
+        assert p["compiles_avoided"] == len(LADDER)
+        assert p["warm_pct"] == 100.0
+    assert obs_lint(tel, tel_up, tel_ro, pm) == []

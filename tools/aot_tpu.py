@@ -7,7 +7,7 @@ against a mesh of those abstract devices runs the REAL TPU compiler
 (Mosaic included for Pallas kernels when they compile ahead-of-time):
 
 - HBM accounting per sweep point (argument/temp/output bytes vs the
-  chip's 16 GB) — validates BENCH_BATCH choices before chip time.
+  chip's 16 GB) — validates batch choices before chip time.
 - TPU-optimized HLO — e.g. whether XLA's all-reduce combiner collapses
   the per-leaf gradient psums (the CPU-backend HLO shows 107 separate
   all-reduces for the DP step; the TPU pipeline is what counts).

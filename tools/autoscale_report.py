@@ -11,14 +11,11 @@ steps, then a summary — scale-ups/downs split horizontal vs vertical
 (the ``actuator`` column: ``horizontal`` | ``ladder`` | ``tier_mix``),
 drain cancels, fleet size range, re-pins charged to resizes, and
 approximate replica-seconds (fleet size integrated over the event
-span, the cost axis the ``--bench=autoscale`` acceptance compares
-against a static fleet). Drains show a handoff-vs-drain mode column
+span: the cost axis on which an autoscaled fleet is compared with a
+static one). Drains show a handoff-vs-drain mode column
 (a ``handoff`` drain live-migrated its pinned sessions,
 ``serving/migration.py``), and ``kind="migration"`` postmortems fold
-into migration counts in the summary. When the log carries a
-``kind="availability"`` postmortem (``--bench=availability``'s
-end-of-day verdict), an availability row joins the summary, with the
-replay's migration count when present.
+into migration counts in the summary.
 
 Usage:
     python tools/autoscale_report.py autoscale.jsonl [more.jsonl ...]
@@ -50,11 +47,6 @@ def _is_episode(rec: dict) -> bool:
         and rec.get("kind") == "autoscale"
 
 
-def _is_availability(rec: dict) -> bool:
-    return rec.get("event") == "postmortem" \
-        and rec.get("kind") == "availability"
-
-
 def _is_migration(rec: dict) -> bool:
     return rec.get("event") == "postmortem" \
         and rec.get("kind") == "migration"
@@ -71,8 +63,6 @@ def aggregate(records: List[dict]) -> dict:
     events = sorted((r for r in records if _is_event(r)),
                     key=lambda r: r.get("t", 0.0))
     episodes = [r for r in records if _is_episode(r)]
-    availability = next(
-        (r for r in records if _is_availability(r)), None)
     # Live-migration postmortems (serving/migration.py): one per
     # session handoff or fallback-to-drain.
     migrations = [r for r in records if _is_migration(r)]
@@ -114,7 +104,6 @@ def aggregate(records: List[dict]) -> dict:
             t_prev = t
     return {
         "timeline": events, "episodes": episodes,
-        "availability": availability,
         "ups": ups, "downs": downs,
         "vertical_ups": vertical_ups,
         "vertical_downs": vertical_downs,
@@ -212,16 +201,6 @@ def render(agg: dict) -> str:
                  f"migration_fallbacks={agg['migration_fallbacks']}")
     lines.append(f"  fleet_size=[{agg['size_min']}..{agg['size_max']}] "
                  f"replica_seconds~{agg['replica_seconds']}")
-    avail = agg.get("availability")
-    if avail is not None:
-        slo = avail.get("slo_attainment")
-        lines.append(
-            f"  availability={avail.get('availability_pct')}% "
-            f"admitted={avail.get('admitted')} "
-            f"lost={avail.get('lost', 0)}"
-            + (f" slo_attainment={slo}" if slo is not None else "")
-            + (f" migrations={avail['sessions_migrated']}"
-               if "sessions_migrated" in avail else ""))
     return "\n".join(lines)
 
 

@@ -2,8 +2,7 @@
 """Fail if a fault-plan JSON file violates the FaultPlan schema.
 
 Chaos schedules ride config, not code: a plan exported via
-``DS2_FAULT_PLAN=/path/plan.json`` (or ``BENCH_FAULT_PLAN`` for the
-chaos bench) is parsed at import time deep inside whatever entry point
+``DS2_FAULT_PLAN=/path/plan.json`` is parsed at import time deep inside whatever entry point
 it lands in — a typo'd kind or an inverted window would otherwise
 surface as a crash mid-run, long after the operator walked away. This
 lint front-loads that failure. The schema is owned by

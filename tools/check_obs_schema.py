@@ -63,11 +63,6 @@ the same tooling (``tools/trace_report.py``, dashboards). The contract:
   fleet moved, from what size to what size, can't be replayed against
   the traffic curve (vertical episodes carry equal from/to: the fleet
   didn't move, the rung did);
-- postmortem records with ``kind="availability"`` (the availability
-  bench's end-of-day verdict, one per replay) additionally carry a
-  numeric ``availability_pct`` and a numeric ``admitted`` — an
-  availability claim without the percentage and the population it was
-  measured over is unauditable;
 - the fairness families (``slo_ok``, ``slo_miss``): a ``tenant``
   label never travels without a ``model`` label — per-tenant SLO
   attainment is only comparable within one model's serving plane
@@ -287,13 +282,6 @@ def validate_record(rec) -> List[str]:
                         or isinstance(rec.get(key), bool):
                     problems.append(
                         f"autoscale postmortem missing/invalid "
-                        f"{key!r} (number)")
-        if rec.get("kind") == "availability":
-            for key in ("availability_pct", "admitted"):
-                if not isinstance(rec.get(key), (int, float)) \
-                        or isinstance(rec.get(key), bool):
-                    problems.append(
-                        f"availability postmortem missing/invalid "
                         f"{key!r} (number)")
         if rec.get("kind") == "migration":
             for key in ("outcome", "reason", "src_replica",

@@ -24,7 +24,7 @@ When the stream carries ``model`` / ``tenant`` attributes (the
 multi-model multi-tenant gateway, ``serving/registry.py`` /
 ``serving/tenancy.py``), per-model and per-tenant attainment sections
 are added (requests, ok count, SLO %, p95) — the isolation evidence
-the multitenant bench asserts on. Mixed-era streams are fine: records
+the tenancy scenario asserts on. Mixed-era streams are fine: records
 without the keys simply don't join those sections.
 
 Rescore-pass traces (``kind="rescore"``, the async LM second pass's

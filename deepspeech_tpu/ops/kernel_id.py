@@ -15,9 +15,11 @@ fact, not a name:
 
   variant  ``resident`` (whole weight matrix a VMEM block), ``blocked``
            (weight columns moved by the pipeline over a second grid
-           axis, from wherever XLA left the matrix), ``blocked_pinned``
-           (the same grid, but the kernel copies the matrix into a
-           VMEM scratch once and slices its column blocks from there),
+           axis, from wherever XLA left the matrix: a matrix past
+           ``rnn_pallas._PINNED_VMEM_CAP``), ``blocked_pinned`` (the
+           same grid, but the kernel copies the matrix into a VMEM
+           scratch once and slices its column blocks from there: the
+           GRU's forward and backward kernel under the cap),
            ``resident_q`` / ``blocked_q`` (as the first two, with int8
            weights)
   reverse  1 if the scan runs from the last frame to the first, else

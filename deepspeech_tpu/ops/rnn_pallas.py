@@ -22,24 +22,27 @@ partials land in a VMEM scratch, the GRU elementwise update firing on
 the last block. It is NOT there because the weights must cross HBM
 every step (PERF.md section 6, PR 22 finding 1: they need not, and a
 step that does fetch them takes 29.5 us against 17.3). Who places the
-matrix:
-
-- forward (``_gru_kernel_blocked``): XLA's memory-space assignment. It
-  has put the operand in VMEM (``S(1)``) in every trace so far, and the
-  BlockSpec pipeline then copies VMEM to VMEM.
-- backward (``_gru_bwd_kernel_blocked``): the kernel itself, when
-  ``_pinned_bwd_vmem_limit`` says the call fits ``_PINNED_VMEM_CAP``
-  (variant ``blocked_pinned``): the operand is taken in ``pl.ANY``, one
-  DMA at the first grid step copies it whole into a VMEM scratch, and
-  every column block is a slice of that scratch; the call raises its
-  own scoped limit from its shapes. Past the cap (f32 dots at H=1760,
-  wider layers) the BlockSpec pipeline streams the blocks from wherever
-  XLA left the operand (variant ``blocked``): from HBM that is the
-  whole matrix every step, the honest cost of a matrix that cannot
-  live in VMEM. One kernel body serves both; only where a column block
-  comes from differs. The backward kernel needs the blocks once per
-  step: it pipelines the ``dgates @ W^T`` contraction one step behind
-  the gate recompute (SURVEY.md §7 hard-parts #2).
+matrix: the kernel itself, in both directions (``_gru_kernel_blocked``,
+``_gru_bwd_kernel_blocked``), when ``_pinned_vmem_limit`` says the call
+fits ``_PINNED_VMEM_CAP`` (variant ``blocked_pinned``): the operand is
+taken in ``pl.ANY``, one DMA at the first grid step copies it whole
+into a VMEM scratch, and every column block is a slice of that scratch
+(``_weight_blocks``); the call raises its own scoped limit from its
+shapes (``_blocked_scan_call``). Where XLA's memory-space assignment
+left the operand no longer matters to either: from HBM the one copy is
+19.8 MB once a call, and an operand XLA had placed in VMEM (``S(1)``)
+is spared the BlockSpec pipeline's VMEM-to-VMEM copy of every block at
+every time step, which is not hidden behind the block's matmul (3.0 us
+of a 17.3 us backward step, 3.2 of a 9.2 us forward step: PERF.md
+section 6, PR 27 and PR 29). Past the cap (f32 dots at H=1760, wider
+layers) the BlockSpec pipeline streams the blocks from wherever XLA
+left the operand (variant ``blocked``): from HBM that is the whole
+matrix every step, the honest cost of a matrix that cannot live in
+VMEM. One kernel body per direction serves both builds; only where a
+column block comes from differs, and that is written once. The
+backward kernel needs the blocks once per step: it pipelines the
+``dgates @ W^T`` contraction one step behind the gate recompute
+(SURVEY.md §7 hard-parts #2).
 
 **int8 resident / int8 blocked streaming** (weight-only PTQ serving):
 ``gru_scan_pallas_q`` keeps the QUANTIZED matrix resident — int8
@@ -281,17 +284,43 @@ def _bigru_bwd_kernel(xpf_ref, xpb_ref, mf_ref, mb_ref,
 # Blocked kernels (weights past the residency budget: flagship H=1760).
 # ---------------------------------------------------------------------------
 
+def _weight_blocks(wh_ref, pinned, g, c: int):
+    """Where a column block of the recurrent matrix comes from, for the
+    forward and the backward blocked kernel alike. Returns the getter.
+
+    ``pinned`` = (w_scr, sem) in the copy-once build: ``wh_ref`` is then
+    the whole padded matrix wherever XLA left it (``pl.ANY``), copied
+    into ``w_scr`` by ONE DMA at the call's first grid step, and block
+    ``g`` is a slice of ``w_scr``. Without it ``wh_ref`` is the
+    ``[H, C]`` block the BlockSpec pipeline moved for this grid step.
+    """
+    if not pinned:
+        return lambda: wh_ref[:]
+    w_scr, sem = pinned
+
+    @pl.when((pl.program_id(0) == 0) & (g == 0))
+    def _():
+        copy = pltpu.make_async_copy(wh_ref, w_scr, sem)
+        copy.start()
+        copy.wait()
+
+    cols = pl.ds(pl.multiple_of(g * c, c), c)
+    return lambda: w_scr[:, cols]
+
+
 def _gru_kernel_blocked(xp_ref, mask_ref, wh_ref, bh_ref, out_ref,
-                        h_c, gates_buf, *, h: int, n_blocks: int, c: int):
+                        h_c, gates_buf, *pinned,
+                        h: int, n_blocks: int, c: int):
     t = pl.program_id(0)
     g = pl.program_id(1)
+    w_blk = _weight_blocks(wh_ref, pinned, g, c)
 
     @pl.when((t == 0) & (g == 0))
     def _():
         h_c[:] = jnp.zeros_like(h_c)
 
     hprev = h_c[:]
-    blk = jnp.dot(hprev.astype(wh_ref.dtype), wh_ref[:],
+    blk = jnp.dot(hprev.astype(wh_ref.dtype), w_blk(),
                   preferred_element_type=jnp.float32) + bh_ref[:]
     gates_buf[:, pl.ds(g * c, c)] = blk
 
@@ -347,27 +376,11 @@ def _gru_bwd_kernel_blocked(xp_ref, mask_ref, ys_prev_ref, dy_ref, wh_ref,
     ``dh_c`` therefore carries only the elementwise part of dh_prev;
     the full dh assembles at the last block as dh_c + dh_acc + dy.
 
-    ``pinned`` = (w_scr, sem) in the copy-once build: ``wh_ref`` is then
-    the whole padded matrix wherever XLA left it (``pl.ANY``), copied
-    into ``w_scr`` once, and a column block is a slice of ``w_scr``.
-    Without it ``wh_ref`` is the ``[H, C]`` block the pipeline moved.
+    ``pinned``: see :func:`_weight_blocks`.
     """
     ti = pl.program_id(0)
     g = pl.program_id(1)
-
-    if pinned:
-        w_scr, sem = pinned
-
-        @pl.when((ti == 0) & (g == 0))
-        def _():
-            copy = pltpu.make_async_copy(wh_ref, w_scr, sem)
-            copy.start()
-            copy.wait()
-
-        cols = pl.ds(pl.multiple_of(g * c, c), c)
-        w_blk = lambda: w_scr[:, cols]
-    else:
-        w_blk = lambda: wh_ref[:]
+    w_blk = _weight_blocks(wh_ref, pinned, g, c)
 
     @pl.when((ti == 0) & (g == 0))
     def _():
@@ -509,28 +522,79 @@ def _use_blocked(h: int, dot, n_gates: int = 3,
     return not fits_vmem(h, wb, n_gates)
 
 
-def _pinned_bwd_vmem_limit(b: int, h: int, xp_bytes: int,
-                           dot_bytes: int) -> Optional[int]:
-    """The scoped-VMEM limit the copy-once blocked backward call asks
-    for, or None when it would pass :data:`_PINNED_VMEM_CAP` (the call
-    then streams its blocks). What the call holds: ONE copy of the
-    padded ``[H, cols]`` matrix, the double-buffered per-step blocks
-    (xproj row, mask, h_prev and dy rows, bias block in; dxp and dgates
-    rows out) and the four float32 scratches; a quarter on top for the
-    gate math's temporaries, rounded up to 4 MiB and never under
-    Mosaic's default of 16 MiB. ds2_full (H=1760, bf16): 32 MiB at
-    b=32, 40 MiB at b=64."""
-    h3 = 3 * h
-    n_blocks, c = _block_layout(h3)
-    cols = n_blocks * c
-    weights = h * cols * dot_bytes
-    rows = 2 * (b * h3 * xp_bytes + b * 128 * 4 + 2 * b * h * 4
-                + 8 * c * 4 + 2 * b * h3 * 4)
-    scratch = 2 * b * h * 4 + 2 * b * cols * 4
+def _pinned_vmem_limit(weight_bytes: int, row_bytes: int,
+                       scratch_bytes: int) -> Optional[int]:
+    """The scoped-VMEM limit a copy-once blocked call asks for, or None
+    when it would pass :data:`_PINNED_VMEM_CAP` (the call then streams
+    its blocks). What the call holds: ONE copy of the padded
+    ``[H, cols]`` matrix, its per-step blocks twice (the pipeline
+    double-buffers them) and its float32 scratches; a quarter on top for
+    the gate math's temporaries, rounded up to 4 MiB and never under
+    Mosaic's default of 16 MiB. ds2_full (H=1760, bf16) at b=32 / 64:
+    forward 28 / 32 MiB, backward 32 / 40 MiB."""
     step = 4 * 1024 * 1024
-    limit = max(16 * 1024 * 1024,
-                pl.cdiv((weights + rows + scratch) * 5 // 4, step) * step)
+    need = weight_bytes + 2 * row_bytes + scratch_bytes
+    limit = max(16 * 1024 * 1024, pl.cdiv(need * 5 // 4, step) * step)
     return limit if limit <= _PINNED_VMEM_CAP else None
+
+
+def _blocked_scan_call(body, kernel: str, reverse: bool, c: int, rows,
+                       w, bias, out_map, out_widths, scratch_widths,
+                       interpret: bool):
+    """The ``(T, G)`` blocked scan call of either direction.
+
+    ``rows``: the per-step operands as ``(array [T, b, X], index map)``
+    pairs in the kernel's order; ``w [H, cols]`` (dot type) and
+    ``bias [1, cols]``, both padded to whole ``c``-wide blocks, follow
+    them. The outputs are float32 ``[T, b, width]`` rows through
+    ``out_map`` (one array for one width, else a list), the scratches
+    float32 ``[b, width]``.
+
+    Who puts the matrix into VMEM is decided here, from the shapes: the
+    kernel (one copy into a scratch, the call's own scoped limit:
+    ``blocked_pinned``) when :func:`_pinned_vmem_limit` fits the cap,
+    else the BlockSpec pipeline, block by block (``blocked``).
+    """
+    t_max, b = rows[0][0].shape[:2]
+    h, cols = w.shape
+    row_bytes = (sum(b * max(x.shape[2], 128) * x.dtype.itemsize
+                     for x, _ in rows)
+                 + 8 * c * 4 + sum(b * n * 4 for n in out_widths))
+    limit = _pinned_vmem_limit(w.size * w.dtype.itemsize, row_bytes,
+                               sum(b * n * 4 for n in scratch_widths))
+    if limit is None:
+        variant = "blocked"
+        w_spec = pl.BlockSpec((h, c), lambda t, g: (0, g),
+                              memory_space=pltpu.VMEM)
+        pin_scratch, pin = [], {}
+    else:
+        variant = "blocked_pinned"
+        w_spec = pl.BlockSpec(memory_space=pl.ANY)
+        pin_scratch = [pltpu.VMEM((h, cols), w.dtype),
+                       pltpu.SemaphoreType.DMA(())]
+        pin = {"compiler_params":
+               pltpu.CompilerParams(vmem_limit_bytes=limit)}
+    outs = [(pl.BlockSpec((1, b, n), out_map, memory_space=pltpu.VMEM),
+             jax.ShapeDtypeStruct((t_max, b, n), jnp.float32))
+            for n in out_widths]
+    out_specs, out_shape = outs[0] if len(outs) == 1 else zip(*outs)
+    return kernel_call(
+        functools.partial(body, h=h, n_blocks=cols // c, c=c),
+        kernel=kernel,
+        facts=scan_facts(variant, reverse, t_max, b, h, 3),
+        grid=(t_max, cols // c),
+        in_specs=[pl.BlockSpec((1, b, x.shape[2]), imap,
+                               memory_space=pltpu.VMEM)
+                  for x, imap in rows] + [
+            w_spec,
+            pl.BlockSpec((1, c), lambda t, g: (0, g),
+                         memory_space=pltpu.VMEM)],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((b, n), jnp.float32)
+                        for n in scratch_widths] + pin_scratch,
+        interpret=interpret,
+        **pin,
+    )(*[x for x, _ in rows], w, bias)
 
 
 def _gru_pallas_raw(xproj, mask, w_h, b_h, reverse: bool, interpret: bool,
@@ -557,28 +621,13 @@ def _gru_pallas_raw(xproj, mask, w_h, b_h, reverse: bool, interpret: bool,
         return ys, xp_t, mask_t, bh2
 
     n_blocks, c = _block_layout(h3)
+    cols = n_blocks * c
     idx, midx = _time_index_maps(t_max, reverse, blocked=True)
-    ys = kernel_call(
-        functools.partial(_gru_kernel_blocked, h=h, n_blocks=n_blocks, c=c),
-        kernel="gru_scan_fwd",
-        facts=scan_facts("blocked", reverse, t_max, b, h, 3),
-        grid=(t_max, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, b, h3), idx, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, b, 1), midx, memory_space=pltpu.VMEM),
-            pl.BlockSpec((h, c), lambda t, g: (0, g),
-                         memory_space=pltpu.VMEM),  # streamed weight block
-            pl.BlockSpec((1, c), lambda t, g: (0, g),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, b, h), idx, memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((t_max, b, h), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((b, h), jnp.float32),
-            pltpu.VMEM((b, n_blocks * c), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xp_t, mask_t, _pad_cols(w, n_blocks * c), _pad_cols(bh2, n_blocks * c))
+    ys = _blocked_scan_call(
+        _gru_kernel_blocked, "gru_scan_fwd", reverse, c,
+        [(xp_t, idx), (mask_t, midx)],
+        _pad_cols(w, cols), _pad_cols(bh2, cols),
+        idx, [h], [h, cols], interpret)
     return ys, xp_t, mask_t, bh2
 
 
@@ -643,8 +692,8 @@ def gru_scan_pallas_stream(xproj: jnp.ndarray, mask: jnp.ndarray,
 # Weight-only int8 inference kernel (VERDICT r3 #7): the quantized
 # [H, 3H] matrix lives int8 in VMEM, so the flagship H=1760 (9.3 MB)
 # becomes RESIDENT — the bf16 forward takes the blocked grid at that
-# size, its 18.6 MB placed by XLA. Dequantization never
-# materializes a full-precision matrix: column-scale associativity,
+# size, its 18.6 MB copied into VMEM once by the kernel. Dequantization
+# never materializes a full-precision matrix: column-scale associativity,
 # (h @ Q) * scale == h @ (Q * scale), moves the per-output-channel
 # scale onto the [B, 3H] gates — O(B*3H) VPU work per step instead of
 # O(H*3H). Inference-only (no vjp): PTQ serves decode, training stays
@@ -945,15 +994,6 @@ def _gru_bwd(reverse, interpret, dot_dtype, residuals, dy):
         # masked in the kernel, so clamp the index to a valid row.
         pidx = lambda i: idx(jnp.maximum(t_max - 2 - i, 0))
 
-    out_specs = [
-        pl.BlockSpec((1, b, h3), bidx, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, b, h3), bidx, memory_space=pltpu.VMEM),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((t_max, b, h3), jnp.float32),
-        jax.ShapeDtypeStruct((t_max, b, h3), jnp.float32),
-    ]
-
     if not blocked:
         dxp_t, dgates_t = kernel_call(
             _gru_bwd_kernel, kernel="gru_scan_bwd",
@@ -969,57 +1009,21 @@ def _gru_bwd(reverse, interpret, dot_dtype, residuals, dy):
                 pl.BlockSpec((1, h3), lambda i: (0, 0),
                              memory_space=pltpu.VMEM),
             ],
-            out_specs=out_specs,
-            out_shape=out_shape,
+            out_specs=[pl.BlockSpec((1, b, h3), bidx,
+                                    memory_space=pltpu.VMEM)] * 2,
+            out_shape=[jax.ShapeDtypeStruct((t_max, b, h3),
+                                            jnp.float32)] * 2,
             scratch_shapes=[pltpu.VMEM((b, h), jnp.float32)],
             interpret=interpret,
         )(xp_t, mask_t, ys, dy_t, w, bh2)
     else:
         n_blocks, c = _block_layout(h3)
         cols = n_blocks * c
-        # Who puts the matrix into VMEM is decided from the shapes: the
-        # kernel (one copy, its own scoped limit) when that fits the
-        # cap, else the pipeline, block by block.
-        limit = _pinned_bwd_vmem_limit(
-            b, h, xp_t.dtype.itemsize, jnp.dtype(dot).itemsize)
-        pinned = limit is not None
-        if pinned:
-            w_spec = pl.BlockSpec(memory_space=pl.ANY)
-            pin = {"compiler_params":
-                   pltpu.CompilerParams(vmem_limit_bytes=limit)}
-            pin_scratch = [pltpu.VMEM((h, cols), dot),
-                           pltpu.SemaphoreType.DMA(())]
-        else:
-            w_spec = pl.BlockSpec((h, c), lambda i, g: (0, g),
-                                  memory_space=pltpu.VMEM)
-            pin, pin_scratch = {}, []
-        dxp_t, dgates_t = kernel_call(
-            functools.partial(_gru_bwd_kernel_blocked, h=h,
-                              n_blocks=n_blocks, c=c),
-            kernel="gru_scan_bwd",
-            facts=scan_facts("blocked_pinned" if pinned else "blocked",
-                             reverse, t_max, b, h, 3),
-            grid=(t_max, n_blocks),
-            in_specs=[
-                pl.BlockSpec((1, b, h3), bidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, 1), bmidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, h), pidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, h), bidx, memory_space=pltpu.VMEM),
-                w_spec,
-                pl.BlockSpec((1, c), lambda i, g: (0, g),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, cols), jnp.float32),
-                pltpu.VMEM((b, cols), jnp.float32),
-            ] + pin_scratch,
-            interpret=interpret,
-            **pin,
-        )(xp_t, mask_t, ys, dy_t, _pad_cols(w, cols), _pad_cols(bh2, cols))
+        dxp_t, dgates_t = _blocked_scan_call(
+            _gru_bwd_kernel_blocked, "gru_scan_bwd", reverse, c,
+            [(xp_t, bidx), (mask_t, bmidx), (ys, pidx), (dy_t, bidx)],
+            _pad_cols(w, cols), _pad_cols(bh2, cols),
+            bidx, [h3, h3], [h, h, cols, cols], interpret)
 
     # h_prev sequence in scan order: ys shifted by one scan step.
     if reverse:

@@ -41,15 +41,21 @@ def scan(kernel, variant, reverse, t, b, h, gates=3):
 # tools/aot_kernels.kernel_cases() at b=8, t=400: the five that
 # test_tpu_compile.py compiles, then the other routed shapes.
 CASES = {
-    "gru_h1760": [scan("gru_scan_fwd", "blocked", 0, 400, 8, 1760),
+    "gru_h1760": [scan("gru_scan_fwd", "blocked_pinned", 0, 400, 8, 1760),
                   scan("gru_scan_bwd", "blocked_pinned", 0, 400, 8, 1760)],
     # ds2_full.train_1chip's own call, and twice its rows
     "gru_h1760_b32": [
-        scan("gru_scan_fwd", "blocked", 0, 850, 32, 1760),
+        scan("gru_scan_fwd", "blocked_pinned", 0, 850, 32, 1760),
         scan("gru_scan_bwd", "blocked_pinned", 0, 850, 32, 1760)],
     "gru_h1760_b64": [
-        scan("gru_scan_fwd", "blocked", 0, 850, 64, 1760),
+        scan("gru_scan_fwd", "blocked_pinned", 0, 850, 64, 1760),
         scan("gru_scan_bwd", "blocked_pinned", 0, 850, 64, 1760)],
+    # offline decode: the forward call alone, no VJP
+    "gru_h1760_decode": [
+        scan("gru_scan_fwd", "blocked_pinned", 0, 600, 32, 1760)],
+    # float32 dots: the matrix passes the cap, both calls stream
+    "gru_h1760_f32": [scan("gru_scan_fwd", "blocked", 0, 400, 8, 1760),
+                      scan("gru_scan_bwd", "blocked", 0, 400, 8, 1760)],
     "gru_stream_h800": [scan("gru_scan_stream", "resident", 0, 32, 2, 800)],
     "bigru_h800": [scan("bigru_scan_fwd", "resident", "both", 400, 8, 800)],
     "ctc_en": [{"kernel": "ctc_alpha", "t": "400", "b": "8", "s": "384"},
@@ -123,25 +129,35 @@ def _pallas_calls(jaxpr):
     return found
 
 
-@pytest.mark.parametrize("case, variant, limit_mib", [
-    ("gru_h1760_b32", "blocked_pinned", 32),
-    ("gru_h1760_b64", "blocked_pinned", 40),
+@pytest.mark.parametrize("case, kernel, variant, limit_mib", [
+    ("gru_h1760", "gru_scan_fwd", "blocked_pinned", 28),
+    ("gru_h1760", "gru_scan_bwd", "blocked_pinned", 28),
+    ("gru_h1760_b32", "gru_scan_fwd", "blocked_pinned", 28),
+    ("gru_h1760_b32", "gru_scan_bwd", "blocked_pinned", 32),
+    ("gru_h1760_b64", "gru_scan_fwd", "blocked_pinned", 32),
+    ("gru_h1760_b64", "gru_scan_bwd", "blocked_pinned", 40),
+    ("gru_h1760_decode", "gru_scan_fwd", "blocked_pinned", 28),
     # f32 dots: 39.6 MB of weights pass the cap, the pipeline streams
-    ("gru_h1760_f32", "blocked", None),
+    ("gru_h1760_f32", "gru_scan_fwd", "blocked", None),
+    ("gru_h1760_f32", "gru_scan_bwd", "blocked", None),
 ])
-def test_who_places_the_backward_scan_weights(case, variant, limit_mib):
-    """The copy-once build says so in its facts, takes its weights
-    where XLA left them (``pl.ANY``: no BlockSpec pipeline on the
-    operand) and asks Mosaic for the scoped VMEM its shapes need; past
-    the module's cap the call is today's streamed one."""
+def test_who_places_the_blocked_scan_weights(case, kernel, variant,
+                                             limit_mib):
+    """The copy-once build, forward or backward, says so in its facts,
+    takes its weights where XLA left them (``pl.ANY``: no BlockSpec
+    pipeline on the operand) and asks Mosaic for the scoped VMEM its
+    shapes need; past the module's cap the call is the streamed one,
+    under Mosaic's default limit."""
     from aot_kernels import kernel_cases
 
     fn, args = kernel_cases()[case]()
-    bwd, = [p for p in _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
-            if p["name"] == "gru_scan_bwd"]
-    assert bwd["metadata"]["variant"] == variant
-    w = bwd["grid_mapping"].block_mappings[4]
-    limit = bwd["compiler_params"].get("mosaic_tpu")
+    call, = [p for p in _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+             if p["name"] == kernel]
+    assert call["metadata"]["variant"] == variant
+    # the weights follow the per-step rows: two forward, four backward
+    w = call["grid_mapping"].block_mappings[
+        {"gru_scan_fwd": 2, "gru_scan_bwd": 4}[kernel]]
+    limit = call["compiler_params"].get("mosaic_tpu")
     if limit_mib is None:
         assert "vmem" in str(w.block_aval) and limit is None
         assert [d.block_size for d in w.block_shape] == [1760, 512]
@@ -151,12 +167,13 @@ def test_who_places_the_backward_scan_weights(case, variant, limit_mib):
         assert limit.vmem_limit_bytes == limit_mib * 2 ** 20
 
 
-def test_every_backward_scan_of_the_ds2_full_step_is_pinned(monkeypatch):
+def test_every_scan_of_the_ds2_full_step_is_pinned(monkeypatch):
     """ds2_full.train_1chip's model (7 BiGRU-1760, bf16, b=32 in the
     1700-frame bucket), forward and gradient, lowered for the TPU as
-    the chip resolves it: 14 forward scans whose weights XLA places,
-    and 14 backward scans that all place their own. None is left to
-    the lottery that made six of them stream 19.8 MB a time step."""
+    the chip resolves it: 14 forward and 14 backward scans, seven per
+    direction, every one placing its own weights. None is left to the
+    lottery that made six backward calls stream 19.8 MB a time step,
+    nor to the pipeline's block copies out of a matrix XLA had placed."""
     from collections import Counter
 
     from deepspeech_tpu.config import get_config
@@ -177,8 +194,8 @@ def test_every_backward_scan_of_the_ds2_full_step_is_pinned(monkeypatch):
     got = Counter((f["kernel"], f["variant"], f["reverse"], f["t"], f["b"])
                   for f in lowered_facts(grads, (variables, x, lens)))
     assert got == {
-        ("gru_scan_fwd", "blocked", r, "850", "32"): 7 for r in "01"} | {
-        ("gru_scan_bwd", "blocked_pinned", r, "850", "32"): 7 for r in "01"}
+        (kernel, "blocked_pinned", r, "850", "32"): 7
+        for kernel in ("gru_scan_fwd", "gru_scan_bwd") for r in "01"}
 
 
 def test_the_roles_no_routed_case_reaches():

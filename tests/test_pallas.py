@@ -380,12 +380,67 @@ def test_gru_pallas_blocked_grads_match_scan(force_blocked, reverse, h):
                                    rtol=1e-4, atol=1e-4, err_msg=name)
 
 
+def _scan_calls(fn, *args):
+    """``(variant, scoped-VMEM limit in MiB or None)`` of every Pallas
+    scan call ``fn`` traces to, in program order: the fact
+    ops/kernel_id.py lowers with the call, and what the call asks
+    Mosaic for."""
+    def limit(params):
+        mosaic = params["compiler_params"].get("mosaic_tpu")
+        return mosaic and mosaic.vmem_limit_bytes / 2 ** 20
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield str(e.params["metadata"]["variant"]), limit(e.params)
+            for value in e.params.values():  # a custom_vjp's own jaxpr
+                inner = getattr(value, "jaxpr", value)
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from walk(inner)
+
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
 def _scan_variants(fn, *args):
-    """``variant`` of every Pallas scan call ``fn`` traces to, in
-    program order (the fact ops/kernel_id.py lowers with the call)."""
-    return [str(e.params["metadata"]["variant"])
-            for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
-            if e.primitive.name == "pallas_call"]
+    return [variant for variant, _ in _scan_calls(fn, *args)]
+
+
+def _assert_within_blocked_tolerance(got, want, names, dot_dtype):
+    tol = 1e-4 if dot_dtype is None else 0.08
+    for a, b_, name in zip(got, want, names):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b_), rtol=tol,
+            atol=tol * max(1.0, float(jnp.abs(b_).max())), err_msg=name)
+
+
+@pytest.mark.parametrize("dot_dtype", [None, "bfloat16"])
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("h", [12, 176])  # 36 -> 128 cols / 528 -> 1024
+def test_gru_blocked_fwd_copy_once_bit_identical_to_streamed(
+        force_blocked, monkeypatch, reverse, h, dot_dtype):
+    """The forward kernel's half of the statement below: its copy-once
+    build and its streamed build (the cap patched to 0) give the same
+    bits, within the blocked kernels' tolerance of the XLA scan."""
+    from deepspeech_tpu.ops import rnn_pallas
+
+    rng = np.random.default_rng(25)
+    xproj, mask, w_h, b_h = _rand_gru(rng, 3, 9, h)
+
+    def pallas():
+        return gru_scan_pallas(xproj, mask, w_h, b_h, reverse, True,
+                               dot_dtype)
+
+    # a fresh lambda each time: make_jaxpr caches a trace by function
+    assert _scan_variants(lambda: pallas()) == ["blocked_pinned"]
+    pinned = pallas()
+    monkeypatch.setattr(rnn_pallas, "_PINNED_VMEM_CAP", 0)
+    assert _scan_variants(lambda: pallas()) == ["blocked"]
+    np.testing.assert_array_equal(np.asarray(pinned), np.asarray(pallas()))
+
+    dot = None if dot_dtype is None else jnp.bfloat16
+    oracle = gru_scan(xproj, mask, w_h, b_h, reverse=reverse, dot_dtype=dot)
+    _assert_within_blocked_tolerance([pinned], [oracle], ["ys"], dot_dtype)
 
 
 @pytest.mark.parametrize("dot_dtype", [None, "bfloat16"])
@@ -394,15 +449,16 @@ def _scan_variants(fn, *args):
 def test_gru_blocked_bwd_copy_once_bit_identical_to_streamed(
         force_blocked, monkeypatch, reverse, h, dot_dtype):
     """Who puts the recurrent matrix into VMEM changes nothing of the
-    mathematics: the copy-once build of the blocked backward kernel
-    (one DMA into a scratch, column blocks sliced from it) and the
-    streamed build (a BlockSpec pipeline on the operand, taken when
-    the call's need passes the cap) give the same bits, and both stay
-    within the blocked kernels' tolerance of the XLA scan."""
+    mathematics: the copy-once build of the blocked kernels (one DMA
+    into a scratch, column blocks sliced from it) and the streamed
+    build (a BlockSpec pipeline on the operand, taken when the call's
+    need passes the cap) give the same gradients to the bit, and both
+    stay within the blocked kernels' tolerance of the XLA scan."""
     from deepspeech_tpu.ops import rnn_pallas
 
     rng = np.random.default_rng(24)
     xproj, mask, w_h, b_h = _rand_gru(rng, 2, 7, h)
+    names = ["dxproj", "dw_h", "db_h"]
 
     def grads(scan):
         return jax.grad(
@@ -413,49 +469,71 @@ def test_gru_blocked_bwd_copy_once_bit_identical_to_streamed(
         return gru_scan_pallas(xp, mask, wh, bh, reverse, True, dot_dtype)
 
     assert _scan_variants(lambda: grads(pallas)) == [
-        "blocked", "blocked_pinned"]
+        "blocked_pinned", "blocked_pinned"]
     pinned = grads(pallas)
     monkeypatch.setattr(rnn_pallas, "_PINNED_VMEM_CAP", 0)
     assert _scan_variants(lambda: grads(pallas)) == ["blocked", "blocked"]
     streamed = grads(pallas)
-    for a, b_, name in zip(pinned, streamed, ["dxproj", "dw_h", "db_h"]):
+    for a, b_, name in zip(pinned, streamed, names):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b_),
                                       err_msg=name)
 
     dot = None if dot_dtype is None else jnp.bfloat16
     oracle = grads(lambda xp, wh, bh: gru_scan(
         xp, mask, wh, bh, reverse=reverse, dot_dtype=dot))
-    tol = 1e-4 if dot_dtype is None else 0.08
-    for a, b_, name in zip(pinned, oracle, ["dxproj", "dw_h", "db_h"]):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b_), rtol=tol,
-            atol=tol * max(1.0, float(jnp.abs(b_).max())), err_msg=name)
+    _assert_within_blocked_tolerance(pinned, oracle, names, dot_dtype)
 
 
-def test_gru_blocked_bwd_streams_when_the_matrix_passes_the_cap():
-    """The choice is made from the call's shapes: ds2_full's layer
-    (H=1760, b=32) in bf16 is pinned under 32 MiB of scoped VMEM; with
-    float32 dots its 39.6 MB of weights pass the cap and the call that
-    is lowered (interpret off) is the streamed build."""
-    from deepspeech_tpu.ops import rnn_pallas
-
+def _ds2_full_scan_args(b, t, h=1760):
     S = jax.ShapeDtypeStruct
-    b, t, h = 32, 850, 1760
-    args = (S((b, t, 3 * h), jnp.bfloat16), S((b, t), jnp.float32),
+    return (S((b, t, 3 * h), jnp.bfloat16), S((b, t), jnp.float32),
             S((h, 3 * h), jnp.float32), S((3 * h,), jnp.float32))
 
-    def variants(dot_dtype):
+
+def test_gru_blocked_streams_when_the_matrix_passes_the_cap():
+    """The choice is made from the call's shapes, by one rule for both
+    directions: ds2_full's layer (H=1760, b=32) in bf16 is pinned,
+    forward under 28 MiB of scoped VMEM and backward under 32; with
+    float32 dots its 39.6 MB of weights pass the cap and the calls
+    that are lowered (interpret off) are the streamed builds, under
+    Mosaic's default limit."""
+    from deepspeech_tpu.ops import rnn_pallas
+
+    def calls(dot_dtype):
         def train(xp, m, w, bh):
             ys, vjp = jax.vjp(lambda *a: gru_scan_pallas(
                 *a, False, False, dot_dtype), xp, m, w, bh)
             return vjp(ys)
 
-        return _scan_variants(train, *args)
+        return _scan_calls(train, *_ds2_full_scan_args(32, 850))
 
-    assert variants("bfloat16") == ["blocked", "blocked_pinned"]
-    assert variants(None) == ["blocked", "blocked"]
-    assert rnn_pallas._pinned_bwd_vmem_limit(b, h, 2, 2) == 32 * 2 ** 20
-    assert rnn_pallas._pinned_bwd_vmem_limit(b, h, 2, 4) is None
+    assert calls("bfloat16") == [("blocked_pinned", 28),
+                                 ("blocked_pinned", 32)]
+    assert calls(None) == [("blocked", None), ("blocked", None)]
+    # the limit function itself: the bf16 matrix alone, Mosaic's
+    # default as the floor, and nothing past the cap
+    mib = 2 ** 20
+    assert rnn_pallas._pinned_vmem_limit(2 * 1760 * 5632, 0, 0) == 24 * mib
+    assert rnn_pallas._pinned_vmem_limit(0, 0, 0) == 16 * mib
+    assert rnn_pallas._pinned_vmem_limit(
+        rnn_pallas._PINNED_VMEM_CAP, 0, 0) is None
+
+
+@pytest.mark.parametrize("b, t, limit_mib", [
+    (8, 400, 28),    # evaluation, the benchmark's reference check
+    (32, 850, 28),   # ds2_full.train_1chip
+    (64, 850, 32),
+    (128, 850, 36),  # offline decode's widest batch: still under the cap
+])
+def test_gru_blocked_fwd_limit_follows_the_call(b, t, limit_mib):
+    """The forward kernel also serves evaluation and offline decode at
+    other (b, t): each call computes its own limit from its shapes and
+    stays pinned, forward-only (no VJP) as well."""
+    def decode(xp, m, w, bh):
+        return gru_scan_pallas(xp, m, w, bh, False, False, "bfloat16")
+
+    assert _scan_calls(decode, *_ds2_full_scan_args(b, t)) == [
+        ("blocked_pinned", limit_mib)]
 
 
 def test_gru_pallas_blocked_respects_mask(force_blocked):

@@ -69,7 +69,10 @@ def kernel_cases():
 
     cases = {}
 
-    def gru_case(h, b_=b, t_=t, xdt=jnp.float32, dot="bfloat16"):
+    def gru_case(h, b_=b, t_=t, xdt=jnp.float32, dot="bfloat16",
+                 vjp=True):
+        """Forward + VJP (training), or with ``vjp=False`` the forward
+        call alone: what evaluation and offline decode run."""
         _, _, w, bh = rnnshapes(h, 3)
         xp, m = S((b_, t_, 3 * h), xdt), S((b_, t_), jnp.float32)
 
@@ -79,9 +82,9 @@ def kernel_cases():
                                           dot_dtype=dot)
 
             def train(xp_, m_, w_, bh_):
-                ys, vjp = jax.vjp(step, xp_, m_, w_, bh_)
-                return vjp(jnp.ones_like(ys))
-            return train, (xp, m, w, bh)
+                ys, vjp_ = jax.vjp(step, xp_, m_, w_, bh_)
+                return vjp_(jnp.ones_like(ys))
+            return (train if vjp else step), (xp, m, w, bh)
         return f
 
     def lstm_case(h):
@@ -214,12 +217,16 @@ def kernel_cases():
     cases["gru_h800"] = gru_case(800)
     cases["gru_h1760"] = gru_case(1760)
     # ds2_full.train_1chip's own call (850 post-conv frames, bf16
-    # xproj) and twice its rows: the backward call copies its weights
-    # into VMEM once and asks for 32 / 40 MiB of scoped VMEM.
+    # xproj) and twice its rows: both calls copy their weights into
+    # VMEM once and ask for their own scoped VMEM, forward 28 / 32 MiB,
+    # backward 32 / 40 MiB.
     cases["gru_h1760_b32"] = gru_case(1760, 32, 850, jnp.bfloat16)
     cases["gru_h1760_b64"] = gru_case(1760, 64, 850, jnp.bfloat16)
-    # float32 dots: 39.6 MB of weights, past what the backward call may
-    # pin, so it streams its column blocks as before.
+    # offline decode of the 1200-frame bucket: the forward call alone
+    cases["gru_h1760_decode"] = gru_case(1760, 32, 600, jnp.bfloat16,
+                                         vjp=False)
+    # float32 dots: 39.6 MB of weights, past what a call may pin, so
+    # both stream their column blocks through the BlockSpec pipeline.
     cases["gru_h1760_f32"] = gru_case(1760, dot="float32")
     cases["gru_stream_h800"] = gru_stream_case(800)
     cases["lstm_h800"] = lstm_case(800)

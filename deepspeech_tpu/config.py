@@ -107,6 +107,45 @@ class ModelConfig:
     frame_stack: int = 1
     time_reduction_layer: int = 0
     time_reduction: int = 2
+    # Decoder-only family (train.objective="lm"; models/lfm2.py): the
+    # LFM2 block. ``frame_stack`` feature frames are projected to the
+    # prefix of the decoder's sequence, the transcript follows it and
+    # is trained by next-token cross-entropy over ``vocab_size`` ids
+    # (this chip's slice of ``lfm_vocab_published``). Sizes carry the
+    # published config's names behind the ``lfm_`` prefix.
+    lfm_hidden: int = 2048
+    lfm_layer_types: Tuple[str, ...] = ()  # "conv" | "full_attention"
+    lfm_dense_layers: int = 1        # leading layers with the dense FFN
+    lfm_heads: int = 32
+    lfm_kv_heads: int = 8
+    lfm_ffn_dim: int = 11776         # intermediate_size
+    lfm_expert_dim: int = 1536       # moe_intermediate_size
+    lfm_conv_taps: int = 3           # conv_L_cache
+    lfm_experts: int = 64            # the router's width, as published
+    lfm_top_k: int = 4               # num_experts_per_tok
+    lfm_rope_theta: float = 1e6
+    lfm_norm_eps: float = 1e-5
+    # The expert layer is told which experts it holds: ids
+    # [expert_offset, expert_offset + experts_held) of ``lfm_experts``.
+    # It routes over all of them and leaves out what the absent ones
+    # would have added (one chip's share of an expert-parallel layer,
+    # without its exchange).
+    experts_held: int = 64
+    expert_offset: int = 0
+    # Static row capacity of the dropless dispatch, as a share of the
+    # step's computed (position, choice) pairs; 0 = the worst case
+    # (every pair lands here). A bound below the worst case is a
+    # statement about the traffic: the step counts what would not fit
+    # (``moe_dropped``, always 0 at capacity 0) and its high-water mark.
+    moe_rows_bound: float = 0.0
+    # Grouped matrix products of the expert layer: "auto" (Pallas
+    # moe_gmm / moe_tgmm on a TPU, jax.lax.ragged_dot elsewhere) |
+    # "xla" | "pallas".
+    moe_impl: str = "auto"
+    # Positions every sequence is right-padded to; 0 = the least that
+    # holds a bucket: ceil(frames / frame_stack) + 1 + max_label_len,
+    # rounded up to a multiple of 8.
+    lfm_seq_positions: int = 0
 
     @property
     def time_stride(self) -> int:
@@ -169,6 +208,8 @@ class TrainConfig:
     optimizer: str = "sgd"  # "sgd" | "adamw"
     learning_rate: float = 3e-4
     momentum: float = 0.99
+    # adamw only; decays every leaf (no mask), so a preset with norm
+    # gains keeps it 0.
     weight_decay: float = 0.0
     grad_clip_norm: float = 400.0
     lr_anneal: float = 1.1  # divide LR by this each epoch (DS2-era schedule)
@@ -206,7 +247,10 @@ class TrainConfig:
     # "rnnt" (transducer: models/transducer.RNNTModel trained through
     # ops/transducer.rnnt_joint_loss, which never holds the
     # [B,T',U+1,V] lattice; greedy transducer eval, single process, no
-    # sequence_parallel/pipeline).
+    # sequence_parallel/pipeline); "lm" (decoder-only recogniser:
+    # models/lfm2.LFM2ASR, projected audio frames as the prefix and
+    # next-token cross-entropy over the transcript; single process, no
+    # sequence_parallel/pipeline, no in-training eval yet).
     objective: str = "ctc"
     # Sequence-parallel training (parallel/seqpar.sp_loss): the TIME
     # axis of each batch shards over the mesh's data axis — conv halos
@@ -422,6 +466,40 @@ def rnnt_he2019() -> Config:
     )
 
 
+LFM2_PERIOD = ("full_attention", "conv", "conv", "conv")
+
+
+def lfm2_24b_a2b() -> Config:
+    """One chip's share of LFM2-24B-A2B (``model_type: lfm2_moe``,
+    https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json)
+    as a decoder-only speech recogniser, every width as published:
+    hidden 2048, 32 query / 8 key-value heads of 64, 3-tap gated short
+    convolutions, dense SwiGLU 11776, 64 routed experts of 1536, top-4
+    with a selection bias, sigmoid scores normalised over the chosen.
+    The stated deployment divides each layer over 8 chips: 8 of the 64
+    experts and 8192 of the 65536 vocabulary rows live here, the rest
+    is replicated. Depth is cut to one leading dense ``conv`` layer and
+    one whole period (attention, conv, conv, conv) of sparse layers.
+    ``benchmark/configs/lfm2_24b_a2b.json`` has the published keys
+    beside these and every reading that is this repo's own (the audio
+    prefix, the tied head, AdamW's settings)."""
+    c = Config(name="lfm2_24b_a2b")
+    return _replace(
+        c,
+        model=_replace(
+            c.model, conv_layers=(), conv_channels=(), rnn_layers=0,
+            bidirectional=False, rnn_batch_norm=False, frame_stack=8,
+            vocab_size=8192, lfm_layer_types=("conv",) + LFM2_PERIOD,
+            lfm_dense_layers=1, experts_held=8, expert_offset=0,
+            moe_rows_bound=0.21875),
+        data=_replace(c.data, batch_size=128, bucket_frames=(1696,),
+                      max_label_len=64),
+        train=_replace(c.train, objective="lm", optimizer="adamw",
+                       learning_rate=1e-4, weight_decay=0.0,
+                       grad_clip_norm=1.0, warmup_steps=100),
+    )
+
+
 PRESETS = {
     "ds2_small": ds2_small,
     "ds2_full": ds2_full,
@@ -430,6 +508,7 @@ PRESETS = {
     "aishell": aishell,
     "dev_slice": dev_slice,
     "rnnt_he2019": rnnt_he2019,
+    "lfm2_24b_a2b": lfm2_24b_a2b,
 }
 
 

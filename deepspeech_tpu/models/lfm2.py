@@ -1,0 +1,314 @@
+"""The LFM2 block as a decoder-only speech recogniser.
+
+``LFM2ASR`` is what ``train.objective="lm"`` trains: the acoustic
+frames of an utterance, stacked and projected, are the prefix of the
+decoder's sequence; the transcript follows and is trained by
+next-token cross-entropy. The decoder from its embeddings to its
+logits is LFM2's (``model_type: lfm2_moe``): pre-norm residual layers
+whose operator is a gated short convolution or grouped-query attention
+(per-head RMSNorm on q and k, rotary positions), and whose
+feed-forward is a dense SwiGLU in the leading layers and a sparse
+expert block (``ops/moe.py``) after them; RMSNorm before the tied
+output head.
+
+One utterance's sequence is LEFT-PACKED: ``a = ceil(frames /
+frame_stack)`` prefix positions, then the embedding of id 0 (start),
+then its ``u`` label ids; padding only on the right, so causality
+alone keeps it out of every valid position. The target at the start
+position is label 1, ..., at the last label id 0 (end). Padded
+positions are not routed and earn no loss.
+"""
+
+from __future__ import annotations
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..config import ModelConfig
+from ..ops import moe
+from .rnn import stack_frames
+
+_INIT = nn.initializers.normal(0.02)
+BIAS_STD = 0.01
+# Every decoder layer is rematerialised: it keeps its matrix products'
+# results (the grouped ones by name) and recomputes the element-wise
+# work between them (norms, gates, softmax, rotations) in the backward
+# pass. At the benchmark cell's shapes that is 1.4 GB of temporaries
+# less (compiled ahead of time for a v5e) for a few per cent of time.
+REMAT_POLICY = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names("moe_rows"))
+
+
+def seq_positions(cfg: ModelConfig, frames: int, max_label_len: int
+                  ) -> int:
+    """Positions every sequence of a ``frames`` bucket is padded to."""
+    least = -(-frames // cfg.frame_stack) + 1 + max_label_len
+    if cfg.lfm_seq_positions:
+        if cfg.lfm_seq_positions < least:
+            raise ValueError(
+                f"lfm_seq_positions={cfg.lfm_seq_positions} cannot hold "
+                f"{frames} frames and {max_label_len} labels ({least})")
+        return cfg.lfm_seq_positions
+    return -(-least // 8) * 8
+
+
+class RMSNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        return (y * scale).astype(x.dtype)
+
+
+class Linear(nn.Module):
+    """``x @ kernel`` without bias: float32 parameter, operands in the
+    activations' dtype, float32 accumulation."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", _INIT, (x.shape[-1], self.features))
+        return jnp.dot(x, kernel.astype(x.dtype))
+
+
+class ShortConv(nn.Module):
+    """Gated short convolution: ``[B, C, x] = split3(W_in h)``,
+    ``c_t = sum_j k_j * (B * x)_{t-j}`` (depthwise, causal, zeros
+    before position 0), ``W_out (C * c)``."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, h):
+        d, taps = self.cfg.lfm_hidden, self.cfg.lfm_conv_taps
+        gate_b, gate_c, x = jnp.split(
+            Linear(3 * d, name="in_proj")(h), 3, axis=-1)
+        z = gate_b * x
+        filt = self.param("filter", nn.initializers.normal(taps ** -0.5),
+                          (taps, d)).astype(z.dtype)
+        c = z * filt[0]
+        for j in range(1, taps):
+            c = c + filt[j] * jnp.pad(z, [(0, 0), (j, 0), (0, 0)]
+                                      )[:, :z.shape[1]]
+        return Linear(d, name="out_proj")(gate_c * c)
+
+
+def rotary(x, theta: float):
+    """Rotary embedding over the whole head, rotate-half pairing;
+    ``x [B, S, H, D]``, positions 0..S-1."""
+    s, d = x.shape[1], x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    return (x32 * jnp.cos(ang) + rot * jnp.sin(ang)).astype(x.dtype)
+
+
+class Attention(nn.Module):
+    """Causal grouped-query attention: RMSNorm over each head of q and
+    of k before the rotation, every key/value head shared by
+    ``heads / kv_heads`` query heads."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.cfg
+        b, s, d = h.shape
+        nh, nkv = cfg.lfm_heads, cfg.lfm_kv_heads
+        hd, rep = d // nh, nh // nkv
+        q = Linear(d, name="q")(h).reshape(b, s, nh, hd)
+        k = Linear(nkv * hd, name="k")(h).reshape(b, s, nkv, hd)
+        v = Linear(nkv * hd, name="v")(h).reshape(b, s, nkv, hd)
+        q = rotary(RMSNorm(cfg.lfm_norm_eps, name="q_norm")(q),
+                   cfg.lfm_rope_theta)
+        k = rotary(RMSNorm(cfg.lfm_norm_eps, name="k_norm")(k),
+                   cfg.lfm_rope_theta)
+        q = q.reshape(b, s, nkv, rep, hd)
+        scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
+                            preferred_element_type=jnp.float32)
+        scores = scores * (hd ** -0.5)
+        causal = jnp.tril(jnp.ones((s, s), bool))
+        scores = jnp.where(causal, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+        out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
+        return Linear(d, name="o")(out.reshape(b, s, d))
+
+
+class SwiGLU(nn.Module):
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        gate = Linear(self.width, name="w1")(x)
+        up = Linear(self.width, name="w3")(x)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(x.dtype)
+        return Linear(x.shape[-1], name="w2")(act)
+
+
+class SparseExperts(nn.Module):
+    """The routed feed-forward: this chip's ``experts_held`` experts of
+    the router's ``lfm_experts`` (``ops/moe.expert_layer``). The
+    selection bias is a buffer, not a parameter: it lives in the
+    ``buffers`` collection, held at its seeded value. In a trained
+    model that bias evens the experts' loads; a seeded one can only
+    uneven them, so it is seeded small (std ``BIAS_STD``: enough to
+    move the chosen set of a quarter of the positions, so a build that
+    drops it is seen; at 0.05 single experts drew three times the mean
+    load and a step's time followed the seed)."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x, valid):
+        cfg = self.cfg
+        b, s, d = x.shape
+        g, f = cfg.experts_held, cfg.lfm_expert_dim
+        w_gate = self.param("router", _INIT, (d, cfg.lfm_experts))
+        w13 = self.param("w13", _INIT, (g, d, 2 * f))
+        w2 = self.param("w2", _INIT, (g, f, d))
+        bias = self.variable(
+            "buffers", "expert_bias",
+            lambda: BIAS_STD * jax.random.normal(
+                self.make_rng("params"), (cfg.lfm_experts,),
+                jnp.float32)).value
+        flat = x.reshape(b * s, d)
+        routing = moe.route(flat, w_gate, bias, cfg.lfm_top_k)
+        self.sow("intermediates", "scores", routing.scores)
+        self.sow("intermediates", "experts", routing.experts)
+        out, counters = moe.expert_layer(
+            flat, valid.reshape(-1), routing, w13, w2,
+            offset=cfg.expert_offset, rows_bound=cfg.moe_rows_bound,
+            impl=cfg.moe_impl)
+        return out.reshape(b, s, d), counters
+
+
+class DecoderLayer(nn.Module):
+    """``h + operator(norm(h))``, then ``h + ffn(norm(h))``."""
+
+    cfg: ModelConfig
+    kind: str      # "conv" | "full_attention"
+    sparse: bool
+
+    @nn.compact
+    def __call__(self, h, valid):
+        cfg = self.cfg
+        x = RMSNorm(cfg.lfm_norm_eps, name="op_norm")(h)
+        if self.kind == "conv":
+            h = h + ShortConv(cfg, name="conv")(x)
+        elif self.kind == "full_attention":
+            h = h + Attention(cfg, name="attn")(x)
+        else:
+            raise ValueError(f"layer type {self.kind!r}")
+        x = RMSNorm(cfg.lfm_norm_eps, name="ffn_norm")(h)
+        if self.sparse:
+            y, counters = SparseExperts(cfg, name="moe")(x, valid)
+            return h + y, counters
+        return h + SwiGLU(cfg.lfm_ffn_dim, name="ffn")(x), None
+
+
+def pack(a_lens, labels, label_lens, s: int):
+    """The left-packed layout of a batch: for each of ``s`` positions,
+    whether it is audio, the id embedded there (text positions), the
+    target id and whether a target is there at all."""
+    u_max = labels.shape[1]
+    tpos = jnp.arange(s)[None, :] - a_lens[:, None]      # 0 = start
+    audio = tpos < 0
+    text = (tpos >= 0) & (tpos <= label_lens[:, None])
+    shifted = jnp.take_along_axis(
+        labels, jnp.clip(tpos - 1, 0, u_max - 1), axis=1)
+    ids = jnp.where(text & (tpos > 0), shifted, 0)
+    nxt = jnp.take_along_axis(labels, jnp.clip(tpos, 0, u_max - 1), axis=1)
+    targets = jnp.where(text & (tpos < label_lens[:, None]), nxt, 0)
+    return audio, text, ids, targets
+
+
+class LFM2ASR(nn.Module):
+    cfg: ModelConfig
+    max_label_len: int
+
+    @nn.compact
+    def hidden(self, features, feat_lens, labels, label_lens):
+        """The normed final hidden state ``[B, S, D]`` of the packed
+        batch, the embedding matrix (the tied head), the batch's layout
+        and each expert layer's counters."""
+        cfg = self.cfg
+        dtype = jnp.dtype(cfg.dtype)
+        layer_cls = nn.remat(DecoderLayer, policy=REMAT_POLICY)
+        embed = self.param("embed", _INIT,
+                           (cfg.vocab_size, cfg.lfm_hidden))
+        x, a_lens = stack_frames(features, feat_lens, cfg.frame_stack)
+        s = seq_positions(cfg, features.shape[1], self.max_label_len)
+        audio, text, ids, targets = pack(a_lens, labels, label_lens, s)
+        pre = Linear(cfg.lfm_hidden, name="prefix")(x.astype(dtype))
+        pre = jnp.pad(pre, [(0, 0), (0, s - pre.shape[1]), (0, 0)])
+        emb = jnp.take(embed.astype(dtype), ids, axis=0)
+        valid = audio | text
+        h = jnp.where(audio[..., None], pre,
+                      jnp.where(text[..., None], emb, 0))
+        counters = []
+        for i, kind in enumerate(cfg.lfm_layer_types):
+            h, c = layer_cls(cfg, kind, i >= cfg.lfm_dense_layers,
+                             name=f"layer{i}")(h, valid)
+            if c is not None:
+                counters.append(c)
+        layout = {"valid": valid, "targets": targets, "a_lens": a_lens}
+        h = RMSNorm(cfg.lfm_norm_eps, name="out_norm")(h)
+        return h, embed, layout, counters
+
+    def loss(self, features, feat_lens, labels, label_lens):
+        """Per-utterance summed cross-entropy over the ``u + 1`` target
+        positions, and the routing counters of the step. The logits are
+        computed at the text positions only, against the tied embedding
+        (this chip's slice of the vocabulary)."""
+        h, embed, layout, counters = self.hidden(
+            features, feat_lens, labels, label_lens)
+        logp, mask = target_logp(h, embed, layout, labels, label_lens)
+        nll = -jnp.sum(logp * mask, axis=1)
+        valid = layout["valid"]
+        stats = {"valid_positions": jnp.sum(valid),
+                 "padded_positions": valid.size - jnp.sum(valid)}
+        if counters:
+            stats.update(jax.tree.map(lambda *xs: jnp.stack(xs),
+                                      *counters))
+        return nll, stats
+
+
+def target_logp(h, embed, layout, labels, label_lens):
+    """Log-probability of each target, ``[B, U+1]``, and which of them
+    count: the logits of the text positions against the tied matrix."""
+    u1 = labels.shape[1] + 1
+    at = jnp.clip(layout["a_lens"][:, None] + jnp.arange(u1)[None, :],
+                  0, h.shape[1] - 1)
+    ht = jnp.take_along_axis(h, at[..., None], axis=1)
+    # One [B*(U+1), D] x [D, V] product: batched per utterance, its 65
+    # rows a matrix would waste the MXU's tiles.
+    logits = jnp.dot(ht.reshape(-1, ht.shape[-1]),
+                     embed.astype(h.dtype).T,
+                     preferred_element_type=jnp.float32)
+    want = jnp.take_along_axis(layout["targets"], at, axis=1)
+    logp = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
+                               want.reshape(-1, 1), axis=1).reshape(
+                                   want.shape)
+    mask = jnp.arange(u1)[None, :] <= label_lens[:, None]
+    return logp, mask.astype(logp.dtype)
+
+
+def create_lfm2_model(cfg: ModelConfig, max_label_len: int) -> LFM2ASR:
+    if not cfg.lfm_layer_types:
+        raise ValueError("objective='lm' needs model.lfm_layer_types")
+    if cfg.expert_offset + cfg.experts_held > cfg.lfm_experts:
+        raise ValueError(
+            f"experts {cfg.expert_offset}..+{cfg.experts_held} are not "
+            f"among the router's {cfg.lfm_experts}")
+    return LFM2ASR(cfg, max_label_len)

@@ -28,6 +28,10 @@ fact, not a name:
   gates    3 (GRU) or 4 (LSTM)
   p        lstmp_scan_*: width of the recurrent projection
   t, b, s  CTC: frames, padded batch rows, padded extended labels
+  m, k, n, groups
+           moe_gmm / moe_tgmm: static row capacity, contraction and
+           output widths, groups (experts held); moe_gmm also
+           ``transpose_rhs`` (1 for the gradient to the rows)
 """
 
 from __future__ import annotations
@@ -50,6 +54,8 @@ KERNELS = frozenset({
     "ctc_alpha",          # alpha recursion, alphas taped for the VJP
     "ctc_alpha_loss",     # alpha recursion, log-likelihood only
     "ctc_gamma",          # beta recursion folded into the occupancies
+    "moe_gmm",            # rows by ragged groups times each group's matrix
+    "moe_tgmm",           # per group, rows^T times rows: weight gradients
 })
 
 

@@ -1,0 +1,129 @@
+"""The sparse expert layer of one chip: routing over all experts,
+dropless dispatch to the experts held here, combine.
+
+An expert-parallel deployment divides a layer's experts over chips.
+This layer is told which it holds (``offset``, and as many as its
+weights have groups): it scores every position over ALL ``experts``
+at the published width, keeps the (position, expert) pairs whose
+expert lives here, computes their part of the result and leaves out
+what the absent experts would have added. On one chip it runs without
+its exchange; nothing here stands in for the absent chips.
+
+Dropless: a pair that lands here is computed, whatever the imbalance.
+Pairs are sorted by expert, the sizes of the runs are the groups of
+two grouped matrix products (``moe_pallas.gmm`` on a TPU,
+``jax.lax.ragged_dot`` elsewhere), and the rows go back to their
+positions by a weighted scatter-add. The one static size is the row
+capacity; at the worst case (``rows_bound`` 0) nothing can exceed it,
+and under a stated bound the layer counts what did (``dropped``).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from ..utils.impl import interpret_default, resolve_impl
+from . import moe_pallas
+
+
+class Routing(NamedTuple):
+    experts: jnp.ndarray   # [N, k] int32: chosen expert ids
+    weights: jnp.ndarray   # [N, k] float32: their combine weights
+    scores: jnp.ndarray    # [N, E] float32: sigmoid router scores
+
+
+def route(x, w_gate, bias, top_k: int) -> Routing:
+    """Sigmoid scores over all experts in float32; the ``top_k`` of
+    score + ``bias`` are chosen (``use_expert_bias``: the bias chooses,
+    it does not weigh); the chosen scores, normalised to sum to one
+    (``norm_topk_prob``), are the weights (``routed_scaling_factor``
+    1)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w_gate.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, experts = lax.top_k(scores + bias[None, :], top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=1)
+    weights = weights / (jnp.sum(weights, axis=1, keepdims=True) + 1e-6)
+    return Routing(experts.astype(jnp.int32), weights, scores)
+
+
+def capacity_rows(positions: int, top_k: int, held: int,
+                  rows_bound: float) -> int:
+    """Static rows of the dispatch buffer for ``positions`` computed
+    positions: the worst case (every position sends min(top_k, held)
+    pairs here), or ``rows_bound`` of all pairs; in whole row tiles."""
+    worst = positions * min(top_k, held)
+    rows = worst if rows_bound <= 0 else min(
+        worst, math.ceil(positions * top_k * rows_bound))
+    return moe_pallas.row_capacity(rows)
+
+
+def grouped_dot(lhs, rhs, group_sizes, impl: str):
+    """``lhs [m,k]`` by row groups times ``rhs [g,k,n]``; rows past the
+    groups give zeros under either implementation."""
+    if impl == "pallas":
+        out = moe_pallas.gmm(lhs, rhs, group_sizes, lhs.dtype,
+                             interpret_default())
+    else:
+        out = lax.ragged_dot(lhs, rhs, group_sizes)
+    # A layer that rematerialises keeps these (models/lfm2.py).
+    return checkpoint_name(out, "moe_rows")
+
+
+def expert_layer(x, valid, routing: Routing, w13, w2, *, offset: int,
+                 rows_bound: float = 0.0, impl: str = "auto"):
+    """``x [N, D]`` through the held experts.
+
+    ``w13 [G, D, 2F]`` holds each expert's gate and up matrices side by
+    side, ``w2 [G, F, D]`` its down matrix; the experts are ids
+    ``offset .. offset+G`` of the router's. ``valid [N]`` marks the
+    positions that are routed at all (padding is not). Returns the
+    partial result ``[N, D]`` (zeros where no chosen expert lives
+    here) and the step's counters.
+    """
+    impl = resolve_impl(impl, oracle="xla")
+    n, d = x.shape
+    g, _, f2 = w13.shape
+    k = routing.experts.shape[1]
+    m = capacity_rows(n, k, g, rows_bound)
+
+    local = routing.experts - offset
+    here = valid[:, None] & (local >= 0) & (local < g)
+    key = jnp.where(here, local, g).reshape(-1)          # [N*k]
+    # Stable: rows of one expert stay in position order.
+    order = jnp.argsort(key, stable=True)[:m]
+    if order.shape[0] < m:  # fewer pairs than one row tile
+        order = jnp.pad(order, (0, m - order.shape[0]))
+    token = order // k
+    counts = jnp.sum(jax.nn.one_hot(key, g + 1, dtype=jnp.int32),
+                     axis=0)[:g]
+    # Under a stated bound the groups are cut to the rows there are.
+    ends = jnp.minimum(jnp.cumsum(counts), m)
+    sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    routed = jnp.arange(m) < ends[-1]
+    weight = jnp.where(routed, routing.weights.reshape(-1)[order], 0.0)
+
+    xs = jnp.take(x, token, axis=0)                       # [M, D]
+    h = grouped_dot(xs, w13.astype(x.dtype), sizes, impl)
+    f = f2 // 2
+    act = (jax.nn.silu(h[:, :f].astype(jnp.float32))
+           * h[:, f:].astype(jnp.float32)).astype(x.dtype)
+    ys = grouped_dot(act, w2.astype(x.dtype), sizes, impl)
+    out = jnp.zeros((n, d), jnp.float32).at[token].add(
+        ys.astype(jnp.float32) * weight[:, None])
+
+    held = jnp.sum(counts)
+    counters = {
+        "expert_pairs": counts,                       # [G] pairs each
+        "pairs_elsewhere": jnp.sum(valid) * k - held,
+        "rows_high_water": held,
+        "rows_capacity": jnp.int32(m),
+        "dropped": held - ends[-1],
+    }
+    return out.astype(x.dtype), counters

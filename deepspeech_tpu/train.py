@@ -123,33 +123,51 @@ def select_loss_fn(cfg: Config, mesh=None):
 def create_train_state(cfg: Config, rng: jax.Array, sample_batch: Dict,
                        optimizer: optax.GradientTransformation,
                        mesh=None) -> Tuple[Any, TrainState]:
+    def few(frames: int):
+        """A few rows and frames of the sample: no parameter's shape
+        depends on the batch, so the rnnt and lm objectives initialise
+        through their training path as ONE compiled program on these
+        (the eager path compiles every primitive on its own)."""
+        return (jnp.asarray(sample_batch["features"][:8, :frames]),
+                jnp.minimum(jnp.asarray(sample_batch["feat_lens"][:8]),
+                            frames),
+                jnp.asarray(sample_batch["labels"][:8]),
+                jnp.asarray(sample_batch["label_lens"][:8]))
+
     if cfg.train.objective == "rnnt":
         from .models.transducer import create_rnnt_model
 
         model = create_rnnt_model(cfg.model, mesh=mesh)
-        # No parameter's shape depends on the batch: initialise through
-        # the training path on a few rows and frames of the sample, as
-        # ONE compiled program (the eager path compiles every primitive
-        # of ten recurrent layers on its own).
-        rows = slice(0, 8)
-        frames = slice(0, 8 * cfg.model.time_stride)
         variables = jax.jit(partial(
             model.init, train=False, method=type(model).loss))(
-            rng, jnp.asarray(sample_batch["features"][rows, frames]),
-            jnp.minimum(jnp.asarray(sample_batch["feat_lens"][rows]),
-                        frames.stop),
-            jnp.asarray(sample_batch["labels"][rows]),
-            jnp.asarray(sample_batch["label_lens"][rows]))
+            rng, *few(8 * cfg.model.time_stride))
+    elif cfg.train.objective == "lm":
+        from .models.lfm2 import create_lfm2_model
+
+        model = create_lfm2_model(cfg.model, cfg.data.max_label_len)
+        # The least positions that hold the few frames, on the oracle.
+        small = create_lfm2_model(
+            dataclasses.replace(cfg.model, lfm_seq_positions=0,
+                                moe_impl="xla"), cfg.data.max_label_len)
+        variables = jax.jit(partial(small.init, method="loss"))(
+            rng, *few(2 * cfg.model.frame_stack))
     else:
         model = create_model(cfg.model, mesh=mesh)
         variables = model.init(
             rng, jnp.asarray(sample_batch["features"]),
             jnp.asarray(sample_batch["feat_lens"]), train=False)
     params = variables["params"]
-    batch_stats = variables.get("batch_stats", {})
+    # What is carried and never trained: batch-norm statistics, or the
+    # lm objective's buffers (the experts' selection bias), which no
+    # optimizer sees.
+    batch_stats = variables.get(_stats_collection(cfg), {})
     opt_state = optimizer.init(params)
     return model, TrainState(step=jnp.zeros((), jnp.int32), params=params,
                              batch_stats=batch_stats, opt_state=opt_state)
+
+
+def _stats_collection(cfg: Config) -> str:
+    return "buffers" if cfg.train.objective == "lm" else "batch_stats"
 
 
 def state_shardings(mesh, state: TrainState,
@@ -189,7 +207,7 @@ def make_train_step(cfg: Config, model, optimizer, mesh, state_sh,
     ``steps_per_epoch=1`` for callers that never fit epochs (AOT
     compile probes).
     """
-    loss_fn = (None if cfg.train.objective == "rnnt"
+    loss_fn = (None if cfg.train.objective in ("rnnt", "lm")
                else select_loss_fn(cfg, mesh=mesh))
     schedule = (lr_schedule if lr_schedule is not None
                 else make_lr_schedule(cfg, 1))
@@ -245,6 +263,19 @@ def make_train_step(cfg: Config, model, optimizer, mesh, state_sh,
                 return loss, mutated.get("batch_stats", stats)
 
             return jax.value_and_grad(loss_of, has_aux=True)(params)
+    elif cfg.train.objective == "lm":
+        def grads_of(params, stats, mb):
+            def loss_of(p):
+                # Summed cross-entropy of each transcript, mean over
+                # utterances; the routing counters ride with the loss
+                # (same fetch, no second sync).
+                per_utt, routing = model.apply(
+                    {"params": p, "buffers": stats},
+                    mb["features"], mb["feat_lens"], mb["labels"],
+                    mb["label_lens"], method="loss")
+                return jnp.mean(per_utt), (stats, routing)
+
+            return jax.value_and_grad(loss_of, has_aux=True)(params)
     else:
         def grads_of(params, stats, mb):
             def loss_of(p):
@@ -291,20 +322,30 @@ def make_train_step(cfg: Config, model, optimizer, mesh, state_sh,
             loss = lsum / accum
         return loss, new_stats, grads
 
+    def split_aux(aux):
+        """What a step carries besides its loss: the new statistics
+        and, for the lm objective, the routing counters that ride with
+        the loss to the host."""
+        if cfg.train.objective == "lm":
+            return aux[0], {"routing": aux[1]}
+        return aux, {}
+
     def step_fn(state: TrainState, batch: Dict) -> Tuple[TrainState, Dict]:
         loss, new_stats, grads = forward(state, batch)
+        new_stats, routing = split_aux(new_stats)
         grad_norm = optax.global_norm(grads)
         updates, new_opt = optimizer.update(grads, opt_state_at(state),
                                             state.params)
         new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params,
                                batch_stats=new_stats, opt_state=new_opt)
-        metrics = {"loss": loss, "grad_norm": grad_norm}
+        metrics = {"loss": loss, "grad_norm": grad_norm, **routing}
         return new_state, metrics
 
     def guarded_step_fn(state: TrainState, batch: Dict,
                         ctl: Dict) -> Tuple[TrainState, Dict]:
         loss, new_stats, grads = forward(state, batch)
+        new_stats, routing = split_aux(new_stats)
         grad_norm = optax.global_norm(grads)
         # The backoff multiplies the schedule INSIDE the optimizer
         # (injected learning_rate hyperparam), so momentum bookkeeping
@@ -329,7 +370,7 @@ def make_train_step(cfg: Config, model, optimizer, mesh, state_sh,
         new_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
                                  new_state, state)
         metrics = {"loss": loss, "grad_norm": grad_norm,
-                   "update_norm": update_norm, "applied": ok}
+                   "update_norm": update_norm, "applied": ok, **routing}
         return new_state, metrics
 
     if cfg.train.sequence_parallel:
@@ -468,20 +509,30 @@ class Trainer:
             raise ValueError(
                 f"batch_size {cfg.data.batch_size} must divide by "
                 f"accum_steps*data = {accum}*{data_size}")
-        if cfg.train.objective not in ("ctc", "rnnt"):
+        objective = cfg.train.objective
+        if objective not in ("ctc", "rnnt", "lm"):
             # A typo must not silently train the CTC stack.
             raise ValueError(
-                f"train.objective={cfg.train.objective!r}; "
-                f"'ctc' or 'rnnt'")
-        if cfg.train.objective == "rnnt":
+                f"train.objective={objective!r}; 'ctc', 'rnnt' or 'lm'")
+        if objective in ("rnnt", "lm"):
             if cfg.train.sequence_parallel or cfg.model.pipeline_stages > 1:
                 raise ValueError(
-                    "objective='rnnt' (experimental transducer) excludes "
+                    f"objective={objective!r} excludes "
                     "sequence_parallel and pipeline_stages>1")
             if jax.process_count() > 1:
                 # Fail at construction, not after an epoch of work in
                 # the (host-loop) transducer eval.
-                raise ValueError("objective='rnnt' is single-process")
+                raise ValueError(f"objective={objective!r} is "
+                                 "single-process")
+        if objective == "lm" and accum > 1:
+            raise ValueError("objective='lm' excludes accum_steps>1 "
+                             "(its step carries the routing counters)")
+        if objective == "lm" and eval_pipeline is not None:
+            # Fail at construction, not after an epoch of work.
+            raise ValueError(
+                "objective='lm' has no transcript decoding yet, so no "
+                "in-training eval: pass no eval_pipeline (ROADMAP: "
+                "greedy decoding for the lm objective)")
         stages = cfg.model.pipeline_stages
         if stages > 1:
             # Training with a pipelined model silently falling back to
@@ -545,7 +596,7 @@ class Trainer:
             cfg, self.model, self.optimizer, self.mesh, self.state_sh,
             guardian=self.guardian_cfg is not None,
             lr_schedule=self.lr_schedule)
-        self.eval_step = (None if cfg.train.objective == "rnnt"
+        self.eval_step = (None if cfg.train.objective in ("rnnt", "lm")
                           else make_eval_step(self.model))
         self.ckpt = None
         if cfg.train.checkpoint_dir:
@@ -556,6 +607,9 @@ class Trainer:
                 keep=cfg.train.keep_checkpoints,
                 last_good_keep=(self.guardian_cfg.ring_size
                                 if self.guardian_cfg else 2))
+        # lm objective: each step's count of dropped pairs, as device
+        # scalars, since the last sync (fit reads them there).
+        self._dropped = []
         self.guardian = None
         if self.guardian_cfg is not None:
             from .resilience.guardian import TrainingGuardian
@@ -575,7 +629,16 @@ class Trainer:
             self.logger.log("restore", step=int(self.state.step),
                             epoch=self.start_epoch)
 
+    def _check_dropless(self) -> None:
+        """Raise if a step since the last sync dropped routed pairs
+        (lm objective): called where the loop syncs anyway."""
+        pending, self._dropped = self._dropped, []
+        if pending:
+            obs.check_dropless(pending)
+
     def save(self, epoch: int) -> None:
+        # No checkpoint holds parameters that a dropping step made.
+        self._check_dropless()
         if self.ckpt is not None:
             with obs.span("train.checkpoint", step=int(self.state.step)):
                 self.ckpt.save(int(self.state.step),
@@ -584,6 +647,10 @@ class Trainer:
     def evaluate(self) -> Dict[str, float]:
         if self.cfg.train.objective == "rnnt":
             return self._evaluate_rnnt()
+        if self.cfg.train.objective == "lm":
+            raise NotImplementedError(
+                "objective='lm' has no transcript decoding yet "
+                "(ROADMAP: greedy decoding for the lm objective)")
         if self.cfg.decode.mode != "greedy":
             # Beam search + LM rescoring live in infer.py (decode/beam.py);
             # in-training eval always uses the cheap greedy path.
@@ -776,6 +843,12 @@ class Trainer:
                             # state; the host step counter must not
                             # advance either.
                             continue
+                    if "routing" in metrics:
+                        # Every step's count of pairs that did not fit
+                        # the expert layers' rows, read at the next
+                        # sync: no step drops a pair unnoticed.
+                        self._dropped.append(
+                            metrics["routing"]["dropped"])
                     thr.update(len(sharded["feat_lens"]))
                     step += 1
                     if self.guardian is not None:
@@ -796,15 +869,24 @@ class Trainer:
                             last = {"loss": float(metrics["loss"]),
                                     "grad_norm":
                                         float(metrics["grad_norm"])}
+                            routing = {}
+                            if "routing" in metrics:
+                                routing = obs.observe_routing(
+                                    metrics["routing"], self._dropped)
+                                self._dropped = []
                             self.logger.log(
                                 "train_step", step=step, epoch=epoch,
                                 lr=round(lr, 8),
                                 utt_per_sec_per_chip=round(rate, 3),
-                                **last)
+                                **last, **routing)
                             if self.tb is not None:
+                                # The per-expert lists stay in the log
+                                # line and the registry.
                                 self.tb.scalars(
                                     step, **last, lr=lr,
-                                    utt_per_sec_per_chip=rate)
+                                    utt_per_sec_per_chip=rate,
+                                    **{k: v for k, v in routing.items()
+                                       if np.isscalar(v)})
                     if (cfg.train.checkpoint_every_steps and self.ckpt and
                             step % cfg.train.checkpoint_every_steps == 0):
                         self.save(epoch)
@@ -823,6 +905,7 @@ class Trainer:
                                 if self.ckpt.latest_step() != step:
                                     self.save(epoch)
                                 self.ckpt.wait()
+                        self._check_dropless()
                         self.logger.log("preempted", step=step,
                                         epoch=epoch)
                         preempted = True

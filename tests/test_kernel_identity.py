@@ -80,6 +80,16 @@ CASES = {
          "p": "640"},
         {**scan("lstmp_scan_bwd", "resident", 0, 65, 64, 2048, 4),
          "p": "640"}],
+    # lfm2_24b_a2b's down projection of 8 held experts over the cell's
+    # 32,256 rows: forward, the gradient to the rows (the contraction
+    # is then the output width), the weights' gradient.
+    "moe_gmm_w2": [
+        {"kernel": "moe_gmm", "m": "32256", "k": "1536", "n": "2048",
+         "groups": "8", "transpose_rhs": "0"},
+        {"kernel": "moe_gmm", "m": "32256", "k": "2048", "n": "1536",
+         "groups": "8", "transpose_rhs": "1"},
+        {"kernel": "moe_tgmm", "m": "32256", "k": "1536", "n": "2048",
+         "groups": "8"}],
 }
 
 
@@ -231,7 +241,8 @@ def test_every_name_of_the_vocabulary_is_built_somewhere():
     """The vocabulary is closed both ways: a name nobody builds is a
     reader's dead branch."""
     used = set()
-    for name in ("rnn_pallas.py", "lstm_pallas.py", "ctc_pallas.py"):
+    for name in ("rnn_pallas.py", "lstm_pallas.py", "ctc_pallas.py",
+                 "moe_pallas.py"):
         with open(os.path.join(REPO, "deepspeech_tpu", "ops", name)) as f:
             used.update(re.findall(r'kernel="(\w+)"', f.read()))
     assert used == kernel_id.KERNELS

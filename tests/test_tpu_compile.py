@@ -61,6 +61,10 @@ def v5e_chip():
     # rnnt_he2019's prediction net: 13.1 MB of single-buffered bf16
     # weights under a raised scoped-VMEM limit, forward + VJP
     ("lstmp_t65_b64", ["lstmp_scan_fwd", "lstmp_scan_bwd"]),
+    # lfm2_24b_a2b's gate+up projection of 8 held experts over 32,256
+    # rows: whole-contraction blocks under a 48 MiB scoped-VMEM limit,
+    # a grid as long as the routed tiles, forward + VJP
+    ("moe_gmm_w13", ["moe_gmm", "moe_gmm", "moe_tgmm"]),
 ])
 def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     """Mosaic accepts the kernel, and the compiled instruction still

@@ -214,6 +214,24 @@ def kernel_cases():
             return train, args
         return f
 
+    def moe_case(k, n, m=32256, groups=8):
+        """lfm2_24b_a2b's grouped products at the cell's row capacity:
+        ``moe_gmm`` forward, and through its gradient the transposed
+        ``moe_gmm`` and ``moe_tgmm``."""
+        from deepspeech_tpu.ops import moe_pallas
+
+        args = (S((m, k), jnp.bfloat16), S((groups, k, n), jnp.bfloat16),
+                S((groups,), jnp.int32))
+
+        def f():
+            def train(lhs, rhs, sizes):
+                out, vjp = jax.vjp(
+                    lambda a, b: moe_pallas.gmm(a, b, sizes,
+                                                jnp.bfloat16), lhs, rhs)
+                return out, vjp(jnp.ones_like(out))
+            return train, args
+        return f
+
     cases["gru_h800"] = gru_case(800)
     cases["gru_h1760"] = gru_case(1760)
     # ds2_full.train_1chip's own call (850 post-conv frames, bf16
@@ -245,6 +263,10 @@ def kernel_cases():
     cases["lstmp_t567_b64"] = lstmp_case(567)
     cases["lstmp_t284_b64"] = lstmp_case(284)
     cases["lstmp_t65_b64"] = lstmp_case(65)
+    # lfm2_24b_a2b.train_asr_16s_b128: gate+up (2048 -> 2 x 1536) and
+    # down (1536 -> 2048) of 8 held experts over 32,256 rows.
+    cases["moe_gmm_w13"] = moe_case(2048, 3072)
+    cases["moe_gmm_w2"] = moe_case(1536, 2048)
     cases["ctc_aishell"] = ctc_case(4336, 400, 60)
     cases["ctc_en"] = ctc_case(29, 400, 160)
     # The weak-#1 shape: AISHELL-width device beam search, both merge

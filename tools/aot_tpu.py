@@ -67,6 +67,10 @@ def main() -> None:
                     help="gradient-accumulation microbatching (>1)")
     ap.add_argument("--objective", default="",
                     help="override train.objective (e.g. rnnt)")
+    ap.add_argument("--set", action="append", default=[], dest="overrides",
+                    metavar="SECTION.KEY=VALUE",
+                    help="override one field of the preset (repeatable),"
+                         " e.g. model.moe_rows_bound=0.25")
     ap.add_argument("--compiler-option", action="append", default=[],
                     dest="compiler_options", metavar="K=V",
                     help="TPU-compile-only XLA option (repeatable), e.g. "
@@ -90,7 +94,7 @@ def main() -> None:
     from jax.experimental import topologies
     from jax.sharding import Mesh
 
-    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.config import apply_overrides, get_config
     from deepspeech_tpu.data.synthetic import synthetic_batch
     from deepspeech_tpu.data.tokenizer import CharTokenizer  # noqa: F401
     from deepspeech_tpu.train import (create_train_state, make_optimizer,
@@ -105,7 +109,8 @@ def main() -> None:
     mesh = Mesh(np.array(topo.devices[:args.ndev]).reshape(args.ndev, 1),
                 ("data", "model"))
 
-    cfg = get_config(args.preset)
+    cfg = apply_overrides(get_config(args.preset),
+                          dict(kv.split("=", 1) for kv in args.overrides))
     model_cfg = cfg.model
     train_cfg = cfg.train
     if args.rnn_impl:
@@ -120,7 +125,8 @@ def main() -> None:
     # The transducer's lattice is padded to max_label_len, so a preset
     # that trains one keeps its own (rnnt_he2019: 64 word-pieces).
     rnnt = train_cfg.objective == "rnnt"
-    max_label_len = cfg.data.max_label_len if rnnt else 160
+    lm = train_cfg.objective == "lm"
+    max_label_len = cfg.data.max_label_len if rnnt or lm else 160
     cfg = dataclasses.replace(
         cfg, model=model_cfg, train=train_cfg,
         data=dataclasses.replace(cfg.data, batch_size=args.batch,
@@ -140,13 +146,22 @@ def main() -> None:
     cfg_init = dataclasses.replace(
         cfg, model=dataclasses.replace(cfg.model, rnn_impl="xla"))
     _log("initializing params on host...")
-    _, state = create_train_state(cfg_init, rng, batch, optimizer,
-                                  mesh=mesh)
+    if lm:
+        # Half a billion parameters and two moments: shapes only.
+        state = jax.eval_shape(
+            lambda r: create_train_state(cfg_init, r, batch, optimizer,
+                                         mesh=mesh)[1], rng)
+    else:
+        _, state = create_train_state(cfg_init, rng, batch, optimizer,
+                                      mesh=mesh)
     # Rebuild the MODEL with the requested impls for the traced step
     # (construction is cheap; no eager compute happens here).
-    if cfg.train.objective == "rnnt":
+    if rnnt:
         from deepspeech_tpu.models.transducer import create_rnnt_model
         model = create_rnnt_model(cfg.model, mesh=mesh)
+    elif lm:
+        from deepspeech_tpu.models.lfm2 import create_lfm2_model
+        model = create_lfm2_model(cfg.model, max_label_len)
     else:
         from deepspeech_tpu.models import create_model
         model = create_model(cfg.model, mesh=mesh)
@@ -159,8 +174,7 @@ def main() -> None:
     step = make_train_step(cfg, model, optimizer, mesh, state_sh)
 
     state_shapes = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype),
-        state)
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), state)
     batch_shapes = {k: jax.ShapeDtypeStruct(np.asarray(v).shape,
                                             np.asarray(v).dtype)
                     for k, v in batch.items()}
@@ -222,10 +236,11 @@ def main() -> None:
 
     from deepspeech_tpu.utils.flops import ds2_step_flops
 
-    # utils/flops.py knows the DS2 stack only: a transducer step gets
-    # no analytic number here (benchmark/costs/rnnt.py has one).
+    # utils/flops.py knows the DS2 stack only: a transducer step and
+    # a decoder-only step get no analytic number here
+    # (benchmark/costs/rnnt.py and costs/lfm2.py have theirs).
     analytic = None
-    if not rnnt:
+    if not (rnnt or lm):
         try:
             analytic = float(ds2_step_flops(
                 cfg.model, args.batch, args.frames,
@@ -238,7 +253,8 @@ def main() -> None:
         "preset": args.preset,
         "batch": args.batch,
         "frames": args.frames,
-        "impls": f"{cfg.model.rnn_impl}/{cfg.train.loss_impl}",
+        "impls": (cfg.model.moe_impl if lm else
+                  f"{cfg.model.rnn_impl}/{cfg.train.loss_impl}"),
         "objective": cfg.train.objective,
         # Non-default compiles must be reproducible from the row alone
         # (a 'fits' verdict under a raised VMEM budget is not a

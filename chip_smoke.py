@@ -13,7 +13,11 @@ are cut:
   train      ds2_full (2 conv + 7 BiGRU-1760 + BN, bf16), b=16, 4 steps
   infer      restores that checkpoint, greedy-decodes 32 utterances
   reference  the Pallas GRU and CTC kernels against the repo's XLA/jnp
-             oracles at those widths on a small input
+             oracles at those widths on a small input; ds2_full's scan
+             call at the benchmark cell's own rows and frames in its
+             two builds (weights copied once / streamed in column
+             blocks): forward the same bits, gradients as near the XLA
+             scan's as each other
   serve      ds2_streaming (uni-GRU 5x800 + lookahead 20): a checkpoint
              from two train steps, two generated wavs streamed chunk by
              chunk, finals compared with the offline decode of the same
@@ -61,6 +65,20 @@ LOSS_RTOL = 0.02
 # Kernel against oracle at bf16 dots (r2 on this chip measured 1.3e-3).
 GRU_RTOL = 1e-2
 CTC_RTOL = 1e-3
+# ds2_full.train_1chip's scan call (rows, post-conv frames), where the
+# two builds of the H=1760 kernels are compared with each other and
+# with the XLA scan.
+SCAN_CALL = (32, 850)
+# Gradients of that call, as shares of each one's largest magnitude:
+# the copy-once build against the streamed build (one float32 sum of
+# dh associated another way; each flipped bf16 rounding of dgates then
+# feeds the next of 850 steps: the chip reads up to 3.3e-4), and either
+# against the XLA scan (the chip reads 2.8e-3, 8.6e-2 and 5.2e-4 for
+# both builds alike; the scan's transposed dot takes bf16 operands
+# where the kernels' dW_h runs at HIGHEST). A build that lost a column
+# block or a time step reads tenths to ones.
+SCAN_BUILDS_RTOL = 2e-3
+SCAN_ORACLE_RTOL = {"dxproj": 1e-2, "dw_h": 0.2, "db_h": 2e-3}
 # Streamed finals against the offline decode of the same audio: the
 # two graphs reduce in different orders in bf16, so an argmax near a
 # tie may flip; more than this is a wrong stream, not rounding.
@@ -350,6 +368,7 @@ def phase_reference() -> dict:
         if not err <= GRU_RTOL:
             fail(f"GRU H={h} kernel differs from the XLA scan: {err}")
         out[f"gru_h{h}_rel_err"] = err
+    out.update(scan_builds(interpret))
     t, v, lmax = 100, 29, 20
     logits = jnp.asarray(rng.normal(size=(b, t, v)), jnp.float32)
     label_lens = jnp.asarray(rng.integers(lmax // 2, lmax + 1, size=b),
@@ -365,6 +384,78 @@ def phase_reference() -> dict:
     if not err <= CTC_RTOL:
         fail(f"CTC kernel differs from the jnp loss: {err}")
     out["ctc_rel_err"] = err
+    return out
+
+
+def scan_builds(interpret: bool) -> dict:
+    """ds2_full's scan call (``SCAN_CALL``, H=1760, bf16) in the build
+    its shapes choose (``pinned``: the weights copied into VMEM once,
+    one grid step a time step) and in the streamed build the same call
+    takes with the cap at 0 (``blocked``: 512-column blocks), same
+    inputs. Forward the two compiled builds give the same bits: the
+    gates' columns are independent (an interpreted run rounds per CPU
+    fusion, tests/test_pallas.py). Backward ``dh`` is one float32 sum
+    associated another way, so the gradients differ by rounding that
+    850 steps of bf16 dots carry along; what holds them is the XLA
+    scan, from which neither build may lie further than the limits."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeech_tpu.models.rnn import gru_scan
+    from deepspeech_tpu.ops import rnn_pallas
+
+    (b, t), h = SCAN_CALL, 1760
+    rng = np.random.default_rng(1)
+    xp = jnp.asarray(rng.normal(size=(b, t, 3 * h)), jnp.bfloat16)
+    wh = jnp.asarray(rng.normal(size=(h, 3 * h)) / np.sqrt(h), jnp.float32)
+    bh = jnp.asarray(rng.normal(size=(3 * h,)) * 0.1, jnp.float32)
+    lens = rng.integers(t // 2, t + 1, size=b)
+    mask = jnp.asarray(np.arange(t)[None] < lens[:, None], jnp.float32)
+    dy = jnp.asarray(rng.normal(size=(b, t, h)) * 0.1, jnp.float32)
+
+    def run(scan):
+        def ys_and_grads(x, w, bias):
+            ys, vjp = jax.vjp(lambda *a: scan(a[0], mask, *a[1:]),
+                              x, w, bias)
+            return (ys,) + vjp(dy)
+
+        return [np.asarray(a, np.float32)
+                for a in jax.jit(ys_and_grads)(xp, wh, bh)]
+
+    def pallas(x, m, w, bias):
+        return rnn_pallas.gru_scan_pallas(x, m, w, bias, False, interpret,
+                                          "bfloat16")
+
+    pinned = run(pallas)
+    with mock.patch.object(rnn_pallas, "_PINNED_VMEM_CAP", 0):
+        streamed = run(pallas)
+    oracle = run(lambda x, m, w, bias: gru_scan(
+        x, m, w, bias, dot_dtype=jnp.bfloat16))
+
+    def rel(got, want):
+        return float(np.max(np.abs(got - want)) / np.abs(want).max())
+
+    differing = int((pinned[0] != streamed[0]).sum())
+    if differing and not interpret:
+        fail(f"GRU H={h} forward: the copy-once build differs from the "
+             f"streamed build in {differing} of {pinned[0].size} values")
+    out = {"gru_builds_fwd_differing": differing,
+           "gru_builds_fwd_values": int(pinned[0].size)}
+    for i, name in enumerate(("ys", "dxproj", "dw_h", "db_h")):
+        builds = rel(pinned[i], streamed[i])
+        errs = {"pinned": rel(pinned[i], oracle[i]),
+                "streamed": rel(streamed[i], oracle[i])}
+        if i and not builds <= SCAN_BUILDS_RTOL:
+            fail(f"GRU H={h} {name}: the builds differ by {builds}")
+        for build, err in errs.items():
+            if not err <= (SCAN_ORACLE_RTOL[name] if i else GRU_RTOL):
+                fail(f"GRU H={h} {name}: the {build} build differs "
+                     f"from the XLA scan by {err}")
+            out[f"gru_{build}_{name}_rel_err"] = err
+        out[f"gru_builds_{name}_rel_diff"] = builds
     return out
 
 

@@ -13,15 +13,16 @@ resolved while jax traces the caller; the kernel's body is unchanged.
 One name per kernel ROLE; what tells two builds of a role apart is a
 fact, not a name:
 
-  variant  ``resident`` (whole weight matrix a VMEM block), ``blocked``
-           (weight columns moved by the pipeline over a second grid
-           axis, from wherever XLA left the matrix: a matrix past
-           ``rnn_pallas._PINNED_VMEM_CAP``), ``blocked_pinned`` (the
-           same grid, but the kernel copies the matrix into a VMEM
-           scratch once and slices its column blocks from there: the
-           GRU's forward and backward kernel under the cap),
-           ``resident_q`` / ``blocked_q`` (as the first two, with int8
-           weights)
+  variant  ``resident`` (whole weight matrix a VMEM block), ``pinned``
+           (the GRU's float kernels past the residency budget, forward
+           and backward: the kernel copies the matrix into a VMEM
+           scratch once and every time step, ONE grid step, consumes
+           it whole), ``blocked`` (weight columns moved by the
+           pipeline over a second grid axis, from wherever XLA left
+           the matrix, because a pipelined operand is double-buffered:
+           a call whose need reaches ``rnn_pallas._PINNED_VMEM_CAP``,
+           and ``lstm_pallas``), ``resident_q`` / ``blocked_q`` (as
+           ``resident`` and ``blocked``, with int8 weights)
   reverse  1 if the scan runs from the last frame to the first, else
            0; ``both`` for the fused bidirectional kernels
   t, b, h  steps, batch rows and hidden width of the call

@@ -1,6 +1,7 @@
 """Fused Pallas GRU cell (SURVEY.md §2 component 6).
 
-The TPU-native answer to cuDNN's fused RNN kernels, in two regimes:
+The TPU-native answer to cuDNN's fused RNN kernels, by where the
+recurrent matrix lives:
 
 **Resident** (small/medium H): the ``[H, 3H]`` recurrent matrix is a
 VMEM block with a constant index map, so Pallas fetches it once and it
@@ -10,39 +11,50 @@ cuDNN's "persistent RNN" equivalent. Budget: 3*H^2*bytes must fit the
 ~10 MB residency budget (H=800 f32 -> 7.7 MB ok; bf16 doubles reach
 to H~1280).
 
-**Blocked** (big H, e.g. the ds2_full flagship H=1760, whose weights
-are 37 MB f32 / 18.6 MB bf16: past the residency budget that Mosaic's
-default 16 MiB scoped limit leaves, not past VMEM, of which a v5e core
-has 128 MiB): the weight columns are consumed in ``[H, C]`` blocks
-over a ``(T, G)`` grid. The grid is there for the scoped-VMEM working
-set and the MXU feed: a pipelined operand is double-buffered, so the
+**Past the residency budget** (big H, e.g. the ds2_full flagship
+H=1760, whose weights are 37 MB f32 / 18.6 MB bf16: past the budget
+that Mosaic's default 16 MiB scoped limit leaves, not past VMEM, of
+which a v5e core has 128 MiB) a call is one of two builds, chosen from
+its shapes by ``_past_budget_scan_call`` through ``_pinned_vmem_limit``:
+
+*Copy-once* (variant ``pinned``; the call's need stays under
+``_PINNED_VMEM_CAP``: H=1760 in bf16 at every batch the presets run).
+The ``[H, 3H]`` operand is taken as it is in ``pl.ANY``; ONE DMA at the
+first grid step copies it whole into a VMEM scratch; the grid is
+``(T,)``, one step per time step, and each step is the resident
+kernels' step with the scratch for its matrix: one ``[b, H] x [H, 3H]``
+matmul for the gates and the element-wise update straight after it
+(``_gru_kernel_pinned`` = the copy + ``_gru_kernel``), backward also one
+``dgates x W^T`` contraction (``_gru_bwd_kernel_pinned`` = the copy +
+``_gru_bwd_kernel``). The call raises its own scoped limit from its
+shapes. Where XLA's memory-space assignment left the operand does not
+matter: from HBM the one copy is 18.6 MB once a call, and nothing moves
+the matrix again. What this build got rid of, each measured on the chip
+(PERF.md section 6): the weights crossing HBM at every step (six of 14
+backward calls until PR 27: 29.5 us a step against 17.3); the BlockSpec
+pipeline's VMEM-to-VMEM copy of every column block of a matrix XLA had
+already placed in VMEM (3.0 us of a 17.3 us backward step, 3.2 of a
+9.2 us forward step: PR 27, PR 29); and the column grid itself, 11 grid
+steps, 11 small matmuls and 11 partial stores at each of 850 dependent
+time steps (PR 31; padding the scratch's columns to the lane width
+reads the same to 0.002 ms a call, so it is not padded).
+
+*Streamed* (variant ``blocked``; past the cap: a float32 model at
+H=1760 beyond a few rows, wider layers; run by no preset). The weight
+columns are consumed in ``[H, C]`` blocks of ``_BLOCK_COLS`` over a
+``(T, G)`` grid, moved by the BlockSpec pipeline from wherever XLA left
+the operand: from HBM that is the whole matrix every step, the honest
+cost of a matrix that cannot live in VMEM. The column grid is there for
+THIS build alone, because a pipelined operand is double-buffered: the
 whole matrix as one block would cost twice its size where two 1.8 MB
-column blocks do, and each step's matmul runs as G block matmuls whose
+column blocks do. Each step's matmul runs as G block matmuls whose
 partials land in a VMEM scratch, the GRU elementwise update firing on
-the last block. It is NOT there because the weights must cross HBM
-every step (PERF.md section 6, PR 22 finding 1: they need not, and a
-step that does fetch them takes 29.5 us against 17.3). Who places the
-matrix: the kernel itself, in both directions (``_gru_kernel_blocked``,
-``_gru_bwd_kernel_blocked``), when ``_pinned_vmem_limit`` says the call
-fits ``_PINNED_VMEM_CAP`` (variant ``blocked_pinned``): the operand is
-taken in ``pl.ANY``, one DMA at the first grid step copies it whole
-into a VMEM scratch, and every column block is a slice of that scratch
-(``_weight_blocks``); the call raises its own scoped limit from its
-shapes (``_blocked_scan_call``). Where XLA's memory-space assignment
-left the operand no longer matters to either: from HBM the one copy is
-19.8 MB once a call, and an operand XLA had placed in VMEM (``S(1)``)
-is spared the BlockSpec pipeline's VMEM-to-VMEM copy of every block at
-every time step, which is not hidden behind the block's matmul (3.0 us
-of a 17.3 us backward step, 3.2 of a 9.2 us forward step: PERF.md
-section 6, PR 27 and PR 29). Past the cap (f32 dots at H=1760, wider
-layers) the BlockSpec pipeline streams the blocks from wherever XLA
-left the operand (variant ``blocked``): from HBM that is the whole
-matrix every step, the honest cost of a matrix that cannot live in
-VMEM. One kernel body per direction serves both builds; only where a
-column block comes from differs, and that is written once. The
-backward kernel needs the blocks once per step: it pipelines the
-``dgates @ W^T`` contraction one step behind the gate recompute
-(SURVEY.md §7 hard-parts #2).
+the last block (``_gru_kernel_blocked``); the backward kernel
+(``_gru_bwd_kernel_blocked``) needs the blocks once per step: it
+pipelines the ``dgates @ W^T`` contraction one step behind the gate
+recompute (SURVEY.md §7 hard-parts #2). The copy-once backward step
+does not: with the matrix whole in its scratch a second pass costs
+nothing, and the resident body's order measured faster on the chip.
 
 **int8 resident / int8 blocked streaming** (weight-only PTQ serving):
 ``gru_scan_pallas_q`` keeps the QUANTIZED matrix resident — int8
@@ -52,7 +64,7 @@ the gates via column-scale associativity (see the section comment
 below). Past even the 1-byte budget (GRU H>1869; LSTM's 4-gate
 layout already at H=1620) the q path switches to
 ``_gru_kernel_blocked_q``: the SAME ``(T, G)`` column-streaming grid
-as the fp blocked kernel, but the moving ``[H, C]`` tile is s8 and
+as the fp streamed build, but the moving ``[H, C]`` tile is s8 and
 the dequant (upcast next to the sliced per-output-channel scale
 columns) happens in VMEM — per-step HBM weight traffic is the int8
 bytes, 4× less than the f32 stream.
@@ -84,9 +96,10 @@ from .kernel_id import kernel_call, scan_facts
 _VMEM_WEIGHT_BUDGET = 10 * 1024 * 1024
 # Weight-block width (lane-aligned); G = ceil(3H / this).
 _BLOCK_COLS = 512
-# The most scoped VMEM a copy-once call asks Mosaic for. A v5e core has
-# 128 MiB; 16 MiB is only the default scoped limit (BASELINE.md:111).
-# The rest stays with XLA, which places the neighbouring calls' operands.
+# The scoped VMEM a copy-once call must stay under (a call whose limit
+# would reach it streams instead). A v5e core has 128 MiB; 16 MiB is
+# only the default scoped limit (BASELINE.md:111). The rest stays with
+# XLA, which places the neighbouring calls' operands.
 _PINNED_VMEM_CAP = 48 * 1024 * 1024
 
 
@@ -281,46 +294,42 @@ def _bigru_bwd_kernel(xpf_ref, xpb_ref, mf_ref, mb_ref,
 
 
 # ---------------------------------------------------------------------------
-# Blocked kernels (weights past the residency budget: flagship H=1760).
+# Weights past the residency budget (flagship H=1760): the copy-once
+# steps (the resident bodies over a scratch, one grid step per time
+# step) and the streamed (blocked) bodies.
 # ---------------------------------------------------------------------------
 
-def _weight_blocks(wh_ref, pinned, g, c: int):
-    """Where a column block of the recurrent matrix comes from, for the
-    forward and the backward blocked kernel alike. Returns the getter.
-
-    ``pinned`` = (w_scr, sem) in the copy-once build: ``wh_ref`` is then
-    the whole padded matrix wherever XLA left it (``pl.ANY``), copied
-    into ``w_scr`` by ONE DMA at the call's first grid step, and block
-    ``g`` is a slice of ``w_scr``. Without it ``wh_ref`` is the
-    ``[H, C]`` block the BlockSpec pipeline moved for this grid step.
-    """
-    if not pinned:
-        return lambda: wh_ref[:]
-    w_scr, sem = pinned
-
-    @pl.when((pl.program_id(0) == 0) & (g == 0))
+def _copy_weights_once(wh_ref, w_scr, sem):
+    """The copy-once build's one DMA: ``wh_ref`` is the whole
+    matrix wherever XLA left it (``pl.ANY``), copied into the VMEM
+    scratch ``w_scr`` at the call's first grid step; every later step
+    reads the scratch."""
+    @pl.when(pl.program_id(0) == 0)
     def _():
         copy = pltpu.make_async_copy(wh_ref, w_scr, sem)
         copy.start()
         copy.wait()
 
-    cols = pl.ds(pl.multiple_of(g * c, c), c)
-    return lambda: w_scr[:, cols]
+
+def _gru_kernel_pinned(xp_ref, mask_ref, wh_ref, bh_ref, out_ref,
+                       h_c, w_scr, sem):
+    """Copy-once forward step: the resident step, its matrix read from
+    the scratch."""
+    _copy_weights_once(wh_ref, w_scr, sem)
+    _gru_kernel(xp_ref, mask_ref, w_scr, bh_ref, out_ref, h_c)
 
 
 def _gru_kernel_blocked(xp_ref, mask_ref, wh_ref, bh_ref, out_ref,
-                        h_c, gates_buf, *pinned,
-                        h: int, n_blocks: int, c: int):
+                        h_c, gates_buf, *, h: int, n_blocks: int, c: int):
     t = pl.program_id(0)
     g = pl.program_id(1)
-    w_blk = _weight_blocks(wh_ref, pinned, g, c)
 
     @pl.when((t == 0) & (g == 0))
     def _():
         h_c[:] = jnp.zeros_like(h_c)
 
     hprev = h_c[:]
-    blk = jnp.dot(hprev.astype(wh_ref.dtype), w_blk(),
+    blk = jnp.dot(hprev.astype(wh_ref.dtype), wh_ref[:],
                   preferred_element_type=jnp.float32) + bh_ref[:]
     gates_buf[:, pl.ds(g * c, c)] = blk
 
@@ -364,9 +373,24 @@ def _gru_kernel_blocked_q(xp_ref, mask_ref, wq_ref, sc_ref, bh_ref,
         out_ref[0] = hnew
 
 
+def _gru_bwd_kernel_pinned(xp_ref, mask_ref, ys_prev_ref, dy_ref, wh_ref,
+                           bh_ref, dxp_ref, dgates_ref, dh_c, w_scr, sem):
+    """Copy-once BPTT step: the resident step (gate recompute,
+    element-wise, ``dgates @ W^T`` into the carried dh), its matrix
+    read from the scratch. On the chip this order reads 8.24 ms a call
+    at ds2_full's shape against 8.36 with the contraction one step
+    behind, as the streamed body has it (PERF.md section 6, PR 31).
+    The resident body reads its matrix once per matmul, stores between
+    them: one read feeding both makes Mosaic hold the matrix a second
+    time (42 MiB of scoped VMEM for 24 at b=32)."""
+    _copy_weights_once(wh_ref, w_scr, sem)
+    _gru_bwd_kernel(xp_ref, mask_ref, ys_prev_ref, dy_ref, w_scr, bh_ref,
+                    dxp_ref, dgates_ref, dh_c)
+
+
 def _gru_bwd_kernel_blocked(xp_ref, mask_ref, ys_prev_ref, dy_ref, wh_ref,
                             bh_ref, dxp_ref, dgates_ref,
-                            dh_c, dh_acc, gates_buf, dg_prev, *pinned,
+                            dh_c, dh_acc, gates_buf, dg_prev, *,
                             h: int, n_blocks: int, c: int):
     """Blocked BPTT step: ONE pass over the weight blocks per time step.
 
@@ -375,12 +399,9 @@ def _gru_bwd_kernel_blocked(xp_ref, mask_ref, ys_prev_ref, dy_ref, wh_ref,
     weight blocks as the current step's gate recompute — no second pass.
     ``dh_c`` therefore carries only the elementwise part of dh_prev;
     the full dh assembles at the last block as dh_c + dh_acc + dy.
-
-    ``pinned``: see :func:`_weight_blocks`.
     """
     ti = pl.program_id(0)
     g = pl.program_id(1)
-    w_blk = _weight_blocks(wh_ref, pinned, g, c)
 
     @pl.when((ti == 0) & (g == 0))
     def _():
@@ -393,13 +414,13 @@ def _gru_bwd_kernel_blocked(xp_ref, mask_ref, ys_prev_ref, dy_ref, wh_ref,
 
     hprev = jnp.where(ti == pl.num_programs(0) - 1,
                       jnp.zeros_like(ys_prev_ref[0]), ys_prev_ref[0])
-    blk = jnp.dot(hprev.astype(wh_ref.dtype), w_blk(),
+    blk = jnp.dot(hprev.astype(wh_ref.dtype), wh_ref[:],
                   preferred_element_type=jnp.float32) + bh_ref[:]
     gates_buf[:, pl.ds(g * c, c)] = blk
 
     dgp = dg_prev[:, pl.ds(g * c, c)]
     dh_acc[:] += jax.lax.dot_general(
-        dgp.astype(wh_ref.dtype), w_blk(), (((1,), (1,)), ((), ())),
+        dgp.astype(wh_ref.dtype), wh_ref[:], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(g == n_blocks - 1)
@@ -512,10 +533,11 @@ def _blocked_q_in_specs(b: int, h: int, hn: int, c: int, idx, midx):
 
 def _use_blocked(h: int, dot, n_gates: int = 3,
                  weight_bytes: Optional[int] = None) -> bool:
-    """Regime selector: blocked streaming iff the matrix misses the
-    residency budget at its STORED width. ``weight_bytes`` is the
-    per-element size of the array that actually sits in / streams from
-    HBM — 1 for the int8 q kernels (the s8 tree is the jit input);
+    """Regime selector: past the residency budget (the float kernels'
+    copy-once or streamed build, the q kernels' blocked streaming) iff
+    the matrix misses that budget at its STORED width. ``weight_bytes``
+    is the per-element size of the array that actually sits in / streams
+    from HBM — 1 for the int8 q kernels (the s8 tree is the jit input);
     defaults to the MXU operand size (the fp kernels pre-cast W to the
     dot dtype, so stored width == operand width there)."""
     wb = jnp.dtype(dot).itemsize if weight_bytes is None else weight_bytes
@@ -524,77 +546,108 @@ def _use_blocked(h: int, dot, n_gates: int = 3,
 
 def _pinned_vmem_limit(weight_bytes: int, row_bytes: int,
                        scratch_bytes: int) -> Optional[int]:
-    """The scoped-VMEM limit a copy-once blocked call asks for, or None
-    when it would pass :data:`_PINNED_VMEM_CAP` (the call then streams
-    its blocks). What the call holds: ONE copy of the padded
-    ``[H, cols]`` matrix, its per-step blocks twice (the pipeline
-    double-buffers them) and its float32 scratches; a quarter on top for
-    the gate math's temporaries, rounded up to 4 MiB and never under
-    Mosaic's default of 16 MiB. ds2_full (H=1760, bf16) at b=32 / 64:
-    forward 28 / 32 MiB, backward 32 / 40 MiB."""
+    """The scoped-VMEM limit a copy-once call asks for, or None when it
+    would reach :data:`_PINNED_VMEM_CAP` (the call then streams its
+    weights in column blocks). What the call holds: ONE copy of the
+    ``[H, 3H]`` matrix (its rows as wide as VMEM's lanes make them),
+    its per-step rows twice (the pipeline double-buffers them) and its
+    float32 scratches, among which the caller counts the step's gate
+    value ``[b, 3H]`` (live whole, since one matmul makes it); a
+    quarter on top for the gate math's other temporaries, rounded up to
+    4 MiB and never under Mosaic's default of 16 MiB. ds2_full (H=1760,
+    bf16, 18.6 MB of weights) at b=32 / 64: forward 28 / 28 MiB,
+    backward 32 / 36 MiB."""
     step = 4 * 1024 * 1024
     need = weight_bytes + 2 * row_bytes + scratch_bytes
     limit = max(16 * 1024 * 1024, pl.cdiv(need * 5 // 4, step) * step)
-    return limit if limit <= _PINNED_VMEM_CAP else None
+    return limit if limit < _PINNED_VMEM_CAP else None
 
 
-def _blocked_scan_call(body, kernel: str, reverse: bool, c: int, rows,
-                       w, bias, out_map, out_widths, scratch_widths,
-                       interpret: bool):
-    """The ``(T, G)`` blocked scan call of either direction.
+# The two builds of each float scan kernel whose matrix is past the
+# residency budget: the body and the widths of its float32 ``[b, n]``
+# scratches (``cols``: the matrix's columns as the build pads them).
+_PAST_BUDGET_BUILDS = {
+    "gru_scan_fwd": {
+        "pinned": (_gru_kernel_pinned, lambda h, cols: [h]),
+        "blocked": (_gru_kernel_blocked, lambda h, cols: [h, cols])},
+    "gru_scan_bwd": {
+        "pinned": (_gru_bwd_kernel_pinned, lambda h, cols: [h]),
+        "blocked": (_gru_bwd_kernel_blocked,
+                    lambda h, cols: [h, h, cols, cols])},
+}
 
-    ``rows``: the per-step operands as ``(array [T, b, X], index map)``
-    pairs in the kernel's order; ``w [H, cols]`` (dot type) and
-    ``bias [1, cols]``, both padded to whole ``c``-wide blocks, follow
-    them. The outputs are float32 ``[T, b, width]`` rows through
-    ``out_map`` (one array for one width, else a list), the scratches
-    float32 ``[b, width]``.
 
-    Who puts the matrix into VMEM is decided here, from the shapes: the
-    kernel (one copy into a scratch, the call's own scoped limit:
-    ``blocked_pinned``) when :func:`_pinned_vmem_limit` fits the cap,
-    else the BlockSpec pipeline, block by block (``blocked``).
+def _past_budget_scan_call(kernel: str, reverse: bool, rows, w, bias,
+                           out_map, out_widths, interpret: bool):
+    """The scan call of either direction for a matrix past the
+    residency budget, in one of two builds chosen here from the shapes.
+
+    ``rows``: the per-step operands as ``(array [T, b, X], time index
+    map)`` pairs in the kernel's order; ``w [H, 3H]`` (dot type) and
+    ``bias [1, 3H]`` follow them. The outputs are float32
+    ``[T, b, width]`` rows through ``out_map`` (one array for one
+    width, else a list).
+
+    Copy-once (``pinned``) when :func:`_pinned_vmem_limit` stays under
+    the cap: grid ``(T,)``, the matrix taken as it is in ``pl.ANY`` and
+    copied by the kernel into a scratch at the first step, each step
+    the resident kernels' step over all of it, under the call's own
+    scoped limit. Else the BlockSpec pipeline streams it (``blocked``):
+    grid ``(T, G)`` over ``_BLOCK_COLS``-wide column blocks of the
+    matrix padded to whole blocks, because a pipelined operand is
+    double-buffered and two 1.8 MB blocks fit where two whole matrices
+    do not.
     """
     t_max, b = rows[0][0].shape[:2]
-    h, cols = w.shape
+    h, h3 = w.shape
+    lanes = pl.cdiv(h3, 128) * 128  # what VMEM holds of a 3H-wide row
     row_bytes = (sum(b * max(x.shape[2], 128) * x.dtype.itemsize
                      for x, _ in rows)
-                 + 8 * c * 4 + sum(b * n * 4 for n in out_widths))
-    limit = _pinned_vmem_limit(w.size * w.dtype.itemsize, row_bytes,
-                               sum(b * n * 4 for n in scratch_widths))
+                 + 8 * lanes * 4 + sum(b * n * 4 for n in out_widths))
+    body, scratch_widths = _PAST_BUDGET_BUILDS[kernel]["pinned"]
+    limit = _pinned_vmem_limit(
+        h * lanes * w.dtype.itemsize, row_bytes,
+        sum(b * n * 4 for n in scratch_widths(h, lanes) + [lanes]))
     if limit is None:
         variant = "blocked"
+        body, scratch_widths = _PAST_BUDGET_BUILDS[kernel][variant]
+        n_blocks, c = _block_layout(h3)
+        cols, grid = n_blocks * c, (t_max, n_blocks)
+        body = functools.partial(body, h=h, n_blocks=n_blocks, c=c)
+        on_grid = lambda imap: lambda t, g: imap(t)
         w_spec = pl.BlockSpec((h, c), lambda t, g: (0, g),
                               memory_space=pltpu.VMEM)
+        bias_spec = pl.BlockSpec((1, c), lambda t, g: (0, g),
+                                 memory_space=pltpu.VMEM)
         pin_scratch, pin = [], {}
     else:
-        variant = "blocked_pinned"
+        variant, cols, grid = "pinned", h3, (t_max,)
+        on_grid = lambda imap: imap
         w_spec = pl.BlockSpec(memory_space=pl.ANY)
-        pin_scratch = [pltpu.VMEM((h, cols), w.dtype),
+        bias_spec = pl.BlockSpec((1, h3), lambda t: (0, 0),
+                                 memory_space=pltpu.VMEM)
+        pin_scratch = [pltpu.VMEM((h, h3), w.dtype),
                        pltpu.SemaphoreType.DMA(())]
         pin = {"compiler_params":
                pltpu.CompilerParams(vmem_limit_bytes=limit)}
-    outs = [(pl.BlockSpec((1, b, n), out_map, memory_space=pltpu.VMEM),
+    outs = [(pl.BlockSpec((1, b, n), on_grid(out_map),
+                          memory_space=pltpu.VMEM),
              jax.ShapeDtypeStruct((t_max, b, n), jnp.float32))
             for n in out_widths]
     out_specs, out_shape = outs[0] if len(outs) == 1 else zip(*outs)
     return kernel_call(
-        functools.partial(body, h=h, n_blocks=cols // c, c=c),
-        kernel=kernel,
+        body, kernel=kernel,
         facts=scan_facts(variant, reverse, t_max, b, h, 3),
-        grid=(t_max, cols // c),
-        in_specs=[pl.BlockSpec((1, b, x.shape[2]), imap,
+        grid=grid,
+        in_specs=[pl.BlockSpec((1, b, x.shape[2]), on_grid(imap),
                                memory_space=pltpu.VMEM)
-                  for x, imap in rows] + [
-            w_spec,
-            pl.BlockSpec((1, c), lambda t, g: (0, g),
-                         memory_space=pltpu.VMEM)],
+                  for x, imap in rows] + [w_spec, bias_spec],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((b, n), jnp.float32)
-                        for n in scratch_widths] + pin_scratch,
+                        for n in scratch_widths(h, cols)] + pin_scratch,
         interpret=interpret,
         **pin,
-    )(*[x for x, _ in rows], w, bias)
+    )(*[x for x, _ in rows], _pad_cols(w, cols), _pad_cols(bias, cols))
 
 
 def _gru_pallas_raw(xproj, mask, w_h, b_h, reverse: bool, interpret: bool,
@@ -606,8 +659,8 @@ def _gru_pallas_raw(xproj, mask, w_h, b_h, reverse: bool, interpret: bool,
     bh2 = b_h.astype(jnp.float32).reshape(1, h3)
     w = w_h.astype(dot)
 
+    idx, midx = _time_index_maps(t_max, reverse, blocked=False)
     if not _use_blocked(h, dot):
-        idx, midx = _time_index_maps(t_max, reverse, blocked=False)
         ys = kernel_call(
             _gru_kernel, kernel="gru_scan_fwd",
             facts=scan_facts("resident", reverse, t_max, b, h, 3),
@@ -620,14 +673,9 @@ def _gru_pallas_raw(xproj, mask, w_h, b_h, reverse: bool, interpret: bool,
         )(xp_t, mask_t, w, bh2)
         return ys, xp_t, mask_t, bh2
 
-    n_blocks, c = _block_layout(h3)
-    cols = n_blocks * c
-    idx, midx = _time_index_maps(t_max, reverse, blocked=True)
-    ys = _blocked_scan_call(
-        _gru_kernel_blocked, "gru_scan_fwd", reverse, c,
-        [(xp_t, idx), (mask_t, midx)],
-        _pad_cols(w, cols), _pad_cols(bh2, cols),
-        idx, [h], [h, cols], interpret)
+    ys = _past_budget_scan_call(
+        "gru_scan_fwd", reverse, [(xp_t, idx), (mask_t, midx)], w, bh2,
+        idx, [h], interpret)
     return ys, xp_t, mask_t, bh2
 
 
@@ -691,8 +739,8 @@ def gru_scan_pallas_stream(xproj: jnp.ndarray, mask: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # Weight-only int8 inference kernel (VERDICT r3 #7): the quantized
 # [H, 3H] matrix lives int8 in VMEM, so the flagship H=1760 (9.3 MB)
-# becomes RESIDENT — the bf16 forward takes the blocked grid at that
-# size, its 18.6 MB copied into VMEM once by the kernel. Dequantization
+# becomes RESIDENT — the bf16 forward is the copy-once build at that
+# size, its 18.6 MB copied into a VMEM scratch by the kernel. Dequantization
 # never materializes a full-precision matrix: column-scale associativity,
 # (h @ Q) * scale == h @ (Q * scale), moves the per-output-channel
 # scale onto the [B, 3H] gates — O(B*3H) VPU work per step instead of
@@ -977,24 +1025,18 @@ def _gru_bwd(reverse, interpret, dot_dtype, residuals, dy):
     dy_t = jnp.moveaxis(dy.astype(jnp.float32), 1, 0)  # [T, B, H]
     bh2 = b_h.astype(jnp.float32).reshape(1, h3)
     w = w_h.astype(dot)
-    blocked = _use_blocked(h, dot)
-    idx, midx = _time_index_maps(t_max, reverse, blocked=blocked)
+    idx, midx = _time_index_maps(t_max, reverse, blocked=False)
 
     # BPTT runs opposite to the forward scan: grid step i processes
     # forward-scan step T-1-i, whose data row is idx(T-1-i).
-    if blocked:
-        bidx = lambda i, g: idx(t_max - 1 - i, g)
-        bmidx = lambda i, g: midx(t_max - 1 - i, g)
-        pidx = lambda i, g: idx(jnp.maximum(t_max - 2 - i, 0), g)
-    else:
-        bidx = lambda i: idx(t_max - 1 - i)
-        bmidx = lambda i: midx(t_max - 1 - i)
-        # h_{t-1} of forward-scan step T-1-i lives at the row of scan
-        # step T-2-i; the out-of-range value at i == T-1 (h0 = 0) is
-        # masked in the kernel, so clamp the index to a valid row.
-        pidx = lambda i: idx(jnp.maximum(t_max - 2 - i, 0))
+    bidx = lambda i: idx(t_max - 1 - i)
+    bmidx = lambda i: midx(t_max - 1 - i)
+    # h_{t-1} of forward-scan step T-1-i lives at the row of scan
+    # step T-2-i; the out-of-range value at i == T-1 (h0 = 0) is
+    # masked in the kernel, so clamp the index to a valid row.
+    pidx = lambda i: idx(jnp.maximum(t_max - 2 - i, 0))
 
-    if not blocked:
+    if not _use_blocked(h, dot):
         dxp_t, dgates_t = kernel_call(
             _gru_bwd_kernel, kernel="gru_scan_bwd",
             facts=scan_facts("resident", reverse, t_max, b, h, 3),
@@ -1017,13 +1059,10 @@ def _gru_bwd(reverse, interpret, dot_dtype, residuals, dy):
             interpret=interpret,
         )(xp_t, mask_t, ys, dy_t, w, bh2)
     else:
-        n_blocks, c = _block_layout(h3)
-        cols = n_blocks * c
-        dxp_t, dgates_t = _blocked_scan_call(
-            _gru_bwd_kernel_blocked, "gru_scan_bwd", reverse, c,
+        dxp_t, dgates_t = _past_budget_scan_call(
+            "gru_scan_bwd", reverse,
             [(xp_t, bidx), (mask_t, bmidx), (ys, pidx), (dy_t, bidx)],
-            _pad_cols(w, cols), _pad_cols(bh2, cols),
-            bidx, [h3, h3], [h, h, cols, cols], interpret)
+            w, bh2, bidx, [h3, h3], interpret)
 
     # h_prev sequence in scan order: ys shifted by one scan step.
     if reverse:

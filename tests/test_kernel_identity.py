@@ -41,21 +41,26 @@ def scan(kernel, variant, reverse, t, b, h, gates=3):
 # tools/aot_kernels.kernel_cases() at b=8, t=400: the five that
 # test_tpu_compile.py compiles, then the other routed shapes.
 CASES = {
-    "gru_h1760": [scan("gru_scan_fwd", "blocked_pinned", 0, 400, 8, 1760),
-                  scan("gru_scan_bwd", "blocked_pinned", 0, 400, 8, 1760)],
+    "gru_h1760": [scan("gru_scan_fwd", "pinned", 0, 400, 8, 1760),
+                  scan("gru_scan_bwd", "pinned", 0, 400, 8, 1760)],
     # ds2_full.train_1chip's own call, and twice its rows
     "gru_h1760_b32": [
-        scan("gru_scan_fwd", "blocked_pinned", 0, 850, 32, 1760),
-        scan("gru_scan_bwd", "blocked_pinned", 0, 850, 32, 1760)],
+        scan("gru_scan_fwd", "pinned", 0, 850, 32, 1760),
+        scan("gru_scan_bwd", "pinned", 0, 850, 32, 1760)],
     "gru_h1760_b64": [
-        scan("gru_scan_fwd", "blocked_pinned", 0, 850, 64, 1760),
-        scan("gru_scan_bwd", "blocked_pinned", 0, 850, 64, 1760)],
+        scan("gru_scan_fwd", "pinned", 0, 850, 64, 1760),
+        scan("gru_scan_bwd", "pinned", 0, 850, 64, 1760)],
     # offline decode: the forward call alone, no VJP
     "gru_h1760_decode": [
-        scan("gru_scan_fwd", "blocked_pinned", 0, 600, 32, 1760)],
-    # float32 dots: the matrix passes the cap, both calls stream
+        scan("gru_scan_fwd", "pinned", 0, 600, 32, 1760)],
+    "gru_h1760_decode_b128": [
+        scan("gru_scan_fwd", "pinned", 0, 850, 128, 1760)],
+    # a float32 model: the need reaches the cap, both calls stream
     "gru_h1760_f32": [scan("gru_scan_fwd", "blocked", 0, 400, 8, 1760),
                       scan("gru_scan_bwd", "blocked", 0, 400, 8, 1760)],
+    "gru_h1760_f32_b32": [
+        scan("gru_scan_fwd", "blocked", 0, 850, 32, 1760),
+        scan("gru_scan_bwd", "blocked", 0, 850, 32, 1760)],
     "gru_stream_h800": [scan("gru_scan_stream", "resident", 0, 32, 2, 800)],
     "bigru_h800": [scan("bigru_scan_fwd", "resident", "both", 400, 8, 800)],
     "ctc_en": [{"kernel": "ctc_alpha", "t": "400", "b": "8", "s": "384"},
@@ -140,30 +145,38 @@ def _pallas_calls(jaxpr):
 
 
 @pytest.mark.parametrize("case, kernel, variant, limit_mib", [
-    ("gru_h1760", "gru_scan_fwd", "blocked_pinned", 28),
-    ("gru_h1760", "gru_scan_bwd", "blocked_pinned", 28),
-    ("gru_h1760_b32", "gru_scan_fwd", "blocked_pinned", 28),
-    ("gru_h1760_b32", "gru_scan_bwd", "blocked_pinned", 32),
-    ("gru_h1760_b64", "gru_scan_fwd", "blocked_pinned", 32),
-    ("gru_h1760_b64", "gru_scan_bwd", "blocked_pinned", 40),
-    ("gru_h1760_decode", "gru_scan_fwd", "blocked_pinned", 28),
-    # f32 dots: 39.6 MB of weights pass the cap, the pipeline streams
+    ("gru_h1760", "gru_scan_fwd", "pinned", 24),
+    ("gru_h1760", "gru_scan_bwd", "pinned", 28),
+    ("gru_h1760_b32", "gru_scan_fwd", "pinned", 28),
+    ("gru_h1760_b32", "gru_scan_bwd", "pinned", 32),
+    ("gru_h1760_b64", "gru_scan_fwd", "pinned", 28),
+    ("gru_h1760_b64", "gru_scan_bwd", "pinned", 36),
+    ("gru_h1760_decode", "gru_scan_fwd", "pinned", 28),
+    ("gru_h1760_decode_b128", "gru_scan_fwd", "pinned", 36),
+    # a float32 model, 37.8 MB of weights: at the cell's rows the need
+    # passes the cap, at 8 rows it comes to the cap itself, which is
+    # not under it: the pipeline streams
     ("gru_h1760_f32", "gru_scan_fwd", "blocked", None),
     ("gru_h1760_f32", "gru_scan_bwd", "blocked", None),
+    ("gru_h1760_f32_b32", "gru_scan_fwd", "blocked", None),
+    ("gru_h1760_f32_b32", "gru_scan_bwd", "blocked", None),
 ])
-def test_who_places_the_blocked_scan_weights(case, kernel, variant,
+def test_who_places_the_past_budget_scan_weights(case, kernel, variant,
                                              limit_mib):
     """The copy-once build, forward or backward, says so in its facts,
     takes its weights where XLA left them (``pl.ANY``: no BlockSpec
-    pipeline on the operand) and asks Mosaic for the scoped VMEM its
-    shapes need; past the module's cap the call is the streamed one,
-    under Mosaic's default limit."""
+    pipeline on the operand) and as they are, runs one grid step a
+    time step and asks Mosaic for the scoped VMEM its shapes need; at
+    the module's cap or past it the call is the streamed one,
+    512-column blocks over a second grid axis under Mosaic's default
+    limit."""
     from aot_kernels import kernel_cases
 
     fn, args = kernel_cases()[case]()
     call, = [p for p in _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
              if p["name"] == kernel]
     assert call["metadata"]["variant"] == variant
+    t = int(call["metadata"]["t"])
     # the weights follow the per-step rows: two forward, four backward
     w = call["grid_mapping"].block_mappings[
         {"gru_scan_fwd": 2, "gru_scan_bwd": 4}[kernel]]
@@ -171,9 +184,11 @@ def test_who_places_the_blocked_scan_weights(case, kernel, variant,
     if limit_mib is None:
         assert "vmem" in str(w.block_aval) and limit is None
         assert [d.block_size for d in w.block_shape] == [1760, 512]
+        assert call["grid_mapping"].grid == (t, 11)
     else:
         assert "any" in str(w.block_aval)
-        assert [d.block_size for d in w.block_shape] == [1760, 5632]
+        assert [d.block_size for d in w.block_shape] == [1760, 5280]
+        assert call["grid_mapping"].grid == (t,)
         assert limit.vmem_limit_bytes == limit_mib * 2 ** 20
 
 
@@ -181,9 +196,11 @@ def test_every_scan_of_the_ds2_full_step_is_pinned(monkeypatch):
     """ds2_full.train_1chip's model (7 BiGRU-1760, bf16, b=32 in the
     1700-frame bucket), forward and gradient, lowered for the TPU as
     the chip resolves it: 14 forward and 14 backward scans, seven per
-    direction, every one placing its own weights. None is left to the
-    lottery that made six backward calls stream 19.8 MB a time step,
-    nor to the pipeline's block copies out of a matrix XLA had placed."""
+    direction, every one placing its own weights and running one grid
+    step a time step. None is left to the lottery that made six
+    backward calls stream 19.8 MB a time step, to the pipeline's block
+    copies out of a matrix XLA had placed, nor to 11 column blocks a
+    step of a matrix that sits whole in the kernel's scratch."""
     from collections import Counter
 
     from deepspeech_tpu.config import get_config
@@ -204,7 +221,7 @@ def test_every_scan_of_the_ds2_full_step_is_pinned(monkeypatch):
     got = Counter((f["kernel"], f["variant"], f["reverse"], f["t"], f["b"])
                   for f in lowered_facts(grads, (variables, x, lens)))
     assert got == {
-        (kernel, "blocked_pinned", r, "850", "32"): 7
+        (kernel, "pinned", r, "850", "32"): 7
         for kernel in ("gru_scan_fwd", "gru_scan_bwd") for r in "01"}
 
 

@@ -84,23 +84,43 @@ def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     ("gru_h1760", ["gru_scan_bwd", "gru_scan_fwd"]),
     ("gru_h1760_b32", ["gru_scan_bwd", "gru_scan_fwd"]),
     ("gru_h1760_b64", ["gru_scan_bwd", "gru_scan_fwd"]),
-    # offline decode: the forward call alone, no VJP
+    # offline decode: the forward call alone, no VJP; its widest batch
     ("gru_h1760_decode", ["gru_scan_fwd"]),
+    ("gru_h1760_decode_b128", ["gru_scan_fwd"]),
 ])
 def test_pinned_scan_fits_the_vmem_it_asks_for(v5e_chip, case, kernels):
-    """ds2_full's scans copy their 19.8 MB of bf16 weights into VMEM
-    once and raise their own scoped limit from their shapes (forward
-    28 MiB at the cell's b=32 and 32 MiB at b=64, backward 32 and
-    40 MiB, where the streamed backward build passes the default
-    16 MiB): Mosaic accepts every one, and the compiled call says
-    which build it is."""
+    """ds2_full's scans copy their 18.6 MB of bf16 weights into VMEM
+    once, consume them whole at every time step (one matmul forward,
+    two backward) and raise their own scoped limit from their shapes
+    (forward 28 MiB at the cell's b=32 and at b=64, 36 at b=128;
+    backward 32 and 36 MiB): Mosaic accepts every one, and the compiled
+    call says which build it is. The backward step reads its scratch
+    once per matmul; read once for both, Mosaic holds the matrix a
+    second time (42 MiB at b=32) and this test fails."""
     from aot_kernels import compile_case, kernel_cases
     from benchmark.layer_metrics._kernel_id import kernel_facts
 
     text = compile_case(kernel_cases()[case], v5e_chip).as_text()
     calls = text.split('custom_call_target="tpu_custom_call"')[1:]
     assert sorted((f["kernel"], f["variant"]) for f in map(
-        kernel_facts, calls)) == [(k, "blocked_pinned") for k in kernels]
+        kernel_facts, calls)) == [(k, "pinned") for k in kernels]
+
+
+@pytest.mark.parametrize("case", ["gru_h1760_f32", "gru_h1760_f32_b32"])
+def test_float32_scans_at_1760_compile_streamed(v5e_chip, case):
+    """A float32 model at ds2_full's width (37.8 MB of weights, run by
+    no preset) is past what a call may copy into VMEM, at the cell's 32
+    rows and at evaluation's 8, where the need comes to the cap itself:
+    Mosaic compiles the streamed build, the one user of the column
+    grid among the GRU's float kernels, under its default limit."""
+    from aot_kernels import compile_case, kernel_cases
+    from benchmark.layer_metrics._kernel_id import kernel_facts
+
+    text = compile_case(kernel_cases()[case], v5e_chip).as_text()
+    calls = text.split('custom_call_target="tpu_custom_call"')[1:]
+    assert sorted((f["kernel"], f["variant"]) for f in map(
+        kernel_facts, calls)) == [("gru_scan_bwd", "blocked"),
+                                  ("gru_scan_fwd", "blocked")]
 
 
 def test_ctc_vjp_folds_gamma_without_a_scatter(v5e_chip):

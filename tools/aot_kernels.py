@@ -236,16 +236,22 @@ def kernel_cases():
     cases["gru_h1760"] = gru_case(1760)
     # ds2_full.train_1chip's own call (850 post-conv frames, bf16
     # xproj) and twice its rows: both calls copy their weights into
-    # VMEM once and ask for their own scoped VMEM, forward 28 / 32 MiB,
-    # backward 32 / 40 MiB.
+    # VMEM once, run one grid step a time step and ask for their own
+    # scoped VMEM, forward 28 / 28 MiB, backward 32 / 36 MiB.
     cases["gru_h1760_b32"] = gru_case(1760, 32, 850, jnp.bfloat16)
     cases["gru_h1760_b64"] = gru_case(1760, 64, 850, jnp.bfloat16)
-    # offline decode of the 1200-frame bucket: the forward call alone
+    # offline decode: the forward call alone, in the 1200-frame bucket
+    # and at its widest batch in the 1700-frame one (36 MiB)
     cases["gru_h1760_decode"] = gru_case(1760, 32, 600, jnp.bfloat16,
                                          vjp=False)
-    # float32 dots: 39.6 MB of weights, past what a call may pin, so
-    # both stream their column blocks through the BlockSpec pipeline.
+    cases["gru_h1760_decode_b128"] = gru_case(1760, 128, 850,
+                                              jnp.bfloat16, vjp=False)
+    # a float32 model at that width (37.8 MB of weights): the calls'
+    # needs pass what a call may pin, or at evaluation's 8 rows come to
+    # the cap itself, so both stream 512-column blocks through the
+    # BlockSpec pipeline.
     cases["gru_h1760_f32"] = gru_case(1760, dot="float32")
+    cases["gru_h1760_f32_b32"] = gru_case(1760, 32, 850, dot="float32")
     cases["gru_stream_h800"] = gru_stream_case(800)
     cases["lstm_h800"] = lstm_case(800)
     cases["lstm_h1536"] = lstm_case(1536)

@@ -78,6 +78,17 @@ SCAN_CALL = (32, 850)
 # where the kernels' dW_h runs at HIGHEST). A build that lost a column
 # block or a time step reads tenths to ones.
 SCAN_BUILDS_RTOL = 2e-3
+# ax_k1's latent attention at its published widths, one layer: (rows,
+# positions) on which its two forms are compared, the decode form
+# (key/value expansion absorbed into query and output, scores against
+# the cached rows) against the sequence form (expanded keys and values)
+# at the last quarter of the positions, as root-mean-square difference
+# over the sequence form's rms. In bfloat16 the two round different
+# intermediates (the chip read 0.46% inside the benchmark's check,
+# PR 32); a form that lost the rotary part, the norm or a mask reads
+# tenths to ones.
+MLA_CALL = (8, 288)
+MLA_FORMS_RTOL = 2e-2
 SCAN_ORACLE_RTOL = {"dxproj": 1e-2, "dw_h": 0.2, "db_h": 2e-3}
 # Streamed finals against the offline decode of the same audio: the
 # two graphs reduce in different orders in bf16, so an argmax near a
@@ -369,6 +380,7 @@ def phase_reference() -> dict:
             fail(f"GRU H={h} kernel differs from the XLA scan: {err}")
         out[f"gru_h{h}_rel_err"] = err
     out.update(scan_builds(interpret))
+    out.update(attention_forms())
     t, v, lmax = 100, 29, 20
     logits = jnp.asarray(rng.normal(size=(b, t, v)), jnp.float32)
     label_lens = jnp.asarray(rng.integers(lmax // 2, lmax + 1, size=b),
@@ -385,6 +397,38 @@ def phase_reference() -> dict:
         fail(f"CTC kernel differs from the jnp loss: {err}")
     out["ctc_rel_err"] = err
     return out
+
+
+def attention_forms() -> dict:
+    """One layer of ax_k1's latent attention (``models/axk1.py``) at
+    the preset's widths, seeded weights, bfloat16: every (row, position)
+    of the last quarter of ``MLA_CALL`` through the decode form against
+    the cache the sequence form wrote, compared with the sequence
+    form's output there."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.models.axk1 import LatentAttention, both_forms
+
+    m = get_config("ax_k1").model
+    (b, s), dtype = MLA_CALL, jnp.dtype(m.dtype)
+    at = np.arange(s - s // 4, s)
+    x = jax.random.normal(jax.random.PRNGKey(0), (b, s, m.lfm_hidden),
+                          dtype)
+    params = jax.jit(lambda r: jax.tree.map(
+        lambda w: w.astype(dtype), LatentAttention(m).init(
+            r, x[:1, :2], jnp.arange(2)[None, :])["params"]))(
+                jax.random.PRNGKey(1))
+    both = jax.jit(lambda p, x: both_forms(m, p, x, at))
+    dec, seq = (np.asarray(a, np.float32) for a in both(params, x))
+    err = float(np.sqrt(np.mean((dec - seq) ** 2) / np.mean(seq ** 2)))
+    if not err <= MLA_FORMS_RTOL:
+        fail(f"latent attention: the decode form differs from the "
+             f"sequence form by {err}")
+    return {"mla_forms_rms_rel": err, "mla_positions_compared": int(
+        b * len(at))}
 
 
 def scan_builds(interpret: bool) -> dict:

@@ -146,6 +146,40 @@ class ModelConfig:
     # holds a bucket: ceil(frames / frame_stack) + 1 + max_label_len,
     # rounded up to a multiple of 8.
     lfm_seq_positions: int = 0
+    # The decoder-only shell serves a second family (models/axk1.py:
+    # ``lfm_layer_types`` of "latent_attention"); what differs between
+    # the two families' presets is stated here, not switched in code.
+    # Output head: the embedding matrix transposed, or a matrix of its
+    # own (``tie_word_embeddings`` false).
+    lm_tied_head: bool = True
+    # Selection rule of the router (``ops/moe.route``): the experts are
+    # ``moe_groups`` runs of consecutive ids, a group scores its
+    # maximum, only the ``moe_groups_kept`` best groups can be chosen
+    # from (1 of 1: plain top-k); a seeded selection bias or none; the
+    # normalised weights times ``moe_routed_scale``.
+    moe_groups: int = 1
+    moe_groups_kept: int = 1
+    moe_select_bias: bool = True
+    moe_routed_scale: float = 1.0
+    # Experts every position passes through beside the routed ones
+    # (``n_shared_experts``): one SwiGLU of that many expert widths.
+    moe_shared_experts: int = 0
+    # Latent attention (``model_type: axk1``): ranks of the query's and
+    # the key/value's low-rank paths, and a head's three sizes (the
+    # part of q.k without positions, the rotary part shared by all
+    # heads' keys, the value).
+    mla_q_rank: int = 1536
+    mla_kv_rank: int = 512
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
+    # YaRN scaling of the rotary frequencies (``rope_scaling``): factor
+    # over the original context (factor 1: plain rotary), the ramp's
+    # (beta_fast, beta_slow), and (mscale, mscale_all_dim).
+    rope_yarn_factor: float = 1.0
+    rope_yarn_original: int = 4096
+    rope_yarn_betas: Tuple[float, float] = (32.0, 1.0)
+    rope_yarn_mscales: Tuple[float, float] = (1.0, 1.0)
 
     @property
     def time_stride(self) -> int:
@@ -309,6 +343,9 @@ class DecodeConfig:
     # "rnnt_greedy"/"rnnt_beam": transducer checkpoints
     #   (train.objective="rnnt"; models/transducer.py) — greedy or
     #   prefix-merged beam (beam_width/nbest apply; no LM path).
+    # "lm_greedy": decoder-only checkpoints (train.objective="lm";
+    #   decode/lm_greedy.py) — prefill the audio prefix into a cache,
+    #   then one on-device loop of argmax steps through the cache.
     mode: str = "greedy"
     # Feature frames per streaming chunk (decode.mode=streaming).
     chunk_frames: int = 64
@@ -348,6 +385,12 @@ class DecodeConfig:
     # CTC argmax alignment (the DS2-era timing proxy) — each utt event
     # gains "times": [[char, start_ms, end_ms], ...].
     timestamps: bool = False
+    # lm_greedy: utterances prefilled at once (the prefill program's
+    # batch; a call's rows are a multiple of it or fewer), and whether
+    # a stream decodes on past the end id up to its ``max_tokens`` (a
+    # serving benchmark on seeded weights, whose end id means nothing).
+    lm_prefill_rows: int = 32
+    lm_ignore_end: bool = False
 
 
 @dataclass(frozen=True)
@@ -500,6 +543,46 @@ def lfm2_24b_a2b() -> Config:
     )
 
 
+def ax_k1() -> Config:
+    """One chip's share of A.X-K1 (``model_type: axk1``,
+    https://huggingface.co/skt/A.X-K1/blob/main/config.json) as a
+    decoder-only speech recogniser that is SERVED (``decode.mode=
+    "lm_greedy"``), every width as published: hidden 7168, 64 heads of
+    latent attention (query rank 1536, key/value rank 512, head sizes
+    128 | 64 | 128, YaRN factor 32 over 4096), dense SwiGLU 18432 in
+    the leading layer, then 192 sigmoid-scored routed experts of 2048,
+    top-8 out of the 4 best of 8 groups, weights normalised times 2.5,
+    one shared expert, an untied head. The stated deployment divides
+    each layer over 16 chips: 12 of the 192 experts and 20,480 of the
+    163,840 vocabulary rows live here, the rest is replicated. Depth is
+    cut to the leading dense layer and 7 expert layers.
+    ``benchmark/configs/ax_k1.json`` has the published keys beside
+    these and every reading that is this repo's own."""
+    c = Config(name="ax_k1")
+    return _replace(
+        c,
+        model=_replace(
+            c.model, conv_layers=(), conv_channels=(), rnn_layers=0,
+            bidirectional=False, rnn_batch_norm=False, frame_stack=8,
+            vocab_size=20480, lfm_hidden=7168,
+            lfm_layer_types=("latent_attention",) * 8,
+            lfm_dense_layers=1, lfm_heads=64, lfm_kv_heads=64,
+            lfm_ffn_dim=18432, lfm_expert_dim=2048, lfm_experts=192,
+            lfm_top_k=8, lfm_rope_theta=1e4, lfm_norm_eps=1e-6,
+            experts_held=12, expert_offset=0, moe_rows_bound=0.25,
+            lfm_seq_positions=288, lm_tied_head=False, moe_groups=8,
+            moe_groups_kept=4, moe_select_bias=False,
+            moe_routed_scale=2.5, moe_shared_experts=1,
+            rope_yarn_factor=32.0),
+        data=_replace(c.data, batch_size=256, bucket_frames=(1696,),
+                      max_label_len=64),
+        train=_replace(c.train, objective="lm", optimizer="adamw",
+                       learning_rate=1e-4, weight_decay=0.0,
+                       grad_clip_norm=1.0, warmup_steps=100),
+        decode=_replace(c.decode, mode="lm_greedy"),
+    )
+
+
 PRESETS = {
     "ds2_small": ds2_small,
     "ds2_full": ds2_full,
@@ -509,6 +592,7 @@ PRESETS = {
     "dev_slice": dev_slice,
     "rnnt_he2019": rnnt_he2019,
     "lfm2_24b_a2b": lfm2_24b_a2b,
+    "ax_k1": ax_k1,
 }
 
 

@@ -118,10 +118,26 @@ class Inferencer:
             from .models.transducer import create_rnnt_model
 
             self.model = create_rnnt_model(cfg.model, mesh=mesh)
+        elif cfg.decode.mode == "lm_greedy":
+            # Decoder-only checkpoints (train.objective="lm") transcribe
+            # through a cache (decode/lm_greedy.py, built below once
+            # the parameters are here); no LM path, no quantized one.
+            if cfg.decode.lm_path or quantize:
+                raise ValueError(
+                    "decode.mode=lm_greedy has no LM fusion and no "
+                    "int8 path; unset decode.lm_path / quantize")
+            self.model = None
         else:
             self.model = create_model(cfg.model, mesh=mesh)
         if params is None:
             params, batch_stats = restore_params(cfg.train.checkpoint_dir)
+        self.lm_greedy = None
+        if cfg.decode.mode == "lm_greedy":
+            from .decode.lm_greedy import LMGreedy
+
+            self.lm_greedy = LMGreedy(cfg, params, batch_stats)
+            self.model = self.lm_greedy.model
+            params = self.lm_greedy.params  # held once, in bfloat16
         self.params = params
         self.batch_stats = batch_stats or {}
         # Weight-only int8 PTQ (utils/quantize.py): kernels live int8 in
@@ -286,6 +302,8 @@ class Inferencer:
             return self._decode_sp_beam(batch)
         if self.cfg.decode.mode in ("rnnt_greedy", "rnnt_beam"):
             return self._decode_rnnt(batch)
+        if self.cfg.decode.mode == "lm_greedy":
+            return self._decode_lm(batch)
         b, t = batch["features"].shape[:2]
         hit = self.shape_cache.note(
             b, t, int(np.minimum(np.asarray(batch["feat_lens"]), t).sum()))
@@ -523,6 +541,16 @@ class Inferencer:
                 hyp_ids = res
         return [self.tokenizer.decode(ids) for ids in hyp_ids]
 
+    def _decode_lm(self, batch: Dict[str, np.ndarray]) -> List[str]:
+        """Greedy transcripts of a decoder-only checkpoint: prefill,
+        then the on-device loop (``decode/lm_greedy.py``). The batch
+        may carry ``max_tokens [B]``, each stream's own limit."""
+        out = self.lm_greedy.transcribe(
+            batch["features"], batch["feat_lens"],
+            max_tokens=batch.get("max_tokens"))
+        return [self.tokenizer.decode(row[:n])
+                for row, n in zip(out["ids"], out["tokens"])]
+
     def _sp_setup(self, batch: Dict[str, np.ndarray]):
         """Shared sp_* decode prep: all-device mesh (the data axis is
         re-purposed as time) + features zero-padded to the shard
@@ -725,7 +753,7 @@ class Inferencer:
         # host-side (the WER loop reads them with numpy), and the other
         # modes (streaming/sp/rnnt) pull features back to numpy anyway.
         if self.cfg.decode.mode in ("greedy", "beam", "beam_fused",
-                                    "beam_fused_device"):
+                                    "beam_fused_device", "lm_greedy"):
             from .data.pipeline import device_prefetch
 
             def _put(item):

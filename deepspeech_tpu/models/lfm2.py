@@ -1,6 +1,14 @@
-"""The LFM2 block as a decoder-only speech recogniser.
+"""The decoder-only speech recogniser, and the LFM2 block.
 
-``LFM2ASR`` is what ``train.objective="lm"`` trains: the acoustic
+``LFM2ASR`` is the shell of two families, the block chosen by the
+preset's ``lfm_layer_types``: LFM2's layers below, and the A.X-K1
+block's latent attention (``models/axk1.py``) with a shared expert
+beside the routed ones and a head of its own. ``hidden`` / ``loss``
+are the training path; ``prefill`` and ``step`` the serving path
+through a cache (``decode/lm_greedy.py``), for the layer kinds that
+have one.
+
+It is what ``train.objective="lm"`` trains: the acoustic
 frames of an utterance, stacked and projected, are the prefix of the
 decoder's sequence; the transcript follows and is trained by
 next-token cross-entropy. The decoder from its embeddings to its
@@ -21,12 +29,15 @@ positions are not routed and earn no loss.
 
 from __future__ import annotations
 
+from functools import partial
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
 from ..ops import moe
+from .axk1 import LatentAttention
 from .rnn import stack_frames
 
 _INIT = nn.initializers.normal(0.02)
@@ -158,8 +169,10 @@ class SwiGLU(nn.Module):
 
 class SparseExperts(nn.Module):
     """The routed feed-forward: this chip's ``experts_held`` experts of
-    the router's ``lfm_experts`` (``ops/moe.expert_layer``). The
-    selection bias is a buffer, not a parameter: it lives in the
+    the router's ``lfm_experts`` (``ops/moe.expert_layer``), chosen by
+    the preset's selection rule, plus the shared expert where the
+    family has one (every chip computes it alike). The selection bias
+    (``moe_select_bias``) is a buffer, not a parameter: it lives in the
     ``buffers`` collection, held at its seeded value. In a trained
     model that bias evens the experts' loads; a seeded one can only
     uneven them, so it is seeded small (std ``BIAS_STD``: enough to
@@ -177,34 +190,61 @@ class SparseExperts(nn.Module):
         w_gate = self.param("router", _INIT, (d, cfg.lfm_experts))
         w13 = self.param("w13", _INIT, (g, d, 2 * f))
         w2 = self.param("w2", _INIT, (g, f, d))
-        bias = self.variable(
-            "buffers", "expert_bias",
-            lambda: BIAS_STD * jax.random.normal(
-                self.make_rng("params"), (cfg.lfm_experts,),
-                jnp.float32)).value
+        bias = None
+        if cfg.moe_select_bias:
+            bias = self.variable(
+                "buffers", "expert_bias",
+                lambda: BIAS_STD * jax.random.normal(
+                    self.make_rng("params"), (cfg.lfm_experts,),
+                    jnp.float32)).value
         flat = x.reshape(b * s, d)
-        routing = moe.route(flat, w_gate, bias, cfg.lfm_top_k)
+        routing = moe.route(flat, w_gate, bias, cfg.lfm_top_k,
+                            cfg.moe_groups, cfg.moe_groups_kept,
+                            cfg.moe_routed_scale)
         self.sow("intermediates", "scores", routing.scores)
         self.sow("intermediates", "experts", routing.experts)
+        self.sow("intermediates", "weights", routing.weights)
         out, counters = moe.expert_layer(
             flat, valid.reshape(-1), routing, w13, w2,
             offset=cfg.expert_offset, rows_bound=cfg.moe_rows_bound,
             impl=cfg.moe_impl)
-        return out.reshape(b, s, d), counters
+        out = out.reshape(b, s, d)
+        if cfg.moe_groups > 1:
+            # Groups a valid position's chosen experts lie in: never
+            # more than ``moe_groups_kept``.
+            group = routing.experts // (cfg.lfm_experts // cfg.moe_groups)
+            used = jnp.any(group[:, :, None] == jnp.arange(
+                cfg.moe_groups)[None, None, :], axis=1)
+            counters["groups_used"] = jnp.sum(
+                used * valid.reshape(-1, 1))
+        if cfg.moe_shared_experts:
+            out = out + SwiGLU(cfg.moe_shared_experts * f,
+                               name="shared")(x)
+        return out, counters
 
 
 class DecoderLayer(nn.Module):
-    """``h + operator(norm(h))``, then ``h + ffn(norm(h))``."""
+    """``h + operator(norm(h))``, then ``h + ffn(norm(h))``. Returns
+    the new ``h``, the expert block's counters (None for a dense
+    feed-forward) and the layer's cache: the rows of this call's
+    positions (the sequence form), or the ``cache`` handed in with the
+    one new row a stream written at ``pos`` (the decode form; latent
+    attention alone has one). A kind without a cache returns None."""
 
     cfg: ModelConfig
-    kind: str      # "conv" | "full_attention"
+    kind: str      # "conv" | "full_attention" | "latent_attention"
     sparse: bool
 
     @nn.compact
-    def __call__(self, h, valid):
+    def __call__(self, h, valid, pos=None, cache=None):
         cfg = self.cfg
         x = RMSNorm(cfg.lfm_norm_eps, name="op_norm")(h)
-        if self.kind == "conv":
+        if self.kind == "latent_attention":
+            y, cache = LatentAttention(cfg, name="attn")(x, pos, cache)
+            h = h + y
+        elif cache is not None:
+            raise ValueError(f"layer type {self.kind!r} has no cache")
+        elif self.kind == "conv":
             h = h + ShortConv(cfg, name="conv")(x)
         elif self.kind == "full_attention":
             h = h + Attention(cfg, name="attn")(x)
@@ -213,8 +253,8 @@ class DecoderLayer(nn.Module):
         x = RMSNorm(cfg.lfm_norm_eps, name="ffn_norm")(h)
         if self.sparse:
             y, counters = SparseExperts(cfg, name="moe")(x, valid)
-            return h + y, counters
-        return h + SwiGLU(cfg.lfm_ffn_dim, name="ffn")(x), None
+            return h + y, counters, cache
+        return h + SwiGLU(cfg.lfm_ffn_dim, name="ffn")(x), None, cache
 
 
 def pack(a_lens, labels, label_lens, s: int):
@@ -237,56 +277,121 @@ class LFM2ASR(nn.Module):
     cfg: ModelConfig
     max_label_len: int
 
-    @nn.compact
+    def setup(self):
+        cfg = self.cfg
+        layer_cls = nn.remat(DecoderLayer, policy=REMAT_POLICY)
+        self.embed = self.param("embed", _INIT,
+                                (cfg.vocab_size, cfg.lfm_hidden))
+        self.prefix = Linear(cfg.lfm_hidden)
+        self.layers = [
+            layer_cls(cfg, kind, i >= cfg.lfm_dense_layers,
+                      name=f"layer{i}")
+            for i, kind in enumerate(cfg.lfm_layer_types)]
+        self.out_norm = RMSNorm(cfg.lfm_norm_eps)
+        if not cfg.lm_tied_head:
+            self.lm_head = self.param(
+                "lm_head", _INIT, (cfg.vocab_size, cfg.lfm_hidden))
+
+    def head(self):
+        """The output head ``[V, D]``: the embedding matrix, or the
+        family's own."""
+        return self.embed if self.cfg.lm_tied_head else self.lm_head
+
     def hidden(self, features, feat_lens, labels, label_lens):
         """The normed final hidden state ``[B, S, D]`` of the packed
-        batch, the embedding matrix (the tied head), the batch's layout
+        batch, the output head's matrix ``[V, D]``, the batch's layout
         and each expert layer's counters."""
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        layer_cls = nn.remat(DecoderLayer, policy=REMAT_POLICY)
-        embed = self.param("embed", _INIT,
-                           (cfg.vocab_size, cfg.lfm_hidden))
         x, a_lens = stack_frames(features, feat_lens, cfg.frame_stack)
         s = seq_positions(cfg, features.shape[1], self.max_label_len)
         audio, text, ids, targets = pack(a_lens, labels, label_lens, s)
-        pre = Linear(cfg.lfm_hidden, name="prefix")(x.astype(dtype))
+        pre = self.prefix(x.astype(dtype))
         pre = jnp.pad(pre, [(0, 0), (0, s - pre.shape[1]), (0, 0)])
-        emb = jnp.take(embed.astype(dtype), ids, axis=0)
+        emb = jnp.take(self.embed.astype(dtype), ids, axis=0)
         valid = audio | text
         h = jnp.where(audio[..., None], pre,
                       jnp.where(text[..., None], emb, 0))
+        pos = jnp.broadcast_to(jnp.arange(s)[None, :], valid.shape)
         counters = []
-        for i, kind in enumerate(cfg.lfm_layer_types):
-            h, c = layer_cls(cfg, kind, i >= cfg.lfm_dense_layers,
-                             name=f"layer{i}")(h, valid)
+        for layer in self.layers:
+            h, c, _ = layer(h, valid, pos)
             if c is not None:
                 counters.append(c)
         layout = {"valid": valid, "targets": targets, "a_lens": a_lens}
-        h = RMSNorm(cfg.lfm_norm_eps, name="out_norm")(h)
-        return h, embed, layout, counters
+        return self.out_norm(h), self.head(), layout, counters
 
     def loss(self, features, feat_lens, labels, label_lens):
         """Per-utterance summed cross-entropy over the ``u + 1`` target
         positions, and the routing counters of the step. The logits are
-        computed at the text positions only, against the tied embedding
+        computed at the text positions only, against the output head
         (this chip's slice of the vocabulary)."""
-        h, embed, layout, counters = self.hidden(
+        h, head, layout, counters = self.hidden(
             features, feat_lens, labels, label_lens)
-        logp, mask = target_logp(h, embed, layout, labels, label_lens)
+        logp, mask = target_logp(h, head, layout, labels, label_lens)
         nll = -jnp.sum(logp * mask, axis=1)
         valid = layout["valid"]
         stats = {"valid_positions": jnp.sum(valid),
                  "padded_positions": valid.size - jnp.sum(valid)}
         if counters:
-            stats.update(jax.tree.map(lambda *xs: jnp.stack(xs),
-                                      *counters))
+            stats.update(stack_counters(counters))
         return nll, stats
+
+    def prefill(self, features, feat_lens):
+        """The serving path's first half: the audio prefix alone
+        (positions ``0 .. a-1`` of each stream) through the layers.
+        Returns each layer's rows to cache ``[B, A, C]``, the prefix
+        lengths and the expert layers' counters."""
+        cfg = self.cfg
+        x, a_lens = stack_frames(features, feat_lens, cfg.frame_stack)
+        h = self.prefix(x.astype(jnp.dtype(cfg.dtype)))
+        pos = jnp.broadcast_to(jnp.arange(h.shape[1])[None, :],
+                               h.shape[:2])
+        valid = pos < a_lens[:, None]
+        rows, counters = [], []
+        for layer in self.layers:
+            h, c, r = layer(h, valid, pos)
+            rows.append(r)
+            if c is not None:
+                counters.append(c)
+        return rows, a_lens, stack_counters(counters)
+
+    def step(self, tokens, pos, active, cache):
+        """The serving path's second half: one new position a stream
+        (the embedding of ``tokens [B]`` at ``pos [B]``) against the
+        cache. Returns the logits ``[B, V]`` in float32, the cache with
+        the new rows and the expert layers' counters; a stream that is
+        not ``active`` is not routed."""
+        h = jnp.take(self.embed.astype(jnp.dtype(self.cfg.dtype)),
+                     tokens, axis=0)[:, None, :]
+        new, counters = [], []
+        for layer, rows in zip(self.layers, cache):
+            h, c, rows = layer(h, active[:, None], pos[:, None], rows)
+            new.append(rows)
+            if c is not None:
+                counters.append(c)
+        h = self.out_norm(h)[:, 0]
+        logits = jnp.dot(h, self.head().astype(h.dtype).T,
+                         preferred_element_type=jnp.float32)
+        return logits, new, stack_counters(counters)
+
+
+def stack_counters(counters: list) -> dict:
+    """The expert layers' counters, one leading axis over the layers."""
+    if not counters:
+        return {}
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *counters)
+
+
+def cached_kinds(cfg: ModelConfig) -> bool:
+    """Whether every layer of the preset has a decode form."""
+    return all(k == "latent_attention" for k in cfg.lfm_layer_types)
 
 
 def target_logp(h, embed, layout, labels, label_lens):
     """Log-probability of each target, ``[B, U+1]``, and which of them
-    count: the logits of the text positions against the tied matrix."""
+    count: the logits of the text positions against the head's matrix
+    ``embed [V, D]``."""
     u1 = labels.shape[1] + 1
     at = jnp.clip(layout["a_lens"][:, None] + jnp.arange(u1)[None, :],
                   0, h.shape[1] - 1)
@@ -312,3 +417,49 @@ def create_lfm2_model(cfg: ModelConfig, max_label_len: int) -> LFM2ASR:
             f"experts {cfg.expert_offset}..+{cfg.experts_held} are not "
             f"among the router's {cfg.lfm_experts}")
     return LFM2ASR(cfg, max_label_len)
+
+
+def seeded_variables(cfg, seed: int, dtype=None):
+    """``(params, buffers)`` of the preset ``cfg`` (a ``Config``) from
+    ``seed`` by the modules' own initialisers, made ON THE DEVICE in
+    ``dtype`` (the model's compute dtype where None) one layer at a
+    time: a model that fits the chip only in bfloat16 never exists in
+    float32. Layers of one kind share one compiled initialisation (the
+    key is its argument); the shell's own parameters (embedding, prefix
+    projection, last norm, head) come from a model without layers."""
+    import dataclasses
+
+    m = cfg.model
+    dtype = jnp.dtype(dtype or m.dtype)
+    frames = 2 * m.frame_stack
+    batch = (jnp.zeros((1, frames, cfg.features.num_features)),
+             jnp.full((1,), frames, jnp.int32),
+             jnp.zeros((1, cfg.data.max_label_len), jnp.int32),
+             jnp.zeros((1,), jnp.int32))
+
+    def cast(tree):
+        return jax.tree.map(lambda x: x.astype(dtype), tree)
+
+    def shell(rng):
+        v = LFM2ASR(dataclasses.replace(m, lfm_layer_types=()),
+                    cfg.data.max_label_len).init(rng, *batch,
+                                                 method="loss")
+        return cast(v["params"])
+
+    h = jnp.zeros((1, 2, m.lfm_hidden), dtype)
+    where = (jnp.ones((1, 2), bool), jnp.arange(2)[None, :])
+    oracle = dataclasses.replace(m, moe_impl="xla")
+
+    @partial(jax.jit, static_argnums=(1, 2))
+    def layer(rng, kind, sparse):
+        v = DecoderLayer(oracle, kind, sparse).init(rng, h, *where)
+        return cast(v["params"]), v.get("buffers", {})
+
+    rng = jax.random.PRNGKey(seed)
+    params, buffers = jax.jit(shell)(rng), {}
+    for i, kind in enumerate(m.lfm_layer_types):
+        params[f"layer{i}"], held = layer(
+            jax.random.fold_in(rng, i + 1), kind, i >= m.lfm_dense_layers)
+        if held:
+            buffers[f"layer{i}"] = held
+    return params, buffers

@@ -48,7 +48,7 @@ from .context import FlightRecorder, TraceContext, flight_recorder
 from .metrics import Histogram, MetricsRegistry, registry
 from .postmortem_link import (postmortem_record, postmortem_recorder,
                               set_postmortem_recorder)
-from .routing import check_dropless, observe_routing
+from .routing import check_dropless, observe_lm_call, observe_routing
 from .slo import SloBurnEngine
 from .status import StatusServer
 from .timeline import EventLog, IncidentCorrelator, MetricSeries
@@ -62,7 +62,7 @@ __all__ = ["Histogram", "MetricsRegistry", "Tracer", "registry",
            "StatusServer", "EventLog", "IncidentCorrelator",
            "MetricSeries", "timeline", "set_postmortem_recorder",
            "postmortem_recorder", "postmortem_record",
-           "observe_routing", "check_dropless"]
+           "observe_routing", "observe_lm_call", "check_dropless"]
 
 
 def span(name: str, **attrs):

@@ -17,6 +17,24 @@ step's log line carries:
   moe_rows_high_water (gauge)     most rows any layer of any step used
   moe_rows_capacity (gauge)       the static rows of the dispatch
   lm_valid_positions / lm_padded_positions
+
+and from a served call's (``decode.mode="lm_greedy"``;
+:func:`observe_lm_call`): the same, over the call's prefill sub-batches
+and decode steps, and
+
+  moe_groups_used                 groups the chosen experts of the valid
+                                  positions lie in (never more than
+                                  ``moe_groups_kept`` a position)
+  moe_experts_hit                 held experts that received a pair, over
+                                  the decode steps and expert layers
+                                  (whose weights a step had to read)
+  lm_decode_steps                 steps of the calls' decode loops
+  lm_idle_slot_steps              steps a finished stream still occupied
+                                  its slot of the batch
+  lm_cache_rows_read              cache rows the active streams' steps
+                                  attended to
+  lm_cache_bytes (gauge)          bytes of the cache (set where it is
+                                  allocated)
 """
 
 from __future__ import annotations
@@ -77,4 +95,72 @@ def observe_routing(routing: Dict, dropped: Sequence = ()
         int(reg.gauges.get("moe_rows_high_water", 0))))
     out["dropped_pairs"] = check_dropless(
         dropped or [r["dropped"]], out["rows_capacity"])
+    return out
+
+
+def observe_lm_call(prefill: Sequence[Dict], decode: Dict, rows: int
+                    ) -> Dict[str, Any]:
+    """``prefill``: the counters of each prefill sub-batch of one served
+    call; ``decode``: what its decode loop accumulated; both already on
+    the host. Counts them, ends the call on a dropped pair, and returns
+    the call's fields: ``prefill`` and ``decode`` each with the pairs
+    on every held expert per expert layer and the pairs elsewhere, the
+    positions, steps and idle slots."""
+    reg = registry()
+
+    def part(c: Dict) -> Dict[str, Any]:
+        out = {"valid_positions": int(np.sum(c["valid_positions"])),
+               "padded_positions": int(np.sum(c["padded_positions"]))}
+        if "expert_pairs" in c:
+            out.update(
+                expert_pairs=np.asarray(c["expert_pairs"]).tolist(),
+                pairs_elsewhere=np.asarray(c["pairs_elsewhere"]).tolist(),
+                rows_high_water=int(np.max(c["rows_high_water"])),
+                rows_capacity=int(np.max(c["rows_capacity"])),
+                dropped=int(np.sum(c["dropped"])))
+        if "groups_used" in c:
+            out["groups_used"] = int(np.sum(c["groups_used"]))
+        return out
+
+    summed = {k: np.sum([np.asarray(c[k]) for c in prefill], axis=0)
+              for k in prefill[0]
+              if k not in ("rows_high_water", "rows_capacity")}
+    for k in ("rows_high_water", "rows_capacity"):
+        if k in prefill[0]:
+            summed[k] = np.max([np.max(c[k]) for c in prefill])
+    steps, idle = int(decode["steps"]), int(decode["idle_slot_steps"])
+    decode = dict(decode, valid_positions=steps * rows - idle,
+                  padded_positions=idle)
+    out = {"prefill": part(summed), "decode": part(decode),
+           "decode_steps": steps, "idle_slot_steps": idle,
+           "cache_rows_read": int(decode["cache_rows_read"]), "rows": rows}
+    if "experts_hit" in decode:
+        out["experts_hit"] = int(np.sum(decode["experts_hit"]))
+        reg.count("moe_experts_hit", out["experts_hit"])
+    reg.count("lm_decode_steps", steps)
+    reg.count("lm_idle_slot_steps", idle)
+    reg.count("lm_cache_rows_read", out["cache_rows_read"])
+    dropped = 0
+    for p in (out["prefill"], out["decode"]):
+        reg.count("lm_valid_positions", p["valid_positions"])
+        reg.count("lm_padded_positions", p["padded_positions"])
+        if "expert_pairs" not in p:
+            continue
+        for layer, row in enumerate(p["expert_pairs"]):
+            for expert, n in enumerate(row):
+                reg.count("moe_expert_pairs", int(n),
+                          labels={"layer": layer, "expert": expert})
+        reg.count("moe_pairs_elsewhere", sum(p["pairs_elsewhere"]))
+        reg.count("moe_groups_used", p.get("groups_used", 0))
+        reg.gauge("moe_rows_capacity", p["rows_capacity"])
+        reg.gauge("moe_rows_high_water", max(
+            p["rows_high_water"],
+            int(reg.gauges.get("moe_rows_high_water", 0))))
+        dropped += p["dropped"]
+    out["dropped_pairs"] = dropped
+    reg.count("moe_dropped_pairs", dropped)
+    if dropped:
+        raise RuntimeError(
+            f"{dropped} routed pairs did not fit the expert layer's rows "
+            f"(model.moe_rows_bound is too low for this traffic)")
     return out

@@ -38,18 +38,34 @@ class Routing(NamedTuple):
     scores: jnp.ndarray    # [N, E] float32: sigmoid router scores
 
 
-def route(x, w_gate, bias, top_k: int) -> Routing:
-    """Sigmoid scores over all experts in float32; the ``top_k`` of
-    score + ``bias`` are chosen (``use_expert_bias``: the bias chooses,
-    it does not weigh); the chosen scores, normalised to sum to one
-    (``norm_topk_prob``), are the weights (``routed_scaling_factor``
-    1)."""
+def route(x, w_gate, bias, top_k: int, groups: int = 1,
+          groups_kept: int = 1, scale: float = 1.0) -> Routing:
+    """Sigmoid scores over all experts in float32, then the preset's
+    selection rule. The experts are ``groups`` runs of consecutive
+    ids; a group's score is its best expert's, and only the
+    ``groups_kept`` best groups can be chosen from (``n_group`` /
+    ``topk_group``; 1 of 1 is the plain rule). Among those the
+    ``top_k`` of score + ``bias`` are chosen (``use_expert_bias``: the
+    bias chooses, it does not weigh; None where the family has none).
+    The chosen scores, normalised to sum to one (``norm_topk_prob``)
+    and times ``scale`` (``routed_scaling_factor``), are the weights."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), w_gate.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
-    _, experts = lax.top_k(scores + bias[None, :], top_k)
+    choose_by = scores if bias is None else scores + bias[None, :]
+    if groups > 1:
+        n, e = choose_by.shape
+        best = jnp.max(choose_by.reshape(n, groups, e // groups), axis=2)
+        _, kept = lax.top_k(best, groups_kept)               # [N, kept]
+        in_kept = jnp.any(
+            kept[:, :, None] == jnp.arange(groups)[None, None, :], axis=1)
+        choose_by = jnp.where(jnp.repeat(in_kept, e // groups, axis=1),
+                              choose_by, -jnp.inf)
+    _, experts = lax.top_k(choose_by, top_k)
     weights = jnp.take_along_axis(scores, experts, axis=1)
     weights = weights / (jnp.sum(weights, axis=1, keepdims=True) + 1e-6)
+    if scale != 1.0:
+        weights = weights * scale
     return Routing(experts.astype(jnp.int32), weights, scores)
 
 
@@ -57,11 +73,12 @@ def capacity_rows(positions: int, top_k: int, held: int,
                   rows_bound: float) -> int:
     """Static rows of the dispatch buffer for ``positions`` computed
     positions: the worst case (every position sends min(top_k, held)
-    pairs here), or ``rows_bound`` of all pairs; in whole row tiles."""
+    pairs here), or ``rows_bound`` of all pairs; in whole row tiles of
+    the size a call of that many rows over ``held`` groups takes."""
     worst = positions * min(top_k, held)
     rows = worst if rows_bound <= 0 else min(
         worst, math.ceil(positions * top_k * rows_bound))
-    return moe_pallas.row_capacity(rows)
+    return moe_pallas.row_capacity(rows, held)
 
 
 def grouped_dot(lhs, rhs, group_sizes, impl: str):

@@ -35,15 +35,44 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .kernel_id import kernel_call
 
-# Row tile of every call; the dispatch's capacity is a multiple of it.
+# Row tile of a call whose groups fill it; a call with fewer rows a
+# group takes a smaller one (``row_tile``).
 TILE_M = 512
+_TILE_M_LEAST = 128
 _TILE = 512          # column tile of an output, and of tgmm's k
 _VMEM_LIMIT = 48 * 1024 * 1024
 
 
-def row_capacity(rows: int) -> int:
-    """``rows`` rounded up to whole row tiles."""
-    return -(-max(int(rows), 1) // TILE_M) * TILE_M
+def row_tile(rows: int, groups: int) -> int:
+    """Rows of one tile for a call of ``rows`` static rows over
+    ``groups`` groups: the power of two that holds a group's even
+    share of the rows, between 128 and ``TILE_M``. A tile is multiplied
+    whole for every group that has a row in it, so where the groups
+    have a dozen rows each (a decode step's few hundred pairs) tiles of
+    512 would be 512-row products for a dozen rows, and the call would
+    be bound by the MXU and not by its weights' bytes; training's and
+    prefill's calls (thousands of rows a group) keep ``TILE_M``."""
+    tm = _TILE_M_LEAST
+    while tm < TILE_M and tm * groups < rows:
+        tm *= 2
+    return tm
+
+
+def row_capacity(rows: int, groups: int) -> int:
+    """``rows`` rounded up to whole row tiles of its call."""
+    tm = row_tile(rows, groups)
+    return -(-max(int(rows), 1) // tm) * tm
+
+
+def _row_tile_of(m: int, groups: int, what: str) -> int:
+    """The tile of a call whose static rows are ``m`` (a capacity that
+    ``row_capacity`` made, or any multiple of ``TILE_M``)."""
+    tm = row_tile(m, groups)
+    while m % tm and tm > _TILE_M_LEAST:
+        tm //= 2
+    if m % tm:
+        raise ValueError(f"{what}: {m} rows are not whole tiles of {tm}")
+    return tm
 
 
 def _tile(x: int, want: int) -> int:
@@ -109,14 +138,12 @@ def _gmm_call(lhs, rhs, group_sizes, out_dtype, transpose_rhs: bool,
     m, k = lhs.shape
     groups = rhs.shape[0]
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tm, tn = TILE_M, _tile(n, _TILE)
-    if m % tm:
-        raise ValueError(f"gmm: {m} rows are not whole tiles of {tm}")
+    tm, tn = _row_tile_of(m, groups, "gmm"), _tile(n, _TILE)
     tiles_n = n // tn
     metadata, num_tiles = make_group_metadata(
         group_sizes, m, tm, visit_empty_groups=False)
 
-    # The contraction is one block (k = 2048, 1536 or 3072 here): the
+    # The contraction is one block (k = 1536 to 7168 here): the
     # weight block's index then changes only with the group, and the
     # pipeline does not fetch it again for the group's next row tile.
     def kernel(metadata, lhs_ref, rhs_ref, out_ref):
@@ -168,9 +195,8 @@ def tgmm(lhs, rhs, group_sizes, out_dtype, interpret: bool = False):
     m, k = lhs.shape
     n = rhs.shape[1]
     groups = group_sizes.shape[0]
-    tm, tk, tn = TILE_M, _tile(k, _TILE), _tile(n, _TILE)
-    if m % tm:
-        raise ValueError(f"tgmm: {m} rows are not whole tiles of {tm}")
+    tm = _row_tile_of(m, groups, "tgmm")
+    tk, tn = _tile(k, _TILE), _tile(n, _TILE)
     metadata, num_tiles = make_group_metadata(
         group_sizes, m, tm, visit_empty_groups=True)
 

@@ -528,11 +528,16 @@ class Trainer:
             raise ValueError("objective='lm' excludes accum_steps>1 "
                              "(its step carries the routing counters)")
         if objective == "lm" and eval_pipeline is not None:
-            # Fail at construction, not after an epoch of work.
-            raise ValueError(
-                "objective='lm' has no transcript decoding yet, so no "
-                "in-training eval: pass no eval_pipeline (ROADMAP: "
-                "greedy decoding for the lm objective)")
+            from .models.lfm2 import cached_kinds
+
+            if not cached_kinds(cfg.model):
+                # Fail at construction, not after an epoch of work.
+                raise ValueError(
+                    "objective='lm': transcripts are decoded through a "
+                    "cache, which latent attention alone has; this "
+                    "preset's layers lack a convolution state and a "
+                    "grouped-query key/value cache, so no in-training "
+                    "eval: pass no eval_pipeline")
         stages = cfg.model.pipeline_stages
         if stages > 1:
             # Training with a pipelined model silently falling back to
@@ -648,9 +653,7 @@ class Trainer:
         if self.cfg.train.objective == "rnnt":
             return self._evaluate_rnnt()
         if self.cfg.train.objective == "lm":
-            raise NotImplementedError(
-                "objective='lm' has no transcript decoding yet "
-                "(ROADMAP: greedy decoding for the lm objective)")
+            return self._evaluate_lm()
         if self.cfg.decode.mode != "greedy":
             # Beam search + LM rescoring live in infer.py (decode/beam.py);
             # in-training eval always uses the cheap greedy path.
@@ -722,6 +725,25 @@ class Trainer:
                 ref = self.tokenizer.decode(
                     batch["labels"][g][:batch["label_lens"][g]])
                 _score_utt(counts, ref, self.tokenizer.decode(hyp_ids[g]))
+        return _counts_summary(counts)
+
+    def _evaluate_lm(self) -> Dict[str, float]:
+        """WER/CER of greedy transcripts through the cache
+        (decode/lm_greedy.py; it raises, naming what is missing, for a
+        preset whose layers have no decode form). Single-process."""
+        from .decode.lm_greedy import LMGreedy
+
+        pipe = self.eval_pipeline or self.pipeline
+        engine = LMGreedy(self.cfg, self.state.params,
+                          self.state.batch_stats)
+        counts = np.zeros((5,), np.int64)
+        for batch, n_valid in pipe.eval_epoch():
+            out = engine.transcribe(batch["features"], batch["feat_lens"])
+            for g in range(n_valid):
+                ref = self.tokenizer.decode(
+                    batch["labels"][g][:batch["label_lens"][g]])
+                _score_utt(counts, ref, self.tokenizer.decode(
+                    out["ids"][g][:out["tokens"][g]]))
         return _counts_summary(counts)
 
     def fit(self, epochs: Optional[int] = None) -> Dict[str, float]:

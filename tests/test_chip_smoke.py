@@ -73,6 +73,7 @@ def test_toy_run_takes_every_phase_in_order(smoke, monkeypatch, capsys):
                         lambda route, text: {"tpu_custom_calls": 0})
     monkeypatch.setattr(smoke, "SYNC_N", 64)
     monkeypatch.setattr(smoke, "SCAN_CALL", (2, 6))
+    monkeypatch.setattr(smoke, "MLA_CALL", (1, 8))
     pid = os.getpid()
     smoke.main([])
     assert os.getpid() == pid
@@ -87,6 +88,10 @@ def test_toy_run_takes_every_phase_in_order(smoke, monkeypatch, capsys):
     assert by_phase["infer"]["n_utts"] == 32
     # both builds of the H=1760 scan ran, 2 rows x 6 steps of them
     assert by_phase["reference"]["gru_builds_fwd_values"] == 2 * 6 * 1760
+    # ... and ax_k1's attention in both forms at its published widths
+    assert by_phase["reference"]["mla_positions_compared"] == 2
+    assert by_phase["reference"]["mla_forms_rms_rel"] \
+        <= smoke.MLA_FORMS_RTOL
     serve = by_phase["serve"]
     assert serve["streams"] == 2 and serve["chunks"] >= 2
     assert serve["stream_vs_offline_cer"] <= smoke.STREAM_CER_MAX
@@ -131,3 +136,17 @@ def test_scan_builds_holds_both_builds_to_its_limits(smoke, monkeypatch,
     monkeypatch.setattr(smoke, limit, zero)
     with pytest.raises(SystemExit, match=said):
         smoke.scan_builds(True)
+
+
+def test_attention_forms_holds_the_two_forms_to_its_limit(smoke,
+                                                          monkeypatch):
+    """ax_k1's latent attention at its published widths, a few
+    positions on the CPU: the decode form against the sequence form
+    reads bfloat16's rounding, and with the limit at nothing the
+    comparison ends the run."""
+    monkeypatch.setattr(smoke, "MLA_CALL", (1, 8))
+    read = smoke.attention_forms()
+    assert 0 < read["mla_forms_rms_rel"] <= smoke.MLA_FORMS_RTOL
+    monkeypatch.setattr(smoke, "MLA_FORMS_RTOL", 0.0)
+    with pytest.raises(SystemExit, match="decode form differs"):
+        smoke.attention_forms()

@@ -65,6 +65,13 @@ def v5e_chip():
     # rows: whole-contraction blocks under a 48 MiB scoped-VMEM limit,
     # a grid as long as the routed tiles, forward + VJP
     ("moe_gmm_w13", ["moe_gmm", "moe_gmm", "moe_tgmm"]),
+    # ax_k1's grouped products over 12 held experts, K = 7168 as one
+    # contraction block: a prefill sub-batch's (row tiles of 512) and a
+    # decode step's (row tiles of 128), both matrices
+    ("moe_gmm_axk1_prefill_w13", ["moe_gmm"]),
+    ("moe_gmm_axk1_prefill_w2", ["moe_gmm"]),
+    ("moe_gmm_axk1_decode_w13", ["moe_gmm"]),
+    ("moe_gmm_axk1_decode_w2", ["moe_gmm"]),
 ])
 def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     """Mosaic accepts the kernel, and the compiled instruction still

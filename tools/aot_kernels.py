@@ -214,6 +214,15 @@ def kernel_cases():
             return train, args
         return f
 
+    def moe_forward_case(k, n, m, groups):
+        """A served program's grouped product: ``moe_gmm`` alone."""
+        from deepspeech_tpu.ops import moe_pallas
+
+        args = (S((m, k), jnp.bfloat16), S((groups, k, n), jnp.bfloat16),
+                S((groups,), jnp.int32))
+        return lambda: (lambda lhs, rhs, sizes: moe_pallas.gmm(
+            lhs, rhs, sizes, jnp.bfloat16), args)
+
     def moe_case(k, n, m=32256, groups=8):
         """lfm2_24b_a2b's grouped products at the cell's row capacity:
         ``moe_gmm`` forward, and through its gradient the transposed
@@ -273,6 +282,15 @@ def kernel_cases():
     # down (1536 -> 2048) of 8 held experts over 32,256 rows.
     cases["moe_gmm_w13"] = moe_case(2048, 3072)
     cases["moe_gmm_w2"] = moe_case(1536, 2048)
+    # ax_k1.transcribe_16s_b256: gate+up (7168 -> 2 x 2048) and down
+    # (2048 -> 7168) of 12 held experts, forward only: a prefill
+    # sub-batch's 13,824 static rows (row tiles of 512) and a decode
+    # step's 512 (row tiles of 128), K = 7168 as one contraction block.
+    for name, m in (("prefill", 13824), ("decode", 512)):
+        cases[f"moe_gmm_axk1_{name}_w13"] = moe_forward_case(
+            7168, 4096, m, 12)
+        cases[f"moe_gmm_axk1_{name}_w2"] = moe_forward_case(
+            2048, 7168, m, 12)
     cases["ctc_aishell"] = ctc_case(4336, 400, 60)
     cases["ctc_en"] = ctc_case(29, 400, 160)
     # The weak-#1 shape: AISHELL-width device beam search, both merge

@@ -53,6 +53,76 @@ V5E_HBM_BYTES = 16 * 1024**3
 _log = functools.partial(log, "aot_tpu")
 
 
+def serve_lm(args, cfg, topo) -> None:
+    """A preset that is SERVED by ``decode.mode="lm_greedy"``: compile
+    its two programs (``decode/lm_greedy.py``: prefill of one sub-batch,
+    the decode loop) for one described chip at ``--batch`` streams of
+    ``--frames`` frames, and print each one's arguments and temporaries.
+    The weights and the cache are arguments of both (the cache donated),
+    so a program's peak is its arguments + temporaries, beside which
+    the process holds the batches in flight."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeech_tpu.decode.lm_greedy import WATCH, LMGreedy
+    from deepspeech_tpu.models.lfm2 import seeded_variables
+
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    m = cfg.model
+    params, buffers = on_chip(jax.eval_shape(
+        lambda: seeded_variables(cfg, 0)))
+    engine = LMGreedy(cfg, params, buffers)
+    os.environ["DS2N_ASSUME_TPU"] = "1"
+    b, t = args.batch, engine.steps_max
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=chip)
+    cache = [sds((b, m.lfm_seq_positions, m.mla_kv_rank + m.mla_rope_dim),
+                 jnp.dtype(m.dtype)) for _ in m.lfm_layer_types]
+    programs = {
+        "prefill": (engine._prefill, (
+            params, buffers, cache,
+            sds((b, args.frames, cfg.features.num_features), jnp.float32),
+            sds((b,), jnp.int32), sds((), jnp.int32))),
+        "decode": (engine._decode, (
+            params, buffers, cache, sds((b,), jnp.int32),
+            sds((b,), jnp.int32), sds((b, t), jnp.int32),
+            sds((min(WATCH, b),), jnp.int32), sds((), jnp.bool_))),
+    }
+    out = {"tool": "aot_tpu", "preset": args.preset, "batch": b,
+           "frames": args.frames, "serve": "lm_greedy",
+           "prefill_rows": cfg.decode.lm_prefill_rows,
+           "layers": len(m.lfm_layer_types),
+           "device_kind": str(topo.devices[0].device_kind)}
+    for name, (fn, shapes) in programs.items():
+        t0 = time.time()
+        _log(f"lowering + TPU-compiling {name}...")
+        lowered = jax.jit(fn, donate_argnums=(2,)).lower(*shapes)
+        comp = lowered.compile()
+        ma = comp.memory_analysis()
+        row = {"argument_bytes": int(ma.argument_size_in_bytes),
+               "temp_bytes": int(ma.temp_size_in_bytes),
+               "output_bytes": int(ma.output_size_in_bytes),
+               "alias_bytes": int(ma.alias_size_in_bytes),
+               "mosaic_calls": lowered.as_text().count("tpu_custom_call"),
+               "compile_s": round(time.time() - t0, 1)}
+        row["peak_estimate_bytes"] = (
+            row["argument_bytes"] + row["temp_bytes"]
+            + row["output_bytes"] - row["alias_bytes"])
+        row["free_of_16gib_bytes"] = (V5E_HBM_BYTES
+                                      - row["peak_estimate_bytes"])
+        out[name] = row
+        if args.hlo_out:
+            with open(f"{args.hlo_out}.{name}", "w") as f:
+                f.write(comp.as_text())
+    print(json.dumps(out))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="ds2_full")
@@ -111,6 +181,9 @@ def main() -> None:
 
     cfg = apply_overrides(get_config(args.preset),
                           dict(kv.split("=", 1) for kv in args.overrides))
+    if cfg.decode.mode == "lm_greedy":
+        serve_lm(args, cfg, topo)
+        return
     model_cfg = cfg.model
     train_cfg = cfg.train
     if args.rnn_impl:

@@ -47,6 +47,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..config import ModelConfig
+from ..models.conv import freq_folded_conv, freq_padding
 from ..models.layers import BN_EPS
 from ..models.rnn import gru_scan, lstm_scan
 from .mesh import DATA_AXIS
@@ -166,15 +167,10 @@ def _conv_sp(cfg: ModelConfig, params, stats, x, lens, axis, n_shards,
         right = jax.lax.ppermute(x[:, :halo_r], axis, send_l) \
             if halo_r else x[:, :0]
         x = jnp.concatenate([left, x, right], axis=1)
-        fdim = x.shape[2]
-        pf_total = (-(-fdim // sf) - 1) * sf + kf - fdim
-        pf = pf_total // 2
-        x = jax.lax.conv_general_dilated(
+        x = freq_folded_conv(
             x.astype(dtype),
-            params[f"conv{i}"]["kernel"].astype(dtype),
-            window_strides=(st, sf),
-            padding=((0, 0), (pf, pf_total - pf)),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            params[f"conv{i}"]["kernel"].astype(dtype), (st, sf),
+            ((0, 0), freq_padding(x.shape[2], kf, sf)), f"conv{i}")
         lens = -(-lens // st)
         t_off = t_off // st
         # Global-validity mask for the local span.

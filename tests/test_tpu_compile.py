@@ -151,6 +151,52 @@ def test_ctc_vjp_folds_gamma_without_a_scatter(v5e_chip):
         == ["ctc_alpha", "ctc_gamma"]
 
 
+def test_frontend_convolutions_fill_the_lanes(v5e_chip):
+    """ds2_full's conv frontend, forward + backward at the cell's
+    ``[32, 1700, 161]``: the compiler sees no frontend convolution
+    whose result has fewer than 128 channels (it lays activations out
+    channels-minor on 128 lanes; the 32 channels as written ran conv1
+    at 8.6% of the MXU's peak), and its own cost model stays under
+    90 M cycles over every instruction of the program: 151.4 M as
+    written, 54.4 M folded (this compile, PR 34; 90 M is their
+    geometric mean, so a compiler update has room on both sides)."""
+    import jax.numpy as jnp
+    from _aot_common import cycles_by_op
+
+    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.models.conv import ConvFrontend
+
+    frontend = ConvFrontend(get_config("ds2_full").model)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e_chip)
+    variables = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: frontend.init(
+            jax.random.PRNGKey(0), jnp.zeros((2, 64, 161)),
+            jnp.array([64, 64]), False)))
+
+    def step(params, stats, feats, lens, ct):
+        def loss(p):
+            (y, _), new = frontend.apply(
+                {"params": p, "batch_stats": stats}, feats, lens, True,
+                mutable=["batch_stats"])
+            return jnp.sum(y.astype(jnp.float32) * ct), new
+        return jax.value_and_grad(loss, has_aux=True)(params)
+
+    text = jax.jit(step).lower(
+        variables["params"], variables["batch_stats"],
+        sds((32, 1700, 161), jnp.float32), sds((32,), jnp.int32),
+        sds((32, 850, 41 * 32), jnp.float32)).compile().as_text()
+    convs = re.findall(
+        r"= \w+\[([\d,]+)\]\{(\d+)[^ ]* convolution\([^\n]*"
+        r'op_name="[^"]*/conv_general_dilated"', text)
+    assert len(convs) == 5, convs  # two forward, two filter, one input
+    for dims, minor in convs:
+        assert int(dims.split(",")[int(minor)]) >= 128, (dims, minor)
+    cycles = sum(c for c, _ in cycles_by_op(text).values())
+    assert cycles < 90e6, cycles
+
+
 def test_store_blob_of_a_described_chip_names_its_device(v5e_chip):
     """What ``tools/aot_*.py --emit-store`` relies on: an executable
     compiled for a described chip (never loaded) serializes, and its

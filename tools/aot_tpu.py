@@ -44,7 +44,8 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _aot_common import count_collectives, log, setup_aot_env  # noqa: E402
+from _aot_common import (count_collectives, cycles_by_op, log,  # noqa: E402
+                         setup_aot_env)
 
 setup_aot_env()
 
@@ -123,6 +124,19 @@ def serve_lm(args, cfg, topo) -> None:
     print(json.dumps(out))
 
 
+def log_cycles(hlo: str, top: int = 40) -> None:
+    """The compiler's cost model by line of the program, largest
+    first: sizes an XLA-level change before anyone asks for it."""
+    by_op = cycles_by_op(hlo)
+    _log(f"estimated_cycles "
+         f"{sum(c for c, _ in by_op.values()) / 1e6:.2f} M over "
+         f"{sum(n for _, n in by_op.values())} instructions; by op_name "
+         f"(M cycles, instructions):")
+    for op, (cycles, n) in sorted(by_op.items(),
+                                  key=lambda kv: -kv[1][0])[:top]:
+        _log(f"{cycles / 1e6:10.2f} {n:4d}  {op[-100:] or '(none)'}")
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="ds2_full")
@@ -148,7 +162,9 @@ def main() -> None:
                          "via compile(compiler_options=...) because "
                          "global XLA_FLAGS is also parsed (and rejected) "
                          "by the cpu runtime client")
-    ap.add_argument("--hlo-out", default="", help="dump optimized HLO here")
+    ap.add_argument("--hlo-out", default="",
+                    help="dump optimized HLO here, and log its "
+                         "estimated_cycles summed per op_name")
     ap.add_argument("--emit-store", default="", metavar="DIR",
                     help="serialize the compiled TRAIN step into this "
                          "warm-store root (utils/aotstore) under the "
@@ -283,6 +299,7 @@ def main() -> None:
     if args.hlo_out:
         with open(args.hlo_out, "w") as f:
             f.write(hlo)
+        log_cycles(hlo)
 
     store_row = {}
     if args.emit_store:

@@ -83,6 +83,7 @@ class LMGreedy:
         self.sparse = [f"layer{i}" for i in range(len(m.lfm_layer_types))
                        if i >= m.lfm_dense_layers]
         self._cache = None
+        self._calls = 0
         self.last_call: Optional[dict] = None
         donate = () if jax.default_backend() == "cpu" else (2,)
         self.prefill = jax.jit(self._prefill, donate_argnums=donate)
@@ -216,35 +217,51 @@ class LMGreedy:
             forced = np.full((b, t), -1, np.int32)
         if watch is None:
             watch = np.arange(min(WATCH, b), dtype=np.int32)
+        self._calls += 1
+        call = self._calls  # what the spans of one call share
         t0 = time.perf_counter()
-        with obs.span("infer.transcribe", rows=b):
-            cache = self.cache_for(b, features.shape[1])
+        with obs.span("infer.transcribe", rows=b, call=call):
+            with obs.span("infer.cache", call=call):
+                cache = self.cache_for(b, features.shape[1])
+            t_cache = time.perf_counter()
             a_lens, pre, pre_watch = [], [], None
             for i in range(b // sub):
-                with obs.span("infer.prefill", rows=sub):
-                    cache, a, counters, mid = self.prefill(
-                        self.params, self.buffers, cache, features,
-                        feat_lens, i * sub)
+                with obs.span("infer.prefill", rows=sub, call=call):
+                    with obs.span("infer.prefill.dispatch", call=call):
+                        cache, a, counters, mid = self.prefill(
+                            self.params, self.buffers, cache, features,
+                            feat_lens, i * sub)
                     if obs.tracer.enabled:
-                        jax.block_until_ready(cache)
+                        with obs.span("infer.prefill.wait", call=call):
+                            jax.block_until_ready(cache)
                 a_lens.append(a)
                 pre.append(counters)
                 pre_watch = mid if i == 0 else pre_watch
             t1 = time.perf_counter()
-            with obs.span("infer.decode", rows=b):
-                ids, cache, acc, seen = self.decode(
-                    self.params, self.buffers, cache,
-                    jnp.concatenate(a_lens), max_tokens,
-                    jnp.asarray(forced, jnp.int32),
-                    jnp.asarray(watch, jnp.int32),
-                    jnp.asarray(cfg.decode.lm_ignore_end))
-                ids, acc, pre = jax.device_get((ids, acc, pre))
+            with obs.span("infer.decode", rows=b, call=call):
+                with obs.span("infer.decode.dispatch", call=call):
+                    ids, cache, acc, seen = self.decode(
+                        self.params, self.buffers, cache,
+                        jnp.concatenate(a_lens), max_tokens,
+                        jnp.asarray(forced, jnp.int32),
+                        jnp.asarray(watch, jnp.int32),
+                        jnp.asarray(cfg.decode.lm_ignore_end))
+                t2 = time.perf_counter()
+                with obs.span("infer.decode.fetch", call=call):
+                    ids, acc, pre = jax.device_get((ids, acc, pre))
+                t3 = time.perf_counter()
             self._cache = cache
         stats = obs.observe_lm_call(pre, acc, rows=b)
-        # Host seconds: dispatching the prefill programs, then up to the
+        # Host seconds, tracer on or off, so that a stalled call says
+        # where: up to the prefill programs dispatched (``cache``, the
+        # cache handed out or made, is its first part), then up to the
         # ids on the host (the device's whole call, where nothing blocks
-        # before).
-        stats["host_s"] = {"prefill_dispatch": t1 - t0,
+        # before), of which ``decode_dispatch`` and ``fetch`` are the
+        # decode program's call and the ``device_get``.
+        stats["host_s"] = {"cache": t_cache - t0,
+                           "prefill_dispatch": t1 - t0,
+                           "decode_dispatch": t2 - t1,
+                           "fetch": t3 - t2,
                            "to_ids": time.perf_counter() - t1}
         self.last_call = {"stats": stats, "prefill_watch": pre_watch,
                           "decode_watch": seen, "cache": cache}

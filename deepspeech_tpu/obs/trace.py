@@ -20,6 +20,33 @@ calling thread, so a recompile inside ``train.step`` is that span's
 child. jax reports a phase when it ends: ``ts`` is the wall clock then
 minus the duration.
 
+The host's turn between two device programs is opened up by child
+spans (the parent's name and extent stay what they were; ``parent``
+comes from the stack, and the children of one unit share its ``step``
+or ``call``)::
+
+    train.step      train.dispatch  the jitted step's call to its return
+                    train.wait      the traced loop's block on the loss
+                                    (tracer on only)
+    train.log       train.sync      the logged step's block on the loss
+                    train.lr        the schedule read back as a float
+                    train.fetch     loss, grad norm and the routing
+                                    counters to the host (``arrays``)
+                    train.emit      the logger and the TensorBoard writer
+    infer.transcribe  infer.cache   the call's cache handed out or made
+    infer.prefill   infer.prefill.dispatch, infer.prefill.wait (tracer
+                                    on only): one sub-batch
+    infer.decode    infer.decode.dispatch  argument conversion + the call
+                    infer.decode.fetch     ids and counters to the host
+
+A garbage collection is a span as well: ``host.gc`` with ``generation``
+and ``collected``, ``parent`` = the span open on the collecting thread,
+from one ``gc.callbacks`` hook installed when a tracer is first enabled
+(never enabled: no hook; disabled again: the hook returns at once). The
+hook writes nothing itself (the collector may run while this tracer's
+lock is held): it leaves the finished collection for the next span's
+record, or for ``configure(enabled=False)``, to write.
+
 Durations come from a monotonic clock (injectable for tests — wall
 time only stamps ``ts``); nesting is tracked per thread, so gateway
 dispatch spans on a worker thread never adopt a train-loop parent.
@@ -34,6 +61,7 @@ Enable with ``configure(jsonl_path=...)`` or by exporting
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import sys
@@ -116,6 +144,11 @@ def _callsite(skip_substrings=(os.sep + "obs" + os.sep,
     return "?"
 
 
+def _forget_gc_hook(hook) -> None:
+    if hook in gc.callbacks:
+        gc.callbacks.remove(hook)
+
+
 class Tracer:
     """Span recorder with an injectable monotonic clock and JSONL sink.
 
@@ -140,6 +173,8 @@ class Tracer:
         self._tl = threading.local()
         self._id = 0
         self._hears_jax = False
+        self._gc_t0: Optional[tuple] = None
+        self._gc_done: list = []
 
     # -- configuration --------------------------------------------------
     def configure(self, enabled: bool = True,
@@ -151,6 +186,8 @@ class Tracer:
         """(Re)configure in place: pass ``jsonl_path`` to append span
         records to a file, or ``sink`` for an open stream (tests use
         ``io.StringIO``). Disabling closes an owned file sink."""
+        if not enabled:
+            self._flush_gc()  # before the lock: writing takes it
         with self._lock:
             if clock is not None:
                 self._clock = clock
@@ -173,8 +210,10 @@ class Tracer:
                 atexit.register(self._close_sink)
             if not enabled:
                 self._close_sink()
+                self._gc_t0 = None
             elif not self._hears_jax:
                 self._listen_to_jax()
+                self._listen_to_gc()
             self.enabled = enabled
 
     def _listen_to_jax(self) -> None:
@@ -193,6 +232,37 @@ class Tracer:
 
         jax.monitoring.register_event_duration_secs_listener(on_duration)
         self._hears_jax = True
+
+    def _listen_to_gc(self) -> None:
+        """Beside ``_listen_to_jax``: one ``gc.callbacks`` hook for the
+        tracer's life, which holds it weakly and goes with it."""
+        ref = weakref.ref(self)
+
+        def on_gc(phase, info):
+            tr = ref()
+            if tr is None or not tr.enabled:
+                return
+            if phase == "start":
+                tr._gc_t0 = (tr._wall(), tr._clock())
+            elif tr._gc_t0 is not None:
+                (ts, t0), tr._gc_t0 = tr._gc_t0, None
+                stack = tr._stack()
+                tr._gc_done.append(
+                    (ts, (tr._clock() - t0) * 1e3,
+                     stack[-1].id if stack else None,
+                     {"generation": info["generation"],
+                      "collected": info["collected"]}))
+
+        gc.callbacks.append(on_gc)
+        weakref.finalize(self, _forget_gc_hook, on_gc).atexit = False
+
+    def _flush_gc(self) -> None:
+        """Write the collections the hook has left (see the module
+        docstring for why it does not write them itself)."""
+        while self._gc_done:
+            ts, dur_ms, parent, attrs = self._gc_done.pop(0)
+            self._write_span("host.gc", ts, dur_ms, self._new_id(),
+                             parent, attrs)
 
     def _close_sink(self) -> None:
         if self._sink is not None and self._owns_sink:
@@ -253,6 +323,8 @@ class Tracer:
         return stack
 
     def _record(self, span: _Span, dur_ms: float) -> None:
+        if self._gc_done:
+            self._flush_gc()
         self._write_span(span.name, span.ts, dur_ms, span.id, span.parent,
                          span.attrs)
 

@@ -825,20 +825,22 @@ class Trainer:
                             jnp.nan, feats.dtype))
                     t_step = time.perf_counter()
                     with obs.span("train.step", step=step):
-                        if self.guardian is not None:
-                            self.state, metrics = self.train_step(
-                                self.state, sharded,
-                                {"lr_scale":
-                                 np.float32(self.guardian.lr_scale)})
-                        else:
-                            self.state, metrics = self.train_step(
-                                self.state, sharded)
+                        with obs.span("train.dispatch", step=step):
+                            if self.guardian is not None:
+                                self.state, metrics = self.train_step(
+                                    self.state, sharded,
+                                    {"lr_scale":
+                                     np.float32(self.guardian.lr_scale)})
+                            else:
+                                self.state, metrics = self.train_step(
+                                    self.state, sharded)
                         if obs.tracer.enabled:
                             # Trace mode trades pipelining for
                             # attribution: blocking here lands the
                             # jitted compute in THIS span instead of
                             # smearing it into the next host wait.
-                            jax.block_until_ready(metrics["loss"])
+                            with obs.span("train.wait", step=step):
+                                jax.block_until_ready(metrics["loss"])
                     if self.guardian is not None:
                         # observe_step reads the metrics (the device
                         # sync the guarded mode accepts), so the
@@ -883,32 +885,44 @@ class Trainer:
                         self.logger.log("profile_saved",
                                         dir=cfg.train.profile_dir, step=step)
                     if step % cfg.train.log_every == 0:
+                        # The host's turn after a logged step, one
+                        # child span a thing it does (obs/trace.py).
                         with obs.span("train.log", step=step):
-                            jax.block_until_ready(metrics["loss"])
+                            with obs.span("train.sync", step=step):
+                                jax.block_until_ready(metrics["loss"])
                             rate = thr.rate_per_chip()
-                            lr = float(self.lr_schedule(
-                                jnp.asarray(step - 1)))
-                            last = {"loss": float(metrics["loss"]),
-                                    "grad_norm":
-                                        float(metrics["grad_norm"])}
-                            routing = {}
-                            if "routing" in metrics:
-                                routing = obs.observe_routing(
-                                    metrics["routing"], self._dropped)
-                                self._dropped = []
-                            self.logger.log(
-                                "train_step", step=step, epoch=epoch,
-                                lr=round(lr, 8),
-                                utt_per_sec_per_chip=round(rate, 3),
-                                **last, **routing)
-                            if self.tb is not None:
-                                # The per-expert lists stay in the log
-                                # line and the registry.
-                                self.tb.scalars(
-                                    step, **last, lr=lr,
-                                    utt_per_sec_per_chip=rate,
-                                    **{k: v for k, v in routing.items()
-                                       if np.isscalar(v)})
+                            with obs.span("train.lr", step=step):
+                                lr = float(self.lr_schedule(
+                                    jnp.asarray(step - 1)))
+                            with obs.span("train.fetch",
+                                          step=step) as fetch:
+                                last = {"loss": float(metrics["loss"]),
+                                        "grad_norm":
+                                            float(metrics["grad_norm"])}
+                                arrays, routing = 2, {}
+                                if "routing" in metrics:
+                                    arrays += (len(metrics["routing"])
+                                               + len(self._dropped))
+                                    routing = obs.observe_routing(
+                                        metrics["routing"],
+                                        self._dropped)
+                                    self._dropped = []
+                                fetch.set(arrays=arrays)
+                            with obs.span("train.emit", step=step):
+                                self.logger.log(
+                                    "train_step", step=step, epoch=epoch,
+                                    lr=round(lr, 8),
+                                    utt_per_sec_per_chip=round(rate, 3),
+                                    **last, **routing)
+                                if self.tb is not None:
+                                    # The per-expert lists stay in the
+                                    # log line and the registry.
+                                    self.tb.scalars(
+                                        step, **last, lr=lr,
+                                        utt_per_sec_per_chip=rate,
+                                        **{k: v
+                                           for k, v in routing.items()
+                                           if np.isscalar(v)})
                     if (cfg.train.checkpoint_every_steps and self.ckpt and
                             step % cfg.train.checkpoint_every_steps == 0):
                         self.save(epoch)

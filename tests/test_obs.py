@@ -8,6 +8,7 @@ regression, Prometheus text exposition, and compile-event attribution
 through ``ShapeBucketCache``.
 """
 
+import gc
 import io
 import json
 import os
@@ -36,6 +37,16 @@ class Clock:
 
     def advance(self, dt: float) -> None:
         self.t += dt
+
+
+@pytest.fixture(autouse=True)
+def no_collection_mid_test():
+    """An enabled tracer writes a ``host.gc`` record for every garbage
+    collection (``tests/test_host_turn.py``); the tests here count and
+    order records, so nothing is collected while one runs."""
+    gc.disable()
+    yield
+    gc.enable()
 
 
 # -- spans ----------------------------------------------------------------
@@ -349,7 +360,6 @@ def test_jax_phase_listener_is_silent_and_free_when_off(monkeypatch):
     assert [(r["name"], r["fun"], r["dur_ms"], r["parent"])
             for r in recs] == [("jax.compile", "f", 250.0, None)]
     # The listener does not keep a dropped tracer alive.
-    import gc
     import weakref
     ref = weakref.ref(tr)
     del tr

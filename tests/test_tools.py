@@ -200,6 +200,7 @@ def _run_obs_schema(tmp_path, text, *extra):
 def test_check_obs_schema_accepts_real_producers(tmp_path):
     """The lint must accept what the actual producers write: a
     registry/telemetry snapshot line and tracer span/compile lines."""
+    import gc
     import io
 
     from deepspeech_tpu.obs.metrics import MetricsRegistry
@@ -212,10 +213,15 @@ def test_check_obs_schema_accepts_real_producers(tmp_path):
     tel.rung(4, 64)
     tel.emit_jsonl(fh, wall_s=1.0)
     tr = Tracer(registry=MetricsRegistry())
-    tr.configure(enabled=True, sink=fh)
-    with tr.span("train.step", step=0):
-        pass
-    tr.compile_event(4, 64, site="infer.py:1")
+    gc.disable()  # a collection would be a record too (host.gc)
+    try:
+        tr.configure(enabled=True, sink=fh)
+        with tr.span("train.step", step=0):
+            pass
+        tr.compile_event(4, 64, site="infer.py:1")
+        tr.configure(enabled=False, sink=fh)
+    finally:
+        gc.enable()
     out = _run_obs_schema(tmp_path, fh.getvalue())
     assert out.returncode == 0, out.stderr
     assert "OK (3 records)" in out.stdout

@@ -11,6 +11,16 @@ the same tooling (``tools/trace_report.py``, dashboards). The contract:
   (wall-clock seconds);
 - timing records (``event`` of ``span`` or ``compile``) additionally
   carry a numeric ``dur_ms`` and a string ``name``;
+- the spans of the host's turn (``obs/trace.py``): a child span of a
+  training step (``train.dispatch`` / ``.wait`` / ``.sync`` / ``.lr`` /
+  ``.fetch`` / ``.emit``) carries the integer ``step`` it shares with
+  its parent, a child span of a served call (``infer.cache``,
+  ``infer.prefill.dispatch`` / ``.wait``, ``infer.decode.dispatch`` /
+  ``.fetch``) the integer ``call``, and ``host.gc`` (one garbage
+  collection) an integer ``generation`` and ``collected`` — a child
+  that does not say whose it is cannot be summed into its unit's
+  turn, and a collection without its generation cannot be told from
+  the cheap ones;
 - postmortem records (``event`` of ``postmortem`` —
   ``resilience.postmortem``, one line per automatic intervention:
   quarantined sample/request, anomaly, rollback, stall) additionally
@@ -190,6 +200,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 from deepspeech_tpu.obs.metrics import parse_series  # noqa: E402
 
 TIMED_EVENTS = ("span", "compile")
+# Spans of the host's turn -> the integer keys each must carry.
+HOST_TURN_SPANS = {
+    **{f"train.{k}": ("step",) for k in (
+        "dispatch", "wait", "sync", "lr", "fetch", "emit")},
+    **{f"infer.{k}": ("call",) for k in (
+        "cache", "prefill.dispatch", "prefill.wait", "decode.dispatch",
+        "decode.fetch")},
+    "host.gc": ("generation", "collected"),
+}
 # Snapshot sections whose keys are (possibly labeled) series names.
 SERIES_SECTIONS = ("counters", "gauges", "histograms")
 # Labels holding the all-or-nothing family rule (module docstring).
@@ -256,6 +275,12 @@ def validate_record(rec) -> List[str]:
                 "timing record missing/invalid 'dur_ms' (number)")
         if not isinstance(rec.get("name"), str) or not rec.get("name"):
             problems.append("timing record missing 'name' (string)")
+        for key in HOST_TURN_SPANS.get(rec.get("name"), ()):
+            if not isinstance(rec.get(key), int) \
+                    or isinstance(rec.get(key), bool):
+                problems.append(
+                    f"{rec['name']} span missing/invalid {key!r} "
+                    f"(integer)")
     if rec.get("event") == "postmortem":
         if not isinstance(rec.get("kind"), str) or not rec.get("kind"):
             problems.append(

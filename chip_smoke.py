@@ -17,7 +17,9 @@ are cut:
              call at the benchmark cell's own rows and frames in its
              two builds (weights copied once / streamed in column
              blocks): forward the same bits, gradients as near the XLA
-             scan's as each other
+             scan's as each other; that call's recurrent weight
+             gradient at six, three and one bf16 passes against the
+             float64 sum of its operands (dw_h_precision)
   serve      ds2_streaming (uni-GRU 5x800 + lookahead 20): a checkpoint
              from two train steps, two generated wavs streamed chunk by
              chunk, finals compared with the offline decode of the same
@@ -40,6 +42,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import gc
 import io
 import json
@@ -75,8 +78,8 @@ SCAN_CALL = (32, 850)
 # feeds the next of 850 steps: the chip reads up to 3.3e-4), and either
 # against the XLA scan (the chip reads 2.8e-3, 8.6e-2 and 5.2e-4 for
 # both builds alike; the scan's transposed dot takes bf16 operands
-# where the kernels' dW_h runs at HIGHEST). A build that lost a column
-# block or a time step reads tenths to ones.
+# where the kernels' dW_h contracts them as float32). A build that
+# lost a column block or a time step reads tenths to ones.
 SCAN_BUILDS_RTOL = 2e-3
 # ax_k1's latent attention at its published widths, one layer: (rows,
 # positions) on which its two forms are compared, the decode form
@@ -90,6 +93,17 @@ SCAN_BUILDS_RTOL = 2e-3
 MLA_CALL = (8, 288)
 MLA_FORMS_RTOL = 2e-2
 SCAN_ORACLE_RTOL = {"dxproj": 1e-2, "dw_h": 0.2, "db_h": 2e-3}
+# The recurrent weight gradient of that call, [T*B, H]^T x [T*B, 3H]
+# from float32 operands (ops/rnn_pallas.py recurrent_dw): what the
+# three-pass contraction a bf16 model runs (Precision.HIGH) may differ
+# by from the float64 sum of the same operands, as largest error over
+# largest value and as rms error over rms value, and how many times
+# under the distance between the bf16-dot program's dW_h and the
+# all-float32 program's (the noise 850 steps of bf16 matmuls have put
+# into both operands) it has to stay. Fixed by ISSUE 37 before the
+# first reading; the chip's readings: PERF.md section 6, PR 37.
+DW_H_LIMIT = 1e-4
+DW_H_TIMES_UNDER_NOISE = 20
 # Streamed finals against the offline decode of the same audio: the
 # two graphs reduce in different orders in bf16, so an argmax near a
 # tie may flip; more than this is a wrong stream, not rounding.
@@ -380,6 +394,7 @@ def phase_reference() -> dict:
             fail(f"GRU H={h} kernel differs from the XLA scan: {err}")
         out[f"gru_h{h}_rel_err"] = err
     out.update(scan_builds(interpret))
+    out.update(dw_h_precision(interpret))
     out.update(attention_forms())
     t, v, lmax = 100, 29, 20
     logits = jnp.asarray(rng.normal(size=(b, t, v)), jnp.float32)
@@ -500,6 +515,94 @@ def scan_builds(interpret: bool) -> dict:
                      f"from the XLA scan by {err}")
             out[f"gru_{build}_{name}_rel_err"] = err
         out[f"gru_builds_{name}_rel_diff"] = builds
+    return out
+
+
+def dw_h_precision(interpret: bool) -> dict:
+    """What the precision of the recurrent weight gradient costs and
+    buys at ds2_full's scan call (``SCAN_CALL``, H=1760). The operands
+    are the ones a real backward pass in bf16 dots hands to
+    ``recurrent_dw`` (seeded inputs, a signed cotangent: sums that
+    cancel, as Gaussian operands would not). For each of ``HIGHEST``
+    (six bf16 passes of the MXU), ``HIGH`` (three) and ``DEFAULT``
+    (one; timed and listed, never used): milliseconds a contraction,
+    and its error against the float64 sum of the SAME float32 operands
+    on the host. Beside them, the distance from that sum to the dW_h
+    of the all-float32 program on the same inputs: the noise the bf16
+    recurrence has put into the operands before any contraction sees
+    them. ``HIGH`` and the program's own dW_h (whatever
+    ``recurrent_dw`` lowers to) must hold ``DW_H_LIMIT`` and stay
+    ``DW_H_TIMES_UNDER_NOISE`` times under that distance. On the CPU
+    every precision is float32 arithmetic: control flow only."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeech_tpu.ops import rnn_pallas
+
+    (b, t), h = SCAN_CALL, 1760
+    rng = np.random.default_rng(37)
+    # Both programs see the same bf16-rounded projections, so that the
+    # distance reads the recurrence's dot type alone.
+    xp = jnp.asarray(rng.normal(size=(b, t, 3 * h)), jnp.bfloat16)
+    wh = jnp.asarray(rng.normal(size=(h, 3 * h)) / np.sqrt(h), jnp.float32)
+    bh = jnp.asarray(rng.normal(size=(3 * h,)) * 0.1, jnp.float32)
+    lens = rng.integers(t * 12 // 17, t + 1, size=b)  # the cell's 12-17 s
+    mask = jnp.asarray(np.arange(t)[None] < lens[:, None], jnp.float32)
+    dy = jnp.asarray(rng.normal(size=(b, t, h)) * 0.1, jnp.float32)
+
+    handed = []
+    shipped = rnn_pallas.recurrent_dw
+
+    def keep(h_prev, dgates, dot):
+        handed.append((h_prev, dgates))
+        return shipped(h_prev, dgates, dot)
+
+    def program_dw_h(x, dot_dtype):
+        # Eagerly, so that the VJP rule runs on concrete arrays.
+        _, vjp = jax.vjp(lambda w: rnn_pallas.gru_scan_pallas(
+            x, mask, w, bh, False, interpret, dot_dtype), wh)
+        return np.asarray(vjp(dy)[0], np.float64)
+
+    with mock.patch.object(rnn_pallas, "recurrent_dw", keep):
+        program = program_dw_h(xp, "bfloat16")
+    (h_prev, dgates), = handed
+    all_float32 = program_dw_h(xp.astype(jnp.float32), None)
+    exact = (np.asarray(h_prev, np.float64).reshape(b * t, h).T
+             @ np.asarray(dgates, np.float64).reshape(b * t, 3 * h))
+
+    def apart(got):
+        d = np.asarray(got, np.float64) - exact
+        return {"max_rel": float(np.abs(d).max() / np.abs(exact).max()),
+                "rms_rel": float(np.sqrt(np.mean(d ** 2)
+                                         / np.mean(exact ** 2)))}
+
+    calls = 10
+    rows = {"program": apart(program)}
+    for name in ("HIGHEST", "HIGH", "DEFAULT"):
+        contract = jax.jit(functools.partial(
+            jnp.einsum, "tbh,tbg->hg",
+            precision=getattr(jax.lax.Precision, name)))
+        rows[name.lower()] = apart(contract(h_prev, dgates))  # compiled
+        t0 = time.perf_counter()
+        jax.block_until_ready([contract(h_prev, dgates)
+                               for _ in range(calls)])
+        rows[name.lower()]["ms"] = (time.perf_counter() - t0) * 1e3 / calls
+    noise = apart(all_float32)
+    for name in ("high", "program"):
+        for kind, err in rows[name].items():
+            if kind != "ms" and not err <= min(
+                    DW_H_LIMIT, noise[kind] / DW_H_TIMES_UNDER_NOISE):
+                fail(f"dW_h at H={h}, {b * t} rows: {name} is {err} "
+                     f"({kind}) from the float64 sum of its operands; "
+                     f"the limit is {DW_H_LIMIT} and a "
+                     f"{DW_H_TIMES_UNDER_NOISE}th of the bf16 "
+                     f"recurrence's own {noise[kind]}")
+    out = {"dw_h_rows": b * t}
+    for name, row in {**rows, "bf16_to_float32": noise}.items():
+        out.update({f"dw_h_{name}_{kind}": v for kind, v in row.items()})
     return out
 
 

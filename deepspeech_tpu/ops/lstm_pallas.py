@@ -36,7 +36,7 @@ from .rnn_pallas import (_block_layout, _blocked_q_in_specs,
                          _dot_jnp_dtype, _pad_cols,
                          _resident_in_specs, _resident_q_in_specs,
                          _time_index_maps, _time_major,
-                         _use_blocked, fits_vmem)
+                         _use_blocked, fits_vmem, recurrent_dw)
 
 
 def _lstm_elementwise_fwd(xp, gates, hprev, cprev, m):
@@ -506,11 +506,9 @@ def _lstm_bwd(reverse, interpret, dot_dtype, residuals, dy):
     else:
         h_prev_seq = jnp.concatenate(
             [jnp.zeros_like(ys[:1]), ys[:-1]], axis=0)
-    # precision=HIGHEST for the same reason as the GRU dW einsum
-    # (rnn_pallas._gru_bwd): f32 operands + cancellation-heavy T*B
-    # contraction; TPU DEFAULT precision would bf16-round them.
-    dw_h = jnp.einsum("tbh,tbg->hg", h_prev_seq, dgates_t,
-                      precision=jax.lax.Precision.HIGHEST)
+    # float32 operands, never rounded to 8 bits, at the precision the
+    # dot type states: the GRU's rule (rnn_pallas.recurrent_dw).
+    dw_h = recurrent_dw(h_prev_seq, dgates_t, dot)
     db_h = jnp.sum(dgates_t, axis=(0, 1))
     dxp = jnp.moveaxis(dxp_t, 0, 1)
     return (dxp, jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1),

@@ -118,6 +118,31 @@ def _dot_jnp_dtype(dot_dtype: Optional[str]):
                      "use None/'float32'/'bfloat16'")
 
 
+def recurrent_dw(h_prev, dgates, dot):
+    """``dW_h = sum over T*B of h_prev^T dgates``: the recurrent weight
+    gradient as one MXU contraction of two float32 ``[T, B, .]``
+    sequences outside the time loop, at the precision the scan's dot
+    type states. The sum is cancellation-heavy (T*B = 27,200 products
+    an entry at ds2_full's cell), so it never rounds an operand to
+    8 bits (``DEFAULT``, one bf16 pass: 3.6e-2 off the float32 truth
+    at toy size, tests/test_pallas.py
+    test_gru_bf16_dw_closer_to_truth_than_oracle).
+
+    float32 dots: ``HIGHEST``, six bf16 passes, 24 bits of each operand
+    (a float32 model states float32 compute). bfloat16 dots: ``HIGH``,
+    three passes (``hi*hi + hi*mid + mid*hi``), 16 bits of each
+    operand: both operands come out of T steps of bf16 matmuls, whose
+    noise puts dW_h 3.2e-4 from the all-float32 program's, and three
+    passes are 1.4e-5 from the float64 sum, 23 times under it (the
+    chip at the cell's shape, ``chip_smoke.py dw_h_precision``; limits
+    and readings: PERF.md section 6, PR 37). The last 8 bits that
+    ``HIGHEST`` would carry are bits of that noise, at twice the MXU
+    time: 14 such contractions were 41% of ds2_full's step."""
+    precision = (jax.lax.Precision.HIGH if dot == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+    return jnp.einsum("tbh,tbg->hg", h_prev, dgates, precision=precision)
+
+
 # ---------------------------------------------------------------------------
 # Resident-weight kernels (weights live in VMEM across the whole scan).
 # ---------------------------------------------------------------------------
@@ -993,13 +1018,12 @@ def _bigru_bwd(interpret, dot_dtype, residuals, dy):
       b_f.astype(jnp.float32).reshape(1, h3),
       b_b.astype(jnp.float32).reshape(1, h3))
 
-    # h_prev sequences in data order; dW at HIGHEST for the same
-    # cancellation-safety reason as the single-direction path.
+    # h_prev sequences in data order; each direction's dW as the
+    # single-direction path has it (recurrent_dw).
     hprev_f = jnp.concatenate([jnp.zeros_like(ysf[:1]), ysf[:-1]], axis=0)
     hprev_b = jnp.concatenate([ysb[1:], jnp.zeros_like(ysb[:1])], axis=0)
-    hi = jax.lax.Precision.HIGHEST
-    dw_f = jnp.einsum("tbh,tbg->hg", hprev_f, dgf, precision=hi)
-    dw_b = jnp.einsum("tbh,tbg->hg", hprev_b, dgb, precision=hi)
+    dw_f = recurrent_dw(hprev_f, dgf, dot)
+    dw_b = recurrent_dw(hprev_b, dgb, dot)
     dxp = jnp.moveaxis(dxpf + dxpb, 0, 1)
     return (dxp, jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1),
             dw_f.astype(w_f.dtype), jnp.sum(dgf, axis=(0, 1)).astype(
@@ -1071,19 +1095,14 @@ def _gru_bwd(reverse, interpret, dot_dtype, residuals, dy):
     else:
         h_prev_seq = jnp.concatenate(
             [jnp.zeros_like(ys[:1]), ys[:-1]], axis=0)
-    # One big MXU contraction instead of a per-step VMEM accumulator.
-    # precision=HIGHEST: both operands are f32 and the T*B contraction
-    # is cancellation-heavy; TPU DEFAULT precision would bf16-round the
-    # operands and reintroduce exactly the noise this path avoids. The
-    # bf16-dots diagnosis (r3; tests/test_pallas.py
-    # test_gru_bf16_dw_closer_to_truth_than_oracle): at dot_dtype=bf16
+    # One big MXU contraction instead of a per-step VMEM accumulator,
+    # from float32 operands whatever the dot type: at dot_dtype=bf16
     # the ORACLE's dW is the noisy one (it rounds h_prev to bf16 in its
-    # per-step outer products, rel err ~3e-2 vs f32 truth) while this
-    # f32 einsum stays ~2e-3 — the r2 chip rows' grad_rel_errs[1]
-    # ~0.15 measured kernel-vs-oracle distance, i.e. oracle noise, not
-    # a kernel defect.
-    dw_h = jnp.einsum("tbh,tbg->hg", h_prev_seq, dgates_t,
-                      precision=jax.lax.Precision.HIGHEST)
+    # per-step outer products, rel err ~3e-2 vs f32 truth; tests/
+    # test_pallas.py test_gru_bf16_dw_closer_to_truth_than_oracle)
+    # while this contraction stays ~2e-3, which is the recurrence's own
+    # bf16 noise and not the contraction's (recurrent_dw).
+    dw_h = recurrent_dw(h_prev_seq, dgates_t, dot)
     db_h = jnp.sum(dgates_t, axis=(0, 1))
     dxp = jnp.moveaxis(dxp_t, 0, 1)  # [B, T, 3H]
     return (dxp, jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1),

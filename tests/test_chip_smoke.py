@@ -88,6 +88,8 @@ def test_toy_run_takes_every_phase_in_order(smoke, monkeypatch, capsys):
     assert by_phase["infer"]["n_utts"] == 32
     # both builds of the H=1760 scan ran, 2 rows x 6 steps of them
     assert by_phase["reference"]["gru_builds_fwd_values"] == 2 * 6 * 1760
+    # ... its recurrent weight gradient was read at three precisions
+    assert by_phase["reference"]["dw_h_rows"] == 2 * 6
     # ... and ax_k1's attention in both forms at its published widths
     assert by_phase["reference"]["mla_positions_compared"] == 2
     assert by_phase["reference"]["mla_forms_rms_rel"] \
@@ -136,6 +138,29 @@ def test_scan_builds_holds_both_builds_to_its_limits(smoke, monkeypatch,
     monkeypatch.setattr(smoke, limit, zero)
     with pytest.raises(SystemExit, match=said):
         smoke.scan_builds(True)
+
+
+@pytest.mark.parametrize("limit, to", [
+    ("DW_H_LIMIT", 0.0), ("DW_H_TIMES_UNDER_NOISE", 1e9)])
+def test_dw_h_precision_holds_the_contraction_to_its_limits(
+        smoke, monkeypatch, limit, to):
+    """ds2_full's recurrent weight gradient on the operands of a real
+    backward pass, 2 rows x 6 steps of it. On the CPU every precision
+    is float32 arithmetic (1e-7 from the float64 sum, where a chip's
+    three bf16 passes read 1e-5, PERF.md section 6, PR 37), a
+    thousandth of the 1e-3 by which the bf16-dot program lies from the
+    all-float32 one: both limits pass, and with either at nothing the
+    comparison it guards ends the run."""
+    monkeypatch.setattr(smoke, "SCAN_CALL", (2, 6))
+    read = smoke.dw_h_precision(True)
+    assert read["dw_h_rows"] == 12
+    assert read["dw_h_high_max_rel"] * smoke.DW_H_TIMES_UNDER_NOISE \
+        < read["dw_h_bf16_to_float32_max_rel"]
+    assert {k for k in read if k.endswith("_ms")} == {
+        "dw_h_highest_ms", "dw_h_high_ms", "dw_h_default_ms"}
+    monkeypatch.setattr(smoke, limit, to)
+    with pytest.raises(SystemExit, match="from the float64 sum"):
+        smoke.dw_h_precision(True)
 
 
 def test_attention_forms_holds_the_two_forms_to_its_limit(smoke,

@@ -381,13 +381,15 @@ def test_gru_pallas_blocked_grads_match_scan(force_blocked, reverse, h):
                                    rtol=1e-4, atol=1e-4, err_msg=name)
 
 
-def _scan_eqns(fn, *args):
-    """Every ``pallas_call`` equation ``fn`` traces to, in program
-    order, a ``custom_vjp``'s own jaxpr included."""
+def _eqns(fn, *args):
+    """Every equation ``fn`` traces to, in program order, a
+    ``custom_vjp``'s own jaxpr included and a Pallas kernel's body
+    left out."""
     def walk(jaxpr):
         for e in jaxpr.eqns:
+            yield e
             if e.primitive.name == "pallas_call":
-                yield e
+                continue
             for value in e.params.values():  # a custom_vjp's own jaxpr
                 inner = getattr(value, "jaxpr", value)
                 inner = getattr(inner, "jaxpr", inner)
@@ -395,6 +397,13 @@ def _scan_eqns(fn, *args):
                     yield from walk(inner)
 
     return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _scan_eqns(fn, *args):
+    """Every ``pallas_call`` equation ``fn`` traces to, in program
+    order."""
+    return [e for e in _eqns(fn, *args)
+            if e.primitive.name == "pallas_call"]
 
 
 def _scan_calls(fn, *args):
@@ -571,8 +580,9 @@ def test_gru_blocked_bwd_builds_stay_together_over_300_steps(
     their last bit and then feeds the next step: the builds part by up
     to 1.5e-3, which is how far EACH lies from the XLA scan (``dxproj``
     1.3e-3 / 1.5e-3, ``db_h`` 0.8e-3 / 0.8e-3; ``dw_h`` 3.6e-2 both:
-    the scan's own transposed dot takes bf16 operands where the
-    kernels' ``dW_h`` runs at HIGHEST). So neither build is the better
+    the scan's own transposed dot rounds both operands to bf16, 8
+    bits, where the kernels' ``dW_h`` contracts them as float32 at
+    ``HIGH``, 16 bits: ``recurrent_dw``). So neither build is the better
     one there, and the scan bounds both. On the chip, at the cell's
     H=1760 and 850 steps, ``chip_smoke.py`` ``scan_builds`` reads the
     same picture (PERF.md section 6, PR 31). The limits are two to
@@ -958,7 +968,8 @@ def test_gru_bf16_dw_closer_to_truth_than_oracle():
     grad_rel_errs[1] ~ 0.15 is kernel-vs-oracle DISTANCE at bf16, and
     the oracle is the noisy side — it rounds h_prev to bf16 in its
     per-step outer products, while the kernel's dW einsum contracts
-    f32 h_prev with f32 dgates at HIGHEST precision. Pin the bound:
+    f32 h_prev with f32 dgates at HIGH precision, 16 bits of each
+    (``recurrent_dw``; on the CPU, float32 arithmetic). Pin the bound:
     against the f32-truth grads, the kernel's dW error must stay an
     order of magnitude under the oracle's bf16 noise level."""
     import jax
@@ -991,6 +1002,121 @@ def test_gru_bf16_dw_closer_to_truth_than_oracle():
     orac_err = float(np.abs(orac - truth).max()) / denom
     assert kern_err < 0.01, kern_err   # kernel tracks f32 truth
     assert kern_err < orac_err, (kern_err, orac_err)  # and beats oracle
+
+
+def _recurrent_dw_dots(fn, *args):
+    """Every ``dot_general`` that ``fn`` traces to OUTSIDE its Pallas
+    calls (the kernels' own per-step matmuls are not weight
+    gradients), as ``(result shape, precision)``."""
+    return [(e.outvars[0].aval.shape, e.params["precision"])
+            for e in _eqns(fn, *args)
+            if e.primitive.name == "dot_general"]
+
+
+@pytest.mark.parametrize("dot_dtype, want", [
+    (None, jax.lax.Precision.HIGHEST),
+    ("bfloat16", jax.lax.Precision.HIGH)])
+@pytest.mark.parametrize("scan, gates, matrices", [
+    ("gru_scan_pallas", 3, 1), ("bigru_scan_pallas", 3, 2),
+    ("lstm_scan_pallas", 4, 1)])
+def test_recurrent_dw_precision_follows_the_dot_type(scan, gates,
+                                                     matrices, dot_dtype,
+                                                     want):
+    """The recurrent weight gradient of every float scan kernel is ONE
+    contraction over T*B outside the kernel (``recurrent_dw``), and
+    its precision is what the dot type states: a float32 model keeps
+    ``HIGHEST`` (six bf16 passes on the MXU), a bf16 model takes
+    ``HIGH`` (three), whose error lies under the noise the bf16
+    recurrence has already put into both operands
+    (test_three_bf16_products_hold_the_dw_limits). None is left at
+    ``DEFAULT``, one pass with both operands rounded to 8 bits, which
+    is the loss of accuracy that
+    test_gru_bf16_dw_closer_to_truth_than_oracle pins."""
+    from deepspeech_tpu.ops import lstm_pallas, rnn_pallas
+
+    fn = getattr(lstm_pallas if gates == 4 else rnn_pallas, scan)
+    b, t, h = 2, 5, 8
+    S = jax.ShapeDtypeStruct
+    weights = (S((h, gates * h), jnp.float32),
+               S((gates * h,), jnp.float32)) * matrices
+    tail = (True, dot_dtype) if matrices == 2 else (
+        False, True, dot_dtype)
+
+    def train(xp, m, *w):
+        ys, vjp = jax.vjp(lambda *a: fn(*a, *tail), xp, m, *w)
+        return vjp(ys)
+
+    dots = _recurrent_dw_dots(
+        train, S((b, t, gates * h), jnp.float32), S((b, t), jnp.float32),
+        *weights)
+    assert dots == [((h, gates * h), (want, want))] * matrices
+
+
+def test_three_bf16_products_hold_the_dw_limits():
+    """The arithmetic of ``Precision.HIGH`` off the chip, on operands
+    of a real backward pass: a GRU layer in bf16 dots over the cell's
+    T*B = 850 * 32 = 27,200 rows at a narrow H (the operands that
+    ``recurrent_dw`` is handed under a signed cotangent, as CTC's is:
+    ``h_prev`` in (-1, 1), ``dgates`` small, signed and uneven over
+    time, so that the sum cancels as the cell's does; under
+    ``sum(ys ** 2)`` it hardly cancels and every form reads eight
+    times better).
+
+    Each float32 operand is split once, ``hi = bf16(x)`` and ``mid =
+    bf16(x - hi)``, 16 significant bits together; the three-pass
+    product is ``hi*hi + hi*mid + mid*hi`` (the MXU accumulates in
+    float32; float64 here, so only the operands' truncation is read).
+    Against the float64 sum of the SAME float32 operands it must hold
+    the limits ISSUE 37 fixed before any reading: largest error over
+    largest value <= 1e-4 and rms error over rms value <= 1e-4: a
+    twentieth of the 2e-3 by which one bf16 rounding moves an operand.
+    (The issue's third limit, 20 times under the noise the recurrence
+    has left in dW_h itself, needs both programs at the cell's width:
+    the chip read 23 times, PERF.md section 6, PR 37.) The one-pass
+    form (``hi*hi``: ``DEFAULT``) must NOT hold them: it rounds both
+    operands to 8 bits, the recurrence's own rounding a second time.
+    Readings here: three passes 4.6e-6 / 4.2e-6, one pass 2.4e-3 /
+    2.3e-3; on the chip at H=1760 1.4e-5 / 1.2e-5 and 2.0e-3 / 2.2e-3."""
+    from deepspeech_tpu.ops import rnn_pallas
+
+    b, t, h = 32, 850, 8
+    rng = np.random.default_rng(37)
+    xproj, _, w_h, b_h = _rand_gru(rng, b, t, h)
+    lens = rng.integers(600, t + 1, size=b)  # the cell's 12-16.5 of 17 s
+    mask = jnp.asarray(np.arange(t)[None] < lens[:, None], jnp.float32)
+    ct = jnp.asarray(rng.normal(size=(b, t, h)), jnp.float32)
+    operands = []
+    real = rnn_pallas.recurrent_dw
+
+    def keep(h_prev, dgates, dot):
+        operands.append((np.asarray(h_prev, np.float64).reshape(-1, h),
+                         np.asarray(dgates, np.float64).reshape(
+                             -1, 3 * h)))
+        return real(h_prev, dgates, dot)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(rnn_pallas, "recurrent_dw", keep)
+        jax.grad(lambda w: jnp.sum(ct * gru_scan_pallas(
+            xproj, mask, w, b_h, False, True, "bfloat16")))(w_h)
+    (h_prev, dgates), = operands
+    assert h_prev.shape == (27200, h)
+
+    def split(x):
+        hi = x.astype(jnp.bfloat16).astype(np.float64)
+        return hi, (x - hi).astype(jnp.bfloat16).astype(np.float64)
+
+    (a_hi, a_mid), (g_hi, g_mid) = split(h_prev), split(dgates)
+    exact = h_prev.T @ dgates
+    three = a_hi.T @ g_hi + a_hi.T @ g_mid + a_mid.T @ g_hi
+    one = a_hi.T @ g_hi
+
+    def errors(got):
+        d = got - exact
+        return (np.abs(d).max() / np.abs(exact).max(),
+                np.sqrt(np.mean(d ** 2) / np.mean(exact ** 2)))
+
+    assert max(errors(three)) <= 1e-4, errors(three)
+    assert max(errors(one)) > 1e-4, errors(one)
 
 
 @pytest.mark.parametrize("dot_dtype", [None, "bfloat16"])

@@ -130,6 +130,26 @@ def test_float32_scans_at_1760_compile_streamed(v5e_chip, case):
                                   ("gru_scan_fwd", "blocked")]
 
 
+@pytest.mark.parametrize("case, passes", [
+    ("gru_h1760", "high"), ("gru_h1760_f32", "highest")])
+def test_recurrent_dw_passes_follow_the_dot_type(v5e_chip, case, passes):
+    """The GRU's recurrent weight gradient at ds2_full's width, as the
+    TPU compiler is handed it: ONE ``f32[1760,5280]`` contraction of
+    the VJP, at ``high`` (three bf16 passes of the MXU) where the scan
+    multiplies in bf16 and at ``highest`` (six) where it multiplies in
+    float32. Six passes at bf16 dots were 233 ms of ds2_full's 572 ms
+    step; the compiler's own estimate for the three-pass form is half
+    the six-pass one's (12.4 M cycles against 24.3 M at the cell's 32
+    rows, PERF.md section 6, PR 37)."""
+    from aot_kernels import compile_case, kernel_cases
+
+    text = compile_case(kernel_cases()[case], v5e_chip).as_text()
+    dws = [line for line in text.splitlines() if re.search(
+        r"= f32\[1760,5280(,1)?\]\S* (convolution|dot)\(", line)]
+    assert len(dws) == 1, dws
+    assert f"operand_precision={{{passes},{passes}}}" in dws[0]
+
+
 def test_ctc_vjp_folds_gamma_without_a_scatter(v5e_chip):
     """CTC backward sums gamma from the extended labels into the
     vocabulary with one f32-exact contraction. A TPU runs a scatter-add

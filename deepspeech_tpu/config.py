@@ -180,6 +180,24 @@ class ModelConfig:
     rope_yarn_original: int = 4096
     rope_yarn_betas: Tuple[float, float] = (32.0, 1.0)
     rope_yarn_mscales: Tuple[float, float] = (1.0, 1.0)
+    # Manifold-constrained hyper-connections (``models/mhc.py``;
+    # ``hc_mult`` and its siblings of ``model_type: xing4_0``): the
+    # residual is ``hc_streams`` streams wide, each sub-layer reads a
+    # learned mix of them and writes back through a post-mix and a
+    # residual mix that ``hc_sinkhorn_iters`` Sinkhorn-Knopp rounds
+    # (``hc_eps`` in each division) make doubly stochastic, from
+    # exp(logits clamped to ``hc_res_clamp``). 1 stream: the plain
+    # ``h + f(norm(h))``, and none of the rest is read.
+    hc_streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # Multi-token-prediction modules (``num_nextn_predict_layers``)
+    # after the last layer: one more expert layer each, over the next
+    # input's embedding and the model's last hidden state, that
+    # predicts the token after next. Serving drafts with it inside the
+    # greedy loop (``decode/lm_greedy.py``); 0: no module, no draft.
+    lm_draft_layers: int = 0
 
     @property
     def time_stride(self) -> int:
@@ -583,6 +601,50 @@ def ax_k1() -> Config:
     )
 
 
+def xing4_29b_a4b() -> Config:
+    """Xing4.0-29B-A4B (``model_type: xing4_0``,
+    https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/config.json)
+    as a decoder-only speech recogniser that is SERVED
+    (``decode.mode="lm_greedy"``), every width as published: hidden
+    3584 in FOUR residual streams mixed by manifold-constrained
+    hyper-connections (20 Sinkhorn rounds), 32 heads of latent
+    attention (query rank 768, key/value rank 512, head sizes 128 | 64
+    | 128, YaRN factor 64 over 4096), dense SwiGLU 9216 in the leading
+    layer, then 64 sigmoid-scored routed experts of 1024, top-4 by
+    score + selection bias, weights normalised times 2, one shared
+    expert, an untied head over all 131,072 ids, and one
+    multi-token-prediction module that drafts inside the greedy loop.
+    One chip holds EVERY expert and the whole vocabulary; depth alone
+    is cut, to the leading dense layer and 6 expert layers (plus the
+    module). ``benchmark/configs/xing4_29b_a4b.json`` has the published
+    keys beside these and every reading that is this repo's own."""
+    c = Config(name="xing4_29b_a4b")
+    return _replace(
+        c,
+        model=_replace(
+            c.model, conv_layers=(), conv_channels=(), rnn_layers=0,
+            bidirectional=False, rnn_batch_norm=False, frame_stack=8,
+            vocab_size=131072, lfm_hidden=3584,
+            lfm_layer_types=("latent_attention",) * 7,
+            lfm_dense_layers=1, lfm_heads=32, lfm_kv_heads=32,
+            lfm_ffn_dim=9216, lfm_expert_dim=1024, lfm_experts=64,
+            lfm_top_k=4, lfm_rope_theta=1e4, lfm_norm_eps=1e-6,
+            experts_held=64, expert_offset=0, moe_rows_bound=0.0,
+            lfm_seq_positions=288, lm_tied_head=False, moe_groups=1,
+            moe_groups_kept=1, moe_select_bias=True,
+            moe_routed_scale=2.0, moe_shared_experts=1,
+            mla_q_rank=768, rope_yarn_factor=64.0, hc_streams=4,
+            hc_sinkhorn_iters=20, hc_eps=1e-6,
+            hc_res_clamp=(-30.0, 30.0), lm_draft_layers=1),
+        data=_replace(c.data, batch_size=256, bucket_frames=(1696,),
+                      max_label_len=64),
+        train=_replace(c.train, objective="lm", optimizer="adamw",
+                       learning_rate=1e-4, weight_decay=0.0,
+                       grad_clip_norm=1.0, warmup_steps=100),
+        decode=_replace(c.decode, mode="lm_greedy"),
+    )
+
+
 PRESETS = {
     "ds2_small": ds2_small,
     "ds2_full": ds2_full,
@@ -593,6 +655,7 @@ PRESETS = {
     "rnnt_he2019": rnnt_he2019,
     "lfm2_24b_a2b": lfm2_24b_a2b,
     "ax_k1": ax_k1,
+    "xing4_29b_a4b": xing4_29b_a4b,
 }
 
 

@@ -16,6 +16,23 @@ A call transcribes a batch of utterances in two compiled programs:
              loop ends when every stream has. The
              ids come back to the host once a call.
 
+With a draft module (``model.lm_draft_layers`` = 1: a
+multi-token-prediction module, ``models/lfm2.DraftModule``) the loop
+DRAFTS FOR ITSELF. A stream holds its last accepted token and a draft
+of the next one; a step runs the model over both positions at once
+(``verify``), takes the argmax at each, and if the first equals the
+draft it emits both and moves two positions, else the first alone and
+one (the rejected draft's cache rows are overwritten by the next step).
+The module then runs over the same two positions with the tokens just
+emitted, keeps its own cache (one more array after the layers'), and
+its argmax at the last accepted position is the next draft; the first
+draft comes from prefill, where the module follows the prefix. Every
+stream has its own pointer, so streams finish after different numbers
+of STEPS as well as of tokens. The emitted ids are those of the loop
+without drafts: a draft changes how many steps a transcript takes, never
+the transcript. A forced input is by definition the next input: it
+takes the draft's place and is accepted.
+
 The step takes its input tokens through the argument ``forced [B, T]``
 (-1: the stream's own argmax), and gives out, for the few streams named
 in ``watch``, every step's logits, last-layer router scores and chosen
@@ -43,10 +60,12 @@ from ..models.lfm2 import (cached_kinds, create_lfm2_model,
 WATCH = 8     # streams whose per-step logits the programs give out
 
 
-def _watched(mid: dict, rows, layers: List[str]) -> dict:
+def _watched(mid: dict, rows, layers: List[str], mixed: str = "") -> dict:
     """Of a pass's sown router outputs, the ``rows`` of the batch: the
     last expert layer's scores and combine weights and every expert
-    layer's chosen sets."""
+    layer's chosen sets; and the feed-forward's hyper-connection
+    coefficients of the layer ``mixed``, where the residual has
+    streams."""
     if not layers:
         return {}
 
@@ -54,9 +73,12 @@ def _watched(mid: dict, rows, layers: List[str]) -> dict:
         x = mid[name]["moe"][key][0]
         return x.reshape((-1, rows[1]) + x.shape[1:])[rows[0]]
 
-    return {"scores": of(layers[-1], "scores"),
-            "weights": of(layers[-1], "weights"),
-            "chosen": jnp.stack([of(n, "experts") for n in layers])}
+    out = {"scores": of(layers[-1], "scores"),
+           "weights": of(layers[-1], "weights"),
+           "chosen": jnp.stack([of(n, "experts") for n in layers])}
+    for key in ("h_pre", "h_post", "h_res") if mixed else ():
+        out[key] = mid[mixed]["ffn_hc"][key][0][rows[0]]
+    return out
 
 
 class LMGreedy:
@@ -80,8 +102,16 @@ class LMGreedy:
             lambda x: x if x.dtype == dtype else x.astype(dtype), params)
         self.buffers = buffers or {}
         self.steps_max = cfg.data.max_label_len + 1
+        if m.lm_draft_layers > 1:
+            raise NotImplementedError(
+                "the greedy loop drafts with one module; "
+                f"{cfg.name!r} has lm_draft_layers={m.lm_draft_layers}")
+        self.draft = m.lm_draft_layers == 1
         self.sparse = [f"layer{i}" for i in range(len(m.lfm_layer_types))
                        if i >= m.lfm_dense_layers]
+        # The layer whose hyper-connection coefficients are given out.
+        self.mixed = (f"layer{len(m.lfm_layer_types) - 1}"
+                      if m.hc_streams > 1 else "")
         self._cache = None
         self._calls = 0
         self.last_call: Optional[dict] = None
@@ -96,7 +126,7 @@ class LMGreedy:
         rows = min(self.cfg.decode.lm_prefill_rows, features.shape[0])
         feats = jax.lax.dynamic_slice_in_dim(features, offset, rows)
         lens = jax.lax.dynamic_slice_in_dim(feat_lens, offset, rows)
-        (new, a_lens, counters), state = self.model.apply(
+        (new, a_lens, counters, draft), state = self.model.apply(
             {"params": params, "buffers": buffers}, feats, lens,
             method="prefill", mutable=["intermediates"])
         cache = [jax.lax.dynamic_update_slice(c, r.astype(c.dtype),
@@ -107,11 +137,26 @@ class LMGreedy:
         counters["valid_positions"] = jnp.sum(a_lens)
         counters["padded_positions"] = rows * a - jnp.sum(a_lens)
         watch = _watched(state.get("intermediates", {}),
-                         (slice(0, min(WATCH, rows)), a), self.sparse)
-        return cache, a_lens, counters, watch
+                         (slice(0, min(WATCH, rows)), a), self.sparse,
+                         self.mixed)
+        return cache, a_lens, counters, watch, draft
+
+    def _count(self, acc: dict, counters: dict) -> None:
+        """A pass's expert-layer counters into the loop's."""
+        for k, v in counters.items():
+            acc[k] = (jnp.maximum(acc[k], v)
+                      if k in ("rows_high_water", "rows_capacity")
+                      else acc[k] + v)
+        if self.sparse:
+            acc["experts_hit"] += jnp.sum(
+                counters["expert_pairs"] > 0, axis=-1)
 
     def _decode(self, params, buffers, cache, a_lens, max_tokens, forced,
-                watch, ignore_end):
+                watch, ignore_end, draft=None):
+        if self.draft:
+            return self._decode_drafting(
+                params, buffers, cache, a_lens, max_tokens, forced, watch,
+                ignore_end, draft)
         m = self.cfg.model
         b, t = forced.shape
         variables = {"params": params, "buffers": buffers}
@@ -141,13 +186,7 @@ class LMGreedy:
             acc["idle_slot_steps"] += jnp.sum(~active)
             acc["cache_rows_read"] += jnp.sum(
                 jnp.where(active, a_lens + j + 1, 0))
-            for k, v in counters.items():
-                acc[k] = (jnp.maximum(acc[k], v)
-                          if k in ("rows_high_water", "rows_capacity")
-                          else acc[k] + v)
-            if self.sparse:
-                acc["experts_hit"] += jnp.sum(
-                    counters["expert_pairs"] > 0, axis=-1)
+            self._count(acc, counters)
             mid = _watched(state.get("intermediates", {}), (watch, 1),
                            self.sparse)
             mid["logits"] = logits[watch][:, None, :]
@@ -179,19 +218,139 @@ class LMGreedy:
             cond, body, carry)
         return out, cache, acc, seen
 
+    def _decode_drafting(self, params, buffers, cache, a_lens, max_tokens,
+                         forced, watch, ignore_end, draft):
+        """The loop with one draft module: ``cache`` is the layers'
+        arrays and then the module's, ``draft [B]`` each stream's first
+        draft (prefill's). Per-stream pointers ``j`` (the index of the
+        stream's input token); a step handles tokens ``j`` and ``j+1``
+        of each active stream."""
+        b, t = forced.shape
+        w = watch.shape[0]
+        variables = {"params": params, "buffers": buffers}
+        stream, two = jnp.arange(b), jnp.arange(2)
+
+        def forced_at(j):
+            return jnp.take_along_axis(
+                forced, jnp.minimum(j, t - 1)[:, None], axis=1)[:, 0]
+
+        def passes(tokens, draft, j, active, cache):
+            """A step's two passes over tokens ``j, j+1`` of every
+            stream: the model on ``(tokens, draft)``, then the module
+            on the inputs that follow them."""
+            ahead, later = forced_at(j + 1), forced_at(j + 2)
+            second = active & (j + 1 < max_tokens)
+            # A forced input takes the draft's place, and is accepted.
+            guess = jnp.where(ahead >= 0, ahead, draft)
+            pos = (a_lens + j)[:, None] + two[None, :]
+            with jax.named_scope("verify"):
+                (logits, hidden, main, counters), state = self.model.apply(
+                    variables, jnp.stack([tokens, guess], axis=1), pos,
+                    jnp.stack([active, second], axis=1), cache[:-1],
+                    method="verify", mutable=["intermediates"])
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, 2]
+            # Tokens j+1 and j+2: forced, or the model's own.
+            inputs = jnp.stack([jnp.where(ahead >= 0, ahead, nxt[:, 0]),
+                                jnp.where(later >= 0, later, nxt[:, 1])],
+                               axis=1)
+            # The end id ends a stream whose next input is its own.
+            ends = ~ignore_end & (nxt == 0) & (
+                jnp.stack([ahead, later], axis=1) < 0)
+            accept = second & ~ends[:, 0] & (inputs[:, 0] == guess)
+            # The module drafts where the stream goes on after a token.
+            goes_on = jnp.stack(
+                [second, accept & (j + 2 < max_tokens)], axis=1) & ~ends
+            with jax.named_scope("mtp_draft"):
+                guesses, own, drafted = self.model.apply(
+                    variables, inputs, hidden, pos, goes_on, cache[-1],
+                    method="draft")
+            # The layers' counters and then the module's.
+            counters = jax.tree.map(
+                lambda x, y: jnp.concatenate([x, y[None]]), counters,
+                drafted)
+            mid = _watched(state.get("intermediates", {}), (watch, 2),
+                           self.sparse, self.mixed)
+            if "chosen" in mid:  # [layers, W, 2, k] -> [W, 2, layers, k]
+                mid["chosen"] = jnp.moveaxis(mid["chosen"], 0, 2)
+            mid["logits"] = logits[watch]
+            mid["draft_logits"] = guesses[watch]
+            drafts = jnp.argmax(guesses, axis=-1).astype(jnp.int32)
+            ends = ends[:, 0] | (accept & ends[:, 1])
+            return (counters, mid, main + [own], nxt, inputs, drafts,
+                    second, accept, ends)
+
+        def body(carry):
+            i, j, tokens, draft, done, out, cache, acc, seen = carry
+            active = ~done
+            (counters, mid, cache, nxt, inputs, drafts, second, accept,
+             ends) = passes(tokens, draft, j, active, cache)
+            slot = jnp.stack([jnp.where(active, j, t),
+                              jnp.where(accept, j + 1, t)], axis=1)
+            out = out.at[stream[:, None], slot].set(nxt, mode="drop")
+            emitted = active.astype(jnp.int32) + accept
+            done = done | (j + emitted >= max_tokens) | ends
+            tokens = jnp.where(accept, inputs[:, 1], inputs[:, 0])
+            draft = jnp.where(accept, drafts[:, 1], drafts[:, 0])
+            at = a_lens + j
+            acc = dict(acc)
+            acc["steps"] += 1
+            acc["tokens"] += emitted
+            acc["idle_slot_steps"] += jnp.sum(~active)
+            acc["cache_rows_read"] += jnp.sum(
+                jnp.where(active, at + 1, 0) + jnp.where(accept, at + 2, 0))
+            acc["verify_positions"] += jnp.sum(active) + jnp.sum(second)
+            acc["draft_positions"] += jnp.sum(second)
+            acc["draft_accepted"] += jnp.sum(accept)
+            acc["rejected_rows_overwritten"] += jnp.sum(
+                second & ~accept & ~done)
+            acc["drafts"] += jnp.sum(~done)
+            self._count(acc, counters)
+            at_w = slot[watch]
+            seen = {k: seen[k].at[jnp.arange(w)[:, None], at_w].set(
+                mid[k], mode="drop") for k in seen}
+            return (i + 1, j + emitted, tokens, draft, done, out, cache,
+                    acc, seen)
+
+        def cond(carry):
+            return (carry[0] < t) & ~jnp.all(carry[4])
+
+        first = jnp.where(forced[:, 0] >= 0, forced[:, 0], 0)
+        zero = jnp.zeros(b, jnp.int32)
+        shapes = jax.eval_shape(
+            lambda c: passes(first, draft, zero, max_tokens > 0, c)[:2],
+            cache)
+        acc = {k: jnp.zeros(v.shape, v.dtype) for k, v in shapes[0].items()}
+        acc.update({k: jnp.int32(0) for k in (
+            "steps", "idle_slot_steps", "cache_rows_read",
+            "verify_positions", "draft_positions", "draft_accepted",
+            "rejected_rows_overwritten")},
+            tokens=zero, drafts=jnp.sum(max_tokens > 0),  # prefill's
+            experts_hit=jnp.zeros(len(self.sparse) + 1, jnp.int32))
+        # Per watched stream and TOKEN (a step writes two of them).
+        seen = {k: jnp.zeros((w, t) + v.shape[2:], v.dtype)
+                for k, v in shapes[1].items()}
+        carry = (jnp.int32(0), zero, first, draft, max_tokens <= 0,
+                 jnp.zeros((b, t), jnp.int32), cache, acc, seen)
+        _, _, _, _, _, out, cache, acc, seen = jax.lax.while_loop(
+            cond, body, carry)
+        seen["chosen"] = jnp.moveaxis(seen["chosen"], 2, 0)
+        return out, cache, acc, seen
+
     # -- a call --------------------------------------------------------------
 
     def cache_for(self, rows: int, frames: int) -> list:
         """The cache of ``rows`` streams whose prefix is ``frames``
-        feature frames: ``model.lfm_seq_positions`` rows a stream, or
-        (0) the least that hold the prefix and every step."""
+        feature frames, one array a layer and one a draft module:
+        ``model.lfm_seq_positions`` rows a stream, or (0) the least
+        that hold the prefix and every step."""
         m = self.cfg.model
         positions = seq_positions(m, frames, self.cfg.data.max_label_len)
         shape = (rows, positions, m.mla_kv_rank + m.mla_rope_dim)
         if self._cache is None or self._cache[0].shape != shape:
             self._cache = None  # free the old one first
             self._cache = [jnp.zeros(shape, jnp.dtype(m.dtype))
-                           for _ in m.lfm_layer_types]
+                           for _ in range(len(m.lfm_layer_types)
+                                          + m.lm_draft_layers)]
             obs.registry().gauge("lm_cache_bytes", sum(
                 c.nbytes for c in self._cache))
         cache, self._cache = self._cache, None
@@ -219,16 +378,20 @@ class LMGreedy:
             watch = np.arange(min(WATCH, b), dtype=np.int32)
         self._calls += 1
         call = self._calls  # what the spans of one call share
+        # The last call's watched outputs (with a vocabulary of 131,072
+        # half a GB) are not held through this one: on a full chip the
+        # loop's dispatch then waits for memory.
+        self.last_call = None
         t0 = time.perf_counter()
         with obs.span("infer.transcribe", rows=b, call=call):
             with obs.span("infer.cache", call=call):
                 cache = self.cache_for(b, features.shape[1])
             t_cache = time.perf_counter()
-            a_lens, pre, pre_watch = [], [], None
+            a_lens, pre, pre_watch, drafts = [], [], None, []
             for i in range(b // sub):
                 with obs.span("infer.prefill", rows=sub, call=call):
                     with obs.span("infer.prefill.dispatch", call=call):
-                        cache, a, counters, mid = self.prefill(
+                        cache, a, counters, mid, draft = self.prefill(
                             self.params, self.buffers, cache, features,
                             feat_lens, i * sub)
                     if obs.tracer.enabled:
@@ -236,6 +399,7 @@ class LMGreedy:
                             jax.block_until_ready(cache)
                 a_lens.append(a)
                 pre.append(counters)
+                drafts.append(draft)
                 pre_watch = mid if i == 0 else pre_watch
             t1 = time.perf_counter()
             with obs.span("infer.decode", rows=b, call=call):
@@ -245,7 +409,8 @@ class LMGreedy:
                         jnp.concatenate(a_lens), max_tokens,
                         jnp.asarray(forced, jnp.int32),
                         jnp.asarray(watch, jnp.int32),
-                        jnp.asarray(cfg.decode.lm_ignore_end))
+                        jnp.asarray(cfg.decode.lm_ignore_end),
+                        jnp.concatenate(drafts) if self.draft else None)
                 t2 = time.perf_counter()
                 with obs.span("infer.decode.fetch", call=call):
                     ids, acc, pre = jax.device_get((ids, acc, pre))

@@ -17,7 +17,8 @@ The layer has TWO FORMS that agree:
 
 - over a whole sequence (training, prefill) the latent rows are
   expanded to per-head keys and values;
-- for one new position against a cache (a decode step) they never
+- for one new position against a cache (a decode step), or a few
+  consecutive ones (a draft behind the token it follows), they never
   are: ``kv_b``'s key half is absorbed into the query (``q~_h =
   q_nope,h W_UK,h^T``, ``kv_rank`` wide), the scores are taken against
   the cached rows themselves, the probabilities weigh the cached
@@ -96,10 +97,10 @@ def rms(x, gain, eps: float):
 class LatentAttention(nn.Module):
     """``__call__(x [B, S, D], pos [B, S])`` is the sequence form and
     returns the output and the rows to cache ``[B, S, kv_rank + rope]``;
-    with ``cache [B, R, kv_rank + rope]`` (S must be 1) it is the decode
-    form: the new row is written at ``pos`` and the position attends
-    to rows ``0 .. pos`` of the cache; it returns the output and the
-    cache."""
+    with ``cache [B, R, kv_rank + rope]`` it is the decode form over S
+    new positions a stream (1, or a few consecutive ones): the new
+    rows are written at ``pos`` and each position attends to rows ``0
+    .. pos`` of the cache; it returns the output and the cache."""
 
     cfg: ModelConfig
 
@@ -145,8 +146,23 @@ class LatentAttention(nn.Module):
             out = jnp.einsum("bhqk,bkhv->bqhv", probs, kv_h[..., dn:])
             return jnp.dot(out.reshape(b, s, nh * dv), w_o), rows
 
-        if s != 1:
-            raise ValueError("the decode form takes one position a row")
+        if s > 1:
+            # q consecutive new positions a stream (a draft and what it
+            # follows): all q rows are written first, and a position's
+            # own mask hides the rows after it. One position (below)
+            # keeps its own, three-dimensional contractions.
+            cache = cache.at[jnp.arange(b)[:, None], pos].set(rows)
+            q_lat = jnp.einsum("bqhn,chn->bqhc", q_nope, w_kvb[..., :dn])
+            qk = jnp.concatenate([q_lat, q_rope], axis=-1)
+            scores = jnp.einsum("bqhc,brc->bhqr", qk, cache,
+                                preferred_element_type=jnp.float32)
+            seen = jnp.arange(cache.shape[1])[None, None, :] \
+                <= pos[:, :, None]
+            scores = jnp.where(seen[:, None], scores * scale, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            mixed = jnp.einsum("bhqr,brc->bqhc", probs, cache[..., :rkv])
+            out = jnp.einsum("bqhc,chv->bqhv", mixed, w_kvb[..., dn:])
+            return jnp.dot(out.reshape(b, s, nh * dv), w_o), cache
         at = pos[:, 0]
         cache = cache.at[jnp.arange(b), at].set(rows[:, 0])
         q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_kvb[..., :dn])
@@ -161,19 +177,21 @@ class LatentAttention(nn.Module):
         return jnp.dot(out.reshape(b, 1, nh * dv), w_o), cache
 
 
-def both_forms(cfg: ModelConfig, params, x, at):
+def both_forms(cfg: ModelConfig, params, x, at, q: int = 1):
     """One layer on ``x [B, S, D]`` in both forms: the sequence form
-    over all positions, then the decode form for each position of
-    ``at`` (a numpy index array) against the cache the sequence form
-    gave, every (row, position) a stream of its own. Returns the two
-    outputs at those positions, ``[B, len(at), D]`` each (the checks of
-    ``chip_smoke.py`` and of the benchmark's driver compare them)."""
+    over all positions, then the decode form for the ``q`` consecutive
+    positions from each of ``at`` (a numpy index array) against the
+    cache the sequence form gave, every (row, start) a stream of its
+    own. Returns the two outputs at those positions, ``[B, len(at) * q,
+    D]`` each (the checks of ``chip_smoke.py`` and of the benchmark's
+    drivers compare them)."""
     layer = LatentAttention(cfg)
     b, s, _ = x.shape
     pos = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     seq, rows = layer.apply({"params": params}, x, pos)
-    one = x[:, at].reshape(b * len(at), 1, -1)
+    span = np.asarray(at)[:, None] + np.arange(q)[None, :]
+    one = x[:, span.reshape(-1)].reshape(b * len(at), q, -1)
     dec, _ = layer.apply({"params": params}, one,
-                         jnp.tile(jnp.asarray(at), b)[:, None],
+                         jnp.tile(jnp.asarray(span), (b, 1)),
                          jnp.repeat(rows, len(at), axis=0))
-    return dec.reshape(b, len(at), -1), seq[:, at]
+    return dec.reshape(b, span.size, -1), seq[:, span.reshape(-1)]

@@ -1,12 +1,17 @@
 """The decoder-only speech recogniser, and the LFM2 block.
 
-``LFM2ASR`` is the shell of two families, the block chosen by the
-preset's ``lfm_layer_types``: LFM2's layers below, and the A.X-K1
-block's latent attention (``models/axk1.py``) with a shared expert
-beside the routed ones and a head of its own. ``hidden`` / ``loss``
-are the training path; ``prefill`` and ``step`` the serving path
-through a cache (``decode/lm_greedy.py``), for the layer kinds that
-have one.
+``LFM2ASR`` is the shell of three families, the block chosen by the
+preset's data: LFM2's layers below; the A.X-K1 block's latent attention
+(``models/axk1.py``) with a shared expert beside the routed ones and a
+head of its own; and the Xing4.0 block, which is the second over
+``hc_streams`` residual streams mixed by hyper-connections
+(``models/mhc.py``) with ``lm_draft_layers`` multi-token-prediction
+modules (``DraftModule``) after the last layer. ``hidden`` / ``loss``
+are the training path (the draft modules are not trained here);
+``prefill`` and ``step`` the serving path through a cache
+(``decode/lm_greedy.py``), for the layer kinds that have one, and
+``verify`` / ``draft`` its form over a few positions a stream, for a
+loop that drafts for itself.
 
 It is what ``train.objective="lm"`` trains: the acoustic
 frames of an utterance, stacked and projected, are the prefix of the
@@ -37,6 +42,7 @@ import jax.numpy as jnp
 
 from ..config import ModelConfig
 from ..ops import moe
+from . import mhc
 from .axk1 import LatentAttention
 from .rnn import stack_frames
 
@@ -224,37 +230,86 @@ class SparseExperts(nn.Module):
 
 
 class DecoderLayer(nn.Module):
-    """``h + operator(norm(h))``, then ``h + ffn(norm(h))``. Returns
+    """``h + operator(norm(h))``, then ``h + ffn(norm(h))``; with
+    ``hc_streams`` > 1 the residual ``h [B, S, n, D]`` is n streams and
+    each of the two sub-layers reads and writes them through its own
+    hyper-connection (``models/mhc.py``). Returns
     the new ``h``, the expert block's counters (None for a dense
     feed-forward) and the layer's cache: the rows of this call's
     positions (the sequence form), or the ``cache`` handed in with the
-    one new row a stream written at ``pos`` (the decode form; latent
+    new rows of each stream written at ``pos`` (the decode form; latent
     attention alone has one). A kind without a cache returns None."""
 
     cfg: ModelConfig
     kind: str      # "conv" | "full_attention" | "latent_attention"
     sparse: bool
 
+    def residual(self, name: str, h, f):
+        """``h`` after the sub-layer ``f`` (``x -> (y, extra)``, its
+        pre-norm inside), and ``extra``."""
+        if self.cfg.hc_streams == 1:
+            y, extra = f(h)
+            return h + y, extra
+        with jax.named_scope("mhc"):
+            h_pre, h_post, h_res = mhc.HyperConnection(
+                self.cfg, name=name)(h)
+            x = mhc.read(h_pre, h)
+        y, extra = f(x)
+        with jax.named_scope("mhc"):
+            return mhc.write(h_res, h_post, h, y), extra
+
     @nn.compact
     def __call__(self, h, valid, pos=None, cache=None):
         cfg = self.cfg
-        x = RMSNorm(cfg.lfm_norm_eps, name="op_norm")(h)
-        if self.kind == "latent_attention":
-            y, cache = LatentAttention(cfg, name="attn")(x, pos, cache)
-            h = h + y
-        elif cache is not None:
-            raise ValueError(f"layer type {self.kind!r} has no cache")
-        elif self.kind == "conv":
-            h = h + ShortConv(cfg, name="conv")(x)
-        elif self.kind == "full_attention":
-            h = h + Attention(cfg, name="attn")(x)
-        else:
+
+        def operator(x):
+            x = RMSNorm(cfg.lfm_norm_eps, name="op_norm")(x)
+            if self.kind == "latent_attention":
+                return LatentAttention(cfg, name="attn")(x, pos, cache)
+            if cache is not None:
+                raise ValueError(f"layer type {self.kind!r} has no cache")
+            if self.kind == "conv":
+                return ShortConv(cfg, name="conv")(x), None
+            if self.kind == "full_attention":
+                return Attention(cfg, name="attn")(x), None
             raise ValueError(f"layer type {self.kind!r}")
-        x = RMSNorm(cfg.lfm_norm_eps, name="ffn_norm")(h)
-        if self.sparse:
-            y, counters = SparseExperts(cfg, name="moe")(x, valid)
-            return h + y, counters, cache
-        return h + SwiGLU(cfg.lfm_ffn_dim, name="ffn")(x), None, cache
+
+        def feed_forward(x):
+            x = RMSNorm(cfg.lfm_norm_eps, name="ffn_norm")(x)
+            if self.sparse:
+                return SparseExperts(cfg, name="moe")(x, valid)
+            return SwiGLU(cfg.lfm_ffn_dim, name="ffn")(x), None
+
+        h, cache = self.residual("op_hc", h, operator)
+        h, counters = self.residual("ffn_hc", h, feed_forward)
+        return h, counters, cache
+
+
+class DraftModule(nn.Module):
+    """A multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
+    section 2.2): at position i, from the model's last hidden state
+    ``h_i`` (the sum of its streams, before the last norm) and the
+    embedding of the NEXT input ``t_{i+1}``, ``z_i = W_eh [norm_e(emb);
+    norm_h(h_i)]`` through one more expert layer of the model's kind
+    (own hyper-connections, own cache row at position i). Returns its
+    normed output, whose logits under the model's head are the
+    distribution of ``t_{i+2}``, the expert block's counters and the
+    cache (rows or array, as ``DecoderLayer``)."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, emb_next, h, valid, pos, cache=None):
+        cfg = self.cfg
+        z = Linear(cfg.lfm_hidden, name="eh_proj")(jnp.concatenate(
+            [RMSNorm(cfg.lfm_norm_eps, name="embed_norm")(emb_next),
+             RMSNorm(cfg.lfm_norm_eps, name="hidden_norm")(h)], axis=-1))
+        x, counters, cache = DecoderLayer(
+            cfg, "latent_attention", True, name="layer")(
+                mhc.fan_out(z, cfg.hc_streams), valid, pos, cache)
+        x = mhc.contract(x, cfg.hc_streams)
+        return RMSNorm(cfg.lfm_norm_eps, name="out_norm")(x), counters, \
+            cache
 
 
 def pack(a_lens, labels, label_lens, s: int):
@@ -288,6 +343,8 @@ class LFM2ASR(nn.Module):
                       name=f"layer{i}")
             for i, kind in enumerate(cfg.lfm_layer_types)]
         self.out_norm = RMSNorm(cfg.lfm_norm_eps)
+        self.drafts = [DraftModule(cfg, name=f"draft{i}")
+                       for i in range(cfg.lm_draft_layers)]
         if not cfg.lm_tied_head:
             self.lm_head = self.param(
                 "lm_head", _INIT, (cfg.vocab_size, cfg.lfm_hidden))
@@ -313,11 +370,13 @@ class LFM2ASR(nn.Module):
         h = jnp.where(audio[..., None], pre,
                       jnp.where(text[..., None], emb, 0))
         pos = jnp.broadcast_to(jnp.arange(s)[None, :], valid.shape)
+        h = mhc.fan_out(h, cfg.hc_streams)
         counters = []
         for layer in self.layers:
             h, c, _ = layer(h, valid, pos)
             if c is not None:
                 counters.append(c)
+        h = mhc.contract(h, cfg.hc_streams)
         layout = {"valid": valid, "targets": targets, "a_lens": a_lens}
         return self.out_norm(h), self.head(), layout, counters
 
@@ -340,21 +399,46 @@ class LFM2ASR(nn.Module):
     def prefill(self, features, feat_lens):
         """The serving path's first half: the audio prefix alone
         (positions ``0 .. a-1`` of each stream) through the layers.
-        Returns each layer's rows to cache ``[B, A, C]``, the prefix
-        lengths and the expert layers' counters."""
+        Returns each layer's rows to cache ``[B, A, C]`` (a draft
+        module's after them), the prefix lengths, the expert layers'
+        counters and, with a draft module, its first draft ``[B]``: the
+        token after the start id. The module's next input at a prefix
+        position is the next projected frame, and the start id's
+        embedding at the last."""
         cfg = self.cfg
         x, a_lens = stack_frames(features, feat_lens, cfg.frame_stack)
-        h = self.prefix(x.astype(jnp.dtype(cfg.dtype)))
-        pos = jnp.broadcast_to(jnp.arange(h.shape[1])[None, :],
-                               h.shape[:2])
+        pre = self.prefix(x.astype(jnp.dtype(cfg.dtype)))
+        pos = jnp.broadcast_to(jnp.arange(pre.shape[1])[None, :],
+                               pre.shape[:2])
         valid = pos < a_lens[:, None]
+        h = mhc.fan_out(pre, cfg.hc_streams)
         rows, counters = [], []
         for layer in self.layers:
             h, c, r = layer(h, valid, pos)
             rows.append(r)
             if c is not None:
                 counters.append(c)
-        return rows, a_lens, stack_counters(counters)
+        draft = None
+        for module in self.drafts:
+            with jax.named_scope("mtp_draft"):
+                ahead = jnp.where(
+                    (pos + 1 < a_lens[:, None])[..., None],
+                    jnp.pad(pre[:, 1:], [(0, 0), (0, 1), (0, 0)]),
+                    self.embed[0].astype(pre.dtype))
+                out, c, r = module(ahead, mhc.contract(h, cfg.hc_streams),
+                                   valid, pos)
+                last = jnp.take_along_axis(
+                    out, jnp.maximum(a_lens - 1, 0)[:, None, None], axis=1)
+                draft = jnp.argmax(self.logits(last[:, 0]), axis=-1
+                                   ).astype(jnp.int32)
+            rows.append(r)
+            counters.append(c)
+        return rows, a_lens, stack_counters(counters), draft
+
+    def logits(self, h):
+        """``h [N, D]`` (normed) against the head, float32."""
+        return jnp.dot(h, self.head().astype(h.dtype).T,
+                       preferred_element_type=jnp.float32)
 
     def step(self, tokens, pos, active, cache):
         """The serving path's second half: one new position a stream
@@ -362,18 +446,52 @@ class LFM2ASR(nn.Module):
         cache. Returns the logits ``[B, V]`` in float32, the cache with
         the new rows and the expert layers' counters; a stream that is
         not ``active`` is not routed."""
-        h = jnp.take(self.embed.astype(jnp.dtype(self.cfg.dtype)),
-                     tokens, axis=0)[:, None, :]
+        n = self.cfg.hc_streams
+        h = mhc.fan_out(jnp.take(
+            self.embed.astype(jnp.dtype(self.cfg.dtype)), tokens,
+            axis=0)[:, None, :], n)
         new, counters = [], []
         for layer, rows in zip(self.layers, cache):
             h, c, rows = layer(h, active[:, None], pos[:, None], rows)
             new.append(rows)
             if c is not None:
                 counters.append(c)
-        h = self.out_norm(h)[:, 0]
-        logits = jnp.dot(h, self.head().astype(h.dtype).T,
-                         preferred_element_type=jnp.float32)
-        return logits, new, stack_counters(counters)
+        h = self.out_norm(mhc.contract(h, n))[:, 0]
+        return self.logits(h), new, stack_counters(counters)
+
+    def verify(self, tokens, pos, valid, cache):
+        """:meth:`step` over ``q`` new positions a stream (``tokens``,
+        ``pos``, ``valid`` are ``[B, q]``, the positions consecutive),
+        each attending to the cache and to the new positions before it,
+        through any number of residual streams. Returns the logits
+        ``[B, q, V]``, the last hidden state a draft module reads
+        ``[B, q, D]`` (the streams' sum, before the last norm), the
+        cache with the new rows and the expert layers' counters."""
+        cfg = self.cfg
+        h = mhc.fan_out(jnp.take(self.embed.astype(jnp.dtype(cfg.dtype)),
+                                 tokens, axis=0), cfg.hc_streams)
+        new, counters = [], []
+        for layer, rows in zip(self.layers, cache):
+            h, c, rows = layer(h, valid, pos, rows)
+            new.append(rows)
+            if c is not None:
+                counters.append(c)
+        h = mhc.contract(h, cfg.hc_streams)
+        b, q, d = h.shape
+        logits = self.logits(self.out_norm(h).reshape(b * q, d))
+        return logits.reshape(b, q, -1), h, new, stack_counters(counters)
+
+    def draft(self, tokens, h, pos, valid, cache):
+        """The draft module over ``q`` positions a stream against ITS
+        cache: ``tokens [B, q]`` are the inputs that FOLLOW each
+        position, ``h [B, q, D]`` what :meth:`verify` gave there.
+        Returns its logits ``[B, q, V]`` (the token after next), its
+        cache and its expert layer's counters."""
+        emb = jnp.take(self.embed.astype(h.dtype), tokens, axis=0)
+        out, counters, cache = self.drafts[0](emb, h, valid, pos, cache)
+        b, q, d = out.shape
+        return self.logits(out.reshape(b * q, d)).reshape(b, q, -1), \
+            cache, counters
 
 
 def stack_counters(counters: list) -> dict:
@@ -441,7 +559,8 @@ def seeded_variables(cfg, seed: int, dtype=None):
         return jax.tree.map(lambda x: x.astype(dtype), tree)
 
     def shell(rng):
-        v = LFM2ASR(dataclasses.replace(m, lfm_layer_types=()),
+        v = LFM2ASR(dataclasses.replace(m, lfm_layer_types=(),
+                                        lm_draft_layers=0),
                     cfg.data.max_label_len).init(rng, *batch,
                                                  method="loss")
         return cast(v["params"])
@@ -450,16 +569,28 @@ def seeded_variables(cfg, seed: int, dtype=None):
     where = (jnp.ones((1, 2), bool), jnp.arange(2)[None, :])
     oracle = dataclasses.replace(m, moe_impl="xla")
 
+    def held(v):
+        return cast(v["params"]), v.get("buffers", {})
+
     @partial(jax.jit, static_argnums=(1, 2))
     def layer(rng, kind, sparse):
-        v = DecoderLayer(oracle, kind, sparse).init(rng, h, *where)
-        return cast(v["params"]), v.get("buffers", {})
+        return held(DecoderLayer(oracle, kind, sparse).init(
+            rng, mhc.fan_out(h, m.hc_streams), *where))
+
+    @jax.jit
+    def draft(rng):
+        return held(DraftModule(oracle).init(rng, h, h, *where))
 
     rng = jax.random.PRNGKey(seed)
     params, buffers = jax.jit(shell)(rng), {}
-    for i, kind in enumerate(m.lfm_layer_types):
-        params[f"layer{i}"], held = layer(
-            jax.random.fold_in(rng, i + 1), kind, i >= m.lfm_dense_layers)
-        if held:
-            buffers[f"layer{i}"] = held
+    made = [(f"layer{i}", layer(jax.random.fold_in(rng, i + 1), kind,
+                                i >= m.lfm_dense_layers))
+            for i, kind in enumerate(m.lfm_layer_types)]
+    made += [(f"draft{i}", draft(jax.random.fold_in(
+        rng, len(m.lfm_layer_types) + i + 1)))
+        for i in range(m.lm_draft_layers)]
+    for name, (p, b) in made:
+        params[name] = p
+        if b:
+            buffers[name] = b
     return params, buffers

@@ -33,8 +33,24 @@ and decode steps, and
                                   its slot of the batch
   lm_cache_rows_read              cache rows the active streams' steps
                                   attended to
-  lm_cache_bytes (gauge)          bytes of the cache (set where it is
+  lm_cache_bytes (gauge)          bytes of the cache, a draft module's
+                                  array included (set where it is
                                   allocated)
+
+and, where the loop drafts for itself (``model.lm_draft_layers``):
+
+  lm_verify_positions             positions the model ran in the steps
+                                  (an active stream's token and the
+                                  draft behind it)
+  lm_draft_positions              drafts put to the test
+  lm_draft_accepted               of them, those the model's argmax
+                                  confirmed (or a forced input replaced)
+  lm_rejected_rows_overwritten    cache rows a rejected draft wrote that
+                                  the stream's next step wrote again
+  lm_drafts                       drafts the loop used: prefill's first
+                                  and one for each stream that went on
+                                  after a step (one an active stream a
+                                  step)
 """
 
 from __future__ import annotations
@@ -129,8 +145,12 @@ def observe_lm_call(prefill: Sequence[Dict], decode: Dict, rows: int
         if k in prefill[0]:
             summed[k] = np.max([np.max(c[k]) for c in prefill])
     steps, idle = int(decode["steps"]), int(decode["idle_slot_steps"])
-    decode = dict(decode, valid_positions=steps * rows - idle,
-                  padded_positions=idle)
+    # A step computes 1 position a stream, or 2 where it drafts; the
+    # valid ones are those whose token was emitted.
+    emitted = int(np.sum(decode["tokens"]))
+    width = 2 if "draft_positions" in decode else 1
+    decode = dict(decode, valid_positions=emitted,
+                  padded_positions=steps * rows * width - emitted)
     out = {"prefill": part(summed), "decode": part(decode),
            "decode_steps": steps, "idle_slot_steps": idle,
            "cache_rows_read": int(decode["cache_rows_read"]), "rows": rows}
@@ -140,6 +160,11 @@ def observe_lm_call(prefill: Sequence[Dict], decode: Dict, rows: int
     reg.count("lm_decode_steps", steps)
     reg.count("lm_idle_slot_steps", idle)
     reg.count("lm_cache_rows_read", out["cache_rows_read"])
+    for k in ("verify_positions", "draft_positions", "draft_accepted",
+              "rejected_rows_overwritten", "drafts"):
+        if k in decode:
+            out[k] = int(decode[k])
+            reg.count("lm_" + k, out[k])
     dropped = 0
     for p in (out["prefill"], out["decode"]):
         reg.count("lm_valid_positions", p["valid_positions"])

@@ -83,8 +83,12 @@ def serve_lm(args, cfg, topo) -> None:
     b, t = args.batch, engine.steps_max
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=chip)
+    # One array a layer and one a draft module; with a module the loop
+    # also takes each stream's first draft.
     cache = [sds((b, m.lfm_seq_positions, m.mla_kv_rank + m.mla_rope_dim),
-                 jnp.dtype(m.dtype)) for _ in m.lfm_layer_types]
+                 jnp.dtype(m.dtype))
+             for _ in range(len(m.lfm_layer_types) + m.lm_draft_layers)]
+    draft = (sds((b,), jnp.int32),) if m.lm_draft_layers else ()
     programs = {
         "prefill": (engine._prefill, (
             params, buffers, cache,
@@ -93,12 +97,14 @@ def serve_lm(args, cfg, topo) -> None:
         "decode": (engine._decode, (
             params, buffers, cache, sds((b,), jnp.int32),
             sds((b,), jnp.int32), sds((b, t), jnp.int32),
-            sds((min(WATCH, b),), jnp.int32), sds((), jnp.bool_))),
+            sds((min(WATCH, b),), jnp.int32), sds((), jnp.bool_))
+            + draft),
     }
     out = {"tool": "aot_tpu", "preset": args.preset, "batch": b,
            "frames": args.frames, "serve": "lm_greedy",
            "prefill_rows": cfg.decode.lm_prefill_rows,
            "layers": len(m.lfm_layer_types),
+           "draft_modules": m.lm_draft_layers,
            "device_kind": str(topo.devices[0].device_kind)}
     for name, (fn, shapes) in programs.items():
         t0 = time.time()

@@ -23,15 +23,24 @@ minus the duration.
 The host's turn between two device programs is opened up by child
 spans (the parent's name and extent stay what they were; ``parent``
 comes from the stack, and the children of one unit share its ``step``
-or ``call``)::
+or ``call``, but for ``train.wait``, which carries the step it blocks
+on)::
 
     train.step      train.dispatch  the jitted step's call to its return
                     train.wait      the traced loop's block on the loss
-                                    (tracer on only)
+                                    of the step BEFORE, whose line is
+                                    owed (``Trainer.fit`` hands step
+                                    k+1 over before it reads step k);
+                                    on its own step's where the line is
+                                    not put off (tracer on only)
     train.log       train.sync      the logged step's block on the loss
-                    train.lr        the schedule read back as a float
+      (``ahead``: 1 if the next     (at once after a ``train.wait``)
+      step was handed over first)
+                    train.lr        the schedule in host floats
+                                    (``train.host_lr``)
                     train.fetch     loss, grad norm and the routing
-                                    counters to the host (``arrays``)
+                                    counters to the host (``arrays``):
+                                    transfers only
                     train.emit      the logger and the TensorBoard writer
     infer.transcribe  infer.cache   the call's cache handed out or made
     infer.prefill   infer.prefill.dispatch, infer.prefill.wait (tracer

@@ -21,7 +21,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import flax.struct
 import jax
@@ -44,6 +44,15 @@ from .resilience.guardian import STEP_HIST
 from .utils.logging import JsonlLogger, Throughput
 
 
+class _LoggedStep(NamedTuple):
+    """A step ``Trainer.fit`` has handed over and owes a log line."""
+    step: int        # steps done, as the line says
+    epoch: int
+    metrics: Dict    # the step's outputs, still on the device
+    dropped: list    # lm: every step's dropped counter since the
+                     # last line, this step's included
+
+
 @flax.struct.dataclass
 class TrainState:
     step: jnp.ndarray
@@ -64,6 +73,17 @@ def make_lr_schedule(cfg: Config, steps_per_epoch: int
         return t.learning_rate * warm / anneal
 
     return schedule
+
+
+def host_lr(cfg: Config, steps_per_epoch: int, step: int) -> float:
+    """``make_lr_schedule``'s formula in Python floats, for the log
+    line: the loop's turn after a logged step issues no device
+    computation (``tests/test_train_ahead.py`` holds the two faces
+    together)."""
+    t = cfg.train
+    warm = min((step + 1) / max(t.warmup_steps, 1), 1.0)
+    epoch = step // max(steps_per_epoch, 1)
+    return t.learning_rate * warm / t.lr_anneal ** epoch
 
 
 def make_optimizer(cfg: Config, steps_per_epoch: int
@@ -746,12 +766,82 @@ class Trainer:
                     out["ids"][g][:out["tokens"][g]]))
         return _counts_summary(counts)
 
+    def _log_step(self, logged: _LoggedStep, rate: float,
+                  ahead: bool) -> Dict[str, float]:
+        """The host's turn after a logged step, one child span a thing
+        it does (obs/trace.py): block on THAT step's loss, so that its
+        ``train_step`` event is a completed step, then write its line.
+        Only transfers of the step's outputs: with ``ahead`` the next
+        step is already queued, and a device computation issued here
+        would complete only when that step does."""
+        step, metrics = logged.step, logged.metrics
+        reg = obs.registry()
+        reg.count("train_logged_steps_total")
+        reg.count("train_log_ahead_total", int(ahead))
+        with obs.span("train.log", step=step, ahead=int(ahead)):
+            with obs.span("train.sync", step=step):
+                jax.block_until_ready(metrics["loss"])
+            with obs.span("train.lr", step=step):
+                lr = host_lr(self.cfg, self.steps_per_epoch, step - 1)
+            with obs.span("train.fetch", step=step) as fetch:
+                loss, grad_norm = jax.device_get(
+                    (metrics["loss"], metrics["grad_norm"]))
+                last = {"loss": float(loss),
+                        "grad_norm": float(grad_norm)}
+                arrays, routing = 2, {}
+                if "routing" in metrics:
+                    arrays += (len(metrics["routing"])
+                               + len(logged.dropped))
+                    routing = obs.observe_routing(metrics["routing"],
+                                                  logged.dropped)
+                fetch.set(arrays=arrays)
+            with obs.span("train.emit", step=step):
+                self.logger.log(
+                    "train_step", step=step, epoch=logged.epoch,
+                    lr=round(lr, 8),
+                    utt_per_sec_per_chip=round(rate, 3),
+                    **last, **routing)
+                if self.tb is not None:
+                    # The per-expert lists stay in the log line and
+                    # the registry.
+                    self.tb.scalars(
+                        step, **last, lr=lr, utt_per_sec_per_chip=rate,
+                        **{k: v for k, v in routing.items()
+                           if np.isscalar(v)})
+        return last
+
     def fit(self, epochs: Optional[int] = None) -> Dict[str, float]:
+        """Run the epochs; returns the last logged step's loss and
+        gradient norm (and the last evaluation's scores).
+
+        The unguarded loop hands step k+1 over BEFORE it reads step k:
+        with ``train.log_every`` the ``train_step`` line for step k is
+        written after step k+1 has been dispatched, once the loop has
+        blocked on step k's loss, so the log line, the next batch's
+        prefetch and the dispatch run while the device works. At most
+        one step is handed over beyond the one being read, and every
+        event is still a completed step, in step order. The pending
+        line is written (``drain``) before anything that relies on it:
+        the end of an epoch and ``train.eval``, every checkpoint, the
+        ``preempted`` event, the profiler's ``stop_trace``, and
+        ``fit``'s return or an exception leaving the loop. Under the
+        guardian the loop is synchronous (a step's metrics decide a
+        rollback before the next dispatch), and steps that are not
+        logged have no turn to hide."""
         cfg = self.cfg
         epochs = epochs if epochs is not None else cfg.train.epochs
         n_chips = self.mesh.devices.size
         thr = Throughput(n_chips)
         last = {}
+        # The logged step whose line is not written yet.
+        pending = None
+
+        def drain(ahead: bool = False) -> None:
+            nonlocal pending, last
+            if pending is not None:
+                logged, pending = pending, None
+                last = self._log_step(logged, thr.rate_per_chip(), ahead)
+
         # Deterministic mid-epoch resume: the sampler is a pure function
         # of (seed, epoch), so skipping the batches already consumed
         # replays the exact original data order (SURVEY.md §5).
@@ -835,12 +925,27 @@ class Trainer:
                                 self.state, metrics = self.train_step(
                                     self.state, sharded)
                         if obs.tracer.enabled:
-                            # Trace mode trades pipelining for
-                            # attribution: blocking here lands the
-                            # jitted compute in THIS span instead of
-                            # smearing it into the next host wait.
-                            with obs.span("train.wait", step=step):
-                                jax.block_until_ready(metrics["loss"])
+                            # Attribution without giving up the
+                            # overlap: the traced loop blocks here on
+                            # the step whose line is owed, the one
+                            # BEFORE this, so the span still ends one
+                            # device step after the last one did. A
+                            # step whose line is not put off (the
+                            # guardian's, an unlogged one) blocks on
+                            # itself.
+                            if pending is not None:
+                                with obs.span("train.wait",
+                                              step=pending.step - 1):
+                                    jax.block_until_ready(
+                                        pending.metrics["loss"])
+                            elif (self.guardian is not None or
+                                  (step + 1) % cfg.train.log_every):
+                                with obs.span("train.wait", step=step):
+                                    jax.block_until_ready(
+                                        metrics["loss"])
+                    # The host's turn for the step before, while the
+                    # device runs this one.
+                    drain(ahead=True)
                     if self.guardian is not None:
                         # observe_step reads the metrics (the device
                         # sync the guarded mode accepts), so the
@@ -885,46 +990,20 @@ class Trainer:
                         self.logger.log("profile_saved",
                                         dir=cfg.train.profile_dir, step=step)
                     if step % cfg.train.log_every == 0:
-                        # The host's turn after a logged step, one
-                        # child span a thing it does (obs/trace.py).
-                        with obs.span("train.log", step=step):
-                            with obs.span("train.sync", step=step):
-                                jax.block_until_ready(metrics["loss"])
-                            rate = thr.rate_per_chip()
-                            with obs.span("train.lr", step=step):
-                                lr = float(self.lr_schedule(
-                                    jnp.asarray(step - 1)))
-                            with obs.span("train.fetch",
-                                          step=step) as fetch:
-                                last = {"loss": float(metrics["loss"]),
-                                        "grad_norm":
-                                            float(metrics["grad_norm"])}
-                                arrays, routing = 2, {}
-                                if "routing" in metrics:
-                                    arrays += (len(metrics["routing"])
-                                               + len(self._dropped))
-                                    routing = obs.observe_routing(
-                                        metrics["routing"],
-                                        self._dropped)
-                                    self._dropped = []
-                                fetch.set(arrays=arrays)
-                            with obs.span("train.emit", step=step):
-                                self.logger.log(
-                                    "train_step", step=step, epoch=epoch,
-                                    lr=round(lr, 8),
-                                    utt_per_sec_per_chip=round(rate, 3),
-                                    **last, **routing)
-                                if self.tb is not None:
-                                    # The per-expert lists stay in the
-                                    # log line and the registry.
-                                    self.tb.scalars(
-                                        step, **last, lr=lr,
-                                        utt_per_sec_per_chip=rate,
-                                        **{k: v
-                                           for k, v in routing.items()
-                                           if np.isscalar(v)})
+                        # Its line is written once the next step has
+                        # been handed over (or at a drain below); this
+                        # step's dropped counter goes with it, so the
+                        # fetch never reads a step still running.
+                        pending = _LoggedStep(step, epoch, metrics,
+                                              self._dropped)
+                        self._dropped = []
+                        if self.guardian is not None:
+                            # Synchronous: the next dispatch waits for
+                            # what this step's metrics decided.
+                            drain()
                     if (cfg.train.checkpoint_every_steps and self.ckpt and
                             step % cfg.train.checkpoint_every_steps == 0):
+                        drain()
                         self.save(epoch)
                     if self.preempt is not None \
                             and self.preempt.requested():
@@ -934,6 +1013,7 @@ class Trainer:
                         # consumed-prefix skip replay the remaining
                         # batches in the original order — the resumed
                         # run is bit-identical to an uninterrupted one.
+                        drain()
                         if self.ckpt is not None:
                             with obs.span("train.emergency_checkpoint",
                                           step=step):
@@ -948,6 +1028,7 @@ class Trainer:
                         break
                 if preempted:
                     break
+                drain()
                 self.logger.log("epoch_end", epoch=epoch,
                                 seconds=round(time.perf_counter() - t_epoch, 1))
                 if self.eval_pipeline is not None:
@@ -962,6 +1043,12 @@ class Trainer:
         except BaseException:
             # Cleanup must not mask the in-flight exception; a cleanup
             # failure while unwinding is secondary, so only log it.
+            try:
+                # The step handed over before the exception is a
+                # completed step all the same: its line is owed.
+                drain()
+            except Exception as e:
+                self.logger.log("train_step_lost", error=repr(e))
             if watchdog is not None:
                 try:
                     watchdog.stop()

@@ -92,8 +92,10 @@ def run(fn, enabled: bool):
         hooks_before=hooks, hooks_after=list(gc.callbacks))
 
 
-def ctc_trainer():
-    """``dev_slice`` cut to one GRU-16 layer, three steps an epoch."""
+def ctc_trainer(hidden=16, layers=1, channels=(4, 4), frames=64,
+                n_utts=24):
+    """``dev_slice`` cut to one GRU-16 layer, three steps an epoch
+    (or as wide and as long as asked)."""
     from deepspeech_tpu.config import get_config
     from deepspeech_tpu.data import CharTokenizer
     from deepspeech_tpu.train import Trainer, _SyntheticPipeline
@@ -102,13 +104,17 @@ def ctc_trainer():
     cfg = get_config("dev_slice")
     cfg = dataclasses.replace(
         cfg,
-        model=dataclasses.replace(cfg.model, rnn_hidden=16, rnn_layers=1,
-                                  conv_channels=(4, 4), dtype="float32"),
+        model=dataclasses.replace(cfg.model, rnn_hidden=hidden,
+                                  rnn_layers=layers,
+                                  conv_channels=channels,
+                                  dtype="float32"),
         data=dataclasses.replace(cfg.data, batch_size=8,
-                                 bucket_frames=(64,), max_label_len=16),
+                                 bucket_frames=(frames,),
+                                 max_label_len=16),
         train=dataclasses.replace(cfg.train, checkpoint_dir="",
                                   log_every=1, warmup_steps=10))
-    pipe = _SyntheticPipeline(cfg, n_utts=24, frames=64, label_len=4)
+    pipe = _SyntheticPipeline(cfg, n_utts=n_utts, frames=frames,
+                              label_len=4)
     return Trainer(cfg, pipe, CharTokenizer.english(),
                    logger=JsonlLogger(echo=False))
 
@@ -161,13 +167,18 @@ def program_children(recs, parent):
     return sorted(kids, key=lambda r: r["ts"])
 
 
-def check_family(recs, parent_name, want, key, units):
+def check_family(recs, parent_name, want, key, units, whose=None):
+    """``want``: the children's names, or a function of the parent's
+    ordinal that gives them; ``whose``: the ``key`` a child carries
+    (its parent's, if not given)."""
+    whose = whose or (lambda parent, kid: parent[key])
     parents = [r for r in recs if r["name"] == parent_name]
     assert len(parents) == units
-    for parent in parents:
+    for i, parent in enumerate(parents):
         kids = program_children(recs, parent)
-        assert [k["name"] for k in kids] == want
-        assert all(k[key] == parent[key] for k in kids)
+        assert [k["name"] for k in kids] == (
+            want(i) if callable(want) else want)
+        assert all(k[key] == whose(parent, k) for k in kids)
         # In order, one after the other, inside the parent.
         ends = [k["ts"] + k["dur_ms"] / 1e3 for k in kids]
         assert parent["ts"] < kids[0]["ts"]
@@ -179,9 +190,16 @@ def check_family(recs, parent_name, want, key, units):
 
 @pytest.mark.parametrize("parent", sorted(TRAIN_CHILDREN))
 def test_training_children_in_order_under_their_parent(training, parent):
-    parents = check_family(training.on.recs, parent,
-                           TRAIN_CHILDREN[parent], "step",
-                           training.steps)
+    want = TRAIN_CHILDREN[parent]
+    # The loop hands step k+1 over before it reads step k: the traced
+    # wait under train.step k+1 is on step k and carries k, and the
+    # first step of a fit has nothing to wait on.
+    parents = check_family(
+        training.on.recs, parent,
+        (lambda i: want[:1] if i == 0 else want)
+        if parent == "train.step" else want,
+        "step", training.steps,
+        whose=lambda p, kid: p["step"] - (kid["name"] == "train.wait"))
     # train.step carries the step it runs, train.log the steps done.
     first = 0 if parent == "train.step" else 1
     assert [p["step"] for p in parents] == list(
@@ -212,13 +230,23 @@ def test_the_fetch_span_counts_the_arrays_it_reads(training):
 
 
 def test_the_training_wait_exists_only_with_the_tracer_on(training):
-    """The traced loop blocks inside ``train.step``, once a step, and
-    again (at once) in ``train.sync``; with the tracer off the first
-    call is not made at all."""
+    """The traced loop blocks inside ``train.step`` on the step before
+    (the last step of a fit has none after it) and again, at once, in
+    that step's ``train.sync``; with the tracer off the first call is
+    not made at all."""
     t = training
-    assert sum(r["name"] == "train.wait" for r in t.on.recs) == t.steps
-    assert (t.on.blocks, t.off.blocks) == (2 * t.steps, t.steps)
+    waits = [r for r in t.on.recs if r["name"] == "train.wait"]
+    assert len(waits) == t.steps - 1
+    assert (t.on.blocks, t.off.blocks) == (2 * t.steps - 1, t.steps)
     assert t.off.recs == []
+    # A wait on step k ends before the line of step k (steps done:
+    # k + 1) begins, and that line is written with step k + 1 handed
+    # over, all but the last.
+    logs = {r["step"]: r for r in t.on.recs if r["name"] == "train.log"}
+    for w in waits:
+        assert w["ts"] + w["dur_ms"] / 1e3 < logs[w["step"] + 1]["ts"]
+    assert [logs[k]["ahead"] for k in sorted(logs)] \
+        == [1] * (t.steps - 1) + [0]
 
 
 def test_the_served_wait_exists_only_with_the_tracer_on(serving):
@@ -297,6 +325,52 @@ def test_trace_report_gives_the_log_line_its_self_time(training):
     assert agg["train.log"]["self_ms"] == pytest.approx(own, abs=1e-2)
     assert agg["train.log"]["self_ms"] < agg["train.log"]["cum_ms"]
     assert agg["train.lr"]["count"] == training.steps
+
+
+def test_the_benchmarks_readers_still_read_a_traced_loop():
+    """The readers of the host's turn (imported as they are) on a real
+    traced loop under the real clock: ``train.step`` = {dispatch of
+    k+1, wait on k} still spans a device step, so its median stays
+    near the step-to-step interval; every unit of the window has its
+    turn; and the schedule's span no longer holds a device round
+    trip. A wider GRU than the other cases', so that a step outweighs
+    the loop's own work on the CPU."""
+    import statistics
+
+    from benchmark.layer_metrics import (_host_turn, host_dispatch_ms,
+                                         host_turn_ms, late_units,
+                                         train_lr_ms, train_step_ms)
+
+    trainer = ctc_trainer(hidden=96, layers=2, channels=(8, 8), frames=128,
+                          n_utts=8 * 14)
+    sink = io.StringIO()
+    obs.configure(enabled=True, sink=sink, registry=MetricsRegistry(),
+                  clock=time.perf_counter, wall=time.perf_counter)
+    try:
+        trainer.fit(1)
+    finally:
+        obs.configure(enabled=False, registry=obs.registry(),
+                      wall=time.time)
+    recs = [json.loads(line) for line in sink.getvalue().splitlines()]
+    spans = [(r["name"], r["ts"], r["ts"] + r["dur_ms"] / 1e3)
+             for r in recs if r["event"] == "span"]
+    # The window opens, as a driver's does, inside the line of the last
+    # warm-up step (the second: the first compiles), and closes with
+    # the last line.
+    logs = sorted((a, b) for n, a, b in spans if n == "train.log")
+    record = {"driver": "train", "spans": spans,
+              "t_window_start": logs[1][0] + 1e-6,
+              "t_window_end": logs[-1][1]}
+    units = _host_turn.units(record)
+    assert len(units) >= 9
+    assert all(0 < u.dispatch_s < u.turn_s for u in units)
+    starts = [a for a, _ in _host_turn.in_window(record, "train.step")]
+    interval = 1e3 * statistics.median(
+        b - a for a, b in zip(starts, starts[1:]))
+    assert train_step_ms.read(record) == pytest.approx(interval, rel=0.2)
+    assert 0 < host_dispatch_ms.read(record) < host_turn_ms.read(record)
+    assert train_lr_ms.read(record) < 0.1
+    assert late_units.read(record) is not None
 
 
 # -- host.gc ---------------------------------------------------------------

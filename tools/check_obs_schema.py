@@ -14,7 +14,8 @@ the same tooling (``tools/trace_report.py``, dashboards). The contract:
 - the spans of the host's turn (``obs/trace.py``): a child span of a
   training step (``train.dispatch`` / ``.wait`` / ``.sync`` / ``.lr`` /
   ``.fetch`` / ``.emit``) carries the integer ``step`` it shares with
-  its parent, a child span of a served call (``infer.cache``,
+  its parent (``train.wait``: the step it blocks on, the one before
+  its parent's), a child span of a served call (``infer.cache``,
   ``infer.prefill.dispatch`` / ``.wait``, ``infer.decode.dispatch`` /
   ``.fetch``) the integer ``call``, and ``host.gc`` (one garbage
   collection) an integer ``generation`` and ``collected`` — a child

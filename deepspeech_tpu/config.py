@@ -114,7 +114,8 @@ class ModelConfig:
     # (this chip's slice of ``lfm_vocab_published``). Sizes carry the
     # published config's names behind the ``lfm_`` prefix.
     lfm_hidden: int = 2048
-    lfm_layer_types: Tuple[str, ...] = ()  # "conv" | "full_attention"
+    # "conv" | "full_attention" | "sliding_attention" | "latent_attention"
+    lfm_layer_types: Tuple[str, ...] = ()
     lfm_dense_layers: int = 1        # leading layers with the dense FFN
     lfm_heads: int = 32
     lfm_kv_heads: int = 8
@@ -198,6 +199,29 @@ class ModelConfig:
     # predicts the token after next. Serving drafts with it inside the
     # greedy loop (``decode/lm_greedy.py``); 0: no module, no draft.
     lm_draft_layers: int = 0
+    # Grouped-query attention as a family states it (``model_type:
+    # afmoe``; every default is LFM2's reading, so its programs do not
+    # move). A head's size where it is not ``lfm_hidden / lfm_heads``
+    # (0); the window of a "sliding_attention" layer (position i sees
+    # keys ``i - window < j <= i``; a "full_attention" layer sees all);
+    # the layer kinds whose queries and keys are rotated (a kind left
+    # out carries no positions); a sigmoid gate on the attention's
+    # output, one value a head and channel, from a projection of the
+    # layer's input (``gate_proj``).
+    lfm_head_dim: int = 0
+    lfm_window: int = 0
+    lfm_rope_kinds: Tuple[str, ...] = ("full_attention",
+                                       "sliding_attention")
+    lfm_attn_gate: bool = False
+    # Sandwich norms: a second RMSNorm on each sub-layer's OUTPUT,
+    # ``h + post_norm(f(pre_norm(h)))``.
+    lfm_post_norms: bool = False
+    # ``mup_enabled``: the embedding (and the audio prefix, so that both
+    # kinds of position enter at one scale) times sqrt(lfm_hidden).
+    lfm_embed_scale: bool = False
+    # Seeded gains of every RMSNorm: 1 + normal(std) (0: ones), so that
+    # on seeded weights a dropped gain is seen.
+    lfm_norm_gain_std: float = 0.0
 
     @property
     def time_stride(self) -> int:
@@ -409,6 +433,10 @@ class DecodeConfig:
     # serving benchmark on seeded weights, whose end id means nothing).
     lm_prefill_rows: int = 32
     lm_ignore_end: bool = False
+    # lm_greedy: streams whose every step's logits and router outputs a
+    # call gives out (a check reads them through the serving
+    # executable): [rows, steps, vocabulary] float32 in every call.
+    lm_watch_rows: int = 8
 
 
 @dataclass(frozen=True)
@@ -645,6 +673,58 @@ def xing4_29b_a4b() -> Config:
     )
 
 
+TRINITY_PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
+
+
+def trinity_large() -> Config:
+    """One chip's share of Trinity-Large-Preview (``model_type: afmoe``,
+    https://huggingface.co/arcee-ai/Trinity-Large-Preview/blob/main/config.json)
+    as a decoder-only speech recogniser that is SERVED
+    (``decode.mode="lm_greedy"``) on recordings of minutes, every width
+    as published: hidden 3072, 48 query / 8 key-value heads of 128 with
+    a sigmoid output gate and RMSNorm on each head of q and k, three
+    sliding-window layers of 4096 (rotary, theta 10000) to one global
+    layer WITHOUT positions, four norms a layer, dense SwiGLU 12288 in
+    the leading layer, then 256 sigmoid-scored routed experts of 3072,
+    top-4 by score + selection bias, weights normalised times 2.448, one
+    shared expert, an untied head, the embedding times sqrt(3072). The
+    stated deployment divides each layer over 8 chips: 32 of the 256
+    experts and 25,024 of the 200,192 vocabulary rows live here, the
+    rest is replicated. Depth is cut to one leading dense layer
+    (sliding) and one whole period of sparse layers. The cache is a ring
+    of 4096 rows for a sliding layer and ``lfm_seq_positions`` rows for
+    the global one. ``benchmark/configs/trinity_large.json`` has the
+    published keys beside these and every reading that is this repo's
+    own."""
+    c = Config(name="trinity_large")
+    return _replace(
+        c,
+        model=_replace(
+            c.model, conv_layers=(), conv_channels=(), rnn_layers=0,
+            bidirectional=False, rnn_batch_norm=False, frame_stack=8,
+            vocab_size=25024, lfm_hidden=3072,
+            lfm_layer_types=("sliding_attention",) + TRINITY_PERIOD,
+            lfm_dense_layers=1, lfm_heads=48, lfm_kv_heads=8,
+            lfm_head_dim=128, lfm_window=4096,
+            lfm_rope_kinds=("sliding_attention",), lfm_attn_gate=True,
+            lfm_post_norms=True, lfm_embed_scale=True,
+            lfm_norm_gain_std=0.1, lfm_ffn_dim=12288,
+            lfm_expert_dim=3072, lfm_experts=256, lfm_top_k=4,
+            lfm_rope_theta=1e4, lfm_norm_eps=1e-5, experts_held=32,
+            expert_offset=0, moe_rows_bound=0.25,
+            lfm_seq_positions=6784, lm_tied_head=False, moe_groups=1,
+            moe_groups_kept=1, moe_select_bias=True,
+            moe_routed_scale=2.448, moe_shared_experts=1),
+        data=_replace(c.data, batch_size=16, bucket_frames=(42000,),
+                      max_label_len=1520),
+        train=_replace(c.train, objective="lm", optimizer="adamw",
+                       learning_rate=1e-4, weight_decay=0.0,
+                       grad_clip_norm=1.0, warmup_steps=100),
+        decode=_replace(c.decode, mode="lm_greedy", lm_prefill_rows=2,
+                        lm_watch_rows=2),
+    )
+
+
 PRESETS = {
     "ds2_small": ds2_small,
     "ds2_full": ds2_full,
@@ -656,6 +736,7 @@ PRESETS = {
     "lfm2_24b_a2b": lfm2_24b_a2b,
     "ax_k1": ax_k1,
     "xing4_29b_a4b": xing4_29b_a4b,
+    "trinity_large": trinity_large,
 }
 
 

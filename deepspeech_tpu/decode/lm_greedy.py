@@ -38,9 +38,19 @@ The step takes its input tokens through the argument ``forced [B, T]``
 in ``watch``, every step's logits, last-layer router scores and chosen
 experts: the very executable that serves runs forced tokens for a
 check against a reference. Weights are held in the model's compute
-dtype (cast once, here). The cache is one array a layer, ``[streams,
-cache_rows, C]``, allocated at the first call of a batch size and
-reused.
+dtype (cast once, here).
+
+The cache is one entry a layer, of the layer's KIND, allocated at the
+first call of a batch size and reused: latent attention's array
+``[streams, cache_rows, C]``; grouped-query attention's pair of arrays
+(keys, values), ``[streams, R, kv_heads, head]`` each, where a "full_attention" layer has R =
+``cache_rows`` and a "sliding_attention" layer a RING of R =
+``lfm_window`` rows (position p lives in slot ``p mod R``; fewer where
+the cache rows are fewer: a ring that never wraps). Prefill writes a
+stream's last ``min(a, R)`` prefix rows into a ring and all of them
+into a full cache; a step writes its row in slot ``pos mod R`` and
+attends to the rows the layer can reach; the call counts the rows
+attended per kind.
 """
 
 from __future__ import annotations
@@ -54,18 +64,18 @@ import numpy as np
 
 from .. import obs
 from ..config import Config
-from ..models.lfm2 import (cached_kinds, create_lfm2_model,
-                           seq_positions)
-
-WATCH = 8     # streams whose per-step logits the programs give out
+from ..models.lfm2 import (ATTENTION_KINDS, create_lfm2_model, head_dim,
+                           ring_positions, seq_positions, uncached_kinds)
 
 
-def _watched(mid: dict, rows, layers: List[str], mixed: str = "") -> dict:
+def _watched(mid: dict, rows, layers: List[str], mixed: str = "",
+             gated=()) -> dict:
     """Of a pass's sown router outputs, the ``rows`` of the batch: the
     last expert layer's scores and combine weights and every expert
-    layer's chosen sets; and the feed-forward's hyper-connection
+    layer's chosen sets; the feed-forward's hyper-connection
     coefficients of the layer ``mixed``, where the residual has
-    streams."""
+    streams; and the gated attention output (before ``o``) of the
+    layers ``gated``."""
     if not layers:
         return {}
 
@@ -78,22 +88,27 @@ def _watched(mid: dict, rows, layers: List[str], mixed: str = "") -> dict:
            "chosen": jnp.stack([of(n, "experts") for n in layers])}
     for key in ("h_pre", "h_post", "h_res") if mixed else ():
         out[key] = mid[mixed]["ffn_hc"][key][0][rows[0]]
+    for i, name in enumerate(gated):
+        out[f"gated{i}"] = mid[name]["attn"]["gated"][0][rows[0]]
     return out
+
+
+def cache_bytes(cache) -> int:
+    """Bytes of a cache, or of some layers' entries of one."""
+    return sum(c.nbytes for c in jax.tree.leaves(cache))
 
 
 class LMGreedy:
     def __init__(self, cfg: Config, params, buffers=None):
         m = cfg.model
-        if not cached_kinds(m):
-            missing = sorted({"conv": "a 2-position convolution state",
-                              "full_attention": "a grouped-query "
-                              "key/value cache"}.get(k, k)
-                             for k in set(m.lfm_layer_types)
-                             if k != "latent_attention")
+        if uncached_kinds(m):
+            missing = [{"conv": "a 2-position convolution state"}.get(k, k)
+                       for k in uncached_kinds(m)]
             raise NotImplementedError(
                 "decode.mode='lm_greedy' needs a cache for every layer "
                 f"kind; {cfg.name!r} lacks " + " and ".join(missing)
-                + " (latent attention alone has its decode form)")
+                + " (latent and grouped-query attention have their "
+                "decode forms)")
         self.cfg = cfg
         self.model = create_lfm2_model(m, cfg.data.max_label_len)
         dtype = jnp.dtype(m.dtype)
@@ -112,6 +127,12 @@ class LMGreedy:
         # The layer whose hyper-connection coefficients are given out.
         self.mixed = (f"layer{len(m.lfm_layer_types) - 1}"
                       if m.hc_streams > 1 else "")
+        # Layers of each grouped-query kind, and of each kind the last
+        # layer, whose gated attention output is given out.
+        self.kinds = {k: [i for i, t in enumerate(m.lfm_layer_types)
+                          if t == k] for k in ATTENTION_KINDS}
+        self.gated = [f"layer{self.kinds[k][-1]}" for k in (
+            "sliding_attention", "full_attention") if self.kinds[k]]
         self._cache = None
         self._calls = 0
         self.last_call: Optional[dict] = None
@@ -129,17 +150,33 @@ class LMGreedy:
         (new, a_lens, counters, draft), state = self.model.apply(
             {"params": params, "buffers": buffers}, feats, lens,
             method="prefill", mutable=["intermediates"])
-        cache = [jax.lax.dynamic_update_slice(c, r.astype(c.dtype),
-                                              (offset, 0, 0))
-                 for c, r in zip(cache, new)]
-        a = new[0].shape[1]
+        cache = jax.tree.map(
+            lambda c, r: jax.lax.dynamic_update_slice(
+                c, self._rows_for(c, r, a_lens).astype(c.dtype),
+                (offset,) + (0,) * (c.ndim - 1)), cache, new)
+        a = -(-features.shape[1] // self.cfg.model.frame_stack)
         counters = dict(counters)
         counters["valid_positions"] = jnp.sum(a_lens)
         counters["padded_positions"] = rows * a - jnp.sum(a_lens)
+        watched = min(self.cfg.decode.lm_watch_rows, rows)
         watch = _watched(state.get("intermediates", {}),
-                         (slice(0, min(WATCH, rows)), a), self.sparse,
-                         self.mixed)
+                         (slice(0, watched), a), self.sparse, self.mixed,
+                         self.gated)
         return cache, a_lens, counters, watch, draft
+
+    @staticmethod
+    def _rows_for(cache, rows, a_lens):
+        """What prefill writes into one array ``cache`` of a layer's
+        cache of a sub-batch's ``rows``: the rows themselves, or, into
+        a ring shorter than the prefix, each slot's newest prefix
+        position (a stream's last ``min(a, R)`` prefix rows; nothing
+        where it has none)."""
+        if cache.shape[1] >= rows.shape[1]:
+            return rows
+        held = ring_positions(a_lens - 1, cache.shape[1])
+        held = held.reshape(held.shape + (1,) * (rows.ndim - 2))
+        ring = jnp.take_along_axis(rows, jnp.maximum(held, 0), axis=1)
+        return jnp.where(held >= 0, ring, 0)
 
     def _count(self, acc: dict, counters: dict) -> None:
         """A pass's expert-layer counters into the loop's."""
@@ -184,11 +221,17 @@ class LMGreedy:
             acc["steps"] += 1
             acc["tokens"] += active
             acc["idle_slot_steps"] += jnp.sum(~active)
-            acc["cache_rows_read"] += jnp.sum(
-                jnp.where(active, a_lens + j + 1, 0))
+            reach = jnp.where(active, a_lens + j + 1, 0)
+            if any(self.kinds.values()):
+                # Rows attended, over the layers of each kind.
+                for key, n in self._reach(reach).items():
+                    acc[key] += n
+                    acc["cache_rows_read"] += n
+            else:
+                acc["cache_rows_read"] += jnp.sum(reach)
             self._count(acc, counters)
             mid = _watched(state.get("intermediates", {}), (watch, 1),
-                           self.sparse)
+                           self.sparse, gated=self.gated)
             mid["logits"] = logits[watch][:, None, :]
             seen = {k: jax.lax.dynamic_update_slice_in_dim(
                 seen[k], mid[k], j, axis=seen[k].ndim - 2) for k in seen}
@@ -206,6 +249,13 @@ class LMGreedy:
                    cache_rows_read=jnp.int32(0))
         w = watch.shape[0]
         seen = {"logits": jnp.zeros((w, t, m.vocab_size), jnp.float32)}
+        if any(self.kinds.values()):
+            acc.update(rows_attended_window=jnp.int32(0),
+                       rows_attended_global=jnp.int32(0))
+            width = m.lfm_heads * head_dim(m)
+            seen.update({f"gated{i}": jnp.zeros((w, t, width),
+                                                jnp.dtype(m.dtype))
+                         for i in range(len(self.gated))})
         if self.sparse:
             acc["experts_hit"] = jnp.zeros(len(self.sparse), jnp.int32)
             seen["scores"] = jnp.zeros((w, t, m.lfm_experts), jnp.float32)
@@ -216,7 +266,24 @@ class LMGreedy:
                  jnp.zeros((b, t), jnp.int32), cache, acc, seen)
         _, _, _, out, cache, acc, seen = jax.lax.while_loop(
             cond, body, carry)
+        if self.kinds["sliding_attention"]:
+            # Streams whose position passed the window: their rings
+            # have wrapped.
+            acc["ring_wraps"] = jnp.sum(
+                (max_tokens > 0)
+                & (a_lens + acc["tokens"] > m.lfm_window))
         return out, cache, acc, seen
+
+    def _reach(self, reach) -> dict:
+        """Cache rows the streams' steps attend to, ``reach [B]`` each
+        in a layer that sees everything (its own row among them): over
+        the windowed layers, where a stream sees ``lfm_window`` at most,
+        and over the global ones."""
+        window = jnp.minimum(reach, self.cfg.model.lfm_window)
+        return {"rows_attended_window": jnp.sum(window) * len(
+                    self.kinds["sliding_attention"]),
+                "rows_attended_global": jnp.sum(reach) * len(
+                    self.kinds["full_attention"])}
 
     def _decode_drafting(self, params, buffers, cache, a_lens, max_tokens,
                          forced, watch, ignore_end, draft):
@@ -338,21 +405,48 @@ class LMGreedy:
 
     # -- a call --------------------------------------------------------------
 
-    def cache_for(self, rows: int, frames: int) -> list:
-        """The cache of ``rows`` streams whose prefix is ``frames``
-        feature frames, one array a layer and one a draft module:
+    def cache_shapes(self, rows: int, frames: int) -> list:
+        """The shapes of each layer's cache (a draft module's after
+        them) for ``rows`` streams whose prefix is ``frames`` feature
+        frames, a list of arrays' shapes a layer (one for latent
+        attention, keys and values for grouped-query attention):
         ``model.lfm_seq_positions`` rows a stream, or (0) the least
-        that hold the prefix and every step."""
+        that hold the prefix and every step; a windowed layer's ring
+        has ``lfm_window`` rows where that is fewer."""
         m = self.cfg.model
         positions = seq_positions(m, frames, self.cfg.data.max_label_len)
-        shape = (rows, positions, m.mla_kv_rank + m.mla_rope_dim)
-        if self._cache is None or self._cache[0].shape != shape:
+        latent = [(rows, positions, m.mla_kv_rank + m.mla_rope_dim)]
+
+        def of(kind):
+            if kind not in ATTENTION_KINDS:
+                return latent
+            ring = kind == "sliding_attention" and m.lfm_window
+            return [(rows, min(ring, positions) if ring else positions,
+                     m.lfm_kv_heads, head_dim(m))] * 2
+
+        return [of(k) for k in m.lfm_layer_types] \
+            + [latent] * m.lm_draft_layers
+
+    def cache_for(self, rows: int, frames: int) -> list:
+        """The cache of ``rows`` streams whose prefix is ``frames``
+        feature frames: an array a latent layer or draft module, the
+        pair (keys, values) a grouped-query layer."""
+        dtype = jnp.dtype(self.cfg.model.dtype)
+        shapes = self.cache_shapes(rows, frames)
+        if self._cache is None or [
+                [c.shape for c in jax.tree.leaves(layer)]
+                for layer in self._cache] != shapes:
             self._cache = None  # free the old one first
-            self._cache = [jnp.zeros(shape, jnp.dtype(m.dtype))
-                           for _ in range(len(m.lfm_layer_types)
-                                          + m.lm_draft_layers)]
-            obs.registry().gauge("lm_cache_bytes", sum(
-                c.nbytes for c in self._cache))
+            self._cache = [
+                jnp.zeros(s[0], dtype) if len(s) == 1
+                else tuple(jnp.zeros(x, dtype) for x in s) for s in shapes]
+            gauge = obs.registry().gauge
+            gauge("lm_cache_bytes", cache_bytes(self._cache))
+            for kind, name in (("sliding_attention", "window"),
+                               ("full_attention", "global")):
+                if self.kinds[kind]:
+                    gauge("lm_cache_bytes_" + name, cache_bytes(
+                        [self._cache[i] for i in self.kinds[kind]]))
         cache, self._cache = self._cache, None
         return cache
 
@@ -375,7 +469,8 @@ class LMGreedy:
         if forced is None:
             forced = np.full((b, t), -1, np.int32)
         if watch is None:
-            watch = np.arange(min(WATCH, b), dtype=np.int32)
+            watch = np.arange(min(cfg.decode.lm_watch_rows, b),
+                              dtype=np.int32)
         self._calls += 1
         call = self._calls  # what the spans of one call share
         # The last call's watched outputs (with a vocabulary of 131,072

@@ -71,12 +71,22 @@ def seq_positions(cfg: ModelConfig, frames: int, max_label_len: int
     return -(-least // 8) * 8
 
 
+def gain_init(std: float):
+    """Ones, or (``lfm_norm_gain_std``) 1 + normal(std)."""
+    if not std:
+        return nn.initializers.ones
+    return lambda rng, shape, dtype=jnp.float32: (
+        1.0 + std * jax.random.normal(rng, shape, dtype))
+
+
 class RMSNorm(nn.Module):
     eps: float
+    gain_std: float = 0.0
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        scale = self.param("scale", gain_init(self.gain_std),
+                           (x.shape[-1],))
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
@@ -117,48 +127,191 @@ class ShortConv(nn.Module):
         return Linear(d, name="out_proj")(gate_c * c)
 
 
-def rotary(x, theta: float):
+def rotary(x, theta: float, pos=None):
     """Rotary embedding over the whole head, rotate-half pairing;
-    ``x [B, S, H, D]``, positions 0..S-1."""
+    ``x [B, S, H, D]`` at positions 0..S-1, or at ``pos [B, S]``."""
     s, d = x.shape[1], x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+    if pos is None:
+        ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+        ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+    else:
+        ang = pos.astype(jnp.float32)[..., None] * inv
+        ang = jnp.concatenate([ang, ang], axis=-1)[:, :, None, :]
     x32 = x.astype(jnp.float32)
     x1, x2 = jnp.split(x32, 2, axis=-1)
     rot = jnp.concatenate([-x2, x1], axis=-1)
     return (x32 * jnp.cos(ang) + rot * jnp.sin(ang)).astype(x.dtype)
 
 
+def head_dim(cfg: ModelConfig) -> int:
+    """A grouped-query head's size: the preset's own, or ``hidden /
+    heads``."""
+    return cfg.lfm_head_dim or cfg.lfm_hidden // cfg.lfm_heads
+
+
+def reach_mask(i0: int, sq: int, j0: int, sk: int, window: int):
+    """``[sq, sk]``: which of the keys ``j0 .. j0 + sk`` each of the
+    queries ``i0 .. i0 + sq`` attends to: ``j <= i``, and ``i - window <
+    j`` where there is a window."""
+    if i0 == j0 == 0 and sq == sk and not window:
+        # One block without a window: the mask as LFM2's trained layers
+        # have always built it, so their lowered step does not move.
+        return jnp.tril(jnp.ones((sq, sk), bool))
+    i = i0 + jnp.arange(sq)[:, None]
+    j = j0 + jnp.arange(sk)[None, :]
+    seen = j <= i
+    return seen & (j > i - window) if window else seen
+
+
+def ring_positions(pos, rows: int):
+    """``[B, rows]``: the position each slot of a cache of ``rows`` rows
+    holds once position ``pos [B]`` is written (row p lives in slot ``p
+    mod rows``): the newest ``p <= pos`` of the slot's class, negative
+    where the stream has not reached the slot. A cache that never wraps
+    (``rows`` > every position) holds position s in slot s."""
+    slot = jnp.arange(rows)[None, :]
+    return pos[:, None] - (pos[:, None] - slot) % rows
+
+
 class Attention(nn.Module):
     """Causal grouped-query attention: RMSNorm over each head of q and
-    of k before the rotation, every key/value head shared by
-    ``heads / kv_heads`` query heads."""
+    of k, then the rotation where the layer's ``kind`` has one
+    (``lfm_rope_kinds``), every key/value head shared by ``heads /
+    kv_heads`` query heads; a "sliding_attention" layer sees the last
+    ``lfm_window`` keys, its own among them; with ``lfm_attn_gate`` the
+    heads' output is multiplied by the sigmoid of a projection of the
+    layer's input before ``o``.
+
+    ``__call__(h [B, S, D])`` is the SEQUENCE form (training, prefill;
+    positions 0..S-1) and returns the output and the keys (normed and
+    rotated) and values it computed, ``(k, v)``, ``[B, S, kv_heads,
+    head]`` each: the rows of the layer's cache. It works in blocks of ``block`` queries, each
+    against the keys its positions can reach and no others, so no
+    ``[S, S]`` array exists past one block and a sliding layer's work is
+    bounded by S x (window + block). With ``cache``, the pair of
+    arrays ``(keys, values)`` ``[B, R, kv_heads, head]`` each (two
+    arrays, so that a step's products read them as they lie), it is the
+    DECODE form, one new position a stream: the new row is written in
+    slot ``pos mod R`` (a ring of R = ``lfm_window``
+    rows for a sliding layer; R above every position for a global one,
+    which never wraps) and the query attends to the slots whose
+    position it can reach (``live [B, 1]``: the streams that write); it
+    returns the output and the cache. Keys are stored rotated by their
+    ABSOLUTE position, so a ring's order means nothing to the
+    softmax."""
 
     cfg: ModelConfig
+    kind: str = "full_attention"
+    block: int = 512
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, pos=None, cache=None, live=None):
         cfg = self.cfg
         b, s, d = h.shape
         nh, nkv = cfg.lfm_heads, cfg.lfm_kv_heads
-        hd, rep = d // nh, nh // nkv
-        q = Linear(d, name="q")(h).reshape(b, s, nh, hd)
+        hd, rep = head_dim(cfg), nh // nkv
+        window = cfg.lfm_window if self.kind == "sliding_attention" else 0
+        scope = "gqa_attn_" + ("window" if window else "global")
+        q = Linear(nh * hd, name="q")(h).reshape(b, s, nh, hd)
         k = Linear(nkv * hd, name="k")(h).reshape(b, s, nkv, hd)
         v = Linear(nkv * hd, name="v")(h).reshape(b, s, nkv, hd)
-        q = rotary(RMSNorm(cfg.lfm_norm_eps, name="q_norm")(q),
-                   cfg.lfm_rope_theta)
-        k = rotary(RMSNorm(cfg.lfm_norm_eps, name="k_norm")(k),
-                   cfg.lfm_rope_theta)
+        std = cfg.lfm_norm_gain_std
+
+        def placed(x, name):
+            """A head's norm, then the layer kind's rotation."""
+            x = RMSNorm(cfg.lfm_norm_eps, std, name=name)(x)
+            if self.kind not in cfg.lfm_rope_kinds:
+                return x
+            return rotary(x, cfg.lfm_rope_theta,
+                          None if cache is None else pos)
+
+        q, k = placed(q, "q_norm"), placed(k, "k_norm")
         q = q.reshape(b, s, nkv, rep, hd)
-        scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = scores * (hd ** -0.5)
-        causal = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(causal, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
-        out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
-        return Linear(d, name="o")(out.reshape(b, s, d))
+
+        def attend(q, k, v, i0: int, j0: int):
+            """Queries ``i0 ..`` ``q [B, sq, kv, rep, hd]`` against the
+            keys ``j0 ..`` ``k, v [B, sk, kv, hd]``."""
+            scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
+                                preferred_element_type=jnp.float32)
+            scores = scores * (hd ** -0.5)
+            scores = jnp.where(
+                reach_mask(i0, q.shape[1], j0, k.shape[1], window),
+                scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+            return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
+
+        if cache is None:
+            kept = (k, v)
+            with jax.named_scope(scope):
+                if s <= self.block:
+                    out = attend(q, k, v, 0, 0)
+                else:
+                    outs = []
+                    for i0 in range(0, s, self.block):
+                        i1 = min(i0 + self.block, s)
+                        j0 = max(0, i0 - window + 1) if window else 0
+                        outs.append(attend(q[:, i0:i1], k[:, j0:i1],
+                                           v[:, j0:i1], i0, j0))
+                    out = jnp.concatenate(outs, axis=1)
+        elif s != 1:
+            raise NotImplementedError(
+                f"grouped-query attention decodes one new position a "
+                f"stream, not {s}: several at once (a loop that verifies "
+                f"its drafts) is latent attention's alone")
+        else:
+            with jax.named_scope(scope):
+                at, r = pos[:, 0], cache[0].shape[1]
+                # A stream that has finished (not ``live``) writes
+                # nothing: in a ring its slot holds a row it still owns.
+                at_slot = (jnp.arange(b), at % r)
+                keys, values = (c.at[at_slot].set(jnp.where(
+                    live[:, :, None], row[:, 0].astype(c.dtype),
+                    c[at_slot])) for c, row in zip(cache, (k, v)))
+                held = ring_positions(at, r)
+                seen = held >= 0
+                if window:
+                    seen &= at[:, None] - held < window
+                scores = jnp.einsum("bgrd,bkgd->bgrk", q[:, 0], keys,
+                                    preferred_element_type=jnp.float32)
+                scores = jnp.where(seen[:, None, None, :],
+                                   scores * (hd ** -0.5), -1e30)
+                probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+                out = jnp.einsum("bgrk,bkgd->bgrd", probs, values)
+            kept = (keys, values)
+        out = out.reshape(b, s, nh * hd)
+        if cfg.lfm_attn_gate:
+            gate = Linear(nh * hd, name="gate")(h)
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(h.dtype)
+        self.sow("intermediates", "gated", out)
+        return Linear(d, name="o")(out), kept
+
+
+def both_forms(cfg: ModelConfig, kind: str, params, x, at, rows: int,
+               block: int = 512):
+    """One grouped-query attention layer of ``kind`` on ``x [B, S, D]``
+    in both forms: the sequence form over all positions, then the
+    decode form at each position of ``at`` (an index array)
+    against a cache of ``rows`` rows that holds what the sequence form
+    gave for the positions before it (slot ``p mod rows``: a ring where
+    ``rows`` < S), every (row, position) a stream of its own. Returns
+    the two outputs at those positions, ``[B, len(at), D]`` each."""
+    layer = Attention(cfg, kind, block)
+    b = x.shape[0]
+    seq, kv = layer.apply({"params": params}, x)
+    at = jnp.asarray(at)
+    held = ring_positions(at - 1, rows)
+    # [B, n, rows, heads, hd]: a stream a (row, position)
+    cache = tuple(
+        jnp.where((held >= 0)[None, :, :, None, None],
+                  c[:, jnp.maximum(held, 0)], 0).reshape(
+                      (b * len(at), rows) + c.shape[2:]) for c in kv)
+    dec, _ = layer.apply(
+        {"params": params}, x[:, at].reshape(b * len(at), 1, -1),
+        jnp.tile(at, b)[:, None], cache,
+        jnp.ones((b * len(at), 1), bool))
+    return dec.reshape(b, len(at), -1), seq[:, at]
 
 
 class SwiGLU(nn.Module):
@@ -229,19 +382,26 @@ class SparseExperts(nn.Module):
         return out, counters
 
 
+ATTENTION_KINDS = ("full_attention", "sliding_attention")
+
+
 class DecoderLayer(nn.Module):
-    """``h + operator(norm(h))``, then ``h + ffn(norm(h))``; with
+    """``h + operator(norm(h))``, then ``h + ffn(norm(h))``, each
+    sub-layer's output through a norm of its own before it is added
+    where the family has sandwich norms (``lfm_post_norms``); with
     ``hc_streams`` > 1 the residual ``h [B, S, n, D]`` is n streams and
     each of the two sub-layers reads and writes them through its own
     hyper-connection (``models/mhc.py``). Returns
     the new ``h``, the expert block's counters (None for a dense
-    feed-forward) and the layer's cache: the rows of this call's
-    positions (the sequence form), or the ``cache`` handed in with the
-    new rows of each stream written at ``pos`` (the decode form; latent
-    attention alone has one). A kind without a cache returns None."""
+    feed-forward) and the layer's cache: what this call's positions
+    would put there (the sequence form: latent rows, or an attention
+    layer's keys and values), or the ``cache`` handed in with the
+    new rows of each stream written at ``pos`` (the decode form, which
+    latent and grouped-query attention have). A kind without a cache
+    returns None."""
 
     cfg: ModelConfig
-    kind: str      # "conv" | "full_attention" | "latent_attention"
+    kind: str      # "conv" | "latent_attention" | ATTENTION_KINDS
     sparse: bool
 
     def residual(self, name: str, h, f):
@@ -262,23 +422,34 @@ class DecoderLayer(nn.Module):
     def __call__(self, h, valid, pos=None, cache=None):
         cfg = self.cfg
 
+        def norm(name):
+            return RMSNorm(cfg.lfm_norm_eps, cfg.lfm_norm_gain_std,
+                           name=name)
+
+        def after(name, out):
+            y, extra = out
+            return (norm(name)(y) if cfg.lfm_post_norms else y), extra
+
         def operator(x):
-            x = RMSNorm(cfg.lfm_norm_eps, name="op_norm")(x)
+            x = norm("op_norm")(x)
             if self.kind == "latent_attention":
                 return LatentAttention(cfg, name="attn")(x, pos, cache)
+            if self.kind in ATTENTION_KINDS:
+                return after("op_post_norm", Attention(
+                    cfg, self.kind, name="attn")(x, pos, cache, valid))
             if cache is not None:
                 raise ValueError(f"layer type {self.kind!r} has no cache")
             if self.kind == "conv":
                 return ShortConv(cfg, name="conv")(x), None
-            if self.kind == "full_attention":
-                return Attention(cfg, name="attn")(x), None
             raise ValueError(f"layer type {self.kind!r}")
 
         def feed_forward(x):
-            x = RMSNorm(cfg.lfm_norm_eps, name="ffn_norm")(x)
+            x = norm("ffn_norm")(x)
             if self.sparse:
-                return SparseExperts(cfg, name="moe")(x, valid)
-            return SwiGLU(cfg.lfm_ffn_dim, name="ffn")(x), None
+                return after("ffn_post_norm", SparseExperts(
+                    cfg, name="moe")(x, valid))
+            return after("ffn_post_norm", (
+                SwiGLU(cfg.lfm_ffn_dim, name="ffn")(x), None))
 
         h, cache = self.residual("op_hc", h, operator)
         h, counters = self.residual("ffn_hc", h, feed_forward)
@@ -342,7 +513,7 @@ class LFM2ASR(nn.Module):
             layer_cls(cfg, kind, i >= cfg.lfm_dense_layers,
                       name=f"layer{i}")
             for i, kind in enumerate(cfg.lfm_layer_types)]
-        self.out_norm = RMSNorm(cfg.lfm_norm_eps)
+        self.out_norm = RMSNorm(cfg.lfm_norm_eps, cfg.lfm_norm_gain_std)
         self.drafts = [DraftModule(cfg, name=f"draft{i}")
                        for i in range(cfg.lm_draft_layers)]
         if not cfg.lm_tied_head:
@@ -353,6 +524,14 @@ class LFM2ASR(nn.Module):
         """The output head ``[V, D]``: the embedding matrix, or the
         family's own."""
         return self.embed if self.cfg.lm_tied_head else self.lm_head
+
+    def enter(self, x):
+        """What enters the first layer, from an embedding or a
+        projected frame: times sqrt(D) where the family scales its
+        embedding (``lfm_embed_scale``)."""
+        if not self.cfg.lfm_embed_scale:
+            return x
+        return x * jnp.asarray(self.cfg.lfm_hidden ** 0.5, x.dtype)
 
     def hidden(self, features, feat_lens, labels, label_lens):
         """The normed final hidden state ``[B, S, D]`` of the packed
@@ -367,8 +546,8 @@ class LFM2ASR(nn.Module):
         pre = jnp.pad(pre, [(0, 0), (0, s - pre.shape[1]), (0, 0)])
         emb = jnp.take(self.embed.astype(dtype), ids, axis=0)
         valid = audio | text
-        h = jnp.where(audio[..., None], pre,
-                      jnp.where(text[..., None], emb, 0))
+        h = self.enter(jnp.where(audio[..., None], pre,
+                                 jnp.where(text[..., None], emb, 0)))
         pos = jnp.broadcast_to(jnp.arange(s)[None, :], valid.shape)
         h = mhc.fan_out(h, cfg.hc_streams)
         counters = []
@@ -399,9 +578,12 @@ class LFM2ASR(nn.Module):
     def prefill(self, features, feat_lens):
         """The serving path's first half: the audio prefix alone
         (positions ``0 .. a-1`` of each stream) through the layers.
-        Returns each layer's rows to cache ``[B, A, C]`` (a draft
-        module's after them), the prefix lengths, the expert layers'
-        counters and, with a draft module, its first draft ``[B]``: the
+        Returns each layer's rows to cache (latent attention's ``[B, A,
+        C]``, grouped-query attention's keys and values, a pair of ``[B,
+        A, kv_heads, head]``;
+        a draft module's after them), the prefix lengths, the expert
+        layers' counters and, with a draft module, its first draft
+        ``[B]``: the
         token after the start id. The module's next input at a prefix
         position is the next projected frame, and the start id's
         embedding at the last."""
@@ -411,7 +593,7 @@ class LFM2ASR(nn.Module):
         pos = jnp.broadcast_to(jnp.arange(pre.shape[1])[None, :],
                                pre.shape[:2])
         valid = pos < a_lens[:, None]
-        h = mhc.fan_out(pre, cfg.hc_streams)
+        h = mhc.fan_out(self.enter(pre), cfg.hc_streams)
         rows, counters = [], []
         for layer in self.layers:
             h, c, r = layer(h, valid, pos)
@@ -447,9 +629,9 @@ class LFM2ASR(nn.Module):
         the new rows and the expert layers' counters; a stream that is
         not ``active`` is not routed."""
         n = self.cfg.hc_streams
-        h = mhc.fan_out(jnp.take(
+        h = mhc.fan_out(self.enter(jnp.take(
             self.embed.astype(jnp.dtype(self.cfg.dtype)), tokens,
-            axis=0)[:, None, :], n)
+            axis=0)[:, None, :]), n)
         new, counters = [], []
         for layer, rows in zip(self.layers, cache):
             h, c, rows = layer(h, active[:, None], pos[:, None], rows)
@@ -468,8 +650,9 @@ class LFM2ASR(nn.Module):
         ``[B, q, D]`` (the streams' sum, before the last norm), the
         cache with the new rows and the expert layers' counters."""
         cfg = self.cfg
-        h = mhc.fan_out(jnp.take(self.embed.astype(jnp.dtype(cfg.dtype)),
-                                 tokens, axis=0), cfg.hc_streams)
+        h = mhc.fan_out(self.enter(jnp.take(
+            self.embed.astype(jnp.dtype(cfg.dtype)), tokens, axis=0)),
+            cfg.hc_streams)
         new, counters = [], []
         for layer, rows in zip(self.layers, cache):
             h, c, rows = layer(h, valid, pos, rows)
@@ -501,9 +684,10 @@ def stack_counters(counters: list) -> dict:
     return jax.tree.map(lambda *xs: jnp.stack(xs), *counters)
 
 
-def cached_kinds(cfg: ModelConfig) -> bool:
-    """Whether every layer of the preset has a decode form."""
-    return all(k == "latent_attention" for k in cfg.lfm_layer_types)
+def uncached_kinds(cfg: ModelConfig) -> list:
+    """The preset's layer kinds that have no decode form yet."""
+    return sorted(set(cfg.lfm_layer_types)
+                  - {"latent_attention", *ATTENTION_KINDS})
 
 
 def target_logp(h, embed, layout, labels, label_lens):

@@ -32,10 +32,27 @@ and decode steps, and
   lm_idle_slot_steps              steps a finished stream still occupied
                                   its slot of the batch
   lm_cache_rows_read              cache rows the active streams' steps
-                                  attended to
+                                  attended to: in one layer where all
+                                  layers see the same rows, else summed
+                                  over the layers (the two below)
   lm_cache_bytes (gauge)          bytes of the cache, a draft module's
                                   array included (set where it is
                                   allocated)
+  moe_empty_groups                held experts of an expert layer's call
+                                  (a prefill sub-batch's, a decode
+                                  step's) that received no pair: groups
+                                  of its grouped products with no row
+
+and, where layers are grouped-query attention with caches per kind
+(a ring of ``lfm_window`` rows, or every row):
+
+  lm_rows_attended_window         cache rows the steps attended to in
+                                  the windowed layers (``lfm_window`` a
+                                  stream and layer at most)
+  lm_rows_attended_global         the same in the layers that see all
+  lm_ring_wraps                   streams of the calls whose position
+                                  passed the window (their rings wrapped)
+  lm_cache_bytes_window / _global (gauges) the cache's bytes per kind
 
 and, where the loop drafts for itself (``model.lm_draft_layers``):
 
@@ -155,8 +172,27 @@ def observe_lm_call(prefill: Sequence[Dict], decode: Dict, rows: int
            "decode_steps": steps, "idle_slot_steps": idle,
            "cache_rows_read": int(decode["cache_rows_read"]), "rows": rows}
     if "experts_hit" in decode:
-        out["experts_hit"] = int(np.sum(decode["experts_hit"]))
+        hit = np.asarray(decode["experts_hit"])
+        out["experts_hit"] = int(np.sum(hit))
+        out["experts_hit_by_layer"] = hit.tolist()
         reg.count("moe_experts_hit", out["experts_hit"])
+        # Groups without a row: per decode step and layer the held
+        # experts that were not hit, per prefill sub-batch and layer
+        # those with no pair.
+        held = np.shape(decode["expert_pairs"])[-1]
+        out["empty_groups"] = {
+            "decode": int(steps * held * hit.size - np.sum(hit)),
+            "prefill": int(sum(np.sum(np.asarray(c["expert_pairs"]) == 0)
+                               for c in prefill)),
+            "decode_calls": steps * hit.size,
+            "prefill_calls": len(prefill) * hit.size, "groups": held}
+        reg.count("moe_empty_groups", out["empty_groups"]["decode"]
+                  + out["empty_groups"]["prefill"])
+    for k in ("rows_attended_window", "rows_attended_global",
+              "ring_wraps"):
+        if k in decode:
+            out[k] = int(decode[k])
+            reg.count("lm_" + k, out[k])
     reg.count("lm_decode_steps", steps)
     reg.count("lm_idle_slot_steps", idle)
     reg.count("lm_cache_rows_read", out["cache_rows_read"])

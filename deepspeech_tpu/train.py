@@ -548,16 +548,16 @@ class Trainer:
             raise ValueError("objective='lm' excludes accum_steps>1 "
                              "(its step carries the routing counters)")
         if objective == "lm" and eval_pipeline is not None:
-            from .models.lfm2 import cached_kinds
+            from .models.lfm2 import uncached_kinds
 
-            if not cached_kinds(cfg.model):
+            if uncached_kinds(cfg.model):
                 # Fail at construction, not after an epoch of work.
                 raise ValueError(
                     "objective='lm': transcripts are decoded through a "
-                    "cache, which latent attention alone has; this "
-                    "preset's layers lack a convolution state and a "
-                    "grouped-query key/value cache, so no in-training "
-                    "eval: pass no eval_pipeline")
+                    "cache, which latent and grouped-query attention "
+                    "have; this preset's layers lack a convolution "
+                    "state, so no in-training eval: pass no "
+                    "eval_pipeline")
         stages = cfg.model.pipeline_stages
         if stages > 1:
             # Training with a pipelined model silently falling back to

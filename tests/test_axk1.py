@@ -326,7 +326,7 @@ def test_inferencer_transcribes_with_the_preset():
     lfm2 = apply_overrides(get_config("lfm2_24b_a2b"),
                            {"decode.mode": "lm_greedy"})
     with pytest.raises(NotImplementedError,
-                       match="convolution state.*key/value cache"):
+                       match="lacks a 2-position convolution state "):
         Inferencer(lfm2, CharTokenizer.synthetic_zh(V - 1), {}, {})
 
 

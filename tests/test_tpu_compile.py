@@ -72,6 +72,12 @@ def v5e_chip():
     ("moe_gmm_axk1_prefill_w2", ["moe_gmm"]),
     ("moe_gmm_axk1_decode_w13", ["moe_gmm"]),
     ("moe_gmm_axk1_decode_w2", ["moe_gmm"]),
+    # trinity_large's over 32 held experts: a prefill sub-batch's
+    # 10,752 rows and a decode step's single row tile of 128
+    ("moe_gmm_trinity_prefill_w13", ["moe_gmm"]),
+    ("moe_gmm_trinity_prefill_w2", ["moe_gmm"]),
+    ("moe_gmm_trinity_decode_w13", ["moe_gmm"]),
+    ("moe_gmm_trinity_decode_w2", ["moe_gmm"]),
 ])
 def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     """Mosaic accepts the kernel, and the compiled instruction still

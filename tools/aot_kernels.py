@@ -291,6 +291,16 @@ def kernel_cases():
             7168, 4096, m, 12)
         cases[f"moe_gmm_axk1_{name}_w2"] = moe_forward_case(
             2048, 7168, m, 12)
+    # trinity_large.transcribe_long_7min_b16: gate+up (3072 -> 2 x 3072)
+    # and down (3072 -> 3072) of 32 held experts, forward only: a
+    # prefill sub-batch's 10,752 static rows (row tiles of 512) and a
+    # decode step's 128 (ONE row tile of 128 for about 8 pairs: most of
+    # the 32 groups are empty).
+    for name, m in (("prefill", 10752), ("decode", 128)):
+        cases[f"moe_gmm_trinity_{name}_w13"] = moe_forward_case(
+            3072, 6144, m, 32)
+        cases[f"moe_gmm_trinity_{name}_w2"] = moe_forward_case(
+            3072, 3072, m, 32)
     cases["ctc_aishell"] = ctc_case(4336, 400, 60)
     cases["ctc_en"] = ctc_case(29, 400, 160)
     # The weak-#1 shape: AISHELL-width device beam search, both merge

@@ -66,7 +66,7 @@ def serve_lm(args, cfg, topo) -> None:
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from deepspeech_tpu.decode.lm_greedy import WATCH, LMGreedy
+    from deepspeech_tpu.decode.lm_greedy import LMGreedy
     from deepspeech_tpu.models.lfm2 import seeded_variables
 
     chip = SingleDeviceSharding(topo.devices[0])
@@ -85,9 +85,11 @@ def serve_lm(args, cfg, topo) -> None:
         shape, dtype, sharding=chip)
     # One array a layer and one a draft module; with a module the loop
     # also takes each stream's first draft.
-    cache = [sds((b, m.lfm_seq_positions, m.mla_kv_rank + m.mla_rope_dim),
-                 jnp.dtype(m.dtype))
-             for _ in range(len(m.lfm_layer_types) + m.lm_draft_layers)]
+    # An array a latent layer or draft module, (keys, values) a
+    # grouped-query layer.
+    cache = [sds(s[0], jnp.dtype(m.dtype)) if len(s) == 1
+             else tuple(sds(x, jnp.dtype(m.dtype)) for x in s)
+             for s in engine.cache_shapes(b, args.frames)]
     draft = (sds((b,), jnp.int32),) if m.lm_draft_layers else ()
     programs = {
         "prefill": (engine._prefill, (
@@ -97,7 +99,8 @@ def serve_lm(args, cfg, topo) -> None:
         "decode": (engine._decode, (
             params, buffers, cache, sds((b,), jnp.int32),
             sds((b,), jnp.int32), sds((b, t), jnp.int32),
-            sds((min(WATCH, b),), jnp.int32), sds((), jnp.bool_))
+            sds((min(cfg.decode.lm_watch_rows, b),), jnp.int32),
+            sds((), jnp.bool_))
             + draft),
     }
     out = {"tool": "aot_tpu", "preset": args.preset, "batch": b,

@@ -41,7 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from ..ops import moe
+from ..ops import attn_pallas, moe
+from ..utils.impl import on_tpu
 from . import mhc
 from .axk1 import LatentAttention
 from .rnn import stack_frames
@@ -241,19 +242,28 @@ class Attention(nn.Module):
             probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
             return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
 
+        def blockwise(q, k, v):
+            """:func:`attend` a block of queries at a time, each
+            against the keys in its reach: the plain form of the
+            sequence past one block, and the kernel's oracle."""
+            outs = []
+            for i0 in range(0, s, self.block):
+                i1 = min(i0 + self.block, s)
+                j0 = max(0, i0 - window + 1) if window else 0
+                outs.append(attend(q[:, i0:i1], k[:, j0:i1],
+                                   v[:, j0:i1], i0, j0))
+            return jnp.concatenate(outs, axis=1)
+
         if cache is None:
             kept = (k, v)
             with jax.named_scope(scope):
                 if s <= self.block:
                     out = attend(q, k, v, 0, 0)
+                elif on_tpu() and attn_pallas.fits(hd):
+                    out = attn_pallas.gqa_attention(q, k, v, window,
+                                                    blockwise)
                 else:
-                    outs = []
-                    for i0 in range(0, s, self.block):
-                        i1 = min(i0 + self.block, s)
-                        j0 = max(0, i0 - window + 1) if window else 0
-                        outs.append(attend(q[:, i0:i1], k[:, j0:i1],
-                                           v[:, j0:i1], i0, j0))
-                    out = jnp.concatenate(outs, axis=1)
+                    out = blockwise(q, k, v)
         elif s != 1:
             raise NotImplementedError(
                 f"grouped-query attention decodes one new position a "

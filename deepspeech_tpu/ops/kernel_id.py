@@ -33,6 +33,17 @@ fact, not a name:
            moe_gmm / moe_tgmm: static row capacity, contraction and
            output widths, groups (experts held); moe_gmm also
            ``transpose_rhs`` (1 for the gradient to the rows)
+  b, s, kv, rep, head, window
+           gqa_attn_fwd: rows, positions, key/value heads, query heads
+           a key/value head serves, a head's size, the window (0: the
+           layer sees all); ``q_tile`` / ``k_tile``: queries of one
+           head and keys a tile; and, a (row, key/value head),
+           ``key_tiles`` (key tiles the grid computes, over all query
+           tiles), ``key_tiles_in_reach`` (those that hold a key one of
+           the tile's queries can reach) and ``key_tiles_masked``
+           (those that hold a key out of reach as well: the masked
+           work a tile size costs is ``key_tiles x q_tile x k_tile``
+           scores against the ones ``s`` and ``window`` need)
 """
 
 from __future__ import annotations
@@ -57,6 +68,7 @@ KERNELS = frozenset({
     "ctc_gamma",          # beta recursion folded into the occupancies
     "moe_gmm",            # rows by ragged groups times each group's matrix
     "moe_tgmm",           # per group, rows^T times rows: weight gradients
+    "gqa_attn_fwd",       # causal grouped-query attention, scores in VMEM
 })
 
 

@@ -115,19 +115,35 @@ def test_sequence_form_equals_the_references_masks(kind, block):
     assert trinity_ref.rms_rel(other[:, W:], want[:, W:]) > 1e-2
 
 
-@pytest.mark.parametrize("kind, rows", [("sliding_attention", W),
-                                        ("sliding_attention", S),
-                                        ("full_attention", S)])
-def test_the_two_forms_agree_past_the_window(kind, rows):
+@pytest.mark.parametrize("kind, rows, kernel", [
+    ("sliding_attention", W, False), ("sliding_attention", S, False),
+    ("full_attention", S, False),
+    # the sequence form through ``gqa_attn_fwd`` (interpreted), as the
+    # chip runs it past one block: heads of 128, a TPU assumed
+    ("sliding_attention", W, True), ("full_attention", S, True)])
+def test_the_two_forms_agree_past_the_window(kind, rows, kernel,
+                                             monkeypatch):
     """The decode form against a ring (and against a cache that never
     wraps) equals the sequence form at positions before, at and past
     the window."""
-    cfg = toy()
+    from jax.experimental.pallas import tpu as pltpu
+
+    cfg = toy(lfm_head_dim=128) if kernel else toy()
     params = attention_params(cfg, kind)
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 33, 32))
     at = np.array([0, 3, W - 1, W, W + 1, 2 * W, 3 * W + 5, 32])
-    dec, seq = lfm2.both_forms(cfg.model, kind, params, x, at, rows,
+
+    def forms(x):
+        return lfm2.both_forms(cfg.model, kind, params, x, at, rows,
                                block=8)
+
+    if kernel:
+        monkeypatch.setenv("DS2N_ASSUME_TPU", "1")
+        assert "name=gqa_attn_fwd" in str(jax.make_jaxpr(forms)(x))
+        with pltpu.force_tpu_interpret_mode():
+            dec, seq = forms(x)
+    else:
+        dec, seq = forms(x)
     assert trinity_ref.rms_rel(dec, seq) < 2e-5
 
 

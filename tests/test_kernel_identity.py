@@ -95,6 +95,14 @@ CASES = {
          "groups": "8", "transpose_rhs": "1"},
         {"kernel": "moe_tgmm", "m": "32256", "k": "1536", "n": "2048",
          "groups": "8"}],
+    # trinity_large's prefill attention in a sliding layer: a sub-batch
+    # of 2 recordings of 5,250 positions; 117 key tiles a (row,
+    # key/value head) over 21 query tiles, 26 of them masked
+    "gqa_attn_fwd_trinity_window": [
+        {"kernel": "gqa_attn_fwd", "b": "2", "s": "5250", "kv": "8",
+         "rep": "6", "head": "128", "window": "4096", "q_tile": "256",
+         "k_tile": "512", "key_tiles": "117", "key_tiles_in_reach": "117",
+         "key_tiles_masked": "26"}],
 }
 
 
@@ -259,7 +267,7 @@ def test_every_name_of_the_vocabulary_is_built_somewhere():
     reader's dead branch."""
     used = set()
     for name in ("rnn_pallas.py", "lstm_pallas.py", "ctc_pallas.py",
-                 "moe_pallas.py"):
+                 "moe_pallas.py", "attn_pallas.py"):
         with open(os.path.join(REPO, "deepspeech_tpu", "ops", name)) as f:
             used.update(re.findall(r'kernel="(\w+)"', f.read()))
     assert used == kernel_id.KERNELS

@@ -78,6 +78,13 @@ def v5e_chip():
     ("moe_gmm_trinity_prefill_w2", ["moe_gmm"]),
     ("moe_gmm_trinity_decode_w13", ["moe_gmm"]),
     ("moe_gmm_trinity_decode_w2", ["moe_gmm"]),
+    # trinity_large's prefill attention, a sub-batch of 2 recordings of
+    # 5,250 positions (20 query tiles of 256 and one of 130 that hangs
+    # over the end), 48 / 8 heads of 128: 6 x 256 x 512 float32 scores
+    # a tile under a 64 MiB scoped-VMEM limit; a sliding layer (window
+    # 4,096) and the global one
+    ("gqa_attn_fwd_trinity_window", ["gqa_attn_fwd"]),
+    ("gqa_attn_fwd_trinity_global", ["gqa_attn_fwd"]),
 ])
 def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     """Mosaic accepts the kernel, and the compiled instruction still

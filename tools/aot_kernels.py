@@ -241,6 +241,18 @@ def kernel_cases():
             return train, args
         return f
 
+    def attn_case(window):
+        """trinity_large's grouped-query attention over a prefill
+        sub-batch in one layer: ``gqa_attn_fwd`` alone, at the module's
+        tiles."""
+        from deepspeech_tpu.ops import attn_pallas
+
+        args = (S((2, 5250, 8, 6, 128), jnp.bfloat16),
+                S((2, 5250, 8, 128), jnp.bfloat16),
+                S((2, 5250, 8, 128), jnp.bfloat16))
+        return lambda: (lambda q, k, v: attn_pallas.gqa_attention(
+            q, k, v, window, None), args)
+
     cases["gru_h800"] = gru_case(800)
     cases["gru_h1760"] = gru_case(1760)
     # ds2_full.train_1chip's own call (850 post-conv frames, bf16
@@ -301,6 +313,11 @@ def kernel_cases():
             3072, 6144, m, 32)
         cases[f"moe_gmm_trinity_{name}_w2"] = moe_forward_case(
             3072, 3072, m, 32)
+    # trinity_large.transcribe_long_7min_b16: a prefill sub-batch of 2
+    # recordings, 5,250 positions (20 query tiles of 256 and one of
+    # 130), 48 / 8 heads of 128, a sliding layer and the global one.
+    cases["gqa_attn_fwd_trinity_window"] = attn_case(4096)
+    cases["gqa_attn_fwd_trinity_global"] = attn_case(0)
     cases["ctc_aishell"] = ctc_case(4336, 400, 60)
     cases["ctc_en"] = ctc_case(29, 400, 160)
     # The weak-#1 shape: AISHELL-width device beam search, both merge

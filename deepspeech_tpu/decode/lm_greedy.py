@@ -50,7 +50,7 @@ the cache rows are fewer: a ring that never wraps). Prefill writes a
 stream's last ``min(a, R)`` prefix rows into a ring and all of them
 into a full cache; a step writes its row in slot ``pos mod R`` and
 attends to the rows the layer can reach; the call counts the rows
-attended per kind.
+attended per kind, and the rows fetched to do so.
 """
 
 from __future__ import annotations
@@ -64,8 +64,10 @@ import numpy as np
 
 from .. import obs
 from ..config import Config
-from ..models.lfm2 import (ATTENTION_KINDS, create_lfm2_model, head_dim,
-                           ring_positions, seq_positions, uncached_kinds)
+from ..models.lfm2 import (ATTENTION_KINDS, attends_in_kernels,
+                           create_lfm2_model, head_dim, ring_positions,
+                           seq_positions, uncached_kinds)
+from ..ops import attn_pallas
 
 
 def _watched(mid: dict, rows, layers: List[str], mixed: str = "",
@@ -227,6 +229,10 @@ class LMGreedy:
                 for key, n in self._reach(reach).items():
                     acc[key] += n
                     acc["cache_rows_read"] += n
+                # Rows the layers moved to attend to them.
+                for key, n in self._fetched(a_lens + j, active,
+                                            cache).items():
+                    acc[key] += n
             else:
                 acc["cache_rows_read"] += jnp.sum(reach)
             self._count(acc, counters)
@@ -250,8 +256,9 @@ class LMGreedy:
         w = watch.shape[0]
         seen = {"logits": jnp.zeros((w, t, m.vocab_size), jnp.float32)}
         if any(self.kinds.values()):
-            acc.update(rows_attended_window=jnp.int32(0),
-                       rows_attended_global=jnp.int32(0))
+            acc.update({k: jnp.int32(0) for k in (
+                "rows_attended_window", "rows_attended_global",
+                "rows_fetched_window", "rows_fetched_global")})
             width = m.lfm_heads * head_dim(m)
             seen.update({f"gated{i}": jnp.zeros((w, t, width),
                                                 jnp.dtype(m.dtype))
@@ -284,6 +291,28 @@ class LMGreedy:
                     self.kinds["sliding_attention"]),
                 "rows_attended_global": jnp.sum(reach) * len(
                     self.kinds["full_attention"])}
+
+    def _fetched(self, pos, active, cache) -> dict:
+        """Cache rows (of keys; as many of values) a step's layers
+        fetch, per kind: where the decode form is the kernel
+        ``gqa_attn_decode`` (``models/lfm2.Attention``'s own choice)
+        the rows of the tiles it visits for the active streams, else
+        every row of every stream."""
+        m = self.cfg.model
+        kernel = attends_in_kernels(m)
+        out = {}
+        for kind, name, window in (
+                ("sliding_attention", "window", m.lfm_window),
+                ("full_attention", "global", 0)):
+            layers = self.kinds[kind]
+            if not layers:
+                out["rows_fetched_" + name] = jnp.int32(0)
+                continue
+            rows = cache[layers[0]][0].shape[1]    # the kind's, every layer
+            out["rows_fetched_" + name] = len(layers) * (
+                attn_pallas.rows_fetched(pos, active, rows, window)
+                if kernel else jnp.int32(pos.shape[0] * rows))
+        return out
 
     def _decode_drafting(self, params, buffers, cache, a_lens, max_tokens,
                          forced, watch, ignore_end, draft):

@@ -151,6 +151,13 @@ def head_dim(cfg: ModelConfig) -> int:
     return cfg.lfm_head_dim or cfg.lfm_hidden // cfg.lfm_heads
 
 
+def attends_in_kernels(cfg: ModelConfig) -> bool:
+    """Whether grouped-query attention runs as the kernels of
+    ``ops/attn_pallas.py`` (the sequence past one block, and the decode
+    form): on a TPU, with heads of whole lane tiles."""
+    return on_tpu() and attn_pallas.fits(head_dim(cfg))
+
+
 def reach_mask(i0: int, sq: int, j0: int, sk: int, window: int):
     """``[sq, sk]``: which of the keys ``j0 .. j0 + sk`` each of the
     queries ``i0 .. i0 + sq`` attends to: ``j <= i``, and ``i - window <
@@ -175,6 +182,23 @@ def ring_positions(pos, rows: int):
     return pos[:, None] - (pos[:, None] - slot) % rows
 
 
+def cached_attend(q, keys, values, pos, window: int):
+    """The decode form's plain mixing, and the kernel's oracle: one
+    query a stream ``q [B, kv, rep, hd]`` at position ``pos [B]``
+    against the slots of ``keys, values [B, R, kv, hd]`` whose position
+    it can reach; ``[B, kv, rep, hd]``."""
+    held = ring_positions(pos, keys.shape[1])
+    seen = held >= 0
+    if window:
+        seen &= pos[:, None] - held < window
+    scores = jnp.einsum("bgrd,bkgd->bgrk", q, keys,
+                        preferred_element_type=jnp.float32)
+    scores = jnp.where(seen[:, None, None, :],
+                       scores * (q.shape[-1] ** -0.5), -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrk,bkgd->bgrd", probs, values)
+
+
 class Attention(nn.Module):
     """Causal grouped-query attention: RMSNorm over each head of q and
     of k, then the rotation where the layer's ``kind`` has one
@@ -197,7 +221,11 @@ class Attention(nn.Module):
     slot ``pos mod R`` (a ring of R = ``lfm_window``
     rows for a sliding layer; R above every position for a global one,
     which never wraps) and the query attends to the slots whose
-    position it can reach (``live [B, 1]``: the streams that write); it
+    position it can reach (``live [B, 1]``: the streams that write;
+    the kernel ``gqa_attn_decode`` where :func:`attends_in_kernels`
+    holds, which fetches a stream's rows once, only the row tiles in
+    reach and none for a stream that is not live, whose output is then
+    zeros; elsewhere, and as its oracle, :func:`cached_attend`); it
     returns the output and the cache. Keys are stored rotated by their
     ABSOLUTE position, so a ring's order means nothing to the
     softmax."""
@@ -259,7 +287,7 @@ class Attention(nn.Module):
             with jax.named_scope(scope):
                 if s <= self.block:
                     out = attend(q, k, v, 0, 0)
-                elif on_tpu() and attn_pallas.fits(hd):
+                elif attends_in_kernels(cfg):
                     out = attn_pallas.gqa_attention(q, k, v, window,
                                                     blockwise)
                 else:
@@ -278,16 +306,11 @@ class Attention(nn.Module):
                 keys, values = (c.at[at_slot].set(jnp.where(
                     live[:, :, None], row[:, 0].astype(c.dtype),
                     c[at_slot])) for c, row in zip(cache, (k, v)))
-                held = ring_positions(at, r)
-                seen = held >= 0
-                if window:
-                    seen &= at[:, None] - held < window
-                scores = jnp.einsum("bgrd,bkgd->bgrk", q[:, 0], keys,
-                                    preferred_element_type=jnp.float32)
-                scores = jnp.where(seen[:, None, None, :],
-                                   scores * (hd ** -0.5), -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
-                out = jnp.einsum("bgrk,bkgd->bgrd", probs, values)
+                if attends_in_kernels(cfg):
+                    out = attn_pallas.gqa_decode(
+                        q[:, 0], keys, values, at, live[:, 0], window)
+                else:
+                    out = cached_attend(q[:, 0], keys, values, at, window)
             kept = (keys, values)
         out = out.reshape(b, s, nh * hd)
         if cfg.lfm_attn_gate:

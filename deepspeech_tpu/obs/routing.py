@@ -50,6 +50,12 @@ and, where layers are grouped-query attention with caches per kind
                                   the windowed layers (``lfm_window`` a
                                   stream and layer at most)
   lm_rows_attended_global         the same in the layers that see all
+  lm_rows_fetched_window / _global
+                                  cache rows those layers MOVED to do
+                                  so: the row tiles the kernel
+                                  ``gqa_attn_decode`` visits for the
+                                  active streams, or every row of every
+                                  stream where the plain form runs
   lm_ring_wraps                   streams of the calls whose position
                                   passed the window (their rings wrapped)
   lm_cache_bytes_window / _global (gauges) the cache's bytes per kind
@@ -189,7 +195,7 @@ def observe_lm_call(prefill: Sequence[Dict], decode: Dict, rows: int
         reg.count("moe_empty_groups", out["empty_groups"]["decode"]
                   + out["empty_groups"]["prefill"])
     for k in ("rows_attended_window", "rows_attended_global",
-              "ring_wraps"):
+              "rows_fetched_window", "rows_fetched_global", "ring_wraps"):
         if k in decode:
             out[k] = int(decode[k])
             reg.count("lm_" + k, out[k])

@@ -1,7 +1,12 @@
-"""Causal grouped-query attention over a whole sequence as ONE Pallas
-kernel, ``gqa_attn_fwd``: the scores of a query tile against a key tile
-live in VMEM only, under a running row maximum and sum (the online
-softmax of flash attention), so no score reaches HBM.
+"""Grouped-query attention's two forms as one Pallas kernel each:
+``gqa_attn_fwd`` (:func:`gqa_attention`, a whole sequence, described
+here) and ``gqa_attn_decode`` (:func:`gqa_decode`, one position a
+stream against its cache, described there).
+
+Causal grouped-query attention over a whole sequence: the scores of a
+query tile against a key tile live in VMEM only, under a running row
+maximum and sum (the online softmax of flash attention), so no score
+reaches HBM.
 
 ``gqa_attention(q [B,S,kv,rep,hd], k, v [B,S,kv,hd], window, oracle)``
 returns ``[B,S,kv,rep,hd]``: query i attends to the keys ``j <= i``, and
@@ -62,6 +67,12 @@ K_TILE = 512
 _MASKED = -1e30
 
 
+def _wide(x, n: int):
+    """A row statistic ``[rows, lanes]`` (lanes 1, or a lane tile that
+    holds it in every lane) against ``n`` columns."""
+    return x if x.shape[1] == 1 else jnp.tile(x, (1, n // x.shape[1]))
+
+
 def _run(i0, s: int, window: int, tq: int, tk: int, xp):
     """First and last key tile that hold a key in reach of the query
     tile that starts at ``i0`` (numpy for the static counts, jax.numpy
@@ -115,10 +126,6 @@ def _fwd(q, k, v, window: int, tq: int, tk: int, interpret: bool):
     # along the lanes in every step (12.7 -> 8.6 ms a layer on the chip).
     lanes = 128 if tk % 128 == 0 and hd % 128 == 0 else 1
 
-    def wide(x, n):
-        """A row statistic ``[tq, lanes]`` against ``n`` columns."""
-        return x if lanes == 1 else jnp.tile(x, (1, n // lanes))
-
     def run_of(qi):
         return _run(qi * tq, s, window, tq, tk, jnp)
 
@@ -161,11 +168,11 @@ def _fwd(q, k, v, window: int, tq: int, tk: int, interpret: bool):
                 m_next = jnp.maximum(
                     m_prev, jnp.max(scores, axis=1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_next)
-                p = jnp.exp(scores - wide(m_next, tk))
+                p = jnp.exp(scores - _wide(m_next, tk))
                 l_ref[r] = alpha * l_ref[r] + jnp.sum(
                     p, axis=1, keepdims=True)
                 m_ref[r] = m_next
-                acc_ref[r] = wide(alpha, hd) * acc_ref[r] + jnp.dot(
+                acc_ref[r] = _wide(alpha, hd) * acc_ref[r] + jnp.dot(
                     p.astype(values.dtype), values,
                     preferred_element_type=jnp.float32)
 
@@ -181,7 +188,7 @@ def _fwd(q, k, v, window: int, tq: int, tk: int, interpret: bool):
         @pl.when(step == steps - 1)
         def _finish():
             for r in range(rep):
-                o_ref[r] = (acc_ref[r] / wide(l_ref[r], hd)).astype(
+                o_ref[r] = (acc_ref[r] / _wide(l_ref[r], hd)).astype(
                     o_ref.dtype)
 
     def q_index(bi, g, qi, step):
@@ -243,6 +250,200 @@ def _attention_bwd(window, oracle, q_tile, k_tile, interpret, res, grad):
 
 
 gqa_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+# Cache rows a grid step of the decode kernel fetches (PERF.md section
+# 6, PR 43 has the sweep).
+ROW_TILE = 512
+
+
+def decode_run(pos, live, rows: int, window: int, rt: int, xp=jnp):
+    """First and last row tile of ``rt`` slots that the decode kernel
+    visits for a stream at position ``pos`` (its new row written) in a
+    cache of ``rows`` slots, slot ``p mod rows`` holding position p:
+    the tiles between them are those that hold a slot in reach by
+    ``models/lfm2.ring_positions``' rule (a position held, and inside
+    the window where there is one). A cache that has not wrapped holds
+    them in the slots ``max(pos - window + 1, 0) .. pos``; one that has
+    wrapped holds one in every slot unless the window is shorter than
+    the cache, where every tile is visited and the mask decides. A
+    stream that is not ``live`` visits none (last < first)."""
+    wrapped = pos >= rows
+    lo = xp.maximum(pos - window + 1, 0) if window else 0 * pos
+    first = xp.where(wrapped, 0, lo) // rt
+    last = xp.where(wrapped, rows - 1, pos) // rt
+    return first, xp.where(live, last, first - 1)
+
+
+def rows_fetched(pos, live, rows: int, window: int, rt: int = 0, xp=jnp):
+    """Cache rows (of keys; as many of values) the decode kernel moves
+    for the streams ``pos, live [B]`` at row tiles of ``rt`` (0: the
+    module's): its visited tiles' rows, the last tile cut at the
+    cache's end."""
+    rt = rt or ROW_TILE
+    first, last = decode_run(pos, live, rows, window, rt, xp)
+    return xp.sum(xp.where(
+        live, xp.minimum((last + 1) * rt, rows) - first * rt, 0))
+
+
+def gqa_decode(q, keys, values, pos, live, window: int,
+               row_tile: int = 0, interpret: bool = False):
+    """The DECODE form as ONE Pallas kernel, ``gqa_attn_decode``: one
+    query position a stream, ``q [B, kv, rep, hd]``, against the
+    stream's cache ``keys, values [B, R, kv, hd]`` (the new row
+    written) at position ``pos [B]``; returns ``[B, kv, rep, hd]``,
+    zeros for a stream that is not ``live [B]``.
+
+    The grid is (stream, row tile), the row tile innermost. The cache
+    is read where it lies, as ``[B, R x kv, hd]`` (a row's key/value
+    heads are neighbouring rows of the array, so a tile is one
+    contiguous piece of HBM), fetched ONCE for all ``kv x rep`` query
+    heads: ``q [kv x rep, hd]`` against the tile's ``rt x kv`` rows is
+    ONE product, of which a query head keeps the columns of its own
+    key/value head (the others are masked like slots out of reach, so
+    their probabilities are exact zeros in the second product, ``p
+    [kv x rep, rt x kv] . V [rt x kv, hd]``). The MXU loads each row
+    of K and V once either way; the masked columns cost exponents, not
+    bandwidth. Arithmetic and precisions are ``gqa_attn_fwd``'s.
+
+    A stream visits the tiles :func:`decode_run` gives; a step past
+    them fetches nothing (its block index stays) and computes nothing,
+    and a stream that is not live holds the block of the live stream
+    before it, so it fetches nothing at all (with none before it, the
+    grid's first block, fetched once a call). Tiles wholly in reach
+    skip the reach mask. The last tile may hang over the cache's end:
+    its overhang is masked and its values zeroed."""
+    b, nkv, rep, hd = q.shape
+    rt = row_tile or ROW_TILE
+    rows, heads, cols = keys.shape[1], nkv * rep, rt * nkv
+    tiles = -(-rows // rt)
+    ragged = rows % rt != 0
+    # a window shorter than the cache cuts a wrapped cache's reach
+    binds = bool(window) and window < rows
+    scale = hd ** -0.5
+    lanes = 128 if cols % 128 == 0 and hd % 128 == 0 else 1
+    pos = pos.astype(jnp.int32)
+    first, last = decode_run(pos, live, rows, window, rt)
+    # The live stream at or before each stream (its own number for a
+    # live one) and that stream's last tile: the block a stream that
+    # is not live holds. Before the first live stream it is the grid's
+    # first block, which the pipeline fetches whatever it is.
+    stream = jnp.arange(b)
+    before = jnp.max(jnp.where(
+        (stream[None, :] <= stream[:, None]) & live[None, :],
+        stream[None, :], -1), axis=1)
+    its_last = jnp.sum(jnp.where(
+        stream[None, :] == before[:, None], last[None, :], 0), axis=1)
+    bounds = jnp.stack([
+        pos, first, last, jnp.maximum(before, 0),
+        jnp.where(before >= 0, its_last, first[0])]).astype(jnp.int32)
+
+    def body(bounds_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
+        bi, step = pl.program_id(0), pl.program_id(1)
+        at, lo, hi = bounds_ref[0, bi], bounds_ref[1, bi], bounds_ref[2, bi]
+        j0 = (lo + step) * rt
+        wrapped = at >= rows
+
+        @pl.when(step == 0)
+        def _start():
+            m_ref[...] = jnp.full_like(m_ref, _MASKED)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def tile(masked: bool):
+            values = v_ref[...]
+            scores = lax.dot_general(
+                q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            # column c is slot c // kv of the tile, key/value head
+            # c % kv; row h is a query head of key/value head h // rep
+            col = lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+            row = lax.broadcasted_iota(jnp.int32, (heads, 1), 0)
+            own = col % nkv == row // rep
+            if masked:
+                slot = j0 + col // nkv
+                seen = jnp.logical_or(wrapped, slot <= at)
+                if window:
+                    newest = at % rows
+                    age = jnp.where(slot <= newest, newest - slot,
+                                    newest - slot + rows)
+                    seen = jnp.logical_and(seen, age < window)
+                if ragged:
+                    seen = jnp.logical_and(seen, slot < rows)
+                    inside = j0 + lax.broadcasted_iota(
+                        jnp.int32, (cols, 1), 0) // nkv < rows
+                    values = jnp.where(inside, values,
+                                       jnp.zeros((), values.dtype))
+                own = jnp.logical_and(own, seen)
+            scores = jnp.where(own, scores * scale, _MASKED)
+            m_prev = m_ref[...]
+            m_next = jnp.maximum(
+                m_prev, jnp.max(scores, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(scores - _wide(m_next, cols))
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(
+                p, axis=1, keepdims=True)
+            m_ref[...] = m_next
+            acc_ref[...] = _wide(alpha, hd) * acc_ref[...] + jnp.dot(
+                p.astype(values.dtype), values,
+                preferred_element_type=jnp.float32)
+
+        whole = jnp.where(
+            wrapped, j0 + rt <= (0 if binds else rows),
+            jnp.logical_and(j0 + rt - 1 <= at,
+                            j0 > at - window if window else True))
+        run = lo + step <= hi
+        pl.when(jnp.logical_and(run, whole))(lambda: tile(False))
+        pl.when(jnp.logical_and(run, jnp.logical_not(whole)))(
+            lambda: tile(True))
+
+        @pl.when(step == tiles - 1)
+        def _finish():
+            # a stream that is not live has summed nothing
+            out = jnp.where(hi >= lo, acc_ref[...] / _wide(l_ref[...], hd),
+                            0.0).astype(o_ref.dtype)
+            for g in range(nkv):
+                o_ref[g] = out[g * rep:(g + 1) * rep]
+
+    def q_index(bi, step, bounds_ref):
+        return bi, 0, 0
+
+    def kv_index(bi, step, bounds_ref):
+        lo, hi = bounds_ref[1, bi], bounds_ref[2, bi]
+        return (bounds_ref[3, bi],
+                jnp.where(hi >= lo, jnp.minimum(lo + step, hi),
+                          bounds_ref[4, bi]), 0)
+
+    def out_index(bi, step, bounds_ref):
+        return bi, 0, 0, 0
+
+    facts = {"b": b, "rows": rows, "kv": nkv, "rep": rep, "head": hd,
+             "window": window, "row_tile": rt, "row_tiles": tiles}
+    return kernel_call(
+        body, kernel="gqa_attn_decode", facts=facts,
+        # what XLA's scheduler may count on when it places the loop's
+        # other copies about the call: every row of every stream
+        cost_estimate=pl.CostEstimate(
+            flops=4 * b * heads * rows * nkv * hd,
+            transcendentals=b * heads * rows * nkv,
+            bytes_accessed=(2 * b * rows * nkv * hd + 2 * b * heads * hd)
+            * q.dtype.itemsize),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, rep, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            in_specs=[pl.BlockSpec((None, heads, hd), q_index),
+                      pl.BlockSpec((None, cols, hd), kv_index),
+                      pl.BlockSpec((None, cols, hd), kv_index)],
+            out_specs=pl.BlockSpec((None, nkv, rep, hd), out_index),
+            grid=(b, tiles),
+            scratch_shapes=[pltpu.VMEM((heads, lanes), jnp.float32),
+                            pltpu.VMEM((heads, lanes), jnp.float32),
+                            pltpu.VMEM((heads, hd), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(bounds, q.reshape(b, heads, hd), keys.reshape(b, rows * nkv, hd),
+      values.reshape(b, rows * nkv, hd))
 
 
 def fits(head: int) -> bool:

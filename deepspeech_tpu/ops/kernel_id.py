@@ -44,6 +44,14 @@ fact, not a name:
            (those that hold a key out of reach as well: the masked
            work a tile size costs is ``key_tiles x q_tile x k_tile``
            scores against the ones ``s`` and ``window`` need)
+  b, rows, kv, rep, head, window, row_tile, row_tiles
+           gqa_attn_decode: streams, rows of a stream's cache (a ring's
+           or a full cache's), key/value heads, query heads each
+           serves, a head's size, the window (0: the layer sees all),
+           cache rows a grid step fetches and the grid's steps a
+           stream (how many of them a stream VISITS follows its
+           position and is counted by the loop:
+           ``lm_rows_fetched_window`` / ``_global``)
 """
 
 from __future__ import annotations
@@ -69,6 +77,7 @@ KERNELS = frozenset({
     "moe_gmm",            # rows by ragged groups times each group's matrix
     "moe_tgmm",           # per group, rows^T times rows: weight gradients
     "gqa_attn_fwd",       # causal grouped-query attention, scores in VMEM
+    "gqa_attn_decode",    # one query a stream against its cache rows in reach
 })
 
 

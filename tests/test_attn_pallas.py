@@ -1,6 +1,12 @@
-"""``ops/attn_pallas.py`` ``gqa_attn_fwd`` on the CPU, interpreted, at toy
-sizes in float32: the kernel against ``reach_mask``'s dense reference
-and against the blockwise loop it replaces, for sliding and global
+"""``ops/attn_pallas.py`` on the CPU, interpreted, at toy sizes in
+float32. ``gqa_attn_decode`` against the plain decode form
+(``models/lfm2.cached_attend``): rings that have not wrapped, are about
+to and have, a global cache's position on and about a tile's edge, a
+finished stream among live ones, the last tile hanging over a cache of
+6,784 rows, the tiles a stream visits against ``ring_positions``' mask
+itself, and ``Attention``'s choice. ``gqa_attn_fwd``: the kernel
+against ``reach_mask``'s dense reference and against the blockwise loop
+it replaces, for sliding and global
 layers, prefixes that are not whole tiles, shorter than the window and
 past window + tile, and tiles of several shapes; a stream's padded tail
 changes no valid row; the gradient past one block is the loop's; the
@@ -145,6 +151,117 @@ def test_tile_counts(s, window, tq, tk, counts):
     assert np.all(first <= last) and last[-1] == (s - 1) // tk
 
 
+def decode_inputs(rows, seed=0, streams=4, hd=HD):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(keys[0], (streams, KV, REP, hd)),
+            jax.random.normal(keys[1], (streams, rows, KV, hd)),
+            jax.random.normal(keys[2], (streams, rows, KV, hd)))
+
+
+def decode_kernel(q, k, v, pos, live, window, rt):
+    return attn_pallas.gqa_decode(q, k, v, jnp.asarray(pos),
+                                  jnp.asarray(live, bool), window, rt, True)
+
+
+@pytest.mark.parametrize("rt", [4, 8, 16])
+@pytest.mark.parametrize("rows, window, pos", [
+    # a ring of the window's rows: not yet wrapped, its last slot
+    # written (R - 1), the first row written again, long wrapped
+    (W, W, [0, 5, W - 2, W - 1]), (W, W, [W, W + 1, 3 * W - 1, 5 * W + 3]),
+    # a cache longer than the window: the window cuts its reach before
+    # it wraps and (two runs of slots) after
+    (40, W, [3, W - 1, W, 39]), (40, W, [40, 41, 47, 95]),
+    # a cache that sees all, never wrapped: a position on a tile's
+    # edge, just before it and just after it
+    (40, 0, [15, 16, 17, 39]), (40, 0, [0, 7, 8, 31]),
+    # not whole tiles of any size here
+    (37, 0, [0, 20, 35, 36]), (37, W, [2, 19, 36, 80])])
+def test_decode_kernel_equals_the_plain_decode_form(rows, window, pos, rt):
+    q, k, v = decode_inputs(rows)
+    got = decode_kernel(q, k, v, pos, [True] * 4, window, rt)
+    want = lfm2.cached_attend(q, k, v, jnp.asarray(pos), window)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize("window", [40, 0], ids=["sliding", "global"])
+def test_decode_kernel_at_whole_lane_tiles(window):
+    """Heads of 128 and row tiles of 128 columns: the shapes of the
+    chip, where a head's running maximum and sum fill a lane tile."""
+    q, k, v = decode_inputs(40, seed=3, hd=128)
+    pos = [11, 31, 39, 90 if window else 32]
+    got = decode_kernel(q, k, v, pos, [True] * 4, window, 64)
+    np.testing.assert_allclose(
+        got, lfm2.cached_attend(q, k, v, jnp.asarray(pos), window),
+        atol=2e-6)
+
+
+@pytest.mark.parametrize("rows, window", [(W, W), (40, 0)],
+                         ids=["sliding", "global"])
+@pytest.mark.parametrize("live", [[True, False, True, True],
+                                  [False, False, True, False],
+                                  [True, True, False, False],
+                                  [False] * 4])
+def test_a_finished_stream_is_not_read_and_gives_zeros(rows, window, live):
+    """A stream that is not live fetches no row (its cache is NaN here)
+    and writes zeros; no live stream's result moves."""
+    q, k, v = decode_inputs(rows, seed=1)
+    pos = [9, 30, 12, 33]
+    dead = ~np.asarray(live)[:, None, None, None]
+    got = decode_kernel(q, jnp.where(dead, jnp.nan, k),
+                        jnp.where(dead, jnp.nan, v), pos, live, window, 8)
+    want = decode_kernel(q, k, v, pos, [True] * 4, window, 8)
+    np.testing.assert_array_equal(got, jnp.where(dead, 0, want))
+
+
+def test_the_last_tile_hangs_over_a_cache_of_6784_rows():
+    """The cell's global cache is 13.25 row tiles of 512: the
+    interpreter fills the overhang with NaN, which must reach neither
+    the scores nor, through a probability of 0, the mix."""
+    rows = 6784
+    q, k, v = decode_inputs(rows, seed=2, streams=3)
+    pos = [6655, 6656, 6783]
+    got = decode_kernel(q, k, v, pos, [True] * 3, 0, attn_pallas.ROW_TILE)
+    np.testing.assert_allclose(
+        got, lfm2.cached_attend(q, k, v, jnp.asarray(pos), 0), atol=2e-6)
+    assert int(attn_pallas.rows_fetched(
+        jnp.asarray(pos), jnp.ones(3, bool), rows, 0)) == 6656 + 2 * 6784
+
+
+@pytest.mark.parametrize("rows, window", [(W, W), (40, W), (40, 0),
+                                          (37, 0), (8, W)])
+@pytest.mark.parametrize("rt", [4, 8, 16])
+def test_a_stream_visits_the_tiles_its_mask_reaches(rows, window, rt):
+    """``decode_run`` against ``ring_positions``' rule itself: the tiles
+    visited are those with a slot in reach (all tiles, and the mask
+    decides, where a window shorter than the cache cuts a wrapped
+    cache), none for a stream that is not live."""
+    pos = np.arange(3 * rows + 2)
+    held = np.asarray(lfm2.ring_positions(jnp.asarray(pos), rows))
+    seen = held >= 0
+    if window:
+        seen &= pos[:, None] - held < window
+    tiles = -(-rows // rt)
+    some = np.stack([seen[:, j * rt:(j + 1) * rt].any(1)
+                     for j in range(tiles)], 1)
+    first, last = attn_pallas.decode_run(pos, True, rows, window, rt, np)
+    visited = (np.arange(tiles) >= first[:, None]) \
+        & (np.arange(tiles) <= last[:, None])
+    assert np.all(visited | ~some)
+    exact = ~((pos >= rows) & bool(window) & (window < rows))
+    np.testing.assert_array_equal(visited[exact], some[exact])
+    fetched = [int(attn_pallas.rows_fetched(
+        pos[i:i + 1], np.array([True]), rows, window, rt, np))
+        for i in range(len(pos))]
+    np.testing.assert_array_equal(
+        fetched, np.minimum((last + 1) * rt, rows) - first * rt)
+    assert np.all(np.asarray(fetched) >= seen.sum(1))
+    first, last = attn_pallas.decode_run(pos, False, rows, window, rt, np)
+    assert np.all(last < first)
+    assert int(attn_pallas.rows_fetched(
+        pos, np.zeros(len(pos), bool), rows, window, rt, np)) == 0
+
+
 def toy_model():
     """Heads of 128 (whole lane tiles: what ``Attention`` asks of a
     preset before it takes the kernel), 4 / 2 of them."""
@@ -154,12 +271,12 @@ def toy_model():
                                lfm_window=W, dtype="float32")
 
 
-def kernels_traced(fn, *args):
+def kernels_traced(fn, *args, name="gqa_attn_fwd"):
     """``pallas_call``s of the kernel in ``fn`` traced now (a wrapper of
     its own: a trace is cached by function, and ``on_tpu`` is asked
     while tracing)."""
     jaxpr = str(jax.make_jaxpr(lambda *a: fn(*a))(*args))
-    return jaxpr.count("name=gqa_attn_fwd")
+    return jaxpr.count("name=" + name)
 
 
 @pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
@@ -198,3 +315,47 @@ def test_attention_takes_the_kernel_past_one_block_on_a_tpu(
     np.testing.assert_allclose(got, want, atol=2e-6)
     jax.tree.map(lambda g, w: np.testing.assert_allclose(g, w, atol=2e-5),
                  got_grad, want_grad)
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_attention_decodes_through_the_kernel_on_a_tpu(kind, monkeypatch):
+    """The decode branch's choice is the prefill branch's: on a TPU and
+    with heads of whole lane tiles ``gqa_attn_decode``, one a layer;
+    on the CPU, or with smaller heads, the plain form. Under the
+    interpreter the layer with the kernel is the layer without: output,
+    the cache it leaves, and a finished stream's cache row unwritten."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    m = toy_model()
+    layer = lfm2.Attention(m, kind, 16)
+    rows = W if "sliding" in kind else 40
+    x = jax.random.normal(jax.random.PRNGKey(3), (4, 1, 64))
+    params = layer.init(jax.random.PRNGKey(4), x)["params"]
+    keys = jax.random.split(jax.random.PRNGKey(5), 2)
+    cache = tuple(jax.random.normal(k, (4, rows, 2, 128)) for k in keys)
+    pos = jnp.asarray([[3], [W - 1], [W + 5], [38]])
+    live = jnp.asarray([[True], [True], [False], [True]])
+
+    def apply(p, x, layer=layer):
+        return layer.apply({"params": p}, x, pos, cache, live)
+
+    name = "gqa_attn_decode"
+    want, kept = apply(params, x)
+    assert kernels_traced(apply, params, x, name=name) == 0
+    monkeypatch.setenv("DS2N_ASSUME_TPU", "1")
+    assert kernels_traced(apply, params, x, name=name) == 1
+    small = lfm2.Attention(dataclasses.replace(m, lfm_head_dim=16),
+                           kind, 16)
+    assert kernels_traced(
+        lambda x: small.init_with_output(
+            jax.random.PRNGKey(4), x, pos,
+            tuple(c[..., :16] for c in cache), live)[0][0],
+        x, name=name) == 0
+    with pltpu.force_tpu_interpret_mode():
+        got, got_kept = apply(params, x)
+    # the finished stream's output is the gate times zeros
+    np.testing.assert_allclose(got[jnp.asarray([0, 1, 3])],
+                               want[jnp.asarray([0, 1, 3])], atol=2e-6)
+    for a, b, c in zip(got_kept, kept, cache):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a[2], c[2])

@@ -118,9 +118,13 @@ def test_sequence_form_equals_the_references_masks(kind, block):
 @pytest.mark.parametrize("kind, rows, kernel", [
     ("sliding_attention", W, False), ("sliding_attention", S, False),
     ("full_attention", S, False),
-    # the sequence form through ``gqa_attn_fwd`` (interpreted), as the
-    # chip runs it past one block: heads of 128, a TPU assumed
-    ("sliding_attention", W, True), ("full_attention", S, True)])
+    # both forms through their kernels (interpreted), ``gqa_attn_fwd``
+    # past one block and ``gqa_attn_decode``, as the chip runs them:
+    # heads of 128, a TPU assumed; a ring, a cache longer than the
+    # window (which then cuts a wrapped cache's reach), and one that
+    # sees all
+    ("sliding_attention", W, True), ("sliding_attention", 20, True),
+    ("full_attention", S, True)])
 def test_the_two_forms_agree_past_the_window(kind, rows, kernel,
                                              monkeypatch):
     """The decode form against a ring (and against a cache that never
@@ -139,7 +143,9 @@ def test_the_two_forms_agree_past_the_window(kind, rows, kernel,
 
     if kernel:
         monkeypatch.setenv("DS2N_ASSUME_TPU", "1")
-        assert "name=gqa_attn_fwd" in str(jax.make_jaxpr(forms)(x))
+        traced = str(jax.make_jaxpr(forms)(x))
+        assert "name=gqa_attn_fwd" in traced
+        assert "name=gqa_attn_decode" in traced
         with pltpu.force_tpu_interpret_mode():
             dec, seq = forms(x)
     else:
@@ -241,6 +247,56 @@ def test_prefill_then_decode_through_ring_and_full_cache(offset, impl):
     groups = stats["empty_groups"]
     assert groups["groups"] == 8 and groups["decode_calls"] == 11 * 4
     assert groups["decode"] == 11 * 4 * 8 - stats["experts_hit"]
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
+def test_the_call_counts_the_rows_it_fetches(kernel, monkeypatch):
+    """Beside the rows attended, the rows moved to attend to them: with
+    the plain decode form every row of every stream's cache at every
+    step, a finished stream's among them; with ``gqa_attn_decode``
+    (heads of 128, a TPU assumed, interpreted) the rows of the row
+    tiles the kernel visits for the live streams, which nothing but
+    tile rounding separates from the rows attended. The call is the
+    same function either way."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from deepspeech_tpu.ops import attn_pallas
+
+    cfg = toy(lfm_head_dim=128)
+    b = batch()
+    params, buffers = init(cfg, b)
+    _, plain = served(cfg, params, buffers, b)
+    out = plain
+    if kernel:
+        monkeypatch.setenv("DS2N_ASSUME_TPU", "1")
+        monkeypatch.setattr(attn_pallas, "ROW_TILE", 4)
+        with pltpu.force_tpu_interpret_mode():
+            engine, out = served(cfg, params, buffers, b)
+        assert "name=gqa_attn_decode" in str(jax.make_jaxpr(
+            lambda *a: engine._decode(*a))(
+                engine.params, engine.buffers, engine.cache_for(4, FRAMES),
+                *(jnp.zeros(4, jnp.int32),) * 2,
+                jnp.zeros((4, U + 1), jnp.int32), jnp.arange(4),
+                jnp.asarray(False)))
+        np.testing.assert_array_equal(out["ids"], plain["ids"])
+    stats = out["stats"]
+    for k in ("rows_attended_window", "rows_attended_global",
+              "cache_rows_read", "decode_steps"):
+        assert stats[k] == plain["stats"][k]
+    a_lens = -(-b[1] // 2)
+    live = [a + j for a, u in zip(a_lens, b[3]) for j in range(u + 1)]
+    if not kernel:
+        assert stats["rows_fetched_window"] == 4 * 11 * 4 * W
+        assert stats["rows_fetched_global"] == 11 * 4 * S
+        return
+    # a ring not yet wrapped and the full cache: the tiles of 4 slots
+    # up to the position's; a wrapped ring: all of it
+    assert stats["rows_fetched_window"] == 4 * sum(
+        W if pos >= W else (pos // 4 + 1) * 4 for pos in live)
+    assert stats["rows_fetched_global"] == sum(
+        (pos // 4 + 1) * 4 for pos in live)
+    assert stats["rows_attended_global"] <= stats["rows_fetched_global"] \
+        < stats["rows_attended_global"] + 4 * len(live)
 
 
 def test_a_short_cache_makes_rings_that_never_wrap():

@@ -103,6 +103,16 @@ CASES = {
          "rep": "6", "head": "128", "window": "4096", "q_tile": "256",
          "k_tile": "512", "key_tiles": "117", "key_tiles_in_reach": "117",
          "key_tiles_masked": "26"}],
+    # and its decode step: 16 streams against the global layer's cache
+    # of 6,784 rows, 14 row tiles of 512 (the last hangs over)
+    "gqa_attn_decode_trinity_global": [
+        {"kernel": "gqa_attn_decode", "b": "16", "rows": "6784",
+         "kv": "8", "rep": "6", "head": "128", "window": "0",
+         "row_tile": "512", "row_tiles": "14"}],
+    "gqa_attn_decode_trinity_window": [
+        {"kernel": "gqa_attn_decode", "b": "16", "rows": "4096",
+         "kv": "8", "rep": "6", "head": "128", "window": "4096",
+         "row_tile": "512", "row_tiles": "8"}],
 }
 
 

@@ -85,6 +85,12 @@ def v5e_chip():
     # 4,096) and the global one
     ("gqa_attn_fwd_trinity_window", ["gqa_attn_fwd"]),
     ("gqa_attn_fwd_trinity_global", ["gqa_attn_fwd"]),
+    # its decode step, 16 streams: a ring of 4,096 rows in 8 row tiles
+    # of 512 (a tile is 4,096 rows of 128: a slot's 8 key/value heads
+    # lie together), the global cache's 6,784 in 14, the last hanging
+    # over; 48 x 4,096 float32 scores a tile
+    ("gqa_attn_decode_trinity_window", ["gqa_attn_decode"]),
+    ("gqa_attn_decode_trinity_global", ["gqa_attn_decode"]),
 ])
 def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     """Mosaic accepts the kernel, and the compiled instruction still

@@ -253,6 +253,19 @@ def kernel_cases():
         return lambda: (lambda q, k, v: attn_pallas.gqa_attention(
             q, k, v, window, None), args)
 
+    def attn_decode_case(rows, window):
+        """trinity_large's grouped-query attention in one decode step
+        of one layer: ``gqa_attn_decode`` alone, 16 streams against
+        their cache of ``rows`` rows, at the module's row tile."""
+        from deepspeech_tpu.ops import attn_pallas
+
+        args = (S((16, 8, 6, 128), jnp.bfloat16),
+                S((16, rows, 8, 128), jnp.bfloat16),
+                S((16, rows, 8, 128), jnp.bfloat16),
+                S((16,), jnp.int32), S((16,), jnp.bool_))
+        return lambda: (lambda q, k, v, pos, live: attn_pallas.gqa_decode(
+            q, k, v, pos, live, window), args)
+
     cases["gru_h800"] = gru_case(800)
     cases["gru_h1760"] = gru_case(1760)
     # ds2_full.train_1chip's own call (850 post-conv frames, bf16
@@ -318,6 +331,11 @@ def kernel_cases():
     # 130), 48 / 8 heads of 128, a sliding layer and the global one.
     cases["gqa_attn_fwd_trinity_window"] = attn_case(4096)
     cases["gqa_attn_fwd_trinity_global"] = attn_case(0)
+    # the same cell's decode step: a sliding layer's ring of 4,096 rows
+    # (8 row tiles of 512) and the global layer's 6,784 (14, the last
+    # hanging over the cache's end by 384 rows)
+    cases["gqa_attn_decode_trinity_window"] = attn_decode_case(4096, 4096)
+    cases["gqa_attn_decode_trinity_global"] = attn_decode_case(6784, 0)
     cases["ctc_aishell"] = ctc_case(4336, 400, 60)
     cases["ctc_en"] = ctc_case(29, 400, 160)
     # The weak-#1 shape: AISHELL-width device beam search, both merge

@@ -7,6 +7,17 @@ two-forms check would read with each.
   chiprun -- python3 tools/attn_bench.py --tiles 256x512 512x512 \
       > chiprun_out/attn_bench.jsonl
 
+``--mode decode``: ``gqa_attn_decode`` against the plain decode form
+(``models/lfm2.cached_attend``) at the cell's decode step: 16 streams,
+a sliding layer's ring of 4,096 rows and the global layer's cache of
+6,784, positions as the traffic file draws them (stratified lengths,
+3.6 word-pieces a second) at the loop's steps ``--steps`` (a stream
+whose tokens are out is not live), a row tile at a time
+(``--row-tiles``); ``ms`` is one call inside a jitted loop of
+``--iters`` calls, ``gbps_fetched`` / ``gbps_reach`` the rows the form
+moves and the rows in reach, 4 kB each, over it; then the two-forms
+reading of a layer with the kernels and without.
+
 One JSON line a reading. ``ms``: the median of ``--iters`` timed calls
 (``block_until_ready``); ``need_tflop``: the mixing operations of the
 keys IN REACH (``4 x heads x head`` a key, as
@@ -66,9 +77,91 @@ def blockwise(window: int, block: int):
     return run
 
 
+def stream_lengths(seed: int, n: int):
+    """Prefix positions and decode steps of ``n`` streams from the
+    parameters of ``benchmark/traffic/transcribe_long_7min_b16.json``,
+    drawn as ``gen/batches.make_batches`` draws them (stratified valid
+    lengths, labels a frame)."""
+    import numpy as np
+
+    traffic = json.load(open(os.path.join(
+        ROOT, "benchmark", "traffic", "transcribe_long_7min_b16.json")))
+    lo, hi = traffic["valid_frames"]
+    strata = lo + (hi + 1 - lo) * (np.arange(n) + 0.5) / n
+    frames = np.random.default_rng(seed).permutation(
+        strata.astype(np.int64))
+    steps = np.round(traffic["labels_per_frame"] * frames).astype(np.int64)
+    return -(-frames // 8), steps + 1
+
+
+def decode_mode(args, m, timed_loop, rms_rel, device) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeech_tpu.models import lfm2
+    from deepspeech_tpu.ops import attn_pallas
+
+    nkv, rep, hd = m.lfm_kv_heads, m.lfm_heads // m.lfm_kv_heads, \
+        lfm2.head_dim(m)
+    dtype, b = jnp.dtype(m.dtype), args.rows
+    prefix, steps = stream_lengths(args.seed, b)
+    caches = ((m.lfm_window, m.lfm_window), (args.cache_rows, 0))
+    if args.rehearse:
+        prefix, steps = prefix // 128, steps // 64
+        caches = ((m.lfm_window, m.lfm_window), (64, 0))
+    row_bytes = 2 * nkv * hd * dtype.itemsize
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 3)
+    q = jax.random.normal(keys[0], (b, nkv, rep, hd), dtype)
+    for rows, window in caches:
+        k = jax.random.normal(keys[1], (b, rows, nkv, hd), dtype)
+        v = jax.random.normal(keys[2], (b, rows, nkv, hd), dtype)
+        for j in args.steps:
+            pos = jnp.asarray(prefix + j, jnp.int32)
+            live = jnp.asarray(j < steps)
+            reach = int(np.sum(np.where(
+                j < steps, np.minimum(prefix + j + 1, window or 1 << 30),
+                0)))
+
+            def plain(q, k, v, pos, live, window=window):
+                return jnp.where(
+                    live[:, None, None, None],
+                    lfm2.cached_attend(q, k, v, pos, window), 0)
+
+            forms = [("xla", None, plain)] + [
+                ("gqa_attn_decode", rt,
+                 lambda q, k, v, pos, live, rt=rt, window=window:
+                 attn_pallas.gqa_decode(q, k, v, pos, live, window, rt,
+                                        args.rehearse))
+                for rt in args.row_tiles]
+            want = None
+            for what, rt, fn in forms:
+                got = jax.jit(fn)(q, k, v, pos, live)
+                want = got if want is None else want
+                ms = timed_loop(fn, q, k, v, pos, live)
+                fetched = b * rows if rt is None else int(
+                    attn_pallas.rows_fetched(pos, live, rows, window, rt))
+                print(json.dumps({
+                    "what": what, "rows": rows, "window": window,
+                    "step": j, "live": int(np.sum(j < steps)),
+                    "row_tile": rt, "ms": ms, "rows_reach": reach,
+                    "rows_fetched": fetched,
+                    "gbps_fetched": fetched * row_bytes / ms / 1e6,
+                    "gbps_reach": reach * row_bytes / ms / 1e6,
+                    "rms_rel": rms_rel(got, want), "device": device}),
+                    flush=True)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--rows", type=int, default=2)
+    ap.add_argument("--mode", choices=["prefill", "decode"],
+                    default="prefill")
+    ap.add_argument("--row-tiles", type=int, nargs="+", default=[512])
+    ap.add_argument("--steps", type=int, nargs="+",
+                    default=[0, 700, 1250, 1400])
+    ap.add_argument("--cache-rows", type=int, default=6784)
+    ap.add_argument("--rows", type=int, default=0,
+                    help="2 prefill rows, 16 decode streams")
     ap.add_argument("--positions", type=int, default=5250)
     ap.add_argument("--windows", type=int, nargs="+", default=[4096, 0])
     ap.add_argument("--tiles", nargs="+", default=["256x512"],
@@ -93,6 +186,7 @@ def main() -> None:
     from deepspeech_tpu.models import lfm2
     from deepspeech_tpu.ops import attn_pallas
 
+    args.rows = args.rows or (16 if args.mode == "decode" else 2)
     m = get_config("trinity_large").model
     block, s = 512, args.positions
     if args.rehearse:
@@ -101,6 +195,7 @@ def main() -> None:
                                 lfm_window=24, dtype="float32")
         block, s = 16, 70
         args.tiles, args.windows = ["16x8"], [24, 0]
+        args.row_tiles, args.steps, args.iters = [8], [0, 9, 20], 2
     nkv, rep, hd = m.lfm_kv_heads, m.lfm_heads // m.lfm_kv_heads, \
         lfm2.head_dim(m)
     dtype = jnp.dtype(m.dtype)
@@ -117,10 +212,29 @@ def main() -> None:
             out.append(1e3 * (time.perf_counter() - t))
         return statistics.median(out)
 
+    def timed_loop(fn, q, *xs):
+        """One call of ``fn(q, *xs)`` inside a jitted loop of
+        ``--iters``, each call's query the one before's result's (a
+        call of 0.3 ms alone is mostly its dispatch)."""
+        many = jax.jit(lambda q, *xs: jax.lax.fori_loop(
+            0, args.iters,
+            lambda _, q: (q + 1e-3 * fn(q, *xs)).astype(q.dtype), q))
+        jax.block_until_ready(many(q, *xs))
+        out = []
+        for _ in range(3):
+            t = time.perf_counter()
+            jax.block_until_ready(many(q, *xs))
+            out.append(1e3 * (time.perf_counter() - t) / args.iters)
+        return statistics.median(out)
+
     def rms_rel(got, want):
         got, want = (jnp.asarray(x, jnp.float32) for x in (got, want))
         return float(jnp.sqrt(jnp.mean((got - want) ** 2)
                               / jnp.mean(want ** 2)))
+
+    if args.mode == "decode":
+        decode_mode(args, m, timed_loop, rms_rel, device)
+        args.no_kernel = True
 
     keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
     q = jax.random.normal(keys[0], (args.rows, s, nkv, rep, hd), dtype)
@@ -161,7 +275,9 @@ def main() -> None:
         return
     from benchmark.drivers.transcribe_long import FORMS_AT
 
-    x = jax.random.normal(keys[3], (args.rows, s, m.lfm_hidden), dtype)
+    decode = args.mode == "decode"
+    x = jax.random.normal(
+        keys[3], (1 if decode else args.rows, s, m.lfm_hidden), dtype)
     at = np.asarray([p for p in FORMS_AT if p < s] if s > 4096
                     else range(0, s, 7))
     for kind in ("sliding_attention", "full_attention"):
@@ -177,21 +293,26 @@ def main() -> None:
             # is cached by function: a function of its own each time
             lfm2.on_tpu = on_tpu if what == "kernel" else (lambda: False)
             try:
-                fn = jax.jit(lambda p, x: layer.apply({"params": p}, x)[0])
-                outs[what] = fn(params, x)
-                ms = timed(fn, params, x)
-                # the decode form against this sequence form, as the
-                # cell's reference check reads it (``ref_forms_rms_rel``)
+                line = {"what": f"layer_{what}", "kind": kind}
+                if not decode:
+                    fn = jax.jit(
+                        lambda p, x: layer.apply({"params": p}, x)[0])
+                    outs[what] = fn(params, x)
+                    line["ms"] = timed(fn, params, x)
+                # the decode form against the sequence form, as the
+                # cell's reference check reads it (``ref_forms_rms_rel``):
+                # both kernels, or neither
                 dec, seq = jax.jit(lambda p, x: lfm2.both_forms(
                     m, kind, p, x, at, cache_rows, block))(params, x[:1])
             finally:
                 lfm2.on_tpu = on_tpu
-            print(json.dumps({"what": f"layer_{what}", "kind": kind,
-                              "ms": ms, "forms_rms_rel": rms_rel(dec, seq)}),
+            print(json.dumps(dict(line, forms_rms_rel=rms_rel(dec, seq))),
                   flush=True)
-        print(json.dumps({"what": "layer_rms_rel", "kind": kind,
-                          "rms_rel": rms_rel(outs["kernel"],
-                                             outs["loop"])}), flush=True)
+        if not decode:
+            print(json.dumps({"what": "layer_rms_rel", "kind": kind,
+                              "rms_rel": rms_rel(outs["kernel"],
+                                                 outs["loop"])}),
+                  flush=True)
 
 
 if __name__ == "__main__":

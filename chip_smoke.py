@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import functools
 import gc
 import io
@@ -94,7 +95,7 @@ MLA_CALL = (8, 288)
 MLA_FORMS_RTOL = 2e-2
 SCAN_ORACLE_RTOL = {"dxproj": 1e-2, "dw_h": 0.2, "db_h": 2e-3}
 # The recurrent weight gradient of that call, [T*B, H]^T x [T*B, 3H]
-# from float32 operands (ops/rnn_pallas.py recurrent_dw): what the
+# from float32 operands (ops/scan_pallas.py recurrent_dw): what the
 # three-pass contraction a bf16 model runs (Precision.HIGH) may differ
 # by from the float64 sum of the same operands, as largest error over
 # largest value and as rms error over rms value, and how many times
@@ -227,29 +228,26 @@ def check_device(want: int) -> dict:
 
 def kernel_route(preset: str) -> dict:
     """What 'auto' resolved to for this preset, and which recurrent
-    kernel its width selects."""
-    import jax.numpy as jnp
-
+    kernel build its widths select at its batch (the route's answer
+    for the Pallas impl, whatever this machine resolves)."""
     from deepspeech_tpu.config import get_config
-    from deepspeech_tpu.ops.rnn_pallas import bigru_fits_vmem, fits_vmem
+    from deepspeech_tpu.models.rnn import layer_scan_route
     from deepspeech_tpu.utils.impl import interpret_default, resolve_impl
     from deepspeech_tpu.utils.quantize import kernel_regime
 
     cfg = get_config(preset)
-    h = cfg.model.rnn_hidden
-    dot_bytes = jnp.dtype(cfg.model.dtype).itemsize
-    if cfg.model.bidirectional and bigru_fits_vmem(h, dot_bytes):
-        route = "bigru-resident"
-    elif fits_vmem(h, dot_bytes):
-        route = "resident"
-    else:
-        route = "blocked"
+    on_chip = dataclasses.replace(cfg.model, rnn_impl="pallas")
+    route = layer_scan_route(on_chip, cfg.data.batch_size,
+                             directions=2 if cfg.model.bidirectional else 1)
     return {"preset": preset,
             "rnn_impl": resolve_impl(cfg.model.rnn_impl, oracle="xla"),
             "loss_impl": resolve_impl(cfg.train.loss_impl, oracle="jnp"),
             "interpret": interpret_default(),
             "kernel_regime": kernel_regime(cfg.model, quantized=False),
-            "rnn_route": route, "rnn_hidden": h}
+            "rnn_route": ("bigru-" + route.variant
+                          if route.kernel == "bigru_scan_fwd"
+                          else route.variant or "xla"),
+            "rnn_hidden": cfg.model.rnn_hidden}
 
 
 def check_kernels(route: dict, step_text: str) -> dict:
@@ -464,7 +462,7 @@ def scan_builds(interpret: bool) -> dict:
     import numpy as np
 
     from deepspeech_tpu.models.rnn import gru_scan
-    from deepspeech_tpu.ops import rnn_pallas
+    from deepspeech_tpu.ops import rnn_pallas, scan_pallas
 
     (b, t), h = SCAN_CALL, 1760
     rng = np.random.default_rng(1)
@@ -489,7 +487,7 @@ def scan_builds(interpret: bool) -> dict:
                                           "bfloat16")
 
     pinned = run(pallas)
-    with mock.patch.object(rnn_pallas, "_PINNED_VMEM_CAP", 0):
+    with mock.patch.object(scan_pallas, "PINNED_VMEM_CAP", 0):
         streamed = run(pallas)
     oracle = run(lambda x, m, w, bias: gru_scan(
         x, m, w, bias, dot_dtype=jnp.bfloat16))
@@ -540,7 +538,7 @@ def dw_h_precision(interpret: bool) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from deepspeech_tpu.ops import rnn_pallas
+    from deepspeech_tpu.ops import rnn_pallas, scan_pallas
 
     (b, t), h = SCAN_CALL, 1760
     rng = np.random.default_rng(37)
@@ -554,7 +552,7 @@ def dw_h_precision(interpret: bool) -> dict:
     dy = jnp.asarray(rng.normal(size=(b, t, h)) * 0.1, jnp.float32)
 
     handed = []
-    shipped = rnn_pallas.recurrent_dw
+    shipped = scan_pallas.recurrent_dw
 
     def keep(h_prev, dgates, dot):
         handed.append((h_prev, dgates))
@@ -566,7 +564,7 @@ def dw_h_precision(interpret: bool) -> dict:
             x, mask, w, bh, False, interpret, dot_dtype), wh)
         return np.asarray(vjp(dy)[0], np.float64)
 
-    with mock.patch.object(rnn_pallas, "recurrent_dw", keep):
+    with mock.patch.object(scan_pallas, "recurrent_dw", keep):
         program = program_dw_h(xp, "bfloat16")
     (h_prev, dgates), = handed
     all_float32 = program_dw_h(xp.astype(jnp.float32), None)

@@ -64,7 +64,9 @@ class ModelConfig:
     #   "xla"    - lax.scan over a jnp cell (reference / oracle path)
     #   "pallas" - fused Pallas cell (interpreter mode off-TPU)
     # Every cell of BENCHMARK.json runs "auto" = the fused cell
-    # (`correct` checks rnn_impl_pallas). Against the XLA scan on this
+    # (`correct` checks rnn_impl_pallas), in the build
+    # ops/scan_pallas.scan_route names from the call's shapes (ds2_full:
+    # copied once into VMEM, `pinned`). Against the XLA scan on this
     # chip: measured for the LSTM-with-projection stack only (PERF.md
     # section 6, PR 26: 712.6 -> 307.9 ms a step); the GRU stack: not
     # measured.

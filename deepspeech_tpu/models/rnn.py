@@ -261,29 +261,70 @@ def lstmp_scan(xproj: jnp.ndarray, mask: jnp.ndarray, w_r: jnp.ndarray,
 LSTMP_REMAT_CHUNK = 32
 
 
+def layer_scan_route(cfg: ModelConfig, rows=None, *, mesh=None,
+                     cell=None, hidden=None, **facts):
+    """``ops/scan_pallas.scan_route`` for one recurrent layer of this
+    model: which kernel and build run it, or that the XLA scan does.
+    From the configuration come the cell type, the resolved
+    ``rnn_impl``, the widths and the dot type; from the call ``rows``
+    (the batch's, divided over ``mesh``'s data axis here) and whatever
+    else it observes (``int8``, ``carry``, ``directions``)."""
+    from ..ops.scan_pallas import scan_route
+    from ..parallel.mesh import DATA_AXIS
+    from ..utils.impl import resolve_impl
+
+    if rows is not None and mesh is not None:
+        rows //= mesh.shape[DATA_AXIS]
+    facts.setdefault("proj", cfg.rnn_proj)
+    return scan_route(
+        cell or cfg.rnn_type, resolve_impl(cfg.rnn_impl, oracle="xla"),
+        rows=rows, hidden=hidden or cfg.rnn_hidden,
+        dot_bytes=jnp.dtype(cfg.dtype).itemsize, **facts)
+
+
+def _scan_kernel(kernel: str):
+    """The function of ``ops/`` that builds the forward kernel a route
+    names, called as ``f(xproj, mask, *weights, [reverse,] interpret,
+    dot_dtype)``."""
+    from ..ops import lstm_pallas, rnn_pallas
+
+    return {"gru_scan_fwd": rnn_pallas.gru_scan_pallas,
+            "gru_scan_q_fwd": rnn_pallas.gru_scan_pallas_q,
+            "bigru_scan_fwd": rnn_pallas.bigru_scan_pallas,
+            "lstm_scan_fwd": lstm_pallas.lstm_scan_pallas,
+            "lstm_scan_q_fwd": lstm_pallas.lstm_scan_pallas_q,
+            "lstmp_scan_fwd": lstm_pallas.lstmp_scan_pallas}[kernel]
+
+
+def _run_kernel(cfg: ModelConfig, kernel: str, mesh, xproj, mask, *weights,
+                reverse=None):
+    """The routed kernel over this layer's operands. On a multi-device
+    mesh it partitions over the data axis via shard_map (batch args
+    sharded, weights replicated); single-device meshes pass through
+    untouched."""
+    from ..parallel.mesh import shard_batchwise
+    from ..utils.impl import interpret_default
+
+    tail = (() if reverse is None else (reverse,)) + (
+        interpret_default(), _pallas_dot_dtype(jnp.dtype(cfg.dtype)))
+    fn = _scan_kernel(kernel)
+    return shard_batchwise(lambda xp, m, *w: fn(xp, m, *w, *tail), mesh,
+                           n_sharded=2)(xproj, mask, *weights)
+
+
 def _run_lstmp(cfg: ModelConfig, xproj, mask, w_r, w_p, ln_scale,
                ln_bias, mesh=None):
     """A whole-sequence LSTM-with-projection recurrence from a zero
-    carry: the fused Pallas kernels where ``rnn_impl`` resolves to them
-    (TPU) and the call fits them — sublane-aligned local batch rows,
-    weights within the kernels' VMEM limit — else the XLA scan."""
-    from ..utils.impl import interpret_default, resolve_impl
-
+    carry: the fused Pallas kernels where the route names them
+    (``rnn_impl`` resolves to them, sublane-aligned local batch rows,
+    weights within the kernels' VMEM limit), else the XLA scan."""
     dtype = jnp.dtype(cfg.dtype)
-    if resolve_impl(cfg.rnn_impl, oracle="xla") == "pallas":
-        from ..ops.lstm_pallas import lstmp_fits_vmem, lstmp_scan_pallas
-        from ..parallel.mesh import DATA_AXIS, shard_batchwise
-
-        h, p = w_p.shape
-        dd = _pallas_dot_dtype(dtype)
-        rows = xproj.shape[0] // (mesh.shape[DATA_AXIS] if mesh else 1)
-        if rows % 8 == 0 and lstmp_fits_vmem(
-                rows, h, p, 4 if dd is None else dtype.itemsize):
-            interp = interpret_default()
-            cell = lambda xp, m, *w: lstmp_scan_pallas(*(xp, m) + w,
-                                                       interp, dd)
-            return shard_batchwise(cell, mesh, n_sharded=2)(
-                xproj, mask, w_r, w_p, ln_scale, ln_bias)
+    h, p = w_p.shape
+    route = layer_scan_route(cfg, xproj.shape[0], mesh=mesh, cell="lstmp",
+                             hidden=h, proj=p)
+    if route.kernel is not None:
+        return _run_kernel(cfg, route.kernel, mesh, xproj, mask, w_r, w_p,
+                           ln_scale, ln_bias)
     return lstmp_scan(
         xproj, mask, w_r, w_p, ln_scale, ln_bias,
         dot_dtype=None if dtype == jnp.float32 else dtype,
@@ -377,57 +418,21 @@ def _is_qdict(w) -> bool:
 
 def _run_direction(cfg: ModelConfig, xproj, mask, w_h, b_h, reverse,
                    mesh=None):
+    """One direction of one layer through what the route names: a fused
+    cell at every H (resident, copied once or streamed; int8 weights
+    straight into the q kernels, so the quantized matrix IS what moves,
+    the recurrent bandwidth win PTQ exists for), else the XLA scan."""
     dtype = jnp.dtype(cfg.dtype)
-    from ..utils.impl import resolve_impl
-
-    impl = resolve_impl(cfg.rnn_impl, oracle="xla")
-    if _is_qdict(w_h):
-        if impl == "pallas" and cfg.rnn_type in ("gru", "lstm"):
-            # int8 weights straight into the fused q kernels, every H:
-            # resident when the matrix fits the 1-byte budget, s8
-            # column streaming (blocked-q) above it — either way the
-            # quantized matrix IS what rides HBM->VMEM each step, the
-            # per-step recurrent bandwidth win PTQ exists for (VERDICT
-            # r3 #7; the blocked regime streams 4× fewer bytes than
-            # the fp working copy this path used to materialize).
-            from ..parallel.mesh import shard_batchwise
-            from ..utils.impl import interpret_default
-
-            if cfg.rnn_type == "gru":
-                from ..ops.rnn_pallas import gru_scan_pallas_q as cell_q
-            else:
-                from ..ops.lstm_pallas import lstm_scan_pallas_q as cell_q
-            cell = lambda xp, m, wq, sc, bh: cell_q(
-                xp, m, wq, sc, bh, reverse, interpret_default(),
-                _pallas_dot_dtype(dtype))
-            return shard_batchwise(cell, mesh, n_sharded=2)(
-                xproj, mask, w_h["q"], w_h["scale"], b_h)
+    quantized = _is_qdict(w_h)
+    route = layer_scan_route(cfg, xproj.shape[0], mesh=mesh, int8=quantized,
+                             xproj_bytes=xproj.dtype.itemsize)
+    if route.kernel is not None:
+        weights = (w_h["q"], w_h["scale"]) if quantized else (w_h,)
+        return _run_kernel(cfg, route.kernel, mesh, xproj, mask, *weights,
+                           b_h, reverse=reverse)
+    if quantized:
         # XLA impl: dequantize on the fly — storage win only, same math.
         w_h = w_h["q"].astype(jnp.float32) * w_h["scale"]
-    if impl == "pallas":
-        from ..utils.impl import interpret_default
-        from ..parallel.mesh import shard_batchwise
-
-        # The fused cells cover every H: VMEM-resident weights when they
-        # fit, blocked column streaming above that (flagship H=1760) —
-        # SURVEY.md §7 hard-parts item 2.
-        dd = _pallas_dot_dtype(dtype)
-        interp = interpret_default()
-        if cfg.rnn_type == "gru":
-            from ..ops.rnn_pallas import gru_scan_pallas
-
-            cell = lambda xp, m, wh, bh: gru_scan_pallas(
-                xp, m, wh, bh, reverse, interp, dd)
-        else:
-            from ..ops.lstm_pallas import lstm_scan_pallas
-
-            cell = lambda xp, m, wh, bh: lstm_scan_pallas(
-                xp, m, wh, bh, reverse, interp, dd)
-        # On a multi-device mesh the kernel partitions over the data
-        # axis via shard_map (batch args sharded, weights replicated);
-        # single-device meshes pass through untouched.
-        return shard_batchwise(cell, mesh, n_sharded=2)(
-            xproj, mask, w_h, b_h)
     scan = gru_scan if cfg.rnn_type == "gru" else lstm_scan
     dot_dtype = None if dtype == jnp.float32 else dtype
     return scan(xproj, mask, w_h, b_h, reverse=reverse, dot_dtype=dot_dtype,
@@ -437,32 +442,19 @@ def _run_direction(cfg: ModelConfig, xproj, mask, w_h, b_h, reverse,
 def _run_stack_dirs(cfg: ModelConfig, xproj, mask, params, mesh=None):
     """Run the direction set of one layer; ``params[rev] = (w_h, b_h)``.
 
-    Fast path (r3): a bidirectional GRU under the Pallas impl whose TWO
-    weight sets fit VMEM together runs as ONE fused kernel
+    Fast path: a bidirectional float GRU whose TWO weight sets fit VMEM
+    together is routed to ONE fused kernel
     (ops/rnn_pallas.bigru_scan_pallas) — the independent per-step
     matmuls of the two directions hide each other's latency instead of
-    serializing as two kernels. Everything else composes per-direction
-    exactly as before.
+    serializing as two kernels. Everything else composes per direction.
     """
-    from ..utils.impl import resolve_impl
-
-    dtype = jnp.dtype(cfg.dtype)
-    if (len(params) == 2 and cfg.rnn_type == "gru"
-            and not any(_is_qdict(w) for w, _ in params.values())
-            and resolve_impl(cfg.rnn_impl, oracle="xla") == "pallas"):
-        from ..ops.rnn_pallas import bigru_fits_vmem, bigru_scan_pallas
-        from ..parallel.mesh import shard_batchwise
-        from ..utils.impl import interpret_default
-
-        dd = _pallas_dot_dtype(dtype)
-        itemsize = 4 if dd is None else jnp.dtype(dd).itemsize
-        if bigru_fits_vmem(cfg.rnn_hidden, itemsize):
-            w_f, b_f = params[False]
-            w_b, b_b = params[True]
-            cell = lambda xp, m, wf, bf, wb, bb: bigru_scan_pallas(
-                xp, m, wf, bf, wb, bb, interpret_default(), dd)
-            return shard_batchwise(cell, mesh, n_sharded=2)(
-                xproj, mask, w_f, b_f, w_b, b_b)
+    if len(params) == 2 and not any(_is_qdict(w) for w, _ in params.values()):
+        route = layer_scan_route(cfg, xproj.shape[0], mesh=mesh,
+                                 directions=2,
+                                 xproj_bytes=xproj.dtype.itemsize)
+        if route.kernel == "bigru_scan_fwd":
+            return _run_kernel(cfg, route.kernel, mesh, xproj, mask,
+                               *params[False], *params[True])
     out = None
     for rev, (w_h, b_h) in params.items():
         ys = _run_direction(cfg, xproj, mask, w_h, b_h, rev, mesh=mesh)

@@ -20,8 +20,8 @@ fact, not a name:
            it whole), ``blocked`` (weight columns moved by the
            pipeline over a second grid axis, from wherever XLA left
            the matrix, because a pipelined operand is double-buffered:
-           a call whose need reaches ``rnn_pallas._PINNED_VMEM_CAP``,
-           and ``lstm_pallas``), ``resident_q`` / ``blocked_q`` (as
+           a call whose need reaches ``scan_pallas.PINNED_VMEM_CAP``,
+           and the plain LSTM), ``resident_q`` / ``blocked_q`` (as
            ``resident`` and ``blocked``, with int8 weights)
   reverse  1 if the scan runs from the last frame to the first, else
            0; ``both`` for the fused bidirectional kernels

@@ -1,17 +1,20 @@
-"""Fused Pallas LSTM cell (SURVEY.md §2 component 6, LSTM variant).
+"""Fused Pallas LSTM cells (SURVEY.md §2 component 6, LSTM variants).
 
-Same two regimes as the GRU cell (ops/rnn_pallas.py): VMEM-resident
-``[H, 4H]`` weights for small/medium H, blocked column streaming with
-automatic double buffering above that. The recurrence matches
-``models.rnn.lstm_scan`` (the XLA oracle), including the +1.0
-forget-gate bias trick and mask-held h/c for padded frames.
+The plain LSTM is a gated cell of ``ops/scan_pallas.py`` like the GRU
+(``ops/rnn_pallas.py``), four gates and two carried states: this file
+holds its element-wise math and its public functions, the route and
+the call are shared. Two regimes, by the route: VMEM-resident
+``[H, 4H]`` weights for small/medium H, streamed column blocks
+(``blocked``) above that, and the int8 pair ``resident_q`` /
+``blocked_q``. The recurrence matches ``models.rnn.lstm_scan`` (the XLA
+oracle), including the +1.0 forget-gate bias trick and mask-held h/c
+for padded frames.
 
 Backward is BPTT with gate recompute: the forward tapes the cell-state
-sequence ``cs`` alongside the outputs ``ys`` (cuDNN does the same),
-and the backward kernel recomputes the four gate activations from
-(h_prev, c_prev, xproj, W) instead of storing them. The blocked
-backward pipelines the ``dgates @ W^T`` contraction one step behind
-the gate recompute so each weight block streams once per time step.
+sequence ``cs`` alongside the outputs ``ys`` (cuDNN does the same; the
+no-grad primal skips that [T, B, H] HBM write), and the backward step
+recomputes the four gate activations from (h_prev, c_prev, xproj, W)
+instead of storing them.
 
 Gate order i, f, g, o:
   i = sigmoid(xp_i + h W_i + b_i)
@@ -29,17 +32,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .kernel_id import kernel_call, scan_facts
-from .rnn_pallas import (_block_layout, _blocked_q_in_specs,
-                         _dot_jnp_dtype, _pad_cols,
-                         _resident_in_specs, _resident_q_in_specs,
-                         _time_index_maps, _time_major,
-                         _use_blocked, fits_vmem, recurrent_dw)
+from .scan_pallas import (ScanCell, dot_jnp_dtype, own_route, prev_sequence,
+                          scan_call, scan_forward, scan_vjp,
+                          time_index_maps, time_major)
 
 
-def _lstm_elementwise_fwd(xp, gates, hprev, cprev, m):
+def _lstm_elementwise_fwd(xp, gates, states, m):
+    hprev, cprev = states
     h = hprev.shape[-1]
     i = jax.nn.sigmoid(xp[:, :h] + gates[:, :h])
     f = jax.nn.sigmoid(xp[:, h:2 * h] + gates[:, h:2 * h] + 1.0)
@@ -52,12 +52,11 @@ def _lstm_elementwise_fwd(xp, gates, hprev, cprev, m):
     return hnew, cnew
 
 
-def _lstm_elementwise_bwd(xp, gates, hprev, cprev, m, dh_in, dc_in, dy):
-    """Shared VPU math for one reverse step.
-
-    Returns (dgates, dh_prev_local, dc_prev) where dh_prev_local still
-    lacks the dgates @ W^T term (regime-specific).
-    """
+def _lstm_elementwise_bwd(xp, gates, prevs, m, dstates, dy):
+    """VPU math of one reverse step. Returns (dxp, dgates, (dh_prev,
+    dc_prev)): the gradient of xproj IS that of the gates, and dh_prev
+    still lacks the dgates @ W^T term (the step body's)."""
+    (hprev, cprev), (dh_in, dc_in) = prevs, dstates
     h = hprev.shape[-1]
     i = jax.nn.sigmoid(xp[:, :h] + gates[:, :h])
     f = jax.nn.sigmoid(xp[:, h:2 * h] + gates[:, h:2 * h] + 1.0)
@@ -80,205 +79,10 @@ def _lstm_elementwise_bwd(xp, gates, hprev, cprev, m, dh_in, dc_in, dy):
     dgates = jnp.concatenate([da_i, da_f, da_g, da_o], axis=1)
     dh_prev_local = (1.0 - m) * dh
     dc_prev = dc_pre * f + (1.0 - m) * dc_in
-    return dgates, dh_prev_local, dc_prev
+    return dgates, dgates, (dh_prev_local, dc_prev)
 
 
-# ---------------------------------------------------------------------------
-# Kernels.
-# ---------------------------------------------------------------------------
-
-def _lstm_kernel(xp_ref, mask_ref, wh_ref, bh_ref, *refs):
-    # refs = (ys_ref, cs_ref, h_c, c_c) when taping the cell-state
-    # sequence for BPTT, (ys_ref, h_c, c_c) on the no-grad eval path
-    # (skips the [T, B, H] HBM tape write entirely).
-    if len(refs) == 4:
-        ys_ref, cs_ref, h_c, c_c = refs
-    else:
-        (ys_ref, h_c, c_c), cs_ref = refs, None
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _():
-        h_c[:] = jnp.zeros_like(h_c)
-        c_c[:] = jnp.zeros_like(c_c)
-
-    hprev, cprev = h_c[:], c_c[:]
-    gates = jnp.dot(hprev.astype(wh_ref.dtype), wh_ref[:],
-                    preferred_element_type=jnp.float32) + bh_ref[:]
-    m = mask_ref[0]
-    hnew, cnew = _lstm_elementwise_fwd(xp_ref[0], gates, hprev, cprev, m)
-    h_c[:] = hnew
-    c_c[:] = cnew
-    ys_ref[0] = hnew
-    if cs_ref is not None:
-        cs_ref[0] = cnew
-
-
-def _lstm_kernel_blocked(xp_ref, mask_ref, wh_ref, bh_ref, *refs,
-                         h: int, n_blocks: int, c: int):
-    if len(refs) == 5:
-        ys_ref, cs_ref, h_c, c_c, gates_buf = refs
-    else:
-        (ys_ref, h_c, c_c, gates_buf), cs_ref = refs, None
-    t = pl.program_id(0)
-    g = pl.program_id(1)
-
-    @pl.when((t == 0) & (g == 0))
-    def _():
-        h_c[:] = jnp.zeros_like(h_c)
-        c_c[:] = jnp.zeros_like(c_c)
-
-    hprev = h_c[:]
-    blk = jnp.dot(hprev.astype(wh_ref.dtype), wh_ref[:],
-                  preferred_element_type=jnp.float32) + bh_ref[:]
-    gates_buf[:, pl.ds(g * c, c)] = blk
-
-    @pl.when(g == n_blocks - 1)
-    def _():
-        m = mask_ref[0]
-        hnew, cnew = _lstm_elementwise_fwd(
-            xp_ref[0], gates_buf[:, :4 * h], hprev, c_c[:], m)
-        h_c[:] = hnew
-        c_c[:] = cnew
-        ys_ref[0] = hnew
-        if cs_ref is not None:
-            cs_ref[0] = cnew
-
-
-def _lstm_bwd_kernel(xp_ref, mask_ref, ys_prev_ref, cs_prev_ref, dy_ref,
-                     wh_ref, bh_ref, dxp_ref, dgates_ref, dh_c, dc_c):
-    ti = pl.program_id(0)
-
-    @pl.when(ti == 0)
-    def _():
-        dh_c[:] = jnp.zeros_like(dh_c)
-        dc_c[:] = jnp.zeros_like(dc_c)
-
-    first = ti == pl.num_programs(0) - 1
-    hprev = jnp.where(first, jnp.zeros_like(ys_prev_ref[0]),
-                      ys_prev_ref[0])
-    cprev = jnp.where(first, jnp.zeros_like(cs_prev_ref[0]),
-                      cs_prev_ref[0])
-    gates = jnp.dot(hprev.astype(wh_ref.dtype), wh_ref[:],
-                    preferred_element_type=jnp.float32) + bh_ref[:]
-    m = mask_ref[0]
-    dgates, dh_local, dc_prev = _lstm_elementwise_bwd(
-        xp_ref[0], gates, hprev, cprev, m, dh_c[:], dc_c[:], dy_ref[0])
-    dxp_ref[0] = dgates
-    dgates_ref[0] = dgates
-    dh_c[:] = dh_local + jax.lax.dot_general(
-        dgates.astype(wh_ref.dtype), wh_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dc_c[:] = dc_prev
-
-
-def _lstm_bwd_kernel_blocked(xp_ref, mask_ref, ys_prev_ref, cs_prev_ref,
-                             dy_ref, wh_ref, bh_ref, dxp_ref, dgates_ref,
-                             dh_c, dc_c, dh_acc, gates_buf, dg_prev,
-                             *, h: int, n_blocks: int, c: int):
-    ti = pl.program_id(0)
-    g = pl.program_id(1)
-
-    @pl.when((ti == 0) & (g == 0))
-    def _():
-        dh_c[:] = jnp.zeros_like(dh_c)
-        dc_c[:] = jnp.zeros_like(dc_c)
-        dg_prev[:] = jnp.zeros_like(dg_prev)
-
-    @pl.when(g == 0)
-    def _():
-        dh_acc[:] = jnp.zeros_like(dh_acc)
-
-    first = ti == pl.num_programs(0) - 1
-    hprev = jnp.where(first, jnp.zeros_like(ys_prev_ref[0]),
-                      ys_prev_ref[0])
-    blk = jnp.dot(hprev.astype(wh_ref.dtype), wh_ref[:],
-                  preferred_element_type=jnp.float32) + bh_ref[:]
-    gates_buf[:, pl.ds(g * c, c)] = blk
-
-    dgp = dg_prev[:, pl.ds(g * c, c)]
-    dh_acc[:] += jax.lax.dot_general(
-        dgp.astype(wh_ref.dtype), wh_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(g == n_blocks - 1)
-    def _():
-        cprev = jnp.where(first, jnp.zeros_like(cs_prev_ref[0]),
-                          cs_prev_ref[0])
-        m = mask_ref[0]
-        dgates, dh_local, dc_prev = _lstm_elementwise_bwd(
-            xp_ref[0], gates_buf[:, :4 * h], hprev, cprev, m,
-            dh_c[:] + dh_acc[:], dc_c[:], dy_ref[0])
-        dxp_ref[0] = dgates
-        dgates_ref[0] = dgates
-        dg_prev[:, :4 * h] = dgates
-        # dgates @ W^T rides the NEXT step's weight stream (dh_acc).
-        dh_c[:] = dh_local
-        dc_c[:] = dc_prev
-
-
-# ---------------------------------------------------------------------------
-# Host-side wiring.
-# ---------------------------------------------------------------------------
-
-def _lstm_pallas_raw(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype,
-                     want_cs: bool = True):
-    """want_cs=False (no-grad primal) skips the [T,B,H] cell-state tape
-    write; the BPTT backward needs it, eval/infer forward does not."""
-    b, t_max, h4 = xproj.shape
-    h = h4 // 4
-    dot = _dot_jnp_dtype(dot_dtype)
-    xp_t, mask_t = _time_major(xproj, mask)
-    bh2 = b_h.astype(jnp.float32).reshape(1, h4)
-    w = w_h.astype(dot)
-    n_out = 2 if want_cs else 1
-    out_shape = [jax.ShapeDtypeStruct((t_max, b, h), jnp.float32)] * n_out
-
-    if not _use_blocked(h, dot, n_gates=4):
-        idx, midx = _time_index_maps(t_max, reverse, blocked=False)
-        out = kernel_call(
-            _lstm_kernel, kernel="lstm_scan_fwd",
-            facts=scan_facts("resident", reverse, t_max, b, h, 4),
-            grid=(t_max,),
-            in_specs=_resident_in_specs(b, h, h4, idx, midx),
-            out_specs=[
-                pl.BlockSpec((1, b, h), idx, memory_space=pltpu.VMEM),
-            ] * n_out,
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((b, h), jnp.float32)] * 2,
-            interpret=interpret,
-        )(xp_t, mask_t, w, bh2)
-    else:
-        n_blocks, c = _block_layout(h4)
-        idx, midx = _time_index_maps(t_max, reverse, blocked=True)
-        out = kernel_call(
-            functools.partial(_lstm_kernel_blocked, h=h, n_blocks=n_blocks,
-                              c=c),
-            kernel="lstm_scan_fwd",
-            facts=scan_facts("blocked", reverse, t_max, b, h, 4),
-            grid=(t_max, n_blocks),
-            in_specs=[
-                pl.BlockSpec((1, b, h4), idx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, 1), midx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((h, c), lambda t, g: (0, g),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, c), lambda t, g: (0, g),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, b, h), idx, memory_space=pltpu.VMEM),
-            ] * n_out,
-            out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, n_blocks * c), jnp.float32),
-            ],
-            interpret=interpret,
-        )(xp_t, mask_t, _pad_cols(w, n_blocks * c),
-          _pad_cols(bh2, n_blocks * c))
-    ys, cs = out if want_cs else (out[0], None)
-    return ys, cs, xp_t, mask_t
+LSTM = ScanCell("lstm", _lstm_elementwise_fwd, _lstm_elementwise_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -288,62 +92,12 @@ def lstm_scan_pallas(xproj: jnp.ndarray, mask: jnp.ndarray,
                      interpret: bool = False,
                      dot_dtype: Optional[str] = None) -> jnp.ndarray:
     """Fused LSTM recurrence; contract matches models.rnn.lstm_scan."""
-    ys, _, _, _ = _lstm_pallas_raw(xproj, mask, w_h, b_h, reverse,
-                                   interpret, dot_dtype, want_cs=False)
+    (ys,), _, _ = scan_forward(LSTM, xproj, mask, w_h, b_h, reverse=reverse,
+                               interpret=interpret, dot_dtype=dot_dtype)
     return jnp.moveaxis(ys, 0, 1)
 
 
-def _lstm_kernel_q(xp_ref, mask_ref, wq_ref, sc_ref, bh_ref, ys_ref,
-                   h_c, c_c, *, dot):
-    """Weight-only int8 eval kernel: gates = (h @ Q) * scale + b (the
-    same column-scale-after-dot refactoring as rnn_pallas's
-    _gru_kernel_q; |q| <= 127 converts to ``dot`` losslessly)."""
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _():
-        h_c[:] = jnp.zeros_like(h_c)
-        c_c[:] = jnp.zeros_like(c_c)
-
-    hprev, cprev = h_c[:], c_c[:]
-    gates = jnp.dot(hprev.astype(dot), wq_ref[:].astype(dot),
-                    preferred_element_type=jnp.float32) \
-        * sc_ref[:] + bh_ref[:]
-    hnew, cnew = _lstm_elementwise_fwd(xp_ref[0], gates, hprev, cprev,
-                                       mask_ref[0])
-    h_c[:] = hnew
-    c_c[:] = cnew
-    ys_ref[0] = hnew
-
-
-def _lstm_kernel_blocked_q(xp_ref, mask_ref, wq_ref, sc_ref, bh_ref,
-                           ys_ref, h_c, c_c, gates_buf, *,
-                           h: int, n_blocks: int, c: int, dot):
-    """_lstm_kernel_blocked with int8 weight tiles (see rnn_pallas's
-    _gru_kernel_blocked_q): the streamed [H, C] block is s8, upcast in
-    VMEM next to its sliced scale columns, so per-step HBM weight
-    traffic is the quantized bytes. No cell-state tape (eval-only)."""
-    t = pl.program_id(0)
-    g = pl.program_id(1)
-
-    @pl.when((t == 0) & (g == 0))
-    def _():
-        h_c[:] = jnp.zeros_like(h_c)
-        c_c[:] = jnp.zeros_like(c_c)
-
-    hprev = h_c[:]
-    blk = jnp.dot(hprev.astype(dot), wq_ref[:].astype(dot),
-                  preferred_element_type=jnp.float32) \
-        * sc_ref[:] + bh_ref[:]
-    gates_buf[:, pl.ds(g * c, c)] = blk
-
-    @pl.when(g == n_blocks - 1)
-    def _():
-        hnew, cnew = _lstm_elementwise_fwd(
-            xp_ref[0], gates_buf[:, :4 * h], hprev, c_c[:], mask_ref[0])
-        h_c[:] = hnew
-        c_c[:] = cnew
-        ys_ref[0] = hnew
+lstm_scan_pallas.defvjp(*scan_vjp(LSTM))
 
 
 def lstm_scan_pallas_q(xproj: jnp.ndarray, mask: jnp.ndarray,
@@ -362,160 +116,10 @@ def lstm_scan_pallas_q(xproj: jnp.ndarray, mask: jnp.ndarray,
     whose 4-gate 12.4 MB int8 matrix misses residency. No cell-state
     tape in either regime (eval has no BPTT).
     """
-    b, t_max, h4 = xproj.shape
-    h = h4 // 4
-    if w_q.dtype != jnp.int8:
-        raise ValueError(f"w_q must be int8, got {w_q.dtype}")
-    dot = _dot_jnp_dtype(dot_dtype)
-    use_blocked = (_use_blocked(h, dot, n_gates=4, weight_bytes=1)
-                   if blocked is None else blocked)
-    if not use_blocked and not fits_vmem(h, 1, n_gates=4):
-        raise ValueError(
-            f"int8 fused LSTM forced resident (blocked=False) but H={h} "
-            f"exceeds the 1-byte residency budget")
-    xp_t, mask_t = _time_major(xproj, mask)
-    sc2 = w_scale.astype(jnp.float32).reshape(1, h4)
-    bh2 = b_h.astype(jnp.float32).reshape(1, h4)
-    if use_blocked:
-        n_blocks, c = _block_layout(h4)
-        idx, midx = _time_index_maps(t_max, reverse, blocked=True)
-        ys = kernel_call(
-            functools.partial(_lstm_kernel_blocked_q, h=h,
-                              n_blocks=n_blocks, c=c, dot=dot),
-            kernel="lstm_scan_q_fwd",
-            facts=scan_facts("blocked_q", reverse, t_max, b, h, 4),
-            grid=(t_max, n_blocks),
-            in_specs=_blocked_q_in_specs(b, h, h4, c, idx, midx),
-            out_specs=pl.BlockSpec((1, b, h), idx,
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((t_max, b, h), jnp.float32),
-            scratch_shapes=[
-                pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, n_blocks * c), jnp.float32),
-            ],
-            interpret=interpret,
-        )(xp_t, mask_t, _pad_cols(w_q, n_blocks * c),
-          _pad_cols(sc2, n_blocks * c), _pad_cols(bh2, n_blocks * c))
-        return jnp.moveaxis(ys, 0, 1)
-    idx, midx = _time_index_maps(t_max, reverse, blocked=False)
-    ys = kernel_call(
-        functools.partial(_lstm_kernel_q, dot=dot),
-        kernel="lstm_scan_q_fwd",
-        facts=scan_facts("resident_q", reverse, t_max, b, h, 4),
-        grid=(t_max,),
-        # Shared with gru_scan_pallas_q: specs in OPERAND order
-        # (xp, mask, w_q, scale, bias) from one constructor (ADVICE r4).
-        in_specs=_resident_q_in_specs(b, h, h4, idx, midx),
-        out_specs=pl.BlockSpec((1, b, h), idx, memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((t_max, b, h), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((b, h), jnp.float32)] * 2,
-        interpret=interpret,
-    )(xp_t, mask_t, w_q, sc2, bh2)
+    (ys,), _, _ = scan_forward(LSTM, xproj, mask, w_q, b_h, scale=w_scale,
+                               reverse=reverse, interpret=interpret,
+                               dot_dtype=dot_dtype, blocked=blocked)
     return jnp.moveaxis(ys, 0, 1)
-
-
-def _lstm_fwd(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype):
-    ys, cs, xp_t, mask_t = _lstm_pallas_raw(xproj, mask, w_h, b_h, reverse,
-                                            interpret, dot_dtype)
-    return jnp.moveaxis(ys, 0, 1), (xp_t, mask_t, w_h, b_h, ys, cs)
-
-
-def _lstm_bwd(reverse, interpret, dot_dtype, residuals, dy):
-    xp_t, mask_t, w_h, b_h, ys, cs = residuals
-    t_max, b, h = ys.shape
-    h4 = 4 * h
-    dot = _dot_jnp_dtype(dot_dtype)
-    dy_t = jnp.moveaxis(dy.astype(jnp.float32), 1, 0)
-    bh2 = b_h.astype(jnp.float32).reshape(1, h4)
-    w = w_h.astype(dot)
-    blocked = _use_blocked(h, dot, n_gates=4)
-    idx, midx = _time_index_maps(t_max, reverse, blocked=blocked)
-
-    if blocked:
-        bidx = lambda i, g: idx(t_max - 1 - i, g)
-        bmidx = lambda i, g: midx(t_max - 1 - i, g)
-        pidx = lambda i, g: idx(jnp.maximum(t_max - 2 - i, 0), g)
-    else:
-        bidx = lambda i: idx(t_max - 1 - i)
-        bmidx = lambda i: midx(t_max - 1 - i)
-        pidx = lambda i: idx(jnp.maximum(t_max - 2 - i, 0))
-
-    out_specs = [
-        pl.BlockSpec((1, b, h4), bidx, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, b, h4), bidx, memory_space=pltpu.VMEM),
-    ]
-    out_shape = [jax.ShapeDtypeStruct((t_max, b, h4), jnp.float32)] * 2
-
-    if not blocked:
-        dxp_t, dgates_t = kernel_call(
-            _lstm_bwd_kernel, kernel="lstm_scan_bwd",
-            facts=scan_facts("resident", reverse, t_max, b, h, 4),
-            grid=(t_max,),
-            in_specs=[
-                pl.BlockSpec((1, b, h4), bidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, 1), bmidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, h), pidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, h), pidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, h), bidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((h, h4), lambda i: (0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, h4), lambda i: (0, 0),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((b, h), jnp.float32)] * 2,
-            interpret=interpret,
-        )(xp_t, mask_t, ys, cs, dy_t, w, bh2)
-    else:
-        n_blocks, c = _block_layout(h4)
-        dxp_t, dgates_t = kernel_call(
-            functools.partial(_lstm_bwd_kernel_blocked, h=h,
-                              n_blocks=n_blocks, c=c),
-            kernel="lstm_scan_bwd",
-            facts=scan_facts("blocked", reverse, t_max, b, h, 4),
-            grid=(t_max, n_blocks),
-            in_specs=[
-                pl.BlockSpec((1, b, h4), bidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, 1), bmidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, h), pidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, h), pidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, b, h), bidx, memory_space=pltpu.VMEM),
-                pl.BlockSpec((h, c), lambda i, g: (0, g),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, c), lambda i, g: (0, g),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, h), jnp.float32),
-                pltpu.VMEM((b, n_blocks * c), jnp.float32),
-                pltpu.VMEM((b, n_blocks * c), jnp.float32),
-            ],
-            interpret=interpret,
-        )(xp_t, mask_t, ys, cs, dy_t, _pad_cols(w, n_blocks * c),
-          _pad_cols(bh2, n_blocks * c))
-
-    if reverse:
-        h_prev_seq = jnp.concatenate(
-            [ys[1:], jnp.zeros_like(ys[:1])], axis=0)
-    else:
-        h_prev_seq = jnp.concatenate(
-            [jnp.zeros_like(ys[:1]), ys[:-1]], axis=0)
-    # float32 operands, never rounded to 8 bits, at the precision the
-    # dot type states: the GRU's rule (rnn_pallas.recurrent_dw).
-    dw_h = recurrent_dw(h_prev_seq, dgates_t, dot)
-    db_h = jnp.sum(dgates_t, axis=(0, 1))
-    dxp = jnp.moveaxis(dxp_t, 0, 1)
-    return (dxp, jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1),
-            dw_h.astype(w_h.dtype), db_h.astype(b_h.dtype))
-
-
-lstm_scan_pallas.defvjp(_lstm_fwd, _lstm_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -533,9 +137,11 @@ lstm_scan_pallas.defvjp(_lstm_fwd, _lstm_bwd)
 # Mosaic's scoped-VMEM limit to what its blocks and temporaries need
 # (rnnt_he2019: 13.1 MB of bf16 weights, over the GRU/LSTM kernels'
 # 10 MB residency budget under the default 16 MiB limit). A model whose
-# weights do not fit :data:`LSTMP_VMEM_LIMIT` runs the XLA scan
-# (``lstmp_fits_vmem``, asked by models/rnn.py), and so does the
-# decoders' one-step path with its carried (c, r).
+# weights do not fit ``scan_pallas.LSTMP_VMEM_LIMIT`` runs the XLA scan
+# (``scan_route``, asked by models/rnn.py), and so does the decoders'
+# one-step path with its carried (c, r). The step is two matmuls around
+# layer-normalised gates, so the step bodies are this file's; the specs
+# and the call are scan_pallas's.
 #
 # Backward is BPTT with gate recompute: the forward tapes the cell
 # state beside the outputs; the backward kernel recomputes the gates
@@ -547,33 +153,7 @@ lstm_scan_pallas.defvjp(_lstm_fwd, _lstm_bwd)
 # all T*B rows run outside the time loop, as the GRU/LSTM kernels' do.
 # ---------------------------------------------------------------------------
 
-LSTMP_VMEM_LIMIT = 96 * 1024 * 1024     # of a v5e core's 128 MiB
 _LN_EPS = 1e-5                          # models.rnn.LN_EPS
-
-
-def _lstmp_vmem_bytes(b: int, h: int, p: int, dot_bytes: int,
-                      backward: bool) -> int:
-    """What a call holds in VMEM: the single-buffered weights, the
-    double-buffered per-step blocks and the float32 [B, 4H]
-    temporaries of the gate math (6 forward, 12 backward)."""
-    weights = (p * 4 * h + h * p) * dot_bytes + 2 * 4 * h * 4
-    row = b * 4 * h
-    if backward:
-        blocks = 2 * (2 * row * dot_bytes + (2 * b * h + 3 * b * p) * 4)
-        return weights + blocks + 12 * row * 4
-    blocks = 2 * (row * dot_bytes + (b * h + b * p) * 4)
-    return weights + blocks + 6 * row * 4
-
-
-def lstmp_fits_vmem(b: int, h: int, p: int, dot_bytes: int) -> bool:
-    return _lstmp_vmem_bytes(b, h, p, dot_bytes, True) <= LSTMP_VMEM_LIMIT
-
-
-def _lstmp_params(b, h, p, dot_bytes, backward):
-    need = _lstmp_vmem_bytes(b, h, p, dot_bytes, backward)
-    limit = min(LSTMP_VMEM_LIMIT, max(32 * 1024 * 1024, need * 5 // 4))
-    return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
-                                vmem_limit_bytes=limit)
 
 
 def _lstmp_gates(a, scale, bias, h: int, layer_norm: bool):
@@ -700,13 +280,6 @@ def _lstmp_bwd_kernel(xp_ref, mask_ref, rs_prev_ref, cs_prev_ref, dy_ref,
     dc_c[:] = dc_new * f + (1.0 - m) * dc_in
 
 
-def _const_spec(shape):
-    """A whole-array VMEM block that never moves: one buffer."""
-    return pl.BlockSpec(shape, lambda t: (0,) * len(shape),
-                        memory_space=pltpu.VMEM,
-                        pipeline_mode=pl.Buffered(1))
-
-
 def _lstmp_operands(w_r, w_p, ln_scale, ln_bias, dot):
     h4 = w_r.shape[1]
     layer_norm = ln_scale is not None
@@ -717,38 +290,36 @@ def _lstmp_operands(w_r, w_p, ln_scale, ln_bias, dot):
     return w_r.astype(dot), w_p.astype(dot), sc, bi, layer_norm
 
 
+def _lstmp_call(body, backward, rows, operands, outs, whole_outs,
+                scratch, interpret):
+    """Either lstmp call: the per-step ``rows``, then W_r, W_p, the
+    layer-norm gain and bias, whole and single-buffered."""
+    wr, wp, sc, bi, layer_norm = operands
+    b, (h, p) = rows[0][0].shape[1], wp.shape
+    route = own_route(
+        "bwd" if backward else "fwd", "lstmp", rows=b, hidden=h, proj=p, dot_bytes=wr.dtype.itemsize,
+        backward=backward)
+    return scan_call(
+        functools.partial(body, h=h, layer_norm=layer_norm), route,
+        reverse=False, hidden=h, gates=4, more_facts={"p": p}, rows=rows,
+        weights=[wr, wp, sc, bi], outs=outs, whole_outs=whole_outs,
+        scratch=lambda cols: scratch, interpret=interpret,
+        buffer_weights_once=True, dimension_semantics=("arbitrary",))
+
+
 def _lstmp_raw(xproj, mask, w_r, w_p, ln_scale, ln_bias, interpret,
                dot_dtype, want_cs: bool):
-    b, t_max, h4 = xproj.shape
-    h, p = h4 // 4, w_p.shape[1]
-    dot = _dot_jnp_dtype(dot_dtype)
-    xp_t, mask_t = _time_major(xproj, mask)
-    wr, wp, sc, bi, layer_norm = _lstmp_operands(w_r, w_p, ln_scale,
-                                                 ln_bias, dot)
-    idx, midx = _time_index_maps(t_max, False, blocked=False)
-    in_specs = [
-        pl.BlockSpec((1, b, h4), idx, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, b, 1), midx, memory_space=pltpu.VMEM),
-        _const_spec((p, h4)), _const_spec((h, p)),
-        _const_spec((1, h4)), _const_spec((1, h4)),
-    ]
-    widths = (p, h) if want_cs else (p,)
-    out = kernel_call(
-        functools.partial(_lstmp_kernel, h=h, layer_norm=layer_norm),
-        kernel="lstmp_scan_fwd",
-        facts={**scan_facts("resident", False, t_max, b, h, 4), "p": p},
-        grid=(t_max,),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, b, w), idx, memory_space=pltpu.VMEM)
-                   for w in widths],
-        out_shape=[jax.ShapeDtypeStruct((t_max, b, w), jnp.float32)
-                   for w in widths],
-        scratch_shapes=[pltpu.VMEM((b, h), jnp.float32),
-                        pltpu.VMEM((b, p), jnp.float32)],
-        compiler_params=_lstmp_params(b, h, p, jnp.dtype(dot).itemsize,
-                                      False),
-        interpret=interpret,
-    )(xp_t, mask_t, wr, wp, sc, bi)
+    """want_cs=False (no-grad primal) skips the [T,B,H] cell-state tape
+    write; the BPTT backward needs it, eval/infer forward does not."""
+    h, p = w_p.shape
+    xp_t, mask_t = time_major(xproj, mask)
+    at, _, _ = time_index_maps(xp_t.shape[0], False)
+    out = _lstmp_call(
+        _lstmp_kernel, False, [(xp_t, at), (mask_t, at)],
+        _lstmp_operands(w_r, w_p, ln_scale, ln_bias,
+                        dot_jnp_dtype(dot_dtype)),
+        [(w, jnp.float32, at) for w in ((p, h) if want_cs else (p,))],
+        [], [h, p], interpret)
     ys, cs = out if want_cs else (out[0], None)
     return ys, cs, xp_t, mask_t
 
@@ -778,50 +349,30 @@ def _lstmp_fwd(xproj, mask, w_r, w_p, ln_scale, ln_bias, interpret,
 
 def _lstmp_bwd(interpret, dot_dtype, residuals, dy):
     xp_t, mask_t, w_r, w_p, ln_scale, ln_bias, ys, cs = residuals
-    t_max, b, h = cs.shape
+    t_max, _, h = cs.shape
     p, h4 = w_p.shape[1], 4 * h
-    dot = _dot_jnp_dtype(dot_dtype)
-    wr, wp, sc, bi, layer_norm = _lstmp_operands(w_r, w_p, ln_scale,
-                                                 ln_bias, dot)
+    dot = dot_jnp_dtype(dot_dtype)
+    operands = _lstmp_operands(w_r, w_p, ln_scale, ln_bias, dot)
     dy_t = jnp.moveaxis(dy.astype(jnp.float32), 1, 0)
-    bidx = lambda i: (t_max - 1 - i, 0, 0)
-    pidx = lambda i: (jnp.maximum(t_max - 2 - i, 0), 0, 0)
-    step = lambda w, idx: pl.BlockSpec((1, b, w), idx,
-                                       memory_space=pltpu.VMEM)
-    acc = pl.BlockSpec((1, h4), lambda i: (0, 0), memory_space=pltpu.VMEM)
-    da_t, mo_t, dr_t, dsc, dbi = kernel_call(
-        functools.partial(_lstmp_bwd_kernel, h=h, layer_norm=layer_norm),
-        kernel="lstmp_scan_bwd",
-        facts={**scan_facts("resident", False, t_max, b, h, 4), "p": p},
-        grid=(t_max,),
-        in_specs=[step(h4, bidx), step(1, bidx), step(p, pidx),
-                  step(h, pidx), step(p, bidx),
-                  _const_spec((p, h4)), _const_spec((h, p)),
-                  _const_spec((1, h4)), _const_spec((1, h4))],
-        out_specs=[step(h4, bidx), step(h, bidx), step(p, bidx), acc, acc],
-        out_shape=[jax.ShapeDtypeStruct((t_max, b, h4), dot),
-                   jax.ShapeDtypeStruct((t_max, b, h), dot),
-                   jax.ShapeDtypeStruct((t_max, b, p), dot),
-                   jax.ShapeDtypeStruct((1, h4), jnp.float32),
-                   jax.ShapeDtypeStruct((1, h4), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((b, p), jnp.float32),
-                        pltpu.VMEM((b, h), jnp.float32)],
-        compiler_params=_lstmp_params(b, h, p, jnp.dtype(dot).itemsize,
-                                      True),
-        interpret=interpret,
-    )(xp_t, mask_t, ys, cs, dy_t, wr, wp, sc, bi)
+    _, at, at_prev = time_index_maps(t_max, False)
+    da_t, mo_t, dr_t, dsc, dbi = _lstmp_call(
+        _lstmp_bwd_kernel, True,
+        [(xp_t, at), (mask_t, at), (ys, at_prev), (cs, at_prev),
+         (dy_t, at)],
+        operands, [(h4, dot, at), (h, dot, at), (p, dot, at)],
+        [(1, h4)] * 2, [p, h], interpret)
 
     # The weight gradients over all T*B rows, outside the time loop;
     # operands in the dot type, float32 accumulation, as the oracle's
     # per-step contractions have them.
-    r_prev = jnp.concatenate([jnp.zeros_like(ys[:1]), ys[:-1]], axis=0)
+    r_prev = prev_sequence(ys, False)
     dw_r = jnp.einsum("tbp,tbg->pg", r_prev.astype(dot), da_t,
                       preferred_element_type=jnp.float32)
     dw_p = jnp.einsum("tbh,tbp->hp", mo_t, dr_t,
                       preferred_element_type=jnp.float32)
     dxp = jnp.moveaxis(da_t, 0, 1).astype(xp_t.dtype)
     dmask = jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1)
-    if not layer_norm:
+    if not operands[-1]:
         return (dxp, dmask, dw_r.astype(w_r.dtype), dw_p.astype(w_p.dtype),
                 None, None)
     return (dxp, dmask, dw_r.astype(w_r.dtype), dw_p.astype(w_p.dtype),

@@ -19,10 +19,11 @@ measured byte counts, producing the ``tier_max_batch`` map the
 asserts the int8 tier's rung strictly exceeds the bf16 tier's under
 the same synthetic budget.
 
-Beyond the resident footprint, blocked-regime replicas also RESERVE
-bandwidth-backed working bytes: when the recurrent matrices miss the
-VMEM residency budget, the kernel re-streams them from HBM every
-timestep, and pre-blocked-q int8 replicas had to hold (and stream) a
+Beyond the resident footprint, replicas past the residency budget
+also RESERVE bandwidth-backed working bytes: when the recurrent
+matrices miss it, the kernel copies them into its VMEM once a scan or
+re-streams them from HBM every timestep (the route's ``pinned`` /
+``blocked`` builds), and pre-blocked-q int8 replicas had to hold (and stream) a
 full-precision working copy — a per-replica constant that competed
 with batch rows for the same budget. :func:`recurrent_stream_bytes`
 prices that term per regime (0 once resident; the stored-width matrix
@@ -59,22 +60,27 @@ def max_batch_for_budget(param_bytes: int, per_row_bytes: int,
 def recurrent_stream_bytes(hidden: int, n_gates: int, weight_bytes: int,
                            *, layers: int = 1,
                            directions: int = 1) -> int:
-    """Per-timestep recurrent weight-stream bytes for one forward.
+    """Recurrent weight bytes a forward moves beside its parameters.
 
-    0 in the resident regime (the ``n_gates * H^2`` matrix at
-    ``weight_bytes``/element fits the VMEM residency budget and is
-    fetched once per scan), else the full matrix at its stored width —
-    the blocked kernels re-stream every column block each step. Scaled
-    by ``layers * directions`` matrices per step. ``weight_bytes`` is
-    the STORED element size: 1 for the s8-streaming q kernels, the dot
-    dtype's size for the fp kernels (including the fp working copy
-    that pre-blocked-q int8 replicas materialized).
+    0 where the route (ops/scan_pallas.scan_route) names a resident
+    build (the ``n_gates * H^2`` matrix at ``weight_bytes``/element
+    fits the VMEM residency budget and is fetched once per scan), else
+    the full matrix at its stored width: past the budget it is copied
+    into the call's VMEM once a scan or re-streamed in column blocks
+    each step. Scaled by ``layers * directions`` matrices.
+    ``weight_bytes`` is the STORED element size: 1 for the int8 q
+    kernels, the dot dtype's size for the fp kernels (including the fp
+    working copy that pre-blocked-q int8 replicas materialized).
     """
-    from ..ops.rnn_pallas import fits_vmem
+    from ..ops.scan_pallas import scan_route
 
     if hidden < 1 or n_gates < 1 or weight_bytes < 1:
         raise ValueError("need hidden, n_gates, weight_bytes >= 1")
-    if fits_vmem(hidden, weight_bytes, n_gates):
+    # resident or not does not depend on the rows: any will do
+    route = scan_route("gru" if n_gates == 3 else "lstm", "pallas", rows=1,
+                       hidden=hidden, dot_bytes=weight_bytes,
+                       int8=weight_bytes == 1)
+    if route.variant.startswith("resident"):
         return 0
     return n_gates * hidden * hidden * weight_bytes * layers * directions
 

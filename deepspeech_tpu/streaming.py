@@ -138,20 +138,13 @@ class StreamingTranscriber:
         self.tokenizer = tokenizer
         self.chunk_frames = chunk_frames
         self.num_features = cfg.features.num_features
-        # Fused Pallas cell for the per-chunk recurrence, when the
-        # resolved impl is pallas (measurement-backed 'auto' default)
-        # AND the weights fit the VMEM-resident regime; otherwise the
-        # XLA scan. The streaming cell is GRU-only (component 7's
-        # lookahead variant).
-        from .ops.rnn_pallas import fits_vmem
-        from .utils.impl import resolve_impl
+        # Fused Pallas cell for the per-chunk recurrence where the
+        # route names one for a carried state (the resolved impl is
+        # pallas, a GRU, weights resident); otherwise the XLA scan.
+        from .models.rnn import layer_scan_route
 
-        dot_bytes = jnp.dtype(cfg.model.dtype).itemsize
-        pallas_impl = (
-            resolve_impl(cfg.model.rnn_impl, oracle="xla") == "pallas"
-            and cfg.model.rnn_type == "gru")
-        self._use_pallas = (pallas_impl
-                            and fits_vmem(cfg.model.rnn_hidden, dot_bytes))
+        self._use_pallas = layer_scan_route(
+            cfg.model, carry=True).kernel is not None
         # Weight-only int8 PTQ for live serving: one-shot consumers
         # dequantize at chunk entry (fused into their matmuls); the
         # recurrent matrices stay int8 into the resident q-kernel when

@@ -14,9 +14,8 @@ Pallas serving path. Recurrent matrices kept int8 by
 regimes: H that fits the 1-byte residency budget (GRU up to H=1869,
 LSTM to H=1619) sits RESIDENT in VMEM — zero per-step weight traffic
 — and larger H (the flagship LSTM H=1760, GRU past 1869) STREAMS s8
-column tiles through the blocked kernels
-(``_gru_kernel_blocked_q``/``_lstm_kernel_blocked_q``), dequantizing
-in VMEM, so the dominant per-step HBM stream is the quantized bytes:
+column tiles through the ``blocked_q`` build (ops/scan_pallas.py's
+streamed step over int8 blocks), dequantizing in VMEM, so the dominant per-step HBM stream is the quantized bytes:
 4× less than f32, with no fp working copy materialized anywhere.
 What still pays full-precision stream bytes: the XLA-impl fallback
 (``gru_scan`` dequantizes outside the scan) and the chunked streaming
@@ -145,24 +144,22 @@ def keep_recurrent_q(model_cfg, streaming: bool = False) -> \
     (ops/rnn_pallas.gru_scan_pallas_q /
     ops/lstm_pallas.lstm_scan_pallas_q), else None (dequant at entry).
 
-    Conditions: the resolved rnn impl is pallas, the cell has a
-    q-kernel (GRU or LSTM), and the tree is non-pipelined
+    Conditions: the route (ops/scan_pallas.scan_route) names a q
+    kernel for the int8 matrix — the resolved rnn impl is pallas and
+    the cell has one (GRU or LSTM) — and the tree is non-pipelined
     (models/pipe_stack threads wh_* straight into gru_scan with no
-    qdict handling). Every H qualifies on the batch path — the q
-    kernels pick resident or s8 blocked streaming themselves —
-    but ``streaming=True`` (the chunked engine, which re-enters the
-    kernel with a carried ``h0``) additionally requires the 1-byte
-    residency budget: the carried-state form is resident-only.
+    qdict handling). Every H qualifies on the batch path — resident or
+    s8 blocked streaming — but ``streaming=True`` (the chunked engine,
+    which re-enters the kernel with a carried ``h0``) additionally
+    requires the 1-byte residency budget: the carried-state form is
+    resident-only.
     """
-    from ..ops.rnn_pallas import fits_vmem
-    from .impl import resolve_impl
+    from ..models.rnn import layer_scan_route
 
-    n_gates = 3 if model_cfg.rnn_type == "gru" else 4
-    if (resolve_impl(model_cfg.rnn_impl, oracle="xla") == "pallas"
-            and model_cfg.rnn_type in ("gru", "lstm")
-            and (not streaming
-                 or fits_vmem(model_cfg.rnn_hidden, 1, n_gates))
-            and model_cfg.pipeline_stages == 1):
+    if (model_cfg.rnn_type in ("gru", "lstm")
+            and model_cfg.pipeline_stages == 1
+            and layer_scan_route(model_cfg, int8=True,
+                                 carry=streaming).kernel is not None):
         return lambda path: path.endswith(("wh_fw", "wh_bw"))
     return None
 
@@ -175,15 +172,13 @@ def kernel_regime(model_cfg, quantized: bool,
     precision kernels / dequant-at-entry). Recorded per replica by the
     two-tier serving scenario so throughput deltas can be attributed to the
     kernel path."""
-    from ..ops.rnn_pallas import fits_vmem
+    from ..models.rnn import layer_scan_route
 
     if not quantized or keep_recurrent_q(model_cfg,
                                          streaming=streaming) is None:
         return "fp"
-    n_gates = 3 if model_cfg.rnn_type == "gru" else 4
-    if fits_vmem(model_cfg.rnn_hidden, 1, n_gates):
-        return "resident-q"
-    return "blocked-q"
+    return layer_scan_route(model_cfg, int8=True,
+                            carry=streaming).variant.replace("_", "-")
 
 
 def dequantize_params(qtree, dtype=jnp.float32, keep=None):

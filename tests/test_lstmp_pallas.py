@@ -83,12 +83,18 @@ def test_vmem_budget_at_the_published_widths():
     """rnnt_he2019 at b=64: bf16 weights are 13.1 MB (over the other
     scan kernels' 10 MB budget); both kernels fit the raised limit,
     float32 weights at a large batch do not and route to the scan."""
-    need = lstm_pallas._lstmp_vmem_bytes
+    from deepspeech_tpu.ops.scan_pallas import scan_route
+
+    def route(rows, dot_bytes, backward=False):
+        return scan_route("lstmp", "pallas", rows=rows, hidden=2048,
+                          proj=640, dot_bytes=dot_bytes, backward=backward)
+
     assert (640 * 8192 + 2048 * 640) * 2 == 13_107_200
-    assert need(64, 2048, 640, 2, False) < 32 * 2 ** 20
-    assert need(64, 2048, 640, 2, True) < 48 * 2 ** 20
-    assert lstm_pallas.lstmp_fits_vmem(64, 2048, 640, 2)
-    assert not lstm_pallas.lstmp_fits_vmem(256, 2048, 640, 4)
+    # a call asks for its need and a quarter: under 32 / 48 MiB of need
+    assert route(64, 2).vmem_limit < 32 * 2 ** 20 * 5 // 4
+    assert route(64, 2, True).vmem_limit < 48 * 2 ** 20 * 5 // 4
+    assert route(64, 2).kernel == "lstmp_scan_fwd"
+    assert route(256, 4).kernel is None
 
 
 def test_layer_routes_by_impl_rows_and_carry(monkeypatch):

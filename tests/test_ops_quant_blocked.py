@@ -10,7 +10,7 @@ programs, and the compiler contracts the elementwise gate update into
 fused multiply-adds differently in each (on this harness XLA:CPU gives
 one-ulp differences from IDENTICAL gates) —
 match the dequant-outside oracle within the established int8
-tolerances, and the regime plumbing — fits_vmem boundaries per stored
+tolerances, and the regime plumbing — the route's boundaries per stored
 width, the serving ladder's streamed-bytes reservation, the analytic
 4x stream ratio — prices them correctly.
 """
@@ -22,10 +22,16 @@ import numpy as np
 import pytest
 
 from deepspeech_tpu.models.rnn import gru_scan, lstm_scan
-from deepspeech_tpu.ops import rnn_pallas
+from deepspeech_tpu.ops import rnn_pallas, scan_pallas
 from deepspeech_tpu.ops.lstm_pallas import lstm_scan_pallas_q
-from deepspeech_tpu.ops.rnn_pallas import (_block_layout, _use_blocked,
-                                           fits_vmem, gru_scan_pallas_q)
+from deepspeech_tpu.ops.rnn_pallas import gru_scan_pallas_q
+from deepspeech_tpu.ops.scan_pallas import block_layout, scan_route
+
+
+def _variant(cell, hidden, dot_bytes, int8=False, rows=8):
+    """The build the route names for a batch call at the Pallas impl."""
+    return scan_route(cell, "pallas", rows=rows, hidden=hidden,
+                      dot_bytes=dot_bytes, int8=int8).variant
 
 
 def _rand_gru(rng, b, t, h):
@@ -150,8 +156,8 @@ def test_blocked_q_auto_dispatch(monkeypatch):
     xproj, mask, w_h, b_h = _rand_gru(rng, 2, 7, 16)
     q, scale = _quantize_wh(w_h)
     ys_res = gru_scan_pallas_q(xproj, mask, q, scale, b_h, False, True)
-    monkeypatch.setattr(rnn_pallas, "_VMEM_WEIGHT_BUDGET", 0)
-    assert _use_blocked(16, jnp.float32, weight_bytes=1)
+    monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
+    assert _variant("gru", 16, 4, int8=True) == "blocked_q"
     ys_auto = gru_scan_pallas_q(xproj, mask, q, scale, b_h, False, True)
     np.testing.assert_allclose(np.asarray(ys_res), np.asarray(ys_auto),
                                **_ULPS)
@@ -172,7 +178,7 @@ def test_models_rnn_routes_qdict_every_h(monkeypatch):
         return real(xp, m, wq, sc, bh, *a, **kw)
 
     monkeypatch.setattr(rnn_pallas, "gru_scan_pallas_q", spy)
-    monkeypatch.setattr(rnn_pallas, "_VMEM_WEIGHT_BUDGET", 0)
+    monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
     cfg = dataclasses.replace(get_config("ds2_small").model,
                               rnn_impl="pallas", rnn_hidden=16,
                               dtype="float32")
@@ -190,30 +196,32 @@ def test_models_rnn_routes_qdict_every_h(monkeypatch):
 
 # ---------------------------------------------------------------------------
 # Regime boundaries: residency is a function of the STORED width. These
-# pins are the dtype-aware _use_blocked contract the Inferencer and the
+# pins are the dtype-aware contract of the route the Inferencer and the
 # ladder both price against.
 # ---------------------------------------------------------------------------
 
 def test_fits_vmem_dtype_boundaries():
     # Flagship H=1760: f32 GRU streams (37.2 MB), int8 GRU is newly
     # resident (9.3 MB), int8 LSTM streams (12.4 MB > 10 MB).
-    assert not fits_vmem(1760, 4, 3)
-    assert fits_vmem(1760, 1, 3)
-    assert not fits_vmem(1760, 1, 4)
+    assert _variant("gru", 1760, 4) == "blocked"
+    assert _variant("gru", 1760, 4, int8=True) == "resident_q"
+    assert _variant("lstm", 1760, 4, int8=True) == "blocked_q"
     # First blocked H per cell at 1-byte storage.
-    assert fits_vmem(1869, 1, 3) and not fits_vmem(1870, 1, 3)
-    assert fits_vmem(1619, 1, 4) and not fits_vmem(1620, 1, 4)
+    assert [_variant("gru", h, 2, int8=True) for h in (1869, 1870)] == [
+        "resident_q", "blocked_q"]
+    assert [_variant("lstm", h, 2, int8=True) for h in (1619, 1620)] == [
+        "resident_q", "blocked_q"]
 
 
 def test_use_blocked_stored_width():
     # fp kernels: regime follows the MXU operand width.
-    assert _use_blocked(1760, jnp.float32)
-    assert _use_blocked(1760, jnp.bfloat16)
-    # q kernels: the s8 array is what streams — weight_bytes=1
+    assert _variant("gru", 1760, 4) == "blocked"
+    assert _variant("gru", 1760, 2) == "pinned"
+    # q kernels: the s8 array is what streams — its one byte
     # overrides the dot width, so int8 H=1760 GRU stays resident.
-    assert not _use_blocked(1760, jnp.bfloat16, weight_bytes=1)
-    assert _use_blocked(1870, jnp.bfloat16, weight_bytes=1)
-    assert _use_blocked(1760, jnp.bfloat16, n_gates=4, weight_bytes=1)
+    assert _variant("gru", 1760, 2, int8=True) == "resident_q"
+    assert _variant("gru", 1870, 2, int8=True) == "blocked_q"
+    assert _variant("lstm", 1760, 2, int8=True) == "blocked_q"
 
 
 def test_kernel_regime_per_replica():
@@ -239,7 +247,7 @@ def test_kernel_regime_per_replica():
 @pytest.mark.parametrize("n_gates", [3, 4])
 def test_blocked_stream_ratio_at_flagship(n_gates):
     h = 1760
-    n_blocks, c = _block_layout(n_gates * h)
+    n_blocks, c = block_layout(n_gates * h)
     step_s8 = n_blocks * c * h * 1
     step_f32 = n_blocks * c * h * 4
     assert step_f32 / step_s8 >= 3.5  # the PR's acceptance floor

@@ -12,7 +12,9 @@ import pytest
 from deepspeech_tpu.models.rnn import gru_scan
 from deepspeech_tpu.ops.ctc import ctc_grad, ctc_loss_ref
 from deepspeech_tpu.ops.ctc_pallas import _ctc_pallas_fwd, ctc_loss_pallas
-from deepspeech_tpu.ops.rnn_pallas import fits_vmem, gru_scan_pallas
+from deepspeech_tpu.ops import scan_pallas
+from deepspeech_tpu.ops.rnn_pallas import gru_scan_pallas
+from deepspeech_tpu.ops.scan_pallas import scan_route
 
 
 def _rand_ctc(rng, b, t, v, lmax):
@@ -178,11 +180,11 @@ def test_gru_pallas_q_beyond_residency_dispatch():
     """H past the 1-byte residency budget now dispatches blocked-q
     (no fp working copy) — the only residual raises are a carried h0
     (streaming has no blocked-q variant) and a forced-resident lie."""
-    from deepspeech_tpu.ops.rnn_pallas import (_use_blocked,
-                                               gru_scan_pallas_q)
+    from deepspeech_tpu.ops.rnn_pallas import gru_scan_pallas_q
 
     h = 2048  # 3*h^2 int8 = 12.6 MB > 10 MB budget -> blocked-q
-    assert _use_blocked(h, jnp.bfloat16, weight_bytes=1)
+    assert scan_route("gru", "pallas", hidden=h, dot_bytes=2,
+                      int8=True).variant == "blocked_q"
     xproj = jnp.zeros((1, 2, 3 * h), jnp.float32)
     mask = jnp.ones((1, 2), jnp.float32)
     q = jnp.zeros((h, 3 * h), jnp.int8)
@@ -208,8 +210,10 @@ def test_gru_pallas_respects_mask():
 
 
 def test_fits_vmem_thresholds():
-    assert fits_vmem(800)        # DS2-small/streaming hidden
-    assert not fits_vmem(1760)   # DS2-full falls back to XLA scan
+    # DS2-small/streaming hidden is resident; DS2-full's is not
+    assert scan_route("gru", "pallas", hidden=800).variant == "resident"
+    assert scan_route("gru", "pallas", rows=8,
+                      hidden=1760).variant == "blocked"
 
 
 def test_model_with_pallas_rnn_end_to_end():
@@ -343,10 +347,9 @@ def test_gru_scan_bf16_dot_close_to_f32():
 
 @pytest.fixture
 def force_blocked(monkeypatch):
-    from deepspeech_tpu.ops import rnn_pallas
-
-    monkeypatch.setattr(rnn_pallas, "_VMEM_WEIGHT_BUDGET", 0)
-    assert rnn_pallas._use_blocked(16, jnp.float32)
+    monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
+    assert scan_route("gru", "pallas", rows=3,
+                      hidden=16).variant == "pinned"
 
 
 @pytest.mark.parametrize("reverse", [False, True])
@@ -492,9 +495,9 @@ def test_gru_blocked_fwd_copy_once_bit_identical_to_streamed(
             lambda *args: pallas(*args), xproj, mask, w_h, b_h)
 
     pinned = build("pinned")
-    monkeypatch.setattr(rnn_pallas, "_PINNED_VMEM_CAP", 0)
+    monkeypatch.setattr(scan_pallas, "PINNED_VMEM_CAP", 0)
     streamed = build("blocked")
-    if dot_dtype is None and rnn_pallas._block_layout(3 * h)[0] > 1:
+    if dot_dtype is None and scan_pallas.block_layout(3 * h)[0] > 1:
         _assert_same_to_float32_rounding([pinned], [streamed], ["ys"])
     else:
         np.testing.assert_array_equal(np.asarray(pinned),
@@ -547,12 +550,12 @@ def test_gru_blocked_bwd_copy_once_agrees_with_streamed(
             lambda *args: grads(pallas)(*args), xproj, w_h, b_h)
 
     pinned = build("pinned")
-    monkeypatch.setattr(rnn_pallas, "_PINNED_VMEM_CAP", 0)
+    monkeypatch.setattr(scan_pallas, "PINNED_VMEM_CAP", 0)
     streamed = build("blocked")
     for a, b_, name in zip(pinned, streamed, names):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b_), rtol=0, err_msg=name,
-            atol=0 if rnn_pallas._block_layout(3 * h)[0] == 1
+            atol=0 if scan_pallas.block_layout(3 * h)[0] == 1
             else 5e-7 * float(jnp.abs(b_).max()))
 
     dot = None if dot_dtype is None else jnp.bfloat16
@@ -606,7 +609,7 @@ def test_gru_blocked_bwd_builds_stay_together_over_300_steps(
                 for a, b_, name in zip(got, want, names)}
 
     pinned = grads(pallas)
-    monkeypatch.setattr(rnn_pallas, "_PINNED_VMEM_CAP", 0)
+    monkeypatch.setattr(scan_pallas, "PINNED_VMEM_CAP", 0)
     assert _scan_variants(lambda: grads(pallas)) == ["blocked"] * 2
     streamed = grads(pallas)
     dot = None if dot_dtype is None else jnp.bfloat16
@@ -654,10 +657,14 @@ def test_gru_blocked_streams_when_the_matrix_passes_the_cap():
     # the limit function itself: the bf16 matrix alone, Mosaic's
     # default as the floor, and nothing past the cap
     mib = 2 ** 20
-    assert rnn_pallas._pinned_vmem_limit(2 * 1760 * 5376, 0, 0) == 24 * mib
-    assert rnn_pallas._pinned_vmem_limit(0, 0, 0) == 16 * mib
-    assert rnn_pallas._pinned_vmem_limit(
-        rnn_pallas._PINNED_VMEM_CAP, 0, 0) is None
+    # (one row: what is left is the matrix)
+    def limit(hidden, dot_bytes):
+        return scan_route("gru", "pallas", rows=1, hidden=hidden,
+                          dot_bytes=dot_bytes).vmem_limit
+
+    assert limit(1760, 2) == 24 * mib
+    assert limit(1323, 2) == 16 * mib   # 10.8 MB: just past the budget
+    assert limit(1760, 4) is None       # 48 MiB would be the cap itself
 
 
 def test_gru_copy_once_runs_one_grid_step_per_time_step(monkeypatch):
@@ -679,7 +686,7 @@ def test_gru_copy_once_runs_one_grid_step_per_time_step(monkeypatch):
                 for e in eqns]
 
     assert grids() == [((850,), [(1760, 5280)])] * 2
-    monkeypatch.setattr(rnn_pallas, "_PINNED_VMEM_CAP", 0)
+    monkeypatch.setattr(scan_pallas, "PINNED_VMEM_CAP", 0)
     assert grids() == [((850, 11), [(1760, 5632)])] * 2
 
 
@@ -719,7 +726,7 @@ def test_gru_pallas_bf16_dot_close_to_f32(monkeypatch, blocked):
     from deepspeech_tpu.ops import rnn_pallas
 
     if blocked:
-        monkeypatch.setattr(rnn_pallas, "_VMEM_WEIGHT_BUDGET", 0)
+        monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
     rng = np.random.default_rng(23)
     xproj, mask, w_h, b_h = _rand_gru(rng, 2, 12, 176)
     ys_o = gru_scan(xproj, mask, w_h, b_h, dot_dtype=jnp.bfloat16)
@@ -744,10 +751,8 @@ def test_gru_pallas_bf16_dot_close_to_f32(monkeypatch, blocked):
 
 
 def test_dot_dtype_rejects_unknown():
-    from deepspeech_tpu.ops.rnn_pallas import _dot_jnp_dtype
-
     with pytest.raises(ValueError, match="dot_dtype"):
-        _dot_jnp_dtype("float16")
+        scan_pallas.dot_jnp_dtype("float16")
 
 
 def test_ctc_pallas_loss_only_matches_vjp_path():
@@ -783,7 +788,7 @@ def test_lstm_pallas_forward_matches_scan(monkeypatch, blocked, reverse):
     from deepspeech_tpu.ops.lstm_pallas import lstm_scan_pallas
 
     if blocked:
-        monkeypatch.setattr(rnn_pallas, "_VMEM_WEIGHT_BUDGET", 0)
+        monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
     rng = np.random.default_rng(40)
     xproj, mask, w_h, b_h = _rand_lstm(rng, 3, 10, 144)  # 4H=576 -> 2 blocks
     ys_p = lstm_scan_pallas(xproj, mask, w_h, b_h, reverse, True)
@@ -800,7 +805,7 @@ def test_lstm_pallas_grads_match_scan(monkeypatch, blocked, reverse):
     from deepspeech_tpu.ops.lstm_pallas import lstm_scan_pallas
 
     if blocked:
-        monkeypatch.setattr(rnn_pallas, "_VMEM_WEIGHT_BUDGET", 0)
+        monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
     rng = np.random.default_rng(41)
     xproj, mask, w_h, b_h = _rand_lstm(rng, 2, 7, 12)
 
@@ -1086,7 +1091,7 @@ def test_three_bf16_products_hold_the_dw_limits():
     mask = jnp.asarray(np.arange(t)[None] < lens[:, None], jnp.float32)
     ct = jnp.asarray(rng.normal(size=(b, t, h)), jnp.float32)
     operands = []
-    real = rnn_pallas.recurrent_dw
+    real = scan_pallas.recurrent_dw
 
     def keep(h_prev, dgates, dot):
         operands.append((np.asarray(h_prev, np.float64).reshape(-1, h),
@@ -1095,7 +1100,7 @@ def test_three_bf16_products_hold_the_dw_limits():
         return real(h_prev, dgates, dot)
 
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(rnn_pallas, "recurrent_dw", keep)
+        mp.setattr(scan_pallas, "recurrent_dw", keep)
         jax.grad(lambda w: jnp.sum(ct * gru_scan_pallas(
             xproj, mask, w, b_h, False, True, "bfloat16")))(w_h)
     (h_prev, dgates), = operands

@@ -196,10 +196,11 @@ def test_inferencer_int8_kernel_path_matches_dequant(model_and_vars):
     must equal the dequantize-at-entry XLA path on the same qtree."""
     from deepspeech_tpu.data import CharTokenizer
     from deepspeech_tpu.infer import Inferencer
-    from deepspeech_tpu.ops.rnn_pallas import fits_vmem
+    from deepspeech_tpu.ops.scan_pallas import scan_route
 
     cfg, _, variables, feats, lens = model_and_vars
-    assert fits_vmem(cfg.model.rnn_hidden, 1)
+    assert scan_route("gru", "pallas", hidden=cfg.model.rnn_hidden,
+                      int8=True).variant == "resident_q"
     model_cfg = dataclasses.replace(cfg.model, vocab_size=29)
     model = create_model(model_cfg)
     variables = model.init(jax.random.PRNGKey(2), feats[:1], lens[:1],

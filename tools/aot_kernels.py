@@ -429,9 +429,9 @@ def _bytes_accessed(comp):
 def _stream_step_bytes(gates, h, weight_bytes):
     """Analytic per-step weight-stream bytes at the kernels' actual
     (padded) block layout."""
-    from deepspeech_tpu.ops.rnn_pallas import _block_layout
+    from deepspeech_tpu.ops.scan_pallas import block_layout
 
-    n_blocks, c = _block_layout(gates * h)
+    n_blocks, c = block_layout(gates * h)
     return n_blocks * c * h * weight_bytes
 
 

@@ -224,6 +224,19 @@ class ModelConfig:
     # Seeded gains of every RMSNorm: 1 + normal(std) (0: ones), so that
     # on seeded weights a dropped gain is seen.
     lfm_norm_gain_std: float = 0.0
+    # The expert block as a second family states it (``model_name:
+    # smallthinker``; every default is LFM2's reading): RMSNorm on each
+    # head of q and k, or none; the router's scoring function
+    # ("sigmoid": scores normalised over the chosen; "softmax": a
+    # softmax over the chosen logits, ``moe_primary_router_apply_softmax``);
+    # the gated experts' activation ("silu" | "relu"); and where the
+    # router reads: the feed-forward's normed input, or
+    # (``moe_route_pre_attn``) the layer's INPUT, before its norm and
+    # its attention, the routing carried to the feed-forward.
+    lfm_qk_norm: bool = True
+    moe_score_func: str = "sigmoid"
+    moe_expert_act: str = "silu"
+    moe_route_pre_attn: bool = False
 
     @property
     def time_stride(self) -> int:
@@ -727,6 +740,52 @@ def trinity_large() -> Config:
     )
 
 
+SMALLTHINKER_PERIOD = ("full_attention",) + ("sliding_attention",) * 3
+
+
+def smallthinker_21b_a3b() -> Config:
+    """One chip's share of SmallThinker-21BA3B-Instruct (``model_name:
+    smallthinker_21b_instruct``,
+    https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct/blob/main/config.json)
+    as a decoder-only speech recogniser that is TRAINED on recordings
+    of minutes, every width as published: hidden 2560, 28 query / 4
+    key-value heads of 128 without q/k norms, one global layer WITHOUT
+    positions to three sliding-window layers of 4096 (rotary, theta
+    1.5e6), two norms a layer, 64 routed experts of 768 in every layer,
+    top-6 by the router's logits, which it reads from the layer's INPUT
+    before attention, weights a softmax over the chosen six, gated-ReLU
+    experts, no shared expert, an untied head. The stated deployment
+    divides each layer over 4 chips: 16 of the 64 experts and 37,984 of
+    the 151,936 vocabulary rows live here, the rest is replicated.
+    Depth is cut to one whole period (global, sliding, sliding,
+    sliding). ``benchmark/configs/smallthinker_21b_a3b.json`` has the
+    published keys beside these and every reading that is this repo's
+    own."""
+    c = Config(name="smallthinker_21b_a3b")
+    return _replace(
+        c,
+        model=_replace(
+            c.model, conv_layers=(), conv_channels=(), rnn_layers=0,
+            bidirectional=False, rnn_batch_norm=False, frame_stack=8,
+            vocab_size=37984, lfm_hidden=2560,
+            lfm_layer_types=SMALLTHINKER_PERIOD, lfm_dense_layers=0,
+            lfm_heads=28, lfm_kv_heads=4, lfm_head_dim=128,
+            lfm_window=4096, lfm_rope_kinds=("sliding_attention",),
+            lfm_qk_norm=False, lfm_expert_dim=768, lfm_experts=64,
+            lfm_top_k=6, lfm_rope_theta=1.5e6, lfm_norm_eps=1e-6,
+            experts_held=16, expert_offset=0, moe_rows_bound=0.375,
+            lfm_seq_positions=6784, lm_tied_head=False,
+            moe_select_bias=False, moe_routed_scale=1.0,
+            moe_shared_experts=0, moe_score_func="softmax",
+            moe_expert_act="relu", moe_route_pre_attn=True),
+        data=_replace(c.data, batch_size=4, bucket_frames=(42000,),
+                      max_label_len=1520),
+        train=_replace(c.train, objective="lm", optimizer="adamw",
+                       learning_rate=1e-4, weight_decay=0.0,
+                       grad_clip_norm=1.0, warmup_steps=100),
+    )
+
+
 PRESETS = {
     "ds2_small": ds2_small,
     "ds2_full": ds2_full,
@@ -739,6 +798,7 @@ PRESETS = {
     "ax_k1": ax_k1,
     "xing4_29b_a4b": xing4_29b_a4b,
     "trinity_large": trinity_large,
+    "smallthinker_21b_a3b": smallthinker_21b_a3b,
 }
 
 

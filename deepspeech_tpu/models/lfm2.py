@@ -6,7 +6,11 @@ preset's data: LFM2's layers below; the A.X-K1 block's latent attention
 head of its own; and the Xing4.0 block, which is the second over
 ``hc_streams`` residual streams mixed by hyper-connections
 (``models/mhc.py``) with ``lm_draft_layers`` multi-token-prediction
-modules (``DraftModule``) after the last layer. ``hidden`` / ``loss``
+modules (``DraftModule``) after the last layer. A fourth family's block
+(``model_name: smallthinker``) is LFM2's with the preset's data: no
+q/k norms, a router that reads the layer's INPUT before attention
+(``moe_route_pre_attn``), a softmax over the chosen logits, gated-ReLU
+experts. ``hidden`` / ``loss``
 are the training path (the draft modules are not trained here);
 ``prefill`` and ``step`` the serving path through a cache
 (``decode/lm_greedy.py``), for the layer kinds that have one, and
@@ -57,6 +61,21 @@ BIAS_STD = 0.01
 REMAT_POLICY = jax.checkpoint_policies.save_from_both_policies(
     jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
     jax.checkpoint_policies.save_only_these_names("moe_rows"))
+# Past one block of queries the products' results are 35 kB a position
+# and layer (3.8 GB for four recordings of 6,784 positions in four
+# layers): such a layer keeps its input, the attention kernel's result
+# and log-sum-exp and the grouped products' results by name (7.2 kB a
+# position and 1.6 kB a routed pair), and runs the rest of its forward
+# pass again in the backward pass: the projections, not the kernels.
+LONG_REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    "moe_rows", "attn_out", "attn_lse")
+
+
+def remat_policy(cfg: ModelConfig):
+    """What a rematerialised layer keeps: by the sequence the preset
+    states (``lfm_seq_positions``), one block or more."""
+    return (LONG_REMAT_POLICY if cfg.lfm_seq_positions > Attention.block
+            else REMAT_POLICY)
 
 
 def seq_positions(cfg: ModelConfig, frames: int, max_label_len: int
@@ -201,9 +220,10 @@ def cached_attend(q, keys, values, pos, window: int):
 
 class Attention(nn.Module):
     """Causal grouped-query attention: RMSNorm over each head of q and
-    of k, then the rotation where the layer's ``kind`` has one
-    (``lfm_rope_kinds``), every key/value head shared by ``heads /
-    kv_heads`` query heads; a "sliding_attention" layer sees the last
+    of k (``lfm_qk_norm``), then the rotation where the layer's
+    ``kind`` has one (``lfm_rope_kinds``), every key/value head shared
+    by ``heads / kv_heads`` query heads; a "sliding_attention" layer
+    sees the last
     ``lfm_window`` keys, its own among them; with ``lfm_attn_gate`` the
     heads' output is multiplied by the sigmoid of a projection of the
     layer's input before ``o``.
@@ -249,7 +269,8 @@ class Attention(nn.Module):
 
         def placed(x, name):
             """A head's norm, then the layer kind's rotation."""
-            x = RMSNorm(cfg.lfm_norm_eps, std, name=name)(x)
+            if cfg.lfm_qk_norm:
+                x = RMSNorm(cfg.lfm_norm_eps, std, name=name)(x)
             if self.kind not in cfg.lfm_rope_kinds:
                 return x
             return rotary(x, cfg.lfm_rope_theta,
@@ -273,7 +294,8 @@ class Attention(nn.Module):
         def blockwise(q, k, v):
             """:func:`attend` a block of queries at a time, each
             against the keys in its reach: the plain form of the
-            sequence past one block, and the kernel's oracle."""
+            sequence past one block, forward and (differentiated)
+            backward, and the kernels' oracle."""
             outs = []
             for i0 in range(0, s, self.block):
                 i1 = min(i0 + self.block, s)
@@ -288,8 +310,7 @@ class Attention(nn.Module):
                 if s <= self.block:
                     out = attend(q, k, v, 0, 0)
                 elif attends_in_kernels(cfg):
-                    out = attn_pallas.gqa_attention(q, k, v, window,
-                                                    blockwise)
+                    out = attn_pallas.gqa_attention(q, k, v, window)
                 else:
                     out = blockwise(q, k, v)
         elif s != 1:
@@ -370,36 +391,53 @@ class SparseExperts(nn.Module):
     uneven them, so it is seeded small (std ``BIAS_STD``: enough to
     move the chosen set of a quarter of the positions, so a build that
     drops it is seen; at 0.05 single experts drew three times the mean
-    load and a step's time followed the seed)."""
+    load and a step's time followed the seed).
+
+    A family may route from another tensor than the one its experts
+    read (``moe_route_pre_attn``): :meth:`route` scores and chooses,
+    and ``__call__`` takes that ``routing`` in place of its own."""
 
     cfg: ModelConfig
 
-    @nn.compact
-    def __call__(self, x, valid):
+    def setup(self):
         cfg = self.cfg
-        b, s, d = x.shape
-        g, f = cfg.experts_held, cfg.lfm_expert_dim
-        w_gate = self.param("router", _INIT, (d, cfg.lfm_experts))
-        w13 = self.param("w13", _INIT, (g, d, 2 * f))
-        w2 = self.param("w2", _INIT, (g, f, d))
-        bias = None
+        d, g, f = cfg.lfm_hidden, cfg.experts_held, cfg.lfm_expert_dim
+        self.router = self.param("router", _INIT, (d, cfg.lfm_experts))
+        self.w13 = self.param("w13", _INIT, (g, d, 2 * f))
+        self.w2 = self.param("w2", _INIT, (g, f, d))
         if cfg.moe_select_bias:
-            bias = self.variable(
+            self.expert_bias = self.variable(
                 "buffers", "expert_bias",
                 lambda: BIAS_STD * jax.random.normal(
                     self.make_rng("params"), (cfg.lfm_experts,),
-                    jnp.float32)).value
-        flat = x.reshape(b * s, d)
-        routing = moe.route(flat, w_gate, bias, cfg.lfm_top_k,
-                            cfg.moe_groups, cfg.moe_groups_kept,
-                            cfg.moe_routed_scale)
+                    jnp.float32))
+        if cfg.moe_shared_experts:
+            self.shared = SwiGLU(cfg.moe_shared_experts * f)
+
+    def route(self, x) -> moe.Routing:
+        """The routing of the positions of ``x [B, S, D]`` or ``[N,
+        D]``, ``[N, .]``."""
+        cfg = self.cfg
+        routing = moe.route(
+            x.reshape(-1, x.shape[-1]), self.router,
+            self.expert_bias.value if cfg.moe_select_bias else None,
+            cfg.lfm_top_k, cfg.moe_groups, cfg.moe_groups_kept,
+            cfg.moe_routed_scale, cfg.moe_score_func)
         self.sow("intermediates", "scores", routing.scores)
         self.sow("intermediates", "experts", routing.experts)
         self.sow("intermediates", "weights", routing.weights)
+        return routing
+
+    def __call__(self, x, valid, routing=None):
+        cfg = self.cfg
+        b, s, d = x.shape
+        flat = x.reshape(b * s, d)
+        if routing is None:
+            routing = self.route(flat)
         out, counters = moe.expert_layer(
-            flat, valid.reshape(-1), routing, w13, w2,
+            flat, valid.reshape(-1), routing, self.w13, self.w2,
             offset=cfg.expert_offset, rows_bound=cfg.moe_rows_bound,
-            impl=cfg.moe_impl)
+            impl=cfg.moe_impl, act=cfg.moe_expert_act)
         out = out.reshape(b, s, d)
         if cfg.moe_groups > 1:
             # Groups a valid position's chosen experts lie in: never
@@ -410,8 +448,7 @@ class SparseExperts(nn.Module):
             counters["groups_used"] = jnp.sum(
                 used * valid.reshape(-1, 1))
         if cfg.moe_shared_experts:
-            out = out + SwiGLU(cfg.moe_shared_experts * f,
-                               name="shared")(x)
+            out = out + self.shared(x)
         return out, counters
 
 
@@ -421,7 +458,11 @@ ATTENTION_KINDS = ("full_attention", "sliding_attention")
 class DecoderLayer(nn.Module):
     """``h + operator(norm(h))``, then ``h + ffn(norm(h))``, each
     sub-layer's output through a norm of its own before it is added
-    where the family has sandwich norms (``lfm_post_norms``); with
+    where the family has sandwich norms (``lfm_post_norms``); where
+    the family's router reads the layer's input (``moe_route_pre_attn``)
+    the experts are chosen from ``h`` as it arrives, before its norm
+    and its operator, and the feed-forward applies that choice to
+    ``norm(h + operator(..))``; with
     ``hc_streams`` > 1 the residual ``h [B, S, n, D]`` is n streams and
     each of the two sub-layers reads and writes them through its own
     hyper-connection (``models/mhc.py``). Returns
@@ -479,11 +520,16 @@ class DecoderLayer(nn.Module):
         def feed_forward(x):
             x = norm("ffn_norm")(x)
             if self.sparse:
-                return after("ffn_post_norm", SparseExperts(
-                    cfg, name="moe")(x, valid))
+                return after("ffn_post_norm", experts(x, valid, routing))
             return after("ffn_post_norm", (
                 SwiGLU(cfg.lfm_ffn_dim, name="ffn")(x), None))
 
+        routing = None
+        if self.sparse:
+            experts = SparseExperts(cfg, name="moe")
+            if cfg.moe_route_pre_attn:
+                with jax.named_scope("moe_route_pre_attn"):
+                    routing = experts.route(h)
         h, cache = self.residual("op_hc", h, operator)
         h, counters = self.residual("ffn_hc", h, feed_forward)
         return h, counters, cache
@@ -538,7 +584,7 @@ class LFM2ASR(nn.Module):
 
     def setup(self):
         cfg = self.cfg
-        layer_cls = nn.remat(DecoderLayer, policy=REMAT_POLICY)
+        layer_cls = nn.remat(DecoderLayer, policy=remat_policy(cfg))
         self.embed = self.param("embed", _INIT,
                                 (cfg.vocab_size, cfg.lfm_hidden))
         self.prefix = Linear(cfg.lfm_hidden)
@@ -604,6 +650,9 @@ class LFM2ASR(nn.Module):
         valid = layout["valid"]
         stats = {"valid_positions": jnp.sum(valid),
                  "padded_positions": valid.size - jnp.sum(valid)}
+        if self.cfg.lfm_window:
+            stats.update(reach_pairs(jnp.sum(valid, axis=1),
+                                     self.cfg.lfm_window))
         if counters:
             stats.update(stack_counters(counters))
         return nll, stats
@@ -710,6 +759,18 @@ class LFM2ASR(nn.Module):
             cache, counters
 
 
+def reach_pairs(lens, window: int) -> dict:
+    """(query, key) pairs in reach of the valid positions of sequences
+    of ``lens [B]`` valid positions (left-packed), in ONE layer of each
+    kind: ``n (n + 1) / 2`` where the layer sees all, and ``min(i + 1,
+    window)`` keys for query i where it has a window."""
+    n = lens.astype(jnp.int32)
+    near = jnp.minimum(n, window)
+    return {"reach_pairs_global": jnp.sum(n * (n + 1) // 2),
+            "reach_pairs_window": jnp.sum(
+                near * (near + 1) // 2 + (n - near) * window)}
+
+
 def stack_counters(counters: list) -> dict:
     """The expert layers' counters, one leading axis over the layers."""
     if not counters:
@@ -751,6 +812,10 @@ def create_lfm2_model(cfg: ModelConfig, max_label_len: int) -> LFM2ASR:
         raise ValueError(
             f"experts {cfg.expert_offset}..+{cfg.experts_held} are not "
             f"among the router's {cfg.lfm_experts}")
+    if cfg.moe_route_pre_attn and cfg.hc_streams > 1:
+        raise NotImplementedError(
+            "a router that reads the layer's input has one residual "
+            "stream to read (hc_streams = 1)")
     return LFM2ASR(cfg, max_label_len)
 
 

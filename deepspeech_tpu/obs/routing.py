@@ -14,9 +14,21 @@ step's log line carries:
                                   fit the static row capacity, over
                                   EVERY step since the last logged
                                   one: 0, or the run ends
+  moe_experts_hit                 held experts that received a pair, over
+                                  the logged steps' expert layers
   moe_rows_high_water (gauge)     most rows any layer of any step used
   moe_rows_capacity (gauge)       the static rows of the dispatch
   lm_valid_positions / lm_padded_positions
+
+and, where the trained layers are grouped-query attention with a
+window (the step then counts them: ``models/lfm2.reach_pairs``):
+
+  lm_reach_pairs_window           (query, key) pairs in reach of the
+                                  step's valid positions in ONE windowed
+                                  layer (``min(i + 1, lfm_window)`` keys
+                                  for query i)
+  lm_reach_pairs_global           the same in ONE layer that sees all
+                                  (every causal pair)
 
 and from a served call's (``decode.mode="lm_greedy"``;
 :func:`observe_lm_call`): the same, over the call's prefill sub-batches
@@ -115,6 +127,10 @@ def observe_routing(routing: Dict, dropped: Sequence = ()
            "padded_positions": int(r["padded_positions"])}
     reg.count("lm_valid_positions", out["valid_positions"])
     reg.count("lm_padded_positions", out["padded_positions"])
+    for k in ("reach_pairs_window", "reach_pairs_global"):
+        if k in r:
+            out[k] = int(r[k])
+            reg.count("lm_" + k, out[k])
     if "expert_pairs" not in r:   # a stack without sparse layers
         return out
     pairs = np.asarray(r["expert_pairs"])               # [layers, held]
@@ -124,9 +140,11 @@ def observe_routing(routing: Dict, dropped: Sequence = ()
                       labels={"layer": layer, "expert": expert})
     out.update(
         expert_pairs=pairs.tolist(),
+        experts_hit_by_layer=np.sum(pairs > 0, axis=1).tolist(),
         pairs_elsewhere=np.asarray(r["pairs_elsewhere"]).tolist(),
         rows_high_water=int(np.max(r["rows_high_water"])),
         rows_capacity=int(np.max(r["rows_capacity"])))
+    reg.count("moe_experts_hit", sum(out["experts_hit_by_layer"]))
     reg.count("moe_pairs_elsewhere", sum(out["pairs_elsewhere"]))
     reg.gauge("moe_rows_capacity", out["rows_capacity"])
     reg.gauge("moe_rows_high_water", max(

@@ -1,14 +1,15 @@
-"""Grouped-query attention's two forms as one Pallas kernel each:
+"""Grouped-query attention's two forms as Pallas kernels:
 ``gqa_attn_fwd`` (:func:`gqa_attention`, a whole sequence, described
-here) and ``gqa_attn_decode`` (:func:`gqa_decode`, one position a
-stream against its cache, described there).
+here) with its backward pair ``gqa_attn_bwd_dq`` / ``gqa_attn_bwd_dkv``,
+and ``gqa_attn_decode`` (:func:`gqa_decode`, one position a stream
+against its cache, described there).
 
 Causal grouped-query attention over a whole sequence: the scores of a
 query tile against a key tile live in VMEM only, under a running row
 maximum and sum (the online softmax of flash attention), so no score
 reaches HBM.
 
-``gqa_attention(q [B,S,kv,rep,hd], k, v [B,S,kv,hd], window, oracle)``
+``gqa_attention(q [B,S,kv,rep,hd], k, v [B,S,kv,hd], window)``
 returns ``[B,S,kv,rep,hd]``: query i attends to the keys ``j <= i``, and
 ``i - window < j`` where there is a window (``models/lfm2.reach_mask``'s
 rule). Products are in the operands' dtype with float32 accumulation;
@@ -37,10 +38,28 @@ K and V are read where they lie, ``[B, S, kv x hd]``; the result is
 ``[B, kv, rep, S, hd]`` (a tile's rows a head at a time, whole tiles
 of the layout Mosaic writes), transposed by the caller's program.
 
-Differentiable through ``oracle(q, k, v)``, the caller's plain form of
-the same function: the backward pass is the oracle's VJP, recomputed.
-Nothing trains through it today (a trained layer's sequence is one
-block); it is there so that no path raises under ``jax.grad``.
+Differentiable through two more kernels that hold no ``[S, S]`` array
+either (flash attention's backward pass). Under ``jax.grad`` the
+forward kernel also gives out each query's log-sum-exp ``[B, kv, rep,
+S]`` (a served call's build has no such output and is the program it
+was); the backward pass recomputes a tile's scores and probabilities
+from it in VMEM, TRANSPOSED (keys down the sublanes, queries along the
+lanes: a query's log-sum-exp and ``delta = sum(dout * out)`` then meet
+their column as a row, without a relayout), and visits only the tiles
+in reach, by the forward's rule:
+
+- ``gqa_attn_bwd_dq``: the forward's grid (row, key/value head, query
+  tile, key tile innermost); ``dq += (p * (dout . v - delta)) . k``
+  summed over a query tile's key tiles in VMEM;
+- ``gqa_attn_bwd_dkv``: grid (row, key/value head, key tile, query
+  tile innermost; :func:`reach_of_keys`); ``dv += p^T . dout`` and
+  ``dk += (p * (dout . v - delta))^T . q`` summed in VMEM over the query
+  tiles AND over the ``rep`` query heads that share the key/value head.
+
+``dout`` is read and ``dq`` written where q lies (``[B, S, kv x rep x
+hd]``), ``dk`` and ``dv`` where k and v lie: nothing is transposed in
+HBM. A tile that hangs over the sequence's end zeroes what it would
+sum over there (queries in ``dkv``, keys in ``dq``).
 """
 
 from __future__ import annotations
@@ -51,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -114,7 +134,16 @@ def tile_counts(s: int, window: int, tq: int, tk: int) -> dict:
             "key_tiles_masked": computed - whole}
 
 
-def _fwd(q, k, v, window: int, tq: int, tk: int, interpret: bool):
+def _row(x):
+    """A row statistic ``[rows, lanes]`` (the same in every lane) as
+    one row ``[1, rows]``."""
+    return x.reshape(1, -1) if x.shape[1] == 1 else x.T[:1]
+
+
+def _fwd(q, k, v, window: int, tq: int, tk: int, interpret: bool,
+         with_lse: bool = False):
+    """The forward kernel's result ``[B, kv, rep, S, hd]`` and, asked
+    for, each query's log-sum-exp ``[B, kv, rep, S]`` in float32."""
     b, s, nkv, rep, hd = q.shape
     scale = hd ** -0.5
     first, last, _, _ = reach(s, window, tq, tk)
@@ -129,7 +158,9 @@ def _fwd(q, k, v, window: int, tq: int, tk: int, interpret: bool):
     def run_of(qi):
         return _run(qi * tq, s, window, tq, tk, jnp)
 
-    def body(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
+    def body(q_ref, k_ref, v_ref, o_ref, *rest):
+        lse_ref = rest[0] if with_lse else None
+        m_ref, l_ref, acc_ref = rest[-3:]
         qi, step = pl.program_id(2), pl.program_id(3)
         lo, hi = run_of(qi)
         kt = lo + step
@@ -190,6 +221,9 @@ def _fwd(q, k, v, window: int, tq: int, tk: int, interpret: bool):
             for r in range(rep):
                 o_ref[r] = (acc_ref[r] / _wide(l_ref[r], hd)).astype(
                     o_ref.dtype)
+                if with_lse:
+                    lse_ref[r:r + 1, :] = _row(
+                        m_ref[r] + jnp.log(l_ref[r]))
 
     def q_index(bi, g, qi, step):
         return bi, qi, g
@@ -201,18 +235,28 @@ def _fwd(q, k, v, window: int, tq: int, tk: int, interpret: bool):
     def out_index(bi, g, qi, step):
         return bi, g, 0, qi, 0
 
+    def lse_index(bi, g, qi, step):
+        return bi, g, 0, qi
+
     facts = {"b": b, "s": s, "kv": nkv, "rep": rep, "head": hd,
              "window": window, "q_tile": tq, "k_tile": tk,
              **tile_counts(s, window, tq, tk)}
+    out_shape = jax.ShapeDtypeStruct((b, nkv, rep, s, hd), q.dtype)
+    out_specs = pl.BlockSpec((None, None, rep, tq, hd), out_index)
+    if with_lse:
+        out_shape = (out_shape, jax.ShapeDtypeStruct(
+            (b, nkv, rep, s), jnp.float32))
+        out_specs = (out_specs,
+                     pl.BlockSpec((None, None, rep, tq), lse_index))
     return kernel_call(
         body, kernel="gqa_attn_fwd", facts=facts,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, rep, s, hd), q.dtype),
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
             in_specs=[pl.BlockSpec((None, tq, rep * hd), q_index),
                       pl.BlockSpec((None, tk, hd), kv_index),
                       pl.BlockSpec((None, tk, hd), kv_index)],
-            out_specs=pl.BlockSpec((None, None, rep, tq, hd), out_index),
+            out_specs=out_specs,
             grid=(b, nkv, len(first), steps),
             scratch_shapes=[pltpu.VMEM((rep, tq, lanes), jnp.float32),
                             pltpu.VMEM((rep, tq, lanes), jnp.float32),
@@ -224,29 +268,296 @@ def _fwd(q, k, v, window: int, tq: int, tk: int, interpret: bool):
       v.reshape(b, s, nkv * hd))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def gqa_attention(q, k, v, window: int, oracle, q_tile: int = Q_TILE,
-                  k_tile: int = K_TILE, interpret: bool = False):
-    """The kernel's result as the sequence form lays it out,
-    ``[B, S, kv, rep, hd]``."""
-    out = _fwd(q, k, v, window, q_tile, k_tile, interpret)
+def reach_of_keys(s: int, window: int, tq: int, tk: int):
+    """For each key tile of ``tk`` positions of ``s``: the first and the
+    last query tile of ``tq`` that hold a query which reaches one of its
+    keys (every tile between them does): :func:`reach`, transposed."""
+    j0 = np.arange(0, s, tk)
+    return _key_run(j0, s, window, tq, tk, np)
+
+
+def _key_run(j0, s: int, window: int, tq: int, tk: int, xp):
+    """:func:`reach_of_keys` for the key tile that starts at ``j0``."""
+    last = (s - 1) // tq + 0 * j0
+    if window:
+        last = xp.minimum(
+            (xp.minimum(j0 + tk, s) + window - 2) // tq, last)
+    return j0 // tq, last
+
+
+_BWD_VMEM = 64 * 1024 * 1024
+
+
+def _seen(i0, j0, s: int, window: int, tq: int, tk: int):
+    """``[tk, tq]``: which of the keys ``j0 ..`` (down) each of the
+    queries ``i0 ..`` (along) reaches, a query past the end none."""
+    at = i0 + lax.broadcasted_iota(jnp.int32, (1, tq), 1)
+    key = j0 + lax.broadcasted_iota(jnp.int32, (tk, 1), 0)
+    seen = jnp.logical_and(key <= at, at < s)
+    if window:
+        seen = jnp.logical_and(seen, key > at - window)
+    return seen
+
+
+def _whole(i0, j0, s: int, window: int, tq: int, tk: int):
+    """Every key of the tile in every query's reach, and every query
+    inside the sequence: the tile takes no mask."""
+    whole = jnp.logical_and(j0 + tk - 1 <= i0, i0 + tq <= s)
+    if window:
+        whole = jnp.logical_and(whole, j0 > i0 + tq - 1 - window)
+    return whole
+
+
+def _tile_grads(q, do, keys, values, lse, delta, seen, scale):
+    """One head's part of a tile pair, scores transposed ``[tk, tq]``:
+    the probabilities and the scores' gradient, in the operands' dtype
+    for the products that follow. ``seen`` None: no mask."""
+    dims = (((1,), (1,)), ((), ()))
+    st = lax.dot_general(keys, q, dims,
+                         preferred_element_type=jnp.float32) * scale
+    pt = jnp.exp(st - lse)
+    dpt = lax.dot_general(values, do, dims,
+                          preferred_element_type=jnp.float32)
+    dst = pt * (dpt - delta)
+    if seen is not None:
+        pt = jnp.where(seen, pt, 0.0)
+        dst = jnp.where(seen, dst, 0.0)
+    return pt.astype(values.dtype), dst.astype(keys.dtype)
+
+
+def _bwd_dq(q, k, v, do, lse, delta, window: int, tq: int, tk: int,
+            interpret: bool):
+    """``dq [B, S, kv x rep x hd]``: the forward's grid."""
     b, s, nkv, rep, hd = q.shape
+    scale = hd ** -0.5
+    first, last, _, _ = reach(s, window, tq, tk)
+    steps = int(np.max(last - first + 1))
+    ragged = s % tk != 0
+
+    def run_of(qi):
+        return _run(qi * tq, s, window, tq, tk, jnp)
+
+    def body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+             acc_ref):
+        qi, step = pl.program_id(2), pl.program_id(3)
+        lo, hi = run_of(qi)
+        kt = lo + step
+        i0, j0 = qi * tq, kt * tk
+
+        @pl.when(step == 0)
+        def _start():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def tile(masked: bool):
+            keys, values = k_ref[...], v_ref[...]
+            seen = _seen(i0, j0, s, window, tq, tk) if masked else None
+            if masked and ragged:
+                # what lies past the end is summed over: zero it
+                held = j0 + lax.broadcasted_iota(
+                    jnp.int32, (tk, 1), 0) < s
+                keys = jnp.where(held, keys, jnp.zeros((), keys.dtype))
+                values = jnp.where(held, values,
+                                   jnp.zeros((), values.dtype))
+            for r in range(rep):
+                head = slice(r * hd, (r + 1) * hd)
+                _, dst = _tile_grads(
+                    q_ref[:, head], do_ref[:, head], keys, values,
+                    lse_ref[r:r + 1, :], delta_ref[r:r + 1, :], seen,
+                    scale)
+                acc_ref[r] += lax.dot_general(
+                    dst, keys, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+        whole = _whole(i0, j0, s, window, tq, tk)
+        live = kt <= hi
+        pl.when(jnp.logical_and(live, whole))(lambda: tile(False))
+        pl.when(jnp.logical_and(live, jnp.logical_not(whole)))(
+            lambda: tile(True))
+
+        @pl.when(step == steps - 1)
+        def _finish():
+            for r in range(rep):
+                dq_ref[:, r * hd:(r + 1) * hd] = (
+                    acc_ref[r] * scale).astype(dq_ref.dtype)
+
+    def q_index(bi, g, qi, step):
+        return bi, qi, g
+
+    def kv_index(bi, g, qi, step):
+        lo, hi = run_of(qi)
+        return bi, jnp.minimum(lo + step, hi), g
+
+    def stat_index(bi, g, qi, step):
+        return bi, g, 0, qi
+
+    facts = {"b": b, "s": s, "kv": nkv, "rep": rep, "head": hd,
+             "window": window, "q_tile": tq, "k_tile": tk,
+             **tile_counts(s, window, tq, tk)}
+    wide = pl.BlockSpec((None, tq, rep * hd), q_index)
+    stat = pl.BlockSpec((None, None, rep, tq), stat_index)
+    return kernel_call(
+        body, kernel="gqa_attn_bwd_dq", facts=facts,
+        out_shape=jax.ShapeDtypeStruct((b, s, nkv * rep * hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            in_specs=[wide, pl.BlockSpec((None, tk, hd), kv_index),
+                      pl.BlockSpec((None, tk, hd), kv_index), wide,
+                      stat, stat],
+            out_specs=wide,
+            grid=(b, nkv, len(first), steps),
+            scratch_shapes=[pltpu.VMEM((rep, tq, hd), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM),
+        interpret=interpret,
+    )(q.reshape(b, s, nkv * rep * hd), k.reshape(b, s, nkv * hd),
+      v.reshape(b, s, nkv * hd), do.reshape(b, s, nkv * rep * hd), lse,
+      delta)
+
+
+def _bwd_dkv(q, k, v, do, lse, delta, window: int, tq: int, tk: int,
+             interpret: bool):
+    """``dk, dv [B, S, kv x hd]``: grid (row, key/value head, key tile,
+    query tile), the query tile innermost."""
+    b, s, nkv, rep, hd = q.shape
+    scale = hd ** -0.5
+    first, last = reach_of_keys(s, window, tq, tk)
+    steps = int(np.max(last - first + 1))
+    ragged = s % tq != 0
+
+    def run_of(kt):
+        return _key_run(kt * tk, s, window, tq, tk, jnp)
+
+    def body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+             dv_ref, dk_acc, dv_acc):
+        kt, step = pl.program_id(2), pl.program_id(3)
+        lo, hi = run_of(kt)
+        qi = lo + step
+        i0, j0 = qi * tq, kt * tk
+
+        @pl.when(step == 0)
+        def _start():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
+
+        def tile(masked: bool):
+            keys, values = k_ref[...], v_ref[...]
+            seen = _seen(i0, j0, s, window, tq, tk) if masked else None
+            for r in range(rep):
+                head = slice(r * hd, (r + 1) * hd)
+                q_r, do_r = q_ref[:, head], do_ref[:, head]
+                if masked and ragged:
+                    # the queries past the end are summed over: zero
+                    held = i0 + lax.broadcasted_iota(
+                        jnp.int32, (tq, 1), 0) < s
+                    q_r = jnp.where(held, q_r, jnp.zeros((), q_r.dtype))
+                    do_r = jnp.where(held, do_r,
+                                     jnp.zeros((), do_r.dtype))
+                pt, dst = _tile_grads(
+                    q_r, do_r, keys, values, lse_ref[r:r + 1, :],
+                    delta_ref[r:r + 1, :], seen, scale)
+                dv_acc[...] += jnp.dot(
+                    pt, do_r, preferred_element_type=jnp.float32)
+                dk_acc[...] += jnp.dot(
+                    dst, q_r, preferred_element_type=jnp.float32)
+
+        whole = _whole(i0, j0, s, window, tq, tk)
+        live = qi <= hi
+        pl.when(jnp.logical_and(live, whole))(lambda: tile(False))
+        pl.when(jnp.logical_and(live, jnp.logical_not(whole)))(
+            lambda: tile(True))
+
+        @pl.when(step == steps - 1)
+        def _finish():
+            dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    def at(kt, step):
+        lo, hi = run_of(kt)
+        return jnp.minimum(lo + step, hi)
+
+    def q_index(bi, g, kt, step):
+        return bi, at(kt, step), g
+
+    def kv_index(bi, g, kt, step):
+        return bi, kt, g
+
+    def stat_index(bi, g, kt, step):
+        return bi, g, 0, at(kt, step)
+
+    # the forward's tile pairs, seen from the keys: the guard is exact
+    pairs = int(np.sum(last - first + 1))
+    facts = {"b": b, "s": s, "kv": nkv, "rep": rep, "head": hd,
+             "window": window, "q_tile": tq, "k_tile": tk,
+             "key_tiles": pairs, "key_tiles_in_reach": pairs}
+    wide = pl.BlockSpec((None, tq, rep * hd), q_index)
+    stat = pl.BlockSpec((None, None, rep, tq), stat_index)
+    narrow = pl.BlockSpec((None, tk, hd), kv_index)
+    return kernel_call(
+        body, kernel="gqa_attn_bwd_dkv", facts=facts,
+        out_shape=(jax.ShapeDtypeStruct((b, s, nkv * hd), k.dtype),
+                   jax.ShapeDtypeStruct((b, s, nkv * hd), v.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            in_specs=[wide, narrow, narrow, wide, stat, stat],
+            out_specs=(narrow, narrow),
+            grid=(b, nkv, len(first), steps),
+            scratch_shapes=[pltpu.VMEM((tk, hd), jnp.float32),
+                            pltpu.VMEM((tk, hd), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM),
+        interpret=interpret,
+    )(q.reshape(b, s, nkv * rep * hd), k.reshape(b, s, nkv * hd),
+      v.reshape(b, s, nkv * hd), do.reshape(b, s, nkv * rep * hd), lse,
+      delta)
+
+
+def _laid_out(out, shape):
+    """The forward kernel's result as the sequence form lays it out."""
+    b, s, nkv, rep, hd = shape
     # The barrier holds the transposition to this dtype and place: left
     # free, XLA converts the kernel's result to the float32 of the
     # layer's gate first and moves twice the bytes (a whole layer 30.7
     # ms against 29.2 on the chip, PERF.md section 6, PR 42).
     flat = lax.optimization_barrier(
         out.transpose(0, 3, 1, 2, 4).reshape(b, s, nkv * rep * hd))
-    return flat.reshape(q.shape)
+    return flat.reshape(shape)
 
 
-def _attention_fwd(q, k, v, window, oracle, q_tile, k_tile, interpret):
-    return gqa_attention(q, k, v, window, oracle, q_tile, k_tile,
-                         interpret), (q, k, v)
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def gqa_attention(q, k, v, window: int, q_tile: int = Q_TILE,
+                  k_tile: int = K_TILE, interpret: bool = False):
+    """The kernel's result as the sequence form lays it out,
+    ``[B, S, kv, rep, hd]``."""
+    return _laid_out(_fwd(q, k, v, window, q_tile, k_tile, interpret),
+                     q.shape)
 
 
-def _attention_bwd(window, oracle, q_tile, k_tile, interpret, res, grad):
-    return jax.vjp(oracle, *res)[1](grad)
+def _attention_fwd(q, k, v, window, q_tile, k_tile, interpret):
+    out, lse = _fwd(q, k, v, window, q_tile, k_tile, interpret,
+                    with_lse=True)
+    # A layer that rematerialises may keep these two (models/lfm2.py):
+    # its backward pass then runs the projections again, not this call.
+    out = checkpoint_name(_laid_out(out, q.shape), "attn_out")
+    return out, (q, k, v, out, checkpoint_name(lse, "attn_lse"))
+
+
+def _attention_bwd(window, q_tile, k_tile, interpret, res, grad):
+    q, k, v, out, lse = res
+    with jax.named_scope(
+            "gqa_attn_" + ("window" if window else "global")):
+        grad = grad.astype(q.dtype)
+        delta = jnp.sum(grad.astype(jnp.float32)
+                        * out.astype(jnp.float32), axis=-1)
+        delta = delta.transpose(0, 2, 3, 1)          # [B, kv, rep, S]
+        args = (q, k, v, grad, lse, delta, window, q_tile, k_tile,
+                interpret)
+        dk, dv = _bwd_dkv(*args)
+        return (_bwd_dq(*args).reshape(q.shape), dk.reshape(k.shape),
+                dv.reshape(v.shape))
 
 
 gqa_attention.defvjp(_attention_fwd, _attention_bwd)

@@ -44,6 +44,11 @@ fact, not a name:
            (those that hold a key out of reach as well: the masked
            work a tile size costs is ``key_tiles x q_tile x k_tile``
            scores against the ones ``s`` and ``window`` need)
+           gqa_attn_bwd_dq: the same facts at its own tiles (its grid
+           is the forward's). gqa_attn_bwd_dkv: ``b`` .. ``k_tile`` the
+           same, ``key_tiles`` the (key tile, query tile) pairs its
+           grid computes and ``key_tiles_in_reach`` those that hold a
+           pair in reach (all: the guard is exact)
   b, rows, kv, rep, head, window, row_tile, row_tiles
            gqa_attn_decode: streams, rows of a stream's cache (a ring's
            or a full cache's), key/value heads, query heads each
@@ -77,6 +82,8 @@ KERNELS = frozenset({
     "moe_gmm",            # rows by ragged groups times each group's matrix
     "moe_tgmm",           # per group, rows^T times rows: weight gradients
     "gqa_attn_fwd",       # causal grouped-query attention, scores in VMEM
+    "gqa_attn_bwd_dq",    # its backward: dq over a query tile's key tiles
+    "gqa_attn_bwd_dkv",   # ... dk, dv over a key tile's query tiles and heads
     "gqa_attn_decode",    # one query a stream against its cache rows in reach
 })
 

@@ -35,23 +35,31 @@ from . import moe_pallas
 class Routing(NamedTuple):
     experts: jnp.ndarray   # [N, k] int32: chosen expert ids
     weights: jnp.ndarray   # [N, k] float32: their combine weights
-    scores: jnp.ndarray    # [N, E] float32: sigmoid router scores
+    scores: jnp.ndarray    # [N, E] float32: the router's scores
 
 
 def route(x, w_gate, bias, top_k: int, groups: int = 1,
-          groups_kept: int = 1, scale: float = 1.0) -> Routing:
-    """Sigmoid scores over all experts in float32, then the preset's
+          groups_kept: int = 1, scale: float = 1.0,
+          score_func: str = "sigmoid") -> Routing:
+    """Scores over all experts in float32, then the preset's
     selection rule. The experts are ``groups`` runs of consecutive
     ids; a group's score is its best expert's, and only the
     ``groups_kept`` best groups can be chosen from (``n_group`` /
     ``topk_group``; 1 of 1 is the plain rule). Among those the
     ``top_k`` of score + ``bias`` are chosen (``use_expert_bias``: the
     bias chooses, it does not weigh; None where the family has none).
-    The chosen scores, normalised to sum to one (``norm_topk_prob``)
-    and times ``scale`` (``routed_scaling_factor``), are the weights."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), w_gate.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+    With ``score_func`` "sigmoid" the scores are the logits' sigmoids
+    and the chosen ones, normalised to sum to one (``norm_topk_prob``),
+    are the weights; with "softmax" the scores are the logits
+    themselves and the weights a softmax over the chosen ones (a
+    softmax over all, the chosen renormalised). Either times ``scale``
+    (``routed_scaling_factor``)."""
+    scores = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if score_func == "sigmoid":
+        scores = jax.nn.sigmoid(scores)
+    elif score_func != "softmax":
+        raise ValueError(f"moe_score_func {score_func!r}")
     choose_by = scores if bias is None else scores + bias[None, :]
     if groups > 1:
         n, e = choose_by.shape
@@ -63,7 +71,11 @@ def route(x, w_gate, bias, top_k: int, groups: int = 1,
                               choose_by, -jnp.inf)
     _, experts = lax.top_k(choose_by, top_k)
     weights = jnp.take_along_axis(scores, experts, axis=1)
-    weights = weights / (jnp.sum(weights, axis=1, keepdims=True) + 1e-6)
+    if score_func == "softmax":
+        weights = jax.nn.softmax(weights, axis=1)
+    else:
+        weights = weights / (jnp.sum(weights, axis=1, keepdims=True)
+                             + 1e-6)
     if scale != 1.0:
         weights = weights * scale
     return Routing(experts.astype(jnp.int32), weights, scores)
@@ -93,13 +105,18 @@ def grouped_dot(lhs, rhs, group_sizes, impl: str):
     return checkpoint_name(out, "moe_rows")
 
 
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def expert_layer(x, valid, routing: Routing, w13, w2, *, offset: int,
-                 rows_bound: float = 0.0, impl: str = "auto"):
+                 rows_bound: float = 0.0, impl: str = "auto",
+                 act: str = "silu"):
     """``x [N, D]`` through the held experts.
 
     ``w13 [G, D, 2F]`` holds each expert's gate and up matrices side by
-    side, ``w2 [G, F, D]`` its down matrix; the experts are ids
-    ``offset .. offset+G`` of the router's. ``valid [N]`` marks the
+    side, ``w2 [G, F, D]`` its down matrix (``act(x gate) * (x up)``
+    through ``down``, ``act`` one of :data:`ACTIVATIONS`); the experts
+    are ids ``offset .. offset+G`` of the router's. ``valid [N]`` marks the
     positions that are routed at all (padding is not). Returns the
     partial result ``[N, D]`` (zeros where no chosen expert lives
     here) and the step's counters.
@@ -129,9 +146,9 @@ def expert_layer(x, valid, routing: Routing, w13, w2, *, offset: int,
     xs = jnp.take(x, token, axis=0)                       # [M, D]
     h = grouped_dot(xs, w13.astype(x.dtype), sizes, impl)
     f = f2 // 2
-    act = (jax.nn.silu(h[:, :f].astype(jnp.float32))
-           * h[:, f:].astype(jnp.float32)).astype(x.dtype)
-    ys = grouped_dot(act, w2.astype(x.dtype), sizes, impl)
+    gated = (ACTIVATIONS[act](h[:, :f].astype(jnp.float32))
+             * h[:, f:].astype(jnp.float32)).astype(x.dtype)
+    ys = grouped_dot(gated, w2.astype(x.dtype), sizes, impl)
     out = jnp.zeros((n, d), jnp.float32).at[token].add(
         ys.astype(jnp.float32) * weight[:, None])
 

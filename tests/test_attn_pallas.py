@@ -9,8 +9,11 @@ against ``reach_mask``'s dense reference and against the blockwise loop
 it replaces, for sliding and global
 layers, prefixes that are not whole tiles, shorter than the window and
 past window + tile, and tiles of several shapes; a stream's padded tail
-changes no valid row; the gradient past one block is the loop's; the
-static tile counts the kernel's facts carry; and ``Attention`` takes
+changes no valid row; the backward kernels (``gqa_attn_bwd_dq``,
+``gqa_attn_bwd_dkv``) against ``jax.vjp`` of the dense form over layer
+kind x sequence x tiles, dk/dv summed over 7 query heads, the gradient
+past the window against the loop's, the query tiles a key tile visits;
+the static tile counts the kernel's facts carry; and ``Attention`` takes
 the kernel where it says it does. (The interpreter pads a block that
 hangs over the sequence's end with NaN: a build that multiplied a
 probability of 0 with what lies there fails every ragged case.)"""
@@ -49,8 +52,8 @@ def dense(q, k, v, window):
     return attend(q, k, v, 0, 0, window)
 
 
-def kernel(q, k, v, window, tq, tk, oracle=None):
-    return attn_pallas.gqa_attention(q, k, v, window, oracle, tq, tk, True)
+def kernel(q, k, v, window, tq, tk):
+    return attn_pallas.gqa_attention(q, k, v, window, tq, tk, True)
 
 
 @pytest.mark.parametrize("window", [W, 0], ids=["sliding", "global"])
@@ -101,21 +104,112 @@ def test_a_padded_tail_changes_no_valid_row(window):
     assert np.all(np.isfinite(got))
 
 
+def grads(f, q, k, v, seed=5):
+    """The gradients of a scalar of ``f(q, k, v)`` (the cotangent comes
+    through tanh of the forward's result)."""
+    ct = jax.random.normal(jax.random.PRNGKey(seed), q.shape)
+    return jax.grad(lambda *x: jnp.sum(jnp.tanh(f(*x)) * ct),
+                    (0, 1, 2))(q, k, v)
+
+
 @pytest.mark.parametrize("window", [W, 0], ids=["sliding", "global"])
-def test_gradient_past_one_block_is_the_oracles(window):
-    q, k, v = qkv(37)
-    ct = jax.random.normal(jax.random.PRNGKey(5), q.shape)
-    oracle = blockwise(window, 8)
-
-    def loss(f):
-        return lambda q, k, v: jnp.sum(jnp.tanh(f(q, k, v)) * ct)
-
-    got = jax.grad(loss(lambda *x: kernel(*x, window, 16, 8, oracle)),
-                   (0, 1, 2))(q, k, v)
-    want = jax.grad(loss(oracle), (0, 1, 2))(q, k, v)
+@pytest.mark.parametrize("s", [11, 37, 64, 70],
+                         ids=["one_block", "ragged", "whole_tiles",
+                              "past_window_and_tile"])
+@pytest.mark.parametrize("tq, tk", [(8, 8), (16, 8), (8, 32), (32, 16)])
+def test_backward_kernels_equal_the_dense_forms_vjp(window, s, tq, tk):
+    """``gqa_attn_bwd_dq`` / ``gqa_attn_bwd_dkv`` interpreted against
+    ``jax.vjp`` of ``reach_mask``'s dense form: 11 positions are one
+    tile and fewer than the window, 37 whole tiles of no shape here (a
+    ragged tail in queries and keys), 64 whole tiles of every shape, 70
+    past window + tile for every tile."""
+    q, k, v = qkv(s)
+    got = grads(lambda *x: kernel(*x, window, tq, tk), q, k, v)
+    want = grads(lambda *x: dense(*x, window), q, k, v)
     for g, w in zip(got, want):
-        # the cotangent comes through tanh of the kernel's forward
+        assert np.all(np.isfinite(g))
         np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+@pytest.mark.parametrize("window", [W, 0], ids=["sliding", "global"])
+def test_backward_sums_dk_dv_over_seven_query_heads(window):
+    """28 / 4 heads: a key/value head's gradient is the sum over the
+    ``rep`` = 7 query heads that share it."""
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(keys[0], (1, 45, 2, 7, HD))
+    k = jax.random.normal(keys[1], (1, 45, 2, HD))
+    v = jax.random.normal(keys[2], (1, 45, 2, HD))
+    got = grads(lambda *x: kernel(*x, window, 16, 8), q, k, v)
+    want = grads(lambda *x: dense(*x, window), q, k, v)
+    # ... and it is NOT one head's share
+    one = grads(lambda q, k, v: dense(q, k, v, window)[:, :, :, :1],
+                q[:, :, :, :1], k, v, seed=5)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-5)
+    assert float(jnp.max(jnp.abs(got[1] - one[1]))) > 1e-2
+
+
+@pytest.mark.parametrize("window", [40, 0], ids=["sliding", "global"])
+def test_backward_at_whole_lane_tiles(window):
+    """Heads of 128 against tiles of 128: the shapes of the chip, where
+    the forward's log-sum-exp leaves its lane-wide statistic as a row."""
+    q, k, v = qkv(300, seed=3, hd=128)
+    got = grads(lambda *x: kernel(*x, window, 128, 128), q, k, v)
+    want = grads(lambda *x: dense(*x, window), q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [W, 0], ids=["sliding", "global"])
+def test_gradient_past_the_window_is_the_loops(window):
+    """``jax.grad`` through a sequence past the window agrees between
+    the kernels and the blockwise loop they replace."""
+    q, k, v = qkv(70)
+    got = grads(lambda *x: kernel(*x, window, 16, 8), q, k, v)
+    want = grads(blockwise(window, 8), q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+@pytest.mark.parametrize("window", [W, 0], ids=["sliding", "global"])
+def test_a_padded_tail_changes_no_valid_rows_gradient(window):
+    """Whatever the positions past a stream's valid length hold, with a
+    zero cotangent there the gradients before them are the same."""
+    s, valid = 61, 43
+    q, k, v = qkv(s)
+    tail = jnp.arange(s)[None, :, None, None] >= valid
+    other = [jnp.where(tail[..., None] if x.ndim == 5 else tail, 7.0 * y, x)
+             for x, y in zip((q, k, v), qkv(s, seed=9))]
+    ct = jnp.where(tail[..., None], 0.0,
+                   jax.random.normal(jax.random.PRNGKey(5), q.shape))
+
+    def grad_of(q, k, v):
+        return jax.grad(lambda *x: jnp.sum(
+            kernel(*x, window, 16, 8) * ct), (0, 1, 2))(q, k, v)
+
+    for g, w in zip(grad_of(*other), grad_of(q, k, v)):
+        np.testing.assert_allclose(g[:, :valid], w[:, :valid], atol=1e-5)
+        assert np.all(np.isfinite(g))
+
+
+@pytest.mark.parametrize("s, window, tq, tk", [
+    (6784, 4096, 256, 512), (6784, 0, 256, 512), (70, W, 16, 8),
+    (70, 0, 8, 32), (37, W, 32, 16)])
+def test_reach_of_keys_is_reach_transposed(s, window, tq, tk):
+    """The query tiles a key tile's grid visits are those that hold a
+    query which reaches one of its keys, counted from the mask itself;
+    over all key tiles they are the forward's tile pairs."""
+    first, last = attn_pallas.reach_of_keys(s, window, tq, tk)
+    seen = np.asarray(lfm2.reach_mask(0, s, 0, s, window))
+    pairs = 0
+    for n, j0 in enumerate(range(0, s, tk)):
+        hit = [i0 // tq for i0 in range(0, s, tq)
+               if seen[i0:i0 + tq, j0:j0 + tk].any()]
+        assert (first[n], last[n]) == (hit[0], hit[-1])
+        assert hit == list(range(hit[0], hit[-1] + 1))
+        pairs += len(hit)
+    assert pairs == attn_pallas.tile_counts(s, window, tq, tk)[
+        "key_tiles_in_reach"]
 
 
 @pytest.mark.parametrize("s, window, tq, tk, counts", [

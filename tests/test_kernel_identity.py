@@ -40,6 +40,9 @@ def scan(kernel, variant, reverse, t, b, h, gates=3):
 
 # tools/aot_kernels.kernel_cases() at b=8, t=400: the five that
 # test_tpu_compile.py compiles, then the other routed shapes.
+_ST_ATTN = {"b": "4", "s": "6784", "kv": "4", "rep": "7", "head": "128",
+            "window": "4096", "q_tile": "256", "k_tile": "512"}
+
 CASES = {
     "gru_h1760": [scan("gru_scan_fwd", "pinned", 0, 400, 8, 1760),
                   scan("gru_scan_bwd", "pinned", 0, 400, 8, 1760)],
@@ -113,6 +116,17 @@ CASES = {
         {"kernel": "gqa_attn_decode", "b": "16", "rows": "4096",
          "kv": "8", "rep": "6", "head": "128", "window": "4096",
          "row_tile": "512", "row_tiles": "8"}],
+    # smallthinker_21b_a3b's attention in training, a sliding layer: 4
+    # recordings of 6,784 positions, forward (under ``jax.vjp``: with its
+    # log-sum-exp) and the backward pair; 171 (query tile, key tile)
+    # pairs a (row, key/value head), the same seen from the keys
+    "gqa_attn_train_smallthinker_window": [
+        {"kernel": "gqa_attn_fwd", **_ST_ATTN, "key_tiles": "171",
+         "key_tiles_in_reach": "171", "key_tiles_masked": "38"},
+        {"kernel": "gqa_attn_bwd_dkv", **_ST_ATTN, "key_tiles": "171",
+         "key_tiles_in_reach": "171"},
+        {"kernel": "gqa_attn_bwd_dq", **_ST_ATTN, "key_tiles": "171",
+         "key_tiles_in_reach": "171", "key_tiles_masked": "38"}],
 }
 
 

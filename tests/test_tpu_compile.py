@@ -91,6 +91,21 @@ def v5e_chip():
     # over; 48 x 4,096 float32 scores a tile
     ("gqa_attn_decode_trinity_window", ["gqa_attn_decode"]),
     ("gqa_attn_decode_trinity_global", ["gqa_attn_decode"]),
+    # smallthinker_21b_a3b's attention in training, 4 recordings of
+    # 6,784 positions (26 query tiles of 256 and one of 128, 13 key
+    # tiles of 512 and one of 128), 28 / 4 heads of 128, forward with
+    # its log-sum-exp (a lane-wide statistic transposed to a row) and
+    # backward: transposed scores 512 x 256 float32 a head, dq summed
+    # over key tiles and dk / dv over query tiles and 7 query heads in
+    # VMEM, under a 64 MiB scoped-VMEM limit; a sliding layer and the
+    # global one
+    ("gqa_attn_train_smallthinker_window",
+     ["gqa_attn_fwd", "gqa_attn_bwd_dq", "gqa_attn_bwd_dkv"]),
+    ("gqa_attn_train_smallthinker_global",
+     ["gqa_attn_fwd", "gqa_attn_bwd_dq", "gqa_attn_bwd_dkv"]),
+    # ... and its grouped products over 16 held experts, 61,440 rows
+    ("moe_gmm_smallthinker_w13", ["moe_gmm", "moe_gmm", "moe_tgmm"]),
+    ("moe_gmm_smallthinker_w2", ["moe_gmm", "moe_gmm", "moe_tgmm"]),
 ])
 def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     """Mosaic accepts the kernel, and the compiled instruction still

@@ -251,7 +251,29 @@ def kernel_cases():
                 S((2, 5250, 8, 128), jnp.bfloat16),
                 S((2, 5250, 8, 128), jnp.bfloat16))
         return lambda: (lambda q, k, v: attn_pallas.gqa_attention(
-            q, k, v, window, None), args)
+            q, k, v, window), args)
+
+    def attn_train_case(window):
+        """smallthinker_21b_a3b's grouped-query attention over a step's
+        four recordings in one layer, forward and backward:
+        ``gqa_attn_fwd`` with its log-sum-exp, ``gqa_attn_bwd_dq`` and
+        ``gqa_attn_bwd_dkv``, at the module's tiles (6,784 positions:
+        26 query tiles of 256 and one of 128, 13 key tiles of 512 and
+        one of 128; 28 / 4 heads of 128)."""
+        from deepspeech_tpu.ops import attn_pallas
+
+        args = (S((4, 6784, 4, 7, 128), jnp.bfloat16),
+                S((4, 6784, 4, 128), jnp.bfloat16),
+                S((4, 6784, 4, 128), jnp.bfloat16))
+
+        def f():
+            def train(q, k, v):
+                out, vjp = jax.vjp(
+                    lambda *x: attn_pallas.gqa_attention(*x, window),
+                    q, k, v)
+                return out, vjp(jnp.ones_like(out))
+            return train, args
+        return f
 
     def attn_decode_case(rows, window):
         """trinity_large's grouped-query attention in one decode step
@@ -336,6 +358,14 @@ def kernel_cases():
     # hanging over the cache's end by 384 rows)
     cases["gqa_attn_decode_trinity_window"] = attn_decode_case(4096, 4096)
     cases["gqa_attn_decode_trinity_global"] = attn_decode_case(6784, 0)
+    # smallthinker_21b_a3b.train_long_7min: a sliding layer and the
+    # global one, forward and backward; and the 16 held experts' gate+up
+    # (2560 -> 2 x 768) and down (768 -> 2560) over 61,440 static rows,
+    # forward, transposed and ``moe_tgmm``.
+    cases["gqa_attn_train_smallthinker_window"] = attn_train_case(4096)
+    cases["gqa_attn_train_smallthinker_global"] = attn_train_case(0)
+    cases["moe_gmm_smallthinker_w13"] = moe_case(2560, 1536, 61440, 16)
+    cases["moe_gmm_smallthinker_w2"] = moe_case(768, 2560, 61440, 16)
     cases["ctc_aishell"] = ctc_case(4336, 400, 60)
     cases["ctc_en"] = ctc_case(29, 400, 160)
     # The weak-#1 shape: AISHELL-width device beam search, both merge

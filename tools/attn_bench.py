@@ -1,6 +1,8 @@
 """``gqa_attn_fwd`` on the chip at a cell's shapes: time and error of the
 kernel against the blockwise loop it replaces, a tile size at a time,
-then one whole ``Attention`` layer (projections, norms, gate and ``o``
+(with ``--grad`` also the kernel's forward + backward pair,
+``gqa_attn_bwd_dq`` and ``gqa_attn_bwd_dkv``, under ``jax.grad``), then
+one whole ``Attention`` layer (projections, norms, gate and ``o``
 included) with the kernel and with the loop, and what the cell's
 two-forms check would read with each.
 
@@ -170,6 +172,11 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--no-kernel", action="store_true",
                     help="skip the kernel alone")
+    ap.add_argument("--preset", default="trinity_large",
+                    help="whose heads, window and layer the shapes are")
+    ap.add_argument("--grad", action="store_true",
+                    help="also time the kernel's forward + backward "
+                         "(gqa_attn_bwd_dq, gqa_attn_bwd_dkv) a tile size")
     ap.add_argument("--no-layer", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
@@ -187,7 +194,7 @@ def main() -> None:
     from deepspeech_tpu.ops import attn_pallas
 
     args.rows = args.rows or (16 if args.mode == "decode" else 2)
-    m = get_config("trinity_large").model
+    m = get_config(args.preset).model
     block, s = 512, args.positions
     if args.rehearse:
         m = dataclasses.replace(m, lfm_hidden=64, lfm_heads=4,
@@ -254,10 +261,11 @@ def main() -> None:
         print(json.dumps(line), flush=True)
         for tile in args.tiles:
             tq, tk = (int(x) for x in tile.split("x"))
-            fn = jax.jit(lambda q, k, v, tq=tq, tk=tk, window=window:
-                         attn_pallas.gqa_attention(
-                             q, k, v, window, None, tq, tk,
-                             args.rehearse))
+            def kernel(q, k, v, tq=tq, tk=tk, window=window):
+                return attn_pallas.gqa_attention(q, k, v, window, tq, tk,
+                                                 args.rehearse)
+
+            fn = jax.jit(kernel)
             t = time.perf_counter()
             got = jax.block_until_ready(fn(q, k, v))
             first = time.perf_counter() - t
@@ -269,6 +277,20 @@ def main() -> None:
                     **attn_pallas.tile_counts(s, window, tq, tk)}
             if peak:
                 line["peak_pct"] = 100 * need / (1e-3 * ms * peak)
+            print(json.dumps(line), flush=True)
+            if not args.grad:
+                continue
+            # forward (with its log-sum-exp) + gqa_attn_bwd_dq + _dkv;
+            # the backward pair NEEDS twice the forward's operations
+            both = jax.jit(jax.grad(lambda *x: jnp.sum(
+                kernel(*x).astype(jnp.float32)), (0, 1, 2)))
+            jax.block_until_ready(both(q, k, v))
+            line = {"what": "gqa_attn_fwd+bwd", "window": window,
+                    "q_tile": tq, "k_tile": tk,
+                    "ms": timed(both, q, k, v)}
+            if peak:
+                line["peak_pct"] = 100 * 3 * need / (
+                    1e-3 * line["ms"] * peak)
             print(json.dumps(line), flush=True)
 
     if args.no_layer:

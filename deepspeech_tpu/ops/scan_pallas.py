@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from .kernel_id import kernel_call, scan_facts
 
 # The budgets, stated once. What a resident matrix may take of Mosaic's
@@ -100,14 +101,24 @@ def _fits(hidden: int, weight_bytes: int, gates: int) -> bool:
     return gates * hidden * hidden * weight_bytes <= VMEM_WEIGHT_BUDGET
 
 
+def _bias_grad_rows(rows: int) -> int:
+    """Sublanes of the backward call's bias-gradient accumulator: 8
+    where the step's ``dgates [rows, G*H]`` is whole sublane tiles (a
+    step then adds ``rows / 8`` vectors a lane tile and reduces nothing
+    across sublanes; the last 8 -> 1 is the VJP's), else 1 (a row sum
+    a step)."""
+    return 1 if rows % 8 else 8
+
+
 def _pinned_vmem_limit(rows: int, hidden: int, cell: _Cell, dot_bytes: int,
                        xproj_bytes: int, backward: bool) -> Optional[int]:
     """The scoped-VMEM limit a copy-once call asks for, or None when it
     would reach :data:`PINNED_VMEM_CAP`. What the call holds: ONE copy
     of the matrix (its rows as wide as VMEM's lanes make them), its
-    per-step rows twice (the pipeline double-buffers them) and its
-    float32 scratches, with the step's gate value ``[b, lanes]`` (live
-    whole, since one matmul makes it); a quarter on top for the gate
+    per-step rows twice (the pipeline double-buffers them; backward
+    the bias gradient's accumulator with them) and its float32
+    scratches, with the step's gate value ``[b, lanes]`` (live whole,
+    since one matmul makes it); a quarter on top for the gate
     math's other temporaries, rounded up to 4 MiB and never under
     Mosaic's default of 16 MiB. ds2_full (H=1760, bf16, 18.6 MB of
     weights) at b=32 / 64: forward 28 / 28 MiB, backward 32 / 36 MiB."""
@@ -118,11 +129,13 @@ def _pinned_vmem_limit(rows: int, hidden: int, cell: _Cell, dot_bytes: int,
     # the lanes takes a whole lane tile
     ins = [(wide, xproj_bytes), (1, 4)]
     outs = [hidden]
+    whole = 1  # float32 blocks of one sublane tile that stay: the bias
     if backward:
         ins += [(hidden, 4)] * (cell.states + 1)
         outs = [wide, wide]
+        whole = 2  # and the bias gradient's accumulator, [1 or 8, wide]
     row_bytes = (sum(rows * max(w, 128) * n for w, n in ins)
-                 + 8 * lanes * 4 + sum(rows * w * 4 for w in outs))
+                 + whole * 8 * lanes * 4 + sum(rows * w * 4 for w in outs))
     scratch_bytes = rows * 4 * (cell.states * hidden + lanes)
     need = hidden * lanes * dot_bytes + 2 * row_bytes + scratch_bytes
     step = 4 * 1024 * 1024
@@ -531,13 +544,19 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
     """One reverse-time BPTT grid step (flash-style gate recompute):
     carries the states' gradients across steps and recomputes the gates
     from (previous states, xproj, W) rather than storing them. Streams
-    per-step ``dxp`` and ``dgates`` out; dW/db are formed outside as
-    one contraction over the streamed dgates (a single large MXU
+    per-step ``dxp`` and ``dgates`` out; dW is formed outside as one
+    contraction over the streamed dgates (a single large MXU
     contraction beats a [H, G*H] VMEM accumulator, which would not
-    leave room for W).
+    leave room for W). The bias gradient is this call's: ``db``
+    ``[8 or 1, G*H]`` (:func:`_bias_grad_rows`) stays in VMEM over the
+    grid and takes every step's ``dgates`` while they are there, so no
+    second pass reads the streamed ``[T, b, G*H]`` for its column sums
+    (14 such XLA reductions over 574 MB each were 10.8 ms of
+    ds2_full's step, and the sums add 0.005 ms to this call's 8.17:
+    PERF.md section 6, PR 47).
 
     refs: xproj row, mask row, each state's previous row, dy row, W,
-    bias, dxp row, dgates row, the state gradients' scratches,
+    bias, dxp row, dgates row, db, the state gradients' scratches,
     [streamed: dh_acc, gates_buf, dg_prev], [pinned: matrix scratch,
     semaphore].
 
@@ -554,8 +573,8 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
     last block."""
     n = _CELLS[cell.name].states
     (xp_ref, mask_ref), prev_refs = refs[:2], refs[2:2 + n]
-    dy_ref, w_ref, b_ref, dxp_ref, dgates_ref = refs[2 + n:7 + n]
-    scratch = refs[7 + n:]
+    dy_ref, w_ref, b_ref, dxp_ref, dgates_ref, db_ref = refs[2 + n:8 + n]
+    scratch = refs[8 + n:]
     if variant == "pinned":
         *scratch, w_scr, sem = scratch
         _copy_weights_once(w_ref, w_scr, sem)
@@ -571,7 +590,7 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
 
     @pl.when(start)
     def _():
-        for d in dstate_refs:
+        for d in (*dstate_refs, db_ref):
             d[:] = jnp.zeros_like(d)
         if blocked:
             dg_prev[:] = jnp.zeros_like(dg_prev)
@@ -593,6 +612,11 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
             (dfirst(),) + tuple(d[:] for d in dstate_refs[1:]), dy_ref[0])
         dxp_ref[0] = dxp
         dgates_ref[0] = dgates
+        if db_ref.shape[0] == 1:
+            db_ref[:] += jnp.sum(dgates, axis=0, keepdims=True)
+        else:  # whole sublane tiles: vector adds, nothing across them
+            db_ref[:] += functools.reduce(jnp.add, [
+                dgates[i:i + 8] for i in range(0, dgates.shape[0], 8)])
         return dgates, dprev
 
     if not blocked:
@@ -671,7 +695,10 @@ def scan_forward(cell: ScanCell, xproj, mask, w, b_h, *, reverse=False,
 
 def scan_vjp(cell: ScanCell):
     """The ``custom_vjp`` pair of a gated cell's
-    ``f(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype)``."""
+    ``f(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype)``. While
+    jax traces the backward call it is recorded as the gauge
+    ``scan_bias_grad{kernel, variant, form}``, ``form`` the
+    accumulator's (``rows8`` / ``rows1``)."""
     gates, n = _CELLS[cell.name].gates, _CELLS[cell.name].states
 
     def fwd(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype):
@@ -693,12 +720,17 @@ def scan_vjp(cell: ScanCell):
             dot_bytes=jnp.dtype(dot).itemsize,
             xproj_bytes=xp_t.dtype.itemsize, backward=True)
         streamed = route.variant == "blocked"
-        dxp_t, dgates_t = scan_call(
+        db_rows = _bias_grad_rows(b)
+        obs.registry().gauge("scan_bias_grad", 1, labels={
+            "kernel": route.kernel, "variant": route.variant,
+            "form": f"rows{db_rows}"})
+        dxp_t, dgates_t, db = scan_call(
             functools.partial(_bwd_step, cell, route.variant), route,
             reverse=reverse, hidden=h, gates=gates,
             rows=([(xp_t, at_bptt), (mask_t, at_bptt)]
                   + [(s, at_prev) for s in seqs] + [(dy_t, at_bptt)]),
             weights=[w, bh2], outs=[(gates * h, jnp.float32, at_bptt)] * 2,
+            whole_outs=[(db_rows, gates * h)],
             scratch=lambda cols: [h] * n + (
                 [h, cols, cols] if streamed else []),
             interpret=interpret)
@@ -711,9 +743,9 @@ def scan_vjp(cell: ScanCell):
         # contraction stays ~2e-3, which is the recurrence's own bf16
         # noise and not the contraction's (recurrent_dw).
         dw_h = recurrent_dw(prev_sequence(seqs[0], reverse), dgates_t, dot)
-        db_h = jnp.sum(dgates_t, axis=(0, 1))
         dxp = jnp.moveaxis(dxp_t, 0, 1)  # [B, T, G*H]
         return (dxp, jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1),
-                dw_h.astype(w_h.dtype), db_h.astype(b_h.dtype))
+                dw_h.astype(w_h.dtype),
+                jnp.sum(db, axis=0).astype(b_h.dtype))
 
     return fwd, bwd

@@ -823,6 +823,74 @@ def test_lstm_pallas_grads_match_scan(monkeypatch, blocked, reverse):
                                    rtol=1e-4, atol=1e-4, err_msg=name)
 
 
+@pytest.mark.parametrize("cell, build, b, form", [
+    ("gru", "resident", 8, "rows8"), ("gru", "resident", 32, "rows8"),
+    ("gru", "resident", 5, "rows1"),
+    ("gru", "pinned", 8, "rows8"), ("gru", "pinned", 32, "rows8"),
+    ("gru", "pinned", 5, "rows1"),
+    ("gru", "blocked", 8, "rows8"), ("gru", "blocked", 32, "rows8"),
+    ("gru", "blocked", 5, "rows1"),
+    ("lstm", "resident", 8, "rows8"), ("lstm", "resident", 5, "rows1"),
+    ("lstm", "blocked", 32, "rows8"),
+])
+@pytest.mark.parametrize("reverse", [False, True])
+def test_scan_bwd_sums_its_own_bias_gradient(monkeypatch, cell, build, b,
+                                             form, reverse):
+    """The backward scan kernel accumulates ``db_h`` in VMEM over its
+    grid: whole sublane tiles of rows into ``[8, G*H]`` (``rows8``),
+    other row counts into ``[1, G*H]`` (``rows1``), from ``b`` alone.
+    The VJP holds no ``reduce_sum`` over a ``[T, B, G*H]`` array
+    outside the kernel (XLA ran 14 of them over 574 MB each a step of
+    ds2_full: PERF.md section 6, PR 47); ``db_h`` is the float64
+    column sum of the kernel's own streamed ``dgates_t`` to 1e-6 of
+    its largest value at ragged masks, in every build; and tracing
+    leaves ``scan_bias_grad{kernel, variant, form}`` in the registry
+    with the form that ran."""
+    from deepspeech_tpu import obs
+    from deepspeech_tpu.ops.lstm_pallas import lstm_scan_pallas
+
+    if build != "resident":
+        monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
+    if build == "blocked":
+        monkeypatch.setattr(scan_pallas, "PINNED_VMEM_CAP", 0)
+    t, h = 9, 144  # G*H = 432 / 576: a ragged last lane tile, 1 / 2 blocks
+    gates, scan, rand = {"gru": (3, gru_scan_pallas, _rand_gru),
+                         "lstm": (4, lstm_scan_pallas, _rand_lstm)}[cell]
+    xproj, mask, w_h, b_h = rand(np.random.default_rng(47 + b), b, t, h)
+    dy = jnp.asarray(np.random.default_rng(1).normal(size=(b, t, h)),
+                     jnp.float32)
+    seen = []
+    dw = scan_pallas.recurrent_dw
+    monkeypatch.setattr(
+        scan_pallas, "recurrent_dw",
+        lambda h_prev, dgates, dot: seen.append(dgates) or dw(
+            h_prev, dgates, dot))
+
+    def vjp(xp, wh, bh):
+        return jax.vjp(lambda *a: scan(a[0], mask, *a[1:], reverse, True),
+                       xp, wh, bh)[1](dy)
+
+    obs.registry().reset()
+    _, _, db_h = vjp(xproj, w_h, b_h)  # eager: dgates_t is concrete
+    dgates_t, = seen
+    assert dgates_t.shape == (t, b, gates * h)
+    want = np.asarray(dgates_t, np.float64).sum(axis=(0, 1))
+    np.testing.assert_allclose(np.asarray(db_h, np.float64), want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+    assert obs.registry().snapshot()["gauges"] == {
+        f'scan_bias_grad{{form="{form}",kernel="{cell}_scan_bwd",'
+        f'variant="{build}"}}': 1}
+
+    eqns = _eqns(vjp, xproj, w_h, b_h)
+    bwd, = [e for e in eqns if e.primitive.name == "pallas_call"
+            and str(e.params["metadata"]["kernel"]) == f"{cell}_scan_bwd"]
+    assert [v.aval.shape for v in bwd.outvars][2] == (
+        int(form[4:]), gates * h)
+    summed = [e for e in eqns if e.primitive.name == "reduce_sum"
+              and e.invars[0].aval.shape == (t, b, gates * h)]
+    assert not summed, summed
+
+
 def test_lstm_pallas_respects_mask():
     from deepspeech_tpu.ops.lstm_pallas import lstm_scan_pallas
 

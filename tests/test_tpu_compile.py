@@ -184,6 +184,29 @@ def test_recurrent_dw_passes_follow_the_dot_type(v5e_chip, case, passes):
     assert f"operand_precision={{{passes},{passes}}}" in dws[0]
 
 
+@pytest.mark.parametrize("case, rows", [
+    ("gru_h1760_b32", 32), ("gru_h1760_b64", 64)])
+def test_backward_scan_sums_its_bias_gradient(v5e_chip, case, rows):
+    """ds2_full's scan VJP as the TPU compiler is handed it, at the
+    cells' call (b=32) and at twice the rows: the only reduction into
+    ``f32[5280]`` is the 8 -> 1 sum of the backward kernel's third
+    result, the ``[8, 5280]`` accumulator it kept in VMEM. Summed by
+    XLA, ``db_h`` was a pass of its own over the kernel's
+    ``f32[850, b, 5280]`` ``dgates`` (574 MB at b=32), 14 times a step
+    (PERF.md section 6, PR 47); the whole step's program says the same
+    (``tools/aot_tpu.py --preset ds2_full --batch 32 --frames 1700
+    --hlo-out F``: two minutes, so not run here)."""
+    from aot_kernels import compile_case, kernel_cases
+
+    text = compile_case(kernel_cases()[case], v5e_chip).as_text()
+    assert re.search(
+        rf"\(f32\[850,{rows},5280\]\S*, f32\[850,{rows},5280\]\S*, "
+        r"f32\[8,5280\]\S*\) custom-call\(", text)
+    sums = re.findall(
+        r"= f32\[5280\]\S* reduce\([^\n]*dimensions=\{([\d,]+)\}", text)
+    assert sums == ["0"], sums
+
+
 def test_ctc_vjp_folds_gamma_without_a_scatter(v5e_chip):
     """CTC backward sums gamma from the extended labels into the
     vocabulary with one f32-exact contraction. A TPU runs a scatter-add

@@ -581,7 +581,7 @@ def dw_h_precision(interpret: bool) -> dict:
     rows = {"program": apart(program)}
     for name in ("HIGHEST", "HIGH", "DEFAULT"):
         contract = jax.jit(functools.partial(
-            jnp.einsum, "tbh,tbg->hg",
+            jnp.einsum, "...h,...g->hg",
             precision=getattr(jax.lax.Precision, name)))
         rows[name.lower()] = apart(contract(h_prev, dgates))  # compiled
         t0 = time.perf_counter()

@@ -121,7 +121,7 @@ def _pinned_vmem_limit(rows: int, hidden: int, cell: _Cell, dot_bytes: int,
     since one matmul makes it); a quarter on top for the gate
     math's other temporaries, rounded up to 4 MiB and never under
     Mosaic's default of 16 MiB. ds2_full (H=1760, bf16, 18.6 MB of
-    weights) at b=32 / 64: forward 28 / 28 MiB, backward 32 / 36 MiB."""
+    weights) at b=32 / 64: forward 28 / 28 MiB, backward 32 / 40 MiB."""
     wide = cell.gates * hidden
     lanes = pl.cdiv(wide, 128) * 128
     # per-step operands (xproj, mask; backward: each state's previous
@@ -132,7 +132,7 @@ def _pinned_vmem_limit(rows: int, hidden: int, cell: _Cell, dot_bytes: int,
     whole = 1  # float32 blocks of one sublane tile that stay: the bias
     if backward:
         ins += [(hidden, 4)] * (cell.states + 1)
-        outs = [wide, wide]
+        outs = [wide, wide, hidden]  # dxp, dgates and the previous state
         whole = 2  # and the bias gradient's accumulator, [1 or 8, wide]
     row_bytes = (sum(rows * max(w, 128) * n for w, n in ins)
                  + whole * 8 * lanes * 4 + sum(rows * w * 4 for w in outs))
@@ -318,12 +318,12 @@ def _pad_cols(x, cols: int):
 
 def recurrent_dw(h_prev, dgates, dot):
     """``dW_h = sum over T*B of h_prev^T dgates``: the recurrent weight
-    gradient as one MXU contraction of two float32 ``[T, B, .]``
-    sequences outside the time loop, at the precision the scan's dot
-    type states. The sum is cancellation-heavy (T*B = 27,200 products
-    an entry at ds2_full's cell), so it never rounds an operand to
-    8 bits (``DEFAULT``, one bf16 pass: 3.6e-2 off the float32 truth
-    at toy size, tests/test_pallas.py
+    gradient as one MXU contraction of two float32 ``[T, B, .]`` (or
+    flat ``[T * B, .]``) sequences outside the time loop, at the
+    precision the scan's dot type states. The sum is cancellation-heavy
+    (T*B = 27,200 products an entry at ds2_full's cell), so it never
+    rounds an operand to 8 bits (``DEFAULT``, one bf16 pass: 3.6e-2 off
+    the float32 truth at toy size, tests/test_pallas.py
     test_gru_bf16_dw_closer_to_truth_than_oracle).
 
     float32 dots: ``HIGHEST``, six bf16 passes, 24 bits of each operand
@@ -338,7 +338,7 @@ def recurrent_dw(h_prev, dgates, dot):
     time: 14 such contractions were 41% of ds2_full's step."""
     precision = (jax.lax.Precision.HIGH if dot == jnp.bfloat16
                  else jax.lax.Precision.HIGHEST)
-    return jnp.einsum("tbh,tbg->hg", h_prev, dgates, precision=precision)
+    return jnp.einsum("...h,...g->hg", h_prev, dgates, precision=precision)
 
 
 def scan_call(body, route: ScanRoute, *, reverse, hidden: int, gates: int,
@@ -355,7 +355,9 @@ def scan_call(body, route: ScanRoute, *, reverse, hidden: int, gates: int,
                     bias) after it
     ``carry``       ``[b, X]`` arrays that enter whole
     ``outs``        per-step results ``(width, dtype, index map)``, each
-                    ``[T, b, width]``
+                    ``[T, b, width]``; with a fourth field True, flat:
+                    ``[T * b, width]``, a step's rows one block of it
+                    (``b`` whole sublane tiles)
     ``whole_outs``  shapes of float32 results written whole (the final
                     carry, gradients accumulated over the grid)
     ``scratch``     ``cols -> widths`` of the float32 ``[b, n]``
@@ -397,6 +399,9 @@ def scan_call(body, route: ScanRoute, *, reverse, hidden: int, gates: int,
         params["vmem_limit_bytes"] = route.vmem_limit
     step = lambda width, imap: pl.BlockSpec(
         (1, b, width), on_grid(imap), memory_space=pltpu.VMEM)
+    flat_step = lambda width, imap: pl.BlockSpec(
+        (b, width), on_grid(lambda t: imap(t)[:2]), memory_space=pltpu.VMEM)
+    outs = [(*out, False)[:4] for out in outs]
     return kernel_call(
         body, kernel=route.kernel,
         facts={**scan_facts(route.variant, reverse, t_max, b, hidden, gates),
@@ -404,10 +409,12 @@ def scan_call(body, route: ScanRoute, *, reverse, hidden: int, gates: int,
         grid=grid,
         in_specs=([step(x.shape[2], imap) for x, imap in rows] + w_specs
                   + [whole(x.shape) for x in carry]),
-        out_specs=([step(width, imap) for width, _, imap in outs]
+        out_specs=([(flat_step if flat else step)(width, imap)
+                    for width, _, imap, flat in outs]
                    + [whole(shape) for shape in whole_outs]),
-        out_shape=([jax.ShapeDtypeStruct((t_max, b, width), dtype)
-                    for width, dtype, _ in outs]
+        out_shape=([jax.ShapeDtypeStruct(
+            (t_max * b, width) if flat else (t_max, b, width), dtype)
+            for width, dtype, _, flat in outs]
                    + [jax.ShapeDtypeStruct(shape, jnp.float32)
                       for shape in whole_outs]),
         scratch_shapes=[pltpu.VMEM((b, n), jnp.float32)
@@ -553,12 +560,18 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
     second pass reads the streamed ``[T, b, G*H]`` for its column sums
     (14 such XLA reductions over 574 MB each were 10.8 ms of
     ds2_full's step, and the sums add 0.005 ms to this call's 8.17:
-    PERF.md section 6, PR 47).
+    PERF.md section 6, PR 47). So is dW's other operand: the first
+    state's previous row, which the step fetches for the gate
+    recompute with the zero state at the scan's start in it, goes out
+    again as ``h_prev``'s row, so the VJP builds no shifted copy of the
+    state sequence (a slice and a layout turn of 191 MB, 14 times a
+    step of ds2_full: PERF.md section 6, PR 48).
 
     refs: xproj row, mask row, each state's previous row, dy row, W,
-    bias, dxp row, dgates row, db, the state gradients' scratches,
-    [streamed: dh_acc, gates_buf, dg_prev], [pinned: matrix scratch,
-    semaphore].
+    bias, dxp row, dgates row, h_prev row (a ``(1, b, H)`` block of
+    ``[T, b, H]`` or the ``(b, H)`` block of a flat result), db, the
+    state gradients' scratches, [streamed: dh_acc, gates_buf,
+    dg_prev], [pinned: matrix scratch, semaphore].
 
     Resident and copy-once: the step's ``dgates @ W^T`` goes into the
     carried gradient straight away (the matrix is whole in VMEM; read
@@ -573,8 +586,9 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
     last block."""
     n = _CELLS[cell.name].states
     (xp_ref, mask_ref), prev_refs = refs[:2], refs[2:2 + n]
-    dy_ref, w_ref, b_ref, dxp_ref, dgates_ref, db_ref = refs[2 + n:8 + n]
-    scratch = refs[8 + n:]
+    (dy_ref, w_ref, b_ref, dxp_ref, dgates_ref, hprev_ref,
+     db_ref) = refs[2 + n:9 + n]
+    scratch = refs[9 + n:]
     if variant == "pinned":
         *scratch, w_scr, sem = scratch
         _copy_weights_once(w_ref, w_scr, sem)
@@ -612,6 +626,10 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
             (dfirst(),) + tuple(d[:] for d in dstate_refs[1:]), dy_ref[0])
         dxp_ref[0] = dxp
         dgates_ref[0] = dgates
+        if len(hprev_ref.shape) == 3:  # a row of [T, b, H]
+            hprev_ref[0] = first
+        else:  # b rows of the flat [T * b, H]
+            hprev_ref[:] = first
         if db_ref.shape[0] == 1:
             db_ref[:] += jnp.sum(dgates, axis=0, keepdims=True)
         else:  # whole sublane tiles: vector adds, nothing across them
@@ -695,10 +713,17 @@ def scan_forward(cell: ScanCell, xproj, mask, w, b_h, *, reverse=False,
 
 def scan_vjp(cell: ScanCell):
     """The ``custom_vjp`` pair of a gated cell's
-    ``f(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype)``. While
-    jax traces the backward call it is recorded as the gauge
-    ``scan_bias_grad{kernel, variant, form}``, ``form`` the
-    accumulator's (``rows8`` / ``rows1``)."""
+    ``f(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype)``. The
+    backward call hands back what it holds in VMEM beside ``dxp``: both
+    operands of ``dW_h`` (``dgates`` and ``h_prev``, the first state
+    one scan step back from the zero state: :func:`prev_sequence`'s
+    rows bit for bit, as ``[T * b, H]`` where the contraction then
+    takes both as they lie) and the bias gradient's sums. While jax
+    traces it, it is recorded as the gauges ``scan_bias_grad{kernel,
+    variant, form}``, ``form`` the accumulator's (``rows8`` /
+    ``rows1``), and ``scan_prev_state{kernel, variant,
+    source="kernel", rows}``, ``rows`` how ``h_prev`` is laid out
+    (``flat`` / ``stepped``)."""
     gates, n = _CELLS[cell.name].gates, _CELLS[cell.name].states
 
     def fwd(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype):
@@ -721,15 +746,31 @@ def scan_vjp(cell: ScanCell):
             xproj_bytes=xp_t.dtype.itemsize, backward=True)
         streamed = route.variant == "blocked"
         db_rows = _bias_grad_rows(b)
+        # Whole sublane tiles of rows: h_prev leaves the kernel flat,
+        # [T * b, H], a step's rows one block of it, and XLA contracts
+        # it with dgates' same rows (a bitcast) over T * b as they lie.
+        # Handed two [T, b, .] arrays it contracts over T with b as a
+        # window and turns both operands batch-major first, on the
+        # contraction (+0.84 ms a call at ds2_full's shape) or as a
+        # pass of its own, and two reshapes outside the kernel it folds
+        # back into that form (PERF.md section 6, PR 48). dgates stays
+        # [T, b, G*H]: a backward scan call is known by its two results
+        # of that shape (benchmark/layer_metrics/rnn_scan_roofline.py).
+        flat = not b % 8
+        build = {"kernel": route.kernel, "variant": route.variant}
         obs.registry().gauge("scan_bias_grad", 1, labels={
-            "kernel": route.kernel, "variant": route.variant,
-            "form": f"rows{db_rows}"})
-        dxp_t, dgates_t, db = scan_call(
+            **build, "form": f"rows{db_rows}"})
+        obs.registry().gauge("scan_prev_state", 1, labels={
+            **build, "source": "kernel",
+            "rows": "flat" if flat else "stepped"})
+        dxp_t, dgates_t, h_prev_t, db = scan_call(
             functools.partial(_bwd_step, cell, route.variant), route,
             reverse=reverse, hidden=h, gates=gates,
             rows=([(xp_t, at_bptt), (mask_t, at_bptt)]
                   + [(s, at_prev) for s in seqs] + [(dy_t, at_bptt)]),
-            weights=[w, bh2], outs=[(gates * h, jnp.float32, at_bptt)] * 2,
+            weights=[w, bh2],
+            outs=([(gates * h, jnp.float32, at_bptt)] * 2
+                  + [(h, jnp.float32, at_bptt, flat)]),
             whole_outs=[(db_rows, gates * h)],
             scratch=lambda cols: [h] * n + (
                 [h, cols, cols] if streamed else []),
@@ -742,7 +783,8 @@ def scan_vjp(cell: ScanCell):
         # test_gru_bf16_dw_closer_to_truth_than_oracle) while this
         # contraction stays ~2e-3, which is the recurrence's own bf16
         # noise and not the contraction's (recurrent_dw).
-        dw_h = recurrent_dw(prev_sequence(seqs[0], reverse), dgates_t, dot)
+        dw_h = recurrent_dw(h_prev_t, dgates_t.reshape(
+            h_prev_t.shape[:-1] + (gates * h,)), dot)
         dxp = jnp.moveaxis(dxp_t, 0, 1)  # [B, T, G*H]
         return (dxp, jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1),
                 dw_h.astype(w_h.dtype),

@@ -182,7 +182,7 @@ def _pallas_calls(jaxpr):
     ("gru_h1760_b32", "gru_scan_fwd", "pinned", 28),
     ("gru_h1760_b32", "gru_scan_bwd", "pinned", 32),
     ("gru_h1760_b64", "gru_scan_fwd", "pinned", 28),
-    ("gru_h1760_b64", "gru_scan_bwd", "pinned", 36),
+    ("gru_h1760_b64", "gru_scan_bwd", "pinned", 40),
     ("gru_h1760_decode", "gru_scan_fwd", "pinned", 28),
     ("gru_h1760_decode_b128", "gru_scan_fwd", "pinned", 36),
     # a float32 model, 37.8 MB of weights: at the cell's rows the need

@@ -823,6 +823,35 @@ def test_lstm_pallas_grads_match_scan(monkeypatch, blocked, reverse):
                                    rtol=1e-4, atol=1e-4, err_msg=name)
 
 
+def _backward_scan_case(monkeypatch, cell, build, b, seed):
+    """A gated cell's scan in the forced ``build`` (the LSTM's
+    ``pinned`` one is named by no route) at ragged masks, and the list
+    that takes what its VJP hands to ``recurrent_dw``:
+    ``(gates, scan, (xproj, mask, w_h, b_h), dy, handed, dw)``."""
+    from deepspeech_tpu.ops.lstm_pallas import lstm_scan_pallas
+
+    if build != "resident":
+        monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
+    if build == "blocked":
+        monkeypatch.setattr(scan_pallas, "PINNED_VMEM_CAP", 0)
+    if (cell, build) == ("lstm", "pinned"):
+        monkeypatch.setitem(scan_pallas._CELLS, "lstm", scan_pallas._CELLS[
+            "lstm"]._replace(copy_once=True))
+    t, h = 9, 144  # G*H = 432 / 576: a ragged last lane tile, 1 / 2 blocks
+    gates, scan, rand = {"gru": (3, gru_scan_pallas, _rand_gru),
+                         "lstm": (4, lstm_scan_pallas, _rand_lstm)}[cell]
+    inputs = rand(np.random.default_rng(seed + b), b, t, h)
+    dy = jnp.asarray(np.random.default_rng(1).normal(size=(b, t, h)),
+                     jnp.float32)
+    handed = []
+    dw = scan_pallas.recurrent_dw
+    monkeypatch.setattr(
+        scan_pallas, "recurrent_dw",
+        lambda h_prev, dgates, dot: handed.append((h_prev, dgates)) or dw(
+            h_prev, dgates, dot))
+    return gates, scan, inputs, dy, handed, dw
+
+
 @pytest.mark.parametrize("cell, build, b, form", [
     ("gru", "resident", 8, "rows8"), ("gru", "resident", 32, "rows8"),
     ("gru", "resident", 5, "rows1"),
@@ -847,24 +876,10 @@ def test_scan_bwd_sums_its_own_bias_gradient(monkeypatch, cell, build, b,
     leaves ``scan_bias_grad{kernel, variant, form}`` in the registry
     with the form that ran."""
     from deepspeech_tpu import obs
-    from deepspeech_tpu.ops.lstm_pallas import lstm_scan_pallas
 
-    if build != "resident":
-        monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
-    if build == "blocked":
-        monkeypatch.setattr(scan_pallas, "PINNED_VMEM_CAP", 0)
-    t, h = 9, 144  # G*H = 432 / 576: a ragged last lane tile, 1 / 2 blocks
-    gates, scan, rand = {"gru": (3, gru_scan_pallas, _rand_gru),
-                         "lstm": (4, lstm_scan_pallas, _rand_lstm)}[cell]
-    xproj, mask, w_h, b_h = rand(np.random.default_rng(47 + b), b, t, h)
-    dy = jnp.asarray(np.random.default_rng(1).normal(size=(b, t, h)),
-                     jnp.float32)
-    seen = []
-    dw = scan_pallas.recurrent_dw
-    monkeypatch.setattr(
-        scan_pallas, "recurrent_dw",
-        lambda h_prev, dgates, dot: seen.append(dgates) or dw(
-            h_prev, dgates, dot))
+    gates, scan, (xproj, mask, w_h, b_h), dy, handed, _ = \
+        _backward_scan_case(monkeypatch, cell, build, b, 47)
+    (_, t, h) = dy.shape
 
     def vjp(xp, wh, bh):
         return jax.vjp(lambda *a: scan(a[0], mask, *a[1:], reverse, True),
@@ -872,23 +887,90 @@ def test_scan_bwd_sums_its_own_bias_gradient(monkeypatch, cell, build, b,
 
     obs.registry().reset()
     _, _, db_h = vjp(xproj, w_h, b_h)  # eager: dgates_t is concrete
-    dgates_t, = seen
-    assert dgates_t.shape == (t, b, gates * h)
-    want = np.asarray(dgates_t, np.float64).sum(axis=(0, 1))
+    (_, dgates_t), = handed
+    streamed = (t * b, gates * h) if form == "rows8" else (t, b, gates * h)
+    assert dgates_t.shape == streamed
+    want = np.asarray(dgates_t, np.float64).reshape(t * b, -1).sum(axis=0)
     np.testing.assert_allclose(np.asarray(db_h, np.float64), want, rtol=0,
                                atol=1e-6 * np.abs(want).max())
-    assert obs.registry().snapshot()["gauges"] == {
+    assert obs.registry().snapshot()["gauges"][
         f'scan_bias_grad{{form="{form}",kernel="{cell}_scan_bwd",'
-        f'variant="{build}"}}': 1}
+        f'variant="{build}"}}'] == 1
 
     eqns = _eqns(vjp, xproj, w_h, b_h)
     bwd, = [e for e in eqns if e.primitive.name == "pallas_call"
             and str(e.params["metadata"]["kernel"]) == f"{cell}_scan_bwd"]
-    assert [v.aval.shape for v in bwd.outvars][2] == (
+    assert [v.aval.shape for v in bwd.outvars][3] == (
         int(form[4:]), gates * h)
     summed = [e for e in eqns if e.primitive.name == "reduce_sum"
-              and e.invars[0].aval.shape == (t, b, gates * h)]
+              and e.invars[0].aval.shape in (streamed, (t, b, gates * h))]
     assert not summed, summed
+
+
+@pytest.mark.parametrize("cell, build, b", [
+    (cell, build, b) for cell, rows in (("gru", (8, 32, 5)), ("lstm", (8,)))
+    for build in ("resident", "pinned", "blocked") for b in rows])
+@pytest.mark.parametrize("reverse", [False, True])
+def test_scan_bwd_hands_back_the_previous_state(monkeypatch, cell, build, b,
+                                                reverse):
+    """The backward scan kernel writes the previous state it fetched
+    for the gate recompute out again, a third per-step result
+    ``h_prev``: ``prev_sequence(ys, reverse)`` bit for bit at ragged
+    masks (the zero state at the scan's start, padded frames holding
+    the state), in every build, both directions and the LSTM's first
+    of two states (its ``pinned`` build is one no route names: forced
+    here). Where the rows are whole sublane tiles, ``h_prev`` leaves
+    the kernel flat, ``[T * B, H]``, the same rows in the same order;
+    else ``[T, B, H]``. The VJP hands it to ``recurrent_dw`` as it is,
+    beside the kernel's ``dgates`` in the same rows, so it holds no
+    ``concatenate`` and no ``slice`` of the state sequence outside the
+    kernel (XLA ran 14 of each over 191 MB a step of ds2_full: PERF.md
+    section 6, PR 48), ``dW_h`` is the shifted sequence's bit for bit,
+    and tracing leaves ``scan_prev_state{kernel, variant,
+    source="kernel", rows}`` in the registry."""
+    from deepspeech_tpu import obs
+
+    gates, scan, (xproj, mask, w_h, b_h), dy, handed, dw = \
+        _backward_scan_case(monkeypatch, cell, build, b, 48)
+    (_, t, h) = dy.shape
+
+    def vjp(xp, wh, bh):
+        return jax.vjp(lambda *a: scan(a[0], mask, *a[1:], reverse, True),
+                       xp, wh, bh)
+
+    obs.registry().reset()
+    ys, pull = vjp(xproj, w_h, b_h)
+    _, dw_h, _ = pull(dy)  # eager: the kernel's results are concrete
+    (h_prev_t, dgates_t), = handed
+    rows = (t, b) if b % 8 else (t * b,)
+    assert (h_prev_t.shape, dgates_t.shape) == (
+        rows + (h,), rows + (gates * h,))
+    want = scan_pallas.prev_sequence(jnp.moveaxis(ys, 1, 0), reverse)
+    want = want.reshape(h_prev_t.shape)
+    np.testing.assert_array_equal(np.asarray(h_prev_t), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(dw_h), np.asarray(dw(want, dgates_t, jnp.float32)))
+    assert obs.registry().snapshot()["gauges"][
+        f'scan_prev_state{{kernel="{cell}_scan_bwd",'
+        f'rows="{"stepped" if b % 8 else "flat"}",source="kernel",'
+        f'variant="{build}"}}'] == 1
+
+    eqns = _eqns(lambda *a: vjp(*a)[1](dy), xproj, w_h, b_h)
+    bwd, = [e for e in eqns if e.primitive.name == "pallas_call"
+            and str(e.params["metadata"]["kernel"]) == f"{cell}_scan_bwd"]
+    assert [v.aval.shape for v in bwd.outvars][1:3] == [
+        (t, b, gates * h), rows + (h,)]
+    contraction, = [e for e in eqns if e.primitive.name == "dot_general"
+                    and e.outvars[0].aval.shape == (h, gates * h)]
+    made = {e.outvars[0]: e for e in eqns if e.primitive.name == "reshape"}
+    state, dgates = contraction.invars
+    if dgates in made:  # the kernel's [T, B, G*H] in h_prev's flat rows
+        dgates, = made[dgates].invars
+    assert [state, dgates] == bwd.outvars[2:0:-1], contraction
+    shifted = [e for e in eqns if e.primitive.name == "concatenate" or (
+        e.primitive.name in ("slice", "dynamic_slice", "pad")
+        and e.invars[0].aval.shape == (t, b, h))]
+    assert not shifted, shifted
 
 
 def test_lstm_pallas_respects_mask():

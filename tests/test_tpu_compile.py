@@ -134,7 +134,7 @@ def test_pinned_scan_fits_the_vmem_it_asks_for(v5e_chip, case, kernels):
     once, consume them whole at every time step (one matmul forward,
     two backward) and raise their own scoped limit from their shapes
     (forward 28 MiB at the cell's b=32 and at b=64, 36 at b=128;
-    backward 32 and 36 MiB): Mosaic accepts every one, and the compiled
+    backward 32 and 40 MiB): Mosaic accepts every one, and the compiled
     call says which build it is. The backward step reads its scratch
     once per matmul; read once for both, Mosaic holds the matrix a
     second time (42 MiB at b=32) and this test fails."""
@@ -184,6 +184,12 @@ def test_recurrent_dw_passes_follow_the_dot_type(v5e_chip, case, passes):
     assert f"operand_precision={{{passes},{passes}}}" in dws[0]
 
 
+# gru_scan_bwd at H=1760 as the compiled text names it: dxp, dgates,
+# h_prev flat (850 * b rows) and the bias gradient's accumulator
+_BWD_RESULTS = (r"%(\S+) = \(f32\[850,{b},5280\]\S*, f32\[850,{b},5280\]\S*, "
+                r"f32\[{rows},1760\]\S*, f32\[8,5280\]\S*\) custom-call\(")
+
+
 @pytest.mark.parametrize("case, rows", [
     ("gru_h1760_b32", 32), ("gru_h1760_b64", 64)])
 def test_backward_scan_sums_its_bias_gradient(v5e_chip, case, rows):
@@ -199,12 +205,84 @@ def test_backward_scan_sums_its_bias_gradient(v5e_chip, case, rows):
     from aot_kernels import compile_case, kernel_cases
 
     text = compile_case(kernel_cases()[case], v5e_chip).as_text()
-    assert re.search(
-        rf"\(f32\[850,{rows},5280\]\S*, f32\[850,{rows},5280\]\S*, "
-        r"f32\[8,5280\]\S*\) custom-call\(", text)
+    assert re.search(_BWD_RESULTS.format(b=rows, rows=850 * rows), text)
     sums = re.findall(
         r"= f32\[5280\]\S* reduce\([^\n]*dimensions=\{([\d,]+)\}", text)
     assert sums == ["0"], sums
+
+
+@pytest.mark.parametrize("case, rows", [
+    ("gru_h1760_b32", 32), ("gru_h1760_b64", 64)])
+def test_backward_scan_hands_back_the_previous_state(v5e_chip, case, rows):
+    """ds2_full's scan VJP as the TPU compiler is handed it: the
+    recurrent weight gradient's two operands are results of the
+    backward Mosaic call itself, ``h_prev``, flat (``[850 * b, H]``),
+    and ``dgates`` through a bitcast to the same rows, and the
+    contraction takes them as they lie: a convolution with no window
+    over the 850 * b rows, no ``copy`` inside its fusion. Nothing
+    slices the forward call's state sequence to 849 rows and nothing
+    concatenates or pads a zero row onto it: built by XLA, the shifted
+    copy was a ``slice`` and a ``copy`` of 191 MB each at b=32, 14
+    times a step, and handed ``[850, b, .]`` the contraction turned
+    its operands batch-major itself (16.66 ms of 445, then 0.84 ms a
+    contraction: PERF.md section 6, PR 48). A copy of the sequence's
+    shape alone is not the shifted copy (``dy``'s turn to time-major
+    is one), so none is forbidden by shape. The call asks for the
+    scoped VMEM ``_pinned_vmem_limit`` counts with the new block in
+    it, 32 / 40 MiB, and Mosaic accepts it."""
+    from aot_kernels import compile_case, kernel_cases
+
+    from deepspeech_tpu.ops.scan_pallas import scan_route
+
+    text = compile_case(kernel_cases()[case], v5e_chip).as_text()
+    bwd = re.search(_BWD_RESULTS.format(b=rows, rows=850 * rows), text)
+    assert bwd, "gru_scan_bwd's four results"
+    shifted = re.findall(
+        rf"= f32\[849,{rows},1760\]\S* slice\(|"
+        rf"= f32\[850,{rows},1760\]\S* (?:concatenate|pad)\(", text)
+    assert not shifted, shifted
+    # the computation that holds the contraction, who feeds it, and
+    # the computations its fusion reaches (none may turn a layout)
+    home, feeds, bodies, where = None, {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            where = head[1]
+            continue
+        bodies.setdefault(where, []).append(line)
+        if re.search(r"= f32\[1760,5280(,1)?\]\S* (convolution|dot)\(", line):
+            assert home is None, "one recurrent weight gradient"
+            home = where
+            assert "window=" not in line, line
+            operands = re.search(r"(?:convolution|dot)\(([^)]*)\)", line)[1]
+        fused = re.search(r"fusion\(([^)]*)\).* calls=%(\S+?),? ", line)
+        if fused:
+            feeds[fused[2]] = fused[1]
+    if home in feeds:  # fused: the fusion's operands, and all it holds
+        operands, reach = feeds[home], [home]
+        for name in reach:
+            reach += re.findall(r"calls=%([^\s,]+)", "\n".join(bodies[name]))
+        turned = [line for name in reach for line in bodies[name]
+                  if re.search(r" (copy|transpose)\(", line)]
+        assert not turned, turned
+
+    def result_of_the_call(name):
+        through = re.search(
+            rf"%{re.escape(name)} = \S+ bitcast\(%([^\s,)]+)\)", text)
+        if through:
+            return result_of_the_call(through[1])
+        return int(re.search(rf"%{re.escape(name)} = \S+ get-tuple-element\("
+                             rf"%{re.escape(bwd[1])}\), index=(\d)", text)[1])
+
+    results = sorted(map(result_of_the_call,
+                         re.findall(r"%([^\s,)]+)", operands)))
+    assert results == [1, 2], results
+    limit = scan_route("gru", "pallas", hidden=1760, rows=rows, dot_bytes=2,
+                       xproj_bytes=2, backward=True).vmem_limit
+    assert limit == {32: 32, 64: 40}[rows] * 1024 * 1024
+    asked = re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                      text[bwd.end():])
+    assert int(asked[1]) == limit
 
 
 def test_ctc_vjp_folds_gamma_without_a_scatter(v5e_chip):

@@ -293,7 +293,7 @@ def kernel_cases():
     # ds2_full.train_1chip's own call (850 post-conv frames, bf16
     # xproj) and twice its rows: both calls copy their weights into
     # VMEM once, run one grid step a time step and ask for their own
-    # scoped VMEM, forward 28 / 28 MiB, backward 32 / 36 MiB.
+    # scoped VMEM, forward 28 / 28 MiB, backward 32 / 40 MiB.
     cases["gru_h1760_b32"] = gru_case(1760, 32, 850, jnp.bfloat16)
     cases["gru_h1760_b64"] = gru_case(1760, 64, 850, jnp.bfloat16)
     # offline decode: the forward call alone, in the 1200-frame bucket

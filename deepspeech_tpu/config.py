@@ -117,6 +117,7 @@ class ModelConfig:
     # published config's names behind the ``lfm_`` prefix.
     lfm_hidden: int = 2048
     # "conv" | "full_attention" | "sliding_attention" | "latent_attention"
+    # | "ssm_attention"
     lfm_layer_types: Tuple[str, ...] = ()
     lfm_dense_layers: int = 1        # leading layers with the dense FFN
     lfm_heads: int = 32
@@ -237,6 +238,35 @@ class ModelConfig:
     moe_score_func: str = "sigmoid"
     moe_expert_act: str = "silu"
     moe_route_pre_attn: bool = False
+    # The hybrid block (``model_type: falcon_h1``; a layer of kind
+    # "ssm_attention"): a Mamba-2 state-space mixer and grouped-query
+    # attention side by side on ONE normed input, their outputs summed
+    # into the residual. The mixer's sizes under the published names
+    # (``mamba_d_ssm`` = ``ssm_heads`` x a head, ``mamba_d_state``,
+    # ``mamba_n_groups``: the heads of a group share B and C,
+    # ``mamba_d_conv`` taps of the depthwise convolution before it,
+    # ``mamba_chunk_size`` positions a chunk of the sequence form).
+    ssm_d_ssm: int = 0
+    ssm_heads: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # muP multipliers, data of a preset under the published names (1:
+    # absent from the program, not multiplied): on what enters the first
+    # layer, on the logits, on attention's input, keys and output, on
+    # the mixer's input, on the five segments of its projection (z, x,
+    # B, C, dt) and on its output, on the dense feed-forward's gate
+    # argument and on its output.
+    mup_embedding: float = 1.0
+    mup_lm_head: float = 1.0
+    mup_attn_in: float = 1.0
+    mup_key: float = 1.0
+    mup_attn_out: float = 1.0
+    mup_ssm_in: float = 1.0
+    mup_ssm: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mup_ssm_out: float = 1.0
+    mup_mlp: Tuple[float, float] = (1.0, 1.0)
 
     @property
     def time_stride(self) -> int:
@@ -786,6 +816,54 @@ def smallthinker_21b_a3b() -> Config:
     )
 
 
+def falcon_h1_34b() -> Config:
+    """One pipeline stage of Falcon-H1-34B-Instruct (``model_type:
+    falcon_h1``,
+    https://huggingface.co/tiiuae/Falcon-H1-34B-Instruct/blob/main/config.json)
+    as a decoder-only speech recogniser that is SERVED
+    (``decode.mode="lm_greedy"``), every width as published: hidden
+    5120; in every layer, under one norm, a Mamba-2 mixer (32 heads of
+    128, state 256, 2 groups, a 4-tap convolution with bias, gated
+    RMSNorm over each group) beside 20 query / 4 key-value heads of 128
+    (rotary, theta 1e11, no q/k norm), summed; a dense gated MLP of
+    21,504; fourteen muP multipliers; the whole vocabulary of 261,120,
+    untied. No chips share a layer: depth is cut to one stage of 6 of
+    the 72 layers. The cache of a layer is keys, values, the mixer's
+    float32 state and the convolution's last three inputs.
+    ``benchmark/configs/falcon_h1_34b.json`` has the published keys
+    beside these and every reading that is this repo's own."""
+    c = Config(name="falcon_h1_34b")
+    return _replace(
+        c,
+        model=_replace(
+            c.model, conv_layers=(), conv_channels=(), rnn_layers=0,
+            bidirectional=False, rnn_batch_norm=False, frame_stack=8,
+            vocab_size=261120, lfm_hidden=5120,
+            lfm_layer_types=("ssm_attention",) * 6, lfm_dense_layers=6,
+            lfm_heads=20, lfm_kv_heads=4, lfm_head_dim=128,
+            lfm_rope_kinds=("ssm_attention",), lfm_qk_norm=False,
+            lfm_norm_gain_std=0.1, lfm_ffn_dim=21504,
+            lfm_rope_theta=1e11, lfm_norm_eps=1e-5,
+            lfm_seq_positions=288, lm_tied_head=False,
+            ssm_d_ssm=4096, ssm_heads=32, ssm_state=256, ssm_groups=2,
+            ssm_conv=4, ssm_chunk=128, mup_embedding=5.656854249492381,
+            mup_lm_head=0.0078125, mup_attn_in=1.0,
+            mup_key=0.011048543456039804, mup_attn_out=0.0375,
+            mup_ssm_in=0.25,
+            mup_ssm=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+            mup_ssm_out=0.08838834764831845,
+            mup_mlp=(0.1767766952966369, 0.011160714285714284)),
+        data=_replace(c.data, batch_size=128, bucket_frames=(1696,),
+                      max_label_len=64),
+        train=_replace(c.train, objective="lm", optimizer="adamw",
+                       learning_rate=1e-4, weight_decay=0.0,
+                       grad_clip_norm=1.0, warmup_steps=100),
+        decode=_replace(c.decode, mode="lm_greedy", lm_prefill_rows=32,
+                        lm_watch_rows=2),
+    )
+
+
 PRESETS = {
     "ds2_small": ds2_small,
     "ds2_full": ds2_full,
@@ -799,6 +877,7 @@ PRESETS = {
     "xing4_29b_a4b": xing4_29b_a4b,
     "trinity_large": trinity_large,
     "smallthinker_21b_a3b": smallthinker_21b_a3b,
+    "falcon_h1_34b": falcon_h1_34b,
 }
 
 

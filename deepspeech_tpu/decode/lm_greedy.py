@@ -50,7 +50,17 @@ the cache rows are fewer: a ring that never wraps). Prefill writes a
 stream's last ``min(a, R)`` prefix rows into a ring and all of them
 into a full cache; a step writes its row in slot ``pos mod R`` and
 attends to the rows the layer can reach; the call counts the rows
-attended per kind, and the rows fetched to do so.
+attended per kind, and the rows fetched to do so. A hybrid layer
+("ssm_attention": a state-space mixer beside attention that sees all)
+holds four arrays: keys and values as a "full_attention" layer, then
+the mixer's RECURRENT STATE ``[streams, heads, state, head]`` in
+float32 and the convolution's last inputs ``[streams, taps - 1,
+channels]``. The last two are not rows by position: prefill writes what
+a stream holds after its last prefix position, and a step updates the
+state of every live stream where it lies (the loop's carry; on a TPU
+the kernel ``ssd_state_step`` aliases it), so no second copy of it
+ever exists. The call counts the live streams' steps and the bytes a
+step needs by part (layer weights, head, state, rows in reach).
 """
 
 from __future__ import annotations
@@ -64,34 +74,43 @@ import numpy as np
 
 from .. import obs
 from ..config import Config
-from ..models.lfm2 import (ATTENTION_KINDS, attends_in_kernels,
+from ..models.lfm2 import (ATTENTION_KINDS, HYBRID, attends_in_kernels,
                            create_lfm2_model, head_dim, ring_positions,
                            seq_positions, uncached_kinds)
 from ..ops import attn_pallas
 
 
+# What a hybrid layer sows apart: its two branches in both forms, and
+# in the steps its feed-forward too (the last layer's is dead code in
+# prefill: it feeds nothing there).
+BRANCHES = ("branch_mixer", "branch_attn")
+STEP_BRANCHES = BRANCHES + ("branch_mlp",)
+
+
 def _watched(mid: dict, rows, layers: List[str], mixed: str = "",
-             gated=()) -> dict:
-    """Of a pass's sown router outputs, the ``rows`` of the batch: the
-    last expert layer's scores and combine weights and every expert
-    layer's chosen sets; the feed-forward's hyper-connection
-    coefficients of the layer ``mixed``, where the residual has
-    streams; and the gated attention output (before ``o``) of the
-    layers ``gated``."""
-    if not layers:
-        return {}
+             gated=(), hybrid: str = "", branches=BRANCHES) -> dict:
+    """Of a pass's sown outputs, the ``rows`` of the batch: the last
+    expert layer's router scores and combine weights and every expert
+    layer's chosen sets (a stack with expert layers); the
+    feed-forward's hyper-connection coefficients of the layer
+    ``mixed``, where the residual has streams; the gated attention
+    output (before ``o``) of the layers ``gated``; and the ``branches``
+    of the hybrid layer ``hybrid``, each output apart."""
+    out = {}
+    if layers:
+        def of(name, key):
+            x = mid[name]["moe"][key][0]
+            return x.reshape((-1, rows[1]) + x.shape[1:])[rows[0]]
 
-    def of(name, key):
-        x = mid[name]["moe"][key][0]
-        return x.reshape((-1, rows[1]) + x.shape[1:])[rows[0]]
-
-    out = {"scores": of(layers[-1], "scores"),
-           "weights": of(layers[-1], "weights"),
-           "chosen": jnp.stack([of(n, "experts") for n in layers])}
-    for key in ("h_pre", "h_post", "h_res") if mixed else ():
-        out[key] = mid[mixed]["ffn_hc"][key][0][rows[0]]
+        out = {"scores": of(layers[-1], "scores"),
+               "weights": of(layers[-1], "weights"),
+               "chosen": jnp.stack([of(n, "experts") for n in layers])}
+        for key in ("h_pre", "h_post", "h_res") if mixed else ():
+            out[key] = mid[mixed]["ffn_hc"][key][0][rows[0]]
     for i, name in enumerate(gated):
         out[f"gated{i}"] = mid[name]["attn"]["gated"][0][rows[0]]
+    for key in branches if hybrid else ():
+        out[key] = mid[hybrid][key][0][rows[0]]
     return out
 
 
@@ -109,8 +128,9 @@ class LMGreedy:
             raise NotImplementedError(
                 "decode.mode='lm_greedy' needs a cache for every layer "
                 f"kind; {cfg.name!r} lacks " + " and ".join(missing)
-                + " (latent and grouped-query attention have their "
-                "decode forms)")
+                + " (latent attention, grouped-query attention and the "
+                "hybrid of a state-space mixer beside attention have "
+                "their decode forms)")
         self.cfg = cfg
         self.model = create_lfm2_model(m, cfg.data.max_label_len)
         dtype = jnp.dtype(m.dtype)
@@ -129,12 +149,20 @@ class LMGreedy:
         # The layer whose hyper-connection coefficients are given out.
         self.mixed = (f"layer{len(m.lfm_layer_types) - 1}"
                       if m.hc_streams > 1 else "")
-        # Layers of each grouped-query kind, and of each kind the last
-        # layer, whose gated attention output is given out.
+        # Layers of each grouped-query kind (a hybrid layer's attention
+        # sees all), and of each kind the last layer, whose gated
+        # attention output is given out.
+        sees = {"sliding_attention": ("sliding_attention",),
+                "full_attention": ("full_attention", HYBRID)}
         self.kinds = {k: [i for i, t in enumerate(m.lfm_layer_types)
-                          if t == k] for k in ATTENTION_KINDS}
+                          if t in sees[k]] for k in ATTENTION_KINDS}
         self.gated = [f"layer{self.kinds[k][-1]}" for k in (
             "sliding_attention", "full_attention") if self.kinds[k]]
+        # The hybrid layers, whose caches hold a recurrent state; of the
+        # last, the two branches' outputs are given out apart.
+        self.stateful = [i for i, t in enumerate(m.lfm_layer_types)
+                         if t == HYBRID]
+        self.hybrid = f"layer{self.stateful[-1]}" if self.stateful else ""
         self._cache = None
         self._calls = 0
         self.last_call: Optional[dict] = None
@@ -163,7 +191,12 @@ class LMGreedy:
         watched = min(self.cfg.decode.lm_watch_rows, rows)
         watch = _watched(state.get("intermediates", {}),
                          (slice(0, watched), a), self.sparse, self.mixed,
-                         self.gated)
+                         self.gated, self.hybrid)
+        if self.stateful:
+            # What the last hybrid layer holds after the prefix: the
+            # steps then update it where it lies.
+            watch["state"], watch["conv"] = (
+                x[:watched] for x in new[self.stateful[-1]][2:])
         return cache, a_lens, counters, watch, draft
 
     @staticmethod
@@ -223,6 +256,10 @@ class LMGreedy:
             acc["steps"] += 1
             acc["tokens"] += active
             acc["idle_slot_steps"] += jnp.sum(~active)
+            if self.stateful:
+                # (stream, layer) states a step read and wrote
+                acc["state_updates"] += jnp.sum(active) * len(
+                    self.stateful)
             reach = jnp.where(active, a_lens + j + 1, 0)
             if any(self.kinds.values()):
                 # Rows attended, over the layers of each kind.
@@ -237,7 +274,8 @@ class LMGreedy:
                 acc["cache_rows_read"] += jnp.sum(reach)
             self._count(acc, counters)
             mid = _watched(state.get("intermediates", {}), (watch, 1),
-                           self.sparse, gated=self.gated)
+                           self.sparse, gated=self.gated,
+                           hybrid=self.hybrid, branches=STEP_BRANCHES)
             mid["logits"] = logits[watch][:, None, :]
             seen = {k: jax.lax.dynamic_update_slice_in_dim(
                 seen[k], mid[k], j, axis=seen[k].ndim - 2) for k in seen}
@@ -263,6 +301,11 @@ class LMGreedy:
             seen.update({f"gated{i}": jnp.zeros((w, t, width),
                                                 jnp.dtype(m.dtype))
                          for i in range(len(self.gated))})
+        if self.stateful:
+            acc["state_updates"] = jnp.int32(0)
+            seen.update({k: jnp.zeros((w, t, m.lfm_hidden),
+                                      jnp.dtype(m.dtype))
+                         for k in STEP_BRANCHES})
         if self.sparse:
             acc["experts_hit"] = jnp.zeros(len(self.sparse), jnp.int32)
             seen["scores"] = jnp.zeros((w, t, m.lfm_experts), jnp.float32)
@@ -435,23 +478,39 @@ class LMGreedy:
     # -- a call --------------------------------------------------------------
 
     def cache_shapes(self, rows: int, frames: int) -> list:
-        """The shapes of each layer's cache (a draft module's after
-        them) for ``rows`` streams whose prefix is ``frames`` feature
-        frames, a list of arrays' shapes a layer (one for latent
-        attention, keys and values for grouped-query attention):
+        """Each layer's cache (a draft module's after them) for ``rows``
+        streams whose prefix is ``frames`` feature frames, as a list of
+        ``jax.ShapeDtypeStruct`` a layer: one array for latent
+        attention; keys and values for grouped-query attention,
         ``model.lfm_seq_positions`` rows a stream, or (0) the least
-        that hold the prefix and every step; a windowed layer's ring
-        has ``lfm_window`` rows where that is fewer."""
+        that hold the prefix and every step, a windowed layer's ring
+        ``lfm_window`` rows where that is fewer; for a hybrid layer
+        keys, values, the mixer's state in float32 and the
+        convolution's last inputs. All but the state are in the
+        model's dtype."""
         m = self.cfg.model
+        dtype = jnp.dtype(m.dtype)
         positions = seq_positions(m, frames, self.cfg.data.max_label_len)
-        latent = [(rows, positions, m.mla_kv_rank + m.mla_rope_dim)]
+
+        def arrays(*shapes):
+            return [jax.ShapeDtypeStruct(s, dtype) for s in shapes]
+
+        latent = arrays((rows, positions, m.mla_kv_rank + m.mla_rope_dim))
 
         def of(kind):
-            if kind not in ATTENTION_KINDS:
+            if kind not in ATTENTION_KINDS + (HYBRID,):
                 return latent
             ring = kind == "sliding_attention" and m.lfm_window
-            return [(rows, min(ring, positions) if ring else positions,
-                     m.lfm_kv_heads, head_dim(m))] * 2
+            held = arrays((rows, min(ring, positions) if ring else positions,
+                           m.lfm_kv_heads, head_dim(m))) * 2
+            if kind != HYBRID:
+                return held
+            return held + [
+                jax.ShapeDtypeStruct(
+                    (rows, m.ssm_heads, m.ssm_state,
+                     m.ssm_d_ssm // m.ssm_heads), jnp.float32)
+            ] + arrays((rows, m.ssm_conv - 1,
+                        m.ssm_d_ssm + 2 * m.ssm_groups * m.ssm_state))
 
         return [of(k) for k in m.lfm_layer_types] \
             + [latent] * m.lm_draft_layers
@@ -459,25 +518,48 @@ class LMGreedy:
     def cache_for(self, rows: int, frames: int) -> list:
         """The cache of ``rows`` streams whose prefix is ``frames``
         feature frames: an array a latent layer or draft module, the
-        pair (keys, values) a grouped-query layer."""
-        dtype = jnp.dtype(self.cfg.model.dtype)
+        pair (keys, values) a grouped-query layer, (keys, values,
+        state, convolution inputs) a hybrid layer."""
         shapes = self.cache_shapes(rows, frames)
-        if self._cache is None or [
-                [c.shape for c in jax.tree.leaves(layer)]
-                for layer in self._cache] != shapes:
+
+        def spec(cache):
+            return [(c.shape, c.dtype) for c in jax.tree.leaves(cache)]
+
+        if self._cache is None or spec(self._cache) != spec(shapes):
             self._cache = None  # free the old one first
             self._cache = [
-                jnp.zeros(s[0], dtype) if len(s) == 1
-                else tuple(jnp.zeros(x, dtype) for x in s) for s in shapes]
+                jnp.zeros(s[0].shape, s[0].dtype) if len(s) == 1
+                else tuple(jnp.zeros(x.shape, x.dtype) for x in s)
+                for s in shapes]
             gauge = obs.registry().gauge
             gauge("lm_cache_bytes", cache_bytes(self._cache))
             for kind, name in (("sliding_attention", "window"),
                                ("full_attention", "global")):
                 if self.kinds[kind]:
                     gauge("lm_cache_bytes_" + name, cache_bytes(
-                        [self._cache[i] for i in self.kinds[kind]]))
+                        [self._cache[i][:2] for i in self.kinds[kind]]))
+            for at, name in ((2, "state"), (3, "conv")):
+                if self.stateful:
+                    gauge("lm_cache_bytes_" + name, cache_bytes(
+                        [self._cache[i][at] for i in self.stateful]))
         cache, self._cache = self._cache, None
         return cache
+
+    def step_bytes(self, acc: dict) -> dict:
+        """Bytes a call's decode steps NEED to move, by part, from the
+        loop's counters: every layer's weights and the head once a
+        step, each live (stream, hybrid layer)'s state read once and
+        written once, and the cache rows in reach, keys and values."""
+        steps = int(acc["steps"])
+        named = {k: cache_bytes(v) for k, v in self.params.items()}
+        head = named["embed" if self.cfg.model.lm_tied_head else "lm_head"]
+        layers = sum(v for k, v in named.items() if k.startswith("layer"))
+        m = self.cfg.model
+        return {"weights": steps * layers, "head": steps * head,
+                "state": int(acc["state_updates"]) * 2 * 4
+                * m.ssm_d_ssm * m.ssm_state,
+                "rows": int(acc["cache_rows_read"]) * 2 * m.lfm_kv_heads
+                * head_dim(m) * jnp.dtype(m.dtype).itemsize}
 
     def transcribe(self, features, feat_lens, max_tokens=None,
                    forced=None, watch=None) -> Dict:
@@ -541,6 +623,8 @@ class LMGreedy:
                 t3 = time.perf_counter()
             self._cache = cache
         stats = obs.observe_lm_call(pre, acc, rows=b)
+        if self.stateful:
+            stats["decode_bytes"] = self.step_bytes(acc)
         # Host seconds, tracer on or off, so that a stalled call says
         # where: up to the prefill programs dispatched (``cache``, the
         # cache handed out or made, is its first part), then up to the

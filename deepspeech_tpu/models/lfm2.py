@@ -10,10 +10,16 @@ modules (``DraftModule``) after the last layer. A fourth family's block
 (``model_name: smallthinker``) is LFM2's with the preset's data: no
 q/k norms, a router that reads the layer's INPUT before attention
 (``moe_route_pre_attn``), a softmax over the chosen logits, gated-ReLU
-experts. ``hidden`` / ``loss``
+experts. A fifth's (``model_type: falcon_h1``) is the HYBRID layer: a
+Mamba-2 state-space mixer (``Mamba2Mixer``; the recurrence in
+``ops/ssd_pallas.py``) and grouped-query attention side by side on one
+normed input, summed, with muP multipliers that are the preset's data
+(``mup_*``) and no expert layer. ``hidden`` / ``loss``
 are the training path (the draft modules are not trained here);
 ``prefill`` and ``step`` the serving path through a cache
-(``decode/lm_greedy.py``), for the layer kinds that have one, and
+(``decode/lm_greedy.py``), for the layer kinds that have one (latent
+and grouped-query attention: rows by position; the hybrid layer: rows
+and a recurrent state), and
 ``verify`` / ``draft`` its form over a few positions a stream, for a
 loop that drafts for itself.
 
@@ -43,9 +49,10 @@ from functools import partial
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..config import ModelConfig
-from ..ops import attn_pallas, moe
+from ..ops import attn_pallas, moe, ssd_pallas
 from ..utils.impl import on_tpu
 from . import mhc
 from .axk1 import LatentAttention
@@ -125,6 +132,13 @@ class Linear(nn.Module):
         return jnp.dot(x, kernel.astype(x.dtype))
 
 
+def scaled(x, by):
+    """``x`` times a muP multiplier (a number, or a vector over the
+    last axis), in float32, in ``x``'s dtype."""
+    return (x.astype(jnp.float32) * jnp.asarray(by, jnp.float32)
+            ).astype(x.dtype)
+
+
 class ShortConv(nn.Module):
     """Gated short convolution: ``[B, C, x] = split3(W_in h)``,
     ``c_t = sum_j k_j * (B * x)_{t-j}`` (depthwise, causal, zeros
@@ -145,6 +159,125 @@ class ShortConv(nn.Module):
             c = c + filt[j] * jnp.pad(z, [(0, 0), (j, 0), (0, 0)]
                                       )[:, :z.shape[1]]
         return Linear(d, name="out_proj")(gate_c * c)
+
+
+def _softplus_inverse(rng, shape, dtype=jnp.float32):
+    """``dt_bias``: the inverse softplus of a step drawn log-uniform
+    in 0.001 .. 0.1."""
+    dt = jnp.exp(jax.random.uniform(rng, shape, dtype)
+                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 state-space mixer (``ops/ssd_pallas.py`` has the
+    recurrence): ``[z | x | B | C | dt] = (W_in u)`` times the family's
+    multipliers by segment, a causal depthwise convolution of
+    ``ssm_conv`` taps with bias and silu over ``[x | B | C]`` (zeros
+    before position 0, the LAST tap on the current position), ``dt =
+    softplus(dt + dt_bias)``, the recurrence a head, ``y * silu(z)``,
+    RMSNorm over each group's channels with one gain, ``W_out``.
+
+    ``__call__(u [B, S, D], valid [B, S])`` is the SEQUENCE form
+    (positions 0..S-1, left-packed: padding only on the right) and
+    returns the output and what the stream's cache holds after its last
+    valid position ``a - 1``: the float32 state ``[B, heads, state,
+    head]`` and the convolution's last ``taps - 1`` INPUTS ``[B, taps -
+    1, channels]`` (positions ``a - 3 .. a - 1``; zeros where the
+    stream is shorter). With ``cache``, that pair, it is the DECODE
+    form, one new position a stream; a stream that is not live
+    (``valid [B, 1]``) leaves its cache as it is."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, u, valid, cache=None):
+        cfg = self.cfg
+        b, s, _ = u.shape
+        d, nh, n, g = (cfg.ssm_d_ssm, cfg.ssm_heads, cfg.ssm_state,
+                       cfg.ssm_groups)
+        p, taps, wide = d // nh, cfg.ssm_conv, d + 2 * g * n
+        f32 = jnp.float32
+        proj = Linear(2 * d + 2 * g * n + nh, name="in_proj")(u)
+        by = np.repeat(np.asarray(cfg.mup_ssm, np.float32),
+                       [d, d, g * n, g * n, nh]) * cfg.mup_ssm_in
+        if np.any(by != 1.0):
+            proj = scaled(proj, by)
+        z, taken, dt = jnp.split(proj, [d, d + wide], axis=-1)
+        filt = self.param("filter", nn.initializers.normal(taps ** -0.5),
+                          (taps, wide)).astype(f32)
+        bias = self.param("conv_bias", _INIT, (wide,)).astype(f32)
+        dt_bias = self.param("dt_bias", _softplus_inverse, (nh,))
+        a = -jnp.exp(self.param(
+            "A_log", lambda rng, shape: jnp.log(jax.random.uniform(
+                rng, shape, f32, 1.0, 16.0)), (nh,)).astype(f32))
+        skip = self.param("D", nn.initializers.ones, (nh,)).astype(f32)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+        if cache is None:
+            ahead = jnp.pad(taken, [(0, 0), (taps - 1, 0), (0, 0)])
+            conv = sum(filt[j] * ahead[:, j:j + s] for j in range(taps))
+            # the inputs at a - taps + 1 .. a - 1 of each stream
+            at = jnp.sum(valid, axis=1)[:, None] + jnp.arange(taps - 1)
+            held = jnp.take_along_axis(ahead, at[..., None], axis=1)
+        elif s != 1:
+            raise NotImplementedError(
+                f"the state-space mixer decodes one new position a "
+                f"stream, not {s}")
+        else:
+            state, held = cache
+            ahead = jnp.concatenate([held, taken.astype(held.dtype)],
+                                    axis=1)
+            conv = jnp.einsum("jc,bjc->bc", filt, ahead)[:, None]
+            held = jnp.where(valid[..., None], ahead[:, 1:], held)
+        conv = jax.nn.silu(conv + bias).astype(u.dtype)
+        x, keys, reads = jnp.split(conv, [d, d + g * n], axis=-1)
+        x = x.reshape(b, s, nh, p)
+        keys, reads = (v.reshape(b, s, g, n) for v in (keys, reads))
+        if cache is None:
+            y, state = ssd_pallas.ssd_scan(x, dt, a, keys, reads, skip,
+                                           valid, cfg.ssm_chunk)
+        else:
+            y, state = ssd_pallas.ssd_step(
+                state, x[:, 0], dt[:, 0], a, keys[:, 0], reads[:, 0],
+                skip, valid[:, 0])
+            y = y[:, None]
+        gated = y.reshape(b, s, g, d // g).astype(f32) * jax.nn.silu(
+            z.astype(f32)).reshape(b, s, g, d // g)
+        gated = gated * jax.lax.rsqrt(jnp.mean(
+            gated * gated, axis=-1, keepdims=True) + cfg.lfm_norm_eps)
+        gain = self.param("norm", gain_init(cfg.lfm_norm_gain_std), (d,))
+        out = Linear(cfg.lfm_hidden, name="out_proj")(
+            (gated.reshape(b, s, d) * gain).astype(u.dtype))
+        if cfg.mup_ssm_out != 1.0:
+            out = scaled(out, cfg.mup_ssm_out)
+        return out, (state, held)
+
+
+def mixer_both_forms(cfg: ModelConfig, params, x, split: int,
+                     state_dtype=jnp.float32):
+    """One state-space mixer on ``x [B, S, D]`` in both forms: the
+    sequence form over all positions; and the sequence form over the
+    first ``split``, then the decode form one position at a time over
+    the rest through the cache it left, the state carried in
+    ``state_dtype``. Returns the two outputs at the positions from
+    ``split`` on ``[B, S - split, D]`` (decode form first) and the two
+    states after the last position."""
+    mixer = Mamba2Mixer(cfg)
+    b, s, _ = x.shape
+    valid = jnp.ones((b, s), bool)
+    seq, (whole, _) = mixer.apply({"params": params}, x, valid)
+    _, (state, held) = mixer.apply({"params": params}, x[:, :split],
+                                   valid[:, :split])
+
+    def step(cache, x_t):
+        out, (state, held) = mixer.apply(
+            {"params": params}, x_t[:, None], valid[:, :1], cache)
+        return (state.astype(state_dtype), held), out[:, 0]
+
+    (state, _), dec = jax.lax.scan(
+        step, (state.astype(state_dtype), held),
+        jnp.moveaxis(x[:, split:], 1, 0))
+    return jnp.moveaxis(dec, 0, 1), seq[:, split:], state, whole
 
 
 def rotary(x, theta: float, pos=None):
@@ -262,8 +395,12 @@ class Attention(nn.Module):
         hd, rep = head_dim(cfg), nh // nkv
         window = cfg.lfm_window if self.kind == "sliding_attention" else 0
         scope = "gqa_attn_" + ("window" if window else "global")
+        if cfg.mup_attn_in != 1.0:
+            h = scaled(h, cfg.mup_attn_in)
         q = Linear(nh * hd, name="q")(h).reshape(b, s, nh, hd)
         k = Linear(nkv * hd, name="k")(h).reshape(b, s, nkv, hd)
+        if cfg.mup_key != 1.0:            # on the keys, before rotation
+            k = scaled(k, cfg.mup_key)
         v = Linear(nkv * hd, name="v")(h).reshape(b, s, nkv, hd)
         std = cfg.lfm_norm_gain_std
 
@@ -339,7 +476,10 @@ class Attention(nn.Module):
             out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(h.dtype)
         self.sow("intermediates", "gated", out)
-        return Linear(d, name="o")(out), kept
+        out = Linear(d, name="o")(out)
+        if cfg.mup_attn_out != 1.0:
+            out = scaled(out, cfg.mup_attn_out)
+        return out, kept
 
 
 def both_forms(cfg: ModelConfig, kind: str, params, x, at, rows: int,
@@ -369,15 +509,23 @@ def both_forms(cfg: ModelConfig, kind: str, params, x, at, rows: int,
 
 
 class SwiGLU(nn.Module):
+    """``w2 (silu(w1 x) * (w3 x))``; ``mup`` (a family's
+    ``mlp_multipliers``, where not 1): the gate's argument and the
+    output each times one."""
+
     width: int
+    mup: tuple = (1.0, 1.0)
 
     @nn.compact
     def __call__(self, x):
         gate = Linear(self.width, name="w1")(x)
         up = Linear(self.width, name="w3")(x)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(x.dtype)
-        return Linear(x.shape[-1], name="w2")(act)
+        gate = gate.astype(jnp.float32)
+        if self.mup[0] != 1.0:
+            gate = gate * self.mup[0]
+        act = (jax.nn.silu(gate) * up.astype(jnp.float32)).astype(x.dtype)
+        out = Linear(x.shape[-1], name="w2")(act)
+        return out if self.mup[1] == 1.0 else scaled(out, self.mup[1])
 
 
 class SparseExperts(nn.Module):
@@ -453,6 +601,8 @@ class SparseExperts(nn.Module):
 
 
 ATTENTION_KINDS = ("full_attention", "sliding_attention")
+# The hybrid layer: a state-space mixer beside attention that sees all.
+HYBRID = "ssm_attention"
 
 
 class DecoderLayer(nn.Module):
@@ -472,10 +622,13 @@ class DecoderLayer(nn.Module):
     layer's keys and values), or the ``cache`` handed in with the
     new rows of each stream written at ``pos`` (the decode form, which
     latent and grouped-query attention have). A kind without a cache
-    returns None."""
+    returns None. A HYBRID layer's operator is two, side by side on the
+    one normed input and summed: the state-space mixer and attention
+    that sees all; its cache is attention's keys and values, then the
+    mixer's state and convolution inputs, four arrays."""
 
     cfg: ModelConfig
-    kind: str      # "conv" | "latent_attention" | ATTENTION_KINDS
+    kind: str      # "conv" | "latent_attention" | ATTENTION_KINDS | HYBRID
     sparse: bool
 
     def residual(self, name: str, h, f):
@@ -511,6 +664,15 @@ class DecoderLayer(nn.Module):
             if self.kind in ATTENTION_KINDS:
                 return after("op_post_norm", Attention(
                     cfg, self.kind, name="attn")(x, pos, cache, valid))
+            if self.kind == HYBRID:
+                with jax.named_scope("ssm_mixer"):
+                    mixed, held = Mamba2Mixer(cfg, name="mixer")(
+                        x, valid, None if cache is None else cache[2:])
+                attended, rows = Attention(cfg, self.kind, name="attn")(
+                    x, pos, None if cache is None else cache[:2], valid)
+                self.sow("intermediates", "branch_mixer", mixed)
+                self.sow("intermediates", "branch_attn", attended)
+                return mixed + attended, tuple(rows) + tuple(held)
             if cache is not None:
                 raise ValueError(f"layer type {self.kind!r} has no cache")
             if self.kind == "conv":
@@ -521,8 +683,10 @@ class DecoderLayer(nn.Module):
             x = norm("ffn_norm")(x)
             if self.sparse:
                 return after("ffn_post_norm", experts(x, valid, routing))
-            return after("ffn_post_norm", (
-                SwiGLU(cfg.lfm_ffn_dim, name="ffn")(x), None))
+            out = SwiGLU(cfg.lfm_ffn_dim, tuple(cfg.mup_mlp), name="ffn")(x)
+            if self.kind == HYBRID:
+                self.sow("intermediates", "branch_mlp", out)
+            return after("ffn_post_norm", (out, None))
 
         routing = None
         if self.sparse:
@@ -608,6 +772,8 @@ class LFM2ASR(nn.Module):
         """What enters the first layer, from an embedding or a
         projected frame: times sqrt(D) where the family scales its
         embedding (``lfm_embed_scale``)."""
+        if self.cfg.mup_embedding != 1.0:
+            x = scaled(x, self.cfg.mup_embedding)
         if not self.cfg.lfm_embed_scale:
             return x
         return x * jnp.asarray(self.cfg.lfm_hidden ** 0.5, x.dtype)
@@ -645,6 +811,8 @@ class LFM2ASR(nn.Module):
         (this chip's slice of the vocabulary)."""
         h, head, layout, counters = self.hidden(
             features, feat_lens, labels, label_lens)
+        if self.cfg.mup_lm_head != 1.0:      # on the logits
+            h = scaled(h, self.cfg.mup_lm_head)
         logp, mask = target_logp(h, head, layout, labels, label_lens)
         nll = -jnp.sum(logp * mask, axis=1)
         valid = layout["valid"]
@@ -700,9 +868,12 @@ class LFM2ASR(nn.Module):
         return rows, a_lens, stack_counters(counters), draft
 
     def logits(self, h):
-        """``h [N, D]`` (normed) against the head, float32."""
-        return jnp.dot(h, self.head().astype(h.dtype).T,
-                       preferred_element_type=jnp.float32)
+        """``h [N, D]`` (normed) against the head, float32 (times the
+        family's ``lm_head_multiplier`` where not 1)."""
+        out = jnp.dot(h, self.head().astype(h.dtype).T,
+                      preferred_element_type=jnp.float32)
+        by = self.cfg.mup_lm_head
+        return out if by == 1.0 else out * by
 
     def step(self, tokens, pos, active, cache):
         """The serving path's second half: one new position a stream
@@ -781,7 +952,7 @@ def stack_counters(counters: list) -> dict:
 def uncached_kinds(cfg: ModelConfig) -> list:
     """The preset's layer kinds that have no decode form yet."""
     return sorted(set(cfg.lfm_layer_types)
-                  - {"latent_attention", *ATTENTION_KINDS})
+                  - {"latent_attention", HYBRID, *ATTENTION_KINDS})
 
 
 def target_logp(h, embed, layout, labels, label_lens):

@@ -72,6 +72,16 @@ and, where layers are grouped-query attention with caches per kind
                                   passed the window (their rings wrapped)
   lm_cache_bytes_window / _global (gauges) the cache's bytes per kind
 
+and, where layers hold a recurrent state beside their cache rows (a
+state-space mixer beside attention):
+
+  lm_state_updates                (live stream, layer) states the decode
+                                  steps read once and wrote once in
+                                  place; a finished stream's is not
+                                  moved
+  lm_cache_bytes_state / _conv    (gauges) the cache's bytes in float32
+                                  states and in convolution inputs
+
 and, where the loop drafts for itself (``model.lm_draft_layers``):
 
   lm_verify_positions             positions the model ran in the steps
@@ -213,7 +223,8 @@ def observe_lm_call(prefill: Sequence[Dict], decode: Dict, rows: int
         reg.count("moe_empty_groups", out["empty_groups"]["decode"]
                   + out["empty_groups"]["prefill"])
     for k in ("rows_attended_window", "rows_attended_global",
-              "rows_fetched_window", "rows_fetched_global", "ring_wraps"):
+              "rows_fetched_window", "rows_fetched_global", "ring_wraps",
+              "state_updates"):
         if k in decode:
             out[k] = int(decode[k])
             reg.count("lm_" + k, out[k])

@@ -57,6 +57,16 @@ fact, not a name:
            stream (how many of them a stream VISITS follows its
            position and is counted by the loop:
            ``lm_rows_fetched_window`` / ``_global``)
+  b, s, heads, head, state, groups, chunk, chunks
+           ssd_chunk_scan: rows, positions, the mixer's heads, a head's
+           size, the state's size, groups that share B and C, positions
+           a chunk and chunks a row (the grid is b x heads x chunks;
+           how many positions are VALID follows the lengths and is
+           counted by the call: ``lm_valid_positions``)
+  b, heads, head, state, groups
+           ssd_state_step: streams, heads, a head's size, the state's
+           size and groups (the grid is b x groups; how many streams
+           are LIVE a step is counted by the loop: ``lm_state_updates``)
 """
 
 from __future__ import annotations
@@ -85,6 +95,8 @@ KERNELS = frozenset({
     "gqa_attn_bwd_dq",    # its backward: dq over a query tile's key tiles
     "gqa_attn_bwd_dkv",   # ... dk, dv over a key tile's query tiles and heads
     "gqa_attn_decode",    # one query a stream against its cache rows in reach
+    "ssd_chunk_scan",     # state-space recurrence over a sequence, in chunks
+    "ssd_state_step",     # ... one position a stream, the state in place
 })
 
 

@@ -547,6 +547,19 @@ class Trainer:
         if objective == "lm" and accum > 1:
             raise ValueError("objective='lm' excludes accum_steps>1 "
                              "(its step carries the routing counters)")
+        if objective == "lm":
+            from .models.lfm2 import HYBRID
+            from .ops import ssd_pallas
+
+            m = cfg.model
+            if HYBRID in m.lfm_layer_types and ssd_pallas.in_kernels(
+                    m.ssm_d_ssm // m.ssm_heads, m.ssm_state):
+                # Fail at construction, not in the first step's trace.
+                raise NotImplementedError(
+                    "objective='lm': the state-space mixer's sequence "
+                    "form runs here as the kernel ssd_chunk_scan, which "
+                    "has no backward pass yet; this preset is served "
+                    "(decode.mode='lm_greedy'), not trained")
         if objective == "lm" and eval_pipeline is not None:
             from .models.lfm2 import uncached_kinds
 
@@ -554,9 +567,10 @@ class Trainer:
                 # Fail at construction, not after an epoch of work.
                 raise ValueError(
                     "objective='lm': transcripts are decoded through a "
-                    "cache, which latent and grouped-query attention "
-                    "have; this preset's layers lack a convolution "
-                    "state, so no in-training eval: pass no "
+                    "cache, which latent attention, grouped-query "
+                    "attention and the hybrid of a state-space mixer "
+                    "beside attention have; this preset's layers lack a "
+                    "convolution state, so no in-training eval: pass no "
                     "eval_pipeline")
         stages = cfg.model.pipeline_stages
         if stages > 1:

@@ -127,6 +127,16 @@ CASES = {
          "key_tiles_in_reach": "171"},
         {"kernel": "gqa_attn_bwd_dq", **_ST_ATTN, "key_tiles": "171",
          "key_tiles_in_reach": "171", "key_tiles_masked": "38"}],
+    # falcon_h1_34b's state-space recurrence: a prefill sub-batch of one
+    # layer (212 positions in 2 chunks of 128) and a decode step of 128
+    # streams
+    "ssd_chunk_scan_falcon": [
+        {"kernel": "ssd_chunk_scan", "b": "32", "s": "212", "heads": "32",
+         "head": "128", "state": "256", "groups": "2", "chunk": "128",
+         "chunks": "2"}],
+    "ssd_state_step_falcon": [
+        {"kernel": "ssd_state_step", "b": "128", "heads": "32",
+         "head": "128", "state": "256", "groups": "2"}],
 }
 
 
@@ -294,7 +304,8 @@ def test_every_name_of_the_vocabulary_is_built_somewhere():
     for name, named in (("scan_pallas.py", r'="(\w+_scan_\w+)"'),
                         ("ctc_pallas.py", r'kernel="(\w+)"'),
                         ("moe_pallas.py", r'kernel="(\w+)"'),
-                        ("attn_pallas.py", r'kernel="(\w+)"')):
+                        ("attn_pallas.py", r'kernel="(\w+)"'),
+                        ("ssd_pallas.py", r'kernel="(\w+)"')):
         with open(os.path.join(REPO, "deepspeech_tpu", "ops", name)) as f:
             used.update(re.findall(named, f.read()))
     assert used == kernel_id.KERNELS

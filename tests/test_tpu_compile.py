@@ -106,6 +106,14 @@ def v5e_chip():
     # ... and its grouped products over 16 held experts, 61,440 rows
     ("moe_gmm_smallthinker_w13", ["moe_gmm", "moe_gmm", "moe_tgmm"]),
     ("moe_gmm_smallthinker_w2", ["moe_gmm", "moe_gmm", "moe_tgmm"]),
+    # falcon_h1_34b's state-space recurrence: a prefill sub-batch of 32
+    # utterances of 212 positions in 2 chunks of 128 (32 heads of 128,
+    # state 256: a [256, 128] float32 carry in VMEM, the chunk's decays
+    # as [128, 128], a row made a column by its diagonal), and a decode
+    # step of 128 streams: a group's 16 heads of state, 2.1 MB, a grid
+    # step in and out under a raised scoped-VMEM limit, aliased
+    ("ssd_chunk_scan_falcon", ["ssd_chunk_scan"]),
+    ("ssd_state_step_falcon", ["ssd_state_step"]),
 ])
 def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     """Mosaic accepts the kernel, and the compiled instruction still

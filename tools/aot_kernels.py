@@ -288,6 +288,34 @@ def kernel_cases():
         return lambda: (lambda q, k, v, pos, live: attn_pallas.gqa_decode(
             q, k, v, pos, live, window), args)
 
+    def ssd_scan_case():
+        """falcon_h1_34b's mixer over a prefill sub-batch of one layer:
+        ``ssd_chunk_scan`` alone, 32 utterances of 212 positions (2
+        chunks of 128, the second hanging over by 44), 32 heads of 128,
+        state 256, 2 groups."""
+        from deepspeech_tpu.ops import ssd_pallas
+
+        args = (S((32, 212, 32, 128), jnp.bfloat16),
+                S((32, 212, 32), jnp.float32), S((32,), jnp.float32),
+                S((32, 212, 2, 256), jnp.bfloat16),
+                S((32, 212, 2, 256), jnp.bfloat16), S((32,), jnp.float32),
+                S((32, 212), jnp.bool_))
+        return lambda: (ssd_pallas.chunk_scan, args)
+
+    def ssd_step_case(streams):
+        """... and one decode step of one layer: ``ssd_state_step``
+        alone, ``streams`` states of 32 x 256 x 128 float32 (4.2 MB
+        each), a group's 16 heads (2.1 MB) a grid step, in place."""
+        from deepspeech_tpu.ops import ssd_pallas
+
+        args = (S((streams, 32, 256, 128), jnp.float32),
+                S((streams, 32, 128), jnp.bfloat16),
+                S((streams, 32), jnp.float32), S((32,), jnp.float32),
+                S((streams, 2, 256), jnp.bfloat16),
+                S((streams, 2, 256), jnp.bfloat16), S((32,), jnp.float32),
+                S((streams,), jnp.bool_))
+        return lambda: (ssd_pallas.state_step, args)
+
     cases["gru_h800"] = gru_case(800)
     cases["gru_h1760"] = gru_case(1760)
     # ds2_full.train_1chip's own call (850 post-conv frames, bf16
@@ -366,6 +394,10 @@ def kernel_cases():
     cases["gqa_attn_train_smallthinker_global"] = attn_train_case(0)
     cases["moe_gmm_smallthinker_w13"] = moe_case(2560, 1536, 61440, 16)
     cases["moe_gmm_smallthinker_w2"] = moe_case(768, 2560, 61440, 16)
+    # falcon_h1_34b.transcribe_16s: the mixer's recurrence over a
+    # prefill sub-batch and in a decode step of 128 streams
+    cases["ssd_chunk_scan_falcon"] = ssd_scan_case()
+    cases["ssd_state_step_falcon"] = ssd_step_case(128)
     cases["ctc_aishell"] = ctc_case(4336, 400, 60)
     cases["ctc_en"] = ctc_case(29, 400, 160)
     # The weak-#1 shape: AISHELL-width device beam search, both merge

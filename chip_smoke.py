@@ -19,7 +19,11 @@ are cut:
              blocks): forward the same bits, gradients as near the XLA
              scan's as each other; that call's recurrent weight
              gradient at six, three and one bf16 passes against the
-             float64 sum of its operands (dw_h_precision)
+             float64 sum of its operands (dw_h_precision); a whole
+             layer of that call, both directions: the backward call
+             that sums the pair's input gradient against XLA's sum of
+             the two directions' float32 results, bit for bit, and the
+             three backward calls' device time (pair_input_grad)
   serve      ds2_streaming (uni-GRU 5x800 + lookahead 20): a checkpoint
              from two train steps, two generated wavs streamed chunk by
              chunk, finals compared with the offline decode of the same
@@ -105,6 +109,14 @@ SCAN_ORACLE_RTOL = {"dxproj": 1e-2, "dw_h": 0.2, "db_h": 2e-3}
 # first reading; the chip's readings: PERF.md section 6, PR 37.
 DW_H_LIMIT = 1e-4
 DW_H_TIMES_UNDER_NOISE = 20
+# A bf16 column sum over that call's T*B rows (the input projection's
+# bias gradient, which XLA reduces from the pair's ``dxp`` rounded to
+# bf16) against the float32 sum of the same bf16 values, over the
+# largest column: one rounding of the result is 2^-9; a sum CARRIED
+# in bf16 over 27,200 rows reads tenths.
+BF16_COLUMN_SUM_RTOL = 2.0 ** -7
+# Traced calls a side of pair_input_grad's timing.
+PAIR_TIMED_CALLS = 10
 # Streamed finals against the offline decode of the same audio: the
 # two graphs reduce in different orders in bf16, so an argmax near a
 # tie may flip; more than this is a wrong stream, not rounding.
@@ -393,6 +405,7 @@ def phase_reference() -> dict:
         out[f"gru_h{h}_rel_err"] = err
     out.update(scan_builds(interpret))
     out.update(dw_h_precision(interpret))
+    out.update(pair_input_grad(interpret))
     out.update(attention_forms())
     t, v, lmax = 100, 29, 20
     logits = jnp.asarray(rng.normal(size=(b, t, v)), jnp.float32)
@@ -601,6 +614,130 @@ def dw_h_precision(interpret: bool) -> dict:
     out = {"dw_h_rows": b * t}
     for name, row in {**rows, "bf16_to_float32": noise}.items():
         out.update({f"dw_h_{name}_{kind}": v for kind, v in row.items()})
+    return out
+
+
+def scan_bwd_ms(sides, calls: int) -> dict:
+    """Median device milliseconds of every backward scan kernel that
+    runs while each of ``sides`` (thunks) is called ``calls`` times in
+    turn under the profiler, by ``(reverse, sum)`` of the call's
+    ``kernel_metadata`` (``sum``: ``pair`` or, where the fact is
+    absent, ``own``). {} where the trace holds no TPU plane (the CPU
+    rehearsal)."""
+    import glob
+    import statistics
+
+    import jax
+
+    from benchmark.layer_metrics._kernel_id import is_scan_bwd, kernel_facts
+    from benchmark.reduce import xplane
+
+    seen = {}
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        try:
+            for _ in range(calls):
+                for side in sides:
+                    jax.block_until_ready(side())
+        finally:
+            jax.profiler.stop_trace()
+        for path in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                              recursive=True):
+            for device in xplane.load(path).devices.values():
+                for start, end, name in device.ops:
+                    facts = kernel_facts(name)
+                    if is_scan_bwd(facts.get("kernel", "")):
+                        seen.setdefault(
+                            (facts["reverse"], facts.get("sum", "own")),
+                            []).append((end - start) / 1e6)
+    return {key: statistics.median(ms) for key, ms in seen.items()}
+
+
+def pair_input_grad(interpret: bool) -> dict:
+    """A whole bidirectional layer of ds2_full's scan call
+    (``SCAN_CALL``, H=1760, bf16 ``xproj``) backward, two ways on the
+    same inputs. As two functions (``gru_scan_pallas`` a direction):
+    two backward calls that each write their own float32 ``dxp``, and
+    XLA's ``(a + b).astype(bfloat16)`` of the two. As one
+    (``gru_scan_pair_pallas``): the forward direction's call, and the
+    reverse direction's taking its rows in and writing the float32
+    sum, which the VJP rounds to bf16. The summed ``dxp`` must be
+    XLA's bit for bit (summed in float32, rounded once). Beside it: how many values of the four weight and
+    bias gradients differ between the two programs (the same kernels
+    and contractions: 0), the bf16 column sum XLA makes of that ``dxp``
+    for the projection's bias gradient against the float32 sum of the
+    same values (``BF16_COLUMN_SUM_RTOL``, held on the chip: XLA's CPU
+    compiler carries such a sum in bf16), and the device time of the
+    three backward calls, the two programs called in turn
+    ``PAIR_TIMED_CALLS`` times under the profiler: today's reverse
+    call (``own``), the forward direction's (``first``, the same call
+    in both programs) and the summing one. Off the chip the times are
+    None: not measured."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeech_tpu.ops import rnn_pallas
+
+    (b, t), h = SCAN_CALL, 1760
+    rng = np.random.default_rng(50)
+    xp = jnp.asarray(rng.normal(size=(b, t, 3 * h)), jnp.bfloat16)
+    weights = [jnp.asarray(a, jnp.float32) for _ in range(2) for a in (
+        rng.normal(size=(h, 3 * h)) / np.sqrt(h),
+        rng.normal(size=(3 * h,)) * 0.1)]
+    lens = rng.integers(t * 12 // 17, t + 1, size=b)  # the cell's 12-17 s
+    mask = jnp.asarray(np.arange(t)[None] < lens[:, None], jnp.float32)
+    dy = jnp.asarray(rng.normal(size=(b, t, h)) * 0.1, jnp.float32)
+
+    @jax.jit
+    def apart(x, w_f, b_f, w_b, b_b):
+        grads = []
+        for reverse, w, bias in ((False, w_f, b_f), (True, w_b, b_b)):
+            _, pull = jax.vjp(lambda x, w, bias: rnn_pallas.gru_scan_pallas(
+                x, mask, w, bias, reverse, interpret, "bfloat16"),
+                x, w, bias)
+            grads.append(pull(dy))
+        (dxp_f, *fw), (dxp_b, *bw) = grads
+        return ((dxp_f + dxp_b).astype(x.dtype), *fw, *bw)
+
+    @jax.jit
+    def as_one(x, *w):
+        _, pull = jax.vjp(lambda x, *w: rnn_pallas.gru_scan_pair_pallas(
+            x, mask, *w, interpret, "bfloat16"), x, *w)
+        return pull(dy)
+
+    want, got = apart(xp, *weights), as_one(xp, *weights)
+    if not got[0].dtype == want[0].dtype == jnp.bfloat16:
+        fail(f"the pair's dxp is {got[0].dtype}, XLA's sum {want[0].dtype}: "
+             f"both are to be xproj's bfloat16")
+    names = ("dxproj", "dw_f", "db_f", "dw_b", "db_b")
+    differing = {name: int(jnp.sum(a != w))
+                 for name, a, w in zip(names, got, want)}
+    if differing["dxproj"]:
+        fail(f"GRU H={h}: the pair's summed dxp differs from XLA's "
+             f"(a + b).astype(bfloat16) of the two directions' float32 "
+             f"results in {differing['dxproj']} of {got[0].size} values")
+    # the projection's bias gradient as flax's Dense transposes it: a
+    # reduce_sum of the bf16 cotangent, in bf16 as far as the HLO says
+    columns = jax.jit(lambda d: (
+        jax.lax.reduce_sum(d, axes=(0, 1)).astype(jnp.float32),
+        jnp.sum(d.astype(jnp.float32), axis=(0, 1))))(got[0])
+    bf16_sum, f32_sum = (np.asarray(c, np.float64) for c in columns)
+    column_err = float(np.abs(bf16_sum - f32_sum).max()
+                       / np.abs(f32_sum).max())
+    if not interpret and not column_err <= BF16_COLUMN_SUM_RTOL:
+        fail(f"the bf16 column sum over {b * t} rows of dxp lies "
+             f"{column_err} of the largest column from the float32 sum "
+             f"of the same values: it is not carried in float32")
+    ms = scan_bwd_ms([lambda: apart(xp, *weights),
+                      lambda: as_one(xp, *weights)], PAIR_TIMED_CALLS)
+    out = {"pair_dxp_values": int(got[0].size),
+           "pair_bf16_column_sum_rel_err": column_err}
+    out.update({f"pair_{name}_differing": n
+                for name, n in differing.items()})
+    for name, key in (("first", ("0", "own")), ("own", ("1", "own")),
+                      ("summing", ("1", "pair"))):
+        out[f"pair_bwd_{name}_ms"] = ms.get(key)
     return out
 
 

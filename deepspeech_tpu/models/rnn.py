@@ -282,23 +282,30 @@ def layer_scan_route(cfg: ModelConfig, rows=None, *, mesh=None,
         dot_bytes=jnp.dtype(cfg.dtype).itemsize, **facts)
 
 
-def _scan_kernel(kernel: str):
+def _scan_kernel(kernel: str, pair: bool = False):
     """The function of ``ops/`` that builds the forward kernel a route
     names, called as ``f(xproj, mask, *weights, [reverse,] interpret,
-    dot_dtype)``."""
+    dot_dtype)``; with ``pair`` the one that runs a layer's two
+    directions where the route over both names that kernel (both
+    weight sets, no ``reverse``), or None where there is none."""
     from ..ops import lstm_pallas, rnn_pallas
 
+    if pair:
+        return {"bigru_scan_fwd": rnn_pallas.bigru_scan_pallas,
+                "gru_scan_fwd": rnn_pallas.gru_scan_pair_pallas,
+                "lstm_scan_fwd": lstm_pallas.lstm_scan_pair_pallas,
+                }.get(kernel)
     return {"gru_scan_fwd": rnn_pallas.gru_scan_pallas,
             "gru_scan_q_fwd": rnn_pallas.gru_scan_pallas_q,
-            "bigru_scan_fwd": rnn_pallas.bigru_scan_pallas,
             "lstm_scan_fwd": lstm_pallas.lstm_scan_pallas,
             "lstm_scan_q_fwd": lstm_pallas.lstm_scan_pallas_q,
             "lstmp_scan_fwd": lstm_pallas.lstmp_scan_pallas}[kernel]
 
 
 def _run_kernel(cfg: ModelConfig, kernel: str, mesh, xproj, mask, *weights,
-                reverse=None):
-    """The routed kernel over this layer's operands. On a multi-device
+                reverse=None, pair: bool = False):
+    """The routed kernel over this layer's operands (``pair``: over
+    both directions' weights, :func:`_scan_kernel`). On a multi-device
     mesh it partitions over the data axis via shard_map (batch args
     sharded, weights replicated); single-device meshes pass through
     untouched."""
@@ -307,7 +314,7 @@ def _run_kernel(cfg: ModelConfig, kernel: str, mesh, xproj, mask, *weights,
 
     tail = (() if reverse is None else (reverse,)) + (
         interpret_default(), _pallas_dot_dtype(jnp.dtype(cfg.dtype)))
-    fn = _scan_kernel(kernel)
+    fn = _scan_kernel(kernel, pair)
     return shard_batchwise(lambda xp, m, *w: fn(xp, m, *w, *tail), mesh,
                            n_sharded=2)(xproj, mask, *weights)
 
@@ -442,19 +449,27 @@ def _run_direction(cfg: ModelConfig, xproj, mask, w_h, b_h, reverse,
 def _run_stack_dirs(cfg: ModelConfig, xproj, mask, params, mesh=None):
     """Run the direction set of one layer; ``params[rev] = (w_h, b_h)``.
 
-    Fast path: a bidirectional float GRU whose TWO weight sets fit VMEM
-    together is routed to ONE fused kernel
-    (ops/rnn_pallas.bigru_scan_pallas) — the independent per-step
-    matmuls of the two directions hide each other's latency instead of
-    serializing as two kernels. Everything else composes per direction.
+    Two float directions run as ONE function of the layer's ``xproj``,
+    from what the route names for them. Where both weight sets fit
+    VMEM together, ONE fused kernel (ops/rnn_pallas.bigru_scan_pallas):
+    the independent per-step matmuls of the two directions hide each
+    other's latency instead of serializing as two kernels. Where the
+    route names a one-direction kernel (a matrix copied once or
+    streamed: ds2_full), that kernel's pair function
+    (ops/scan_pallas.scan_pair_vjp), under ONE ``shard_batchwise``:
+    the forward calls are the two a direction loop makes, and backward
+    the forward direction's call hands its float32 ``dxp`` to the
+    reverse direction's, which writes the two's float32 sum, so no
+    pass outside the kernels adds two ``[B, T, G*H]`` cotangents. One direction, int8 leaves and the XLA scan compose
+    per direction.
     """
     if len(params) == 2 and not any(_is_qdict(w) for w, _ in params.values()):
         route = layer_scan_route(cfg, xproj.shape[0], mesh=mesh,
                                  directions=2,
                                  xproj_bytes=xproj.dtype.itemsize)
-        if route.kernel == "bigru_scan_fwd":
+        if _scan_kernel(route.kernel, pair=True) is not None:
             return _run_kernel(cfg, route.kernel, mesh, xproj, mask,
-                               *params[False], *params[True])
+                               *params[False], *params[True], pair=True)
     out = None
     for rev, (w_h, b_h) in params.items():
         ys = _run_direction(cfg, xproj, mask, w_h, b_h, rev, mesh=mesh)

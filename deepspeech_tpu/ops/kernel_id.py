@@ -27,6 +27,11 @@ fact, not a name:
            0; ``both`` for the fused bidirectional kernels
   t, b, h  steps, batch rows and hidden width of the call
   gates    3 (GRU) or 4 (LSTM)
+  sum      ``pair`` on the ONE backward call of a bidirectional layer
+           (``*_scan_bwd``, the reverse direction's) that takes the
+           other direction's float32 ``dxp`` rows in and writes the
+           two's sum as its own ``dxp``
+           (``scan_pallas.scan_pair_vjp``); absent on every other call
   p        lstmp_scan_*: width of the recurrent projection
   t, b, s  CTC: frames, padded batch rows, padded extended labels
   m, k, n, groups

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .scan_pallas import (ScanCell, dot_jnp_dtype, own_route, prev_sequence,
-                          scan_call, scan_forward, scan_vjp,
+                          scan_call, scan_forward, scan_pair_vjp, scan_vjp,
                           time_index_maps, time_major)
 
 
@@ -98,6 +98,11 @@ def lstm_scan_pallas(xproj: jnp.ndarray, mask: jnp.ndarray,
 
 
 lstm_scan_pallas.defvjp(*scan_vjp(LSTM))
+
+
+# Both directions of a bidirectional LSTM layer, summed [B, T, H], as
+# rnn_pallas.gru_scan_pair_pallas.
+lstm_scan_pair_pallas = scan_pair_vjp(LSTM)
 
 
 def lstm_scan_pallas_q(xproj: jnp.ndarray, mask: jnp.ndarray,

@@ -76,6 +76,16 @@ elementwise update after the gates is compiled apart in the two
 programs. Inference-only (no vjp): PTQ serves decode, training stays on
 the full-precision kernels.
 
+**Both directions of a layer** run as one function of the layer's
+``xproj``. Where both matrices fit VMEM together: ONE kernel
+(``bigru_scan_pallas``, below). Where they do not (the flagship):
+``gru_scan_pair_pallas`` (``scan_pallas.scan_pair_vjp``), the two
+forward calls one after the other, and backward the forward
+direction's call handing its float32 ``dxp`` rows to the reverse
+direction's, which adds its own while they are in VMEM and writes the
+ONE float32 sum (the VJP rounds it to ``xproj``'s dtype): no pass
+outside the kernels adds the two directions' ``[T, b, 3H]`` gradients.
+
 Contract matches ``models.rnn.gru_scan`` (the XLA-scan oracle):
 ``(xproj [B,T,3H] incl. b_x, mask [B,T], w_h [H,3H], b_h [3H],
 reverse) -> ys [B,T,H] float32``. Direction is implemented purely in
@@ -98,7 +108,7 @@ from jax.experimental import pallas as pl
 
 from . import scan_pallas
 from .scan_pallas import (ScanCell, dot_jnp_dtype, own_route, prev_sequence,
-                          scan_call, scan_forward, scan_vjp,
+                          scan_call, scan_forward, scan_pair_vjp, scan_vjp,
                           time_index_maps, time_major)
 
 
@@ -155,6 +165,14 @@ def gru_scan_pallas(xproj: jnp.ndarray, mask: jnp.ndarray,
 
 
 gru_scan_pallas.defvjp(*scan_vjp(GRU))
+
+
+# Both directions of a bidirectional GRU layer whose matrices do not
+# fit VMEM together, summed [B, T, H]: gru_scan_pallas's two forward
+# calls as ONE function, so that its VJP's two backward calls make
+# xproj's gradient between them. (xproj, mask, w_f, b_f, w_b, b_b,
+# interpret, dot_dtype).
+gru_scan_pair_pallas = scan_pair_vjp(GRU)
 
 
 def gru_scan_pallas_stream(xproj: jnp.ndarray, mask: jnp.ndarray,

@@ -111,7 +111,8 @@ def _bias_grad_rows(rows: int) -> int:
 
 
 def _pinned_vmem_limit(rows: int, hidden: int, cell: _Cell, dot_bytes: int,
-                       xproj_bytes: int, backward: bool) -> Optional[int]:
+                       xproj_bytes: int, backward: bool,
+                       sums_pair: bool = False) -> Optional[int]:
     """The scoped-VMEM limit a copy-once call asks for, or None when it
     would reach :data:`PINNED_VMEM_CAP`. What the call holds: ONE copy
     of the matrix (its rows as wide as VMEM's lanes make them), its
@@ -121,7 +122,9 @@ def _pinned_vmem_limit(rows: int, hidden: int, cell: _Cell, dot_bytes: int,
     since one matmul makes it); a quarter on top for the gate
     math's other temporaries, rounded up to 4 MiB and never under
     Mosaic's default of 16 MiB. ds2_full (H=1760, bf16, 18.6 MB of
-    weights) at b=32 / 64: forward 28 / 28 MiB, backward 32 / 40 MiB."""
+    weights) at b=32 / 64: forward 28 / 28 MiB, backward 32 / 40 MiB;
+    where the call sums the pair (``sums_pair``: the other direction's
+    float32 ``dxp`` row comes in too, 0.68 MB twice at b=32) 32 / 44."""
     wide = cell.gates * hidden
     lanes = pl.cdiv(wide, 128) * 128
     # per-step operands (xproj, mask; backward: each state's previous
@@ -134,6 +137,8 @@ def _pinned_vmem_limit(rows: int, hidden: int, cell: _Cell, dot_bytes: int,
         ins += [(hidden, 4)] * (cell.states + 1)
         outs = [wide, wide, hidden]  # dxp, dgates and the previous state
         whole = 2  # and the bias gradient's accumulator, [1 or 8, wide]
+        if sums_pair:
+            ins += [(wide, 4)]
     row_bytes = (sum(rows * max(w, 128) * n for w, n in ins)
                  + whole * 8 * lanes * 4 + sum(rows * w * 4 for w in outs))
     scratch_bytes = rows * 4 * (cell.states * hidden + lanes)
@@ -162,7 +167,8 @@ def scan_route(cell: str, impl: str, *, hidden: int,
                dot_bytes: int = 4,
                xproj_bytes: Optional[int] = None, int8: bool = False,
                carry: bool = False, directions: int = 1,
-               backward: bool = False) -> ScanRoute:
+               backward: bool = False,
+               sums_pair: bool = False) -> ScanRoute:
     """Which kernel runs one layer's recurrence, in which build, from
     what the call can observe. ALL of the choice is here: the callers
     (``models/rnn.py``, ``streaming.py``, ``utils/quantize.py``,
@@ -186,6 +192,10 @@ def scan_route(cell: str, impl: str, *, hidden: int,
     ``directions``   2: both directions' weights are present and float
     ``backward``     the BPTT call of the same layer (a kernel of its
                      own, with more rows a step: its build may differ)
+    ``sums_pair``    that BPTT call also takes the other direction's
+                     ``dxp`` rows in and writes the pair's sum
+                     (:func:`scan_pair_vjp`): one more row a step,
+                     counted in a copy-once limit
     """
     if impl != "pallas":
         return XLA_SCAN
@@ -231,7 +241,8 @@ def scan_route(cell: str, impl: str, *, hidden: int,
     if facts.copy_once:
         limit = _pinned_vmem_limit(
             known_rows(), hidden, facts, dot_bytes,
-            dot_bytes if xproj_bytes is None else xproj_bytes, backward)
+            dot_bytes if xproj_bytes is None else xproj_bytes, backward,
+            sums_pair)
         if limit is not None:
             return ScanRoute(kernel, "pinned", limit)
     return ScanRoute(kernel, "blocked")
@@ -367,9 +378,10 @@ def scan_call(body, route: ScanRoute, *, reverse, hidden: int, gates: int,
     The body's refs come in this order, and after the scratches a
     ``pinned`` build's matrix scratch and DMA semaphore. A streamed
     build's body is also handed ``n_blocks`` and ``c``. Returns the
-    list of results, per-step ones first. What only lstmp sets today:
-    ``more_facts`` (its ``p``), ``buffer_weights_once`` (whole blocks
-    under ``pl.Buffered(1)``) and ``dimension_semantics``.
+    list of results, per-step ones first. ``more_facts``: lstmp's
+    ``p``, the pair's ``sum``. What only lstmp sets today:
+    ``buffer_weights_once`` (whole blocks under ``pl.Buffered(1)``)
+    and ``dimension_semantics``.
     """
     t_max, b = rows[0][0].shape[:2]
     whole = lambda shape, **kw: pl.BlockSpec(
@@ -546,8 +558,8 @@ def _fwd_step(cell: ScanCell, variant: str, n_weights: int, carried: bool,
         update(lambda: gates)
 
 
-def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
-              c: int = 0):
+def _bwd_step(cell: ScanCell, variant: str, sums_pair: bool, *refs,
+              n_blocks: int = 1, c: int = 0):
     """One reverse-time BPTT grid step (flash-style gate recompute):
     carries the states' gradients across steps and recomputes the gates
     from (previous states, xproj, W) rather than storing them. Streams
@@ -567,11 +579,28 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
     state sequence (a slice and a layout turn of 191 MB, 14 times a
     step of ds2_full: PERF.md section 6, PR 48).
 
-    refs: xproj row, mask row, each state's previous row, dy row, W,
-    bias, dxp row, dgates row, h_prev row (a ``(1, b, H)`` block of
-    ``[T, b, H]`` or the ``(b, H)`` block of a flat result), db, the
-    state gradients' scratches, [streamed: dh_acc, gates_buf,
-    dg_prev], [pinned: matrix scratch, semaphore].
+    ``sums_pair``: this is the second backward call of a bidirectional
+    layer's pair (:func:`scan_pair_vjp`). The first call's float32
+    ``dxp`` row of the same frames comes in as one more per-step
+    operand, and the ``dxp`` row that goes out is the input
+    projection's whole gradient, ``other + own`` in float32: the sum
+    XLA otherwise makes in a pass of its own over both float32
+    ``[T, b, G*H]`` arrays (2 x 574 MB read seven times a step of
+    ds2_full, and once more by the bias gradient it was fused into:
+    PERF.md section 6, PR 50). The result stays float32
+    ``[T, b, G*H]``, as every backward call's (the cast to the
+    projection's dtype is the VJP's): a call that writes it in bf16
+    saves XLA that pass too, but leaves the reader of the scans'
+    roofline, which knows a backward call by its two float32 results
+    of that shape, blind to it (PERF.md section 7). Nothing else of
+    the step differs.
+
+    refs: xproj row, mask row, each state's previous row, dy row,
+    [``sums_pair``: the other direction's dxp row], W, bias, dxp row,
+    dgates row, h_prev row (a ``(1, b, H)`` block of ``[T, b, H]`` or
+    the ``(b, H)`` block of a flat result), db, the state gradients'
+    scratches, [streamed: dh_acc, gates_buf, dg_prev], [pinned: matrix
+    scratch, semaphore].
 
     Resident and copy-once: the step's ``dgates @ W^T`` goes into the
     carried gradient straight away (the matrix is whole in VMEM; read
@@ -586,9 +615,10 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
     last block."""
     n = _CELLS[cell.name].states
     (xp_ref, mask_ref), prev_refs = refs[:2], refs[2:2 + n]
-    (dy_ref, w_ref, b_ref, dxp_ref, dgates_ref, hprev_ref,
-     db_ref) = refs[2 + n:9 + n]
-    scratch = refs[9 + n:]
+    dy_ref, *refs = refs[2 + n:]
+    other_dxp_ref = refs.pop(0) if sums_pair else None
+    w_ref, b_ref, dxp_ref, dgates_ref, hprev_ref, db_ref = refs[:6]
+    scratch = refs[6:]
     if variant == "pinned":
         *scratch, w_scr, sem = scratch
         _copy_weights_once(w_ref, w_scr, sem)
@@ -624,6 +654,8 @@ def _bwd_step(cell: ScanCell, variant: str, *refs, n_blocks: int = 1,
         dxp, dgates, dprev = cell.bwd(
             xp_ref[0], gates(), prevs, mask_ref[0],
             (dfirst(),) + tuple(d[:] for d in dstate_refs[1:]), dy_ref[0])
+        if sums_pair:
+            dxp = other_dxp_ref[0] + dxp
         dxp_ref[0] = dxp
         dgates_ref[0] = dgates
         if len(hprev_ref.shape) == 3:  # a row of [T, b, H]
@@ -711,20 +743,104 @@ def scan_forward(cell: ScanCell, xproj, mask, w, b_h, *, reverse=False,
     return results, xp_t, mask_t
 
 
-def scan_vjp(cell: ScanCell):
-    """The ``custom_vjp`` pair of a gated cell's
-    ``f(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype)``. The
-    backward call hands back what it holds in VMEM beside ``dxp``: both
+def _scan_backward(cell: ScanCell, residuals, dy_t, *, reverse, interpret,
+                   dot_dtype, other_dxp_t=None):
+    """The backward call of one direction and what the VJP makes of its
+    results: ``(dxp_t [T, b, G*H], dW_h, db_h)`` from the forward
+    call's ``residuals`` and the time-major float32 cotangent ``dy_t``.
+    The call hands back what it holds in VMEM beside ``dxp``: both
     operands of ``dW_h`` (``dgates`` and ``h_prev``, the first state
     one scan step back from the zero state: :func:`prev_sequence`'s
     rows bit for bit, as ``[T * b, H]`` where the contraction then
-    takes both as they lie) and the bias gradient's sums. While jax
-    traces it, it is recorded as the gauges ``scan_bias_grad{kernel,
-    variant, form}``, ``form`` the accumulator's (``rows8`` /
-    ``rows1``), and ``scan_prev_state{kernel, variant,
-    source="kernel", rows}``, ``rows`` how ``h_prev`` is laid out
-    (``flat`` / ``stepped``)."""
+    takes both as they lie) and the bias gradient's sums.
+
+    ``other_dxp_t``: the float32 ``dxp_t`` of the layer's other
+    direction. The call then takes its rows in beside its own
+    (``_bwd_step``'s ``sums_pair``) and its ``dxp_t`` is the pair's
+    sum; without it, its own. Float32 either way.
+
+    While jax traces it, it is recorded as the gauges
+    ``scan_bias_grad{kernel, variant, form}``, ``form`` the
+    accumulator's (``rows8`` / ``rows1``), ``scan_prev_state{kernel,
+    variant, source="kernel", rows}``, ``rows`` how ``h_prev`` is laid
+    out (``flat`` / ``stepped``), and ``scan_input_grad{kernel,
+    variant, sum, dtype}``: whose ``dxp`` the call writes (``pair`` /
+    ``own``) and in what type (float32 today)."""
     gates, n = _CELLS[cell.name].gates, _CELLS[cell.name].states
+    xp_t, mask_t, w_h, b_h, *seqs = residuals
+    t_max, b, h = seqs[0].shape
+    dot = dot_jnp_dtype(dot_dtype)
+    sums_pair = other_dxp_t is not None
+    bh2 = b_h.astype(jnp.float32).reshape(1, gates * h)
+    w = w_h.astype(dot)
+    _, at_bptt, at_prev = time_index_maps(t_max, reverse)
+    route = own_route(
+        "bwd", cell.name, rows=b, hidden=h,
+        dot_bytes=jnp.dtype(dot).itemsize,
+        xproj_bytes=xp_t.dtype.itemsize, backward=True,
+        sums_pair=sums_pair)
+    streamed = route.variant == "blocked"
+    db_rows = _bias_grad_rows(b)
+    # Whole sublane tiles of rows: h_prev leaves the kernel flat,
+    # [T * b, H], a step's rows one block of it, and XLA contracts
+    # it with dgates' same rows (a bitcast) over T * b as they lie.
+    # Handed two [T, b, .] arrays it contracts over T with b as a
+    # window and turns both operands batch-major first, on the
+    # contraction (+0.84 ms a call at ds2_full's shape) or as a
+    # pass of its own, and two reshapes outside the kernel it folds
+    # back into that form (PERF.md section 6, PR 48). dgates stays
+    # [T, b, G*H]: a backward scan call is known by its two results
+    # of that shape (benchmark/layer_metrics/rnn_scan_roofline.py),
+    # the call that sums the pair too.
+    flat = not b % 8
+    build = {"kernel": route.kernel, "variant": route.variant}
+    obs.registry().gauge("scan_bias_grad", 1, labels={
+        **build, "form": f"rows{db_rows}"})
+    obs.registry().gauge("scan_prev_state", 1, labels={
+        **build, "source": "kernel",
+        "rows": "flat" if flat else "stepped"})
+    obs.registry().gauge("scan_input_grad", 1, labels={
+        **build, "sum": "pair" if sums_pair else "own",
+        "dtype": "float32"})
+    dxp_t, dgates_t, h_prev_t, db = scan_call(
+        functools.partial(_bwd_step, cell, route.variant, sums_pair), route,
+        reverse=reverse, hidden=h, gates=gates,
+        rows=([(xp_t, at_bptt), (mask_t, at_bptt)]
+              + [(s, at_prev) for s in seqs] + [(dy_t, at_bptt)]
+              + ([(other_dxp_t, at_bptt)] if sums_pair else [])),
+        weights=[w, bh2],
+        outs=([(gates * h, jnp.float32, at_bptt)] * 2
+              + [(h, jnp.float32, at_bptt, flat)]),
+        whole_outs=[(db_rows, gates * h)],
+        scratch=lambda cols: [h] * n + (
+            [h, cols, cols] if streamed else []),
+        interpret=interpret,
+        more_facts={"sum": "pair"} if sums_pair else None)
+    # One big MXU contraction instead of a per-step VMEM
+    # accumulator, from float32 operands whatever the dot type: at
+    # dot_dtype=bf16 the ORACLE's dW is the noisy one (it rounds
+    # h_prev to bf16 in its per-step outer products, rel err ~3e-2
+    # vs f32 truth; tests/test_pallas.py
+    # test_gru_bf16_dw_closer_to_truth_than_oracle) while this
+    # contraction stays ~2e-3, which is the recurrence's own bf16
+    # noise and not the contraction's (recurrent_dw).
+    dw_h = recurrent_dw(h_prev_t, dgates_t.reshape(
+        h_prev_t.shape[:-1] + (gates * h,)), dot)
+    return (dxp_t, dw_h.astype(w_h.dtype),
+            jnp.sum(db, axis=0).astype(b_h.dtype))
+
+
+def _mask_cotangent(mask_t):
+    return jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1)
+
+
+def scan_vjp(cell: ScanCell):
+    """The ``custom_vjp`` pair of a gated cell's
+    ``f(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype)``: the
+    taped forward call, and :func:`_scan_backward`'s one call, whose
+    float32 ``dxp`` goes back batch-major as it is. A layer with two
+    directions does not come here (jax would add the two functions'
+    ``dxp`` in a pass of its own): :func:`scan_pair_vjp`."""
 
     def fwd(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype):
         seqs, xp_t, mask_t = scan_forward(
@@ -733,61 +849,74 @@ def scan_vjp(cell: ScanCell):
         return jnp.moveaxis(seqs[0], 0, 1), (xp_t, mask_t, w_h, b_h, *seqs)
 
     def bwd(reverse, interpret, dot_dtype, residuals, dy):
-        xp_t, mask_t, w_h, b_h, *seqs = residuals
-        t_max, b, h = seqs[0].shape
-        dot = dot_jnp_dtype(dot_dtype)
         dy_t = jnp.moveaxis(dy.astype(jnp.float32), 1, 0)  # [T, B, H]
-        bh2 = b_h.astype(jnp.float32).reshape(1, gates * h)
-        w = w_h.astype(dot)
-        _, at_bptt, at_prev = time_index_maps(t_max, reverse)
-        route = own_route(
-            "bwd", cell.name, rows=b, hidden=h,
-            dot_bytes=jnp.dtype(dot).itemsize,
-            xproj_bytes=xp_t.dtype.itemsize, backward=True)
-        streamed = route.variant == "blocked"
-        db_rows = _bias_grad_rows(b)
-        # Whole sublane tiles of rows: h_prev leaves the kernel flat,
-        # [T * b, H], a step's rows one block of it, and XLA contracts
-        # it with dgates' same rows (a bitcast) over T * b as they lie.
-        # Handed two [T, b, .] arrays it contracts over T with b as a
-        # window and turns both operands batch-major first, on the
-        # contraction (+0.84 ms a call at ds2_full's shape) or as a
-        # pass of its own, and two reshapes outside the kernel it folds
-        # back into that form (PERF.md section 6, PR 48). dgates stays
-        # [T, b, G*H]: a backward scan call is known by its two results
-        # of that shape (benchmark/layer_metrics/rnn_scan_roofline.py).
-        flat = not b % 8
-        build = {"kernel": route.kernel, "variant": route.variant}
-        obs.registry().gauge("scan_bias_grad", 1, labels={
-            **build, "form": f"rows{db_rows}"})
-        obs.registry().gauge("scan_prev_state", 1, labels={
-            **build, "source": "kernel",
-            "rows": "flat" if flat else "stepped"})
-        dxp_t, dgates_t, h_prev_t, db = scan_call(
-            functools.partial(_bwd_step, cell, route.variant), route,
-            reverse=reverse, hidden=h, gates=gates,
-            rows=([(xp_t, at_bptt), (mask_t, at_bptt)]
-                  + [(s, at_prev) for s in seqs] + [(dy_t, at_bptt)]),
-            weights=[w, bh2],
-            outs=([(gates * h, jnp.float32, at_bptt)] * 2
-                  + [(h, jnp.float32, at_bptt, flat)]),
-            whole_outs=[(db_rows, gates * h)],
-            scratch=lambda cols: [h] * n + (
-                [h, cols, cols] if streamed else []),
-            interpret=interpret)
-        # One big MXU contraction instead of a per-step VMEM
-        # accumulator, from float32 operands whatever the dot type: at
-        # dot_dtype=bf16 the ORACLE's dW is the noisy one (it rounds
-        # h_prev to bf16 in its per-step outer products, rel err ~3e-2
-        # vs f32 truth; tests/test_pallas.py
-        # test_gru_bf16_dw_closer_to_truth_than_oracle) while this
-        # contraction stays ~2e-3, which is the recurrence's own bf16
-        # noise and not the contraction's (recurrent_dw).
-        dw_h = recurrent_dw(h_prev_t, dgates_t.reshape(
-            h_prev_t.shape[:-1] + (gates * h,)), dot)
-        dxp = jnp.moveaxis(dxp_t, 0, 1)  # [B, T, G*H]
-        return (dxp, jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1),
-                dw_h.astype(w_h.dtype),
-                jnp.sum(db, axis=0).astype(b_h.dtype))
+        dxp_t, dw_h, db_h = _scan_backward(
+            cell, residuals, dy_t, reverse=reverse, interpret=interpret,
+            dot_dtype=dot_dtype)
+        return (jnp.moveaxis(dxp_t, 0, 1),  # [B, T, G*H]
+                _mask_cotangent(residuals[1]), dw_h, db_h)
 
     return fwd, bwd
+
+
+def scan_pair_vjp(cell: ScanCell):
+    """A gated cell's two directions over the SAME ``xproj`` as ONE
+    function under its own ``custom_vjp``: ``f(xproj, mask, w_f, b_f,
+    w_b, b_b, interpret=False, dot_dtype=None) -> ys_f + ys_b
+    [B, T, H]``. Forward it is the two calls a one-direction layer
+    makes, one a direction (same kernels, facts and residuals).
+    Backward, its two calls make the input projection's gradient
+    between them. The first (the forward direction's) is a
+    one-direction layer's call and hands its float32 ``dxp_t
+    [T, b, G*H]`` to the second; the second reads those rows under its
+    own time map (both maps index frames, so the same rows meet
+    although the two calls run through time in opposite orders), adds
+    its own and writes the one float32 sum, which the VJP rounds once
+    to ``xproj``'s dtype: what the program states with two functions
+    (``add_any`` of the two float32 cotangents, then the cast to the
+    projection's dtype), without the pass over 2 x ``[T, b, G*H]``
+    float32 that XLA ran for the sum. Each direction's ``dgates``,
+    ``h_prev``, ``db``, ``dW_h`` and ``db_h`` are a one-direction
+    layer's bit for bit."""
+
+    def both(xproj, mask, w_f, b_f, w_b, b_b, tape, **kw):
+        seqs_f, xp_t, mask_t = scan_forward(
+            cell, xproj, mask, w_f, b_f, reverse=False, tape=tape, **kw)
+        seqs_b, _, _ = scan_forward(
+            cell, xproj, mask, w_b, b_b, reverse=True, tape=tape, **kw)
+        ys = jnp.moveaxis(seqs_f[0], 0, 1) + jnp.moveaxis(seqs_b[0], 0, 1)
+        return ys, (xp_t, mask_t, (w_f, b_f, *seqs_f), (w_b, b_b, *seqs_b))
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+    def pair(xproj, mask, w_f, b_f, w_b, b_b, interpret=False,
+             dot_dtype=None):
+        return both(xproj, mask, w_f, b_f, w_b, b_b, False,
+                    interpret=interpret, dot_dtype=dot_dtype)[0]
+
+    def fwd(xproj, mask, w_f, b_f, w_b, b_b, interpret, dot_dtype):
+        return both(xproj, mask, w_f, b_f, w_b, b_b, True,
+                    interpret=interpret, dot_dtype=dot_dtype)
+
+    def bwd(interpret, dot_dtype, residuals, dy):
+        xp_t, mask_t, fw, bw = residuals
+        dy_t = jnp.moveaxis(dy.astype(jnp.float32), 1, 0)  # [T, B, H]
+        kw = dict(interpret=interpret, dot_dtype=dot_dtype)
+        dxp_f, dw_f, db_f = _scan_backward(
+            cell, (xp_t, mask_t, *fw), dy_t, reverse=False, **kw)
+        # The first call's weight gradient before the second call: its
+        # operands (dgates and h_prev, 766 MB at ds2_full's call) are
+        # then dead while the second runs. Left free, XLA's scheduler
+        # put both contractions after both calls (+0.47 GB at the
+        # step's peak, by the compiler's own count). Only dxp_f waits
+        # on the barrier; dw_f goes round it, so that what follows the
+        # contraction fuses with it as in a one-direction layer.
+        dxp_f, _ = jax.lax.optimization_barrier((dxp_f, dw_f))
+        dxp_t, dw_b, db_b = _scan_backward(
+            cell, (xp_t, mask_t, *bw), dy_t, reverse=True,
+            other_dxp_t=dxp_f, **kw)
+        # the float32 sum, rounded once: [B, T, G*H] in xproj's dtype
+        return (jnp.moveaxis(dxp_t.astype(xp_t.dtype), 0, 1),
+                _mask_cotangent(mask_t), dw_f, db_f, dw_b, db_b)
+
+    pair.defvjp(fwd, bwd)
+    return pair

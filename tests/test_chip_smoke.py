@@ -90,6 +90,9 @@ def test_toy_run_takes_every_phase_in_order(smoke, monkeypatch, capsys):
     assert by_phase["reference"]["gru_builds_fwd_values"] == 2 * 6 * 1760
     # ... its recurrent weight gradient was read at three precisions
     assert by_phase["reference"]["dw_h_rows"] == 2 * 6
+    # ... a whole layer of it backward, as two functions and as one
+    assert by_phase["reference"]["pair_dxp_values"] == 2 * 6 * 5280
+    assert by_phase["reference"]["pair_dxproj_differing"] == 0
     # ... and ax_k1's attention in both forms at its published widths
     assert by_phase["reference"]["mla_positions_compared"] == 2
     assert by_phase["reference"]["mla_forms_rms_rel"] \
@@ -175,3 +178,31 @@ def test_attention_forms_holds_the_two_forms_to_its_limit(smoke,
     monkeypatch.setattr(smoke, "MLA_FORMS_RTOL", 0.0)
     with pytest.raises(SystemExit, match="decode form differs"):
         smoke.attention_forms()
+
+
+def test_pair_input_grad_holds_the_sum_to_xlas_bits(smoke, monkeypatch):
+    """A bidirectional layer of ds2_full's scan call backward, 2 rows
+    x 6 steps of it, interpreted: the summing call's ``dxp`` is XLA's
+    ``(a + b).astype(bfloat16)`` of the two directions' float32 results
+    bit for bit, the four weight and bias gradients are the two
+    functions' own, and the calls' device times are None off the chip
+    (not measured). A pair whose second direction sees another matrix
+    ends the run."""
+    from deepspeech_tpu.ops import rnn_pallas
+
+    monkeypatch.setattr(smoke, "SCAN_CALL", (2, 6))
+    monkeypatch.setattr(smoke, "PAIR_TIMED_CALLS", 1)
+    read = smoke.pair_input_grad(True)
+    assert read["pair_dxp_values"] == 2 * 6 * 5280
+    assert {k: v for k, v in read.items() if k.endswith("_differing")} == {
+        f"pair_{name}_differing": 0
+        for name in ("dxproj", "dw_f", "db_f", "dw_b", "db_b")}
+    assert {k: v for k, v in read.items() if k.endswith("_ms")} == {
+        f"pair_bwd_{name}_ms": None for name in ("first", "own", "summing")}
+    pair = rnn_pallas.gru_scan_pair_pallas
+    monkeypatch.setattr(
+        rnn_pallas, "gru_scan_pair_pallas",
+        lambda x, m, w_f, b_f, w_b, b_b, *tail: pair(
+            x, m, w_f, b_f, w_b * 1.01, b_b, *tail))
+    with pytest.raises(SystemExit, match="differs from XLA's"):
+        smoke.pair_input_grad(True)

@@ -260,10 +260,14 @@ def test_every_scan_of_the_ds2_full_step_is_pinned(monkeypatch):
             {**v, "params": p}, x_, l_, train=False)[0]
             .astype(jnp.float32)))(v["params"])
 
-    got = Counter((f["kernel"], f["variant"], f["reverse"], f["t"], f["b"])
+    got = Counter((f["kernel"], f["variant"], f["reverse"], f["t"], f["b"],
+                   f.get("sum"))
                   for f in lowered_facts(grads, (variables, x, lens)))
+    # ... and of a layer's two backward calls the reverse direction's
+    # sums the pair's input gradient (the fact ``sum``), seven a step
     assert got == {
-        (kernel, "pinned", r, "850", "32"): 7
+        (kernel, "pinned", r, "850", "32",
+         "pair" if (kernel, r) == ("gru_scan_bwd", "1") else None): 7
         for kernel in ("gru_scan_fwd", "gru_scan_bwd") for r in "01"}
 
 

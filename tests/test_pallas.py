@@ -973,6 +973,210 @@ def test_scan_bwd_hands_back_the_previous_state(monkeypatch, cell, build, b,
     assert not shifted, shifted
 
 
+def _scan_bwd_calls(eqns):
+    """The backward scan calls among ``eqns``, in program order."""
+    return [e for e in eqns if e.primitive.name == "pallas_call" and str(
+        e.params["metadata"]["kernel"]).endswith("_scan_bwd")]
+
+
+def _input_grad_adds(eqns, wide):
+    """Every ``add`` / ``add_any`` outside a Pallas call over a
+    ``[., ., wide]`` operand: jax summing two directions' ``dxp``."""
+    return [e for e in eqns if e.primitive.name in ("add", "add_any")
+            and any(len(v.aval.shape) == 3 and v.aval.shape[-1] == wide
+                    for v in e.invars)]
+
+
+@pytest.mark.parametrize("cell, build, b", [
+    (cell, build, b) for cell, rows in (("gru", (8, 32, 5)), ("lstm", (8,)))
+    for build in ("resident", "pinned", "blocked") for b in rows])
+@pytest.mark.parametrize("xproj_dtype", ["float32", "bfloat16"])
+def test_scan_pair_bwd_sums_the_input_gradient(monkeypatch, cell, build, b,
+                                               xproj_dtype):
+    """A layer's two directions as ONE function
+    (``scan_pallas.scan_pair_vjp``): backward, the forward direction's
+    call is a one-direction layer's and the reverse direction's takes
+    its float32 ``dxp`` rows in and writes the two's float32 sum,
+    which the VJP casts to the projection's dtype. At ragged masks, in
+    every build, for a float32 and a bfloat16 ``xproj``: the pair's
+    ``dxp`` is
+    ``(dxp_f + dxp_b).astype(xproj.dtype)`` of the two one-direction
+    VJPs' float32 results bit for bit (summed in float32, rounded
+    once); both ``dW_h`` and both ``db_h``, and what each call hands
+    to ``recurrent_dw``, are theirs bit for bit. The VJP holds no
+    ``add`` or ``add_any`` over a ``[., ., G*H]`` operand outside a
+    kernel (XLA ran 7 such passes over 2 x 574 MB a step of ds2_full,
+    and read both again for the projection's bias gradient: PERF.md
+    section 6, PR 50) and two ``*_scan_bwd`` calls, of which the
+    second alone carries the fact ``sum=pair`` and takes one more
+    ``[T, b, G*H]`` operand; both return two float32 ``[T, b, G*H]``
+    results, which is how ``benchmark/layer_metrics/
+    rnn_scan_roofline.py`` knows a backward scan; tracing leaves
+    ``scan_input_grad{kernel, variant, sum, dtype}`` in the registry,
+    once ``pair`` and once ``own``."""
+    from deepspeech_tpu import obs
+    from deepspeech_tpu.ops import lstm_pallas, rnn_pallas
+
+    gates, scan, (xproj, mask, w_f, b_f), dy, handed, _ = \
+        _backward_scan_case(monkeypatch, cell, build, b, 50)
+    pair = {"gru": rnn_pallas.gru_scan_pair_pallas,
+            "lstm": lstm_pallas.lstm_scan_pair_pallas}[cell]
+    (_, t, h) = dy.shape
+    xproj = xproj.astype(xproj_dtype)
+    rng = np.random.default_rng(150 + b)
+    w_b = jnp.asarray(rng.normal(size=w_f.shape) / np.sqrt(h), jnp.float32)
+    b_b = jnp.asarray(rng.normal(size=b_f.shape) * 0.1, jnp.float32)
+
+    def vjp(*a):
+        return jax.vjp(lambda xp, *w: pair(xp, mask, *w, True), *a)
+
+    def one(reverse, w, bias):
+        return jax.vjp(lambda xp, wh, bh: scan(xp, mask, wh, bh, reverse,
+                                               True), xproj, w, bias)
+
+    obs.registry().reset()
+    ys, pull = vjp(xproj, w_f, b_f, w_b, b_b)
+    got = pull(dy)  # eager: every kernel result is concrete
+    gauges = obs.registry().snapshot()["gauges"]
+    for summed in ("pair", "own"):
+        assert gauges[
+            f'scan_input_grad{{dtype="float32",kernel="{cell}_scan_bwd",'
+            f'sum="{summed}",variant="{build}"}}'] == 1
+    (ys_f, pull_f), (ys_b, pull_b) = one(False, w_f, b_f), one(True, w_b, b_b)
+    np.testing.assert_array_equal(np.asarray(ys), np.asarray(ys_f + ys_b))
+    (dxp_f, *want_f), (dxp_b, *want_b) = pull_f(dy), pull_b(dy)
+    assert dxp_f.dtype == dxp_b.dtype == jnp.float32
+    assert got[0].dtype == xproj.dtype
+    np.testing.assert_array_equal(
+        np.asarray(got[0], np.float32),
+        np.asarray((dxp_f + dxp_b).astype(xproj.dtype), np.float32))
+    for a, want, name in zip(got[1:], want_f + want_b,
+                             ["dw_f", "db_f", "dw_b", "db_b"]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(want), name)
+    assert len(handed) == 4  # the pair's two calls, then one a direction
+    for mine, theirs in zip(handed[:2], handed[2:]):
+        for a, want in zip(mine, theirs):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(want))
+
+    eqns = _eqns(lambda *a: vjp(*a)[1](dy), xproj, w_f, b_f, w_b, b_b)
+    assert not _input_grad_adds(eqns, gates * h)
+    first, second = _scan_bwd_calls(eqns)
+    assert [c.params["metadata"].get("sum") for c in (first, second)] == [
+        None, "pair"]
+    assert [int(str(c.params["metadata"]["reverse"]))
+            for c in (first, second)] == [0, 1]
+    wide = (t, b, gates * h)
+    assert [[str(v.aval.dtype) for v in c.outvars if v.aval.shape == wide]
+            for c in (first, second)] == [["float32", "float32"]] * 2
+    # the first call's dxp reaches the second behind its own weight
+    # gradient (one barrier over the two: the contraction runs first)
+    (barrier,) = [e for e in eqns
+                  if e.primitive.name == "optimization_barrier"]
+    assert barrier.invars[0] is first.outvars[0]
+    assert barrier.invars[1].aval.shape == w_f.shape
+    assert barrier.outvars[0] in second.invars
+    assert len(second.invars) == len(first.invars) + 1
+    # and the sum goes back as the kernel wrote it, cast and turned
+    (back,) = [e for e in eqns if second.outvars[0] in e.invars]
+    assert back.primitive.name == {
+        "float32": "transpose", "bfloat16": "convert_element_type"}[
+            xproj_dtype]
+
+
+def _bidirectional_layer(monkeypatch, b, h, **cfg):
+    """A GRU layer past the residency budget (two directions then are
+    two kernels), its model configuration and ``(xproj, mask, params)``
+    for ``models.rnn._run_stack_dirs``."""
+    from deepspeech_tpu.config import get_config
+
+    monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
+    cfg = dataclasses.replace(
+        get_config("ds2_small").model, rnn_hidden=h, rnn_impl="pallas",
+        dtype="float32", **cfg)
+    rng = np.random.default_rng(b)
+    xproj, mask, w_f, b_f = _rand_gru(rng, b, 7, h)
+    _, _, w_b, b_b = _rand_gru(rng, b, 7, h)
+    return cfg, xproj, mask, {False: (w_f, b_f), True: (w_b, b_b)}
+
+
+@pytest.mark.parametrize("layer, calls, sums", [
+    ("two_directions", ["gru_scan_fwd"] * 2 + ["gru_scan_bwd"] * 2,
+     {"own": 1, "pair": 1}),
+    ("one_direction", ["gru_scan_fwd", "gru_scan_bwd"], {"own": 1}),
+    ("int8", ["gru_scan_q_fwd"] * 2, {}),
+    ("xla", [], {}),
+])
+def test_layer_sums_the_pair_where_two_float_kernels_run(monkeypatch, layer,
+                                                         calls, sums):
+    """``models.rnn._run_stack_dirs`` chooses from what it observes: a
+    layer of two float directions whose route names the one-direction
+    kernel runs that kernel's pair function (one ``add`` fewer over
+    ``[B, T, G*H]`` under ``jax.vjp``, the second backward call
+    carrying ``sum=pair``); one direction, int8 leaves and the XLA
+    scan lower to the calls and gauges they had."""
+    from deepspeech_tpu import obs
+    from deepspeech_tpu.models import rnn
+
+    b, h = 8, 16
+    cfg, xproj, mask, params = _bidirectional_layer(monkeypatch, b, h)
+    if layer == "one_direction":
+        del params[True]
+    elif layer == "int8":
+        params = {rev: (dict(zip(("q", "scale"), _quantize_wh(w))), bias)
+                  for rev, (w, bias) in params.items()}
+    elif layer == "xla":
+        cfg = dataclasses.replace(cfg, rnn_impl="xla")
+
+    def run(xp):
+        return rnn._run_stack_dirs(cfg, xp, mask, params)
+
+    def train(xp):
+        ys, pull = jax.vjp(run, xp)
+        return pull(ys)
+
+    obs.registry().reset()
+    eqns = _eqns(run if layer == "int8" else train, xproj)
+    assert [str(e.params["metadata"]["kernel"]) for e in eqns
+            if e.primitive.name == "pallas_call"] == calls
+    assert [c.params["metadata"].get("sum")
+            for c in _scan_bwd_calls(eqns)] == [
+                None, "pair"][:len(_scan_bwd_calls(eqns))]
+    gauges = obs.registry().snapshot()["gauges"]
+    assert {key.split('sum="')[1].split('"')[0]: value
+            for key, value in gauges.items()
+            if key.startswith("scan_input_grad")} == sums
+    # the directions' outputs are summed outside a kernel, one add;
+    # their input gradients only where the XLA scan runs
+    adds = _input_grad_adds(eqns, 3 * h)
+    assert bool(adds) == (layer == "xla"), adds
+
+
+def test_layer_pair_runs_under_one_shard_map(monkeypatch):
+    """On a mesh the pair is ONE ``shard_batchwise`` call (``xproj``
+    and ``mask`` split over ``data``, the four weight operands
+    replicated), so every chip sums its own rows' ``dxp`` as one chip
+    does, and values and gradients are the unsharded layer's."""
+    from deepspeech_tpu.models import rnn
+    from deepspeech_tpu.parallel import make_mesh
+
+    cfg, xproj, mask, params = _bidirectional_layer(monkeypatch, 16, 16)
+    mesh = make_mesh((8, 1))
+
+    def train(mesh, xp, params):
+        ys, pull = jax.vjp(lambda xp, p: rnn._run_stack_dirs(
+            cfg, xp, mask, p, mesh=mesh), xp, params)
+        return ys, pull(ys * ys)
+
+    eqns = _eqns(lambda xp, p: train(mesh, xp, p), xproj, params)
+    maps = [e for e in eqns if e.primitive.name == "shard_map"]
+    assert len(maps) == 2, maps  # the pair, and its VJP
+    assert not _input_grad_adds(eqns, 3 * 16)
+    want = train(None, xproj, params)
+    got = train(mesh, xproj, params)
+    for a, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), atol=1e-5)
+
+
 def test_lstm_pallas_respects_mask():
     from deepspeech_tpu.ops.lstm_pallas import lstm_scan_pallas
 

@@ -70,21 +70,24 @@ def kernel_cases():
     cases = {}
 
     def gru_case(h, b_=b, t_=t, xdt=jnp.float32, dot="bfloat16",
-                 vjp=True):
+                 vjp=True, pair=False):
         """Forward + VJP (training), or with ``vjp=False`` the forward
-        call alone: what evaluation and offline decode run."""
+        call alone: what evaluation and offline decode run. ``pair``: a
+        whole bidirectional layer, both directions over one ``xproj``
+        as ONE function, whose second backward call sums the pair's
+        ``dxp``."""
         _, _, w, bh = rnnshapes(h, 3)
         xp, m = S((b_, t_, 3 * h), xdt), S((b_, t_), jnp.float32)
+        scan = rp.gru_scan_pair_pallas if pair else rp.gru_scan_pallas
 
         def f():
-            def step(xp_, m_, w_, bh_):
-                return rp.gru_scan_pallas(xp_, m_, w_, bh_,
-                                          dot_dtype=dot)
+            def step(*a):
+                return scan(*a, dot_dtype=dot)
 
-            def train(xp_, m_, w_, bh_):
-                ys, vjp_ = jax.vjp(step, xp_, m_, w_, bh_)
+            def train(*a):
+                ys, vjp_ = jax.vjp(step, *a)
                 return vjp_(jnp.ones_like(ys))
-            return (train if vjp else step), (xp, m, w, bh)
+            return (train if vjp else step), (xp, m) + (w, bh) * (1 + pair)
         return f
 
     def lstm_case(h):
@@ -324,6 +327,13 @@ def kernel_cases():
     # scoped VMEM, forward 28 / 28 MiB, backward 32 / 40 MiB.
     cases["gru_h1760_b32"] = gru_case(1760, 32, 850, jnp.bfloat16)
     cases["gru_h1760_b64"] = gru_case(1760, 64, 850, jnp.bfloat16)
+    # a whole layer of that cell: both directions as one function, whose
+    # second backward call takes the first's float32 dxp rows in and
+    # writes the pair's float32 sum (32 / 44 MiB)
+    cases["gru_pair_h1760_b32"] = gru_case(1760, 32, 850, jnp.bfloat16,
+                                           pair=True)
+    cases["gru_pair_h1760_b64"] = gru_case(1760, 64, 850, jnp.bfloat16,
+                                           pair=True)
     # offline decode: the forward call alone, in the 1200-frame bucket
     # and at its widest batch in the 1700-frame one (36 MiB)
     cases["gru_h1760_decode"] = gru_case(1760, 32, 600, jnp.bfloat16,

@@ -180,10 +180,11 @@ class LMGreedy:
         (new, a_lens, counters, draft), state = self.model.apply(
             {"params": params, "buffers": buffers}, feats, lens,
             method="prefill", mutable=["intermediates"])
-        cache = jax.tree.map(
-            lambda c, r: jax.lax.dynamic_update_slice(
-                c, self._rows_for(c, r, a_lens).astype(c.dtype),
-                (offset,) + (0,) * (c.ndim - 1)), cache, new)
+        with jax.named_scope("cache_update"):
+            cache = jax.tree.map(
+                lambda c, r: jax.lax.dynamic_update_slice(
+                    c, self._rows_for(c, r, a_lens).astype(c.dtype),
+                    (offset,) + (0,) * (c.ndim - 1)), cache, new)
         a = -(-features.shape[1] // self.cfg.model.frame_stack)
         counters = dict(counters)
         counters["valid_positions"] = jnp.sum(a_lens)
@@ -243,7 +244,8 @@ class LMGreedy:
             active = ~done
             (logits, cache, counters), state = step(
                 tokens, a_lens + j, active, cache)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("lm_head"):  # the argmax's passes
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             out = jax.lax.dynamic_update_slice(
                 out, jnp.where(active, nxt, 0)[:, None], (0, j))
             ahead = jax.lax.dynamic_slice_in_dim(
@@ -387,7 +389,8 @@ class LMGreedy:
                     variables, jnp.stack([tokens, guess], axis=1), pos,
                     jnp.stack([active, second], axis=1), cache[:-1],
                     method="verify", mutable=["intermediates"])
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, 2]
+            with jax.named_scope("lm_head"):  # the argmax's passes
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, 2]
             # Tokens j+1 and j+2: forced, or the model's own.
             inputs = jnp.stack([jnp.where(ahead >= 0, ahead, nxt[:, 0]),
                                 jnp.where(later >= 0, later, nxt[:, 1])],
@@ -413,7 +416,8 @@ class LMGreedy:
                 mid["chosen"] = jnp.moveaxis(mid["chosen"], 0, 2)
             mid["logits"] = logits[watch]
             mid["draft_logits"] = guesses[watch]
-            drafts = jnp.argmax(guesses, axis=-1).astype(jnp.int32)
+            with jax.named_scope("lm_head"):
+                drafts = jnp.argmax(guesses, axis=-1).astype(jnp.int32)
             ends = ends[:, 0] | (accept & ends[:, 1])
             return (counters, mid, main + [own], nxt, inputs, drafts,
                     second, accept, ends)
@@ -603,6 +607,13 @@ class LMGreedy:
                     if obs.tracer.enabled:
                         with obs.span("infer.prefill.wait", call=call):
                             jax.block_until_ready(cache)
+                        # The program's layer table, for a reader after
+                        # the run (the new cache stands for the donated
+                        # one).
+                        obs.layers.watch(
+                            "lm_prefill", self.prefill,
+                            (self.params, self.buffers, cache, features,
+                             feat_lens, i * sub))
                 a_lens.append(a)
                 pre.append(counters)
                 drafts.append(draft)
@@ -610,14 +621,19 @@ class LMGreedy:
             t1 = time.perf_counter()
             with obs.span("infer.decode", rows=b, call=call):
                 with obs.span("infer.decode.dispatch", call=call):
+                    rest = (jnp.concatenate(a_lens), max_tokens,
+                            jnp.asarray(forced, jnp.int32),
+                            jnp.asarray(watch, jnp.int32),
+                            jnp.asarray(cfg.decode.lm_ignore_end),
+                            jnp.concatenate(drafts) if self.draft
+                            else None)
                     ids, cache, acc, seen = self.decode(
-                        self.params, self.buffers, cache,
-                        jnp.concatenate(a_lens), max_tokens,
-                        jnp.asarray(forced, jnp.int32),
-                        jnp.asarray(watch, jnp.int32),
-                        jnp.asarray(cfg.decode.lm_ignore_end),
-                        jnp.concatenate(drafts) if self.draft else None)
+                        self.params, self.buffers, cache, *rest)
                 t2 = time.perf_counter()
+                if obs.tracer.enabled:
+                    obs.layers.watch(
+                        "lm_decode", self.decode,
+                        (self.params, self.buffers, cache, *rest))
                 with obs.span("infer.decode.fetch", call=call):
                     ids, acc, pre = jax.device_get((ids, acc, pre))
                 t3 = time.perf_counter()

@@ -144,14 +144,16 @@ class LatentAttention(nn.Module):
             scores = jnp.where(causal, scores * scale, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             out = jnp.einsum("bhqk,bkhv->bqhv", probs, kv_h[..., dn:])
-            return jnp.dot(out.reshape(b, s, nh * dv), w_o), rows
+            with jax.named_scope("attn_out"):
+                return jnp.dot(out.reshape(b, s, nh * dv), w_o), rows
 
         if s > 1:
             # q consecutive new positions a stream (a draft and what it
             # follows): all q rows are written first, and a position's
             # own mask hides the rows after it. One position (below)
             # keeps its own, three-dimensional contractions.
-            cache = cache.at[jnp.arange(b)[:, None], pos].set(rows)
+            with jax.named_scope("cache_update"):
+                cache = cache.at[jnp.arange(b)[:, None], pos].set(rows)
             q_lat = jnp.einsum("bqhn,chn->bqhc", q_nope, w_kvb[..., :dn])
             qk = jnp.concatenate([q_lat, q_rope], axis=-1)
             scores = jnp.einsum("bqhc,brc->bhqr", qk, cache,
@@ -162,9 +164,11 @@ class LatentAttention(nn.Module):
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             mixed = jnp.einsum("bhqr,brc->bqhc", probs, cache[..., :rkv])
             out = jnp.einsum("bqhc,chv->bqhv", mixed, w_kvb[..., dn:])
-            return jnp.dot(out.reshape(b, s, nh * dv), w_o), cache
+            with jax.named_scope("attn_out"):
+                return jnp.dot(out.reshape(b, s, nh * dv), w_o), cache
         at = pos[:, 0]
-        cache = cache.at[jnp.arange(b), at].set(rows[:, 0])
+        with jax.named_scope("cache_update"):
+            cache = cache.at[jnp.arange(b), at].set(rows[:, 0])
         q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_kvb[..., :dn])
         qk = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)
         scores = jnp.einsum("bhc,brc->bhr", qk, cache,
@@ -174,7 +178,8 @@ class LatentAttention(nn.Module):
         probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
         mixed = jnp.einsum("bhr,brc->bhc", probs, cache[..., :rkv])
         out = jnp.einsum("bhc,chv->bhv", mixed, w_kvb[..., dn:])
-        return jnp.dot(out.reshape(b, 1, nh * dv), w_o), cache
+        with jax.named_scope("attn_out"):
+            return jnp.dot(out.reshape(b, 1, nh * dv), w_o), cache
 
 
 def both_forms(cfg: ModelConfig, params, x, at, q: int = 1):

@@ -461,9 +461,10 @@ class Attention(nn.Module):
                 # A stream that has finished (not ``live``) writes
                 # nothing: in a ring its slot holds a row it still owns.
                 at_slot = (jnp.arange(b), at % r)
-                keys, values = (c.at[at_slot].set(jnp.where(
-                    live[:, :, None], row[:, 0].astype(c.dtype),
-                    c[at_slot])) for c, row in zip(cache, (k, v)))
+                with jax.named_scope("cache_update"):
+                    keys, values = (c.at[at_slot].set(jnp.where(
+                        live[:, :, None], row[:, 0].astype(c.dtype),
+                        c[at_slot])) for c, row in zip(cache, (k, v)))
                 if attends_in_kernels(cfg):
                     out = attn_pallas.gqa_decode(
                         q[:, 0], keys, values, at, live[:, 0], window)
@@ -476,7 +477,8 @@ class Attention(nn.Module):
             out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(h.dtype)
         self.sow("intermediates", "gated", out)
-        out = Linear(d, name="o")(out)
+        with jax.named_scope("attn_out"):
+            out = Linear(d, name="o")(out)
         if cfg.mup_attn_out != 1.0:
             out = scaled(out, cfg.mup_attn_out)
         return out, kept
@@ -596,7 +598,8 @@ class SparseExperts(nn.Module):
             counters["groups_used"] = jnp.sum(
                 used * valid.reshape(-1, 1))
         if cfg.moe_shared_experts:
-            out = out + self.shared(x)
+            with jax.named_scope("moe_shared"):
+                out = out + self.shared(x)
         return out, counters
 
 
@@ -660,7 +663,8 @@ class DecoderLayer(nn.Module):
         def operator(x):
             x = norm("op_norm")(x)
             if self.kind == "latent_attention":
-                return LatentAttention(cfg, name="attn")(x, pos, cache)
+                with jax.named_scope("latent_attention"):
+                    return LatentAttention(cfg, name="attn")(x, pos, cache)
             if self.kind in ATTENTION_KINDS:
                 return after("op_post_norm", Attention(
                     cfg, self.kind, name="attn")(x, pos, cache, valid))
@@ -784,15 +788,16 @@ class LFM2ASR(nn.Module):
         and each expert layer's counters."""
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        x, a_lens = stack_frames(features, feat_lens, cfg.frame_stack)
         s = seq_positions(cfg, features.shape[1], self.max_label_len)
-        audio, text, ids, targets = pack(a_lens, labels, label_lens, s)
-        pre = self.prefix(x.astype(dtype))
-        pre = jnp.pad(pre, [(0, 0), (0, s - pre.shape[1]), (0, 0)])
-        emb = jnp.take(self.embed.astype(dtype), ids, axis=0)
-        valid = audio | text
-        h = self.enter(jnp.where(audio[..., None], pre,
-                                 jnp.where(text[..., None], emb, 0)))
+        with jax.named_scope("embed"):
+            x, a_lens = stack_frames(features, feat_lens, cfg.frame_stack)
+            audio, text, ids, targets = pack(a_lens, labels, label_lens, s)
+            pre = self.prefix(x.astype(dtype))
+            pre = jnp.pad(pre, [(0, 0), (0, s - pre.shape[1]), (0, 0)])
+            emb = jnp.take(self.embed.astype(dtype), ids, axis=0)
+            valid = audio | text
+            h = self.enter(jnp.where(audio[..., None], pre,
+                                     jnp.where(text[..., None], emb, 0)))
         pos = jnp.broadcast_to(jnp.arange(s)[None, :], valid.shape)
         h = mhc.fan_out(h, cfg.hc_streams)
         counters = []
@@ -813,8 +818,9 @@ class LFM2ASR(nn.Module):
             features, feat_lens, labels, label_lens)
         if self.cfg.mup_lm_head != 1.0:      # on the logits
             h = scaled(h, self.cfg.mup_lm_head)
-        logp, mask = target_logp(h, head, layout, labels, label_lens)
-        nll = -jnp.sum(logp * mask, axis=1)
+        with jax.named_scope("lm_head"):
+            logp, mask = target_logp(h, head, layout, labels, label_lens)
+            nll = -jnp.sum(logp * mask, axis=1)
         valid = layout["valid"]
         stats = {"valid_positions": jnp.sum(valid),
                  "padded_positions": valid.size - jnp.sum(valid)}
@@ -838,12 +844,15 @@ class LFM2ASR(nn.Module):
         position is the next projected frame, and the start id's
         embedding at the last."""
         cfg = self.cfg
-        x, a_lens = stack_frames(features, feat_lens, cfg.frame_stack)
-        pre = self.prefix(x.astype(jnp.dtype(cfg.dtype)))
+        with jax.named_scope("embed"):
+            x, a_lens = stack_frames(features, feat_lens, cfg.frame_stack)
+            pre = self.prefix(x.astype(jnp.dtype(cfg.dtype)))
         pos = jnp.broadcast_to(jnp.arange(pre.shape[1])[None, :],
                                pre.shape[:2])
         valid = pos < a_lens[:, None]
-        h = mhc.fan_out(self.enter(pre), cfg.hc_streams)
+        with jax.named_scope("embed"):
+            h = self.enter(pre)
+        h = mhc.fan_out(h, cfg.hc_streams)
         rows, counters = [], []
         for layer in self.layers:
             h, c, r = layer(h, valid, pos)
@@ -870,10 +879,11 @@ class LFM2ASR(nn.Module):
     def logits(self, h):
         """``h [N, D]`` (normed) against the head, float32 (times the
         family's ``lm_head_multiplier`` where not 1)."""
-        out = jnp.dot(h, self.head().astype(h.dtype).T,
-                      preferred_element_type=jnp.float32)
-        by = self.cfg.mup_lm_head
-        return out if by == 1.0 else out * by
+        with jax.named_scope("lm_head"):
+            out = jnp.dot(h, self.head().astype(h.dtype).T,
+                          preferred_element_type=jnp.float32)
+            by = self.cfg.mup_lm_head
+            return out if by == 1.0 else out * by
 
     def step(self, tokens, pos, active, cache):
         """The serving path's second half: one new position a stream
@@ -882,9 +892,11 @@ class LFM2ASR(nn.Module):
         the new rows and the expert layers' counters; a stream that is
         not ``active`` is not routed."""
         n = self.cfg.hc_streams
-        h = mhc.fan_out(self.enter(jnp.take(
-            self.embed.astype(jnp.dtype(self.cfg.dtype)), tokens,
-            axis=0)[:, None, :]), n)
+        with jax.named_scope("embed"):
+            h = self.enter(jnp.take(
+                self.embed.astype(jnp.dtype(self.cfg.dtype)), tokens,
+                axis=0)[:, None, :])
+        h = mhc.fan_out(h, n)
         new, counters = [], []
         for layer, rows in zip(self.layers, cache):
             h, c, rows = layer(h, active[:, None], pos[:, None], rows)
@@ -903,9 +915,10 @@ class LFM2ASR(nn.Module):
         ``[B, q, D]`` (the streams' sum, before the last norm), the
         cache with the new rows and the expert layers' counters."""
         cfg = self.cfg
-        h = mhc.fan_out(self.enter(jnp.take(
-            self.embed.astype(jnp.dtype(cfg.dtype)), tokens, axis=0)),
-            cfg.hc_streams)
+        with jax.named_scope("embed"):
+            h = self.enter(jnp.take(
+                self.embed.astype(jnp.dtype(cfg.dtype)), tokens, axis=0))
+        h = mhc.fan_out(h, cfg.hc_streams)
         new, counters = [], []
         for layer, rows in zip(self.layers, cache):
             h, c, rows = layer(h, valid, pos, rows)
@@ -923,7 +936,8 @@ class LFM2ASR(nn.Module):
         position, ``h [B, q, D]`` what :meth:`verify` gave there.
         Returns its logits ``[B, q, V]`` (the token after next), its
         cache and its expert layer's counters."""
-        emb = jnp.take(self.embed.astype(h.dtype), tokens, axis=0)
+        with jax.named_scope("embed"):
+            emb = jnp.take(self.embed.astype(h.dtype), tokens, axis=0)
         out, counters, cache = self.drafts[0](emb, h, valid, pos, cache)
         b, q, d = out.shape
         return self.logits(out.reshape(b * q, d)).reshape(b, q, -1), \

@@ -36,6 +36,12 @@ step's time go?". This package is the shared substrate:
   registers its recorder through (:func:`set_postmortem_recorder`)
   so obs never imports resilience at module load.
 
+- device time by layer (PR 51): ``obs/layers.py`` maps a compiled
+  program's instructions to the model's layers through their
+  ``op_name`` (module path + ``jax.named_scope``), for the readers of a
+  device trace; the dispatch sites ``watch`` their programs while the
+  tracer is on.
+
 Enable tracing with ``obs.configure(jsonl_path=...)`` or by exporting
 ``DS2_TRACE=/path/to/trace.jsonl``; read traces with
 ``tools/trace_report.py`` and request breakdowns with
@@ -53,14 +59,14 @@ from .slo import SloBurnEngine
 from .status import StatusServer
 from .timeline import EventLog, IncidentCorrelator, MetricSeries
 from .trace import Tracer, tracer
-from . import timeline
+from . import layers, timeline
 
 __all__ = ["Histogram", "MetricsRegistry", "Tracer", "registry",
            "tracer", "span", "configure", "compile_event",
            "render_text", "emit_jsonl", "TraceContext",
            "FlightRecorder", "flight_recorder", "SloBurnEngine",
            "StatusServer", "EventLog", "IncidentCorrelator",
-           "MetricSeries", "timeline", "set_postmortem_recorder",
+           "MetricSeries", "timeline", "layers", "set_postmortem_recorder",
            "postmortem_recorder", "postmortem_record",
            "observe_routing", "observe_lm_call", "check_dropless"]
 

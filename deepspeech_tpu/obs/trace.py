@@ -56,6 +56,13 @@ hook writes nothing itself (the collector may run while this tracer's
 lock is held): it leaves the finished collection for the next span's
 record, or for ``configure(enabled=False)``, to write.
 
+The device's side of the same units is not here: a device trace names
+its events by HLO instruction, and ``obs/layers.py`` maps the
+instructions of the programs these loops dispatch (``watch`` beside
+``train.dispatch``, ``infer.prefill.dispatch`` and
+``infer.decode.dispatch``, reached with the tracer on only) to the
+model's layers by the scopes the code opens.
+
 Durations come from a monotonic clock (injectable for tests — wall
 time only stamps ``ts``); nesting is tracked per thread, so gateway
 dispatch spans on a worker thread never adopt a train-loop parent.

@@ -343,6 +343,7 @@ def lstmp_scan_pallas(xproj: jnp.ndarray, mask: jnp.ndarray,
     return jnp.moveaxis(ys, 0, 1)
 
 
+@jax.named_scope("rnn_scan")
 def _lstmp_fwd(xproj, mask, w_r, w_p, ln_scale, ln_bias, interpret,
                dot_dtype):
     ys, cs, xp_t, mask_t = _lstmp_raw(
@@ -352,6 +353,7 @@ def _lstmp_fwd(xproj, mask, w_r, w_p, ln_scale, ln_bias, interpret,
                                     ln_bias, ys, cs)
 
 
+@jax.named_scope("rnn_scan")
 def _lstmp_bwd(interpret, dot_dtype, residuals, dy):
     xp_t, mask_t, w_r, w_p, ln_scale, ln_bias, ys, cs = residuals
     t_max, _, h = cs.shape
@@ -370,11 +372,12 @@ def _lstmp_bwd(interpret, dot_dtype, residuals, dy):
     # The weight gradients over all T*B rows, outside the time loop;
     # operands in the dot type, float32 accumulation, as the oracle's
     # per-step contractions have them.
-    r_prev = prev_sequence(ys, False)
-    dw_r = jnp.einsum("tbp,tbg->pg", r_prev.astype(dot), da_t,
-                      preferred_element_type=jnp.float32)
-    dw_p = jnp.einsum("tbh,tbp->hp", mo_t, dr_t,
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("dw_h"):
+        r_prev = prev_sequence(ys, False)
+        dw_r = jnp.einsum("tbp,tbg->pg", r_prev.astype(dot), da_t,
+                          preferred_element_type=jnp.float32)
+        dw_p = jnp.einsum("tbh,tbp->hp", mo_t, dr_t,
+                          preferred_element_type=jnp.float32)
     dxp = jnp.moveaxis(da_t, 0, 1).astype(xp_t.dtype)
     dmask = jnp.zeros_like(mask_t[..., 0]).swapaxes(0, 1)
     if not operands[-1]:

@@ -38,6 +38,7 @@ class Routing(NamedTuple):
     scores: jnp.ndarray    # [N, E] float32: the router's scores
 
 
+@jax.named_scope("moe_route")   # the name its device time is read under
 def route(x, w_gate, bias, top_k: int, groups: int = 1,
           groups_kept: int = 1, scale: float = 1.0,
           score_func: str = "sigmoid") -> Routing:
@@ -127,30 +128,36 @@ def expert_layer(x, valid, routing: Routing, w13, w2, *, offset: int,
     k = routing.experts.shape[1]
     m = capacity_rows(n, k, g, rows_bound)
 
-    local = routing.experts - offset
-    here = valid[:, None] & (local >= 0) & (local < g)
-    key = jnp.where(here, local, g).reshape(-1)          # [N*k]
-    # Stable: rows of one expert stay in position order.
-    order = jnp.argsort(key, stable=True)[:m]
-    if order.shape[0] < m:  # fewer pairs than one row tile
-        order = jnp.pad(order, (0, m - order.shape[0]))
-    token = order // k
-    counts = jnp.sum(jax.nn.one_hot(key, g + 1, dtype=jnp.int32),
-                     axis=0)[:g]
-    # Under a stated bound the groups are cut to the rows there are.
-    ends = jnp.minimum(jnp.cumsum(counts), m)
-    sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
-    routed = jnp.arange(m) < ends[-1]
-    weight = jnp.where(routed, routing.weights.reshape(-1)[order], 0.0)
-
-    xs = jnp.take(x, token, axis=0)                       # [M, D]
-    h = grouped_dot(xs, w13.astype(x.dtype), sizes, impl)
-    f = f2 // 2
-    gated = (ACTIVATIONS[act](h[:, :f].astype(jnp.float32))
-             * h[:, f:].astype(jnp.float32)).astype(x.dtype)
-    ys = grouped_dot(gated, w2.astype(x.dtype), sizes, impl)
-    out = jnp.zeros((n, d), jnp.float32).at[token].add(
-        ys.astype(jnp.float32) * weight[:, None])
+    # The scopes are the names the device's time is read under
+    # (obs/layers.py): the sort and the row gather, the experts'
+    # products with the gate between them, the weighted scatter-add.
+    with jax.named_scope("moe_dispatch"):
+        local = routing.experts - offset
+        here = valid[:, None] & (local >= 0) & (local < g)
+        key = jnp.where(here, local, g).reshape(-1)          # [N*k]
+        # Stable: rows of one expert stay in position order.
+        order = jnp.argsort(key, stable=True)[:m]
+        if order.shape[0] < m:  # fewer pairs than one row tile
+            order = jnp.pad(order, (0, m - order.shape[0]))
+        token = order // k
+        counts = jnp.sum(jax.nn.one_hot(key, g + 1, dtype=jnp.int32),
+                         axis=0)[:g]
+        # Under a stated bound the groups are cut to the rows there are.
+        ends = jnp.minimum(jnp.cumsum(counts), m)
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        routed = jnp.arange(m) < ends[-1]
+        weight = jnp.where(routed,
+                           routing.weights.reshape(-1)[order], 0.0)
+        xs = jnp.take(x, token, axis=0)                       # [M, D]
+    with jax.named_scope("moe_gmm"):
+        h = grouped_dot(xs, w13.astype(x.dtype), sizes, impl)
+        f = f2 // 2
+        gated = (ACTIVATIONS[act](h[:, :f].astype(jnp.float32))
+                 * h[:, f:].astype(jnp.float32)).astype(x.dtype)
+        ys = grouped_dot(gated, w2.astype(x.dtype), sizes, impl)
+    with jax.named_scope("moe_combine"):
+        out = jnp.zeros((n, d), jnp.float32).at[token].add(
+            ys.astype(jnp.float32) * weight[:, None])
 
     held = jnp.sum(counts)
     counters = {

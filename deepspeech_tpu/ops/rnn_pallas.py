@@ -356,6 +356,7 @@ def bigru_scan_pallas(xproj: jnp.ndarray, mask: jnp.ndarray,
     return jnp.moveaxis(ysf + ysb, 0, 1)
 
 
+@jax.named_scope("rnn_scan")
 def _bigru_fwd(xproj, mask, w_f, b_f, w_b, b_b, interpret, dot_dtype):
     ysf, ysb, xp_t, mask_t = _bigru_raw(xproj, mask, w_f, b_f, w_b, b_b,
                                         interpret, dot_dtype)
@@ -363,6 +364,7 @@ def _bigru_fwd(xproj, mask, w_f, b_f, w_b, b_b, interpret, dot_dtype):
             (xp_t, mask_t, w_f, b_f, w_b, b_b, ysf, ysb))
 
 
+@jax.named_scope("rnn_scan")
 def _bigru_bwd(interpret, dot_dtype, residuals, dy):
     xp_t, mask_t, w_f, b_f, w_b, b_b, ysf, ysb = residuals
     t_max, _, h = ysf.shape

@@ -349,7 +349,9 @@ def recurrent_dw(h_prev, dgates, dot):
     time: 14 such contractions were 41% of ds2_full's step."""
     precision = (jax.lax.Precision.HIGH if dot == jnp.bfloat16
                  else jax.lax.Precision.HIGHEST)
-    return jnp.einsum("...h,...g->hg", h_prev, dgates, precision=precision)
+    with jax.named_scope("dw_h"):  # obs/layers.py reads its time by it
+        return jnp.einsum("...h,...g->hg", h_prev, dgates,
+                          precision=precision)
 
 
 def scan_call(body, route: ScanRoute, *, reverse, hidden: int, gates: int,
@@ -842,12 +844,14 @@ def scan_vjp(cell: ScanCell):
     directions does not come here (jax would add the two functions'
     ``dxp`` in a pass of its own): :func:`scan_pair_vjp`."""
 
+    @jax.named_scope("rnn_scan")
     def fwd(xproj, mask, w_h, b_h, reverse, interpret, dot_dtype):
         seqs, xp_t, mask_t = scan_forward(
             cell, xproj, mask, w_h, b_h, reverse=reverse,
             interpret=interpret, dot_dtype=dot_dtype, tape=True)
         return jnp.moveaxis(seqs[0], 0, 1), (xp_t, mask_t, w_h, b_h, *seqs)
 
+    @jax.named_scope("rnn_scan")
     def bwd(reverse, interpret, dot_dtype, residuals, dy):
         dy_t = jnp.moveaxis(dy.astype(jnp.float32), 1, 0)  # [T, B, H]
         dxp_t, dw_h, db_h = _scan_backward(
@@ -879,6 +883,7 @@ def scan_pair_vjp(cell: ScanCell):
     ``h_prev``, ``db``, ``dW_h`` and ``db_h`` are a one-direction
     layer's bit for bit."""
 
+    @jax.named_scope("rnn_scan")
     def both(xproj, mask, w_f, b_f, w_b, b_b, tape, **kw):
         seqs_f, xp_t, mask_t = scan_forward(
             cell, xproj, mask, w_f, b_f, reverse=False, tape=tape, **kw)
@@ -897,6 +902,7 @@ def scan_pair_vjp(cell: ScanCell):
         return both(xproj, mask, w_f, b_f, w_b, b_b, True,
                     interpret=interpret, dot_dtype=dot_dtype)
 
+    @jax.named_scope("rnn_scan")
     def bwd(interpret, dot_dtype, residuals, dy):
         xp_t, mask_t, fw, bw = residuals
         dy_t = jnp.moveaxis(dy.astype(jnp.float32), 1, 0)  # [T, B, H]

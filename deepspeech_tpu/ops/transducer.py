@@ -195,6 +195,7 @@ def _tile_logits(hq, w_o, b_o):
                       preferred_element_type=jnp.float32) + b_o
 
 
+@jax.named_scope("rnnt_joint")
 def _joint_picks(e, p, w_o, b_o, labels_ext, tile_t):
     """Forward over tiles of T: (lse, blank, emit) float32 [B, T, U+1];
     ``emit[..., u]`` is the log-probability of labels_ext[:, u] (the
@@ -313,8 +314,9 @@ def _rnnt_joint_loss(e, p, w_o, b_o, labels_ext, input_lens, label_lens,
 def _joint_loss_fwd(e, p, w_o, b_o, labels_ext, input_lens, label_lens,
                     tile_t):
     lse, blank, emit = _joint_picks(e, p, w_o, b_o, labels_ext, tile_t)
-    emit = _mask_emit(emit[:, :, :-1], label_lens)
-    nll, alpha = _lattice_nll(blank, emit, input_lens, label_lens)
+    with jax.named_scope("rnnt_lattice"):
+        emit = _mask_emit(emit[:, :, :-1], label_lens)
+        nll, alpha = _lattice_nll(blank, emit, input_lens, label_lens)
     return nll, (e, p, w_o, b_o, labels_ext, input_lens, label_lens,
                  lse, blank, emit, alpha, nll)
 
@@ -324,11 +326,12 @@ def _joint_loss_bwd(tile_t, residuals, g):
      lse, blank, emit, alpha, nll) = residuals
     t_max = e.shape[1]
     v = w_o.shape[1]
-    beta = _beta_rows(blank, emit, input_lens, label_lens)
-    ob, oe = _occupancies(alpha, beta, blank, emit, nll, input_lens,
-                          label_lens)
-    g = g.astype(jnp.float32)[:, None, None]
-    ob, oe = ob * g, oe * g
+    with jax.named_scope("rnnt_lattice"):
+        beta = _beta_rows(blank, emit, input_lens, label_lens)
+        ob, oe = _occupancies(alpha, beta, blank, emit, nll, input_lens,
+                              label_lens)
+        g = g.astype(jnp.float32)[:, None, None]
+        ob, oe = ob * g, oe * g
     vidx = jnp.arange(v)
     is_label = vidx[None, None, :] == labels_ext[:, :, None]  # [B,U+1,V]
 
@@ -356,11 +359,13 @@ def _joint_loss_bwd(tile_t, residuals, g):
     init = (jnp.zeros(w_o.shape, jnp.float32),
             jnp.zeros(b_o.shape, jnp.float32),
             jnp.zeros(p.shape, jnp.float32))
-    (dw, db, dp), de = jax.lax.scan(
-        tile, init, (_tiles(e, tile_t), _tiles(lse, tile_t),
-                     _tiles(ob, tile_t), _tiles(oe, tile_t)))
-    return (_untile(de, t_max).astype(e.dtype), dp.astype(p.dtype),
-            dw.astype(w_o.dtype), db.astype(b_o.dtype), None, None, None)
+    with jax.named_scope("rnnt_joint"):
+        (dw, db, dp), de = jax.lax.scan(
+            tile, init, (_tiles(e, tile_t), _tiles(lse, tile_t),
+                         _tiles(ob, tile_t), _tiles(oe, tile_t)))
+        return (_untile(de, t_max).astype(e.dtype), dp.astype(p.dtype),
+                dw.astype(w_o.dtype), db.astype(b_o.dtype), None, None,
+                None)
 
 
 _rnnt_joint_loss.defvjp(_joint_loss_fwd, _joint_loss_bwd)

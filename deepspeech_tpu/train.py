@@ -135,9 +135,15 @@ def select_loss_fn(cfg: Config, mesh=None):
 
         def mean_loss(logits, labels, lens, label_lens):
             return jnp.mean(per_utt(logits, labels, lens, label_lens))
+    else:
+        mean_loss = ctc_loss_mean
 
-        return mean_loss
-    return ctc_loss_mean
+    def ctc_loss(logits, labels, lens, label_lens):
+        # The scope the device's time is read under (obs/layers.py).
+        with jax.named_scope("ctc_loss"):
+            return mean_loss(logits, labels, lens, label_lens)
+
+    return ctc_loss
 
 
 def create_train_state(cfg: Config, rng: jax.Array, sample_batch: Dict,
@@ -353,10 +359,12 @@ def make_train_step(cfg: Config, model, optimizer, mesh, state_sh,
     def step_fn(state: TrainState, batch: Dict) -> Tuple[TrainState, Dict]:
         loss, new_stats, grads = forward(state, batch)
         new_stats, routing = split_aux(new_stats)
-        grad_norm = optax.global_norm(grads)
-        updates, new_opt = optimizer.update(grads, opt_state_at(state),
-                                            state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("grad_norm"):
+            grad_norm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, opt_state_at(state), state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params,
                                batch_stats=new_stats, opt_state=new_opt)
         metrics = {"loss": loss, "grad_norm": grad_norm, **routing}
@@ -366,18 +374,21 @@ def make_train_step(cfg: Config, model, optimizer, mesh, state_sh,
                         ctl: Dict) -> Tuple[TrainState, Dict]:
         loss, new_stats, grads = forward(state, batch)
         new_stats, routing = split_aux(new_stats)
-        grad_norm = optax.global_norm(grads)
-        # The backoff multiplies the schedule INSIDE the optimizer
-        # (injected learning_rate hyperparam), so momentum bookkeeping
-        # and the recorded lr both see the backed-off step.
-        updates, new_opt = optimizer.update(
-            grads, opt_state_at(state, ctl["lr_scale"]), state.params)
-        # Health is judged on the RAW update norm (what an unscaled
-        # step would have applied) so the soft-anomaly statistics
-        # don't shift with the backoff level; lr enters the emitted
-        # update linearly, so dividing the scale back out is exact.
-        update_norm = optax.global_norm(updates) / ctl["lr_scale"]
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("grad_norm"):
+            grad_norm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            # The backoff multiplies the schedule INSIDE the optimizer
+            # (injected learning_rate hyperparam), so momentum
+            # bookkeeping and the recorded lr both see the backed-off
+            # step.
+            updates, new_opt = optimizer.update(
+                grads, opt_state_at(state, ctl["lr_scale"]), state.params)
+            # Health is judged on the RAW update norm (what an unscaled
+            # step would have applied) so the soft-anomaly statistics
+            # don't shift with the backoff level; lr enters the emitted
+            # update linearly, so dividing the scale back out is exact.
+            update_norm = optax.global_norm(updates) / ctl["lr_scale"]
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params,
                                batch_stats=new_stats, opt_state=new_opt)
         ok = (jnp.isfinite(loss) & jnp.isfinite(grad_norm)
@@ -939,6 +950,14 @@ class Trainer:
                                 self.state, metrics = self.train_step(
                                     self.state, sharded)
                         if obs.tracer.enabled:
+                            # The step's layer table, for a reader after
+                            # the run: the new state stands for the
+                            # donated one (same shapes and shardings).
+                            obs.layers.watch(
+                                "train_step", self.train_step,
+                                (self.state, sharded) + (
+                                    () if self.guardian is None else
+                                    ({"lr_scale": np.float32(1)},)))
                             # Attribution without giving up the
                             # overlap: the traced loop blocks here on
                             # the step whose line is owed, the one

@@ -1640,3 +1640,35 @@ def test_journal_report_verify_classifies_records(tmp_path):
     text = journal_report.render(report)
     assert "verify: 1 decodable  1 incompatible  1 corrupt" in text
     assert "[corrupt]" in text and "[incompatible]" in text
+
+
+def test_layer_sums_puts_a_traces_events_under_the_programs_layers():
+    """``tools/layer_sums.py``: device events named by their HLO
+    instructions + the compiled module's text -> ms a unit by layer and
+    direction; a loop's own event is left out (its body's are counted),
+    an event no program holds is ``(unmatched)``."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import layer_sums
+    finally:
+        sys.path.remove(os.path.join(REPO, "tools"))
+    from test_obs_layers import HLO
+
+    from deepspeech_tpu.obs import layers
+
+    programs = {"step.hlo": layers.instruction_scopes(HLO)}
+    events = [
+        ("%fusion.638 = f32[4]{0} fusion(%x.1), kind=kLoop", 0.004),
+        ("%fusion.638 = f32[4]{0} fusion(%x.1), kind=kLoop", 0.004),
+        ("%dot.9 = f32[4]{0:T(128)} dot(%x, %y)", 0.006),
+        ("%while.3 = (s32[], f32[4]{0}) while(%t), body=%body.7", 0.5),
+        ("%copy.1 = f32[4]{0} copy(%z)", 0.002),
+    ]
+    out = layer_sums.sums(events, programs, units=2, chips=1)
+    got = {(r["layer"], r["direction"]): r["ms"] for r in out["layers"]}
+    assert got == {("optimizer", "fwd"): 4.0, ("rnn_wx", "fwd"): 3.0,
+                   ("(unmatched)", "fwd"): 1.0}
+    assert out["ms_a_unit"] == 8.0
+    top = out["rows"][0]
+    assert (top["instruction"], top["shape"], top["calls"]) \
+        == ("fusion", "f32[4]", 1.0)

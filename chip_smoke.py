@@ -117,6 +117,16 @@ DW_H_TIMES_UNDER_NOISE = 20
 BF16_COLUMN_SUM_RTOL = 2.0 ** -7
 # Traced calls a side of pair_input_grad's timing.
 PAIR_TIMED_CALLS = 10
+# xing4_29b_a4b's hyper-connection of one sub-layer: the positions of
+# a prefill sub-batch and of a drafting step, and (streams, width).
+MHC_CALLS = {"prefill": 6784, "decode": 512}
+MHC_STREAMS = (4, 3584)
+# The kernels' float32 coefficients against the jax.numpy form's
+# (sigmoids in (0, 2), a doubly stochastic matrix: absolute), and their
+# bf16 results, over the larger of the value and 1: one bf16 ulp.
+MHC_COEF_ATOL = 1e-5
+MHC_BF16_RTOL = 2.0 ** -7
+MHC_TIMED_CALLS = 10
 # Streamed finals against the offline decode of the same audio: the
 # two graphs reduce in different orders in bf16, so an argmax near a
 # tie may flip; more than this is a wrong stream, not rounding.
@@ -407,6 +417,7 @@ def phase_reference() -> dict:
     out.update(dw_h_precision(interpret))
     out.update(pair_input_grad(interpret))
     out.update(attention_forms())
+    out.update(mhc_passes(interpret))
     t, v, lmax = 100, 29, 20
     logits = jnp.asarray(rng.normal(size=(b, t, v)), jnp.float32)
     label_lens = jnp.asarray(rng.integers(lmax // 2, lmax + 1, size=b),
@@ -617,22 +628,17 @@ def dw_h_precision(interpret: bool) -> dict:
     return out
 
 
-def scan_bwd_ms(sides, calls: int) -> dict:
-    """Median device milliseconds of every backward scan kernel that
-    runs while each of ``sides`` (thunks) is called ``calls`` times in
-    turn under the profiler, by ``(reverse, sum)`` of the call's
-    ``kernel_metadata`` (``sum``: ``pair`` or, where the fact is
-    absent, ``own``). {} where the trace holds no TPU plane (the CPU
-    rehearsal)."""
+def device_ops(sides, calls: int) -> list:
+    """``(event name, device milliseconds)`` of every operation that
+    runs on a TPU while each of ``sides`` (thunks) is called ``calls``
+    times in turn under the profiler. [] where the trace holds no TPU
+    plane (the CPU rehearsal)."""
     import glob
-    import statistics
 
     import jax
 
-    from benchmark.layer_metrics._kernel_id import is_scan_bwd, kernel_facts
     from benchmark.reduce import xplane
 
-    seen = {}
     with tempfile.TemporaryDirectory() as trace_dir:
         jax.profiler.start_trace(trace_dir)
         try:
@@ -641,15 +647,29 @@ def scan_bwd_ms(sides, calls: int) -> dict:
                     jax.block_until_ready(side())
         finally:
             jax.profiler.stop_trace()
-        for path in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                              recursive=True):
-            for device in xplane.load(path).devices.values():
-                for start, end, name in device.ops:
-                    facts = kernel_facts(name)
-                    if is_scan_bwd(facts.get("kernel", "")):
-                        seen.setdefault(
-                            (facts["reverse"], facts.get("sum", "own")),
-                            []).append((end - start) / 1e6)
+        return [(name, (end - start) / 1e6)
+                for path in glob.glob(
+                    os.path.join(trace_dir, "**", "*.xplane.pb"),
+                    recursive=True)
+                for device in xplane.load(path).devices.values()
+                for start, end, name in device.ops]
+
+
+def scan_bwd_ms(sides, calls: int) -> dict:
+    """Median device milliseconds of every backward scan kernel that
+    runs while :func:`device_ops` calls ``sides``, by ``(reverse,
+    sum)`` of the call's ``kernel_metadata`` (``sum``: ``pair`` or,
+    where the fact is absent, ``own``). {} off the chip."""
+    import statistics
+
+    from benchmark.layer_metrics._kernel_id import is_scan_bwd, kernel_facts
+
+    seen = {}
+    for name, ms in device_ops(sides, calls):
+        facts = kernel_facts(name)
+        if is_scan_bwd(facts.get("kernel", "")):
+            seen.setdefault((facts["reverse"], facts.get("sum", "own")),
+                            []).append(ms)
     return {key: statistics.median(ms) for key, ms in seen.items()}
 
 
@@ -738,6 +758,119 @@ def pair_input_grad(interpret: bool) -> dict:
     for name, key in (("first", ("0", "own")), ("own", ("1", "own")),
                       ("summing", ("1", "pair"))):
         out[f"pair_bwd_{name}_ms"] = ms.get(key)
+    return out
+
+
+def mhc_passes(interpret: bool) -> dict:
+    """One sub-layer's hyper-connection at xing4_29b_a4b's two calls
+    (``MHC_CALLS`` positions of ``MHC_STREAMS``, bf16, seeded
+    parameters with the preset's bias of std 1), two ways on the same
+    streams: ``models/mhc.py``'s ``jax.numpy`` form
+    (``HyperConnection``, ``read``, ``write``) and the kernels
+    ``mhc_read`` / ``mhc_write`` (``ops/mhc_pallas.py``). The kernels'
+    coefficients, read mix and written streams are held to the form's
+    (``MHC_COEF_ATOL``, ``MHC_BF16_RTOL``). Both write-backs take the
+    SAME sub-layer output, the plain form's read mix: the kernel's own
+    is another rounding of the same sum at some values (each as near
+    the float64 sum), and fed back it comes through ``H_post`` as up
+    to two ulps (0.0214 for the limit's 0.0078 on the chip). Beside
+    them, each way called ``MHC_TIMED_CALLS`` times under the
+    profiler: the device time of each kernel (its Mosaic call alone:
+    XLA's copy of the coefficients carries the call's identity too)
+    with the GB/s of the bytes the call moves (the streams in, the mix
+    out; the streams and the sub-layer's output in, the streams out),
+    of what XLA runs about the kernels (where the streams enter and
+    leave their ``[N, n * D]`` form, the fold of gain and phi), and of
+    everything the ``jax.numpy`` form runs. Off the chip the times are
+    None: not measured."""
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.layer_metrics._kernel_id import kernel_facts
+    from benchmark.reduce import xplane
+    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.models import mhc
+    from deepspeech_tpu.ops import mhc_pallas
+
+    n, d = MHC_STREAMS
+    cfg = dataclasses.replace(get_config("xing4_29b_a4b").model,
+                              hc_streams=n, lfm_hidden=d)
+    layer = mhc.HyperConnection(cfg)
+    count = n * (n + 2)
+    out = {}
+    for call, rows in MHC_CALLS.items():
+        key = jax.random.PRNGKey(rows)
+        x = jax.random.normal(key, (rows, n, d)).astype(jnp.bfloat16)
+        params = layer.init(jax.random.PRNGKey(52), x)["params"]
+
+        @jax.jit
+        def plain_read(x, params):
+            h_pre, h_post, h_res = layer.apply({"params": params}, x)
+            coef = jnp.concatenate(
+                [h_pre, h_post, h_res.reshape(rows, n * n)], axis=-1)
+            return coef, mhc.read(h_pre, x)
+
+        @jax.jit
+        def plain_write(x, y, coef):
+            _, h_post, h_res = mhc.unpack(coef, n)
+            return mhc.write(h_res, h_post, x, y)
+
+        @jax.jit
+        def kernel_read(x, params):
+            return mhc_pallas.read(
+                x, params["norm"], params["phi"], params["alpha"],
+                params["bias"], norm_eps=cfg.lfm_norm_eps,
+                clamp=cfg.hc_res_clamp, iters=cfg.hc_sinkhorn_iters,
+                eps=cfg.hc_eps, interpret=interpret)
+
+        kernel_write = functools.partial(mhc_pallas.write,
+                                         interpret=interpret)
+        want_coef, y = plain_read(x, params)
+        coef, mix = kernel_read(x, params)
+        errs = {"coef": float(jnp.max(jnp.abs(coef - want_coef)))}
+        for name, got, ref in (
+                ("mix", mix, y),
+                ("streams", kernel_write(x, y, coef),
+                 plain_write(x, y, want_coef))):
+            got, ref = (np.asarray(a, np.float32) for a in (got, ref))
+            errs[name] = float(np.max(
+                np.abs(got - ref) / np.maximum(np.abs(ref), 1.0)))
+        out.update({f"mhc_{call}_{k}_err": v for k, v in errs.items()})
+        size = x.dtype.itemsize
+        moved = {"mhc_read": rows * (n * d + d) * size + rows * count * 4,
+                 "mhc_write": rows * (2 * n * d + d) * size
+                 + rows * count * 4}
+        seen, around = {}, 0.0
+        for name, ms in device_ops(
+                [lambda: kernel_write(x, y, kernel_read(x, params)[0])],
+                MHC_TIMED_CALLS):
+            kernel = kernel_facts(name).get("kernel")
+            if kernel in moved and xplane.KERNEL_MARK in name:
+                seen.setdefault(kernel, []).append(ms)
+            else:
+                around += ms
+        for kernel, nbytes in moved.items():
+            ms = statistics.median(seen[kernel]) if seen else None
+            out[f"mhc_{call}_{kernel[4:]}_ms"] = ms
+            out[f"mhc_{call}_{kernel[4:]}_gb_s"] = (
+                nbytes / ms / 1e6 if ms else None)
+        # what XLA runs about the kernels, a call, and the plain form whole
+        plain_ms = sum(ms for _, ms in device_ops(
+            [lambda: plain_write(x, y, plain_read(x, params)[0])],
+            MHC_TIMED_CALLS))
+        for name, ms in (("around", around), ("plain", plain_ms)):
+            out[f"mhc_{call}_{name}_ms"] = (
+                ms / MHC_TIMED_CALLS if seen else None)
+        # the times above are printed with the fault: they say what ran
+        if not (errs["coef"] <= MHC_COEF_ATOL
+                and errs["mix"] <= MHC_BF16_RTOL
+                and errs["streams"] <= MHC_BF16_RTOL):
+            fail(f"the hyper-connection kernels at {rows} positions differ "
+                 f"from the jax.numpy form: {errs} (limits {MHC_COEF_ATOL} "
+                 f"and {MHC_BF16_RTOL}); read so far: {json.dumps(out)}")
     return out
 
 

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import ModelConfig
-from ..ops import attn_pallas, moe, ssd_pallas
+from ..ops import attn_pallas, mhc_pallas, moe, ssd_pallas
 from ..utils.impl import on_tpu
 from . import mhc
 from .axk1 import LatentAttention
@@ -640,9 +640,15 @@ class DecoderLayer(nn.Module):
         if self.cfg.hc_streams == 1:
             y, extra = f(h)
             return h + y, extra
+        hyper = mhc.HyperConnection(self.cfg, name=name)
+        if mhc_pallas.in_kernels(*h.shape[-2:]):
+            with jax.named_scope("mhc"):
+                coef, x = hyper(h, kernels=True)
+            y, extra = f(x)
+            with jax.named_scope("mhc"):
+                return mhc.kernel_write(h, y, coef), extra
         with jax.named_scope("mhc"):
-            h_pre, h_post, h_res = mhc.HyperConnection(
-                self.cfg, name=name)(h)
+            h_pre, h_post, h_res = hyper(h)
             x = mhc.read(h_pre, h)
         y, extra = f(x)
         with jax.named_scope("mhc"):

@@ -72,6 +72,14 @@ fact, not a name:
            ssd_state_step: streams, heads, a head's size, the state's
            size and groups (the grid is b x groups; how many streams
            are LIVE a step is counted by the loop: ``lm_state_updates``)
+  n, d, rows, tile, dtype
+           mhc_read / mhc_write: residual streams, a stream's width,
+           positions of the call (a prefill sub-batch's rows x prefix
+           positions, or the positions a decode step verifies),
+           positions a grid step takes and the streams' dtype (the grid
+           is the ``rows / tile`` tiles, the last one ragged where
+           ``tile`` does not divide ``rows``; every position of a call
+           is computed, so the count of engagement is the calls')
 """
 
 from __future__ import annotations
@@ -102,6 +110,8 @@ KERNELS = frozenset({
     "gqa_attn_decode",    # one query a stream against its cache rows in reach
     "ssd_chunk_scan",     # state-space recurrence over a sequence, in chunks
     "ssd_state_step",     # ... one position a stream, the state in place
+    "mhc_read",           # hyper-connection: coefficients + the read mix
+    "mhc_write",          # ... the streams after the sub-layer, one pass
 })
 
 

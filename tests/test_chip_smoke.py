@@ -74,6 +74,9 @@ def test_toy_run_takes_every_phase_in_order(smoke, monkeypatch, capsys):
     monkeypatch.setattr(smoke, "SYNC_N", 64)
     monkeypatch.setattr(smoke, "SCAN_CALL", (2, 6))
     monkeypatch.setattr(smoke, "MLA_CALL", (1, 8))
+    monkeypatch.setattr(smoke, "MHC_CALLS", {"prefill": 40})
+    monkeypatch.setattr(smoke, "MHC_STREAMS", (4, 128))
+    monkeypatch.setattr(smoke, "MHC_TIMED_CALLS", 1)
     pid = os.getpid()
     smoke.main([])
     assert os.getpid() == pid
@@ -97,6 +100,9 @@ def test_toy_run_takes_every_phase_in_order(smoke, monkeypatch, capsys):
     assert by_phase["reference"]["mla_positions_compared"] == 2
     assert by_phase["reference"]["mla_forms_rms_rel"] \
         <= smoke.MLA_FORMS_RTOL
+    # ... and a hyper-connection's kernels against the plain form
+    assert by_phase["reference"]["mhc_prefill_coef_err"] \
+        <= smoke.MHC_COEF_ATOL
     serve = by_phase["serve"]
     assert serve["streams"] == 2 and serve["chunks"] >= 2
     assert serve["stream_vs_offline_cer"] <= smoke.STREAM_CER_MAX
@@ -206,3 +212,32 @@ def test_pair_input_grad_holds_the_sum_to_xlas_bits(smoke, monkeypatch):
             x, m, w_f, b_f, w_b * 1.01, b_b, *tail))
     with pytest.raises(SystemExit, match="differs from XLA's"):
         smoke.pair_input_grad(True)
+
+
+def test_mhc_passes_holds_the_kernels_to_the_plain_form(smoke, monkeypatch):
+    """One sub-layer's hyper-connection, 40 and 300 positions of four
+    streams of 128, interpreted: the kernels' coefficients, read mix
+    and written streams are the ``jax.numpy`` form's within the limits,
+    the times are None off the chip (not measured), and kernels whose
+    write-back sees other coefficients end the run."""
+    from deepspeech_tpu.ops import mhc_pallas
+
+    monkeypatch.setattr(smoke, "MHC_CALLS", {"prefill": 300, "decode": 40})
+    monkeypatch.setattr(smoke, "MHC_STREAMS", (4, 128))
+    monkeypatch.setattr(smoke, "MHC_TIMED_CALLS", 1)
+    read = smoke.mhc_passes(True)
+    for call in ("prefill", "decode"):
+        assert 0 < read[f"mhc_{call}_coef_err"] <= smoke.MHC_COEF_ATOL
+        assert read[f"mhc_{call}_streams_err"] <= smoke.MHC_BF16_RTOL
+    timed = {k: v for k, v in read.items() if k.endswith(("_ms", "_gb_s"))}
+    assert set(timed) == {
+        f"mhc_{call}_{what}" for call in ("prefill", "decode")
+        for what in ("read_ms", "read_gb_s", "write_ms", "write_gb_s",
+                     "around_ms", "plain_ms")}
+    assert set(timed.values()) == {None}
+    write = mhc_pallas.write
+    monkeypatch.setattr(mhc_pallas, "write",
+                        lambda x, y, coef, **kw: write(x, y, coef * 1.01,
+                                                       **kw))
+    with pytest.raises(SystemExit, match="differ from the jax.numpy form"):
+        smoke.mhc_passes(True)

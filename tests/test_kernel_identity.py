@@ -137,6 +137,19 @@ CASES = {
     "ssd_state_step_falcon": [
         {"kernel": "ssd_state_step", "b": "128", "heads": "32",
          "head": "128", "state": "256", "groups": "2"}],
+    # xing4_29b_a4b's hyper-connection of one sub-layer: a prefill
+    # sub-batch's 6,784 positions in 53 tiles of 128, a drafting step's
+    # 512 in 4
+    "mhc_xing4_prefill": [
+        {"kernel": "mhc_read", "n": "4", "d": "3584", "rows": "6784",
+         "tile": "128", "dtype": "bfloat16"},
+        {"kernel": "mhc_write", "n": "4", "d": "3584", "rows": "6784",
+         "tile": "128", "dtype": "bfloat16"}],
+    "mhc_xing4_decode": [
+        {"kernel": "mhc_read", "n": "4", "d": "3584", "rows": "512",
+         "tile": "128", "dtype": "bfloat16"},
+        {"kernel": "mhc_write", "n": "4", "d": "3584", "rows": "512",
+         "tile": "128", "dtype": "bfloat16"}],
 }
 
 
@@ -309,7 +322,8 @@ def test_every_name_of_the_vocabulary_is_built_somewhere():
                         ("ctc_pallas.py", r'kernel="(\w+)"'),
                         ("moe_pallas.py", r'kernel="(\w+)"'),
                         ("attn_pallas.py", r'kernel="(\w+)"'),
-                        ("ssd_pallas.py", r'kernel="(\w+)"')):
+                        ("ssd_pallas.py", r'kernel="(\w+)"'),
+                        ("mhc_pallas.py", r'kernel="(\w+)"')):
         with open(os.path.join(REPO, "deepspeech_tpu", "ops", name)) as f:
             used.update(re.findall(named, f.read()))
     assert used == kernel_id.KERNELS

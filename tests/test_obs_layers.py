@@ -305,6 +305,14 @@ STEP_OF = "jit(_decode)/while/body/LFM2ASR.step/checkpoint/"
     (LM + "layer2/layer2.residual/moe/moe_shared/shared/w1/dot_general",
      ("moe_shared", "fwd")),
     (LM + "layer2/layer2.residual/mhc/op_hc/dot_general", ("mhc", "fwd")),
+    # the hyper-connection's Mosaic calls, as the compiled programs of
+    # xing4_29b_a4b name them (PR 52): in the module and beside it
+    ("jit(_prefill)/LFM2ASR.prefill/checkpoint/layer2/layer2.residual/mhc/"
+     "ffn_hc/jit(read)/mhc_read/pallas_call", ("mhc", "fwd")),
+    ("jit(_decode)/while/body/verify/LFM2ASR.verify/checkpoint/layer0/"
+     "layer0.residual/mhc/jit(write)/mhc_write/pallas_call", ("mhc", "fwd")),
+    ("jit(_decode)/while/body/mtp_draft/LFM2ASR.draft/draft0/layer/"
+     "layer.residual/mhc/op_hc/jit(read)/mhc_read/pallas_call", ("mhc", "fwd")),
     (LM + "layer2/layer2.residual/ssm_mixer/mixer/ssd_scan/pallas_call",
      ("ssm_mixer", "fwd")),
     (LM + "out_norm/mul", ("norm", "fwd")),

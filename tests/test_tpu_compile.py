@@ -21,13 +21,12 @@ from _aot_common import AOT_ENV  # noqa: E402
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """Sharding onto one chip of a described v5e:2x2, with the
-    persistent compile cache off (an entry written by a compile for a
-    described device cannot be read back here, and warns)."""
+def v5e():
+    """A described v5e:2x2, with the persistent compile cache off (an
+    entry written by a compile for a described device cannot be read
+    back here, and warns)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     with pytest.MonkeyPatch.context() as mp:
         for key, value in AOT_ENV.items():
@@ -41,10 +40,18 @@ def v5e_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was_on)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e):
+    """Sharding onto one chip of it."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 @pytest.mark.parametrize("case, kernels", [
@@ -114,6 +121,14 @@ def v5e_chip():
     # step in and out under a raised scoped-VMEM limit, aliased
     ("ssd_chunk_scan_falcon", ["ssd_chunk_scan"]),
     ("ssd_state_step_falcon", ["ssd_state_step"]),
+    # xing4_29b_a4b's hyper-connection of one sub-layer, four bfloat16
+    # streams of 3,584: a prefill sub-batch's 6,784 positions (53 tiles
+    # of 128: 3.67 MB of streams a grid step, in twice and in
+    # ``mhc_write`` out twice, the three bf16 parts of ``gain * phi``
+    # resident beside them) and a drafting step's 512, at the scoped
+    # VMEM the calls compute from their shapes
+    ("mhc_xing4_prefill", ["mhc_read", "mhc_write"]),
+    ("mhc_xing4_decode", ["mhc_read", "mhc_write"]),
 ])
 def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     """Mosaic accepts the kernel, and the compiled instruction still
@@ -126,6 +141,52 @@ def test_kernel_compiles_for_v5e(v5e_chip, case, kernels):
     calls = text.split('custom_call_target="tpu_custom_call"')[1:]
     assert sorted(kernel_facts(c)["kernel"] for c in calls) \
         == sorted(kernels)
+
+
+def test_no_float32_copy_of_the_streams_leaves_a_hyper_connection(
+        v5e, monkeypatch, tmp_path, capsys):
+    """xing4_29b_a4b's two served programs at the cell's sizes
+    (``tools/aot_tpu.py --preset xing4_29b_a4b --batch 256 --frames
+    1696 --hlo-out``): each holds the 16 sub-layers' ``mhc_read`` and
+    ``mhc_write`` by name, and no float32 array of the streams' size,
+    ``[.., 4, 3584]`` or ``[positions, 14336]``, exists outside a
+    kernel: before PR 52 XLA gave the write-back's result twice, as
+    bf16 and as the float32 copy the next coefficient product read
+    (the parent's prefill program matches the first pattern below 456
+    times, its decode program 358), and a compiler upgrade cannot
+    bring it back unseen."""
+    import argparse
+    import json
+
+    import aot_tpu
+    from benchmark.layer_metrics._kernel_id import kernel_facts
+    from deepspeech_tpu.config import get_config
+
+    monkeypatch.setenv("DS2N_ASSUME_TPU", "1")  # serve_lm sets it too
+    out = str(tmp_path / "xing4")
+    aot_tpu.serve_lm(
+        argparse.Namespace(preset="xing4_29b_a4b", batch=256, frames=1696,
+                           hlo_out=out),
+        get_config("xing4_29b_a4b"), v5e)
+    said = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    for program, positions in (("prefill", 32 * 212), ("decode", 2 * 256)):
+        with open(f"{out}.{program}") as f:
+            text = f.read()
+        facts = [kernel_facts(c) for c in text.split(
+            'custom_call_target="tpu_custom_call"')[1:]]
+        # the lowered text holds ONE function a kernel, called by the 16
+        # sub-layers (``mhc_pallas.read`` / ``write`` are jitted so that
+        # they are traced and lowered once); compiled, it is 16 calls
+        assert said[program]["mosaic_calls"] == 14 + 2
+        assert len(facts) == 14 + 32 and all(f.get("kernel") for f in facts)
+        ours = [f for f in facts if f["kernel"].startswith("mhc_")]
+        assert sorted(f["kernel"] for f in ours) \
+            == ["mhc_read"] * 16 + ["mhc_write"] * 16
+        assert {f["rows"] for f in ours} == {str(positions)}
+        assert not re.findall(r"f32\[(?:\d+,)*4,3584\]", text)
+        assert not re.findall(rf"f32\[{positions},14336\]", text)
+        assert re.findall(rf"bf16\[{positions},14336\]", text)
+        assert said[program]["peak_estimate_bytes"] < 16 * 1024 ** 3
 
 
 @pytest.mark.parametrize("case, kernels", [

@@ -319,6 +319,31 @@ def kernel_cases():
                 S((streams,), jnp.bool_))
         return lambda: (ssd_pallas.state_step, args)
 
+    def mhc_case(rows):
+        """xing4_29b_a4b's hyper-connection of one sub-layer over
+        ``rows`` positions of four bfloat16 streams of 3,584:
+        ``mhc_read`` (coefficients and read mix from one tile of 128
+        positions, 3.67 MB), a sub-layer that is the identity, and
+        ``mhc_write``, at the scoped VMEM the calls compute."""
+        from deepspeech_tpu.ops import mhc_pallas
+
+        n, d, count = 4, 3584, 24
+        args = (S((rows, n, d), jnp.bfloat16), S((n * d,), jnp.float32),
+                S((n * d, count), jnp.float32), S((3,), jnp.float32),
+                S((count,), jnp.float32))
+
+        def sub_layer(x, gain, phi, alpha, bias):
+            coef, mix = mhc_pallas.read(
+                x, gain, phi, alpha, bias, norm_eps=1e-6,
+                clamp=(-10.0, 10.0), iters=20, eps=1e-6)
+            return coef, mhc_pallas.write(x, mix, coef)
+
+        return lambda: (sub_layer, args)
+
+    # xing4_29b_a4b.transcribe_mtp_16s_b256: a prefill sub-batch's 32 x
+    # 212 positions (53 tiles) and a drafting step's 2 x 256 (4 tiles)
+    cases["mhc_xing4_prefill"] = mhc_case(6784)
+    cases["mhc_xing4_decode"] = mhc_case(512)
     cases["gru_h800"] = gru_case(800)
     cases["gru_h1760"] = gru_case(1760)
     # ds2_full.train_1chip's own call (850 post-conv frames, bf16

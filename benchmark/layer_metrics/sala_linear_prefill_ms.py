@@ -1,0 +1,13 @@
+"""Device milliseconds a call and chip in the kernel ``ssd_chunk_scan``
+as the linear-attention layers run it (a group a head): their sequence
+form over the prefix, three layers x sixteen prefill sub-batches; found
+by the kernel's name in the device trace, in a call of the driver whose
+program has a selection."""
+
+from benchmark.layer_metrics import _kernel_id, by_driver
+
+
+def read(record):
+    if not by_driver.ask(record, "select_calls"):
+        return None
+    return _kernel_id.ms_per_step(record, lambda k: k == "ssd_chunk_scan")

@@ -117,7 +117,7 @@ class ModelConfig:
     # published config's names behind the ``lfm_`` prefix.
     lfm_hidden: int = 2048
     # "conv" | "full_attention" | "sliding_attention" | "latent_attention"
-    # | "ssm_attention"
+    # | "ssm_attention" | "sparse_attention" | "linear_attention"
     lfm_layer_types: Tuple[str, ...] = ()
     lfm_dense_layers: int = 1        # leading layers with the dense FFN
     lfm_heads: int = 32
@@ -267,6 +267,36 @@ class ModelConfig:
     mup_ssm: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     mup_ssm_out: float = 1.0
     mup_mlp: Tuple[float, float] = (1.0, 1.0)
+    # ... and on every sub-layer's output before it joins the residual
+    # (``scale_depth / sqrt(num_hidden_layers)`` of ``model_type:
+    # minicpm_sala``, with the PUBLISHED depth).
+    mup_residual: float = 1.0
+    # The block selection of a layer of kind "sparse_attention"
+    # (InfLLM-v2, the ``minicpm4`` mixer of ``model_type: minicpm_sala``):
+    # past ``sparse_dense_len`` rows a query reads block 0
+    # (``sparse_init_blocks``), the blocks of ``sparse_block`` rows that
+    # hold its last ``sparse_window`` rows, and the ``sparse_topk`` best
+    # of the rest, scored through keys pooled over ``sparse_kernel``
+    # rows every ``sparse_stride`` (``models/lfm2.block_scores``).
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_dense_len: int = 8192
+    # A layer of kind "linear_attention" (``lightning-attn``): ``lin_heads``
+    # heads of ``lin_head_dim`` with one constant decay a head,
+    # ``exp(-s_h (1 - l / (lin_depth - 1) + 1e-5))``, ``s_h = 2^(-8 h /
+    # lin_heads)``, l the PUBLISHED index of the layer (``lin_layer_index``,
+    # one entry a layer of ``lfm_layer_types``; entries of other kinds
+    # are not read) and ``lin_depth`` the published depth; rotary
+    # positions at ``lin_rope_theta``.
+    lin_heads: int = 0
+    lin_head_dim: int = 0
+    lin_layer_index: Tuple[int, ...] = ()
+    lin_depth: int = 0
+    lin_rope_theta: float = 1e4
 
     @property
     def time_stride(self) -> int:
@@ -864,6 +894,59 @@ def falcon_h1_34b() -> Config:
     )
 
 
+SALA_PERIOD = ("sparse_attention",) + ("linear_attention",) * 3
+
+
+def minicpm_sala() -> Config:
+    """The first pipeline stage of MiniCPM-SALA (``model_type:
+    minicpm_sala``,
+    https://huggingface.co/openbmb/MiniCPM-SALA/blob/main/config.json)
+    as a decoder-only speech recogniser that is SERVED
+    (``decode.mode="lm_greedy"``) on recordings of 15-20 minutes, every
+    width as published: hidden 4096; published layers 0-3, one
+    ``minicpm4`` mixer (32 query / 2 key-value heads of 128 without
+    positions, q/k norm, a sigmoid output gate; past 8,192 rows a query
+    reads a SELECTION of the cache's blocks of 64: the first, the 32
+    that hold its last 2,048 rows and the 64 best of the rest) to three
+    ``lightning-attn`` mixers (32 heads of 128, rotary, q/k norm, one
+    constant decay a head and published layer, an output norm and
+    gate); dense SwiGLU 16,384; muP: embeddings times 12, every
+    sub-layer's output times 1.4 / sqrt(32), logits over 16; the whole
+    vocabulary of 73,448, untied. No chips share a layer. The cache of
+    a sparse layer is keys, values and POOLED keys, of a linear layer
+    the float32 state alone. ``benchmark/configs/minicpm_sala.json``
+    has the published keys beside these and every reading that is this
+    repo's own."""
+    c = Config(name="minicpm_sala")
+    return _replace(
+        c,
+        model=_replace(
+            c.model, conv_layers=(), conv_channels=(), rnn_layers=0,
+            bidirectional=False, rnn_batch_norm=False, frame_stack=8,
+            vocab_size=73448, lfm_hidden=4096,
+            lfm_layer_types=SALA_PERIOD, lfm_dense_layers=4,
+            lfm_heads=32, lfm_kv_heads=2, lfm_head_dim=128,
+            lfm_rope_kinds=(), lfm_qk_norm=True, lfm_attn_gate=True,
+            lfm_norm_gain_std=0.1, lfm_ffn_dim=16384,
+            lfm_rope_theta=1e4, lfm_norm_eps=1e-6,
+            lfm_seq_positions=19328, lm_tied_head=False,
+            mup_embedding=12.0, mup_lm_head=0.0625,
+            mup_residual=0.2474873734152916,
+            sparse_kernel=32, sparse_stride=16, sparse_block=64,
+            sparse_topk=64, sparse_init_blocks=1, sparse_window=2048,
+            sparse_dense_len=8192, lin_heads=32, lin_head_dim=128,
+            lin_layer_index=(0, 1, 2, 3), lin_depth=32,
+            lin_rope_theta=1e4),
+        data=_replace(c.data, batch_size=32, bucket_frames=(120000,),
+                      max_label_len=4320),
+        train=_replace(c.train, objective="lm", optimizer="adamw",
+                       learning_rate=1e-4, weight_decay=0.0,
+                       grad_clip_norm=1.0, warmup_steps=100),
+        decode=_replace(c.decode, mode="lm_greedy", lm_prefill_rows=2,
+                        lm_watch_rows=1),
+    )
+
+
 PRESETS = {
     "ds2_small": ds2_small,
     "ds2_full": ds2_full,
@@ -878,6 +961,7 @@ PRESETS = {
     "trinity_large": trinity_large,
     "smallthinker_21b_a3b": smallthinker_21b_a3b,
     "falcon_h1_34b": falcon_h1_34b,
+    "minicpm_sala": minicpm_sala,
 }
 
 

@@ -61,6 +61,17 @@ state of every live stream where it lies (the loop's carry; on a TPU
 the kernel ``ssd_state_step`` aliases it), so no second copy of it
 ever exists. The call counts the live streams' steps and the bytes a
 step needs by part (layer weights, head, state, rows in reach).
+A "sparse_attention" layer holds three arrays: keys and values as a
+"full_attention" layer but HEAD-MAJOR, ``[streams, kv_heads, R, head]``
+(whole blocks of ``sparse_block`` rows, a head's block one contiguous
+piece for the kernel that fetches blocks by index), and the POOLED keys
+``[streams, windows, kv_heads, head]`` its queries rank the blocks by;
+prefill writes the prefix's whole windows, a step whose row ends a
+window writes that window's, and a step past ``sparse_dense_len``
+rows reads the selected blocks only: the call counts the rows read
+against the rows held, the pooled keys read and written. A
+"linear_attention" layer holds its float32 state ``[streams, heads,
+head, head]`` alone.
 """
 
 from __future__ import annotations
@@ -74,8 +85,9 @@ import numpy as np
 
 from .. import obs
 from ..config import Config
-from ..models.lfm2 import (ATTENTION_KINDS, HYBRID, attends_in_kernels,
-                           create_lfm2_model, head_dim, ring_positions,
+from ..models.lfm2 import (ATTENTION_KINDS, HYBRID, LINEAR, SPARSE,
+                           attends_in_kernels, create_lfm2_model, head_dim,
+                           pooled_rows, ring_positions, rows_selected,
                            seq_positions, uncached_kinds)
 from ..ops import attn_pallas
 
@@ -88,14 +100,17 @@ STEP_BRANCHES = BRANCHES + ("branch_mlp",)
 
 
 def _watched(mid: dict, rows, layers: List[str], mixed: str = "",
-             gated=(), hybrid: str = "", branches=BRANCHES) -> dict:
+             gated=(), hybrid: str = "", branches=BRANCHES,
+             selecting: str = "") -> dict:
     """Of a pass's sown outputs, the ``rows`` of the batch: the last
     expert layer's router scores and combine weights and every expert
     layer's chosen sets (a stack with expert layers); the
     feed-forward's hyper-connection coefficients of the layer
     ``mixed``, where the residual has streams; the gated attention
-    output (before ``o``) of the layers ``gated``; and the ``branches``
-    of the hybrid layer ``hybrid``, each output apart."""
+    output (before ``o``) of the layers ``gated`` ((layer, module)
+    names); the ``branches`` of the hybrid layer ``hybrid``, each
+    output apart; and the blocks the queries of the sparse layer
+    ``selecting`` chose, ``[.., positions, kv_heads x blocks]``."""
     out = {}
     if layers:
         def of(name, key):
@@ -107,10 +122,16 @@ def _watched(mid: dict, rows, layers: List[str], mixed: str = "",
                "chosen": jnp.stack([of(n, "experts") for n in layers])}
         for key in ("h_pre", "h_post", "h_res") if mixed else ():
             out[key] = mid[mixed]["ffn_hc"][key][0][rows[0]]
-    for i, name in enumerate(gated):
-        out[f"gated{i}"] = mid[name]["attn"]["gated"][0][rows[0]]
+    for i, (name, module) in enumerate(gated):
+        out[f"gated{i}"] = mid[name][module]["gated"][0][rows[0]]
     for key in branches if hybrid else ():
         out[key] = mid[hybrid][key][0][rows[0]]
+    if selecting:
+        sel = mid[selecting]["sparse"]["selected"][0]   # [B, kv, (Q,) NB]
+        if sel.ndim == 3:                               # a step: one query
+            sel = sel[:, :, None]
+        sel = jnp.moveaxis(sel[rows[0]], 1, 2)          # [w, Q, kv, NB]
+        out["selected"] = sel.reshape(sel.shape[:2] + (-1,))
     return out
 
 
@@ -128,7 +149,8 @@ class LMGreedy:
             raise NotImplementedError(
                 "decode.mode='lm_greedy' needs a cache for every layer "
                 f"kind; {cfg.name!r} lacks " + " and ".join(missing)
-                + " (latent attention, grouped-query attention and the "
+                + " (latent attention, grouped-query attention, with or "
+                "without a block selection, linear attention and the "
                 "hybrid of a state-space mixer beside attention have "
                 "their decode forms)")
         self.cfg = cfg
@@ -156,13 +178,29 @@ class LMGreedy:
                 "full_attention": ("full_attention", HYBRID)}
         self.kinds = {k: [i for i, t in enumerate(m.lfm_layer_types)
                           if t in sees[k]] for k in ATTENTION_KINDS}
-        self.gated = [f"layer{self.kinds[k][-1]}" for k in (
+        self.gated = [(f"layer{self.kinds[k][-1]}", "attn") for k in (
             "sliding_attention", "full_attention") if self.kinds[k]]
-        # The hybrid layers, whose caches hold a recurrent state; of the
-        # last, the two branches' outputs are given out apart.
-        self.stateful = [i for i, t in enumerate(m.lfm_layer_types)
-                         if t == HYBRID]
-        self.hybrid = f"layer{self.stateful[-1]}" if self.stateful else ""
+        # The layers whose queries read a selection of their cache's
+        # blocks, and those that hold a state alone; of each the last
+        # layer's gated output is given out too.
+        self.selecting = [i for i, t in enumerate(m.lfm_layer_types)
+                          if t == SPARSE]
+        self.linear = [i for i, t in enumerate(m.lfm_layer_types)
+                       if t == LINEAR]
+        self.gated += [(f"layer{layers[-1]}", module) for layers, module in (
+            (self.selecting, "sparse"), (self.linear, "lin")) if layers]
+        # The layers whose caches hold a recurrent state (where in the
+        # layer's arrays: after a hybrid layer's keys and values); of
+        # the last hybrid layer the two branches' outputs are given out
+        # apart.
+        self.state_at = {i: 2 if t == HYBRID else 0
+                         for i, t in enumerate(m.lfm_layer_types)
+                         if t in (HYBRID, LINEAR)}
+        self.stateful = sorted(self.state_at)
+        hybrids = [i for i in self.stateful if self.state_at[i]]
+        self.hybrid = f"layer{hybrids[-1]}" if hybrids else ""
+        self.chooser = (f"layer{self.selecting[-1]}"
+                        if self.selecting else "")
         self._cache = None
         self._calls = 0
         self.last_call: Optional[dict] = None
@@ -192,12 +230,15 @@ class LMGreedy:
         watched = min(self.cfg.decode.lm_watch_rows, rows)
         watch = _watched(state.get("intermediates", {}),
                          (slice(0, watched), a), self.sparse, self.mixed,
-                         self.gated, self.hybrid)
+                         self.gated, self.hybrid, selecting=self.chooser)
         if self.stateful:
-            # What the last hybrid layer holds after the prefix: the
+            # What the last stateful layer holds after the prefix: the
             # steps then update it where it lies.
-            watch["state"], watch["conv"] = (
-                x[:watched] for x in new[self.stateful[-1]][2:])
+            last = self.stateful[-1]
+            held = new[last][self.state_at[last]:]
+            watch["state"] = held[0][:watched]
+            if len(held) > 1:
+                watch["conv"] = held[1][:watched]
         return cache, a_lens, counters, watch, draft
 
     @staticmethod
@@ -272,12 +313,31 @@ class LMGreedy:
                 for key, n in self._fetched(a_lens + j, active,
                                             cache).items():
                     acc[key] += n
-            else:
+            if self.selecting:
+                # Rows the selection read of the rows held, the pooled
+                # keys it ranked them by (past ``sparse_dense_len``
+                # rows) and those the step wrote, over the sparse
+                # layers; the first three a stream, summed on the host:
+                # a call's rows held pass 2^31.
+                at, n = a_lens + j, len(self.selecting)
+                read = n * jnp.where(active, rows_selected(m, at), 0)
+                ranked = at >= m.sparse_kernel - 1
+                past = at + 1 - m.sparse_kernel
+                acc["select_rows_read"] += read
+                acc["select_rows_held"] += n * reach
+                acc["select_windows_read"] += n * jnp.where(
+                    active & ranked & (at + 1 > m.sparse_dense_len),
+                    past // m.sparse_stride + 1, 0)
+                acc["pooled_key_writes"] += n * jnp.sum(
+                    active & ranked & (past % m.sparse_stride == 0))
+                acc["cache_rows_read"] += jnp.sum(read)
+            if not self.selecting and not any(self.kinds.values()):
                 acc["cache_rows_read"] += jnp.sum(reach)
             self._count(acc, counters)
             mid = _watched(state.get("intermediates", {}), (watch, 1),
                            self.sparse, gated=self.gated,
-                           hybrid=self.hybrid, branches=STEP_BRANCHES)
+                           hybrid=self.hybrid, branches=STEP_BRANCHES,
+                           selecting=self.chooser)
             mid["logits"] = logits[watch][:, None, :]
             seen = {k: jax.lax.dynamic_update_slice_in_dim(
                 seen[k], mid[k], j, axis=seen[k].ndim - 2) for k in seen}
@@ -303,8 +363,23 @@ class LMGreedy:
             seen.update({f"gated{i}": jnp.zeros((w, t, width),
                                                 jnp.dtype(m.dtype))
                          for i in range(len(self.gated))})
+        if self.selecting or self.linear:
+            seen.update({f"gated{i}": jnp.zeros(
+                (w, t, m.lin_heads * m.lin_head_dim if module == "lin"
+                 else m.lfm_heads * head_dim(m)), jnp.dtype(m.dtype))
+                for i, (_, module) in enumerate(self.gated)})
+        if self.selecting:
+            acc.update({k: jnp.zeros(b, jnp.int32) for k in (
+                "select_rows_read", "select_rows_held",
+                "select_windows_read")},
+                pooled_key_writes=jnp.int32(0))
+            blocks = -(-cache[self.selecting[-1]][0].shape[2]
+                       // m.sparse_block)
+            seen["selected"] = jnp.zeros(
+                (w, t, m.lfm_kv_heads * blocks), bool)
         if self.stateful:
             acc["state_updates"] = jnp.int32(0)
+        if self.hybrid:
             seen.update({k: jnp.zeros((w, t, m.lfm_hidden),
                                       jnp.dtype(m.dtype))
                          for k in STEP_BRANCHES})
@@ -483,15 +558,18 @@ class LMGreedy:
 
     def cache_shapes(self, rows: int, frames: int) -> list:
         """Each layer's cache (a draft module's after them) for ``rows``
-        streams whose prefix is ``frames`` feature frames, as a list of
-        ``jax.ShapeDtypeStruct`` a layer: one array for latent
-        attention; keys and values for grouped-query attention,
+        streams whose prefix is ``frames`` feature frames, as
+        ``jax.ShapeDtypeStruct`` s a layer: a list of one array for
+        latent attention, else a tuple: keys and values for
+        grouped-query attention,
         ``model.lfm_seq_positions`` rows a stream, or (0) the least
         that hold the prefix and every step, a windowed layer's ring
         ``lfm_window`` rows where that is fewer; for a hybrid layer
         keys, values, the mixer's state in float32 and the
-        convolution's last inputs. All but the state are in the
-        model's dtype."""
+        convolution's last inputs; for a sparse layer keys and values
+        (whole blocks of ``sparse_block`` rows) and the pooled keys of
+        their whole windows; for a linear layer its float32 state
+        alone. All but the states are in the model's dtype."""
         m = self.cfg.model
         dtype = jnp.dtype(m.dtype)
         positions = seq_positions(m, frames, self.cfg.data.max_label_len)
@@ -502,6 +580,16 @@ class LMGreedy:
         latent = arrays((rows, positions, m.mla_kv_rank + m.mla_rope_dim))
 
         def of(kind):
+            if kind == LINEAR:
+                return [jax.ShapeDtypeStruct(
+                    (rows, m.lin_heads, m.lin_head_dim, m.lin_head_dim),
+                    jnp.float32)]
+            if kind == SPARSE:
+                held = -(-positions // m.sparse_block) * m.sparse_block
+                # head-major: a head's block of rows is one piece
+                return arrays((rows, m.lfm_kv_heads, held, head_dim(m))) \
+                    * 2 + arrays((rows, pooled_rows(m, held),
+                                  m.lfm_kv_heads, head_dim(m)))
             if kind not in ATTENTION_KINDS + (HYBRID,):
                 return latent
             ring = kind == "sliding_attention" and m.lfm_window
@@ -516,14 +604,17 @@ class LMGreedy:
             ] + arrays((rows, m.ssm_conv - 1,
                         m.ssm_d_ssm + 2 * m.ssm_groups * m.ssm_state))
 
-        return [of(k) for k in m.lfm_layer_types] \
+        # an array alone (a list of one) for latent rows, else a tuple
+        held = [of(k) for k in m.lfm_layer_types]
+        return [s if s is latent else tuple(s) for s in held] \
             + [latent] * m.lm_draft_layers
 
     def cache_for(self, rows: int, frames: int) -> list:
         """The cache of ``rows`` streams whose prefix is ``frames``
         feature frames: an array a latent layer or draft module, the
         pair (keys, values) a grouped-query layer, (keys, values,
-        state, convolution inputs) a hybrid layer."""
+        state, convolution inputs) a hybrid layer, (keys, values,
+        pooled keys) a sparse layer, (state,) a linear layer."""
         shapes = self.cache_shapes(rows, frames)
 
         def spec(cache):
@@ -532,9 +623,9 @@ class LMGreedy:
         if self._cache is None or spec(self._cache) != spec(shapes):
             self._cache = None  # free the old one first
             self._cache = [
-                jnp.zeros(s[0].shape, s[0].dtype) if len(s) == 1
-                else tuple(jnp.zeros(x.shape, x.dtype) for x in s)
-                for s in shapes]
+                tuple(jnp.zeros(x.shape, x.dtype) for x in s)
+                if isinstance(s, tuple)
+                else jnp.zeros(s[0].shape, s[0].dtype) for s in shapes]
             gauge = obs.registry().gauge
             gauge("lm_cache_bytes", cache_bytes(self._cache))
             for kind, name in (("sliding_attention", "window"),
@@ -542,28 +633,43 @@ class LMGreedy:
                 if self.kinds[kind]:
                     gauge("lm_cache_bytes_" + name, cache_bytes(
                         [self._cache[i][:2] for i in self.kinds[kind]]))
-            for at, name in ((2, "state"), (3, "conv")):
-                if self.stateful:
-                    gauge("lm_cache_bytes_" + name, cache_bytes(
-                        [self._cache[i][at] for i in self.stateful]))
+            if self.stateful:
+                gauge("lm_cache_bytes_state", cache_bytes(
+                    [self._cache[i][at] for i, at in self.state_at.items()]))
+            if self.hybrid:
+                gauge("lm_cache_bytes_conv", cache_bytes(
+                    [self._cache[i][at + 1]
+                     for i, at in self.state_at.items() if at]))
+            if self.selecting:
+                gauge("lm_cache_bytes_select", cache_bytes(
+                    [self._cache[i][:2] for i in self.selecting]))
+                gauge("lm_cache_bytes_pooled", cache_bytes(
+                    [self._cache[i][2] for i in self.selecting]))
         cache, self._cache = self._cache, None
         return cache
 
     def step_bytes(self, acc: dict) -> dict:
         """Bytes a call's decode steps NEED to move, by part, from the
         loop's counters: every layer's weights and the head once a
-        step, each live (stream, hybrid layer)'s state read once and
-        written once, and the cache rows in reach, keys and values."""
+        step, each live (stream, stateful layer)'s state read once and
+        written once, the cache rows in reach (under a selection: the
+        selected rows), keys and values, and (``select``) the pooled
+        keys a selection ranked its blocks by."""
         steps = int(acc["steps"])
         named = {k: cache_bytes(v) for k, v in self.params.items()}
         head = named["embed" if self.cfg.model.lm_tied_head else "lm_head"]
         layers = sum(v for k, v in named.items() if k.startswith("layer"))
         m = self.cfg.model
-        return {"weights": steps * layers, "head": steps * head,
-                "state": int(acc["state_updates"]) * 2 * 4
-                * m.ssm_d_ssm * m.ssm_state,
-                "rows": int(acc["cache_rows_read"]) * 2 * m.lfm_kv_heads
-                * head_dim(m) * jnp.dtype(m.dtype).itemsize}
+        state = (m.ssm_d_ssm * m.ssm_state if self.hybrid
+                 else m.lin_heads * m.lin_head_dim ** 2)
+        row = m.lfm_kv_heads * head_dim(m) * jnp.dtype(m.dtype).itemsize
+        out = {"weights": steps * layers, "head": steps * head,
+               "state": int(acc["state_updates"]) * 2 * 4 * state,
+               "rows": int(acc["cache_rows_read"]) * 2 * row}
+        if self.selecting:
+            out["select"] = int(np.sum(
+                acc["select_windows_read"], dtype=np.int64)) * row
+        return out
 
     def transcribe(self, features, feat_lens, max_tokens=None,
                    forced=None, watch=None) -> Dict:

@@ -14,12 +14,22 @@ experts. A fifth's (``model_type: falcon_h1``) is the HYBRID layer: a
 Mamba-2 state-space mixer (``Mamba2Mixer``; the recurrence in
 ``ops/ssd_pallas.py``) and grouped-query attention side by side on one
 normed input, summed, with muP multipliers that are the preset's data
-(``mup_*``) and no expert layer. ``hidden`` / ``loss``
+(``mup_*``) and no expert layer. A sixth's (``model_type:
+minicpm_sala``) has two layer kinds of its own: "sparse_attention",
+grouped-query attention whose queries, past ``sparse_dense_len`` rows,
+read a SELECTION of their cache's blocks (``Attention.select_sequence``
+/ ``select_step``: keys mean-pooled over windows, a query's scores
+against them pooled to blocks, the first block, a local window and the
+top-k of the rest; ``ops/attn_pallas.py`` has the two kernels), and
+"linear_attention" (``LinearAttention``: one constant decay a head, on
+``ops/ssd_pallas.py``'s recurrence), each sub-layer's output times the
+family's depth scaling (``mup_residual``). ``hidden`` / ``loss``
 are the training path (the draft modules are not trained here);
 ``prefill`` and ``step`` the serving path through a cache
 (``decode/lm_greedy.py``), for the layer kinds that have one (latent
-and grouped-query attention: rows by position; the hybrid layer: rows
-and a recurrent state), and
+and grouped-query attention: rows by position, under a selection with
+pooled keys beside them; the hybrid layer: rows and a recurrent state;
+linear attention: a state alone), and
 ``verify`` / ``draft`` its form over a few positions a stream, for a
 loop that drafts for itself.
 
@@ -351,6 +361,151 @@ def cached_attend(q, keys, values, pos, window: int):
     return jnp.einsum("bgrk,bkgd->bgrd", probs, values)
 
 
+SPARSE = "sparse_attention"     # queries read a selection of blocks
+LINEAR = "linear_attention"     # a constant-decay state, no rows
+_OUT = float("-inf")
+_FORCED = 1e30
+
+
+def selected_blocks(cfg: ModelConfig) -> int:
+    """Blocks a query past ``sparse_dense_len`` reads at most: the
+    first ones, those that hold its last ``sparse_window`` rows, and
+    the ``sparse_topk`` best of the rest."""
+    return (cfg.sparse_init_blocks + cfg.sparse_window // cfg.sparse_block
+            + cfg.sparse_topk)
+
+
+def select_list_len(cfg: ModelConfig) -> int:
+    """Entries of the decode form's index list, which holds the blocks
+    BEFORE the local window (that is read as one run): what a query
+    under the selection reads of them, or every such block of a sequence
+    of ``sparse_dense_len`` rows, whole steps of the kernel."""
+    local = cfg.sparse_window // cfg.sparse_block
+    n = max(selected_blocks(cfg),
+            -(-cfg.sparse_dense_len // cfg.sparse_block)) - local
+    per = attn_pallas.SELECT_PER_STEP
+    return -(-n // per) * per
+
+
+def pooled_rows(cfg: ModelConfig, rows: int) -> int:
+    """Pooled keys a cache of ``rows`` rows holds: its whole windows of
+    ``sparse_kernel`` rows every ``sparse_stride``, up to a multiple of
+    8."""
+    n = max((rows - cfg.sparse_kernel) // cfg.sparse_stride + 1, 1)
+    return -(-n // 8) * 8
+
+
+def pool_keys(cfg: ModelConfig, k):
+    """``Kc_j = mean(k[stride j : stride j + kernel])`` over the whole
+    windows of ``k [B, S, kv, hd]``: ``[B, n, kv, hd]`` (n at least 1:
+    zeros where the sequence holds no whole window), summed in
+    float32."""
+    s = k.shape[1]
+    n = (s - cfg.sparse_kernel) // cfg.sparse_stride + 1
+    if n < 1:
+        return jnp.zeros((k.shape[0], 1) + k.shape[2:], k.dtype)
+    rows = (np.arange(n)[:, None] * cfg.sparse_stride
+            + np.arange(cfg.sparse_kernel)[None, :])
+    return jnp.mean(k[:, rows].astype(jnp.float32), axis=2).astype(k.dtype)
+
+
+def block_scores(cfg: ModelConfig, q, pooled, t, dense, blocks: int):
+    """What the selection ranks, ``[B, kv, Q, blocks]`` float32: for
+    the queries ``q [B, Q, kv, rep, hd]`` at rows ``t [B, Q]`` against
+    the pooled keys ``pooled [B, n, kv, hd]``: per head a softmax over
+    the windows whole inside ``0 .. t``, summed over the key/value
+    head's ``rep`` heads, a block the maximum over the windows that
+    overlap it; a block every query reads (the first ones and those of
+    the local window; all in reach where the query's sequence is
+    ``dense``, ``[B, Q]`` bool) scores ``_FORCED`` and one past the
+    query's own ``-inf``."""
+    kernel, stride, block = (cfg.sparse_kernel, cfg.sparse_stride,
+                             cfg.sparse_block)
+    n = pooled.shape[1]
+    logits = jnp.einsum("bqgrd,bjgd->bgrqj", q, pooled,
+                        preferred_element_type=jnp.float32)
+    logits = logits * (q.shape[-1] ** -0.5)
+    whole = (np.arange(n) * stride + kernel - 1)[None, None, :] \
+        <= t[:, :, None]                                   # [B, Q, n]
+    at = whole[:, None, None]
+    probs = jax.nn.softmax(jnp.where(at, logits, -1e30), axis=-1)
+    score = jnp.sum(jnp.where(at, probs, 0.0), axis=2)     # [B, g, Q, n]
+    score = jnp.where(whole[:, None], score, _OUT)
+    # the windows that overlap block b: j_lo(b) .. j_hi(b)
+    b0 = np.arange(blocks) * block
+    lo = -((kernel - 1 - b0) // stride)
+    hi = (b0 + block - 1) // stride
+    table = lo[:, None] + np.arange(int(np.max(hi - lo)) + 1)[None, :]
+    table = np.where((table >= 0) & (table <= hi[:, None]) & (table < n),
+                     table, n)
+    padded = jnp.pad(score, [(0, 0)] * 3 + [(0, 1)],
+                     constant_values=_OUT)
+    by_block = jnp.max(padded[..., table], axis=-1)        # [B, g, Q, NB]
+    mine = (t // block)[:, None, :, None]
+    b = np.arange(blocks)[None, None, None, :]
+    reach = b <= mine
+    forced = (b < cfg.sparse_init_blocks) \
+        | (b > mine - cfg.sparse_window // block) \
+        | dense[:, None, :, None]
+    return jnp.where(reach, jnp.where(forced, _FORCED, by_block), _OUT)
+
+
+def select_mask(cfg: ModelConfig, scores):
+    """``[..., blocks]`` bool: the :func:`selected_blocks` best of
+    :func:`block_scores` (all forced ones among them, however many),
+    and nothing out of reach."""
+    k = selected_blocks(cfg)
+    if scores.shape[-1] <= k:
+        return scores > _OUT
+    least = jax.lax.top_k(scores, k)[0][..., -1:]
+    return (scores >= least) & (scores > _OUT)
+
+
+def selected_attend(q, k, v, sel, i0: int, block: int):
+    """The plain mixing under a selection, and the kernels' oracle:
+    queries ``i0 ..`` ``q [B, sq, kv, rep, hd]`` against the keys ``0
+    ..`` ``k, v [B, sk, kv, hd]``, query i reading the keys ``j <= i``
+    of the blocks ``sel [B, kv, sq, NB]`` marks."""
+    sq, sk = q.shape[1], k.shape[1]
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores * (q.shape[-1] ** -0.5)
+    seen = sel[..., np.arange(sk) // block] & reach_mask(i0, sq, 0, sk, 0)
+    scores = jnp.where(seen[:, :, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
+
+
+def cached_attend_selected(q, keys, values, sel, pos, block: int):
+    """The decode form's plain mixing under a selection, and the
+    kernel's oracle: one query a stream ``q [B, kv, rep, hd]`` at row
+    ``pos [B]`` against the rows ``<= pos`` of the blocks ``sel [B, kv,
+    NB]`` marks of ``keys, values [B, kv, R, hd]``; zeros where nothing
+    is marked (a stream that is not live). The cache is head-major,
+    ``[B, kv, R, hd]``."""
+    rows = np.arange(keys.shape[2])
+    seen = sel[..., rows // block] & (rows[None, None, :]
+                                      <= pos[:, None, None])
+    scores = jnp.einsum("bgrd,bgkd->bgrk", q, keys,
+                        preferred_element_type=jnp.float32)
+    scores = jnp.where(seen[:, :, None],
+                       scores * (q.shape[-1] ** -0.5), -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bgrk,bgkd->bgrd", probs, values)
+    return jnp.where(jnp.any(sel, axis=-1)[..., None, None], out, 0)
+
+
+def rows_selected(cfg: ModelConfig, pos, xp=jnp):
+    """Cache rows a query at row ``pos`` reads in a sparse layer: all
+    ``pos + 1`` up to ``sparse_dense_len``, else those ``<= pos`` of
+    its :func:`selected_blocks` blocks (fewer where fewer are in
+    reach)."""
+    block = cfg.sparse_block
+    blocks = xp.minimum(pos // block + 1, selected_blocks(cfg))
+    chosen = (blocks - 1) * block + pos % block + 1
+    return xp.where(pos + 1 <= cfg.sparse_dense_len, pos + 1, chosen)
+
+
 class Attention(nn.Module):
     """Causal grouped-query attention: RMSNorm over each head of q and
     of k (``lfm_qk_norm``), then the rotation where the layer's
@@ -395,6 +550,8 @@ class Attention(nn.Module):
         hd, rep = head_dim(cfg), nh // nkv
         window = cfg.lfm_window if self.kind == "sliding_attention" else 0
         scope = "gqa_attn_" + ("window" if window else "global")
+        if self.kind == SPARSE:
+            scope = SPARSE
         if cfg.mup_attn_in != 1.0:
             h = scaled(h, cfg.mup_attn_in)
         q = Linear(nh * hd, name="q")(h).reshape(b, s, nh, hd)
@@ -441,7 +598,9 @@ class Attention(nn.Module):
                                    v[:, j0:i1], i0, j0))
             return jnp.concatenate(outs, axis=1)
 
-        if cache is None:
+        if cache is None and self.kind == SPARSE:
+            out, kept = self.select_sequence(q, k, v, live)
+        elif cache is None:
             kept = (k, v)
             with jax.named_scope(scope):
                 if s <= self.block:
@@ -455,6 +614,9 @@ class Attention(nn.Module):
                 f"grouped-query attention decodes one new position a "
                 f"stream, not {s}: several at once (a loop that verifies "
                 f"its drafts) is latent attention's alone")
+        elif self.kind == SPARSE:
+            out, kept = self.select_step(
+                q[:, 0], k[:, 0], v[:, 0], cache, pos[:, 0], live[:, 0])
         else:
             with jax.named_scope(scope):
                 at, r = pos[:, 0], cache[0].shape[1]
@@ -482,6 +644,200 @@ class Attention(nn.Module):
         if cfg.mup_attn_out != 1.0:
             out = scaled(out, cfg.mup_attn_out)
         return out, kept
+
+    def select_sequence(self, q, k, v, valid):
+        """The sequence form of a "sparse_attention" layer: the pooled
+        keys of the whole windows inside each stream's ``valid``
+        positions (zeros past them), every query's selection in blocks
+        of ``block`` queries (no array over all queries and all
+        windows), and the mixing under it (the kernel
+        ``gqa_attn_select_fwd`` past one block where
+        :func:`attends_in_kernels` holds). Returns the output ``[B, S,
+        kv, rep, hd]`` and the cache's rows ``(k, v, pooled)``, keys
+        and values HEAD-MAJOR ``[B, kv, S, hd]`` as the cache holds
+        them (a head's block of rows one contiguous piece)."""
+        cfg = self.cfg
+        b, s = q.shape[:2]
+        size, step = cfg.sparse_block, self.block
+        lens = jnp.sum(valid, axis=1)
+        blocks = -(-s // size)
+        with jax.named_scope("sparse_select"):
+            pooled = pool_keys(cfg, k)
+            n = pooled.shape[1]
+            whole = (np.arange(n) * cfg.sparse_stride + cfg.sparse_kernel
+                     )[None, :] <= lens[:, None]
+            pooled = jnp.where(whole[..., None, None], pooled, 0)
+            dense = jnp.broadcast_to(
+                (lens <= cfg.sparse_dense_len)[:, None], (b, step))
+            pad = -s % step
+            tiles = jnp.moveaxis(
+                jnp.pad(q, [(0, 0), (0, pad)] + [(0, 0)] * 3).reshape(
+                    (b, -1, step) + q.shape[2:]), 1, 0)
+
+            def chosen(at):
+                tile, i0 = at
+                t = jnp.broadcast_to(i0 + jnp.arange(step)[None, :],
+                                     (b, step))
+                return select_mask(cfg, block_scores(
+                    cfg, tile, pooled, t, dense, blocks))
+
+            sel = jax.lax.map(chosen, (tiles, jnp.arange(
+                0, s + pad, step)))                  # [T, B, kv, step, NB]
+            sel = jnp.moveaxis(sel, 0, 2).reshape(
+                sel.shape[1:3] + (s + pad, blocks))[:, :, :s]
+        self.sow("intermediates", "selected", sel)
+        with jax.named_scope(SPARSE):
+            if s > step and attends_in_kernels(cfg):
+                out = attn_pallas.gqa_select_attention(q, k, v, sel, size)
+            else:
+                out = jnp.concatenate([
+                    selected_attend(q[:, i0:i0 + step], k[:, :i0 + step],
+                                    v[:, :i0 + step], sel[:, :, i0:i0 + step],
+                                    i0, size)
+                    for i0 in range(0, s, step)], axis=1)
+        return out, (k.swapaxes(1, 2), v.swapaxes(1, 2), pooled)
+
+    def select_step(self, q, k, v, cache, at, live):
+        """The decode form of a "sparse_attention" layer: a live stream
+        writes its new row ``k, v [B, kv, hd]`` at ``at [B]`` of the
+        cache ``(keys, values [B, kv, R, hd], pooled)``, and where that
+        row ends a pooling window, the window's pooled key; the query
+        ``q [B, kv, rep, hd]`` ranks the blocks through the pooled keys
+        and reads the selected ones (the kernel
+        ``gqa_attn_select_decode``, which fetches those blocks only,
+        where :func:`attends_in_kernels` holds). Returns the output
+        ``[B, kv, rep, hd]`` (zeros for a stream that is not ``live``)
+        and the cache."""
+        cfg = self.cfg
+        keys, values, pooled = cache
+        b, rows = keys.shape[0], keys.shape[2]
+        kernel, stride, size = (cfg.sparse_kernel, cfg.sparse_stride,
+                                cfg.sparse_block)
+        nkv, hd = keys.shape[1], keys.shape[3]
+        with jax.named_scope("cache_update"):
+            # A (stream, head) a row of a [B x kv, R, hd] view, so that
+            # the write is the scatter a full cache's row write is:
+            # indexed [B, :, at] XLA kept the cache rows-major in the
+            # loop and copied it whole for the kernel every step.
+            line = jnp.arange(b * nkv)
+            slot = (line, jnp.repeat(at, nkv))
+            alive = jnp.repeat(live, nkv)[:, None]
+            keys, values = (
+                c.reshape(b * nkv, rows, hd).at[slot].set(jnp.where(
+                    alive, row.reshape(b * nkv, hd).astype(c.dtype),
+                    c.reshape(b * nkv, rows, hd)[slot]))
+                for c, row in zip((keys, values), (k, v)))
+            # the window's rows by (stream, row), as the rows' own
+            # write is indexed: a slice a stream made XLA copy the
+            # whole cache into another layout every step
+            last = jnp.maximum(at - kernel + 1, 0)[:, None] \
+                + jnp.arange(kernel)[None, :]
+            window = keys[line[:, None], jnp.repeat(last, nkv, axis=0)]
+            new = jnp.mean(window.astype(jnp.float32), axis=1
+                           ).astype(pooled.dtype).reshape(b, nkv, hd)
+            keys, values = (c.reshape(b, nkv, rows, hd)
+                            for c in (keys, values))
+            ends = live & (at >= kernel - 1) \
+                & ((at + 1 - kernel) % stride == 0)
+            slot = (jnp.arange(b), jnp.clip((at + 1 - kernel) // stride, 0,
+                                            pooled.shape[1] - 1))
+            pooled = pooled.at[slot].set(jnp.where(
+                ends[:, None, None], new, pooled[slot]))
+        blocks = -(-rows // size)
+        with jax.named_scope("sparse_select"):
+            sel = select_mask(cfg, block_scores(
+                cfg, q[:, None], pooled, at[:, None],
+                (at + 1 <= cfg.sparse_dense_len)[:, None], blocks))[:, :, 0]
+            sel = sel & live[:, None, None]
+            # the local window's blocks are read as one run of rows
+            local = cfg.sparse_window // size
+            first = jnp.maximum(at // size - local + 1, 0)
+            if attends_in_kernels(cfg):
+                idx, count = attn_pallas.select_list(
+                    sel, select_list_len(cfg), first)
+        self.sow("intermediates", "selected", sel)
+        with jax.named_scope(SPARSE):
+            if attends_in_kernels(cfg):
+                out = attn_pallas.gqa_select_decode(
+                    q, keys, values, idx, count, at, first * size, live,
+                    size, cfg.sparse_window)
+            else:
+                out = cached_attend_selected(q, keys, values, sel, at, size)
+        return out, (keys, values, pooled)
+
+
+def decay_slopes(cfg: ModelConfig, index: int):
+    """``[heads]``: what a "linear_attention" layer's state loses a
+    position, ``lambda_h = exp(-slope_h)``: ``s_h (1 - l / (depth - 1) +
+    1e-5)`` with ``s_h = 2^(-8 h / heads)``, h = 1 .. heads, and l the
+    layer's PUBLISHED index (Lightning Attention-2)."""
+    h = np.arange(1, cfg.lin_heads + 1, dtype=np.float64)
+    layer = 1.0 - index / max(cfg.lin_depth - 1, 1) + 1e-5
+    return (2.0 ** (-8.0 * h / cfg.lin_heads) * layer).astype(np.float32)
+
+
+class LinearAttention(nn.Module):
+    """Linear attention with one constant decay a head
+    (``lightning-attn``): ``q, k = RoPE(RMSNorm_head(W_q x)),
+    RoPE(RMSNorm_head(W_k x))``, ``v = W_v x``; a head's state ``S_t =
+    lambda_h S_{t-1} + k_t^T v_t`` (float32, zero before position 0),
+    ``o_t = q_t S_t / sqrt(head)``; ``W_o (RMSNorm(o_t) *
+    sigmoid(W_g x))``, the norm over all heads' channels with one gain.
+    The recurrence is ``ops/ssd_pallas.py``'s with ``x = v, B = k, C =
+    q, dt = 1`` (0 at padding), ``A = -slope_h``, no skip and as many
+    groups as heads; the division by ``sqrt(head)`` is taken on the
+    float32 output, where it costs no rounding of ``q``.
+
+    ``__call__(h [B, S, D], valid [B, S])`` is the SEQUENCE form and
+    returns the output and the cache: the state after each stream's
+    last valid position ``([B, heads, head, head] float32,)``. With
+    ``cache`` it is the DECODE form, one new position a stream at ``pos
+    [B, 1]``; a stream that is not live leaves its state as it is."""
+
+    cfg: ModelConfig
+    index: int = 0
+
+    @nn.compact
+    def __call__(self, h, valid, pos=None, cache=None):
+        cfg = self.cfg
+        b, s, d = h.shape
+        nh, hd = cfg.lin_heads, cfg.lin_head_dim
+        f32 = jnp.float32
+        std = cfg.lfm_norm_gain_std
+        with jax.named_scope(LINEAR):
+            q, k, v = (Linear(nh * hd, name=n)(h).reshape(b, s, nh, hd)
+                       for n in ("q", "k", "v"))
+            at = None if cache is None else pos
+            if cfg.lfm_qk_norm:
+                q = RMSNorm(cfg.lfm_norm_eps, std, name="q_norm")(q)
+                k = RMSNorm(cfg.lfm_norm_eps, std, name="k_norm")(k)
+            q = rotary(q, cfg.lin_rope_theta, at)
+            k = rotary(k, cfg.lin_rope_theta, at)
+            a = -jnp.asarray(decay_slopes(cfg, self.index))
+            ones = jnp.ones((b, s, nh), f32)
+            if cache is None:
+                y, state = ssd_pallas.ssd_scan(
+                    v, ones, a, k, q, None, valid, cfg.ssm_chunk)
+            elif s != 1:
+                raise NotImplementedError(
+                    f"linear attention decodes one new position a "
+                    f"stream, not {s}")
+            else:
+                y, state = ssd_pallas.ssd_step(
+                    cache[0], v[:, 0], ones[:, 0], a, k[:, 0], q[:, 0],
+                    None, valid[:, 0])
+                y = y[:, None]
+            o = y.reshape(b, s, nh * hd).astype(f32) * (hd ** -0.5)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                                  + cfg.lfm_norm_eps)
+            gain = self.param("o_norm", gain_init(std), (nh * hd,))
+            gate = Linear(nh * hd, name="gate")(h)
+            out = (o * gain * jax.nn.sigmoid(gate.astype(f32))
+                   ).astype(h.dtype)
+        self.sow("intermediates", "gated", out)
+        with jax.named_scope("attn_out"):
+            out = Linear(d, name="o")(out)
+        return out, (state,)
 
 
 def both_forms(cfg: ModelConfig, kind: str, params, x, at, rows: int,
@@ -632,13 +988,18 @@ class DecoderLayer(nn.Module):
 
     cfg: ModelConfig
     kind: str      # "conv" | "latent_attention" | ATTENTION_KINDS | HYBRID
+    #              # | SPARSE | LINEAR
     sparse: bool
+    index: int = 0          # the layer's PUBLISHED index (LINEAR's decay)
 
     def residual(self, name: str, h, f):
         """``h`` after the sub-layer ``f`` (``x -> (y, extra)``, its
-        pre-norm inside), and ``extra``."""
+        pre-norm inside), and ``extra``; the sub-layer's output times
+        the family's depth scaling where it has one (``mup_residual``)."""
         if self.cfg.hc_streams == 1:
             y, extra = f(h)
+            if self.cfg.mup_residual != 1.0:
+                y = scaled(y, self.cfg.mup_residual)
             return h + y, extra
         hyper = mhc.HyperConnection(self.cfg, name=name)
         if mhc_pallas.in_kernels(*h.shape[-2:]):
@@ -674,6 +1035,12 @@ class DecoderLayer(nn.Module):
             if self.kind in ATTENTION_KINDS:
                 return after("op_post_norm", Attention(
                     cfg, self.kind, name="attn")(x, pos, cache, valid))
+            if self.kind == SPARSE:
+                return Attention(cfg, SPARSE, name="sparse")(
+                    x, pos, cache, valid)
+            if self.kind == LINEAR:
+                return LinearAttention(cfg, self.index, name="lin")(
+                    x, valid, pos, cache)
             if self.kind == HYBRID:
                 with jax.named_scope("ssm_mixer"):
                     mixed, held = Mamba2Mixer(cfg, name="mixer")(
@@ -764,6 +1131,7 @@ class LFM2ASR(nn.Module):
         self.prefix = Linear(cfg.lfm_hidden)
         self.layers = [
             layer_cls(cfg, kind, i >= cfg.lfm_dense_layers,
+                      cfg.lin_layer_index[i] if cfg.lin_layer_index else i,
                       name=f"layer{i}")
             for i, kind in enumerate(cfg.lfm_layer_types)]
         self.out_norm = RMSNorm(cfg.lfm_norm_eps, cfg.lfm_norm_gain_std)
@@ -972,7 +1340,8 @@ def stack_counters(counters: list) -> dict:
 def uncached_kinds(cfg: ModelConfig) -> list:
     """The preset's layer kinds that have no decode form yet."""
     return sorted(set(cfg.lfm_layer_types)
-                  - {"latent_attention", HYBRID, *ATTENTION_KINDS})
+                  - {"latent_attention", HYBRID, SPARSE, LINEAR,
+                     *ATTENTION_KINDS})
 
 
 def target_logp(h, embed, layout, labels, label_lens):

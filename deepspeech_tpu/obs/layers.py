@@ -44,7 +44,8 @@ LAYERS = (
     "conv_frontend", "rnn_wx", "rnn_scan", "rnn_dw_h", "norm", "head",
     "ctc_loss", "rnnt_joint", "rnnt_lattice", "optimizer", "grad_norm",
     "attention", "latent_attention", "attn_out", "short_conv",
-    "ssm_mixer", "mlp", "moe_route", "moe_dispatch", "moe_gmm",
+    "ssm_mixer", "sparse_select", "sparse_attention", "linear_attention",
+    "mlp", "moe_route", "moe_dispatch", "moe_gmm",
     "moe_combine", "moe_shared", "mhc", "lm_head", "embed", "draft",
     "cache_update", "collective",
 )
@@ -70,13 +71,16 @@ _SEGMENTS = {
     "gqa_attn_window": "attention", "gqa_attn_global": "attention",
     "mhc": "mhc", "ssm_mixer": "ssm_mixer", "ssd_scan": "ssm_mixer",
     "ssd_step": "ssm_mixer", "mtp_draft": "draft",
+    "sparse_select": "sparse_select", "sparse_attention": "sparse_attention",
+    "linear_attention": "linear_attention",
     # flax modules and their method scopes
     "head": "head", "bn": "norm", "bn_out": "norm", "op_norm": "norm",
     "ffn_norm": "norm", "op_post_norm": "norm", "ffn_post_norm": "norm",
     "out_norm": "norm", "embed_norm": "norm", "hidden_norm": "norm",
     "ln": "norm", "wx": "rnn_wx", "moe.route": "moe_route",
     "router": "moe_route", "moe": "moe_dispatch", "ffn": "mlp",
-    "mixer": "ssm_mixer", "prefix": "embed", "joint": "rnnt_joint",
+    "mixer": "ssm_mixer", "sparse": "sparse_attention",
+    "lin": "linear_attention", "prefix": "embed", "joint": "rnnt_joint",
     "op_hc": "mhc", "ffn_hc": "mhc", "eh_proj": "draft",
     "lookahead": "conv_frontend",
 }
@@ -89,6 +93,8 @@ _SEALED = frozenset({
     "rnn_dw_h", "rnn_wx", "norm", "moe_route", "moe_shared", "moe_gmm",
     "moe_combine", "attn_out", "ssm_mixer", "mhc", "lm_head",
     "cache_update"})
+_MIXERS = ("attention", "latent_attention", "sparse_attention",
+           "sparse_select", "linear_attention")
 _LATENT_WEIGHTS = ("q_a", "q_b", "kv_a", "kv_b", "kv_norm")
 _DECODER_LAYER = re.compile(r"^layer\d*$")
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
@@ -131,8 +137,10 @@ def _one(path: str) -> Tuple[str, str]:
             new = "moe_gmm"  # the experts' matrices, by their names
         elif seg in _LATENT_WEIGHTS and layer == "attention":
             new = "latent_attention"  # an argument's path has no scope
-        elif seg == "o" and layer in ("attention", "latent_attention"):
+        elif seg == "o" and layer in _MIXERS:
             new = "attn_out"
+        elif seg in ("ssd_scan", "ssd_step") and layer == "linear_attention":
+            new = layer  # the recurrence is the layer's, not a mixer's
         elif new is None:
             decoder = decoder or bool(_DECODER_LAYER.match(seg))
             new = next((to for rx, to in _NUMBERED if rx.match(seg)), None)

@@ -224,9 +224,11 @@ def observe_lm_call(prefill: Sequence[Dict], decode: Dict, rows: int
                   + out["empty_groups"]["prefill"])
     for k in ("rows_attended_window", "rows_attended_global",
               "rows_fetched_window", "rows_fetched_global", "ring_wraps",
-              "state_updates"):
+              "state_updates", "select_rows_read", "select_rows_held",
+              "select_windows_read", "pooled_key_writes"):
         if k in decode:
-            out[k] = int(decode[k])
+            # a selection's rows come a stream: their sum passes 2^31
+            out[k] = int(np.sum(decode[k], dtype=np.int64))
             reg.count("lm_" + k, out[k])
     reg.count("lm_decode_steps", steps)
     reg.count("lm_idle_slot_steps", idle)

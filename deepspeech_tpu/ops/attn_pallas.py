@@ -2,7 +2,11 @@
 ``gqa_attn_fwd`` (:func:`gqa_attention`, a whole sequence, described
 here) with its backward pair ``gqa_attn_bwd_dq`` / ``gqa_attn_bwd_dkv``,
 and ``gqa_attn_decode`` (:func:`gqa_decode`, one position a stream
-against its cache, described there).
+against its cache, described there); and both forms under a BLOCK
+SELECTION, ``gqa_attn_select_fwd`` (:func:`gqa_select_attention`) and
+``gqa_attn_select_decode`` (:func:`gqa_select_decode`, which fetches the
+selected blocks only, by a scalar-prefetched index list), at the end of
+the file.
 
 Causal grouped-query attention over a whole sequence: the scores of a
 query tile against a key tile live in VMEM only, under a running row
@@ -761,3 +765,350 @@ def fits(head: int) -> bool:
     """Whether Mosaic takes the kernel's blocks: a head is whole lane
     tiles (K and V are read a head's columns at a time)."""
     return head % 128 == 0
+
+
+# -- attention under a block selection ---------------------------------------
+#
+# A layer whose queries read a SELECTION of the cache's blocks of
+# ``block`` rows (``models/lfm2.select_mask``: one selection a (query,
+# key/value head)). Two kernels, arithmetic and precisions
+# ``gqa_attn_fwd``'s:
+#
+# ``gqa_attn_select_decode``  one query a stream; the selected blocks
+#     ONLY are fetched: the local window's rows as one run, the others
+#     by a scalar-prefetched index list, ``per_step`` blocks a grid step
+#     (each its own operand of the one cache array, so the pipeline
+#     moves them side by side); the cache is head-major, ``[B, kv, R,
+#     hd]``, so that a head's block is one contiguous piece of HBM;
+# ``gqa_attn_select_fwd``     a whole sequence: ``gqa_attn_fwd``'s
+#     tiles below the diagonal, each under its queries' own selection
+#     (a ``[queries, blocks]`` map of 0 / 1 spread over the tile's keys
+#     by one small product), so that no ``[S, S]`` array exists and no
+#     query's selection is coarsened to its tile's.
+
+# Selected blocks a grid step of the decode kernel fetches, each an
+# operand of its own: 4, 8 and 16 read 1.22, 0.98 and 0.79 ms a step of
+# 32 streams on the chip (PERF.md section 6, PR 54).
+SELECT_PER_STEP = 16
+
+
+def select_list(mask, length: int, first_local, per_step: int =
+                SELECT_PER_STEP):
+    """The decode kernel's index list from a selection ``mask [B, kv,
+    NB]`` (bool): the selected blocks BEFORE the local window (block
+    ``first_local [B]`` and after are read as one run of rows) in
+    ascending order, ``[B, kv, length]`` int32, and how many they are
+    ``[B, kv]``. An entry past the count repeats the entry ``per_step``
+    before it (the same operand of the grid step before: nothing is
+    fetched for it). No sort: a block's rank is the count of selected
+    blocks before it."""
+    nb = mask.shape[-1]
+    block = jnp.arange(nb)
+    mask = mask & (block < first_local[:, None, None])
+    rank = jnp.cumsum(mask, axis=-1) - 1
+    count = jnp.minimum(jnp.sum(mask, axis=-1), length).astype(jnp.int32)
+    at = (rank[..., None, :] == jnp.arange(length)[:, None]) \
+        & mask[..., None, :]                               # [B, kv, L, NB]
+    order = jnp.sum(jnp.where(at, block, 0), axis=-1).astype(jnp.int32)
+    p = jnp.arange(length)
+    back = per_step * ((p - count[..., None]) // per_step + 1)
+    src = jnp.maximum(jnp.where(p < count[..., None], p, p - back), 0)
+    return jnp.take_along_axis(order, src, axis=-1), count
+
+
+def gqa_select_decode(q, keys, values, idx, count, pos, first_row, live,
+                      block: int, window: int,
+                      per_step: int = SELECT_PER_STEP,
+                      interpret: bool = False):
+    """The DECODE form under a selection as ONE Pallas kernel,
+    ``gqa_attn_select_decode``: ``q [B, kv, rep, hd]`` at row ``pos
+    [B]`` against the stream's cache ``keys, values [B, kv, R, hd]``
+    (head-major: a head's block of rows is one contiguous piece of HBM;
+    the new row written): the rows ``first_row .. pos`` (the local
+    window: at most ``window`` rows, fetched as ONE run) and the blocks
+    ``idx [B, kv, L]`` before them (``count [B, kv]`` of them valid,
+    :func:`select_list`), fetched by index. Returns ``[B, kv, rep,
+    hd]``, zeros for a stream that is not ``live [B]``.
+
+    The grid is (key/value head, stream, list step), the list
+    innermost. The first step of a (head, stream) takes the window's
+    rows (an element-offset block: the run starts at any row); every
+    step takes ``per_step`` list entries, each a block ``[block, hd]``
+    of K and of V through an operand of its own, in one product of the
+    head's ``rep`` queries against their ``per_step x block`` rows. A
+    step past the count fetches nothing and computes nothing; a stream
+    that is not live holds the blocks of the live stream before it."""
+    b, nkv, rep, hd = q.shape
+    rows, length, n = keys.shape[2], idx.shape[-1], per_step
+    if length % n or rows % block:
+        raise ValueError(
+            f"a list of {length} is not whole steps of {n}, or {rows} "
+            f"cache rows are not whole blocks of {block}")
+    steps, cols = length // n, n * block
+    wide = min(window, rows)                   # the run's rows, as fetched
+    scale = hd ** -0.5
+    lanes = 128 if cols % 128 == 0 and wide % 128 == 0 \
+        and hd % 128 == 0 else 1
+    pos = pos.astype(jnp.int32)
+    # Where the run is fetched from: its first row, or earlier where it
+    # would hang over the cache's end (the mask knows the rows).
+    start = jnp.clip(first_row, 0, rows - wide).astype(jnp.int32)
+    # The live stream at or before each stream: what one that is not
+    # live holds, so that it moves nothing (before the first live one,
+    # the grid's first blocks, fetched whatever they are).
+    stream = jnp.arange(b)
+    before = jnp.max(jnp.where(
+        (stream[None, :] <= stream[:, None]) & live[None, :],
+        stream[None, :], -1), axis=1)
+    held = jnp.maximum(before, 0)
+    # ... and of its list the entries its operands held last
+    last = jnp.tile(idx[..., -n:], (1, 1, steps))
+    idx = jnp.where(live[:, None, None], idx, last[held])
+    count = jnp.where(live[:, None], count, 0)
+    idx = jnp.moveaxis(idx, 1, 0).reshape(nkv * b, length).astype(jnp.int32)
+    bounds = jnp.stack([
+        jnp.moveaxis(count, 1, 0).reshape(nkv * b),
+        jnp.tile(pos, nkv), jnp.tile(first_row.astype(jnp.int32), nkv),
+        jnp.tile(start[held], nkv), jnp.tile(held, nkv),
+        jnp.tile(live.astype(jnp.int32), nkv)]).astype(jnp.int32)
+
+    def body(idx_ref, bounds_ref, q_ref, kw_ref, vw_ref, *rest):
+        k_refs, v_refs = rest[:n], rest[n:2 * n]
+        o_ref, m_ref, l_ref, acc_ref = rest[2 * n:]
+        at = pl.program_id(0) * b + pl.program_id(1)
+        step = pl.program_id(2)
+        held_, t = bounds_ref[0, at], bounds_ref[1, at]
+        is_live = bounds_ref[5, at] == 1
+
+        def update(scores, seen, v):
+            width = scores.shape[1]
+            scores = jnp.where(seen, scores * scale, _MASKED)
+            m_prev = m_ref[...]
+            m_next = jnp.maximum(
+                m_prev, jnp.max(scores, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.where(seen, jnp.exp(scores - _wide(m_next, width)), 0.0)
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(
+                p, axis=1, keepdims=True)
+            m_ref[...] = m_next
+            acc_ref[...] = _wide(alpha, hd) * acc_ref[...] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+        @pl.when(step == 0)
+        def _start():
+            m_ref[...] = jnp.full_like(m_ref, _MASKED)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        @pl.when(jnp.logical_and(step == 0, is_live))
+        def _window():
+            row = bounds_ref[3, at] + lax.broadcasted_iota(
+                jnp.int32, (1, wide), 1)
+            seen = jnp.logical_and(row >= bounds_ref[2, at], row <= t)
+            update(lax.dot_general(
+                q_ref[...], kw_ref[0, 0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32), seen, vw_ref[0, 0])
+
+        @pl.when(step * n < held_)
+        def _blocks():
+            k = jnp.concatenate([r[...] for r in k_refs], axis=0)
+            v = jnp.concatenate([r[...] for r in v_refs], axis=0)
+            # column c is row c % block of the list's entry c // block
+            col = lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+            entry = col // block
+            first = jnp.zeros((1, cols), jnp.int32)
+            for i in range(n):
+                first = jnp.where(entry == i,
+                                  idx_ref[at, step * n + i] * block, first)
+            seen = jnp.logical_and(step * n + entry < held_,
+                                   first + col % block <= t)
+            update(lax.dot_general(
+                q_ref[...], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32), seen, v)
+
+        @pl.when(step == steps - 1)
+        def _finish():
+            o_ref[...] = jnp.where(
+                is_live, acc_ref[...] / _wide(
+                    jnp.maximum(l_ref[...], 1e-30), hd),
+                0.0).astype(o_ref.dtype)
+
+    def q_index(g, bi, step, idx_ref, bounds_ref):
+        return bi, g, 0, 0
+
+    def window_index(g, bi, step, idx_ref, bounds_ref):
+        at = g * b + bi
+        # the run starts at a block's first row (the cache is whole
+        # blocks): Mosaic has to know it lies on a tile of rows
+        return (bounds_ref[4, at], g,
+                pl.multiple_of(bounds_ref[3, at], block), 0)
+
+    def block_index(i):
+        def index(g, bi, step, idx_ref, bounds_ref):
+            at = g * b + bi
+            return (bounds_ref[4, at], g, idx_ref[at, step * n + i], 0, 0)
+        return index
+
+    # (Mosaic takes element offsets in every dimension of a block or
+    # in none: the run's block is [1, 1, wide, hd] at (stream, head,
+    # first row, 0))
+    run = pl.BlockSpec((pl.Element(1), pl.Element(1), pl.Element(wide),
+                        pl.Element(hd)), window_index)
+    blocks = [pl.BlockSpec((None, None, None, block, hd), block_index(i))
+              for i in range(n)]
+    facts = {"b": b, "rows": rows, "kv": nkv, "rep": rep, "head": hd,
+             "block": block, "window": wide, "list": length, "per_step": n}
+    tiled = tuple(c.reshape(b, nkv, rows // block, block, hd)
+                  for c in (keys, values))
+    return kernel_call(
+        body, kernel="gqa_attn_select_decode", facts=facts,
+        cost_estimate=pl.CostEstimate(
+            flops=4 * b * nkv * rep * (length * block + wide) * hd,
+            transcendentals=b * nkv * rep * (length * block + wide),
+            bytes_accessed=(2 * b * nkv * (length * block + wide) * hd
+                            + 2 * b * nkv * rep * hd) * q.dtype.itemsize),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, rep, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            in_specs=[pl.BlockSpec((None, None, rep, hd), q_index),
+                      run, run] + blocks + blocks,
+            out_specs=pl.BlockSpec((None, None, rep, hd), q_index),
+            grid=(nkv, b, steps),
+            scratch_shapes=[pltpu.VMEM((rep, lanes), jnp.float32),
+                            pltpu.VMEM((rep, lanes), jnp.float32),
+                            pltpu.VMEM((rep, hd), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(idx, bounds, q, keys, values, *([tiled[0]] * n), *([tiled[1]] * n))
+
+
+# Queries of one head and keys a tile of the sequence form under a
+# selection: a key tile is ``K_TILE / block`` blocks.
+SELECT_Q_TILE = 256
+_SELECT_VMEM = 64 * 1024 * 1024
+
+
+def gqa_select_attention(q, k, v, sel, block: int,
+                         q_tile: int = SELECT_Q_TILE, k_tile: int = K_TILE,
+                         interpret: bool = False):
+    """The SEQUENCE form under a selection as ONE Pallas kernel,
+    ``gqa_attn_select_fwd``: ``q [B, S, kv, rep, hd]``, ``k, v [B, S,
+    kv, hd]``, ``sel [B, kv, S, NB]`` (1 where query i of key/value
+    head g reads block j of ``block`` rows, else 0; block 0 is read by
+    every query); query i attends to the keys ``j <= i`` of its
+    selected blocks. Returns ``[B, S, kv, rep, hd]``.
+
+    ``gqa_attn_fwd``'s grid and tiles without a window: (row, key/value
+    head, query tile, key tile). A tile's mask is its queries' rows of
+    ``sel`` at the tile's ``k_tile / block`` blocks, spread over the
+    keys by a product with a 0 / 1 matrix (``[q_tile, 128] x [128,
+    k_tile]``: a few per cent of the tile's work), and the causal rule.
+    Every tile at or below the diagonal is computed: a later change may
+    skip the tiles no query of which selects a block."""
+    b, s, nkv, rep, hd = q.shape
+    tq, tk = q_tile, k_tile
+    per = tk // block                       # blocks a key tile
+    if tk % block or 128 % per:
+        raise ValueError(f"key tiles of {tk} are not whole blocks of "
+                         f"{block}, {per} of which must divide 128")
+    nb = sel.shape[-1]
+    sel = jnp.pad(sel.astype(q.dtype),
+                  [(0, 0)] * 3 + [(0, -nb % 128)])
+    scale = hd ** -0.5
+    first, last, _, _ = reach(s, 0, tq, tk)
+    steps = int(np.max(last - first + 1))
+    ragged = s % tk != 0
+    lanes = 128 if tk % 128 == 0 and hd % 128 == 0 else 1
+
+    def last_of(qi):
+        return (jnp.minimum(qi * tq + tq, s) - 1) // tk
+
+    def body(q_ref, k_ref, v_ref, sel_ref, o_ref, m_ref, l_ref, acc_ref):
+        qi, step = pl.program_id(2), pl.program_id(3)
+        i0, j0 = qi * tq, step * tk
+
+        @pl.when(step == 0)
+        def _start():
+            m_ref[...] = jnp.full_like(m_ref, _MASKED)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        @pl.when(step <= last_of(qi))
+        def _tile():
+            keys, values = k_ref[...], v_ref[...]
+            at = i0 + lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+            col = lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+            # the tile's blocks among the 128 the map's tile holds
+            spread = lax.broadcasted_iota(jnp.int32, (128, 1), 0) \
+                == (step * per) % 128 + col // block
+            chosen = jnp.dot(sel_ref[...], spread.astype(sel_ref.dtype),
+                             preferred_element_type=jnp.float32)
+            seen = jnp.logical_and(chosen > 0.5, j0 + col <= at)
+            if ragged:
+                held = j0 + lax.broadcasted_iota(
+                    jnp.int32, (tk, 1), 0) < s
+                values = jnp.where(held, values,
+                                   jnp.zeros((), values.dtype))
+            for r in range(rep):
+                scores = lax.dot_general(
+                    q_ref[:, r * hd:(r + 1) * hd], keys,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                scores = jnp.where(seen, scores, _MASKED)
+                m_prev = m_ref[r]
+                m_next = jnp.maximum(
+                    m_prev, jnp.max(scores, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_next)
+                p = jnp.where(seen, jnp.exp(scores - _wide(m_next, tk)),
+                              0.0)
+                l_ref[r] = alpha * l_ref[r] + jnp.sum(
+                    p, axis=1, keepdims=True)
+                m_ref[r] = m_next
+                acc_ref[r] = _wide(alpha, hd) * acc_ref[r] + jnp.dot(
+                    p.astype(values.dtype), values,
+                    preferred_element_type=jnp.float32)
+
+        @pl.when(step == steps - 1)
+        def _finish():
+            for r in range(rep):
+                o_ref[r] = (acc_ref[r] / _wide(
+                    jnp.maximum(l_ref[r], 1e-30), hd)).astype(o_ref.dtype)
+
+    def q_index(bi, g, qi, step):
+        return bi, qi, g
+
+    def kv_index(bi, g, qi, step):
+        return bi, jnp.minimum(step, last_of(qi)), g
+
+    def sel_index(bi, g, qi, step):
+        return bi, g, qi, jnp.minimum(step, last_of(qi)) * per // 128
+
+    def out_index(bi, g, qi, step):
+        return bi, g, 0, qi, 0
+
+    facts = {"b": b, "s": s, "kv": nkv, "rep": rep, "head": hd,
+             "block": block, "q_tile": tq, "k_tile": tk,
+             "key_tiles": int(np.sum(last - first + 1))}
+    out = kernel_call(
+        body, kernel="gqa_attn_select_fwd", facts=facts,
+        out_shape=jax.ShapeDtypeStruct((b, nkv, rep, s, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            in_specs=[pl.BlockSpec((None, tq, rep * hd), q_index),
+                      pl.BlockSpec((None, tk, hd), kv_index),
+                      pl.BlockSpec((None, tk, hd), kv_index),
+                      pl.BlockSpec((None, None, tq, 128), sel_index)],
+            out_specs=pl.BlockSpec((None, None, rep, tq, hd), out_index),
+            grid=(b, nkv, len(first), steps),
+            scratch_shapes=[pltpu.VMEM((rep, tq, lanes), jnp.float32),
+                            pltpu.VMEM((rep, tq, lanes), jnp.float32),
+                            pltpu.VMEM((rep, tq, hd), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_SELECT_VMEM),
+        interpret=interpret,
+    )(q.reshape(b, s, nkv * rep * hd), k.reshape(b, s, nkv * hd),
+      v.reshape(b, s, nkv * hd), sel)
+    return _laid_out(out, q.shape)

@@ -62,6 +62,17 @@ fact, not a name:
            stream (how many of them a stream VISITS follows its
            position and is counted by the loop:
            ``lm_rows_fetched_window`` / ``_global``)
+  b, rows, kv, rep, head, block, list, per_step
+           gqa_attn_select_decode: streams, rows of a stream's cache,
+           key/value heads, query heads each serves, a head's size,
+           rows a block of the selection, entries of the index list a
+           (stream, key/value head) and entries a grid step fetches
+           (how many entries are VALID follows the position and is
+           counted by the loop: ``lm_select_rows_read``)
+  b, s, kv, rep, head, block, q_tile, k_tile, key_tiles
+           gqa_attn_select_fwd: as gqa_attn_fwd without a window, and
+           rows a block of the selection (every tile at or below the
+           diagonal is computed under its queries' selection)
   b, s, heads, head, state, groups, chunk, chunks
            ssd_chunk_scan: rows, positions, the mixer's heads, a head's
            size, the state's size, groups that share B and C, positions
@@ -71,7 +82,9 @@ fact, not a name:
   b, heads, head, state, groups
            ssd_state_step: streams, heads, a head's size, the state's
            size and groups (the grid is b x groups; how many streams
-           are LIVE a step is counted by the loop: ``lm_state_updates``)
+           are LIVE a step is counted by the loop: ``lm_state_updates``);
+           ``group_block`` where a grid step takes several groups (a
+           linear-attention layer's groups are single heads)
   n, d, rows, tile, dtype
            mhc_read / mhc_write: residual streams, a stream's width,
            positions of the call (a prefill sub-batch's rows x prefix
@@ -108,6 +121,8 @@ KERNELS = frozenset({
     "gqa_attn_bwd_dq",    # its backward: dq over a query tile's key tiles
     "gqa_attn_bwd_dkv",   # ... dk, dv over a key tile's query tiles and heads
     "gqa_attn_decode",    # one query a stream against its cache rows in reach
+    "gqa_attn_select_decode",  # ... against the SELECTED blocks, by index
+    "gqa_attn_select_fwd",     # a sequence, each query under its selection
     "ssd_chunk_scan",     # state-space recurrence over a sequence, in chunks
     "ssd_state_step",     # ... one position a stream, the state in place
     "mhc_read",           # hyper-connection: coefficients + the read mix

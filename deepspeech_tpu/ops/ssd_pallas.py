@@ -29,6 +29,12 @@ plain product ``C h``.
     stream before it (as ``gqa_attn_decode`` does) and its output is
     zeros.
 
+A LINEAR-ATTENTION layer with a constant decay a head is the same
+recurrence (``x = v``, ``B = k``, ``C = q / sqrt(head)``, ``dt = 1``,
+``A = -slope``, no skip: ``d`` None) with as many groups as heads; its
+step then takes all groups in one grid step (``group_block``,
+:func:`state_step`), a group's block being one head's 64 KB.
+
 Off the TPU both run as the plain forms below them (``lax.scan`` over
 the positions; one update), which are also the kernels' oracles. A
 build lies in its facts (``ops/kernel_id.py``).
@@ -71,8 +77,8 @@ def _column(row):
 
 def scan_oracle(x, dt, a, bm, cm, d, valid):
     """The recurrence position by position (``lax.scan``), float32:
-    ``x [B, S, H, P]``, ``dt [B, S, H]``, ``a, d [H]``, ``bm, cm [B, S,
-    G, N]``, ``valid [B, S]``. Returns ``y [B, S, H, P]`` in ``x``'s
+    ``x [B, S, H, P]``, ``dt [B, S, H]``, ``a, d [H]`` (``d`` None: no
+    skip), ``bm, cm [B, S, G, N]``, ``valid [B, S]``. Returns ``y [B, S, H, P]`` in ``x``'s
     dtype and the state after each stream's last valid position ``[B,
     H, N, P]`` float32."""
     b, s, h, p = x.shape
@@ -91,7 +97,9 @@ def scan_oracle(x, dt, a, bm, cm, d, valid):
     state, y = lax.scan(
         step, jnp.zeros((b, h, n, p), f32),
         tuple(jnp.moveaxis(v, 1, 0) for v in (x.astype(f32), dt, bm, cm)))
-    y = jnp.moveaxis(y, 0, 1) + d[:, None] * x.astype(f32)
+    y = jnp.moveaxis(y, 0, 1)
+    if d is not None:
+        y = y + d[:, None] * x.astype(f32)
     return y.astype(x.dtype), state
 
 
@@ -201,6 +209,8 @@ def chunk_scan(x, dt, a, bm, cm, d, valid, chunk: int = 128,
     )(rows, whole, x.reshape(b, chunks * q, h * p),
       bm.reshape(b, chunks * q, g * n), cm.reshape(b, chunks * q, g * n))
     y = y.reshape(b, chunks * q, h, p)[:, :s]
+    if d is None:
+        return y, state
     skip = d[:, None] * x[:, :s].astype(f32)
     return (y.astype(f32) + skip).astype(x.dtype), state
 
@@ -230,19 +240,28 @@ def step_oracle(state, x, dt, a, bm, cm, d, live):
     new = jnp.exp(dt * a)[..., None, None] * state.astype(f32) \
         + (dt[..., None] * bm)[..., :, None] * x32[..., None, :]
     new = new.astype(state.dtype)
-    y = jnp.sum(new.astype(f32) * cm[..., :, None], axis=-2) \
-        + d[:, None] * x32
+    y = jnp.sum(new.astype(f32) * cm[..., :, None], axis=-2)
+    if d is not None:
+        y = y + d[:, None] * x32
     at = live[:, None, None]
     return (jnp.where(at, y, 0.0).astype(x.dtype),
             jnp.where(at[..., None], new, state))
 
 
-def state_step(state, x, dt, a, bm, cm, d, live, interpret: bool = False):
+def state_step(state, x, dt, a, bm, cm, d, live, interpret: bool = False,
+               group_block: int = 1):
     """:func:`step_oracle` as the kernel ``ssd_state_step``; the state
-    is float32."""
+    is float32. A grid step takes ``group_block`` groups (their heads'
+    states one block): 1 where a group's heads are many; where a group
+    is one head of 64 KB, all of them (or whole lane tiles of them)."""
     b, h, n, p = state.shape
     g = bm.shape[1]
-    per = h // g
+    per, gb = h // g, group_block
+    if g % gb or (gb > 1 and gb != g and gb % 128):
+        raise ValueError(f"{g} groups are not whole blocks of {gb} (several "
+                         f"groups a step lie along the lanes: all of them, "
+                         f"or whole lane tiles)")
+    held_heads = gb * per
     f32 = jnp.float32
     x32, dt = x.astype(f32), dt.astype(f32)
     # Along the lanes, a row a head: the decay, and dt x.
@@ -259,7 +278,7 @@ def state_step(state, x, dt, a, bm, cm, d, live, interpret: bool = False):
     held = jnp.where(before >= 0, before, first)
     # Such a stream holds the last group of a stream before it, the
     # first group of one after it: the block the grid is at.
-    group = jnp.where(before >= 0, g - 1, 0)
+    group = jnp.where(before >= 0, g // gb - 1, 0)
     bounds = jnp.stack([live.astype(jnp.int32), held, group,
                         jnp.broadcast_to(jnp.any(live), (b,))]
                        ).astype(jnp.int32)
@@ -270,13 +289,18 @@ def state_step(state, x, dt, a, bm, cm, d, live, interpret: bool = False):
 
         @pl.when(bounds_ref[0, bi] == 1)
         def _update():
-            key, read = _column(b_ref[...]), _column(c_ref[...])
-            for i in range(per):
-                new = state_ref[i] * decay_ref[i:i + 1, :] \
-                    + key * xdt_ref[i:i + 1, :]
-                out_ref[i] = new
-                y_ref[i:i + 1, :] = jnp.sum(new * read, axis=0,
-                                            keepdims=True)
+            for j in range(gb):
+                # B and C of a group as columns: made from the row by
+                # its diagonal, or (several groups a step) handed in
+                # with the groups along the lanes, a column a group
+                key, read = (_column(r[...]) if gb == 1 else r[:, j:j + 1]
+                             for r in (b_ref, c_ref))
+                for i in range(j * per, (j + 1) * per):
+                    new = state_ref[i] * decay_ref[i:i + 1, :] \
+                        + key * xdt_ref[i:i + 1, :]
+                    out_ref[i] = new
+                    y_ref[i:i + 1, :] = jnp.sum(new * read, axis=0,
+                                                keepdims=True)
 
         @pl.when(bounds_ref[0, bi] == 0)
         def _idle():
@@ -300,6 +324,15 @@ def state_step(state, x, dt, a, bm, cm, d, live, interpret: bool = False):
         return bi, gi, 0, 0
 
     facts = {"b": b, "heads": h, "head": p, "state": n, "groups": g}
+    if gb > 1:
+        facts["group_block"] = gb
+    if gb == 1:
+        shared = pl.BlockSpec((None, None, 1, n), own_group)
+        keys, reads = (v.astype(f32)[:, :, None, :] for v in (bm, cm))
+    else:       # [B, N, G]: the state's rows down, a group a lane
+        shared = pl.BlockSpec((None, n, gb), lambda bi, gi, bounds_ref:
+                              (bi, 0, gi))
+        keys, reads = (jnp.swapaxes(v.astype(f32), 1, 2) for v in (bm, cm))
     y, state = kernel_call(
         body, kernel="ssd_state_step", facts=facts,
         cost_estimate=pl.CostEstimate(
@@ -309,25 +342,33 @@ def state_step(state, x, dt, a, bm, cm, d, live, interpret: bool = False):
                    jax.ShapeDtypeStruct(state.shape, f32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            in_specs=[pl.BlockSpec((None, per, n, p), state_index),
-                      pl.BlockSpec((None, per, p), own),
-                      pl.BlockSpec((None, per, p), own),
-                      pl.BlockSpec((None, None, 1, n), own_group),
-                      pl.BlockSpec((None, None, 1, n), own_group)],
-            out_specs=(pl.BlockSpec((None, per, p), own),
-                       pl.BlockSpec((None, per, n, p), state_index)),
-            grid=(b, g)),
+            in_specs=[pl.BlockSpec((None, held_heads, n, p), state_index),
+                      pl.BlockSpec((None, held_heads, p), own),
+                      pl.BlockSpec((None, held_heads, p), own),
+                      shared, shared],
+            out_specs=(pl.BlockSpec((None, held_heads, p), own),
+                       pl.BlockSpec((None, held_heads, n, p), state_index)),
+            grid=(b, g // gb)),
         # the state is updated where it lies: argument 1 (after the
         # prefetched scalars) is result 1
         input_output_aliases={1: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=4 * 4 * per * n * p + (16 << 20)),
+            vmem_limit_bytes=4 * 4 * held_heads * n * p + (16 << 20)),
         interpret=interpret,
-    )(bounds, state, decay, xdt, bm.astype(f32)[:, :, None, :],
-      cm.astype(f32)[:, :, None, :])
-    return jnp.where(live[:, None, None], y + d[:, None] * x32, 0.0
-                     ).astype(x.dtype), state
+    )(bounds, state, decay, xdt, keys, reads)
+    if d is not None:
+        y = y + d[:, None] * x32
+    return jnp.where(live[:, None, None], y, 0.0).astype(x.dtype), state
+
+
+def head_group_block(groups: int) -> int:
+    """Groups a grid step of ``ssd_state_step`` where a group is ONE
+    head (a linear-attention layer): all of them (32 heads of [128, 128]
+    float32 are 2 MB a block, a hybrid layer's group of 16 heads of
+    [256, 128]); B and C then come with the groups along the lanes
+    (PERF.md section 6, PR 54 has the sweep)."""
+    return groups
 
 
 def ssd_step(state, x, dt, a, bm, cm, d, live):
@@ -336,5 +377,8 @@ def ssd_step(state, x, dt, a, bm, cm, d, live):
     with jax.named_scope("ssd_step"):
         if in_kernels(x.shape[-1], bm.shape[-1]) \
                 and state.dtype == jnp.float32:
-            return state_step(state, x, dt, a, bm, cm, d, live)
+            heads, groups = x.shape[1], bm.shape[1]
+            gb = head_group_block(groups) if heads == groups > 1 else 1
+            return state_step(state, x, dt, a, bm, cm, d, live,
+                              group_block=gb)
         return step_oracle(state, x, dt, a, bm, cm, d, live)

@@ -559,12 +559,14 @@ class Trainer:
             raise ValueError("objective='lm' excludes accum_steps>1 "
                              "(its step carries the routing counters)")
         if objective == "lm":
-            from .models.lfm2 import HYBRID
+            from .models.lfm2 import HYBRID, LINEAR
             from .ops import ssd_pallas
 
             m = cfg.model
-            if HYBRID in m.lfm_layer_types and ssd_pallas.in_kernels(
-                    m.ssm_d_ssm // m.ssm_heads, m.ssm_state):
+            if (HYBRID in m.lfm_layer_types and ssd_pallas.in_kernels(
+                    m.ssm_d_ssm // m.ssm_heads, m.ssm_state)) or (
+                    LINEAR in m.lfm_layer_types and ssd_pallas.in_kernels(
+                        m.lin_head_dim, m.lin_head_dim)):
                 # Fail at construction, not in the first step's trace.
                 raise NotImplementedError(
                     "objective='lm': the state-space mixer's sequence "

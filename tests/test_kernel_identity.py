@@ -137,6 +137,29 @@ CASES = {
     "ssd_state_step_falcon": [
         {"kernel": "ssd_state_step", "b": "128", "heads": "32",
          "head": "128", "state": "256", "groups": "2"}],
+    # minicpm_sala's sparse layer under its selection (a decode step of
+    # 32 streams: the local window as one run of 2,048 rows and lists
+    # of 96 blocks of 64, 16 a grid step; a prefill
+    # sub-batch of 2 x 15,000 positions: every tile at or below the
+    # diagonal, 900 a (row, key/value head)) and a linear layer's
+    # recurrence with a group a head (118 chunks; all 32 groups a grid
+    # step)
+    "gqa_attn_select_decode_sala": [
+        {"kernel": "gqa_attn_select_decode", "b": "32", "rows": "19328",
+         "kv": "2", "rep": "16", "head": "128", "block": "64",
+         "window": "2048", "list": "96", "per_step": "16"}],
+    "gqa_attn_select_fwd_sala": [
+        {"kernel": "gqa_attn_select_fwd", "b": "2", "s": "15000",
+         "kv": "2", "rep": "16", "head": "128", "block": "64",
+         "q_tile": "256", "k_tile": "512", "key_tiles": "900"}],
+    "ssd_chunk_scan_sala": [
+        {"kernel": "ssd_chunk_scan", "b": "2", "s": "15000", "heads": "32",
+         "head": "128", "state": "128", "groups": "32", "chunk": "128",
+         "chunks": "118"}],
+    "ssd_state_step_sala": [
+        {"kernel": "ssd_state_step", "b": "32", "heads": "32",
+         "head": "128", "state": "128", "groups": "32",
+         "group_block": "32"}],
     # xing4_29b_a4b's hyper-connection of one sub-layer: a prefill
     # sub-batch's 6,784 positions in 53 tiles of 128, a drafting step's
     # 512 in 4

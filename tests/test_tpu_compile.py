@@ -121,6 +121,19 @@ def v5e_chip(v5e):
     # step in and out under a raised scoped-VMEM limit, aliased
     ("ssd_chunk_scan_falcon", ["ssd_chunk_scan"]),
     ("ssd_state_step_falcon", ["ssd_state_step"]),
+    # minicpm_sala's sparse layer under its selection: a decode step of
+    # 32 streams (the local window's [2048, 128] of K and of V at an
+    # element offset; 32 operands of the one cache, a [64, 128] block of
+    # K or V each by a scalar-prefetched index; [16, 2048] float32 scores)
+    # and a prefill sub-batch of 2 x 15,000 positions (16 query heads a
+    # key/value head: 2 MB of float32 accumulators and 4 MB of lane-wide
+    # statistics under a 64 MiB scoped-VMEM limit; the selection map's
+    # tile spread over the keys by a [256, 128] x [128, 512] product);
+    # and a linear layer's recurrence with a group a head
+    ("gqa_attn_select_decode_sala", ["gqa_attn_select_decode"]),
+    ("gqa_attn_select_fwd_sala", ["gqa_attn_select_fwd"]),
+    ("ssd_chunk_scan_sala", ["ssd_chunk_scan"]),
+    ("ssd_state_step_sala", ["ssd_state_step"]),
     # xing4_29b_a4b's hyper-connection of one sub-layer, four bfloat16
     # streams of 3,584: a prefill sub-batch's 6,784 positions (53 tiles
     # of 128: 3.67 MB of streams a grid step, in twice and in

@@ -319,6 +319,64 @@ def kernel_cases():
                 S((streams,), jnp.bool_))
         return lambda: (ssd_pallas.state_step, args)
 
+    def select_decode_case(streams=32, rows=19328):
+        """minicpm_sala's sparse layer in one decode step:
+        ``gqa_attn_select_decode`` alone, ``streams`` caches of ``rows``
+        rows, head-major (2 key/value heads of 128, 16 query heads
+        each): the local window's 2,048 rows as one run and an index
+        list of 96 blocks of 64 a (stream, head), 16 a grid step."""
+        from deepspeech_tpu.ops import attn_pallas
+
+        args = (S((streams, 2, 16, 128), jnp.bfloat16),
+                S((streams, 2, rows, 128), jnp.bfloat16),
+                S((streams, 2, rows, 128), jnp.bfloat16),
+                S((streams, 2, 96), jnp.int32),
+                S((streams, 2), jnp.int32), S((streams,), jnp.int32),
+                S((streams,), jnp.int32), S((streams,), jnp.bool_))
+        return lambda: (lambda *v: attn_pallas.gqa_select_decode(
+            *v, 64, 2048), args)
+
+    def select_fwd_case(rows=2, s=15000):
+        """... and its sequence form over a prefill sub-batch:
+        ``gqa_attn_select_fwd`` alone, 2 recordings of 15,000 positions
+        under a selection map of 235 blocks a (query, head)."""
+        from deepspeech_tpu.ops import attn_pallas
+
+        args = (S((rows, s, 2, 16, 128), jnp.bfloat16),
+                S((rows, s, 2, 128), jnp.bfloat16),
+                S((rows, s, 2, 128), jnp.bfloat16),
+                S((rows, 2, s, -(-s // 64)), jnp.bool_))
+        return lambda: (lambda *v: attn_pallas.gqa_select_attention(*v, 64),
+                        args)
+
+    def linear_scan_case(rows=2, s=15000):
+        """minicpm_sala's linear-attention layer over a prefill
+        sub-batch: ``ssd_chunk_scan`` with a group a head (32 heads of
+        128, state 128), no skip, 118 chunks of 128."""
+        from deepspeech_tpu.ops import ssd_pallas
+
+        args = (S((rows, s, 32, 128), jnp.bfloat16),
+                S((rows, s, 32), jnp.float32), S((32,), jnp.float32),
+                S((rows, s, 32, 128), jnp.bfloat16),
+                S((rows, s, 32, 128), jnp.bfloat16), S((rows, s), jnp.bool_))
+        return lambda: (lambda x, dt, a, b, c, valid: ssd_pallas.chunk_scan(
+            x, dt, a, b, c, None, valid), args)
+
+    def linear_step_case(streams=32):
+        """... and one decode step: ``ssd_state_step`` with all 32
+        single-head groups (2 MB of state) a grid step, in place."""
+        from deepspeech_tpu.ops import ssd_pallas
+
+        args = (S((streams, 32, 128, 128), jnp.float32),
+                S((streams, 32, 128), jnp.bfloat16),
+                S((streams, 32), jnp.float32), S((32,), jnp.float32),
+                S((streams, 32, 128), jnp.bfloat16),
+                S((streams, 32, 128), jnp.bfloat16),
+                S((streams,), jnp.bool_))
+        return lambda: (lambda s_, x, dt, a, b, c, live: ssd_pallas.state_step(
+            s_, x, dt, a, b, c, None, live,
+            group_block=ssd_pallas.head_group_block(32)), args)
+
     def mhc_case(rows):
         """xing4_29b_a4b's hyper-connection of one sub-layer over
         ``rows`` positions of four bfloat16 streams of 3,584:
@@ -433,6 +491,13 @@ def kernel_cases():
     # prefill sub-batch and in a decode step of 128 streams
     cases["ssd_chunk_scan_falcon"] = ssd_scan_case()
     cases["ssd_state_step_falcon"] = ssd_step_case(128)
+    # minicpm_sala.transcribe_long_20min_b32: the sparse layer under
+    # its selection and a linear layer's recurrence, in a prefill
+    # sub-batch and in a decode step of 32 streams
+    cases["gqa_attn_select_decode_sala"] = select_decode_case()
+    cases["gqa_attn_select_fwd_sala"] = select_fwd_case()
+    cases["ssd_chunk_scan_sala"] = linear_scan_case()
+    cases["ssd_state_step_sala"] = linear_step_case()
     cases["ctc_aishell"] = ctc_case(4336, 400, 60)
     cases["ctc_en"] = ctc_case(29, 400, 160)
     # The weak-#1 shape: AISHELL-width device beam search, both merge

@@ -83,12 +83,13 @@ def serve_lm(args, cfg, topo) -> None:
     b, t = args.batch, engine.steps_max
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=chip)
-    # An array a latent layer or draft module, (keys, values) a
-    # grouped-query layer, (keys, values, state, convolution inputs) a
-    # hybrid layer; with a draft module the loop also takes each
-    # stream's first draft.
-    cache = [sds(s[0].shape, s[0].dtype) if len(s) == 1
-             else tuple(sds(x.shape, x.dtype) for x in s)
+    # An array a latent layer or draft module, else a tuple: (keys,
+    # values) a grouped-query layer, (keys, values, state, convolution
+    # inputs) a hybrid layer, (keys, values, pooled keys) a sparse
+    # layer, (state,) a linear one; with a draft module the loop also
+    # takes each stream's first draft.
+    cache = [tuple(sds(x.shape, x.dtype) for x in s)
+             if isinstance(s, tuple) else sds(s[0].shape, s[0].dtype)
              for s in engine.cache_shapes(b, args.frames)]
     draft = (sds((b,), jnp.int32),) if m.lm_draft_layers else ()
     programs = {

@@ -282,12 +282,20 @@ def layer_scan_route(cfg: ModelConfig, rows=None, *, mesh=None,
         dot_bytes=jnp.dtype(cfg.dtype).itemsize, **facts)
 
 
+# The forward kernels whose pair function is the kernel's two calls
+# (``ops/scan_pallas.scan_pair_vjp``): it takes the input projection's
+# bias apart from its matmul, before the weights.
+_PAIR_SUMS = ("gru_scan_fwd", "lstm_scan_fwd")
+
+
 def _scan_kernel(kernel: str, pair: bool = False):
     """The function of ``ops/`` that builds the forward kernel a route
     names, called as ``f(xproj, mask, *weights, [reverse,] interpret,
     dot_dtype)``; with ``pair`` the one that runs a layer's two
     directions where the route over both names that kernel (both
-    weight sets, no ``reverse``), or None where there is none."""
+    weight sets, no ``reverse``; the kernels of :data:`_PAIR_SUMS`:
+    ``f(product, mask, bias, *weights, ...)``), or None where there is
+    none."""
     from ..ops import lstm_pallas, rnn_pallas
 
     if pair:
@@ -446,8 +454,11 @@ def _run_direction(cfg: ModelConfig, xproj, mask, w_h, b_h, reverse,
                 remat_chunk=cfg.rnn_remat_chunk)
 
 
-def _run_stack_dirs(cfg: ModelConfig, xproj, mask, params, mesh=None):
-    """Run the direction set of one layer; ``params[rev] = (w_h, b_h)``.
+def _run_stack_dirs(cfg: ModelConfig, product, bias, mask, params,
+                    mesh=None):
+    """Run the direction set of one layer over its input projection,
+    handed over as :class:`InputProjection` leaves it (``xproj =
+    product + bias``); ``params[rev] = (w_h, b_h)``.
 
     Two float directions run as ONE function of the layer's ``xproj``,
     from what the route names for them. Where both weight sets fit
@@ -459,22 +470,56 @@ def _run_stack_dirs(cfg: ModelConfig, xproj, mask, params, mesh=None):
     (ops/scan_pallas.scan_pair_vjp), under ONE ``shard_batchwise``:
     the forward calls are the two a direction loop makes, and backward
     the forward direction's call hands its float32 ``dxp`` to the
-    reverse direction's, which writes the two's float32 sum, so no
-    pass outside the kernels adds two ``[B, T, G*H]`` cotangents. One direction, int8 leaves and the XLA scan compose
-    per direction.
+    reverse direction's, which writes the projection's gradient as its
+    backward reads it (the two's sum in ``xproj``'s type and, for the
+    bias, that sum's column sums), so no pass outside the kernels
+    adds, casts or reduces a ``[B, T, G*H]`` cotangent. That function
+    alone takes ``product`` and ``bias`` apart, the bias replicated
+    like the recurrent weights; everywhere else the bias is added
+    here, as ``nn.Dense`` added it. One direction, int8 leaves and the
+    XLA scan compose per direction.
     """
+    from ..ops.scan_pallas import add_proj_bias
+
     if len(params) == 2 and not any(_is_qdict(w) for w, _ in params.values()):
-        route = layer_scan_route(cfg, xproj.shape[0], mesh=mesh,
+        route = layer_scan_route(cfg, product.shape[0], mesh=mesh,
                                  directions=2,
-                                 xproj_bytes=xproj.dtype.itemsize)
+                                 xproj_bytes=product.dtype.itemsize)
         if _scan_kernel(route.kernel, pair=True) is not None:
-            return _run_kernel(cfg, route.kernel, mesh, xproj, mask,
+            operands = ((product, mask, bias) if route.kernel in _PAIR_SUMS
+                        else (add_proj_bias(product, bias), mask))
+            return _run_kernel(cfg, route.kernel, mesh, *operands,
                                *params[False], *params[True], pair=True)
+    xproj = add_proj_bias(product, bias)
     out = None
     for rev, (w_h, b_h) in params.items():
         ys = _run_direction(cfg, xproj, mask, w_h, b_h, rev, mesh=mesh)
         out = ys if out is None else out + ys
     return out
+
+
+class InputProjection(nn.Module):
+    """The hoisted input projection with its bias handed back apart:
+    ``nn.Dense``'s parameters (``kernel`` and ``bias``: its names,
+    shapes, float32 and initialisers, so a tree made by either loads
+    in the other) and its matmul in ``dtype``, returned as ``(product
+    [..., features], bias [features])`` for the caller to add
+    (``ops/scan_pallas.add_proj_bias``, the add ``nn.Dense`` makes) or
+    to hand to a function that adds it under its own VJP."""
+
+    features: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        kernel = self.param("kernel", nn.linear.default_kernel_init,
+                            (x.shape[-1], self.features), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (self.features,), jnp.float32)
+        product = jax.lax.dot_general(
+            x.astype(self.dtype), kernel.astype(self.dtype),
+            (((x.ndim - 1,), (0,)), ((), ())))
+        return product, bias
 
 
 class RNNLayer(nn.Module):
@@ -494,7 +539,8 @@ class RNNLayer(nn.Module):
             x = MaskedBatchNorm(name="bn")(x, mask, train)
         dtype = jnp.dtype(cfg.dtype)
         # Hoisted input projection: one big MXU matmul over all frames.
-        xproj = nn.Dense(n_gates * h, dtype=dtype, name="wx")(x.astype(dtype))
+        product, bias = InputProjection(n_gates * h, dtype, name="wx")(
+            x.astype(dtype))
 
         dirs = [False, True] if cfg.bidirectional else [False]
         params = {}
@@ -506,7 +552,8 @@ class RNNLayer(nn.Module):
                 self.param(f"bh_{suffix}", nn.initializers.zeros,
                            (n_gates * h,), jnp.float32))
 
-        out = _run_stack_dirs(cfg, xproj, mask, params, mesh=self.mesh)
+        out = _run_stack_dirs(cfg, product, bias, mask, params,
+                              mesh=self.mesh)
         out = out * mask[:, :, None]
         return out.astype(dtype)
 

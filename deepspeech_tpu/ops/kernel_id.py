@@ -30,7 +30,10 @@ fact, not a name:
   sum      ``pair`` on the ONE backward call of a bidirectional layer
            (``*_scan_bwd``, the reverse direction's) that takes the
            other direction's float32 ``dxp`` rows in and writes the
-           two's sum as its own ``dxp``
+           two's sum as its own ``dxp``, in ``xproj``'s type (its
+           first result: the one backward call whose ``dxp`` is not
+           float32 where ``xproj`` is not), with one more
+           ``[8 or 1, G*H]`` result, that sum's column sums
            (``scan_pallas.scan_pair_vjp``); absent on every other call
   p        lstmp_scan_*: width of the recurrent projection
   t, b, s  CTC: frames, padded batch rows, padded extended labels

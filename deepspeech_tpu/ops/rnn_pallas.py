@@ -79,12 +79,15 @@ the full-precision kernels.
 **Both directions of a layer** run as one function of the layer's
 ``xproj``. Where both matrices fit VMEM together: ONE kernel
 (``bigru_scan_pallas``, below). Where they do not (the flagship):
-``gru_scan_pair_pallas`` (``scan_pallas.scan_pair_vjp``), the two
-forward calls one after the other, and backward the forward
+``gru_scan_pair_pallas`` (``scan_pallas.scan_pair_vjp``), which takes
+the projection's matmul and its bias apart and adds them itself, then
+the two forward calls one after the other, and backward the forward
 direction's call handing its float32 ``dxp`` rows to the reverse
 direction's, which adds its own while they are in VMEM and writes the
-ONE float32 sum (the VJP rounds it to ``xproj``'s dtype): no pass
-outside the kernels adds the two directions' ``[T, b, 3H]`` gradients.
+ONE sum rounded to ``xproj``'s dtype, with the float32 sum's column
+sums (the projection's bias gradient) beside it: no pass outside the
+kernels adds, casts or reduces the two directions' ``[T, b, 3H]``
+gradients.
 
 Contract matches ``models.rnn.gru_scan`` (the XLA-scan oracle):
 ``(xproj [B,T,3H] incl. b_x, mask [B,T], w_h [H,3H], b_h [3H],
@@ -170,8 +173,8 @@ gru_scan_pallas.defvjp(*scan_vjp(GRU))
 # Both directions of a bidirectional GRU layer whose matrices do not
 # fit VMEM together, summed [B, T, H]: gru_scan_pallas's two forward
 # calls as ONE function, so that its VJP's two backward calls make
-# xproj's gradient between them. (xproj, mask, w_f, b_f, w_b, b_b,
-# interpret, dot_dtype).
+# xproj's gradient between them, the bias' too. (product, mask, b_x,
+# w_f, b_f, w_b, b_b, interpret, dot_dtype); xproj = product + b_x.
 gru_scan_pair_pallas = scan_pair_vjp(GRU)
 
 

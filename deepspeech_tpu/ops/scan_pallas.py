@@ -123,24 +123,31 @@ def _pinned_vmem_limit(rows: int, hidden: int, cell: _Cell, dot_bytes: int,
     math's other temporaries, rounded up to 4 MiB and never under
     Mosaic's default of 16 MiB. ds2_full (H=1760, bf16, 18.6 MB of
     weights) at b=32 / 64: forward 28 / 28 MiB, backward 32 / 40 MiB;
-    where the call sums the pair (``sums_pair``: the other direction's
-    float32 ``dxp`` row comes in too, 0.68 MB twice at b=32) 32 / 44."""
+    where the call sums the pair (``sums_pair``) the same 32 / 40: the
+    other direction's float32 ``dxp`` row comes in too (0.68 MB twice
+    at b=32), its own ``dxp`` row leaves as the sum in ``xproj``'s
+    type (0.34 MB for 0.68) and the projection's bias gradient has an
+    accumulator of its own beside ``db``'s."""
     wide = cell.gates * hidden
     lanes = pl.cdiv(wide, 128) * 128
     # per-step operands (xproj, mask; backward: each state's previous
     # row and dy) and results, as (width, bytes): a row narrower than
     # the lanes takes a whole lane tile
     ins = [(wide, xproj_bytes), (1, 4)]
-    outs = [hidden]
+    outs = [(hidden, 4)]
     whole = 1  # float32 blocks of one sublane tile that stay: the bias
     if backward:
         ins += [(hidden, 4)] * (cell.states + 1)
-        outs = [wide, wide, hidden]  # dxp, dgates and the previous state
+        # dxp, dgates and the previous state
+        outs = [(wide, 4), (wide, 4), (hidden, 4)]
         whole = 2  # and the bias gradient's accumulator, [1 or 8, wide]
         if sums_pair:
             ins += [(wide, 4)]
+            outs[0] = (wide, xproj_bytes)
+            whole = 3
     row_bytes = (sum(rows * max(w, 128) * n for w, n in ins)
-                 + whole * 8 * lanes * 4 + sum(rows * w * 4 for w in outs))
+                 + whole * 8 * lanes * 4
+                 + sum(rows * w * n for w, n in outs))
     scratch_bytes = rows * 4 * (cell.states * hidden + lanes)
     need = hidden * lanes * dot_bytes + 2 * row_bytes + scratch_bytes
     step = 4 * 1024 * 1024
@@ -193,9 +200,10 @@ def scan_route(cell: str, impl: str, *, hidden: int,
     ``backward``     the BPTT call of the same layer (a kernel of its
                      own, with more rows a step: its build may differ)
     ``sums_pair``    that BPTT call also takes the other direction's
-                     ``dxp`` rows in and writes the pair's sum
-                     (:func:`scan_pair_vjp`): one more row a step,
-                     counted in a copy-once limit
+                     ``dxp`` rows in and writes the pair's sum in
+                     ``xproj``'s type, with its column sums
+                     (:func:`scan_pair_vjp`): one more row a step and
+                     one more accumulator, counted in a copy-once limit
     """
     if impl != "pallas":
         return XLA_SCAN
@@ -560,6 +568,16 @@ def _fwd_step(cell: ScanCell, variant: str, n_weights: int, carried: bool,
         update(lambda: gates)
 
 
+def _add_column_sums(acc_ref, rows):
+    """One step's ``rows [b, G*H]`` into a gradient accumulator of
+    :func:`_bias_grad_rows`' form."""
+    if acc_ref.shape[0] == 1:
+        acc_ref[:] += jnp.sum(rows, axis=0, keepdims=True)
+    else:  # whole sublane tiles: vector adds, nothing across them
+        acc_ref[:] += functools.reduce(jnp.add, [
+            rows[i:i + 8] for i in range(0, rows.shape[0], 8)])
+
+
 def _bwd_step(cell: ScanCell, variant: str, sums_pair: bool, *refs,
               n_blocks: int = 1, c: int = 0):
     """One reverse-time BPTT grid step (flash-style gate recompute):
@@ -582,27 +600,27 @@ def _bwd_step(cell: ScanCell, variant: str, sums_pair: bool, *refs,
     step of ds2_full: PERF.md section 6, PR 48).
 
     ``sums_pair``: this is the second backward call of a bidirectional
-    layer's pair (:func:`scan_pair_vjp`). The first call's float32
-    ``dxp`` row of the same frames comes in as one more per-step
-    operand, and the ``dxp`` row that goes out is the input
-    projection's whole gradient, ``other + own`` in float32: the sum
-    XLA otherwise makes in a pass of its own over both float32
-    ``[T, b, G*H]`` arrays (2 x 574 MB read seven times a step of
-    ds2_full, and once more by the bias gradient it was fused into:
-    PERF.md section 6, PR 50). The result stays float32
-    ``[T, b, G*H]``, as every backward call's (the cast to the
-    projection's dtype is the VJP's): a call that writes it in bf16
-    saves XLA that pass too, but leaves the reader of the scans'
-    roofline, which knows a backward call by its two float32 results
-    of that shape, blind to it (PERF.md section 7). Nothing else of
-    the step differs.
+    layer's pair (:func:`scan_pair_vjp`), and it writes what the input
+    projection's backward READS. The first call's float32 ``dxp`` row
+    of the same frames comes in as one more per-step operand, and the
+    ``dxp`` row that goes out is the projection's whole gradient in
+    its final form: ``other + own`` in float32, rounded once, in VMEM,
+    to ``xproj``'s type (the result's). The float32 sum's rows go into
+    a second accumulator ``dbx`` of ``db``'s form on the way: the
+    projection's bias gradient. So no pass outside the kernels reads a
+    ``[T, b, G*H]`` cotangent but the projection's own contractions.
+    XLA made each of these in a pass of its own, seven times a step of
+    ds2_full: the sum over 2 x 574 MB float32 (PERF.md section 6,
+    PR 50), the float32 sum read back and rounded to bf16 (8.88 ms a
+    step) and one more reading for the bias' column sums (5.42 ms;
+    PERF.md section 6, PR 55). Nothing else of the step differs.
 
     refs: xproj row, mask row, each state's previous row, dy row,
     [``sums_pair``: the other direction's dxp row], W, bias, dxp row,
     dgates row, h_prev row (a ``(1, b, H)`` block of ``[T, b, H]`` or
-    the ``(b, H)`` block of a flat result), db, the state gradients'
-    scratches, [streamed: dh_acc, gates_buf, dg_prev], [pinned: matrix
-    scratch, semaphore].
+    the ``(b, H)`` block of a flat result), db, [``sums_pair``: dbx],
+    the state gradients' scratches, [streamed: dh_acc, gates_buf,
+    dg_prev], [pinned: matrix scratch, semaphore].
 
     Resident and copy-once: the step's ``dgates @ W^T`` goes into the
     carried gradient straight away (the matrix is whole in VMEM; read
@@ -621,6 +639,7 @@ def _bwd_step(cell: ScanCell, variant: str, sums_pair: bool, *refs,
     other_dxp_ref = refs.pop(0) if sums_pair else None
     w_ref, b_ref, dxp_ref, dgates_ref, hprev_ref, db_ref = refs[:6]
     scratch = refs[6:]
+    dbx_ref = scratch.pop(0) if sums_pair else None
     if variant == "pinned":
         *scratch, w_scr, sem = scratch
         _copy_weights_once(w_ref, w_scr, sem)
@@ -636,7 +655,7 @@ def _bwd_step(cell: ScanCell, variant: str, sums_pair: bool, *refs,
 
     @pl.when(start)
     def _():
-        for d in (*dstate_refs, db_ref):
+        for d in (*dstate_refs, db_ref, *([dbx_ref] if sums_pair else [])):
             d[:] = jnp.zeros_like(d)
         if blocked:
             dg_prev[:] = jnp.zeros_like(dg_prev)
@@ -658,17 +677,14 @@ def _bwd_step(cell: ScanCell, variant: str, sums_pair: bool, *refs,
             (dfirst(),) + tuple(d[:] for d in dstate_refs[1:]), dy_ref[0])
         if sums_pair:
             dxp = other_dxp_ref[0] + dxp
-        dxp_ref[0] = dxp
+            _add_column_sums(dbx_ref, dxp)
+        dxp_ref[0] = dxp.astype(dxp_ref.dtype)
         dgates_ref[0] = dgates
         if len(hprev_ref.shape) == 3:  # a row of [T, b, H]
             hprev_ref[0] = first
         else:  # b rows of the flat [T * b, H]
             hprev_ref[:] = first
-        if db_ref.shape[0] == 1:
-            db_ref[:] += jnp.sum(dgates, axis=0, keepdims=True)
-        else:  # whole sublane tiles: vector adds, nothing across them
-            db_ref[:] += functools.reduce(jnp.add, [
-                dgates[i:i + 8] for i in range(0, dgates.shape[0], 8)])
+        _add_column_sums(db_ref, dgates)
         return dgates, dprev
 
     if not blocked:
@@ -748,7 +764,7 @@ def scan_forward(cell: ScanCell, xproj, mask, w, b_h, *, reverse=False,
 def _scan_backward(cell: ScanCell, residuals, dy_t, *, reverse, interpret,
                    dot_dtype, other_dxp_t=None):
     """The backward call of one direction and what the VJP makes of its
-    results: ``(dxp_t [T, b, G*H], dW_h, db_h)`` from the forward
+    results: ``(dxp_t [T, b, G*H], dW_h, db_h, db_x)`` from the forward
     call's ``residuals`` and the time-major float32 cotangent ``dy_t``.
     The call hands back what it holds in VMEM beside ``dxp``: both
     operands of ``dW_h`` (``dgates`` and ``h_prev``, the first state
@@ -758,16 +774,20 @@ def _scan_backward(cell: ScanCell, residuals, dy_t, *, reverse, interpret,
 
     ``other_dxp_t``: the float32 ``dxp_t`` of the layer's other
     direction. The call then takes its rows in beside its own
-    (``_bwd_step``'s ``sums_pair``) and its ``dxp_t`` is the pair's
-    sum; without it, its own. Float32 either way.
+    (``_bwd_step``'s ``sums_pair``), its ``dxp_t`` is the pair's sum
+    in ``xproj``'s type and ``db_x [G*H]`` float32 that sum's column
+    sums over ``T * b`` (the input projection's bias gradient, summed
+    before the rounding); without it, ``dxp_t`` is its own in float32
+    (what a summing call reads) and ``db_x`` None.
 
     While jax traces it, it is recorded as the gauges
     ``scan_bias_grad{kernel, variant, form}``, ``form`` the
     accumulator's (``rows8`` / ``rows1``), ``scan_prev_state{kernel,
     variant, source="kernel", rows}``, ``rows`` how ``h_prev`` is laid
-    out (``flat`` / ``stepped``), and ``scan_input_grad{kernel,
-    variant, sum, dtype}``: whose ``dxp`` the call writes (``pair`` /
-    ``own``) and in what type (float32 today)."""
+    out (``flat`` / ``stepped``), ``scan_input_grad{kernel, variant,
+    sum, dtype}``: whose ``dxp`` the call writes (``pair`` / ``own``)
+    and in what type, and, on the summing call alone,
+    ``scan_proj_bias_grad{kernel, variant, source="kernel"}``."""
     gates, n = _CELLS[cell.name].gates, _CELLS[cell.name].states
     xp_t, mask_t, w_h, b_h, *seqs = residuals
     t_max, b, h = seqs[0].shape
@@ -791,10 +811,11 @@ def _scan_backward(cell: ScanCell, residuals, dy_t, *, reverse, interpret,
     # contraction (+0.84 ms a call at ds2_full's shape) or as a
     # pass of its own, and two reshapes outside the kernel it folds
     # back into that form (PERF.md section 6, PR 48). dgates stays
-    # [T, b, G*H]: a backward scan call is known by its two results
-    # of that shape (benchmark/layer_metrics/rnn_scan_roofline.py),
-    # the call that sums the pair too.
+    # [T, b, G*H], a bitcast away from those rows.
     flat = not b % 8
+    # the pair's sum leaves as xproj reads it; an own dxp float32, as
+    # the summing call takes it in
+    dxp_dtype = xp_t.dtype if sums_pair else jnp.dtype(jnp.float32)
     build = {"kernel": route.kernel, "variant": route.variant}
     obs.registry().gauge("scan_bias_grad", 1, labels={
         **build, "form": f"rows{db_rows}"})
@@ -803,17 +824,21 @@ def _scan_backward(cell: ScanCell, residuals, dy_t, *, reverse, interpret,
         "rows": "flat" if flat else "stepped"})
     obs.registry().gauge("scan_input_grad", 1, labels={
         **build, "sum": "pair" if sums_pair else "own",
-        "dtype": "float32"})
-    dxp_t, dgates_t, h_prev_t, db = scan_call(
+        "dtype": dxp_dtype.name})
+    if sums_pair:
+        obs.registry().gauge("scan_proj_bias_grad", 1, labels={
+            **build, "source": "kernel"})
+    dxp_t, dgates_t, h_prev_t, db, *dbx = scan_call(
         functools.partial(_bwd_step, cell, route.variant, sums_pair), route,
         reverse=reverse, hidden=h, gates=gates,
         rows=([(xp_t, at_bptt), (mask_t, at_bptt)]
               + [(s, at_prev) for s in seqs] + [(dy_t, at_bptt)]
               + ([(other_dxp_t, at_bptt)] if sums_pair else [])),
         weights=[w, bh2],
-        outs=([(gates * h, jnp.float32, at_bptt)] * 2
-              + [(h, jnp.float32, at_bptt, flat)]),
-        whole_outs=[(db_rows, gates * h)],
+        outs=[(gates * h, dxp_dtype, at_bptt),
+              (gates * h, jnp.float32, at_bptt),
+              (h, jnp.float32, at_bptt, flat)],
+        whole_outs=[(db_rows, gates * h)] * (1 + sums_pair),
         scratch=lambda cols: [h] * n + (
             [h, cols, cols] if streamed else []),
         interpret=interpret,
@@ -829,7 +854,8 @@ def _scan_backward(cell: ScanCell, residuals, dy_t, *, reverse, interpret,
     dw_h = recurrent_dw(h_prev_t, dgates_t.reshape(
         h_prev_t.shape[:-1] + (gates * h,)), dot)
     return (dxp_t, dw_h.astype(w_h.dtype),
-            jnp.sum(db, axis=0).astype(b_h.dtype))
+            jnp.sum(db, axis=0).astype(b_h.dtype),
+            jnp.sum(dbx[0], axis=0) if sums_pair else None)
 
 
 def _mask_cotangent(mask_t):
@@ -854,7 +880,7 @@ def scan_vjp(cell: ScanCell):
     @jax.named_scope("rnn_scan")
     def bwd(reverse, interpret, dot_dtype, residuals, dy):
         dy_t = jnp.moveaxis(dy.astype(jnp.float32), 1, 0)  # [T, B, H]
-        dxp_t, dw_h, db_h = _scan_backward(
+        dxp_t, dw_h, db_h, _ = _scan_backward(
             cell, residuals, dy_t, reverse=reverse, interpret=interpret,
             dot_dtype=dot_dtype)
         return (jnp.moveaxis(dxp_t, 0, 1),  # [B, T, G*H]
@@ -863,51 +889,71 @@ def scan_vjp(cell: ScanCell):
     return fwd, bwd
 
 
+def add_proj_bias(product, bias):
+    """The input projection's rows ``xproj`` from its matmul's
+    ``product [B, T, G*H]`` and its bias ``[G*H]``, in the product's
+    type: the add ``flax.linen.Dense`` makes after its matmul, under
+    the projection's name (``obs/layers.py`` reads a device
+    operation's layer from its scopes)."""
+    with jax.named_scope("wx"):
+        return product + jnp.reshape(bias.astype(product.dtype),
+                                     (1,) * (product.ndim - 1) + (-1,))
+
+
 def scan_pair_vjp(cell: ScanCell):
-    """A gated cell's two directions over the SAME ``xproj`` as ONE
-    function under its own ``custom_vjp``: ``f(xproj, mask, w_f, b_f,
-    w_b, b_b, interpret=False, dot_dtype=None) -> ys_f + ys_b
-    [B, T, H]``. Forward it is the two calls a one-direction layer
-    makes, one a direction (same kernels, facts and residuals).
-    Backward, its two calls make the input projection's gradient
-    between them. The first (the forward direction's) is a
-    one-direction layer's call and hands its float32 ``dxp_t
-    [T, b, G*H]`` to the second; the second reads those rows under its
-    own time map (both maps index frames, so the same rows meet
-    although the two calls run through time in opposite orders), adds
-    its own and writes the one float32 sum, which the VJP rounds once
-    to ``xproj``'s dtype: what the program states with two functions
-    (``add_any`` of the two float32 cotangents, then the cast to the
-    projection's dtype), without the pass over 2 x ``[T, b, G*H]``
-    float32 that XLA ran for the sum. Each direction's ``dgates``,
-    ``h_prev``, ``db``, ``dW_h`` and ``db_h`` are a one-direction
-    layer's bit for bit."""
+    """A gated cell's two directions over the SAME input projection as
+    ONE function under its own ``custom_vjp``: ``f(product, mask, b_x,
+    w_f, b_f, w_b, b_b, interpret=False, dot_dtype=None) -> ys_f + ys_b
+    [B, T, H]``, ``product [B, T, G*H]`` the projection's matmul and
+    ``b_x [G*H]`` its bias, which the function adds itself
+    (:func:`add_proj_bias`: ``xproj``, the program ``flax.linen.Dense``
+    states) so that the bias' cotangent has somewhere to come from.
+    Forward it is then the two calls a one-direction layer makes, one a
+    direction (same kernels, facts and residuals). Backward, its two
+    calls make the input projection's gradient between them, in the
+    form the projection's backward reads. The first (the forward
+    direction's) is a one-direction layer's call and hands its float32
+    ``dxp_t [T, b, G*H]`` to the second; the second reads those rows
+    under its own time map (both maps index frames, so the same rows
+    meet although the two calls run through time in opposite orders),
+    adds its own in float32 and writes the ONE sum rounded to
+    ``xproj``'s dtype, with the float32 sum's column sums beside it:
+    ``product``'s cotangent and ``b_x``'s. That is what the program
+    states with two functions (``add_any`` of the two float32
+    cotangents, the cast to the projection's dtype, a ``reduce_sum``
+    of that for the bias), without XLA's passes over ``[T, b, G*H]``
+    for the sum, for the cast and for the column sums; and the bias
+    gradient is the sum of the float32 values, not of the rounded
+    ones. Each direction's ``dgates``, ``h_prev``, ``db``, ``dW_h`` and
+    ``db_h`` are a one-direction layer's bit for bit."""
 
     @jax.named_scope("rnn_scan")
-    def both(xproj, mask, w_f, b_f, w_b, b_b, tape, **kw):
+    def both(product, mask, b_x, w_f, b_f, w_b, b_b, tape, **kw):
+        xproj = add_proj_bias(product, b_x)
         seqs_f, xp_t, mask_t = scan_forward(
             cell, xproj, mask, w_f, b_f, reverse=False, tape=tape, **kw)
         seqs_b, _, _ = scan_forward(
             cell, xproj, mask, w_b, b_b, reverse=True, tape=tape, **kw)
         ys = jnp.moveaxis(seqs_f[0], 0, 1) + jnp.moveaxis(seqs_b[0], 0, 1)
-        return ys, (xp_t, mask_t, (w_f, b_f, *seqs_f), (w_b, b_b, *seqs_b))
+        return ys, (xp_t, mask_t, b_x, (w_f, b_f, *seqs_f),
+                    (w_b, b_b, *seqs_b))
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-    def pair(xproj, mask, w_f, b_f, w_b, b_b, interpret=False,
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+    def pair(product, mask, b_x, w_f, b_f, w_b, b_b, interpret=False,
              dot_dtype=None):
-        return both(xproj, mask, w_f, b_f, w_b, b_b, False,
+        return both(product, mask, b_x, w_f, b_f, w_b, b_b, False,
                     interpret=interpret, dot_dtype=dot_dtype)[0]
 
-    def fwd(xproj, mask, w_f, b_f, w_b, b_b, interpret, dot_dtype):
-        return both(xproj, mask, w_f, b_f, w_b, b_b, True,
+    def fwd(product, mask, b_x, w_f, b_f, w_b, b_b, interpret, dot_dtype):
+        return both(product, mask, b_x, w_f, b_f, w_b, b_b, True,
                     interpret=interpret, dot_dtype=dot_dtype)
 
     @jax.named_scope("rnn_scan")
     def bwd(interpret, dot_dtype, residuals, dy):
-        xp_t, mask_t, fw, bw = residuals
+        xp_t, mask_t, b_x, fw, bw = residuals
         dy_t = jnp.moveaxis(dy.astype(jnp.float32), 1, 0)  # [T, B, H]
         kw = dict(interpret=interpret, dot_dtype=dot_dtype)
-        dxp_f, dw_f, db_f = _scan_backward(
+        dxp_f, dw_f, db_f, _ = _scan_backward(
             cell, (xp_t, mask_t, *fw), dy_t, reverse=False, **kw)
         # The first call's weight gradient before the second call: its
         # operands (dgates and h_prev, 766 MB at ds2_full's call) are
@@ -917,12 +963,12 @@ def scan_pair_vjp(cell: ScanCell):
         # on the barrier; dw_f goes round it, so that what follows the
         # contraction fuses with it as in a one-direction layer.
         dxp_f, _ = jax.lax.optimization_barrier((dxp_f, dw_f))
-        dxp_t, dw_b, db_b = _scan_backward(
+        dxp_t, dw_b, db_b, db_x = _scan_backward(
             cell, (xp_t, mask_t, *bw), dy_t, reverse=True,
             other_dxp_t=dxp_f, **kw)
-        # the float32 sum, rounded once: [B, T, G*H] in xproj's dtype
-        return (jnp.moveaxis(dxp_t.astype(xp_t.dtype), 0, 1),
-                _mask_cotangent(mask_t), dw_f, db_f, dw_b, db_b)
+        # the sum as the kernel rounded it: [B, T, G*H] in xproj's dtype
+        return (jnp.moveaxis(dxp_t, 0, 1), _mask_cotangent(mask_t),
+                db_x.astype(b_x.dtype), dw_f, db_f, dw_b, db_b)
 
     pair.defvjp(fwd, bwd)
     return pair

@@ -190,16 +190,21 @@ def test_pair_input_grad_holds_the_sum_to_xlas_bits(smoke, monkeypatch):
     """A bidirectional layer of ds2_full's scan call backward, 2 rows
     x 6 steps of it, interpreted: the summing call's ``dxp`` is XLA's
     ``(a + b).astype(bfloat16)`` of the two directions' float32 results
-    bit for bit, the four weight and bias gradients are the two
-    functions' own, and the calls' device times are None off the chip
-    (not measured). A pair whose second direction sees another matrix
-    ends the run."""
-    from deepspeech_tpu.ops import rnn_pallas
+    bit for bit, the projection's bias gradient it sums lies within
+    the limit of the float64 sum of the float32 values (and nearer
+    than the sum of the rounded ones), the four recurrent weight and
+    bias gradients are the two functions' own, and the calls' device
+    times are None off the chip (not measured). A pair whose second
+    direction sees another matrix, or whose bias gradient is another
+    array's sums, ends the run."""
+    from deepspeech_tpu.ops import rnn_pallas, scan_pallas
 
     monkeypatch.setattr(smoke, "SCAN_CALL", (2, 6))
     monkeypatch.setattr(smoke, "PAIR_TIMED_CALLS", 1)
     read = smoke.pair_input_grad(True)
     assert read["pair_dxp_values"] == 2 * 6 * 5280
+    assert (read["pair_proj_bias_grad_rel_err"] <= 1e-6
+            < read["pair_proj_bias_grad_rounded_rel_err"])
     assert {k: v for k, v in read.items() if k.endswith("_differing")} == {
         f"pair_{name}_differing": 0
         for name in ("dxproj", "dw_f", "db_f", "dw_b", "db_b")}
@@ -208,9 +213,17 @@ def test_pair_input_grad_holds_the_sum_to_xlas_bits(smoke, monkeypatch):
     pair = rnn_pallas.gru_scan_pair_pallas
     monkeypatch.setattr(
         rnn_pallas, "gru_scan_pair_pallas",
-        lambda x, m, w_f, b_f, w_b, b_b, *tail: pair(
-            x, m, w_f, b_f, w_b * 1.01, b_b, *tail))
+        lambda x, m, b_x, w_f, b_f, w_b, b_b, *tail: pair(
+            x, m, b_x, w_f, b_f, w_b * 1.01, b_b, *tail))
     with pytest.raises(SystemExit, match="differs from XLA's"):
+        smoke.pair_input_grad(True)
+    monkeypatch.setattr(rnn_pallas, "gru_scan_pair_pallas", pair)
+    sums = scan_pallas._add_column_sums
+    monkeypatch.setattr(
+        scan_pallas, "_add_column_sums",
+        lambda acc, rows: sums(acc, rows * (
+            1.01 if rows.shape[0] == 2 and acc.shape[0] == 1 else 1.0)))
+    with pytest.raises(SystemExit, match="projection's bias gradient"):
         smoke.pair_input_grad(True)
 
 

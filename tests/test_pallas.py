@@ -4,6 +4,7 @@ are the oracles."""
 
 import dataclasses
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -987,70 +988,107 @@ def _input_grad_adds(eqns, wide):
                     for v in e.invars)]
 
 
+def _wide_passes(eqns, wide):
+    """Every ``add`` / ``add_any`` (jax summing two directions'
+    ``dxp``), ``convert_element_type`` (rounding the sum) and
+    ``reduce_sum`` (the projection's bias gradient) outside a Pallas
+    call over a ``[., ., wide]`` operand."""
+    return [e for e in eqns if e.primitive.name in (
+        "add", "add_any", "convert_element_type", "reduce_sum")
+            and any(len(v.aval.shape) == 3 and v.aval.shape[-1] == wide
+                    for v in e.invars)]
+
+
+def _column_sum_errs(db_x, dxp_sum, dtype):
+    """How far the pair's bias cotangent ``db_x`` and the parent's (a
+    ``reduce_sum`` in ``dtype`` of the ROUNDED float32 ``dxp_sum
+    [B, T, G*H]``) lie from the float64 column sums of ``dxp_sum``,
+    as shares of the largest column: ``(mine, parents)``."""
+    want = np.asarray(dxp_sum, np.float64).sum(axis=(0, 1))
+    parents = jax.lax.reduce_sum(dxp_sum.astype(dtype), axes=(0, 1))
+    return tuple(float(np.abs(np.asarray(a, np.float64) - want).max()
+                       / np.abs(want).max()) for a in (db_x, parents))
+
+
 @pytest.mark.parametrize("cell, build, b", [
     (cell, build, b) for cell, rows in (("gru", (8, 32, 5)), ("lstm", (8,)))
     for build in ("resident", "pinned", "blocked") for b in rows])
 @pytest.mark.parametrize("xproj_dtype", ["float32", "bfloat16"])
 def test_scan_pair_bwd_sums_the_input_gradient(monkeypatch, cell, build, b,
                                                xproj_dtype):
-    """A layer's two directions as ONE function
-    (``scan_pallas.scan_pair_vjp``): backward, the forward direction's
-    call is a one-direction layer's and the reverse direction's takes
-    its float32 ``dxp`` rows in and writes the two's float32 sum,
-    which the VJP casts to the projection's dtype. At ragged masks, in
-    every build, for a float32 and a bfloat16 ``xproj``: the pair's
-    ``dxp`` is
-    ``(dxp_f + dxp_b).astype(xproj.dtype)`` of the two one-direction
-    VJPs' float32 results bit for bit (summed in float32, rounded
-    once); both ``dW_h`` and both ``db_h``, and what each call hands
-    to ``recurrent_dw``, are theirs bit for bit. The VJP holds no
-    ``add`` or ``add_any`` over a ``[., ., G*H]`` operand outside a
-    kernel (XLA ran 7 such passes over 2 x 574 MB a step of ds2_full,
-    and read both again for the projection's bias gradient: PERF.md
-    section 6, PR 50) and two ``*_scan_bwd`` calls, of which the
-    second alone carries the fact ``sum=pair`` and takes one more
-    ``[T, b, G*H]`` operand; both return two float32 ``[T, b, G*H]``
-    results, which is how ``benchmark/layer_metrics/
-    rnn_scan_roofline.py`` knows a backward scan; tracing leaves
-    ``scan_input_grad{kernel, variant, sum, dtype}`` in the registry,
-    once ``pair`` and once ``own``."""
+    """A layer's two directions as ONE function of the projection's
+    matmul and its bias (``scan_pallas.scan_pair_vjp``): backward, the
+    forward direction's call is a one-direction layer's and the
+    reverse direction's takes its float32 ``dxp`` rows in and writes
+    the projection's gradient in final form, the two's sum in
+    ``xproj``'s dtype and that sum's float32 column sums. At ragged
+    masks, in every build, for a float32 and a bfloat16 ``xproj``: the
+    pair's ``dxp`` is ``(dxp_f + dxp_b).astype(xproj.dtype)`` of the
+    two one-direction VJPs' float32 results over ``product + bias``
+    bit for bit (summed in float32, rounded once); the bias' cotangent
+    is the float64 column sum of the float32 ``dxp_f + dxp_b`` to
+    float32 summation error, and no further from it than the parent's
+    sum of the rounded values; both ``dW_h`` and both ``db_h``, and
+    what each call hands to ``recurrent_dw``, are the one-direction
+    VJPs' bit for bit. The backward program holds no ``add``,
+    ``add_any``, ``convert_element_type`` or ``reduce_sum`` over a
+    ``[., ., G*H]`` operand outside a kernel (XLA ran 7 of each a step
+    of ds2_full over 574 MB float32 and more: PERF.md section 6, PRs 50
+    and 55) and two ``*_scan_bwd`` calls, of which the second alone
+    carries the fact ``sum=pair``, takes one more ``[T, b, G*H]``
+    operand and returns one more ``[8 or 1, G*H]`` accumulator, its
+    first result in ``xproj``'s dtype (the first call's stays float32,
+    as the second reads it); tracing leaves ``scan_input_grad{kernel,
+    variant, sum, dtype}`` in the registry, once ``pair`` in
+    ``xproj``'s dtype and once ``own`` in float32, and
+    ``scan_proj_bias_grad{kernel, variant, source="kernel"}``."""
     from deepspeech_tpu import obs
     from deepspeech_tpu.ops import lstm_pallas, rnn_pallas
 
-    gates, scan, (xproj, mask, w_f, b_f), dy, handed, _ = \
+    gates, scan, (product, mask, w_f, b_f), dy, handed, _ = \
         _backward_scan_case(monkeypatch, cell, build, b, 50)
     pair = {"gru": rnn_pallas.gru_scan_pair_pallas,
             "lstm": lstm_pallas.lstm_scan_pair_pallas}[cell]
     (_, t, h) = dy.shape
-    xproj = xproj.astype(xproj_dtype)
+    product = product.astype(xproj_dtype)
     rng = np.random.default_rng(150 + b)
     w_b = jnp.asarray(rng.normal(size=w_f.shape) / np.sqrt(h), jnp.float32)
     b_b = jnp.asarray(rng.normal(size=b_f.shape) * 0.1, jnp.float32)
+    b_x = jnp.asarray(rng.normal(size=b_f.shape) * 0.5, jnp.float32)
+    xproj = scan_pallas.add_proj_bias(product, b_x)
+    assert xproj.dtype == product.dtype
 
     def vjp(*a):
-        return jax.vjp(lambda xp, *w: pair(xp, mask, *w, True), *a)
+        return jax.vjp(lambda p, *w: pair(p, mask, *w, True), *a)
 
     def one(reverse, w, bias):
         return jax.vjp(lambda xp, wh, bh: scan(xp, mask, wh, bh, reverse,
                                                True), xproj, w, bias)
 
     obs.registry().reset()
-    ys, pull = vjp(xproj, w_f, b_f, w_b, b_b)
+    ys, pull = vjp(product, b_x, w_f, b_f, w_b, b_b)
     got = pull(dy)  # eager: every kernel result is concrete
     gauges = obs.registry().snapshot()["gauges"]
-    for summed in ("pair", "own"):
+    for summed, dtype in (("pair", xproj_dtype), ("own", "float32")):
         assert gauges[
-            f'scan_input_grad{{dtype="float32",kernel="{cell}_scan_bwd",'
+            f'scan_input_grad{{dtype="{dtype}",kernel="{cell}_scan_bwd",'
             f'sum="{summed}",variant="{build}"}}'] == 1
+    assert gauges[f'scan_proj_bias_grad{{kernel="{cell}_scan_bwd",'
+                  f'source="kernel",variant="{build}"}}'] == 1
+    assert len([k for k in gauges if k.startswith("scan_proj_bias_grad")]) == 1
     (ys_f, pull_f), (ys_b, pull_b) = one(False, w_f, b_f), one(True, w_b, b_b)
     np.testing.assert_array_equal(np.asarray(ys), np.asarray(ys_f + ys_b))
     (dxp_f, *want_f), (dxp_b, *want_b) = pull_f(dy), pull_b(dy)
     assert dxp_f.dtype == dxp_b.dtype == jnp.float32
-    assert got[0].dtype == xproj.dtype
+    assert got[0].dtype == product.dtype
     np.testing.assert_array_equal(
         np.asarray(got[0], np.float32),
-        np.asarray((dxp_f + dxp_b).astype(xproj.dtype), np.float32))
-    for a, want, name in zip(got[1:], want_f + want_b,
+        np.asarray((dxp_f + dxp_b).astype(product.dtype), np.float32))
+    assert (got[1].dtype, got[1].shape) == (b_x.dtype, b_x.shape)
+    mine, parents = _column_sum_errs(got[1], dxp_f + dxp_b, product.dtype)
+    assert mine <= 1e-6, mine
+    assert mine <= max(parents, 1e-6), (mine, parents)
+    for a, want, name in zip(got[2:], want_f + want_b,
                              ["dw_f", "db_f", "dw_b", "db_b"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(want), name)
     assert len(handed) == 4  # the pair's two calls, then one a direction
@@ -1058,16 +1096,20 @@ def test_scan_pair_bwd_sums_the_input_gradient(monkeypatch, cell, build, b,
         for a, want in zip(mine, theirs):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(want))
 
-    eqns = _eqns(lambda *a: vjp(*a)[1](dy), xproj, w_f, b_f, w_b, b_b)
-    assert not _input_grad_adds(eqns, gates * h)
+    eqns = _eqns(pull, dy)  # the backward program alone
+    wide = (t, b, gates * h)
+    assert not _wide_passes(eqns, gates * h)
     first, second = _scan_bwd_calls(eqns)
     assert [c.params["metadata"].get("sum") for c in (first, second)] == [
         None, "pair"]
     assert [int(str(c.params["metadata"]["reverse"]))
             for c in (first, second)] == [0, 1]
-    wide = (t, b, gates * h)
     assert [[str(v.aval.dtype) for v in c.outvars if v.aval.shape == wide]
-            for c in (first, second)] == [["float32", "float32"]] * 2
+            for c in (first, second)] == [["float32", "float32"],
+                                          [xproj_dtype, "float32"]]
+    db_rows = (8 if b % 8 == 0 else 1, gates * h)
+    assert [[v.aval.shape for v in c.outvars].count(db_rows)
+            for c in (first, second)] == [1, 2]
     # the first call's dxp reaches the second behind its own weight
     # gradient (one barrier over the two: the contraction runs first)
     (barrier,) = [e for e in eqns
@@ -1076,17 +1118,19 @@ def test_scan_pair_bwd_sums_the_input_gradient(monkeypatch, cell, build, b,
     assert barrier.invars[1].aval.shape == w_f.shape
     assert barrier.outvars[0] in second.invars
     assert len(second.invars) == len(first.invars) + 1
-    # and the sum goes back as the kernel wrote it, cast and turned
+    # and the sum goes back as the kernel wrote it, turned batch-major
     (back,) = [e for e in eqns if second.outvars[0] in e.invars]
-    assert back.primitive.name == {
-        "float32": "transpose", "bfloat16": "convert_element_type"}[
-            xproj_dtype]
+    assert back.primitive.name == "transpose"
+    # forward, the bias is added to the product where nn.Dense adds it
+    fwd_eqns = _eqns(lambda *a: vjp(*a)[0], product, b_x, w_f, b_f, w_b, b_b)
+    (add,) = _input_grad_adds(fwd_eqns, gates * h)
+    assert add.invars[0].aval.shape == product.shape
 
 
 def _bidirectional_layer(monkeypatch, b, h, **cfg):
     """A GRU layer past the residency budget (two directions then are
-    two kernels), its model configuration and ``(xproj, mask, params)``
-    for ``models.rnn._run_stack_dirs``."""
+    two kernels), its model configuration and ``(product, bias, mask,
+    params)`` for ``models.rnn._run_stack_dirs``."""
     from deepspeech_tpu.config import get_config
 
     monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
@@ -1094,9 +1138,10 @@ def _bidirectional_layer(monkeypatch, b, h, **cfg):
         get_config("ds2_small").model, rnn_hidden=h, rnn_impl="pallas",
         dtype="float32", **cfg)
     rng = np.random.default_rng(b)
-    xproj, mask, w_f, b_f = _rand_gru(rng, b, 7, h)
+    product, mask, w_f, b_f = _rand_gru(rng, b, 7, h)
     _, _, w_b, b_b = _rand_gru(rng, b, 7, h)
-    return cfg, xproj, mask, {False: (w_f, b_f), True: (w_b, b_b)}
+    bias = jnp.asarray(rng.normal(size=(3 * h,)) * 0.5, jnp.float32)
+    return cfg, product, bias, mask, {False: (w_f, b_f), True: (w_b, b_b)}
 
 
 @pytest.mark.parametrize("layer, calls, sums", [
@@ -1110,32 +1155,35 @@ def test_layer_sums_the_pair_where_two_float_kernels_run(monkeypatch, layer,
                                                          calls, sums):
     """``models.rnn._run_stack_dirs`` chooses from what it observes: a
     layer of two float directions whose route names the one-direction
-    kernel runs that kernel's pair function (one ``add`` fewer over
-    ``[B, T, G*H]`` under ``jax.vjp``, the second backward call
-    carrying ``sum=pair``); one direction, int8 leaves and the XLA
-    scan lower to the calls and gauges they had."""
+    kernel runs that kernel's pair function and hands it product and
+    bias apart (no ``add``, ``convert_element_type`` or ``reduce_sum``
+    over ``[B, T, G*H]`` under ``jax.vjp``'s backward program, the
+    second backward call carrying ``sum=pair`` and the projection's
+    bias gradient); one direction, int8 leaves and the XLA scan add
+    the bias first and lower to the calls and gauges they had, the
+    bias' cotangent a ``reduce_sum`` of the ``xproj`` cotangent."""
     from deepspeech_tpu import obs
     from deepspeech_tpu.models import rnn
 
     b, h = 8, 16
-    cfg, xproj, mask, params = _bidirectional_layer(monkeypatch, b, h)
+    cfg, product, bias, mask, params = _bidirectional_layer(monkeypatch, b, h)
     if layer == "one_direction":
         del params[True]
     elif layer == "int8":
-        params = {rev: (dict(zip(("q", "scale"), _quantize_wh(w))), bias)
-                  for rev, (w, bias) in params.items()}
+        params = {rev: (dict(zip(("q", "scale"), _quantize_wh(w))), b_h)
+                  for rev, (w, b_h) in params.items()}
     elif layer == "xla":
         cfg = dataclasses.replace(cfg, rnn_impl="xla")
 
-    def run(xp):
-        return rnn._run_stack_dirs(cfg, xp, mask, params)
+    def run(product, bias):
+        return rnn._run_stack_dirs(cfg, product, bias, mask, params)
 
-    def train(xp):
-        ys, pull = jax.vjp(run, xp)
+    def train(product, bias):
+        ys, pull = jax.vjp(run, product, bias)
         return pull(ys)
 
     obs.registry().reset()
-    eqns = _eqns(run if layer == "int8" else train, xproj)
+    eqns = _eqns(run if layer == "int8" else train, product, bias)
     assert [str(e.params["metadata"]["kernel"]) for e in eqns
             if e.primitive.name == "pallas_call"] == calls
     assert [c.params["metadata"].get("sum")
@@ -1145,36 +1193,220 @@ def test_layer_sums_the_pair_where_two_float_kernels_run(monkeypatch, layer,
     assert {key.split('sum="')[1].split('"')[0]: value
             for key, value in gauges.items()
             if key.startswith("scan_input_grad")} == sums
-    # the directions' outputs are summed outside a kernel, one add;
-    # their input gradients only where the XLA scan runs
-    adds = _input_grad_adds(eqns, 3 * h)
-    assert bool(adds) == (layer == "xla"), adds
+    assert len([k for k in gauges if k.startswith(
+        "scan_proj_bias_grad")]) == (layer == "two_directions")
+    if layer == "int8":
+        return
+    # backward: the directions' input gradients are summed, and the
+    # bias' reduced from them, outside a kernel only where no pair
+    # function runs
+    ys, pull = jax.vjp(run, product, bias)
+    passes = {e.primitive.name for e in _wide_passes(_eqns(pull, ys), 3 * h)}
+    assert passes == {"two_directions": set(),
+                      "one_direction": {"reduce_sum"},
+                      "xla": {"add_any", "reduce_sum"}}[layer], passes
+    d_product, d_bias = pull(ys)
+    want = np.asarray(d_product, np.float64).sum(axis=(0, 1))
+    np.testing.assert_allclose(np.asarray(d_bias, np.float64), want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
 
 
 def test_layer_pair_runs_under_one_shard_map(monkeypatch):
-    """On a mesh the pair is ONE ``shard_batchwise`` call (``xproj``
-    and ``mask`` split over ``data``, the four weight operands
-    replicated), so every chip sums its own rows' ``dxp`` as one chip
-    does, and values and gradients are the unsharded layer's."""
+    """On a mesh the pair is ONE ``shard_batchwise`` call (``product``
+    and ``mask`` split over ``data``, the projection's bias and the
+    four weight operands replicated), so every chip sums its own rows'
+    ``dxp`` as one chip does, the bias' cotangent is summed over the
+    batch axis like the recurrent weights', and values and gradients
+    are the unsharded layer's."""
     from deepspeech_tpu.models import rnn
     from deepspeech_tpu.parallel import make_mesh
 
-    cfg, xproj, mask, params = _bidirectional_layer(monkeypatch, 16, 16)
+    cfg, product, bias, mask, params = _bidirectional_layer(
+        monkeypatch, 16, 16)
     mesh = make_mesh((8, 1))
 
-    def train(mesh, xp, params):
-        ys, pull = jax.vjp(lambda xp, p: rnn._run_stack_dirs(
-            cfg, xp, mask, p, mesh=mesh), xp, params)
+    def pulled(mesh, product, bias, params):
+        return jax.vjp(lambda *a: rnn._run_stack_dirs(
+            cfg, a[0], a[1], mask, a[2], mesh=mesh), product, bias, params)
+
+    def train(mesh, *a):
+        ys, pull = pulled(mesh, *a)
         return ys, pull(ys * ys)
 
-    eqns = _eqns(lambda xp, p: train(mesh, xp, p), xproj, params)
+    eqns = _eqns(lambda *a: train(mesh, *a), product, bias, params)
     maps = [e for e in eqns if e.primitive.name == "shard_map"]
     assert len(maps) == 2, maps  # the pair, and its VJP
-    assert not _input_grad_adds(eqns, 3 * 16)
-    want = train(None, xproj, params)
-    got = train(mesh, xproj, params)
+    assert [len(e.invars) for e in maps][0] == 7  # the bias goes in apart
+    ys, pull = pulled(mesh, product, bias, params)
+    assert not _wide_passes(_eqns(pull, ys), 3 * 16)
+    want = train(None, product, bias, params)
+    got = train(mesh, product, bias, params)
     for a, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(w), atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_layer_bias_gradient_is_the_float32_sums(monkeypatch, dtype):
+    """Through ``models.rnn.RNNLayer`` (a bidirectional GRU layer past
+    the residency budget, the kernels interpreted): the gradient of
+    ``wx/bias`` is the float64 column sum of the two one-direction
+    VJPs' float32 ``dxp_f + dxp_b`` to float32 summation error, for a
+    float32 and a bfloat16 model, and no further from it than the
+    parent's ``reduce_sum`` of the rounded cotangent; ``wx/kernel``'s
+    gradient is the contraction of the layer's input with the rounded
+    sum, as the parent's."""
+    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.models import rnn
+
+    monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
+    b, t, d, h = 8, 9, 24, 16
+    cfg = dataclasses.replace(
+        get_config("ds2_small").model, rnn_hidden=h, rnn_impl="pallas",
+        rnn_batch_norm=False, bidirectional=True, dtype=dtype)
+    rng = np.random.default_rng(55)
+    x = jnp.asarray(rng.normal(size=(b, t, d)), jnp.float32)
+    lens = jnp.asarray(rng.integers(4, t + 1, size=b), jnp.int32)
+    dy = jnp.asarray(rng.normal(size=(b, t, h)), jnp.float32)
+    layer = rnn.RNNLayer(cfg)
+    params = layer.init(jax.random.PRNGKey(0), x, lens, False)["params"]
+    params["wx"]["bias"] = jnp.asarray(
+        rng.normal(size=(3 * h,)) * 0.5, jnp.float32)
+
+    def loss(p):
+        ys = layer.apply({"params": p}, x, lens, False)
+        return jnp.sum(ys.astype(jnp.float32) * dy)
+
+    grads = jax.grad(loss)(params)
+    # the same layer a direction at a time, from the same xproj
+    mask = rnn.length_mask(lens, t)
+    xd = x.astype(dtype)
+    xproj = scan_pallas.add_proj_bias(
+        xd @ params["wx"]["kernel"].astype(dtype), params["wx"]["bias"])
+    dys = (dy.astype(dtype).astype(jnp.float32) if dtype == "bfloat16"
+           else dy) * mask[:, :, None]
+    dxp = [jax.vjp(lambda xp: gru_scan_pallas(
+        xp, mask, params[f"wh_{sfx}"], params[f"bh_{sfx}"], rev, True,
+        rnn._pallas_dot_dtype(jnp.dtype(dtype))), xproj)[1](dys)[0]
+        for rev, sfx in ((False, "fw"), (True, "bw"))]
+    assert dxp[0].dtype == jnp.float32
+    assert grads["wx"]["bias"].dtype == jnp.float32
+    mine, parents = _column_sum_errs(grads["wx"]["bias"], dxp[0] + dxp[1],
+                                     jnp.dtype(dtype))
+    assert mine <= 1e-6, mine
+    assert mine <= max(parents, 1e-6), (mine, parents)
+    rounded = (dxp[0] + dxp[1]).astype(dtype)
+    want = jnp.einsum("btd,btg->dg", xd, rounded).astype(jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(grads["wx"]["kernel"]), np.asarray(want),
+        rtol=2e-2 if dtype == "bfloat16" else 1e-5,
+        atol=(2e-2 if dtype == "bfloat16" else 1e-5)
+        * float(jnp.abs(want).max()))
+
+
+class _ParentLayer(nn.Module):
+    """``models.rnn.RNNLayer``'s parameters as the parent declared
+    them: ``wx`` a ``flax.linen.Dense``."""
+
+    cfg: object
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        gates, h = 3, cfg.rnn_hidden
+        xproj = nn.Dense(gates * h, dtype=jnp.dtype(cfg.dtype), name="wx")(x)
+        for sfx in ("fw", "bw"):
+            self.param(f"wh_{sfx}", nn.initializers.orthogonal(),
+                       (h, gates * h), jnp.float32)
+            self.param(f"bh_{sfx}", nn.initializers.zeros, (gates * h,),
+                       jnp.float32)
+        return xproj
+
+
+def test_layer_parameters_are_the_parents(monkeypatch, tmp_path):
+    """A two-direction layer's parameter tree is what it was with
+    ``wx`` an ``nn.Dense``: the same names, shapes and dtypes, and from
+    one seed the same values; a checkpoint written from the parent's
+    tree restores onto this layer's and runs, its ``xproj`` the
+    ``nn.Dense``'s bit for bit (the kernels' output then is the
+    layer's over that ``xproj``)."""
+    from deepspeech_tpu.checkpoint import CheckpointManager
+    from deepspeech_tpu.config import get_config
+    from deepspeech_tpu.models import rnn
+
+    monkeypatch.setattr(scan_pallas, "VMEM_WEIGHT_BUDGET", 0)
+    b, t, d, h = 8, 9, 24, 16
+    cfg = dataclasses.replace(
+        get_config("ds2_small").model, rnn_hidden=h, rnn_impl="pallas",
+        rnn_batch_norm=False, bidirectional=True, dtype="bfloat16")
+    rng = np.random.default_rng(56)
+    x = jnp.asarray(rng.normal(size=(b, t, d)), jnp.bfloat16)
+    lens = jnp.asarray(rng.integers(4, t + 1, size=b), jnp.int32)
+    layer, parent = rnn.RNNLayer(cfg), _ParentLayer(cfg)
+    mine = layer.init(jax.random.PRNGKey(7), x, lens, False)["params"]
+    theirs = parent.init(jax.random.PRNGKey(7), x)["params"]
+    assert jax.tree.structure(mine) == jax.tree.structure(theirs)
+    assert sorted(mine["wx"]) == ["bias", "kernel"]
+    for a, w in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
+        assert (a.shape, a.dtype) == (w.shape, w.dtype)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
+    theirs["wx"]["bias"] = jnp.asarray(rng.normal(size=(3 * h,)),
+                                       jnp.float32)
+    mgr = CheckpointManager(str(tmp_path), keep=1)
+    mgr.save(1, {"params": theirs})
+    mgr.wait()
+    loaded = mgr.restore(1, template={"params": mine})["params"]
+    mgr.close()
+    xproj = parent.apply({"params": theirs}, x)
+    mask = rnn.length_mask(lens, t)
+    want = sum(gru_scan_pallas(
+        xproj, mask, theirs[f"wh_{sfx}"], theirs[f"bh_{sfx}"], rev, True,
+        "bfloat16") for rev, sfx in ((False, "fw"), (True, "bw")))
+    got = layer.apply({"params": loaded}, x, lens, False)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32),
+        np.asarray((want * mask[:, :, None]).astype(jnp.bfloat16),
+                   np.float32))
+
+
+@pytest.mark.parametrize("cell", ["gru", "lstm"])
+@pytest.mark.parametrize("reverse", [False, True])
+def test_one_direction_backward_is_untouched_by_the_pair(monkeypatch, cell,
+                                                         reverse):
+    """A one-direction layer's backward call beside the pair's summing
+    call: one float32 ``dxp`` result, ONE bias accumulator and no
+    ``sum`` fact, and through ``models.rnn._run_stack_dirs`` its
+    gradients are the bare function's over ``product + bias`` bit for
+    bit, the bias' cotangent the ``reduce_sum`` of that ``dxp``."""
+    from deepspeech_tpu.models import rnn
+
+    gates, scan, (product, mask, w_h, b_h), dy, _, _ = \
+        _backward_scan_case(monkeypatch, cell, "pinned", 8, 57)
+    (b, t, h) = dy.shape
+    bias = jnp.asarray(np.random.default_rng(58).normal(
+        size=(gates * h,)), jnp.float32)
+    xproj = scan_pallas.add_proj_bias(product, bias)
+    ys, pull = jax.vjp(lambda xp, w, bh: scan(xp, mask, w, bh, reverse,
+                                              True), xproj, w_h, b_h)
+    dxp, dw, db = pull(dy)
+    (call,) = _scan_bwd_calls(_eqns(pull, dy))
+    assert "sum" not in call.params["metadata"]
+    assert [(v.aval.shape, str(v.aval.dtype)) for v in call.outvars] == [
+        ((t, b, gates * h), "float32"), ((t, b, gates * h), "float32"),
+        ((t * b, h), "float32"), ((8, gates * h), "float32")]
+    if reverse:
+        return  # a layer's one direction runs forward in time
+    from deepspeech_tpu.config import get_config
+    cfg = dataclasses.replace(
+        get_config("ds2_small").model, rnn_hidden=h, rnn_impl="pallas",
+        rnn_type=cell, dtype="float32")
+    ys_l, pull_l = jax.vjp(
+        lambda p, bx, w, bh: rnn._run_stack_dirs(
+            cfg, p, bx, mask, {False: (w, bh)}), product, bias, w_h, b_h)
+    np.testing.assert_array_equal(np.asarray(ys_l), np.asarray(ys))
+    d_product, d_bias, dw_l, db_l = pull_l(dy)
+    for a, want in ((d_product, dxp), (dw_l, dw), (db_l, db),
+                    (d_bias, jnp.sum(dxp, axis=(0, 1)))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(want))
 
 
 def test_lstm_pallas_respects_mask():

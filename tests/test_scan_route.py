@@ -115,7 +115,8 @@ def test_the_route_names_what_is_lowered(monkeypatch, cell, stored, hidden,
         params = {rev: _weights(cell, stored, hidden)
                   for rev in (False, True)[:directions]}
         args = (xproj, mask, params)
-        run = lambda xp, m, p: rnn_model._run_stack_dirs(cfg, xp, m, p)
+        run = lambda xp, m, p: rnn_model._run_stack_dirs(
+            cfg, xp, jnp.zeros(xp.shape[-1:], jnp.float32), m, p)
 
     def train(*a):
         ys, vjp = jax.vjp(lambda xp: run(xp, *a[1:]), a[0])

@@ -367,56 +367,73 @@ def test_backward_scan_hands_back_the_previous_state(v5e_chip, case, rows):
     assert int(asked[1]) == limit
 
 
+# the pair's summing call: the sum in xproj's bf16, and the
+# projection's bias gradient in an accumulator of its own
+_SUM_RESULTS = (r"%(\S+) = \(bf16\[850,{b},5280\]\S*, f32\[850,{b},5280\]\S*, "
+                r"f32\[{rows},1760\]\S*, f32\[8,5280\]\S*, f32\[8,5280\]\S*\) "
+                r"custom-call\(")
+
+
 @pytest.mark.parametrize("case, rows", [
     ("gru_pair_h1760_b32", 32), ("gru_pair_h1760_b64", 64)])
 def test_pair_backward_scans_sum_the_input_gradient(v5e_chip, case, rows):
     """A whole bidirectional layer of ds2_full as the TPU compiler is
     handed it (``gru_scan_pair_pallas`` + VJP at the cells' b=32 and
-    at twice the rows, bf16 ``xproj``): four ``pinned`` calls, of which
-    ONE backward call carries ``sum=pair`` and takes the other's
-    float32 ``dxp`` as one more operand; both return today's
+    at twice the rows, bf16 ``xproj``, the projection's bias handed
+    over apart): four ``pinned`` calls, known by their kernel facts
+    (``kernel``, ``sum``, ``t``, ``b``: what the scans' roofline reads
+    them by, ``benchmark/layer_metrics/rnn_scan_roofline.py`` ``read``).
+    The first backward call returns a one-direction layer's
     ``(f32[850,b,5280], f32[850,b,5280], f32[850*b,1760],
-    f32[8,5280])``, by which ``benchmark/layer_metrics/
-    rnn_scan_roofline.py`` knows a backward scan. Each asks for the
-    scoped VMEM ``_pinned_vmem_limit`` counts (32 / 40 MiB; the
-    summing call 32 / 44 with the new operand's double-buffered
-    float32 block) and Mosaic accepts both. No fusion reads two
-    ``f32[850,b,5280]`` (or ``[b,850,5280]``) arrays: XLA's sum of the
-    two directions' ``dxp``, 2 x 574 MB in and 287 MB out at b=32,
-    seven times a step of ds2_full (PERF.md section 6, PR 50). And
-    nothing but the two calls' own ``[8, 5280]`` accumulators is
-    reduced into ``f32[5280]``."""
+    f32[8,5280])``; the ONE that carries ``sum=pair`` takes that
+    float32 ``dxp`` as one more operand and returns
+    ``(bf16[850,b,5280], f32[850,b,5280], f32[850*b,1760], f32[8,5280],
+    f32[8,5280])``: the pair's sum as the projection's backward reads
+    it, and its column sums. Each asks for the scoped VMEM
+    ``_pinned_vmem_limit`` counts (32 / 40 MiB, the summing call too:
+    one more double-buffered float32 block in, its first result at
+    half the bytes, one more accumulator) under ``PINNED_VMEM_CAP``,
+    and Mosaic accepts both. No fusion reads two ``f32[850,b,5280]``
+    (or ``[b,850,5280]``) arrays (XLA's sum of the two directions'
+    ``dxp``: PERF.md section 6, PR 50), none reads one to write a
+    ``bf16`` array of that shape (the cast pass, 8.88 ms a step of
+    ds2_full: PR 55), and nothing but the calls' own ``[8, 5280]``
+    accumulators is reduced into ``[5280]``: three of them, the third
+    the projection's bias gradient (a reading of the sum of its own,
+    5.42 ms a step)."""
     from aot_kernels import compile_case, kernel_cases
     from benchmark.layer_metrics._kernel_id import kernel_facts
-    from benchmark.layer_metrics.rnn_scan_roofline import classify
 
-    from deepspeech_tpu.ops.scan_pallas import scan_route
+    from deepspeech_tpu.ops.scan_pallas import PINNED_VMEM_CAP, scan_route
 
     text = compile_case(kernel_cases()[case], v5e_chip).as_text()
     # each call's own line up to its target, then what follows it
     parts = text.split('custom_call_target="tpu_custom_call"')
     calls = [before.rsplit("\n", 1)[-1] + after
              for before, after in zip(parts, parts[1:])]
-    assert sorted((f["kernel"], f["variant"], f.get("sum", "own"))
-                  for f in map(kernel_facts, calls)) == [
-        ("gru_scan_bwd", "pinned", "own"), ("gru_scan_bwd", "pinned", "pair"),
-        ("gru_scan_fwd", "pinned", "own"), ("gru_scan_fwd", "pinned", "own")]
+    assert sorted((f["kernel"], f["variant"], f.get("sum", "own"),
+                   f["t"], f["b"]) for f in map(kernel_facts, calls)) == [
+        ("gru_scan_bwd", "pinned", "own", "850", str(rows)),
+        ("gru_scan_bwd", "pinned", "pair", "850", str(rows)),
+        ("gru_scan_fwd", "pinned", "own", "850", str(rows)),
+        ("gru_scan_fwd", "pinned", "own", "850", str(rows))]
     backward = [c for c in calls if kernel_facts(c)["kernel"] == "gru_scan_bwd"]
     for call in backward:
-        assert re.search(_BWD_RESULTS.format(b=rows, rows=850 * rows), call)
-        assert classify(call, 1760, 3) == ("bwd", 850, rows)
         sums_pair = kernel_facts(call).get("sum") == "pair"
+        results = _SUM_RESULTS if sums_pair else _BWD_RESULTS
+        assert re.search(results.format(b=rows, rows=850 * rows), call)
         limit = scan_route(
             "gru", "pallas", hidden=1760, rows=rows, dot_bytes=2,
             xproj_bytes=2, backward=True, sums_pair=sums_pair).vmem_limit
-        assert limit == {(32, False): 32, (32, True): 32, (64, False): 40,
-                         (64, True): 44}[rows, sums_pair] * 1024 * 1024
+        assert limit == {32: 32, 64: 40}[rows] * 1024 * 1024
+        assert limit < PINNED_VMEM_CAP
         asked = re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
                           call)
         assert int(asked[1]) == limit
         operands = call.split(" custom-call(", 1)[1].split("), ", 1)[0]
         assert operands.count("%") == 6 + sums_pair, operands
     wide = re.compile(rf"f32\[(850,{rows}|{rows},850),5280\]")
+    narrow = re.compile(rf"= bf16\[(850,{rows}|{rows},850),5280\]")
     shape_of = dict(re.findall(r"%(\S+) = (\(?\w+\[[\d,]*\])", text))
     for line in text.splitlines():
         fused = re.search(r" fusion\(([^)]*)\)", line)
@@ -424,9 +441,10 @@ def test_pair_backward_scans_sum_the_input_gradient(v5e_chip, case, rows):
             read = [name for name in re.findall(r"%([^\s,)]+)", fused[1])
                     if wide.match(shape_of.get(name, ""))]
             assert len(read) < 2, line
+            assert not (read and narrow.search(line)), line
     sums = re.findall(
-        r"= f32\[5280\]\S* reduce\([^\n]*dimensions=\{([\d,]+)\}", text)
-    assert sums == ["0", "0"], sums
+        r"= \w+\[5280\]\S* reduce\([^\n]*dimensions=\{([\d,]+)\}", text)
+    assert sums == ["0", "0", "0"], sums
 
 
 def test_ctc_vjp_folds_gamma_without_a_scatter(v5e_chip):

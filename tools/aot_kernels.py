@@ -74,8 +74,9 @@ def kernel_cases():
         """Forward + VJP (training), or with ``vjp=False`` the forward
         call alone: what evaluation and offline decode run. ``pair``: a
         whole bidirectional layer, both directions over one ``xproj``
-        as ONE function, whose second backward call sums the pair's
-        ``dxp``."""
+        (the projection's matmul and its float32 bias, handed over
+        apart) as ONE function, whose second backward call sums the
+        pair's ``dxp`` and its columns."""
         _, _, w, bh = rnnshapes(h, 3)
         xp, m = S((b_, t_, 3 * h), xdt), S((b_, t_), jnp.float32)
         scan = rp.gru_scan_pair_pallas if pair else rp.gru_scan_pallas
@@ -87,7 +88,8 @@ def kernel_cases():
             def train(*a):
                 ys, vjp_ = jax.vjp(step, *a)
                 return vjp_(jnp.ones_like(ys))
-            return (train if vjp else step), (xp, m) + (w, bh) * (1 + pair)
+            return (train if vjp else step), (
+                (xp, m) + (bh,) * pair + (w, bh) * (1 + pair))
         return f
 
     def lstm_case(h):
@@ -410,9 +412,10 @@ def kernel_cases():
     # scoped VMEM, forward 28 / 28 MiB, backward 32 / 40 MiB.
     cases["gru_h1760_b32"] = gru_case(1760, 32, 850, jnp.bfloat16)
     cases["gru_h1760_b64"] = gru_case(1760, 64, 850, jnp.bfloat16)
-    # a whole layer of that cell: both directions as one function, whose
-    # second backward call takes the first's float32 dxp rows in and
-    # writes the pair's float32 sum (32 / 44 MiB)
+    # a whole layer of that cell: both directions as one function of
+    # the projection's matmul and its bias, whose second backward call
+    # takes the first's float32 dxp rows in and writes the pair's sum
+    # in bf16 with its float32 column sums (32 / 40 MiB)
     cases["gru_pair_h1760_b32"] = gru_case(1760, 32, 850, jnp.bfloat16,
                                            pair=True)
     cases["gru_pair_h1760_b64"] = gru_case(1760, 64, 850, jnp.bfloat16,
